@@ -84,7 +84,9 @@ def start_worker_process(head_address: str, *,
     """Spawn a worker-node subprocess (reference: services.py:1514
     start_raylet — here the "raylet" and the worker runtime share one
     process).  ``force_cpu_platform`` keeps worker jax off the TPU so
-    the driver retains chip ownership (one jax TPU client per chip)."""
+    the driver retains chip ownership (one process per chip): it
+    ASSIGNS ``JAX_PLATFORMS=cpu``, overriding whatever platform the
+    parent's environment names."""
     cmd = [sys.executable, "-m", "ray_tpu.cluster.worker_main",
            "--head", head_address]
     if num_cpus is not None:
@@ -97,7 +99,7 @@ def start_worker_process(head_address: str, *,
         cmd += ["--labels", json.dumps(labels)]
     child_env = dict(os.environ)
     if force_cpu_platform:
-        child_env.setdefault("JAX_PLATFORMS", "cpu")
+        child_env["JAX_PLATFORMS"] = "cpu"
     # Worker prints must reach the node log promptly (and survive a
     # crash) — see worker_main's log capture.
     child_env.setdefault("PYTHONUNBUFFERED", "1")

@@ -10,6 +10,8 @@ yet — pass them explicitly at node start.
 
 from __future__ import annotations
 
+import glob
+import os
 import threading
 from typing import Dict, Optional
 
@@ -88,34 +90,34 @@ def detect_node_resources(num_cpus: Optional[float] = None,
                           num_tpus: Optional[float] = None,
                           resources: Optional[Dict[str, float]] = None
                           ) -> Dict[str, float]:
-    """Auto-detect this host's resources (reference:
-    _private/accelerators/tpu.py detects TPU chips via env/libtpu)."""
-    import os
-
+    """Auto-detect this host's resources."""
     total: Dict[str, float] = {}
     total["CPU"] = float(num_cpus if num_cpus is not None
                          else os.cpu_count() or 1)
     if num_tpus is None:
-        if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-            # CPU-forced process: no TPUs by construction.  Probing
-            # would initialize the jax backend, which must stay
-            # untouched until a possible jax.distributed.initialize
-            # (multi-host train bootstrap requires init-before-backend).
-            num_tpus = 0.0
-        else:
-            try:
-                import jax
-
-                num_tpus = float(len([d for d in jax.devices()
-                                      if d.platform != "cpu"]))
-            except Exception:
-                num_tpus = 0.0
+        num_tpus = float(_count_tpu_chips())
     if num_tpus:
         total["TPU"] = float(num_tpus)
     total["memory"] = float(_detect_memory_bytes())
     if resources:
         total.update({k: float(v) for k, v in resources.items()})
     return total
+
+
+def _count_tpu_chips() -> int:
+    """TPU chips this process may use, counted WITHOUT jax: from the
+    device files the TPU driver exposes (``/dev/accel<N>``, or one
+    numbered ``/dev/vfio`` group per chip — the same probe as the
+    reference's _private/accelerators/tpu.py).  ``jax.devices()`` would
+    answer too, but it initialises the backend: it takes the chip away
+    from a child this process may be about to start (one process per
+    chip), it breaks a later ``jax.distributed.initialize``, and its
+    failure used to be swallowed into "0 chips".  A process held to the
+    CPU (``JAX_PLATFORMS=cpu``) advertises none."""
+    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        return 0
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 def _detect_memory_bytes() -> int:

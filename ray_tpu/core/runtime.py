@@ -112,11 +112,17 @@ class Runtime:
 
         _logs_mod.install()
         # Device-plane telemetry (observability/device.py): a sampler
-        # thread that idles until this process imports jax, then ships
-        # HBM gauges + XLA compile events on the EventShipper rails.
+        # thread that idles until the program initialises a jax
+        # backend, then ships HBM gauges + XLA compile events on the
+        # EventShipper rails.  It never touches the chip unasked.
         from ..observability import device as _device_mod
 
         _device_mod.install()
+        # XLA's persistent compile cache: where the environment placed
+        # it, else one fixed in-checkout directory (compile_cache.py).
+        from ..compile_cache import place_compile_cache
+
+        place_compile_cache()
         # Flight recorder (observability/flightrec.py): crash-safe
         # on-disk ring of recent spans/logs/gauges plus faulthandler
         # stacks, so a kill -9'd process still leaves forensics its

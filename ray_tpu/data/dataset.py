@@ -623,7 +623,9 @@ def _assemble_batches(blocks: Iterator[Block], *, batch_size: int,
                                      local_shuffle_seed)
     it = _batch_iterator(blocks, batch_size, drop_last)
     if device_put:
-        it = _device_put_iter(it)
+        # Resolved HERE, on the consumer's thread: the active mesh is
+        # thread-local and the pump below runs on its own thread.
+        it = _device_put_iter(it, _batch_sharding())
     if prefetch > 0:
         it = _prefetch_iter(it, prefetch)
     if batch_format == "numpy":
@@ -690,14 +692,29 @@ def _format_batch(batch: Block, batch_format: str) -> Any:
     raise ValueError(f"unknown batch_format {batch_format!r}")
 
 
-def _device_put_iter(batches: Iterator[Block]) -> Iterator[Any]:
-    """Move batches to the default jax device, one ahead of the consumer
+def _batch_sharding():
+    """Where ``device_put=True`` batches go: split along the row
+    dimension over the active mesh's batch axes, so each device
+    receives only its own rows — or None (jax's default device) when
+    no mesh is active.  A mesh spanning several processes is left to
+    the caller: each process holds only its own rows there."""
+    from ray_tpu.parallel.sharding import current_mesh, logical_sharding
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1 or mesh.is_multi_process:
+        return None
+    return logical_sharding(("batch",), mesh)
+
+
+def _device_put_iter(batches: Iterator[Block], sharding=None
+                     ) -> Iterator[Any]:
+    """Move batches to the device(s), one ahead of the consumer
     (host→HBM transfer overlaps the consumer's current step)."""
     import jax
 
     pending = None
     for batch in batches:
-        nxt = jax.device_put(batch)
+        nxt = jax.device_put(batch, sharding)
         if pending is not None:
             yield pending
         pending = nxt

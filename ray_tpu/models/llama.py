@@ -613,9 +613,8 @@ def loss_fn(params: PyTree, batch: Dict[str, jax.Array],
     if positions is None:
         # Run the forward at the full sequence length and drop the last
         # position's logits, instead of slicing tokens to S-1: a
-        # 2047-long sequence defeats the flash kernel's block tiling
-        # (its fallback materializes S×S f32 scores — measured
-        # 2.4s/step vs 1.4s on the 440M bench).
+        # 2047-long sequence does not tile the flash kernel's blocks
+        # (it would take the kernel's pad-and-slice path every step).
         logits, aux = forward(params, tokens, config, return_aux=True)
         logits = logits[:, :-1]
     else:
@@ -662,24 +661,58 @@ def init_train_state(rng: jax.Array, config: LlamaConfig,
                      fused: bool = False) -> Dict[str, Any]:
     """``fused=True`` pairs with ``make_train_step(fused=True)``: the
     opt_state is a ``FusedAdamWState`` instead of the optax chain
-    tuple (same logical contents — count + two moment trees)."""
-    params = init_params(rng, config)
-    if fused:
-        if optimizer is not None:
-            raise ValueError("fused=True replaces the optax chain; "
-                             "pass hyperparameters, not an optimizer")
-        from ray_tpu.train.optim import fused_adamw_init
+    tuple (same logical contents — count + two moment trees).
 
-        opt_state = fused_adamw_init(params)
+    The whole state is built by ONE jitted program, directly under its
+    shardings when a mesh is active: params and every optimizer tree
+    shaped like them (both Adam moments) by the logical-axis rules,
+    scalars replicated.  No device ever holds the unsharded state, and
+    the train step meets the layout it keeps."""
+    if fused and optimizer is not None:
+        raise ValueError("fused=True replaces the optax chain; "
+                         "pass hyperparameters, not an optimizer")
+    from ray_tpu.parallel.sharding import current_mesh, current_rules
+
+    return _train_state_builder(config, optimizer, fused, current_mesh(),
+                                current_rules())(rng)
+
+
+@functools.lru_cache(maxsize=16)
+def _train_state_builder(config: LlamaConfig, optimizer, fused: bool,
+                         mesh, rules) -> Callable:
+    """The jitted ``rng -> train state`` for one (config, optimizer,
+    mesh, rules); cached so repeated inits share one trace and one
+    compile."""
+    if fused:
+        from ray_tpu.train.optim import fused_adamw_init as opt_init
     else:
-        if optimizer is None:
-            optimizer = default_optimizer()
-        opt_state = optimizer.init(params)
-    return {
-        "params": params,
-        "opt_state": opt_state,
-        "step": jnp.zeros((), jnp.int32),
-    }
+        opt_init = (optimizer or default_optimizer()).init
+
+    def build(rng):
+        params = init_params(rng, config)
+        return {
+            "params": params,
+            "opt_state": opt_init(params),
+            "step": jnp.zeros((), jnp.int32),
+        }
+
+    if mesh is None or mesh.size == 1:
+        return jax.jit(build)
+    from ray_tpu.parallel.sharding import logical_sharding
+
+    param_shardings = jax.tree.map(
+        lambda axes: logical_sharding(axes, mesh, rules),
+        param_logical_axes(config),
+        is_leaf=lambda v: isinstance(v, tuple))
+    params_def = jax.tree.structure(param_shardings)
+    replicated = logical_sharding((), mesh, rules)
+    shardings = jax.tree.map(
+        lambda sub: (param_shardings
+                     if jax.tree.structure(sub) == params_def
+                     else replicated),
+        jax.eval_shape(build, jax.random.key(0)),
+        is_leaf=lambda sub: jax.tree.structure(sub) == params_def)
+    return jax.jit(build, out_shardings=shardings)
 
 
 def make_train_step(config: LlamaConfig, optimizer=None,

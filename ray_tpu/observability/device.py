@@ -16,9 +16,11 @@ scheduling decisions read.  Four surfaces, one module:
    on the CPU backend — where tier-1 runs — a live-arrays fallback
    attributes ``jax.live_arrays()`` bytes per device, so the whole
    pipeline (sampler → gauges → shipper → TSDB → query/alert) is
-   exercised without a chip.  The sampler NEVER imports jax itself: it
-   idles until the process does (``sys.modules`` check), so non-jax
-   workers pay one sleeping thread and nothing else.
+   exercised without a chip.  The sampler NEVER imports jax and
+   NEVER initialises a backend: it idles until the program has done
+   both itself, so non-jax workers pay one sleeping thread, and a
+   process that imports jax but leaves the chip to a child (or has yet
+   to call ``jax.distributed.initialize``) is left alone.
 
 2. **XLA compile tracking** — a ``jax.monitoring`` duration listener
    turns every backend compilation into a timeline span
@@ -172,16 +174,19 @@ def model_plane_metrics():
 
 
 def peak_bf16_flops(device_kind: str) -> Optional[float]:
-    """Per-chip bf16 peak by device kind (public TPU spec sheets);
-    None for unknown kinds (CPU) — callers skip the MFU gauge then."""
+    """Per-chip bf16 peak by device kind (public TPU spec sheets).
+    None for a CPU kind — callers skip the MFU gauge there.  Any other
+    kind missing from the table raises: a guessed peak turns into a
+    wrong utilization that looks measured."""
     kind = (device_kind or "").lower()
+    if not kind or "cpu" in kind:
+        return None
     table = [
         ("v6", 918e12),          # Trillium / v6e
         ("v5 lite", 197e12),     # v5e (394 is the int8 number)
         ("v5litepod", 197e12),
         ("v5e", 197e12),
         ("v5p", 459e12),
-        ("v5", 459e12),          # bare v5 -> assume v5p
         ("v4", 275e12),
         ("v3", 123e12),
         ("v2", 46e12),
@@ -189,7 +194,9 @@ def peak_bf16_flops(device_kind: str) -> Optional[float]:
     for key, flops in table:
         if key in kind:
             return flops
-    return None
+    raise ValueError(
+        f"no bf16 peak on record for device kind {device_kind!r}; add "
+        f"it to peak_bf16_flops with its source")
 
 
 # ------------------------------------------------------------- sampler
@@ -199,18 +206,31 @@ def peak_bf16_flops(device_kind: str) -> Optional[float]:
 _fallback_peak: Dict[str, int] = {}
 
 
-def sample_devices() -> Optional[List[Dict[str, Any]]]:
-    """One sample of every local device: ``{device, platform, used,
-    peak, limit, live_buffers}`` per device.  Returns None when jax is
-    not loaded in this process (the sampler must never force the
-    import — that is the worker's decision)."""
+def initialized_jax():
+    """The jax module iff this process imported it AND has already
+    initialised a backend; else None.  Telemetry only ever looks at a
+    backend the program brought up itself: ``jax.local_devices()`` on
+    an uninitialised process would take the chip (one process per
+    chip — a parent that leaves it to a child must stay off it) and
+    would break a later ``jax.distributed.initialize``.  jax has no
+    public probe for this; ``xla_bridge.backends_are_initialized`` is
+    what ``jax.distributed`` itself checks."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    try:
-        devices = jax.local_devices()
-    except Exception:
-        return None  # backend not initialized yet
+    from jax._src import xla_bridge
+
+    return jax if xla_bridge.backends_are_initialized() else None
+
+
+def sample_devices() -> Optional[List[Dict[str, Any]]]:
+    """One sample of every local device: ``{device, platform, used,
+    peak, limit, live_buffers}`` per device.  Returns None until the
+    program has initialised a jax backend (see initialized_jax)."""
+    jax = initialized_jax()
+    if jax is None:
+        return None
+    devices = jax.local_devices()
     stats_by_dev = {}
     for dev in devices:
         try:
@@ -265,15 +285,16 @@ def _live_array_bytes(jax) -> Dict[str, tuple]:
 
 
 def sample_once() -> Optional[List[Dict[str, Any]]]:
-    """Sample and publish the device gauges (one sampler tick).  Also
-    the moment the compile listener installs — jax just proved it is
-    importable."""
+    """Sample and publish the device gauges (one sampler tick).  The
+    compile listener installs as soon as jax is imported (registering
+    it touches no backend), so compiles are counted from the first
+    tick; the gauges wait for the program's own backend."""
     if not _enabled:
         return None
+    _install_compile_listener()
     samples = sample_devices()
     if samples is None:
         return None
-    _install_compile_listener()
     m = _device_metrics()
     for s in samples:
         tags = {"device": s["device"]}
@@ -293,7 +314,8 @@ _sampler_stop: Optional[threading.Event] = None
 def install() -> None:
     """Start the per-process sampler thread (idempotent; called at
     Runtime boot next to the structured-log handler).  The thread
-    no-ops until jax is imported, so boot stays jax-free."""
+    no-ops until the program initialises a jax backend, so boot stays
+    jax-free and never takes the chip."""
     global _sampler_stop
     with _sampler_lock:
         if _sampler_stop is not None:
@@ -477,12 +499,9 @@ def record_train_step(tokens: int, step_s: float,
         m["train_tokens_per_s"].set(tps)
         m["train_step_seconds"].set(step_s)
         if device_kind is None:
-            jax = sys.modules.get("jax")
+            jax = initialized_jax()
             if jax is not None:
-                try:
-                    device_kind = jax.local_devices()[0].device_kind
-                except Exception:
-                    device_kind = None
+                device_kind = jax.local_devices()[0].device_kind
         if n_params and device_kind:
             peak = peak_bf16_flops(device_kind)
             if peak:

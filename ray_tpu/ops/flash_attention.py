@@ -31,23 +31,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams → CompilerParams; accept either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+from ray_tpu.parallel.sharding import shard_over_mesh
 
 LANES = 128
-# 1024 measured end-to-end on the 440M train bench (v5e, chained steps
-# with host readback): 22.5k tok/s vs 18.9k at 512 and 14.9k at 256 —
-# fewer grid steps amortize per-step sequencing overhead.  2048-wide
-# blocks fail to compile (VMEM).  (An earlier 1024 change was reverted
-# in 0982f3d because it was justified by dispatch-only microbenchmarks;
-# this one is justified by the full train step.)
+# 1024 x 1024 tiles: forward, dq and dk/dv all compile through Mosaic
+# at the train shape (B8 S2048 H8 D128) under libtpu 0.0.34 within the
+# default scoped-VMEM limit (chip run, PR 21).  The choice of 1024 over
+# 512 / 256 rests on a pre-PR-1 record taken under another compiler
+# (fewer grid steps amortize per-step sequencing overhead; 2048-wide
+# blocks did not fit VMEM) and has not been re-measured: PERF.md.
 DEFAULT_BLOCK = 1024
 NEG_INF = -1e30
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() not in ("tpu",)
+    """Compiled through Mosaic iff the backend is ``tpu``; interpreted
+    iff it is ``cpu`` (the simulated-mesh test suite).  Any other
+    backend is an error: interpreting there would hide that the kernel
+    never met the compiler."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash attention kernels run compiled on 'tpu' or interpreted "
+        f"on 'cpu'; jax.default_backend() is {backend!r}")
 
 
 def _block_sizes(sq: int, sk: int, block_q: Optional[int],
@@ -201,7 +210,7 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret):
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -349,7 +358,7 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, 1, bq, D), q_map),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -389,7 +398,7 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -492,13 +501,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Flash attention.  q: (B, S, Hq, D); k/v: (B, S, Hkv, D) with
     Hq % Hkv == 0 (GQA).  Softmax scale is D**-0.5 (applied inside).
 
-    Falls back to the einsum path for shapes the TPU kernel does not
-    tile (tiny/odd S or D) — numerics are identical either way.
+    On TPU a shape the kernel cannot tile (after the causal pad below)
+    raises: silently running another implementation would hide that
+    the kernel is off the path.
     """
     B, Sq, Hq, D = q.shape
     Sk = k.shape[1]
     if Hq % k.shape[2]:
         raise ValueError(f"Hq={Hq} not a multiple of Hkv={k.shape[2]}")
+    # Under a mesh the kernel runs per shard of the batch and head axes
+    # (attention is independent across both); the sequence stays whole.
+    q_axes = ("batch", None, "heads", "head_dim")
+    kv_axes = ("batch", None, "kv_heads", "head_dim")
+    flash = shard_over_mesh(
+        functools.partial(_flash, causal=causal, block_q=block_q,
+                          block_k=block_k),
+        in_axes=(q_axes, kv_axes, kv_axes), out_axes=q_axes)
     if not _supported(Sq, Sk, D):
         if causal and Sq == Sk:
             # Pad the sequence up to a tileable length and slice the
@@ -510,32 +528,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             s_pad = -Sq % LANES
             if _supported(Sq + s_pad, Sk + s_pad, D):
                 pad = ((0, 0), (0, s_pad), (0, 0), (0, 0))
-                out = _flash(jnp.pad(q, pad), jnp.pad(k, pad),
-                             jnp.pad(v, pad), causal, block_q, block_k)
+                out = flash(jnp.pad(q, pad), jnp.pad(k, pad),
+                            jnp.pad(v, pad))
                 return out[:, :Sq]
-        if _use_interpret():
-            # Interpret mode tiles any shape; no fallback needed.
-            return _flash(q, k, v, causal, block_q, block_k)
-        return _einsum_fallback(q, k, v, causal)
-    return _flash(q, k, v, causal, block_q, block_k)
-
-
-def _einsum_fallback(q, k, v, causal):
-    B, Sq, Hq, D = q.shape
-    if causal:
-        from ray_tpu.models.llama import dot_attention
-
-        positions = jnp.broadcast_to(jnp.arange(Sq, dtype=jnp.int32),
-                                     (B, Sq))
-        return dot_attention(q, k, v, positions)
-    Hkv = k.shape[2]
-    group = Hq // Hkv
-    qg = q.reshape(B, Sq, Hkv, group, D)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
-                   preferred_element_type=jnp.float32) * (D ** -0.5)
-    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
-    return out.reshape(B, Sq, Hq, D)
+        if not _use_interpret():  # interpret mode tiles any shape
+            raise ValueError(
+                f"flash_attention cannot tile Sq={Sq}, Sk={Sk}, D={D} "
+                f"on TPU (needs Sq % 8 == 0, Sk % {LANES} == 0 and D "
+                f"<= {LANES} or a multiple of it); use "
+                f"attention_impl='dot' for this shape")
+    return flash(q, k, v)
 
 
 def flash_attention_causal(q, k, v, positions=None,

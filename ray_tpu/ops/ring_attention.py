@@ -157,9 +157,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     rules = current_rules()
     q_spec = rules.spec(("batch", "seq", "heads", "head_dim"))
     kv_spec = rules.spec(("batch", "seq", "kv_heads", "head_dim"))
-    from ray_tpu.parallel.sharding import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring, axis_name=axis_name, axis_size=n),
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec),

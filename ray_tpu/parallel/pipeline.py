@@ -64,10 +64,10 @@ def pipeline_layers(layer_fn: Callable[[jax.Array, PyTree], jax.Array],
     x_spec = P(None, batch_spec, *(None,) * (x.ndim - 1))
     param_spec = jax.tree.map(lambda _: P(pipe_axis), stacked_params)
 
-    from .sharding import shard_map, suppress_constraints
+    from .sharding import suppress_constraints
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_spec, x_spec), out_specs=x_spec,
         check_vma=False)
     def run(local_layers, xmb):

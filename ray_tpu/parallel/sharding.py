@@ -19,20 +19,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions: new jax exposes it at the
-    top level with ``check_vma``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-
-
 # A logical axis maps to: one mesh axis, a tuple of mesh axes (the dim
 # is sharded over their product), or None (replicated).
 Rule = Tuple[str, Union[str, Tuple[str, ...], None]]
@@ -180,6 +166,27 @@ def with_logical_constraint(x, *logical_axes: Optional[str],
     rules = rules or _ctx.rules
     spec = rules.spec(logical_axes)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def shard_over_mesh(fn, in_axes: Sequence[Sequence[Optional[str]]],
+                    out_axes: Sequence[Optional[str]]):
+    """``fn`` run once per shard of the active mesh: ``jax.shard_map``
+    with its specs resolved from logical axis names.  Returns ``fn``
+    itself when no mesh is active, the mesh has one device, or the
+    caller is already inside a manual region (suppress_constraints).
+
+    For ops the SPMD partitioner cannot split — Pallas kernels: a bare
+    ``pallas_call`` inside jit on sharded operands is handed the GLOBAL
+    arrays, so every device gathers all of them and does all the
+    work."""
+    mesh = _ctx.mesh
+    if mesh is None or mesh.size == 1 or getattr(_ctx, "suppress", False):
+        return fn
+    rules = _ctx.rules
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(rules.spec(axes) for axes in in_axes),
+        out_specs=rules.spec(out_axes), check_vma=False)
 
 
 def shard_params(params, logical_axes_tree, mesh: Optional[Mesh] = None,

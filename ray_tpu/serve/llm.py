@@ -71,10 +71,10 @@ whose per-call host↔device round trip is tens of milliseconds:
   shapes and decode buckets are compiled at init (warmup=True) so no
   request ever pays a compile.
 
-Measured end-to-end (BENCH_r05, dense plane, 125M model,
-max_slots=112, 24-token prompts, 32 new tokens): 4,098 decode tok/s
-sustained at saturation — the whole-request number, including prefill
-admission and host scheduling.
+Speed: not measured on current code (``PERF.md`` is where a measured
+number will be stated).  ``chip_smoke.py`` drives both planes on the
+chip at the 125M engine shape and checks that no request pays a
+compile.
 """
 
 from __future__ import annotations

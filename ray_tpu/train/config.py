@@ -27,7 +27,6 @@ class ScalingConfig:
 
     num_workers: int = 1
     mesh: Optional[MeshSpec] = None
-    use_tpu: bool = True
     resources_per_worker: Optional[Dict[str, float]] = None
     placement_strategy: str = "PACK"
 

@@ -130,8 +130,9 @@ class _TrainWorker:
         One call per OS process: actors run as threads inside their
         node's process, so a multi-host gang needs one worker per node
         (SPREAD placement).  jax backends must not have been touched in
-        this process yet — detect_node_resources deliberately avoids
-        probing on CPU-forced workers for this reason."""
+        this process yet — which is why neither Runtime boot
+        (detect_node_resources counts chips from device files) nor the
+        device-telemetry sampler ever initialises one."""
         import jax
 
         st = _jax_distributed_state

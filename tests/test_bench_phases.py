@@ -51,21 +51,13 @@ def test_kv_quant_bench_phase_smoke(serve_session):
     assert out["kv_quant_decode_ratio"] > 0
 
 
-def test_train_phase_emits_mfu_field():
-    """The train phase's JSON always carries the ``mfu`` key (None on
-    CPU where the roofline is unknown) so BENCH tooling can assert on
-    it — the ≥0.50 target must be visible round over round."""
-    import json
+def test_bench_module_imports_without_side_effects():
+    """``import bench`` starts nothing (the phase smokes above import
+    from it).  That bench.py refuses to RUN without the chip is held
+    by tests/test_chip_bringup.py."""
     import subprocess
     import sys
 
-    # bench.py main() is too heavy for tier-1; assert the contract at
-    # the source level instead: the field is set unconditionally.
-    src = open("bench.py").read()
-    assert 'extra["mfu"] = ' in src
-    assert "if mfu_denom and on_tpu else None" in src
-    # And the serialization stays parseable with a None mfu.
-    assert json.loads(json.dumps({"mfu": None}))["mfu"] is None
     assert subprocess.run(
         [sys.executable, "-c", "import bench"],
         capture_output=True).returncode == 0

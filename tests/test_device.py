@@ -89,6 +89,41 @@ class TestSampler:
                    for t in threading.enumerate())
 
 
+    def test_sampler_never_initialises_a_backend(self):
+        """A Runtime process that imports jax but leaves it unused must
+        stay backend-uninitialised (on the chip the sampler would
+        otherwise take the TPU away from a child, and it broke
+        jax.distributed.initialize); once the PROGRAM brings a backend
+        up, the gauges appear.  Fresh process: this one's backend is
+        long initialised."""
+        code = """
+import time
+import ray_tpu
+ray_tpu.init(num_cpus=1, num_tpus=0)
+import jax
+from jax._src import xla_bridge
+from ray_tpu.observability import metrics
+time.sleep(0.3)  # six sampler periods
+assert not xla_bridge.backends_are_initialized(), "sampler took the backend"
+assert not metrics.metrics_summary().get("ray_tpu_device_hbm_bytes_used")
+x = jax.numpy.ones((64, 64)).block_until_ready()  # the program's own init
+deadline = time.monotonic() + 10
+while not metrics.metrics_summary().get("ray_tpu_device_hbm_bytes_used"):
+    assert time.monotonic() < deadline, "no gauges after backend init"
+    time.sleep(0.05)
+ray_tpu.shutdown()
+print("ok")
+"""
+        import os
+
+        proc = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=120,
+            env={**os.environ, "RAY_TPU_DEVICE_SAMPLE_S": "0.05"})
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.stdout.strip().endswith("ok")
+
+
 # ---------------------------------------------------- compile tracking
 class TestCompileTracking:
     def test_forced_recompiles_count_and_span(self):
@@ -212,6 +247,12 @@ class TestModelPlane:
         assert device_mod.peak_bf16_flops("TPU v4") == 275e12
         assert device_mod.peak_bf16_flops("TPU v5e") == 197e12
         assert device_mod.peak_bf16_flops("TFRT_CPU_0") is None
+        assert device_mod.peak_bf16_flops("cpu") is None
+        # No guessed peak: a chip missing from the table is an error,
+        # and bare "v5" no longer silently means v5p.
+        for kind in ("TPU v9", "TPU v5"):
+            with pytest.raises(ValueError, match="no bf16 peak"):
+                device_mod.peak_bf16_flops(kind)
 
 
 # ------------------------------------------------------- top rendering
