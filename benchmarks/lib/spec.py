@@ -1,0 +1,114 @@
+"""Find a cell's files by name.  Nothing here knows any cell, model,
+traffic mix or metric: a later PR adds one by adding a file and an entry
+in ``BENCHMARK.json``.
+
+    cell     benchmarks/workloads/<cell>.json
+    config   benchmarks/configs/<config>.json    (+ references/<reference>.py)
+    traffic  benchmarks/traffic/<traffic>.json
+    kind     benchmarks/kinds/<kind>.py          run(ctx) -> observations
+    metric   benchmarks/metrics/<reader>.py      read(obs) -> number | None
+
+A metric's name is ``<reader>`` or ``<group>.<reader>``.  An entry of
+``BENCHMARK.json`` has ONE ``moves``, so a reader that is reported where
+it moves tokens/s and where it moves a latency is two entries
+(``batch.decode_step_device_ms``, ``chat.decode_step_device_ms``) that
+share the one file ``metrics/decode_step_device_ms.py``.  A later cell
+joins an entry's ``workloads`` or brings an entry under a group name of
+its own; neither needs a file.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import re
+from typing import Any, Dict, List, Optional
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH_DIR)
+_NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+class SpecError(Exception):
+    """The benchmark's own files do not fit together."""
+
+
+def _load_json(kind: str, name: str, bench_dir: str) -> Dict[str, Any]:
+    if not _NAME.match(name):
+        raise SpecError(f"{kind} name {name!r} is not a name")
+    path = os.path.join(bench_dir, kind, name + ".json")
+    if not os.path.isfile(path):
+        raise SpecError(f"no {kind} file {path}")
+    with open(path) as f:
+        return json.load(f)
+
+
+def load_module(kind: str, name: str, bench_dir: str = BENCH_DIR):
+    """``benchmarks/<kind>/<name>.py`` as a module, or None if absent."""
+    if not _NAME.match(name):
+        raise SpecError(f"{kind} name {name!r} is not a name")
+    path = os.path.join(bench_dir, kind, name + ".py")
+    if not os.path.isfile(path):
+        return None
+    spec = importlib.util.spec_from_file_location(
+        f"benchmarks.{kind}.{name.replace('-', '_').replace('.', '_')}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+class Cell:
+    """One entry of ``BENCHMARK.json``'s ``workloads`` with everything
+    its names lead to."""
+
+    def __init__(self, name: str, bench_dir: str = BENCH_DIR,
+                 benchmark_json: Optional[str] = None):
+        self.bench_dir = bench_dir
+        path = benchmark_json or os.path.join(
+            os.path.dirname(bench_dir), "BENCHMARK.json")
+        with open(path) as f:
+            self.benchmark = json.load(f)
+        entries = [w for w in self.benchmark["workloads"]
+                   if w["name"] == name]
+        if not entries:
+            raise SpecError(f"{name!r} is not a workload of {path}")
+        self.entry = entries[0]
+        self.name = name
+        self.chips = int(self.entry["chips"])
+        self.workload = _load_json("workloads", name, bench_dir)
+        self.config = _load_json("configs", self.entry["config"], bench_dir)
+        self.traffic = _load_json("traffic", self.entry["traffic"],
+                                  bench_dir)
+        for key in ("config", "traffic", "chips"):
+            if self.workload[key] != self.entry[key]:
+                raise SpecError(
+                    f"{name}: workloads/{name}.json says {key}="
+                    f"{self.workload[key]!r}, BENCHMARK.json says "
+                    f"{self.entry[key]!r}")
+        self.kind = load_module("kinds", self.workload["kind"], bench_dir)
+        if self.kind is None:
+            raise SpecError(f"no kind {self.workload['kind']!r}")
+        self.reference = load_module("references",
+                                     self.config["reference"], bench_dir)
+        if self.reference is None:
+            raise SpecError(
+                f"no reference {self.config['reference']!r}")
+
+    def metric_entries(self, group: str) -> List[Dict[str, Any]]:
+        """The ``end_to_end`` or ``per_layer`` metrics this cell
+        reports: those with no ``workloads`` key, or that list it."""
+        return [m for m in self.benchmark[group]
+                if self.name in m.get("workloads", [self.name])]
+
+    def readers(self, group: str):
+        """(entry, read function) per metric of the cell."""
+        out = []
+        for entry in self.metric_entries(group):
+            reader = entry["name"].rsplit(".", 1)[-1]
+            module = load_module("metrics", reader, self.bench_dir)
+            if module is None:
+                raise SpecError(f"metric {entry['name']!r} has no "
+                                f"benchmarks/metrics/{reader}.py")
+            out.append((entry, module.read))
+        return out

@@ -1,0 +1,256 @@
+"""Each kind of run end to end on the CPU at a tiny configuration, through
+the same ``run.measure`` the command uses (the platform check lifted
+here and nowhere else) — and, by the way the tiny cells get there, the
+proof that a configuration, a cell, a traffic mix and a per-layer metric
+are added as NEW FILES and entries, with no edit to a file that exists:
+the benchmark's tree is copied, files are dropped in, ``BENCHMARK.json``
+gets entries appended, and ``run.py`` finds them by name.
+"""
+
+import json
+import os
+import shutil
+import time
+
+import pytest
+
+from benchmarks import run as bench_run
+from benchmarks.lib import spec
+
+TINY = {
+    "name": "tiny", "source": "none (test)", "reference": "dense_decoder",
+    "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 2,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "intermediate_size": 128, "max_position_embeddings": 256,
+    "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
+    "tie_word_embeddings": False, "hidden_act": "silu", "bias": False,
+    "reduced": [], "assumed": [],
+    "program_fields": {"attention_impl": "flash", "remat_policy": "attn"},
+}
+LENGTHS = {"prompt_tokens": {"dist": "lognormal", "median": 16,
+                             "sigma": 0.5, "min": 4, "max": 32,
+                             "stratified": 4},
+           "output_tokens": {"dist": "lognormal", "median": 12,
+                             "sigma": 0.5, "min": 2, "max": 40,
+                             "stratified": 4}}
+TRAFFIC = {
+    "tiny-train": {"generator": "token_batches", "batch": 4, "seq_len": 128,
+                   "distinct_batches": 4},
+    "tiny-train4": {"generator": "token_batches", "batch": 8,
+                    "seq_len": 128, "distinct_batches": 4},
+    "tiny-closed": {"generator": "requests", **LENGTHS,
+                    "arrivals": {"process": "closed", "callers": 8,
+                                 "lead_in_s": 0.5, "drain_s": 20.0}},
+    "tiny-open": {"generator": "requests", **LENGTHS,
+                  "arrivals": {"process": "poisson", "rate_per_s": 15.0,
+                               "lead_in_s": 0.5, "drain_s": 20.0}},
+}
+ENGINE = {"max_slots": 4, "max_len": 128, "prefill_buckets": [32, 64],
+          "paged": False, "prefill_groups": [2, 4]}
+TRAINER = {"fused_optimizer": True, "prefetch_batches": 2,
+           "sync_every_steps": 3, "warmup_steps": 1}
+CELLS = {   # name -> (cell file, the real cell whose metrics it reports)
+    "tiny.tiny-train": (
+        {"kind": "train_lm", "chips": 1,
+         "trainer": {"mesh": None, **TRAINER}},
+        "smollm2-360m.train-1chip"),
+    "tiny.tiny-train4": (
+        {"kind": "train_lm", "chips": 4,
+         # the worker builds its mesh over ALL of jax.devices(), and the
+         # test suite's CPU has eight: data=2 takes up the other four
+         "trainer": {"mesh": {"data": 2, "fsdp": 4}, **TRAINER}},
+        "internlm2-1.8b.train-fsdp4"),
+    "tiny.tiny-closed": (
+        {"kind": "serve_llm", "chips": 1, "engine": ENGINE,
+         "deployment": {"max_ongoing_requests": 64}},
+        "internlm2-1.8b.serve-batch-decode"),
+    "tiny.tiny-open": (
+        {"kind": "serve_llm", "chips": 1, "engine": ENGINE,
+         "deployment": {"max_ongoing_requests": 64}},
+        "internlm2-1.8b.serve-chat-open"),
+}
+NEW_METRIC = '''"""Requests the generator measured (a count, from its log)."""
+
+
+def read(obs):
+    return float(len(obs["measured"])) if "measured" in obs else None
+'''
+
+
+@pytest.fixture(scope="module")
+def tree(tmp_path_factory):
+    """A copy of the benchmark with the throw-away files dropped in."""
+    root = tmp_path_factory.mktemp("bench")
+    bench = str(root / "benchmarks")
+    shutil.copytree(spec.BENCH_DIR, bench, ignore=shutil.ignore_patterns(
+        "out", "__pycache__", "tests"))
+    before = {os.path.relpath(os.path.join(d, f), bench): os.path.getmtime(
+        os.path.join(d, f)) for d, _, fs in os.walk(bench) for f in fs}
+
+    def drop(rel, text):
+        path = os.path.join(bench, rel)
+        assert not os.path.exists(path), f"{rel} would be an edit"
+        with open(path, "w") as f:
+            f.write(text)
+
+    drop("configs/tiny.json", json.dumps(TINY))
+    for name, traffic in TRAFFIC.items():
+        drop(f"traffic/{name}.json", json.dumps(traffic))
+    drop("metrics/tiny_requests_measured.py", NEW_METRIC)
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+        benchmark = json.load(f)
+    benchmark["configs"].append(
+        {"name": "tiny", "source": "none (test)", "reduced": [],
+         "file": "benchmarks/configs/tiny.json", "why": "test"})
+    for name, (cell, like) in CELLS.items():
+        traffic = name.split(".", 1)[1]
+        cell = dict(cell, name=name, config="tiny", traffic=traffic,
+                    why="test")
+        drop(f"workloads/{name}.json", json.dumps(cell))
+        benchmark["workloads"].append(
+            {"name": name, "config": "tiny", "traffic": traffic,
+             "chips": cell["chips"], "why": "test"})
+        for group in ("end_to_end", "per_layer"):
+            for metric in benchmark[group]:
+                if like in metric.get("workloads", []):
+                    metric["workloads"].append(name)
+    benchmark["per_layer"].append(
+        {"name": "tiny_requests_measured", "unit": "count",
+         "better": "higher", "source": "program_counter",
+         "layer": "request path", "moves": "serve_tpot_p50_ms",
+         "workloads": ["tiny.tiny-open"]})
+    path = str(root / "BENCHMARK.json")
+    with open(path, "w") as f:
+        json.dump(benchmark, f)
+    for rel, mtime in before.items():   # nothing that was there changed
+        assert os.path.getmtime(os.path.join(bench, rel)) == mtime, rel
+    return bench, path
+
+
+@pytest.fixture
+def cpu_peaks(monkeypatch):
+    """Percent-of-peak metrics need a peaks row; on the CPU the test
+    supplies one (no file of the benchmark lists a CPU)."""
+    from benchmarks.lib import runtime
+
+    real = runtime.load_peaks
+    monkeypatch.setattr(
+        runtime, "load_peaks",
+        lambda kind, bench_dir=None: real("TPU v5 lite"))
+
+
+def _measure(tree, cell, trace, seconds=2.0):
+    bench, benchmark_json = tree
+    result, obs = bench_run.measure(
+        ["--workload", cell, "--seed", "3", "--seconds", str(seconds),
+         "--trace", str(trace)],
+        allow_platforms=("cpu",), bench_dir=bench,
+        benchmark_json=benchmark_json, t_process=time.perf_counter())
+    assert set(result) >= {"correct", "attempted", "failed", "metrics",
+                           "device"}
+    assert result["correct"] is True, obs["checks"]
+    assert result["failed"] == 0 < result["attempted"]
+    assert result["device"]["platform"] == "cpu"
+    json.dumps(result)      # the last line is JSON
+    for metric in result["metrics"].values():
+        assert isinstance(metric["value"], float) and metric["unit"]
+    return result, obs
+
+
+@pytest.mark.parametrize("cell,headlines", [
+    ("tiny.tiny-train", ["train_tokens_per_s_per_chip"]),
+    ("tiny.tiny-train4", ["train_tokens_per_s_per_chip"]),
+    ("tiny.tiny-closed", ["serve_output_tokens_per_s"]),
+    ("tiny.tiny-open", ["serve_tpot_p50_ms"]),
+])
+def test_kind_end_to_end_on_the_cpu(tree, cpu_peaks, cell, headlines):
+    result, obs = _measure(tree, cell, trace=0)
+    assert set(result["metrics"]) == {*headlines, "setup_s"}
+    assert all(result["metrics"][h]["value"] > 0 for h in headlines)
+    assert result["device"]["count"] == (4 if "train4" in cell else 1)
+    if "train" in cell:
+        # the step's first loss and gradient norm against the float32
+        # reference's, as on the chip
+        assert obs["loss_gap"] < 2e-3
+        assert obs["grad_norm_gap"] < 2e-2
+        assert max(obs["grad_leaf_gaps"].values()) < 0.1
+    else:
+        assert len(obs["logit_gaps"]) == 4
+
+
+def test_traced_run_reports_per_layer_metrics_and_a_new_one(tree,
+                                                            cpu_peaks):
+    """``--trace 1`` on the open-loop kind, on a cell made of a NEW
+    config, traffic mix and metric."""
+    result, obs = _measure(tree, "tiny.tiny-open", trace=1, seconds=5.0)
+    metrics = result["metrics"]
+    assert metrics["tiny_requests_measured"]["value"] == \
+        result["attempted"]
+    assert {"chat.ttft_p50_ms", "ttft_p90_ms", "loadgen_lag_p99_ms",
+            "setup_compile_s", "window_compiles"} <= set(metrics)
+    assert metrics["window_compiles"]["value"] == 0.0
+    # no end-to-end metric in a traced run, and nothing a CPU cannot know
+    assert "serve_tpot_p50_ms" not in metrics
+    assert not any("roofline" in m or "device" in m for m in metrics)
+    assert "breakdown" in result
+    assert {"busy_s", "window_s"} <= set(result["device"])
+
+
+def test_command_refuses_without_a_chip(tree):
+    from benchmarks.lib import runtime
+
+    with pytest.raises(runtime.BenchmarkRefused):
+        bench_run.measure(
+            ["--workload", "smollm2-360m.train-1chip", "--seed", "1",
+             "--seconds", "1", "--trace", "0"])
+    assert bench_run.main(
+        ["--workload", "smollm2-360m.train-1chip", "--seed", "1",
+         "--seconds", "1", "--trace", "0"]) == 2
+
+
+@pytest.mark.parametrize("tied", [False, True])
+def test_reference_gradient_matches_the_program_at_tiny_size(tied):
+    """The reference's layer-by-layer backward (what the on-chip check
+    holds the step's ``grad_norm`` to) against ``value_and_grad`` of the
+    program's loss, float32, same weights; and the whole gradient, leaf
+    by leaf, through ``jax.grad`` of the reference's logits."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from benchmarks.lib import program
+    from benchmarks.references import dense_decoder
+    from ray_tpu.models import llama
+
+    tiny = dict(TINY, tie_word_embeddings=tied)
+    cfg = program.llama_config(tiny, dtype=jnp.float32,
+                               attention_impl="dot", remat=False)
+    params = llama.init_params(jax.random.key(0), cfg)
+    tokens = jax.random.randint(jax.random.key(1), (4, 32), 0, 256)
+
+    def ref_loss(p):
+        lg = dense_decoder.logits(p, tokens, tiny)
+        logp = jax.nn.log_softmax(lg[:, :-1], -1)
+        return -jnp.mean(jnp.take_along_axis(
+            logp, tokens[:, 1:, None], -1))
+
+    loss, ours = jax.value_and_grad(llama.loss_fn)(
+        params, {"tokens": tokens}, cfg)
+    theirs = jax.grad(ref_loss)(params)
+    for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6)
+    ref, layered = dense_decoder.loss_and_grads(
+        params, np.asarray(tokens), tiny, rows_at_a_time=2)
+    assert ref == pytest.approx(float(loss), rel=1e-5)
+    assert dense_decoder.global_norm(layered) == pytest.approx(
+        float(optax.global_norm(ours)), rel=1e-5)
+    gaps = dense_decoder.gradient_gaps(ours, layered)
+    assert set(gaps) == set(params["layers"]) | (set(params) - {"layers"})
+    assert max(gaps.values()) < 1e-4
+    # a backward that loses dq moves the norm by a few percent (1% at
+    # smollm2-360m's sizes) and wq's own gradient by all of it
+    ours["layers"]["wq"] = 0.0 * ours["layers"]["wq"]
+    assert optax.global_norm(ours) == pytest.approx(
+        dense_decoder.global_norm(layered), rel=0.05)
+    assert dense_decoder.gradient_gaps(ours, layered)["wq"] == 1.0

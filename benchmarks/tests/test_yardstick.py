@@ -1,0 +1,276 @@
+"""The yardstick's own arithmetic: FLOP and byte counts against
+hand-worked numbers, the load generator's determinism, the trace
+reduction on a recorded TPU trace, and that BENCHMARK.json's names all
+lead to files."""
+
+import json
+import os
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from benchmarks.lib import flops, loadgen, spec, trace_reduce
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+FIXTURE = os.path.join(HERE, "fixtures", "tiny_tpu.xplane.pb")
+
+
+def _config(name):
+    with open(os.path.join(spec.BENCH_DIR, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+# ------------------------------------------------------------------ flops
+@pytest.mark.parametrize("name,params,matmul", [
+    # 24 x (2048*2048 + 2*2048*1024 + 2048*2048 + 3*2048*8192 + 2*2048)
+    #   + 2 * 92544*2048 + 2048 ; matmul = the same less norms/embedding
+    ("internlm2-1.8b", 1_889_110_016, 1_699_479_552),
+    # 32 x (960*960 + 2*960*320 + 960*960 + 3*960*2560 + 2*960)
+    #   + 49152*960 + 960 (tied head)
+    ("smollm2-360m", 361_821_120, 361_758_720),
+])
+def test_parameter_counts_by_hand(name, params, matmul):
+    c = _config(name)
+    assert flops.param_count(c) == params == c["parameters"]
+    assert flops.matmul_params(c) == matmul
+
+
+def test_train_flops_per_token_by_hand():
+    c = _config("smollm2-360m")
+    # forward: 2 FLOPs a weight a token; causal attention of one 2048-token
+    # sequence: QK^T and PV, 2*2*S*S*heads*D, halved, over 32 layers
+    attn = 32 * (2 * 2 * 2048 * 2048 * 15 * 64) / 2 / 2048
+    assert flops.train_flops_per_token(c, 2048) == pytest.approx(
+        3 * (2 * 361_758_720 + attn))
+    assert flops.train_flops_per_token(c, 2048) == pytest.approx(
+        2.548e9, rel=1e-3)
+    c = _config("internlm2-1.8b")
+    assert flops.train_flops_per_token(c, 4096) == pytest.approx(
+        11.405e9, rel=1e-3)
+
+
+def test_decode_bytes_by_hand():
+    c = _config("internlm2-1.8b")
+    # K and V, 24 layers x 8 heads x 128 x 2 bytes = 98,304 bytes a position
+    assert flops.kv_bytes_per_token(c) == 98_304
+    assert flops.weight_bytes(c) == 2 * 1_699_479_552
+    assert flops.decode_step_bytes(c, 10_000) == \
+        2 * 1_699_479_552 + 10_000 * 98_304
+    # flash: forward 1 + backward 2.5 of the causal forward's FLOPs
+    assert flops.flash_train_flops(c, 4, 4096) == pytest.approx(
+        3.5 * 4 * flops.attention_flops_fwd(c, 4096))
+
+
+# ---------------------------------------------------------------- loadgen
+TRAFFIC = {
+    "generator": "requests",
+    "arrivals": {"process": "poisson", "rate_per_s": 200.0,
+                 "lead_in_s": 0.2, "drain_s": 5.0},
+    "prompt_tokens": {"dist": "lognormal", "median": 20, "sigma": 0.8,
+                      "min": 4, "max": 64, "stratified": 8},
+    "output_tokens": {"dist": "lognormal", "median": 8, "sigma": 0.7,
+                      "min": 2, "max": 32, "stratified": 8},
+}
+
+
+def _stream(seed, n=50):
+    source = loadgen.RequestSource(TRAFFIC, seed, 0, 1000)
+    return [source.next() for _ in range(n)]
+
+
+def test_same_seed_same_schedule_and_requests():
+    assert _stream(7) == _stream(7)
+    assert _stream(7) != _stream(8)
+    a = loadgen.arrival_offsets(TRAFFIC["arrivals"], 7, 2.0)
+    assert np.array_equal(a, loadgen.arrival_offsets(
+        TRAFFIC["arrivals"], 7, 2.0))
+    assert a[0] >= -0.2 and a[-1] < 2.0 and np.all(np.diff(a) > 0)
+    assert len(a) == pytest.approx(2.2 * 200, rel=0.2)
+    for r in _stream(7):
+        assert 4 <= len(r["prompt"]) <= 64
+        assert 2 <= r["max_new_tokens"] <= 32
+    rows = loadgen.token_batches(
+        {"batch": 2, "seq_len": 16, "distinct_batches": 3}, 5, 100)
+    assert rows.shape == (6, 16) and rows.max() < 100
+    assert np.array_equal(rows, loadgen.token_batches(
+        {"batch": 2, "seq_len": 16, "distinct_batches": 3}, 5, 100))
+
+
+class _FakeResponse:
+    def __init__(self, request, delay):
+        self.request, self.delay = request, delay
+        self.t0 = time.perf_counter()
+
+    def result(self, timeout=None):
+        time.sleep(max(0.0, self.t0 + self.delay - time.perf_counter()))
+        return {"tokens": [1] * self.request["max_new_tokens"],
+                "ttft_ms": 1.0}
+
+
+def test_open_loop_times_from_due_and_reports_its_own_lateness():
+    """A sender that stalls 30 ms inside ``send`` falls behind a 200/s
+    schedule; the log shows it as ``sent - due``, and every request due
+    in the window is awaited."""
+    def slow_send(request):
+        time.sleep(0.03)
+        return _FakeResponse(request, 0.01)
+
+    gen = loadgen.LoadGenerator(TRAFFIC, 3, 1000, slow_send)
+    log = gen.run(0.5)
+    measured = log.measured()
+    assert measured and all(r.ok and r.done >= r.sent for r in measured)
+    assert all(log.t_open <= r.due < log.t_close for r in measured)
+    lag = max(r.sent - r.due for r in measured)
+    assert lag > 0.1, lag      # the generator was late, and says so
+
+
+def test_closed_loop_keeps_callers_busy_and_counts_completions():
+    traffic = dict(TRAFFIC, arrivals={"process": "closed", "callers": 4,
+                                      "lead_in_s": 0.1})
+    in_flight, peak = [0], [0]
+    lock = threading.Lock()
+
+    def send(request):
+        with lock:
+            in_flight[0] += 1
+            peak[0] = max(peak[0], in_flight[0])
+
+        class R(_FakeResponse):
+            def result(self, timeout=None):
+                out = super().result(timeout)
+                with lock:
+                    in_flight[0] -= 1
+                return out
+        return R(request, 0.02)
+
+    gen = loadgen.LoadGenerator(traffic, 3, 1000, send)
+    log = gen.run(0.4)
+    assert gen.join(5.0) == 0
+    assert peak[0] == 4
+    # every request in the system during the window, each one finished
+    measured = log.measured()
+    assert all(r.ok and r.sent < log.t_close and r.done >= log.t_open
+               for r in measured)
+    assert 40 <= len(measured) <= 100      # 4 callers x 0.4 s / 20 ms
+    # tokens are counted where they were produced: 4 callers, a token
+    # every 20 ms / request length, so the window's count is the rate
+    # times its length whatever straddles its edges
+    per_s = sum(r.got_tokens for r in measured) / sum(
+        r.done - r.sent for r in measured) * 4
+    assert log.tokens_in_window() == pytest.approx(0.4 * per_s, rel=0.1)
+
+
+def test_stratified_lengths_and_fixed_counts_are_the_same_work():
+    spec_ = {"dist": "lognormal", "median": 100, "sigma": 0.7, "min": 8,
+             "max": 400, "stratified": 16}
+    sums = set()
+    for seed in range(5):
+        lengths = loadgen.Lengths(spec_, np.random.default_rng(seed))
+        draws = [lengths.draw() for _ in range(64)]
+        sums.add(sum(draws))
+        assert min(draws) >= 8 and max(draws) <= 400
+    assert len(sums) == 1          # every seed: the same lengths
+    assert loadgen.quantile(spec_, 0.5) == 100
+    counts = {len(loadgen.arrival_offsets(TRAFFIC["arrivals"], seed, 2.0))
+              for seed in range(5)}
+    assert counts == {int(round(2.2 * 200))}
+
+
+# ----------------------------------------------------------- trace_reduce
+def test_interval_algebra():
+    assert trace_reduce.union([(0, 1), (0.5, 2), (3, 4)]) == \
+        [(0, 2), (3, 4)]
+    assert trace_reduce.subtract([(0, 10)], [(1, 2), (3, 4)]) == \
+        [(0, 1), (2, 3), (4, 10)]
+    # a while spans its body: its own time is what the body leaves
+    assert trace_reduce.self_times(
+        [(0, 10, "while"), (1, 3, "a"), (3, 6, "b"), (11, 12, "a")]) == \
+        {"a": 3.0, "b": 3.0, "while": 5.0}
+
+
+def test_collectives_exposed_is_what_no_other_op_covers():
+    ag = "%all-gather.1 = bf16[8] all-gather(bf16[2] %p), dimensions={0}"
+    mm = "%fusion.1 = bf16[8] fusion(bf16[8] %x), kind=kOutput"
+    wh = "%while.1 = (s32[]) while((s32[]) %t), body=%b"
+    dev = trace_reduce.DeviceTrace(
+        0, ops=[(0.0, 10.0, wh), (1.0, 3.0, mm), (6.0, 8.0, mm)],
+        modules=[], async_ops=[(2.0, 7.0, ag)])
+    trace = trace_reduce.Trace([dev], [], 0.0, 10.0)
+    total, exposed = trace.collective_seconds()
+    assert total == pytest.approx(5.0)
+    assert exposed == pytest.approx(3.0)     # 3..6; the while is no cover
+    assert trace_reduce.short_name(ag) == "%all-gather.1 all-gather bf16[8]"
+
+
+def test_reduction_of_a_recorded_tpu_trace():
+    """Recorded on a v5e (PR 22): three runs of one jitted program — a
+    4-step scan of matmuls, then a Mosaic flash forward and backward —
+    each launched under a ``train.step`` annotation, with a 2 ms sleep
+    under ``serve.harvest_chunk`` between them."""
+    trace = trace_reduce.read(FIXTURE)
+    assert [d.index for d in trace.devices] == [0]
+    runs = trace.module_runs(r"^jit_step\b")
+    assert len(runs) == 3
+    module_s = sum(e - s for s, e, _ in runs)
+    # busy is the union of op intervals: inside the modules' time, and the
+    # while is not counted on top of its body
+    assert 0.5 * module_s < trace.busy_s <= module_s * 1.001
+    assert trace.window_s > 0.004          # two sleeps of 2 ms
+    assert 0.9 < trace.idle_share() < 1.0
+    ops = trace.op_seconds()
+    assert sum(ops.values()) == pytest.approx(trace.busy_s, rel=0.02)
+    mosaic = trace.seconds_matching(
+        'custom_call_target="tpu_custom_call"')
+    assert 0 < mosaic < trace.busy_s
+    assert trace.collective_seconds() == (0, 0)
+    # the idle time lies under the annotation the host had open
+    gaps = dict(trace.idle_gaps(names=("train.step",
+                                       "serve.harvest_chunk")))
+    assert gaps["serve.harvest_chunk"] > 0.9 * sum(gaps.values())
+    names = [n for n, _ in trace.top_ops(top=5)]
+    assert any("custom-call[mosaic]" in n for n in names), names
+    assert all(len(n) <= 120 for n in names)
+
+
+# ------------------------------------------------------------------- spec
+def test_every_name_in_benchmark_json_leads_to_its_file():
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    end_to_end = {m["name"] for m in bench["end_to_end"]}
+    for w in bench["workloads"]:
+        cell = spec.Cell(w["name"])
+        assert cell.chips == w["chips"] and len(w["why"]) <= 200
+        assert callable(cell.kind.run)
+        assert callable(cell.reference.logits)
+        for group in ("end_to_end", "per_layer"):
+            readers = cell.readers(group)
+            assert readers, (w["name"], group)
+            for entry, read in readers:
+                assert callable(read)
+                if group == "per_layer":
+                    # reported only where the metric it moves is
+                    moved = [m for m in cell.metric_entries("end_to_end")
+                             if m["name"] == entry["moves"]]
+                    assert moved, (w["name"], entry["name"])
+        assert "setup_s" in {e["name"] for e, _ in
+                             cell.readers("end_to_end")}
+    for m in bench["per_layer"]:
+        assert m["moves"] in end_to_end
+    for c in bench["configs"]:
+        with open(os.path.join(spec.ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["source"] == c["source"] and cfg["reduced"] == []
+        assert cfg["assumed"]
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+
+
+def test_unknown_chip_is_an_error_not_a_default():
+    from benchmarks.lib import runtime
+
+    assert runtime.load_peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(runtime.BenchmarkRefused):
+        runtime.load_peaks("TPU v9 imaginary")
+    with pytest.raises(runtime.BenchmarkRefused):
+        runtime.load_peaks("_source")
