@@ -1,0 +1,6 @@
+"""Device time of the dq flash kernel (by its name) / device time of the steps.
+"""
+
+from benchmarks.lib import flash_names
+
+read = flash_names.time_share("dq")

@@ -1,0 +1,7 @@
+"""Gap between the bursts in which a request's tokens reach the host (the
+harvest stamps of serve.request), median over all gaps of the window.
+"""
+
+from benchmarks.lib import program_spans
+
+read = program_spans.token_burst_gap_percentile(50)

@@ -480,7 +480,8 @@ def decoder_layer(x: jax.Array, layer: Dict[str, jax.Array],
                   config: LlamaConfig,
                   attention_fn: Callable) -> jax.Array:
     q, k, v = _qkv_rope(x, layer, sin, cos, config)
-    attn = attention_fn(q, k, v, positions)
+    with jax.named_scope("attention"):
+        attn = attention_fn(q, k, v, positions)
     return _attn_out_mlp(x, attn, layer, config)
 
 
@@ -490,7 +491,8 @@ def decoder_layer_moe(x: jax.Array, layer: Dict[str, jax.Array],
                       attention_fn: Callable
                       ) -> Tuple[jax.Array, jax.Array]:
     q, k, v = _qkv_rope(x, layer, sin, cos, config)
-    attn = attention_fn(q, k, v, positions)
+    with jax.named_scope("attention"):
+        attn = attention_fn(q, k, v, positions)
     return _attn_out_moe(x, attn, layer, config)
 
 
@@ -591,13 +593,15 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             x, _ = jax.lax.scan(scan_body, x, params["layers"],
                                 unroll=c.scan_unroll)
 
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    if c.tie_embeddings:
-        head = params["embed_tokens"].astype(c.dtype).T
-    else:
-        head = params["lm_head"].astype(c.dtype)
-    logits = matmul(x, head)
-    logits = with_logical_constraint(logits, "batch", "seq", "vocab")
+    with jax.named_scope("head_loss"):
+        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        if c.tie_embeddings:
+            head = params["embed_tokens"].astype(c.dtype).T
+        else:
+            head = params["lm_head"].astype(c.dtype)
+        logits = matmul(x, head)
+        logits = with_logical_constraint(logits, "batch", "seq",
+                                         "vocab")
     if return_aux:
         return logits, aux_total
     return logits
@@ -626,11 +630,12 @@ def loss_fn(params: PyTree, batch: Dict[str, jax.Array],
                               positions=positions[:, :-1],
                               return_aux=True)
     targets = tokens[:, 1:]
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None],
-                               axis=-1).squeeze(-1)
-    nll = logz - gold
+    with jax.named_scope("head_loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None],
+                                   axis=-1).squeeze(-1)
+        nll = logz - gold
     mask = batch.get("loss_mask")
     if mask is None:
         ce = jnp.mean(nll)
@@ -744,8 +749,9 @@ def make_train_step(config: LlamaConfig, optimizer=None,
         def step(state, batch):
             loss, grads = jax.value_and_grad(loss_fn)(
                 state["params"], batch, config)
-            params, opt_state, gnorm = fused_adamw_update(
-                grads, state["opt_state"], state["params"], **hp)
+            with jax.named_scope("optimizer"):
+                params, opt_state, gnorm = fused_adamw_update(
+                    grads, state["opt_state"], state["params"], **hp)
             new_state = {"params": params, "opt_state": opt_state,
                          "step": state["step"] + 1}
             return new_state, {"loss": loss, "grad_norm": gnorm,
@@ -760,9 +766,10 @@ def make_train_step(config: LlamaConfig, optimizer=None,
     def step(state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch,
                                                   config)
-        updates, opt_state = optimizer.update(grads, state["opt_state"],
-                                              state["params"])
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state["opt_state"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
         new_state = {"params": params, "opt_state": opt_state,
                      "step": state["step"] + 1}
         gnorm = optax.global_norm(grads)

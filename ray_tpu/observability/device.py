@@ -455,22 +455,28 @@ _NULL_CTX = contextlib.nullcontext()
 
 
 def annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` carrying the ambient trace
-    id (``name#trace=<id>``) so device-trace slices correlate with the
-    cluster timeline — or a shared no-op context when the plane is
-    disabled or jax is not loaded.  Cheap enough for per-chunk hot
-    loops: one dict probe + one string concat when active."""
+    """A ``jax.profiler.TraceAnnotation`` named ``name#trace=<id>,t=<s>``
+    (``name#t=<s>`` outside a trace): the ambient trace id, so
+    device-trace slices correlate with the cluster timeline, and the
+    host clock reading of its opening (``timeline.now()``, wall-clock
+    seconds) — the profiler's clock is session-relative, so the
+    difference between an annotation event's start and its ``t`` places
+    every timeline span against the device's module events of the same
+    trace.  A shared no-op context when the plane is disabled or jax is
+    not loaded; the plain name when tracing is off.  Cheap enough for
+    per-chunk hot loops: one clock read + one string format."""
     if not _enabled:
         return _NULL_CTX
     jax = sys.modules.get("jax")
     if jax is None:
         return _NULL_CTX
     try:
-        from . import tracing
+        from . import timeline, tracing
 
-        ctx = tracing.current()
-        if ctx is not None:
-            name = f"{name}#trace={ctx[0]}"
+        if tracing.enabled():
+            ctx = tracing.current()
+            head = f"trace={ctx[0]}," if ctx is not None else ""
+            name = f"{name}#{head}t={timeline.now():.6f}"
         return jax.profiler.TraceAnnotation(name)
     except Exception:
         return _NULL_CTX

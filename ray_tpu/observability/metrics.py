@@ -401,6 +401,41 @@ def kv_cache_counters():
     })
 
 
+def serve_engine_counters():
+    """What the serve engine (serve/llm.py) computes against what it
+    keeps, for the operator who has no timeline: decode chunks run
+    ``decode_chunk x max_slots`` token-steps whatever is occupied, and
+    prefill groups are padded to a row count and a length bucket.  The
+    same numbers ride the ``serve.chunk`` / ``serve.prefill_group``
+    timeline spans; all of it is off when tracing is."""
+    return metric_group("serve_engine", lambda: {
+        "decode_tokens_kept": Counter(
+            "ray_tpu_serve_decode_tokens_kept_total",
+            "decode-chunk tokens appended to a live request",
+            tag_keys=("deployment",)),
+        "decode_slot_steps": Counter(
+            "ray_tpu_serve_decode_slot_steps_total",
+            "decode-chunk token-steps computed (chunk length x "
+            "max_slots per chunk); kept / computed = slot utilization",
+            tag_keys=("deployment",)),
+        "prefill_prompt_tokens": Counter(
+            "ray_tpu_serve_prefill_prompt_tokens_total",
+            "prompt tokens the prefill programs were asked to compute",
+            tag_keys=("deployment",)),
+        "prefill_padded_tokens": Counter(
+            "ray_tpu_serve_prefill_padded_tokens_total",
+            "token positions the prefill programs computed (group rows "
+            "x length bucket, padding included); 1 - prompt / padded = "
+            "padding share", tag_keys=("deployment",)),
+        "queue_wait": Histogram(
+            "ray_tpu_serve_queue_wait_seconds",
+            "engine submit -> bound to a slot, per request that got one",
+            boundaries=[0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
+                        5.0, 10.0, 30.0],
+            tag_keys=("deployment",)),
+    })
+
+
 def shuffle_counters():
     """The push-exchange data plane's series (data/exchange.py): bytes
     moved per transport, reduce-partition completions, spill volume,

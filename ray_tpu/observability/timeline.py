@@ -32,6 +32,31 @@ _MAX_EVENTS = int(os.environ.get("RAY_TPU_TIMELINE_MAX_EVENTS",
 _dropped = 0     # events evicted (drop-oldest) since last clear()
 _total = 0       # events ever recorded since last clear() (drain cursor base)
 
+# ONE CLOCK.  Events carry wall-clock seconds (the merged cluster
+# timeline lines processes up by them), but hot loops stamp
+# ``time.perf_counter()`` (monotonic, what the serve engine's
+# ``ttft_ms`` and a load generator use).  The offset between the two is
+# sampled once per process, so a span built from perf_counter stamps
+# keeps their exact differences and a reader can window events by its
+# own perf_counter readings.
+_WALL_MINUS_PERF = time.time() - time.perf_counter()
+
+
+def wall_from_perf(t: float) -> float:
+    """Wall-clock seconds of a ``time.perf_counter()`` reading."""
+    return t + _WALL_MINUS_PERF
+
+
+def perf_from_wall(t: float) -> float:
+    """The ``time.perf_counter()`` reading at wall-clock second ``t``
+    (as stamped through :func:`wall_from_perf` / :func:`now`)."""
+    return t - _WALL_MINUS_PERF
+
+
+def now() -> float:
+    """Wall-clock seconds on the process's one clock."""
+    return time.perf_counter() + _WALL_MINUS_PERF
+
 
 def set_capacity(n: int) -> None:
     """Resize the ring buffer (tests); evicts oldest as needed."""

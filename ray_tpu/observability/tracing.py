@@ -33,8 +33,9 @@ import os
 import random
 import contextvars
 import threading
-import time
 from typing import Any, Dict, Optional, Tuple
+
+from . import timeline as _timeline
 
 # A ContextVar, NOT threading.local: async actors interleave many
 # requests on one event-loop thread, and each request runs as its
@@ -147,7 +148,7 @@ class span:
             self.trace_id, self.parent_span_id = new_trace_id(), None
         self.span_id = new_span_id()
         self._prev = set_current((self.trace_id, self.span_id))
-        self._t0 = time.time()
+        self._t0 = _timeline.now()
         return self
 
     def __exit__(self, *exc) -> None:
@@ -159,16 +160,15 @@ class span:
         set_current(self._prev)
         if not _enabled:
             return
-        from .timeline import process_pid, record_span
-
         args = {"trace_id": self.trace_id, "span_id": self.span_id}
         if self.parent_span_id:
             args["parent_span_id"] = self.parent_span_id
         if self.args:
             args.update(self.args)
-        record_span(self.name, self._t0, time.time(),
-                    pid=process_pid(),
-                    tid=threading.current_thread().name, args=args)
+        _timeline.record_span(
+            self.name, self._t0, _timeline.now(),
+            pid=_timeline.process_pid(),
+            tid=threading.current_thread().name, args=args)
 
 
 class scope_from:

@@ -189,8 +189,9 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret):
 
     kernel = functools.partial(_fwd_kernel, block_q=bq, block_k=bk,
                                nk=nk, causal=causal)
-    o, lse = pl.pallas_call(
+    fwd = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), q_map),
@@ -214,7 +215,13 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )
+    # The kernel's ``name=`` is what a device trace shows (XLA names the
+    # custom call after the innermost scope of its name stack, and the
+    # name is pushed as that); this scope lands in the HLO's ``op_name``
+    # metadata only (PERF.md section 3).
+    with jax.named_scope("flash_attention.fwd"):
+        o, lse = fwd(q, k, v)
     return o, lse
 
 
@@ -343,9 +350,10 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             ki = jax.lax.select(bk * ki <= bq * qi + bq - 1, ki, 0)
         return (b, h, ki, 0)
 
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=bq, block_k=bk, nk=nk,
                           causal=causal),
+        name="flash_attention_dq",
         grid=(B, Hq, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), q_map),
@@ -362,7 +370,9 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )
+    with jax.named_scope("flash_attention.dq"):
+        dq = dq_call(q, k, v, do, lse, delta)
 
     def kv_map(b, h, ki, qi):
         return (b, h, ki, 0)
@@ -374,9 +384,10 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             qi = jax.lax.select(bq * qi + bq - 1 >= bk * ki, qi, nq - 1)
         return (b, h, qi, 0)
 
-    dk, dv = pl.pallas_call(
+    dkdv_call = pl.pallas_call(
         functools.partial(_dkdv_kernel, block_q=bq, block_k=bk, nq=nq,
                           causal=causal),
+        name="flash_attention_dkdv",
         grid=(B, Hq, nk, nq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), q_map_kv),
@@ -402,7 +413,9 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )
+    with jax.named_scope("flash_attention.dkdv"):
+        dk, dv = dkdv_call(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 

@@ -104,6 +104,9 @@ class DeploymentResponse:
         self._done = False
         self._retry = retry
         self._deadline = deadline
+        # (trace id, handle span id, submission time) when traced:
+        # _settle writes the return leg, ``serve.response``, under it.
+        self._trace = None
 
     def _budget(self, timeout: Optional[float]) -> Optional[float]:
         left = _deadlines.remaining(self._deadline)
@@ -161,6 +164,17 @@ class DeploymentResponse:
         # result() must NOT settle (a timed-out result() would release
         # the routing slot while the request still runs).
         self._done = True
+        if self._trace is not None:
+            from ..observability import timeline, tracing
+
+            trace_id, parent, t_submitted = self._trace
+            if tracing.enabled():
+                timeline.record_span(
+                    "serve.response", t_submitted, timeline.now(),
+                    pid=timeline.process_pid(), tid="serve.response",
+                    args={"trace_id": trace_id,
+                          "span_id": tracing.new_span_id(),
+                          "parent_span_id": parent})
         self._on_done()
 
     @property
@@ -632,7 +646,7 @@ class DeploymentHandle:
 
     # -- calls -------------------------------------------------------------
     def remote(self, *args, **kwargs):
-        from ..observability import tracing
+        from ..observability import timeline, tracing
 
         # Mint the request's absolute deadline: an explicit
         # options(deadline_s=...) wins, else inherit the ambient scope
@@ -659,9 +673,10 @@ class DeploymentHandle:
         # attaches to the same trace (the deadline scope makes the
         # replica-bound task spec inherit the request budget).
         with tracing.span(f"serve:{self.deployment_name}."
-                          f"{self._method or 'call'}"), \
+                          f"{self._method or 'call'}") as handle_span, \
                 _deadlines.scope(deadline):
             ref, release, key = self._issue(args, kwargs)
+        t_submitted = timeline.now()
         last_key = [key]
 
         def retry(dead: bool = True):
@@ -686,6 +701,12 @@ class DeploymentHandle:
 
         resp = DeploymentResponse(ref, on_done=release, retry=retry,
                                   deadline=deadline)
+        if handle_span.trace_id is not None and tracing.enabled():
+            # The handle's span ends at submission; the return leg
+            # (submission -> the ref's completion callback) is the
+            # other half of the request path's own cost.
+            resp._trace = (handle_span.trace_id, handle_span.span_id,
+                           t_submitted)
         # Release the slot when the result lands even if .result() is
         # never called, and feed the router's breaker + depth state
         # from the sealed response (completion callback keeps counts

@@ -90,6 +90,8 @@ import numpy as np
 from ..core import deadlines as _deadlines
 from ..exceptions import BackPressureError, DeadlineExceededError
 from ..observability import device as _device
+from ..observability import timeline as _timeline
+from ..observability import tracing as _tracing
 
 # Prefill group sizes (prompts per call, padded with slot=-1).  Each
 # call costs a device round trip serialized against decode chunks, so
@@ -127,7 +129,9 @@ class _Request:
     __slots__ = ("prompt", "max_new_tokens", "event", "tokens",
                  "t_submit", "t_first_token", "error", "done",
                  "on_done", "deadline", "arrival", "want_kv", "kv",
-                 "preseed", "rid")
+                 "preseed", "rid", "trace", "t_seen", "t_admitted",
+                 "t_prefill_launched", "prefill_shape", "harvests",
+                 "t_done", "outcome", "preemptions", "slot")
 
     _arrival_counter = 0
     _arrival_lock = threading.Lock()
@@ -138,8 +142,28 @@ class _Request:
         self.max_new_tokens = max_new_tokens
         self.event = threading.Event()
         self.tokens: List[int] = []
+        # The request's life, stamped where the work happens (one host
+        # clock, time.perf_counter) and written to the timeline when it
+        # ends (LLMServer._record_request): submit -> seen by the
+        # scheduler thread -> bound to a slot -> prefill launched ->
+        # first token -> done.  ``trace`` is (trace id, parent span id)
+        # of the span current in generate() — (None, None) with tracing
+        # off, and then nothing below is ever written anywhere.
+        self.trace = _tracing.for_submission()
         self.t_submit = time.perf_counter()
+        self.t_seen: Optional[float] = None
+        self.t_admitted: Optional[float] = None
+        self.t_prefill_launched: Optional[float] = None
+        self.prefill_shape: Optional[Tuple[int, int]] = None
         self.t_first_token: Optional[float] = None
+        # (clock, tokens so far) once per harvest that delivered tokens:
+        # tokens reach the host a burst at a time, not one by one.
+        self.harvests: Optional[List[Tuple[float, int]]] = (
+            [] if self.trace[0] is not None else None)
+        self.t_done: Optional[float] = None
+        self.outcome: Optional[str] = None   # ok | shed | error
+        self.preemptions = 0
+        self.slot: Optional[int] = None
         self.error: Optional[BaseException] = None
         self.done = False
         # Completion callback (asyncio wakeup) fired after event.set —
@@ -235,6 +259,15 @@ class LLMServer:
         self.paged = bool(paged)
         self.role = role
         self._deployment = serve_deployment
+        # Metric groups and tags, resolved once: the launch and harvest
+        # paths hold no import and build no dict.
+        from ..observability import metrics as _metrics
+
+        self._kv_metrics = _metrics.kv_cache_counters()
+        self._engine_metrics = _metrics.serve_engine_counters()
+        self._tags = {"deployment": serve_deployment or "llm"}
+        self._lane = f"llm:{serve_deployment or 'llm'}"
+        self._timeline_pid: Optional[str] = None
         # Prefill group ladder (compile-matrix knob: each size × bucket
         # × {cold, warm} is one warmed compile).
         self.prefill_groups = tuple(sorted(
@@ -944,10 +977,16 @@ class LLMServer:
         return self._nb_buckets[-1]
 
     # ----------------------------------------------- admission (EDF plane)
+    def _see(self, req: _Request):
+        """The scheduler thread takes ``req`` off the queue: its wait
+        for the chunk boundary ends here."""
+        req.t_seen = time.perf_counter()
+        self._backlog.append(req)
+
     def _drain_queue(self):
         while True:
             try:
-                self._backlog.append(self._queue.get_nowait())
+                self._see(self._queue.get_nowait())
             except queue.Empty:
                 return
 
@@ -955,6 +994,19 @@ class LLMServer:
         req.error = err
         if isinstance(err, DeadlineExceededError):
             _shed_counter(where)
+        self._conclude(req)
+
+    def _conclude(self, req: _Request):
+        """The end of a request's life, however it ends: stamp it,
+        write its spans (off the launch path), wake its waiter.  A
+        typed overload error is a shed, any other an error."""
+        req.t_done = time.perf_counter()
+        req.outcome = (
+            "ok" if req.error is None else
+            "shed" if isinstance(req.error, (BackPressureError,
+                                             DeadlineExceededError))
+            else "error")
+        self._record_request(req)
         req.finish_notify()
 
     def _estimate_need_s(self, req: _Request) -> Optional[float]:
@@ -1078,7 +1130,7 @@ class LLMServer:
         disaggregated ingest)."""
         P = len(req.prompt)
         if not self.paged:
-            self.slot_req[slot] = req
+            self._bind(slot, req)
             self.slot_len[slot] = P
             self.slot_waiting[slot] = True
             return (slot, req, self._bucket(P), 0)
@@ -1091,7 +1143,7 @@ class LLMServer:
             except BaseException:
                 table.release()
                 raise
-            self.slot_req[slot] = req
+            self._bind(slot, req)
             self.slot_table[slot] = table
             try:
                 self._apply_preseed(slot, req, table)
@@ -1101,7 +1153,7 @@ class LLMServer:
                 # window) fails THIS ingest typed; it must not
                 # _fatal the whole decode engine.
                 req.error = e
-                req.finish_notify()
+                self._conclude(req)
             return None
         shared = self.prefix_cache.lookup(req.prompt)
         table = BlockTable(self.allocator, shared=shared)
@@ -1111,7 +1163,7 @@ class LLMServer:
             table.release()  # give the forked prefix refs back
             raise
         pos0 = table.num_shared * self.block_size
-        self.slot_req[slot] = req
+        self._bind(slot, req)
         self.slot_table[slot] = table
         self.slot_len[slot] = P
         self.slot_waiting[slot] = True
@@ -1120,6 +1172,12 @@ class LLMServer:
         # now could gather blocks whose prefill hasn't executed yet
         # (grouped prefills launch in arbitrary order within a wave).
         return (slot, req, self._bucket(P - pos0), pos0)
+
+    def _bind(self, slot: int, req: _Request) -> None:
+        """``req`` takes ``slot``: its wait for a slot ends here."""
+        self.slot_req[slot] = req
+        req.slot = slot
+        req.t_admitted = time.perf_counter()
 
     def _apply_preseed(self, slot: int, req: _Request, table) -> None:
         """Disaggregated ingest: scatter the handed-off KV blocks into
@@ -1187,7 +1245,8 @@ class LLMServer:
                 self.cache, first = self._prefill(
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(lens), jnp.asarray(slots))
-            self._pending_prefills.append((first, members, t0))
+            self._prefill_launched(first, members, t0, bucket, g,
+                                   int(lens[:len(group)].sum()))
             return
         bs = self.block_size
         nw = -(-bucket // bs)
@@ -1240,12 +1299,24 @@ class LLMServer:
                 self.draft_params, self.draft_cache,
                 jnp.asarray(dtoks), jnp.asarray(dlens),
                 jnp.asarray(dslots))
-        self._pending_prefills.append((first, members, t0))
+        self._prefill_launched(first, members, t0, bucket, g,
+                               int(lens[:len(group)].sum()))
+
+    def _prefill_launched(self, first, members, t0, bucket, g, n_tok):
+        """After the (async) launch: stamp the group's requests and
+        queue it for _harvest_prefills.  ``n_tok``: prompt positions the
+        group was asked to compute (suffixes only, on a warm group)."""
+        for _j, _slot, req in members:
+            req.t_prefill_launched = t0
+            req.prefill_shape = (bucket, g)
+        self._pending_prefills.append(
+            (first, members, t0, bucket, g, n_tok))
 
     def _harvest_prefills(self):
         """Materialize queued prefill first-tokens into request streams
         and decode overrides."""
-        for first, members, t0 in self._pending_prefills:
+        for first, members, t0, bucket, g, n_tok in \
+                self._pending_prefills:
             first = np.asarray(first)
             now = time.perf_counter()
             dt = now - t0
@@ -1268,12 +1339,16 @@ class LLMServer:
                 tok = int(first[j])
                 req.t_first_token = now
                 req.tokens.append(tok)
+                if req.harvests is not None:
+                    req.harvests.append((now, 1))
                 self._ov_tok[slot] = tok
                 self._ov_len[slot] = self.slot_len[slot]
                 self._ov_mask[slot] = True
                 self.slot_waiting[slot] = False
                 if len(req.tokens) >= req.max_new_tokens:
                     self._finish(slot)
+            self._record_prefill_group(t0, now, bucket, g,
+                                       len(members), n_tok)
         self._pending_prefills.clear()
 
     def _extract_kv(self, req: _Request, table) -> None:
@@ -1317,7 +1392,7 @@ class LLMServer:
                 table.release()
         if req is not None:
             req.done = True
-            req.finish_notify()
+            self._conclude(req)
 
     def _preempt(self, slot: int):
         """Pool pressure: evict the running request in ``slot`` back to
@@ -1334,6 +1409,12 @@ class LLMServer:
         if req is not None and not req.done:
             req.tokens = []
             req.t_first_token = None
+            # Back to waiting for a slot: the next _bind stamps again.
+            req.t_admitted = req.t_prefill_launched = None
+            req.slot = None
+            req.preemptions += 1
+            if req.harvests is not None:
+                req.harvests = []
             # A pre-seeded (disaggregated) request KEEPS its preseed:
             # the handed-off K/V are host copies on the request, so
             # readmission re-injects them.  Re-prefilling instead
@@ -1353,7 +1434,7 @@ class LLMServer:
                 self._finish(slot)
         for req in self._backlog:
             req.error = e
-            req.finish_notify()
+            self._conclude(req)
         self._backlog = []
         while True:
             try:
@@ -1361,7 +1442,7 @@ class LLMServer:
             except queue.Empty:
                 break
             req.error = e
-            req.finish_notify()
+            self._conclude(req)
 
     def _loop(self):
         if self.spec_k:
@@ -1385,8 +1466,7 @@ class LLMServer:
                         and not self._backlog:
                     # Idle: block for work instead of spinning.
                     try:
-                        self._backlog.append(
-                            self._queue.get(timeout=0.05))
+                        self._see(self._queue.get(timeout=0.05))
                     except queue.Empty:
                         pass
         except BaseException as e:  # noqa: BLE001
@@ -1408,8 +1488,7 @@ class LLMServer:
                         r is not None for r in self.slot_req) \
                         and not self._backlog:
                     try:
-                        self._backlog.append(
-                            self._queue.get(timeout=0.05))
+                        self._see(self._queue.get(timeout=0.05))
                     except queue.Empty:
                         pass
         except BaseException as e:  # noqa: BLE001
@@ -1438,14 +1517,8 @@ class LLMServer:
             snapshot, active = self._active_snapshot()
         if not snapshot:
             return False
-        try:
-            from ..observability.metrics import kv_cache_counters
-
-            kv_cache_counters()["batch_occupancy"].set(
-                len(snapshot),
-                tags={"deployment": self._deployment or "llm"})
-        except Exception:
-            pass
+        self._kv_metrics["batch_occupancy"].set(len(snapshot),
+                                                tags=self._tags)
         B = self.max_slots
         tok = np.zeros(B, np.int32)
         pos = np.zeros(B, np.int32)
@@ -1457,6 +1530,8 @@ class LLMServer:
         t0 = time.perf_counter()
         sa = next((b for b in self.decode_buckets if high <= b),
                   self.decode_buckets[-1])
+        info = (len(snapshot), int(self.slot_waiting.sum()),
+                len(self._backlog), int(sa))
         with _device.annotation("serve.spec_draft"):
             self.draft_cache, dts = self._draft_propose(
                 self.draft_params, self.draft_cache, jnp.asarray(tok),
@@ -1515,12 +1590,15 @@ class LLMServer:
                         or self._slot_ctx(req) >= self.max_len - 1):
                     finished = True
                     break
+            if emit and req.harvests is not None:
+                req.harvests.append((now, len(req.tokens)))
             if finished:
                 self._finish(s)
             else:
                 ctx = self._slot_ctx(req)
                 self.slot_table[s].trim(ctx)
                 self.slot_len[s] = ctx
+        self._record_chunk(t0, now, k, info, emitted_total)
         per_slot = emitted_total / max(1, len(snapshot))
         self._spec_tok_ema = (per_slot if self._spec_tok_ema is None
                               else 0.8 * self._spec_tok_ema
@@ -1529,11 +1607,11 @@ class LLMServer:
         return True
 
     def _emit_ema(self, program: str, seconds) -> None:
-        """Model-plane gauge: the engine's per-program execution-time
-        EMA (the same numbers the feasibility shed steers by) as
-        ``ray_tpu_serve_program_seconds{deployment,program}`` — ships
-        to the head TSDB so `ray_tpu top` / metrics_query watch the
-        engine's device-time live (observability/device.py)."""
+        """Model-plane gauge: the EMAs the feasibility shed steers by,
+        as ``ray_tpu_serve_program_seconds{deployment,program}``.  Each
+        is the HOST's launch-to-harvest time of a program through the
+        one-deep pipeline (it includes the wait behind the call in
+        flight), not a device execution time."""
         _device.record_program_ema(self._deployment or "llm",
                                    program, seconds)
 
@@ -1542,15 +1620,8 @@ class LLMServer:
         self._spec_accepted += accepted
         if not proposed:
             return
-        try:
-            from ..observability.metrics import kv_cache_counters
-
-            m = kv_cache_counters()
-            tags = {"deployment": self._deployment or "llm"}
-            m["spec_proposed"].inc(proposed, tags=tags)
-            m["spec_accepted"].inc(accepted, tags=tags)
-        except Exception:
-            pass
+        self._kv_metrics["spec_proposed"].inc(proposed, tags=self._tags)
+        self._kv_metrics["spec_accepted"].inc(accepted, tags=self._tags)
 
     def _active_snapshot(self):
         snapshot = []  # (slot, req, len_at_launch)
@@ -1639,14 +1710,8 @@ class LLMServer:
                 snapshot, active = self._active_snapshot()
         if not snapshot:
             return None
-        try:
-            from ..observability.metrics import kv_cache_counters
-
-            kv_cache_counters()["batch_occupancy"].set(
-                len(snapshot),
-                tags={"deployment": self._deployment or "llm"})
-        except Exception:
-            pass
+        self._kv_metrics["batch_occupancy"].set(len(snapshot),
+                                                tags=self._tags)
         k = self.decode_chunk
         t0 = time.perf_counter()
         # .copy(): on the CPU backend jnp.asarray ALIASES numpy buffers,
@@ -1673,6 +1738,7 @@ class LLMServer:
                                        self._tok_dev, self._len_dev,
                                        *ov_args, jnp.asarray(bt),
                                        k=int(k))
+            sa = nb * self.block_size
         else:
             sa = self._decode_bucket()
             with _device.annotation("serve.decode_chunk"):
@@ -1684,13 +1750,16 @@ class LLMServer:
         self._ov_mask[:] = False
         for s, _req, _len0 in snapshot:
             self.slot_len[s] += k
-        return (toks, snapshot, k, t0)
+        # What the chunk was launched over (serve.chunk's args).
+        info = (len(snapshot), int(self.slot_waiting.sum()),
+                len(self._backlog), int(sa))
+        return (toks, snapshot, k, t0, info)
 
     def _process(self, pending):
         """Materialize a finished chunk's tokens (blocks until the
         device call completes — by then the NEXT chunk is already
         queued) and route them to their requests."""
-        toks_dev, snapshot, k, t0 = pending
+        toks_dev, snapshot, k, t0, info = pending
         # Declared sync boundary: this is THE pipeline's harvest
         # point — the next chunk is already dispatched, so blocking
         # here overlaps host routing with device compute.
@@ -1701,20 +1770,124 @@ class LLMServer:
         self._chunk_ema = (dt if self._chunk_ema is None
                            else 0.8 * self._chunk_ema + 0.2 * dt)
         self._emit_ema("decode_chunk", self._chunk_ema)
+        kept = 0
         for slot, req, len0 in snapshot:
             if req is None or req.done:
                 continue
             if self.slot_req[slot] is not req:
                 continue  # preempted after this chunk launched
+            had = len(req.tokens)
+            finished = False
             for step in range(k):
-                tok = int(toks[step, slot])
-                if req.t_first_token is None:
-                    req.t_first_token = now
-                req.tokens.append(tok)
+                req.tokens.append(int(toks[step, slot]))
                 if (len(req.tokens) >= req.max_new_tokens
                         or len0 + step + 1 >= self.max_len - 1):
-                    self._finish(slot)
+                    finished = True
                     break
+            if req.t_first_token is None:
+                req.t_first_token = now
+            # One stamp for the burst: the k tokens reach the host
+            # together, whenever the device made them.
+            kept += len(req.tokens) - had
+            if req.harvests is not None:
+                req.harvests.append((now, len(req.tokens)))
+            if finished:
+                self._finish(slot)
+        self._record_chunk(t0, now, k, info, kept)
+
+    # ------------------------------------ spans and counters (off-launch)
+    def _span(self, name: str, t0: float, t1: float,
+              args: Dict[str, Any], tid: Optional[str] = None) -> None:
+        """One span on the process's timeline, from two perf_counter
+        stamps (``timeline.wall_from_perf``: the ring holds wall-clock
+        time, on the one clock)."""
+        if self._timeline_pid is None:
+            self._timeline_pid = _timeline.process_pid()
+        _timeline.record_span(
+            name, _timeline.wall_from_perf(t0),
+            _timeline.wall_from_perf(t1), pid=self._timeline_pid,
+            tid=tid or self._lane, args=args)
+
+    def _record_request(self, req: _Request) -> None:
+        """``serve.request`` and its phases under the request's trace:
+        ``serve.wait_boundary`` (submit -> seen), ``serve.wait_slot``
+        (seen -> bound to a slot), ``serve.wait_prefill`` (bound ->
+        first token) — the three sum to ``ttft_ms`` — and
+        ``serve.decode`` (first token -> done).  A request that ends
+        early leaves the phase it ended in, cut at its end."""
+        trace_id, parent = req.trace
+        if trace_id is None or not _tracing.enabled():
+            return
+        t0, t_done = req.t_submit, req.t_done
+        if req.t_admitted is not None:
+            self._engine_metrics["queue_wait"].observe(
+                req.t_admitted - t0, tags=self._tags)
+        span_id = _tracing.new_span_id()
+        tid = (f"{self._lane}/queue" if req.slot is None
+               else f"{self._lane}/slot{req.slot}")
+        args = {"trace_id": trace_id, "span_id": span_id,
+                "rid": req.rid, "slot": req.slot,
+                "prompt_tokens": len(req.prompt),
+                "output_tokens": len(req.tokens),
+                "preemptions": req.preemptions,
+                "outcome": req.outcome,
+                # [ms after submit, tokens so far] per burst
+                "harvests": [[round((t - t0) * 1e3, 3), n]
+                             for t, n in req.harvests or ()]}
+        if parent:
+            args["parent_span_id"] = parent
+        self._span("serve.request", t0, t_done, args, tid)
+        stamps = (t0, req.t_seen, req.t_admitted, req.t_first_token,
+                  t_done)
+        for i, name in enumerate(("serve.wait_boundary",
+                                  "serve.wait_slot",
+                                  "serve.wait_prefill", "serve.decode")):
+            end = stamps[i + 1]
+            phase = {"trace_id": trace_id,
+                     "span_id": _tracing.new_span_id(),
+                     "parent_span_id": span_id, "rid": req.rid}
+            if i == 2 and req.t_prefill_launched is not None:
+                phase["launch_ms"] = round(
+                    (req.t_prefill_launched - stamps[i]) * 1e3, 3)
+                phase["bucket"], phase["rows"] = req.prefill_shape
+            self._span(name, stamps[i],
+                       t_done if end is None else end, phase, tid)
+            if end is None:
+                break
+
+    def _record_chunk(self, t0: float, t1: float, k: int, info: tuple,
+                      kept: int) -> None:
+        """``serve.chunk`` (launch -> harvest returned) and the decode
+        counters: token-steps computed (k x max_slots, whatever is
+        occupied) against tokens kept (appended to a live request)."""
+        if not _tracing.enabled():
+            return
+        computed = k * self.max_slots
+        m = self._engine_metrics
+        m["decode_tokens_kept"].inc(kept, tags=self._tags)
+        m["decode_slot_steps"].inc(computed, tags=self._tags)
+        active, waiting, backlog, s_active = info
+        self._span("serve.chunk", t0, t1, {
+            "k": k, "active": active, "waiting": waiting,
+            "backlog": backlog, "s_active": s_active,
+            "tokens_kept": kept, "token_steps": computed},
+            f"{self._lane}/chunks")
+
+    def _record_prefill_group(self, t0: float, t1: float, bucket: int,
+                              rows: int, real: int, tokens: int) -> None:
+        """``serve.prefill_group`` (launch -> harvest) and the prefill
+        counters: prompt tokens against the rows x bucket positions the
+        padded group computed."""
+        if not _tracing.enabled():
+            return
+        computed = rows * bucket
+        m = self._engine_metrics
+        m["prefill_prompt_tokens"].inc(tokens, tags=self._tags)
+        m["prefill_padded_tokens"].inc(computed, tags=self._tags)
+        self._span("serve.prefill_group", t0, t1, {
+            "bucket": bucket, "rows": real, "rows_padded": rows,
+            "prompt_tokens": tokens, "token_positions": computed},
+            f"{self._lane}/prefills")
 
     # ----------------------------------------- disaggregation (KV handoff)
     def kv_endpoint(self, peer: str) -> Dict[str, Any]:
