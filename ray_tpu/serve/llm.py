@@ -24,11 +24,16 @@ whose per-call host↔device round trip is tens of milliseconds:
   the two planes (tests/test_kv_cache.py parity gate): the gathered
   block layout equals the dense layout position-for-position, and the
   cold prefill path runs the same ``prefill_forward`` computation.
-- Cache rows are written with a masked select, not per-slot scatters
-  (XLA TPU serializes scatters; the masked write is bandwidth-bound).
-  The paged plane scatters whole BLOCKS back (block-granular indices,
-  the layout XLA handles well), mirroring the dense plane's
-  slice-update of the attended prefix.
+- A decode step writes ONE K/V row per slot, in place: the stacked
+  cache is the carry of the token loop and of the layer loop, each
+  layer scatters B rows of (Hkv, D) at the slots' own positions and
+  its attention reads the attended prefix of that layer, a group of
+  slots at a time — no select over a layer, no slice-out and
+  write-back of the prefix (120 rows scatter in ~15 us on a v5e;
+  PERF.md section 5).  The paged plane
+  gathers its block tables into the same layout, runs the same step
+  on the gathered buffer and scatters whole BLOCKS back
+  (block-granular indices, the layout XLA handles well).
 - Prefill runs plain causal attention WITHIN the prompt (no cache
   read), inserts K/V via a one-hot slot projection (dense) or a
   block-table scatter (paged) at static offsets, and returns the
@@ -100,6 +105,17 @@ from ..observability import tracing as _tracing
 # 1-request wave through a 32-wide group would pay 32 prompts of
 # latency).  Each size × prompt bucket is one compile, warmed at init.
 PREFILL_GROUPS = (4, 32)
+
+# A decode step's attention reads one layer's attended K and V for a
+# GROUP of slots at a time.  XLA does not read a slice of the stacked
+# cache from inside the attention: it copies the slice out first, to HBM
+# if it is large, on chip (VMEM) if it is small enough.  This is the
+# "small enough" for one of K and V.  On a v5e at the benchmark's widths
+# (PERF.md section 6, PR 24) slices up to 25 MB were staged on chip, but
+# from 16 MiB up XLA also parked a weight stack there and moved it out
+# and back in every layer; at 4 to 12 MiB it did neither.
+# tests/test_decode_inplace.py holds both at the real widths.
+_ATTEND_GROUP_BYTES = 8 << 20
 
 # How aggressively the feasibility shed fires: a request is shed when
 # its remaining budget is under this fraction of the ESTIMATED time to
@@ -192,6 +208,16 @@ class _Request:
                 cb()
             except Exception:
                 pass
+
+
+def _attend_group(slots: int, slot_bytes: int) -> int:
+    """Slots a decode step attends at a time: the most, dividing the
+    slot count, whose attended K (or V) of one layer fits
+    ``_ATTEND_GROUP_BYTES``."""
+    group = max(1, min(slots, _ATTEND_GROUP_BYTES // slot_bytes))
+    while slots % group:
+        group -= 1
+    return group
 
 
 class LLMServer:
@@ -367,20 +393,12 @@ class LLMServer:
                      ov_tok, ov_len, ov_mask, active, k, s_active):
             tok = jnp.where(ov_mask, ov_tok, tok_dev)
             lens = jnp.where(ov_mask, ov_len, len_dev)
-            ck = jax.lax.slice_in_dim(cache["k"], 0, s_active, axis=2)
-            cv = jax.lax.slice_in_dim(cache["v"], 0, s_active, axis=2)
-            key_pos = jnp.arange(s_active, dtype=jnp.int32)
-            step = self._make_decode_step(params, key_pos, active,
+            step = self._make_decode_step(params, s_active, active,
                                           llama, jax, jnp)
             (ck, cv, tok, lens), toks = jax.lax.scan(
-                step, (ck, cv, tok, lens), None, length=k)
-            cache = {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], ck, 0, axis=2),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], cv, 0, axis=2),
-            }
-            return cache, toks, tok, lens
+                step, (cache["k"], cache["v"], tok, lens), None,
+                length=k)
+            return {"k": ck, "v": cv}, toks, tok, lens
 
         self._prefill = jax.jit(prefill, donate_argnums=(1,))
         # tok_dev/len_dev (args 2, 3) are always overwritten by the
@@ -388,14 +406,19 @@ class LLMServer:
         self._decode_k = jax.jit(decode_k, donate_argnums=(1, 2, 3),
                                  static_argnames=("k", "s_active"))
 
-    def _make_decode_step(self, params, key_pos, active, llama, jax,
+    def _make_decode_step(self, params, s_active, active, llama, jax,
                           jnp, cfg=None):
-        """The shared per-token decode step (scan body): masked-select
-        K/V write at each slot's current position, bucketed cache
-        attention, greedy argmax fed back in-graph.  IDENTICAL math for
-        the dense slice and the paged gathered layout — block ordering
-        makes gathered index == absolute position, which is what keeps
-        the two planes' tokens bit-identical.  ``cfg`` overrides the
+        """The shared per-token decode step (scan body): a row write of
+        each slot's new K/V at its current position, cache attention
+        over the first ``s_active`` positions, greedy argmax fed back
+        in-graph.  The carry holds the WHOLE stacked (L, B, S, Hkv, D)
+        K and V through the token loop and the layer loop, so XLA's
+        while loops alias them in place: a step reads each layer's
+        attended prefix once and writes B rows per layer, nothing of
+        the cache's shape is rebuilt.  IDENTICAL math for the dense
+        cache and the paged gathered layout — block ordering makes
+        gathered index == absolute position, which is what keeps the
+        two planes' tokens bit-identical.  ``cfg`` overrides the
         target config (the speculative DRAFT model reuses this step on
         its own dense cache)."""
         cfg = cfg or self.cfg
@@ -409,26 +432,58 @@ class LLMServer:
             # Inactive slots MUST not write: a just-admitted slot's
             # prefill may already have landed (it sits out this
             # chunk awaiting its first token) and a stale-position
-            # write would corrupt its fresh rows.
-            writemask = ((key_pos[None, :] == lens[:, None])
-                         & active[:, None])[:, :, None, None]
+            # write would corrupt its fresh rows.  Nor does a slot
+            # past the attended prefix.  Their row goes out of range
+            # and the scatter drops it.
+            slots = tok.shape[0]
+            rows = jnp.arange(slots, dtype=jnp.int32)
+            pos = jnp.where(active & (lens < s_active), lens,
+                            ck.shape[2])
             scale = cfg.head_dim ** -0.5
+            group = _attend_group(
+                slots, s_active * cfg.n_kv_heads * cfg.head_dim
+                * jnp.dtype(ck.dtype).itemsize)
 
-            def body(x, layer_and_cache):
-                layer, ck_l, cv_l = layer_and_cache
+            def attend(q, ck, cv, l):
+                # A group of slots at a time, so that a group's
+                # attended K and V of the layer are staged on chip:
+                # asked for all slots at once, XLA copies the layer's
+                # whole prefix out to HBM first and reads it back.
+                def prefix(c, lo):
+                    return jax.lax.dynamic_slice(
+                        c, (l, lo, 0, 0, 0),
+                        (1, group, s_active) + c.shape[3:])[0]
+
+                def one(lo):
+                    return llama._cache_attend(
+                        jax.lax.dynamic_slice_in_dim(q, lo, group),
+                        prefix(ck, lo), prefix(cv, lo),
+                        jax.lax.dynamic_slice_in_dim(
+                            lens, lo, group)[:, None], scale)
+
+                out = jax.lax.map(one, jnp.arange(
+                    0, slots, group, dtype=jnp.int32))
+                return out.reshape(q.shape)
+
+            def body(carry, layer_and_index):
+                x, ck, cv = carry
+                layer, l = layer_and_index
                 q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg)
-                ck_l = jnp.where(writemask, kk.astype(ck_l.dtype),
-                                 ck_l)
-                cv_l = jnp.where(writemask, vv.astype(cv_l.dtype),
-                                 cv_l)
-                attn = llama._cache_attend(q, ck_l, cv_l,
-                                           lens[:, None], scale)
+                # Write before attend: the new row is among the keys.
+                ck = ck.at[l, rows, pos].set(
+                    kk[:, 0].astype(ck.dtype), mode="drop",
+                    indices_are_sorted=True, unique_indices=True)
+                cv = cv.at[l, rows, pos].set(
+                    vv[:, 0].astype(cv.dtype), mode="drop",
+                    indices_are_sorted=True, unique_indices=True)
+                attn = attend(q, ck, cv, l)
                 x = llama._attn_out_mlp(x, attn, layer, cfg)
-                return x, (ck_l, cv_l)
+                return (x, ck, cv), None
 
-            x, (ck, cv) = jax.lax.scan(
-                lambda x, i: body(x, i), x,
-                (params["layers"], ck, cv))
+            (x, ck, cv), _ = jax.lax.scan(
+                body, (x, ck, cv),
+                (params["layers"],
+                 jnp.arange(cfg.n_layers, dtype=jnp.int32)))
             x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
             head = (params["embed_tokens"].astype(cfg.dtype).T
                     if cfg.tie_embeddings
@@ -628,8 +683,7 @@ class LLMServer:
             nb = bt.shape[1]
             ck = gather(pool, "k", bt)
             cv = gather(pool, "v", bt)
-            key_pos = jnp.arange(nb * bs, dtype=jnp.int32)
-            step = self._make_decode_step(params, key_pos, active,
+            step = self._make_decode_step(params, nb * bs, active,
                                           llama, jax, jnp)
             (ck, cv, tok, lens), toks = jax.lax.scan(
                 step, (ck, cv, tok, lens), None, length=k)
@@ -725,8 +779,9 @@ class LLMServer:
         """Build the speculative draft: its config/params, a DENSE
         per-slot KV cache (the draft is small — paging it buys
         nothing), and the propose/prefill programs.  The draft rides
-        the SAME decode-step math as the dense plane, so its cache
-        bookkeeping inherits the write-before-attend invariant."""
+        the SAME decode step as the dense plane, its cache that step's
+        carry with the rows written in place, so its cache bookkeeping
+        inherits the write-before-attend invariant."""
         import dataclasses
 
         cfg = self.cfg
@@ -780,20 +835,12 @@ class LLMServer:
 
         def draft_propose(params, cache, tok, pos, active, k,
                           s_active):
-            ck = jax.lax.slice_in_dim(cache["k"], 0, s_active, axis=2)
-            cv = jax.lax.slice_in_dim(cache["v"], 0, s_active, axis=2)
-            key_pos = jnp.arange(s_active, dtype=jnp.int32)
-            step = self._make_decode_step(params, key_pos, active,
+            step = self._make_decode_step(params, s_active, active,
                                           llama, jax, jnp, cfg=dcfg)
             (ck, cv, tok, pos), toks = jax.lax.scan(
-                step, (ck, cv, tok, pos), None, length=k)
-            cache = {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], ck, 0, axis=2),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], cv, 0, axis=2),
-            }
-            return cache, toks
+                step, (cache["k"], cache["v"], tok, pos), None,
+                length=k)
+            return {"k": ck, "v": cv}, toks
 
         self._draft_prefill = jax.jit(draft_prefill,
                                       donate_argnums=(1,))
