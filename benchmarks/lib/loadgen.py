@@ -14,10 +14,10 @@ receives only the generated inputs.
     output_tokens  the same
 
 A fixed amount of work from the seed: Poisson gaps are scaled so that
-exactly rate x span requests fall in the span (that IS the process,
-given its count); a closed loop's callers start part-way through their
-first request, as in a steady state; a length
-distribution with ``"stratified": n`` is sampled n values at a time at
+exactly rate x span requests fall in the lead-in and in the window,
+each (that IS the process, given its counts); a closed loop's callers
+start part-way through their first request, as in a steady state; a
+length distribution with ``"stratified": n`` is sampled n values at a time at
 evenly spaced quantiles, shuffled — the same distribution, every seed
 with the same amount of work, only order and timing left to chance.
 Token ids are uniform from the seed.
@@ -120,14 +120,21 @@ def arrival_offsets(arrivals: Dict[str, Any], seed: int,
     request is due, from ``-lead_in_s`` up to ``seconds``."""
     rate = float(arrivals["rate_per_s"])
     lead = float(arrivals.get("lead_in_s", 0.0))
-    span = lead + seconds
     if arrivals["process"] != "poisson":
         raise ValueError(f"not an open-loop process: "
                          f"{arrivals['process']!r}")
-    n = int(round(span * rate))
-    due = np.cumsum(_rng(seed, 3).exponential(1.0 / rate, n + 1))
-    # n arrivals in the span: the next one is the first past its end
-    return due[:-1] * (span / due[-1]) - lead
+    rng = _rng(seed, 3)
+
+    def stretch(span: float) -> np.ndarray:
+        # rate x span arrivals in the span: the next one is the first
+        # past its end
+        due = np.cumsum(rng.exponential(1.0 / rate,
+                                        int(round(span * rate)) + 1))
+        return due[:-1] * (span / due[-1])
+
+    # The lead-in and the window hold their counts each: every seed
+    # offers the window the same load, not 97-103% of it.
+    return np.concatenate([stretch(lead) - lead, stretch(seconds)])
 
 
 # ------------------------------------------------------------- sending

@@ -15,6 +15,35 @@ it moves tokens/s and where it moves a latency is two entries
 share the one file ``metrics/decode_step_device_ms.py``.  A later cell
 joins an entry's ``workloads`` or brings an entry under a group name of
 its own; neither needs a file.
+
+What a ``model_config`` PR brings, all of it new files and appended
+entries (``benchmarks/tests/test_rehearsal.py`` does exactly this, on
+the CPU):
+
+    configs/<config>.json        the published keys, ``reference``,
+                                 ``assumed`` (non-empty), ``reduced``,
+                                 ``program_fields`` (fields of the
+                                 program's config that no published key
+                                 spells, passed through verbatim)
+    references/<reference>.py    ``logits`` and ``teacher_forced_gap``
+                                 (serving), ``loss_and_grads``,
+                                 ``global_norm``, ``gradient_gaps``
+                                 (training), if no reference here is the
+                                 configuration's mathematics
+    workloads/<cell>.json        and traffic/<traffic>.json unless the
+                                 cell runs a mix that is here
+    metrics/<reader>.py          for what no reader here measures
+    BENCHMARK.json               the configuration, the cell, its own
+                                 metrics; the cell's name appended to the
+                                 ``workloads`` of the entries it joins
+
+A configuration that is cut says so twice: ``reduced`` in
+``BENCHMARK.json`` lists the keys, and ``reduced`` in the file has one
+``{"key", "published", "here", "why"}`` per key, in the same order
+(``[]`` where nothing is cut).  ``lib/flops.py`` counts a dense decoder
+only: a model with experts brings its own operation and byte functions
+inside its own roofline reader, under a metric name of its own, and
+does not join ``batch.decode_step_roofline``.
 """
 
 from __future__ import annotations

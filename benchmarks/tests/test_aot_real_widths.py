@@ -230,22 +230,35 @@ def _engine_programs(cell_name, one_chip, max_slots=None):
 
 
 @pytest.mark.parametrize("cell", ["internlm2-1.8b.serve-batch-decode",
-                                  "internlm2-1.8b.serve-chat-open"])
+                                  "internlm2-1.8b.serve-chat-busy"])
 def test_engine_programs_fit_one_chip(one_chip, cell):
     for label, compile_it in _engine_programs(cell, one_chip):
         compile_it()       # the compiler raises RESOURCE_EXHAUSTED if not
 
 
-@pytest.mark.parametrize("cell,slots,refused", [
-    # the next multiple of 8 up is refused: the slot counts are the
-    # largest that fit (the dense plane copies the attended slice)
-    ("internlm2-1.8b.serve-batch-decode", 128, "decode_k s_active=256"),
-    ("internlm2-1.8b.serve-chat-open", 40, "decode_k s_active=1024"),
-])
-def test_eight_more_slots_do_not_fit(one_chip, cell, slots, refused):
+@pytest.mark.parametrize("cell,slots,refused,used", [
+    # 128 x 512 compiles since the decode step updates the cache in place
+    # (PR 24); the cell keeps 120 so that its ledger line is one series
+    ("internlm2-1.8b.serve-batch-decode", 128, None, None),
+    # 40 x 1,280 is the largest multiple of 8 that fits: at 48 the widest
+    # prefill group's scratch beside weights and cache is over the chip
+    ("internlm2-1.8b.serve-chat-busy", 48, "prefill group=32 bucket=1024",
+     "16.64G of 15.75G"),
+], ids=["serve-batch-decode-128", "serve-chat-busy-48"])
+def test_eight_more_slots_compile_or_name_what_refuses(one_chip, cell, slots,
+                                                       refused, used):
+    """What bounds a serve cell's slot count, by the compiler's own
+    message: every program the engine warms compiles at ``slots`` except
+    ``refused`` (none: the cell is not at its bound, and says why)."""
     import jax
 
-    programs = dict(_engine_programs(cell, one_chip, max_slots=slots))
-    with pytest.raises(jax.errors.JaxRuntimeError,
-                       match="RESOURCE_EXHAUSTED"):
-        programs[refused]()
+    assert slots == _json("workloads", cell)["engine"]["max_slots"] + 8
+    for label, compile_it in _engine_programs(cell, one_chip,
+                                              max_slots=slots):
+        if label != refused:
+            compile_it()
+            continue
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="RESOURCE_EXHAUSTED") as refusal:
+            compile_it()
+        assert used in str(refusal.value)

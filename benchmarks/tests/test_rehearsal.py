@@ -24,9 +24,34 @@ TINY = {
     "intermediate_size": 128, "max_position_embeddings": 256,
     "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
     "tie_word_embeddings": False, "hidden_act": "silu", "bias": False,
-    "reduced": [], "assumed": [],
+    "reduced": [], "assumed": ["test"],
     "program_fields": {"attention_impl": "flash", "remat_policy": "attn"},
 }
+# What the next ``model_config`` PR brings, at toy size: a configuration
+# that is cut (half its source's depth, written out), its own reference
+# module beside it, and fields of the program's config that the published
+# keys do not spell, passed through ``program_fields``.
+TINY_CUT = dict(
+    TINY, name="tiny-cut", source="none (test, cut)",
+    reference="tiny_cut_reference", num_hidden_layers=1,
+    reduced=[{"key": "num_hidden_layers", "published": 2, "here": 1,
+              "why": "test: half the depth, as a model that does not fit"}],
+    program_fields={"attention_impl": "dot", "scan_unroll": 2})
+TINY_CUT_REFERENCE = '''"""A reference brought by its configuration: here the dense decoder's
+mathematics again (a real one differs: experts, norms on q and k), with
+a count of the times the benchmark's ``correct`` came through it."""
+
+from benchmarks.references import dense_decoder
+
+CALLS = {"teacher_forced_gap": 0}
+logits = dense_decoder.logits
+
+
+def teacher_forced_gap(params, prompt, emitted, config, pad_to=0):
+    CALLS["teacher_forced_gap"] += 1
+    return dense_decoder.teacher_forced_gap(params, prompt, emitted,
+                                            config, pad_to=pad_to)
+'''
 LENGTHS = {"prompt_tokens": {"dist": "lognormal", "median": 16,
                              "sigma": 0.5, "min": 4, "max": 32,
                              "stratified": 4},
@@ -49,6 +74,8 @@ ENGINE = {"max_slots": 4, "max_len": 128, "prefill_buckets": [32, 64],
           "paged": False, "prefill_groups": [2, 4]}
 TRAINER = {"fused_optimizer": True, "prefetch_batches": 2,
            "sync_every_steps": 3, "warmup_steps": 1}
+SERVE = {"kind": "serve_llm", "chips": 1, "engine": ENGINE,
+         "deployment": {"max_ongoing_requests": 64}}
 CELLS = {   # name -> (cell file, the real cell whose metrics it reports)
     "tiny.tiny-train": (
         {"kind": "train_lm", "chips": 1,
@@ -60,14 +87,9 @@ CELLS = {   # name -> (cell file, the real cell whose metrics it reports)
          # test suite's CPU has eight: data=2 takes up the other four
          "trainer": {"mesh": {"data": 2, "fsdp": 4}, **TRAINER}},
         "internlm2-1.8b.train-fsdp4"),
-    "tiny.tiny-closed": (
-        {"kind": "serve_llm", "chips": 1, "engine": ENGINE,
-         "deployment": {"max_ongoing_requests": 64}},
-        "internlm2-1.8b.serve-batch-decode"),
-    "tiny.tiny-open": (
-        {"kind": "serve_llm", "chips": 1, "engine": ENGINE,
-         "deployment": {"max_ongoing_requests": 64}},
-        "internlm2-1.8b.serve-chat-open"),
+    "tiny.tiny-closed": (SERVE, "internlm2-1.8b.serve-batch-decode"),
+    "tiny.tiny-open": (SERVE, "internlm2-1.8b.serve-chat-busy"),
+    "tiny-cut.tiny-closed": (SERVE, "internlm2-1.8b.serve-batch-decode"),
 }
 NEW_METRIC = '''"""Requests the generator measured (a count, from its log)."""
 
@@ -94,21 +116,26 @@ def tree(tmp_path_factory):
             f.write(text)
 
     drop("configs/tiny.json", json.dumps(TINY))
+    drop("configs/tiny-cut.json", json.dumps(TINY_CUT))
+    drop("references/tiny_cut_reference.py", TINY_CUT_REFERENCE)
     for name, traffic in TRAFFIC.items():
         drop(f"traffic/{name}.json", json.dumps(traffic))
     drop("metrics/tiny_requests_measured.py", NEW_METRIC)
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         benchmark = json.load(f)
-    benchmark["configs"].append(
-        {"name": "tiny", "source": "none (test)", "reduced": [],
-         "file": "benchmarks/configs/tiny.json", "why": "test"})
+    for config in (TINY, TINY_CUT):
+        benchmark["configs"].append(
+            {"name": config["name"], "source": config["source"],
+             "reduced": [cut["key"] for cut in config["reduced"]],
+             "file": f"benchmarks/configs/{config['name']}.json",
+             "why": "test"})
     for name, (cell, like) in CELLS.items():
-        traffic = name.split(".", 1)[1]
-        cell = dict(cell, name=name, config="tiny", traffic=traffic,
+        config, traffic = name.split(".", 1)
+        cell = dict(cell, name=name, config=config, traffic=traffic,
                     why="test")
         drop(f"workloads/{name}.json", json.dumps(cell))
         benchmark["workloads"].append(
-            {"name": name, "config": "tiny", "traffic": traffic,
+            {"name": name, "config": config, "traffic": traffic,
              "chips": cell["chips"], "why": "test"})
         for group in ("end_to_end", "per_layer"):
             for metric in benchmark[group]:
@@ -176,6 +203,31 @@ def test_kind_end_to_end_on_the_cpu(tree, cpu_peaks, cell, headlines):
         assert max(obs["grad_leaf_gaps"].values()) < 0.1
     else:
         assert len(obs["logit_gaps"]) == 4
+
+
+def test_a_cut_configuration_with_its_own_reference_is_files_alone(
+        tree, cpu_peaks):
+    """A serve cell of ``tiny-cut``: one layer of its source's two, a
+    reference module dropped in beside it, program fields passed through
+    — found by name, run through ``run.measure``, checked by ITS
+    reference; and the spec check of the real ``BENCHMARK.json`` passes on
+    the tree that holds it."""
+    from benchmarks.lib import program
+    from benchmarks.tests.test_yardstick import names_lead_to_files
+
+    bench, benchmark_json = tree
+    names_lead_to_files(os.path.dirname(benchmark_json))
+    result, obs = _measure(tree, "tiny-cut.tiny-closed", trace=0)
+    assert set(result["metrics"]) == {"serve_output_tokens_per_s",
+                                      "setup_s"}
+    cell = obs["cell"]
+    assert cell.reference.__file__ == os.path.join(
+        bench, "references", "tiny_cut_reference.py")
+    assert cell.reference.CALLS["teacher_forced_gap"] == len(
+        obs["logit_gaps"]) == 4
+    fields = program.llama_fields(cell.config)
+    assert (fields["n_layers"], fields["attention_impl"],
+            fields["scan_unroll"]) == (1, "dot", 2)
 
 
 def test_traced_run_reports_per_layer_metrics_and_a_new_one(tree,
