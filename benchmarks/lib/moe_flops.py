@@ -1,0 +1,102 @@
+"""Operations and bytes the ALGORITHM of a decoder with experts needs,
+from shapes alone (``lib/flops.py`` counts a dense decoder only).  The
+yardstick's own arithmetic: nothing here is read from the program.
+
+A configuration is the dict of ``benchmarks/configs/<name>.json`` (the
+published ``config.json`` keys: ``num_experts``, ``num_experts_per_tok``,
+``intermediate_size`` = the width of ONE expert).  A multiply-add counts
+as 2 FLOPs.  Every layer has experts and none is shared (OLMoE).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+from .flops import kv_bytes_per_token  # K and V rows of a position
+
+
+def _sizes(c: Dict[str, Any]):
+    h = c["hidden_size"]
+    q = c["num_attention_heads"] * c["head_dim"]
+    kv = c["num_key_value_heads"] * c["head_dim"]
+    return h, q, kv, c["intermediate_size"], c["vocab_size"], \
+        c["num_experts"]
+
+
+def expert_params(c: Dict[str, Any]) -> int:
+    """The three matrices of ONE expert of one layer."""
+    h, _q, _kv, f, _v, _e = _sizes(c)
+    return 3 * h * f
+
+
+def dense_matmul_params_per_layer(c: Dict[str, Any]) -> int:
+    """What every token is multiplied by in a layer whatever it is
+    routed to: q, k, v, o and the router."""
+    h, q, kv, _f, _v, e = _sizes(c)
+    return h * q + 2 * h * kv + q * h + h * e
+
+
+def param_count(c: Dict[str, Any], layers: int = None) -> int:
+    """Every parameter, at ``layers`` layers (the file's own depth if
+    not given): projections, the q and k norms' weights, the two block
+    norms, router, experts; embedding, untied head, final norm."""
+    h, q, kv, _f, v, e = _sizes(c)
+    layers = c["num_hidden_layers"] if layers is None else layers
+    per_layer = (dense_matmul_params_per_layer(c) + q + kv + 2 * h
+                 + e * expert_params(c))
+    head = 0 if c["tie_word_embeddings"] else v * h
+    return layers * per_layer + v * h + head + h
+
+
+def active_params(c: Dict[str, Any], layers: int = None) -> int:
+    """Matmul weights ONE token meets in a forward pass: the dense part
+    of every layer, its ``num_experts_per_tok`` experts, the head."""
+    h, _q, _kv, _f, v, _e = _sizes(c)
+    layers = c["num_hidden_layers"] if layers is None else layers
+    return layers * (dense_matmul_params_per_layer(c)
+                     + c["num_experts_per_tok"] * expert_params(c)) + h * v
+
+
+def expert_matmul_bytes(c: Dict[str, Any], experts_touched: float,
+                        expert_rows: float, dtype_bytes: int = 2) -> float:
+    """Least HBM traffic of the grouped matmuls alone: the three
+    matrices of each (layer, expert) pair that has a row, once, and each
+    row's activations (in at width h twice, the hidden row of width f
+    out twice and in once, out at width h once)."""
+    h, _q, _kv, f, _v, _e = _sizes(c)
+    return (experts_touched * expert_params(c)
+            + expert_rows * (3 * h + 3 * f)) * dtype_bytes
+
+
+def expert_matmul_flops(c: Dict[str, Any], expert_rows: float) -> float:
+    """``expert_rows`` (token, expert) assignments through three
+    matrices of h x f."""
+    return 2.0 * expert_rows * expert_params(c)
+
+
+def decode_step_bytes(c: Dict[str, Any], experts_touched: float,
+                      context_tokens: float, dtype_bytes: int = 2) -> float:
+    """Least HBM traffic of ONE decode step: attention, router and head
+    weights once (the embedding is gathered row-wise), the three matrices
+    of each (layer, expert) pair TOUCHED in the step (``experts_touched``
+    <= layers x experts), and each sequence's keys and values once
+    (``context_tokens`` positions held by the batch in flight)."""
+    h, _q, _kv, _f, v, _e = _sizes(c)
+    dense = c["num_hidden_layers"] * dense_matmul_params_per_layer(c) \
+        + h * v
+    return (dense + experts_touched * expert_params(c)) * dtype_bytes \
+        + context_tokens * kv_bytes_per_token(c, dtype_bytes)
+
+
+def decode_step_flops(c: Dict[str, Any], batch: float,
+                      context_tokens: float, expert_rows: float) -> float:
+    """One decode step over ``batch`` sequences holding
+    ``context_tokens`` positions in all, whose tokens made
+    ``expert_rows`` (token, expert) assignments over all layers."""
+    h, _q, _kv, _f, v, _e = _sizes(c)
+    heads, d = c["num_attention_heads"], c["head_dim"]
+    dense = c["num_hidden_layers"] * dense_matmul_params_per_layer(c) \
+        + h * v
+    attn = 2 * 2 * context_tokens * heads * d * c["num_hidden_layers"]
+    return 2.0 * dense * batch + attn \
+        + expert_matmul_flops(c, expert_rows)

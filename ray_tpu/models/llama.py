@@ -81,14 +81,23 @@ class LlamaConfig:
     # schedule over pipe stages (parallel/pipeline.py) instead of one
     # scan.  Value = number of microbatches.
     pipeline_microbatches: int = 0
-    # >0 replaces every layer's dense FFN with a GShard/Switch MoE FFN
-    # (models/moe.py) of this many experts, sharded over the "expert"
-    # mesh axis.  The Switch aux loss is added to the training loss
-    # scaled by moe_aux_weight.
+    # >0 replaces every layer's dense FFN with this many experts of
+    # width intermediate_size (models/moe.py), moe_top_k a token.  Every
+    # token reaches all of its experts (dropless grouped matmuls); only
+    # under a mesh with expert > 1 does training dispatch densely with a
+    # capacity (moe_capacity_factor), which drops.  The Switch aux loss
+    # is added to the training loss scaled by moe_aux_weight.
     moe_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # The chosen gates renormalised to sum to one (Switch, Mixtral) or
+    # the softmax's probabilities as they are (OLMoE: norm_topk_prob
+    # false).
+    moe_norm_topk: bool = True
+    # RMSNorm on q and on k, each over its WHOLE projection, before the
+    # split into heads and before RoPE (OLMoE).
+    qk_norm: bool = False
 
     @property
     def q_dim(self) -> int:
@@ -206,6 +215,9 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         },
         "final_norm": (None,),
     }
+    if config.qk_norm:
+        axes["layers"]["q_norm"] = ("layers", None)
+        axes["layers"]["k_norm"] = ("layers", None)
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -267,6 +279,9 @@ def init_params(rng: jax.Array, config: LlamaConfig,
         },
         "final_norm": jnp.ones((c.hidden_size,), dtype),
     }
+    if c.qk_norm:
+        params["layers"]["q_norm"] = jnp.ones((L, c.q_dim), dtype)
+        params["layers"]["k_norm"] = jnp.ones((L, c.kv_dim), dtype)
     if not c.tie_embeddings:
         params["lm_head"] = dense(
             jax.random.fold_in(rng, 99), (c.hidden_size, c.vocab_size),
@@ -415,10 +430,13 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     B, S, _ = x.shape
     dt = c.dtype
     h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-    q = matmul(h, layer["wq"].astype(dt)).reshape(B, S, c.n_heads,
-                                                  c.head_dim)
-    k = matmul(h, layer["wk"].astype(dt)).reshape(B, S, c.n_kv_heads,
-                                                  c.head_dim)
+    q = matmul(h, layer["wq"].astype(dt))
+    k = matmul(h, layer["wk"].astype(dt))
+    if c.qk_norm:
+        q = rms_norm(q, layer["q_norm"], c.norm_eps)
+        k = rms_norm(k, layer["k_norm"], c.norm_eps)
+    q = q.reshape(B, S, c.n_heads, c.head_dim)
+    k = k.reshape(B, S, c.n_kv_heads, c.head_dim)
     v = matmul(h, layer["wv"].astype(dt)).reshape(B, S, c.n_kv_heads,
                                                   c.head_dim)
     q = apply_rope(q, sin, cos)
@@ -428,72 +446,96 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     return q, k, v
 
 
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def split_expert_stacks(layers: Dict[str, jax.Array],
+                        config: LlamaConfig):
+    """(what a layer scan slices per layer, what it closes over): the
+    ``[L, E, ...]`` expert matrices stay whole, for ``attn_out_ffn`` to
+    read at ``layer_index``; everything of a dense model is sliced."""
+    if config.moe_experts == 0:
+        return layers, {}
+    return ({k: v for k, v in layers.items() if k not in EXPERT_STACKS},
+            {k: layers[k] for k in EXPERT_STACKS})
+
+
+def attn_out_ffn(x: jax.Array, attn: jax.Array,
+                 layer: Dict[str, jax.Array], config: LlamaConfig,
+                 valid: Optional[jax.Array] = None,
+                 layer_index: Optional[jax.Array] = None):
+    """Output projection + FFN half of the block, dense or experts as
+    the config says: THE function every path calls (training forward,
+    ``prefill_forward``, ``forward_with_cache``, the serve programs; the
+    conventions shared with ``_qkv_rope`` live here).  Constraints are
+    no-ops outside a mesh.
+
+    Returns ``(x, aux, expert_rows)``: the layer's Switch aux loss and
+    the rows each expert computed ((E,) int32) — a constant 0 and None
+    for a dense config.  ``valid`` (broadcastable to (B, S)) marks the
+    rows that are real; experts compute no others.  With ``layer_index``
+    the expert matrices in ``layer`` are the whole ``[L, E, ...]`` stacks
+    (``split_expert_stacks``), read in place."""
+    c = config
+    B, S, _ = x.shape
+    dt = c.dtype
+    x = x + matmul(attn.reshape(B, S, c.q_dim), layer["wo"].astype(dt))
+    x = with_logical_constraint(x, "batch", "seq", None)
+    h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
+    if c.moe_experts == 0:
+        gate = matmul(h, layer["w_gate"].astype(dt))
+        up = matmul(h, layer["w_up"].astype(dt))
+        # Named so the "attn_ffn" remat policy can save it (inert under
+        # every other policy and outside jax.checkpoint).
+        from jax.ad_checkpoint import checkpoint_name
+
+        ff = checkpoint_name(jax.nn.silu(gate) * up, "ffn_act")
+        ff = with_logical_constraint(ff, "batch", "seq", "mlp")
+        x = x + matmul(ff, layer["w_down"].astype(dt))
+        return (with_logical_constraint(x, "batch", "seq", None),
+                jnp.zeros((), jnp.float32), None)
+    from ray_tpu.models import moe
+    from ray_tpu.parallel.sharding import current_mesh
+
+    mcfg = moe.MoEConfig(hidden_size=c.hidden_size,
+                         intermediate_size=c.intermediate_size,
+                         n_experts=c.moe_experts, top_k=c.moe_top_k,
+                         capacity_factor=c.moe_capacity_factor,
+                         norm_topk=c.moe_norm_topk, dtype=dt)
+    moe_params = {k: layer[k] for k in ("router",) + EXPERT_STACKS}
+    mesh = current_mesh()
+    if mesh is not None and mesh.shape.get("expert", 1) > 1:
+        # Expert-parallel training: the dense dispatch, whose sharding
+        # constraint lowers to the all-to-all (it has a capacity).
+        ff, aux = moe.moe_ffn(h, moe_params, mcfg)
+        expert_rows = None
+    else:
+        ff, aux, expert_rows = moe.moe_ffn_dropless(
+            h, moe_params, mcfg, valid=valid, layer_index=layer_index)
+    x = x + ff.astype(x.dtype)
+    return with_logical_constraint(x, "batch", "seq", None), aux, \
+        expert_rows
+
+
 def _attn_out_mlp(x: jax.Array, attn: jax.Array,
                   layer: Dict[str, jax.Array],
                   config: LlamaConfig) -> jax.Array:
-    """Output projection + MLP half of the block (shared, see
-    _qkv_rope).  Constraints are no-ops outside a mesh."""
-    c = config
-    B, S, _ = x.shape
-    dt = c.dtype
-    x = x + matmul(attn.reshape(B, S, c.q_dim), layer["wo"].astype(dt))
-    x = with_logical_constraint(x, "batch", "seq", None)
-    h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
-    gate = matmul(h, layer["w_gate"].astype(dt))
-    up = matmul(h, layer["w_up"].astype(dt))
-    # Named so the "attn_ffn" remat policy can save it (inert under
-    # every other policy and outside jax.checkpoint).
-    from jax.ad_checkpoint import checkpoint_name
-
-    ff = checkpoint_name(jax.nn.silu(gate) * up, "ffn_act")
-    ff = with_logical_constraint(ff, "batch", "seq", "mlp")
-    x = x + matmul(ff, layer["w_down"].astype(dt))
-    return with_logical_constraint(x, "batch", "seq", None)
-
-
-def _attn_out_moe(x: jax.Array, attn: jax.Array,
-                  layer: Dict[str, jax.Array],
-                  config: LlamaConfig) -> Tuple[jax.Array, jax.Array]:
-    """MoE twin of _attn_out_mlp: the dense FFN is replaced by the
-    expert-parallel Switch FFN; returns (x, layer aux loss)."""
-    from ray_tpu.models.moe import MoEConfig, moe_ffn
-
-    c = config
-    B, S, _ = x.shape
-    dt = c.dtype
-    x = x + matmul(attn.reshape(B, S, c.q_dim), layer["wo"].astype(dt))
-    x = with_logical_constraint(x, "batch", "seq", None)
-    h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
-    mcfg = MoEConfig(hidden_size=c.hidden_size,
-                     intermediate_size=c.intermediate_size,
-                     n_experts=c.moe_experts, top_k=c.moe_top_k,
-                     capacity_factor=c.moe_capacity_factor, dtype=dt)
-    moe_params = {k: layer[k]
-                  for k in ("router", "w_gate", "w_up", "w_down")}
-    ff, aux = moe_ffn(h, moe_params, mcfg)
-    x = x + ff.astype(x.dtype)
-    return with_logical_constraint(x, "batch", "seq", None), aux
+    """``attn_out_ffn``'s residual stream alone (the name the test-local
+    reference of tests/test_decode_inplace.py calls)."""
+    return attn_out_ffn(x, attn, layer, config)[0]
 
 
 def decoder_layer(x: jax.Array, layer: Dict[str, jax.Array],
                   sin: jax.Array, cos: jax.Array, positions: jax.Array,
                   config: LlamaConfig,
-                  attention_fn: Callable) -> jax.Array:
+                  attention_fn: Callable
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """One block: ``(x, the layer's aux loss)`` (0 for a dense one)."""
     q, k, v = _qkv_rope(x, layer, sin, cos, config)
     with jax.named_scope("attention"):
         attn = attention_fn(q, k, v, positions)
-    return _attn_out_mlp(x, attn, layer, config)
-
-
-def decoder_layer_moe(x: jax.Array, layer: Dict[str, jax.Array],
-                      sin: jax.Array, cos: jax.Array,
-                      positions: jax.Array, config: LlamaConfig,
-                      attention_fn: Callable
-                      ) -> Tuple[jax.Array, jax.Array]:
-    q, k, v = _qkv_rope(x, layer, sin, cos, config)
-    with jax.named_scope("attention"):
-        attn = attention_fn(q, k, v, positions)
-    return _attn_out_moe(x, attn, layer, config)
+    x, aux, _rows = attn_out_ffn(x, attn, layer, config)
+    return x, aux
 
 
 # ---------------------------------------------------------------------------
@@ -535,9 +577,8 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
 
     def make_block(sin, cos, positions):
         block = functools.partial(
-            decoder_layer_moe if moe else decoder_layer,
-            sin=sin, cos=cos, positions=positions, config=c,
-            attention_fn=attention_fn)
+            decoder_layer, sin=sin, cos=cos, positions=positions,
+            config=c, attention_fn=attention_fn)
         if c.remat:
             block = jax.checkpoint(block, policy=_remat_policy(c))
         return block
@@ -571,27 +612,20 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
         batch_axes = [a for a in ("data", "fsdp") if a in mesh.shape
                       and mesh.shape[a] > 1]
         x = pipeline_layers(
-            lambda h, layer: block(h, layer), params["layers"], x,
+            lambda h, layer: block(h, layer)[0], params["layers"], x,
             mesh=mesh, num_microbatches=c.pipeline_microbatches,
             batch_axes=batch_axes)
     else:
         block = make_block(sin, cos, positions)
 
-        if moe:
-            def scan_body(carry, layer_params):
-                h, aux = carry
-                h, aux_l = block(h, layer_params)
-                return (h, aux + aux_l), None
+        def scan_body(carry, layer_params):
+            h, aux = carry
+            h, aux_l = block(h, layer_params)
+            return (h, aux + aux_l), None
 
-            (x, aux_total), _ = jax.lax.scan(
-                scan_body, (x, aux_total), params["layers"],
-                unroll=c.scan_unroll)
-        else:
-            def scan_body(carry, layer_params):
-                return block(carry, layer_params), None
-
-            x, _ = jax.lax.scan(scan_body, x, params["layers"],
-                                unroll=c.scan_unroll)
+        (x, aux_total), _ = jax.lax.scan(
+            scan_body, (x, aux_total), params["layers"],
+            unroll=c.scan_unroll)
 
     with jax.named_scope("head_loss"):
         x = rms_norm(x, params["final_norm"], c.norm_eps)
@@ -896,8 +930,8 @@ def dequantize_kv_blocks(stored: jax.Array, scale: jax.Array,
 
 
 def prefill_forward(params: PyTree, tokens: jax.Array,
-                    lengths: jax.Array, config: LlamaConfig
-                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                    lengths: jax.Array, config: LlamaConfig,
+                    return_expert_rows: bool = False):
     """Causal forward over right-padded prompts for cache insertion.
 
     tokens: (G, P) int32 right-padded prompts; lengths: (G,) real
@@ -909,7 +943,11 @@ def prefill_forward(params: PyTree, tokens: jax.Array,
     token comes out of the prefill call itself — one less decode
     round-trip of TTFT).  Padding rows produce garbage K/V beyond
     lengths; the decode path overwrites each position before it first
-    attends it, so they are never observed."""
+    attends it, so they are never observed.  Experts compute the real
+    positions only (position < length: a group's padding rows come with
+    length 0); ``return_expert_rows`` adds a fourth result, the (L, E)
+    int32 rows each layer's experts computed (None for a dense
+    config)."""
     c = config
     G, P = tokens.shape
     dt = c.dtype
@@ -917,21 +955,27 @@ def prefill_forward(params: PyTree, tokens: jax.Array,
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None, :],
                                  (G, P))
     sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
+    valid = positions < lengths[:, None]
+    sliced, stacks = split_expert_stacks(params["layers"], c)
 
-    def body(x, layer):
+    def body(x, layer_and_index):
+        layer, l = layer_and_index
         q, k, v = _qkv_rope(x, layer, sin, cos, c)
         attn = dot_attention(q, k, v, positions)
-        x = _attn_out_mlp(x, attn, layer, c)
-        return x, (k, v)
+        x, _aux, rows = attn_out_ffn(x, attn, {**layer, **stacks}, c,
+                                     valid=valid, layer_index=l)
+        return x, (k, v, rows)
 
-    x, (ks, vs) = jax.lax.scan(lambda x, l: body(x, l), x,
-                               params["layers"])
+    x, (ks, vs, expert_rows) = jax.lax.scan(
+        body, x, (sliced, jnp.arange(c.n_layers, dtype=jnp.int32)))
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)  # (G,1,H)
     head = (params["embed_tokens"].astype(dt).T if c.tie_embeddings
             else params["lm_head"].astype(dt))
     last_logits = matmul(last, head)[:, 0]
+    if return_expert_rows:
+        return last_logits, ks, vs, expert_rows
     return last_logits, ks, vs
 
 
@@ -992,10 +1036,6 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
     T=prompt_bucket for prefill, T=1 for decode — each T compiles
     once."""
     c = config
-    if c.moe_experts > 0:
-        raise NotImplementedError(
-            "KV-cache decode for MoE models is not implemented yet; "
-            "serve with a dense config")
     B, T = tokens.shape
     dt = c.dtype
     x = params["embed_tokens"].astype(dt)[tokens]
@@ -1017,7 +1057,7 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
         cv_l = jax.vmap(write)(cv_l, v.astype(cv_l.dtype), pos0)
 
         attn = _cache_attend(q, ck_l, cv_l, positions, scale)
-        x = _attn_out_mlp(x, attn, layer, c)
+        x, _aux, _rows = attn_out_ffn(x, attn, layer, c)
         return x, (ck_l, cv_l)
 
     def scan_body(x, inputs):

@@ -91,7 +91,7 @@ def _run_layers(x: jax.Array, layers: PyTree, config: LlamaConfig):
         block = jax.checkpoint(block, policy=_remat_policy(c))
 
     def body(h, layer):
-        return block(h, layer), None
+        return block(h, layer)[0], None
 
     x, _ = jax.lax.scan(body, x, layers)
     return x

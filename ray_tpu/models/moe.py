@@ -1,24 +1,33 @@
-"""Mixture-of-Experts FFN with expert parallelism.
+"""Mixture-of-Experts FFN: dropless grouped matmuls, and a dense
+dispatch for an ``expert`` mesh axis.
 
 Reference: Ray implements NO MoE/EP (SURVEY §2.3 — it only offers
 placement-group primitives); the TPU build must supply the strategy
-natively.  Design is GShard/Switch-style DENSE dispatch, the
-TPU-idiomatic formulation: top-k routing builds a (tokens, experts,
-capacity) one-hot dispatch tensor, so dispatch/combine are einsums
-that run on the MXU with static shapes — no ragged buffers, no
-data-dependent shapes.  Sharding the expert dimension over the
-``expert`` mesh axis (logical axis "expert") makes XLA lower the
-dispatch/combine einsums to all_to_all over ICI automatically.
+natively.  Two formulations share one router (``_route``):
 
-Tokens beyond an expert's capacity are dropped (their combine weight
-is zero and the residual path carries them) — standard Switch
-semantics; ``capacity_factor`` trades drop rate for padding compute.
+- ``moe_ffn_dropless`` — what every path but expert-parallel training
+  runs (``llama.forward``, ``prefill_forward``, ``forward_with_cache``,
+  the serve programs).  The ``T x K`` (token, expert) assignments are
+  sorted by expert, the sorted rows go through three grouped matmuls
+  (``jax.lax.ragged_dot`` over the ``[E, D, H]`` stacks; on a TPU XLA
+  lowers it to a Mosaic kernel that reads each touched expert's tiles
+  in place), are un-sorted and summed under their gates.  Every token
+  reaches all of its K experts whatever the load; rows marked not
+  ``valid`` (prompt padding, inactive decode slots) are sorted behind
+  every group and take no expert's rows.
+- ``moe_ffn`` — GShard/Switch-style DENSE dispatch with a capacity:
+  top-k routing builds a (tokens, experts, capacity) one-hot dispatch
+  tensor, so dispatch/combine are einsums with static shapes, and
+  sharding the expert dimension over the ``expert`` mesh axis makes XLA
+  lower them to all_to_all over ICI.  Tokens beyond an expert's
+  capacity are dropped (the residual path carries them).  Kept ONLY as
+  what a mesh with ``expert > 1`` uses in training.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +44,10 @@ class MoEConfig:
     n_experts: int = 8
     top_k: int = 2
     capacity_factor: float = 1.25
+    # Renormalise the K chosen gates to sum to one (Switch, Mixtral), or
+    # use the softmax's probabilities as they are (OLMoE's
+    # ``norm_topk_prob: false``).
+    norm_topk: bool = True
     dtype: Any = jnp.bfloat16
 
 
@@ -74,21 +87,108 @@ def _einsum(eq, *args):
     return out.astype(args[0].dtype)
 
 
-def _route(xt: jax.Array, router: jax.Array, k: int):
-    """Shared by moe_ffn and the parity reference so the two can't
-    drift: f32 softmax routing + renormalized top-k gates."""
+def _route(xt: jax.Array, router: jax.Array, k: int, norm_topk: bool):
+    """The one routing function (dropless, dense dispatch and the parity
+    reference cannot drift): f32 softmax over all experts, top-k, the k
+    gates renormalised to sum to one or left as they are."""
     logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
                         router.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)          # (T, E)
     gate_vals, expert_idx = jax.lax.top_k(probs, k)  # (T, K)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    if norm_topk:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
     return probs, gate_vals, expert_idx
+
+
+def _aux_loss(probs: jax.Array, expert_idx: jax.Array) -> jax.Array:
+    """Switch load-balancing loss: E * sum_e(share of tokens whose first
+    choice is e * mean router probability of e)."""
+    E = probs.shape[-1]
+    top1 = jax.nn.one_hot(expert_idx[:, 0], E, dtype=jnp.float32)
+    return E * jnp.sum(top1.mean(0) * probs.mean(0))
+
+
+def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
+                     valid: Optional[jax.Array] = None,
+                     layer_index: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, S, D) → (out (B, S, D), aux_loss scalar, expert_rows (E,)
+    int32: the rows each expert computed).
+
+    ``valid`` (broadcastable to (B, S), bool): rows that are real.  The
+    others (prompt padding, inactive decode slots) get a zero and are
+    assigned to no expert, so the grouped matmuls do not compute them
+    and ``expert_rows`` does not count them.
+
+    ``layer_index``: the three expert matrices in ``params`` are then the
+    whole ``[L, E, ...]`` stacks of a layer-stacked model and this is the
+    layer to use.  The stack is handed to the grouped matmul as ``L * E``
+    groups of which only this layer's are non-empty: the kernel reads the
+    touched experts' tiles from the stack where it lies.  (Slicing a
+    layer out first makes XLA copy ``[E, D, H]`` per matrix per layer,
+    since a custom call cannot read through a dynamic slice.)  The
+    router in ``params`` is the layer's own either way."""
+    c = config
+    B, S, D = x.shape
+    T = B * S
+    E, K = c.n_experts, c.top_k
+    dt = c.dtype
+    xt = x.reshape(T, D).astype(dt)
+
+    probs, gate_vals, expert_idx = _route(xt, params["router"], K,
+                                          c.norm_topk)
+    flat = expert_idx.reshape(T * K)
+    if valid is not None:
+        valid = jnp.broadcast_to(valid, (B, S)).reshape(T)
+        # behind every group: sorted last, counted by no expert
+        flat = jnp.where(jnp.repeat(valid, K), flat, E)
+    order = jnp.argsort(flat)            # stable: a token's K stay in order
+    expert_rows = jnp.sum(
+        flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+
+    w_gate, w_up, w_down = (params[k].astype(dt)
+                            for k in ("w_gate", "w_up", "w_down"))
+    group_sizes = expert_rows
+    if layer_index is not None:
+        L = w_gate.shape[0]
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * E,), jnp.int32), expert_rows,
+            (layer_index * E,))
+        w_gate, w_up, w_down = (w.reshape((L * E,) + w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+
+    def grouped(rows, w):
+        # f32 accumulation, as llama.matmul
+        return jax.lax.ragged_dot(rows, w, group_sizes,
+                                  preferred_element_type=jnp.float32)
+
+    rows = xt[order // K]                                  # (T*K, D)
+    act = jax.nn.silu(grouped(rows, w_gate).astype(dt)) \
+        * grouped(rows, w_up).astype(dt)
+    out = grouped(act, w_down)                             # (T*K, D) f32
+    # Un-sort (order is a permutation) and sum under the gates.  Rows
+    # past the last group were not computed: select, do not multiply.
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * K, dtype=order.dtype), unique_indices=True)
+    out = out[inverse].reshape(T, K, D)
+    out = jnp.sum(out * gate_vals[..., None], axis=1)
+    if valid is not None:
+        out = jnp.where(valid[:, None], out, 0.0)
+    return (out.reshape(B, S, D).astype(x.dtype),
+            _aux_loss(probs, expert_idx), expert_rows)
 
 
 def moe_ffn(x: jax.Array, params: PyTree, config: MoEConfig
             ) -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, D) → (out (B, S, D), aux_loss scalar).
+
+    The dense-dispatch formulation with a capacity, which DROPS tokens
+    past it.  It stays only as what a mesh with ``expert > 1`` uses in
+    training: its sharding constraint on the expert dimension is what
+    lowers to the all-to-all.  No serving path and no benchmark cell
+    runs it; everything else routes through ``moe_ffn_dropless``.
 
     aux_loss is the Switch load-balancing loss (mean fraction of
     tokens per expert × mean router prob per expert × E); add it to
@@ -100,7 +200,8 @@ def moe_ffn(x: jax.Array, params: PyTree, config: MoEConfig
     dt = c.dtype
     xt = x.reshape(T, D).astype(dt)
 
-    probs, gate_vals, expert_idx = _route(xt, params["router"], K)
+    probs, gate_vals, expert_idx = _route(xt, params["router"], K,
+                                          c.norm_topk)
 
     capacity = int(max(1, round(T * K / E * c.capacity_factor)))
 
@@ -141,12 +242,8 @@ def moe_ffn(x: jax.Array, params: PyTree, config: MoEConfig
 
     out = _einsum("tec,ecd->td", combine.astype(dt), expert_out)
 
-    # Switch aux loss: E * mean_e(frac_tokens_e * mean_prob_e).
-    top1 = jax.nn.one_hot(expert_idx[:, 0], E, dtype=jnp.float32)
-    frac_tokens = top1.mean(0)
-    mean_prob = probs.mean(0)
-    aux = E * jnp.sum(frac_tokens * mean_prob)
-    return out.reshape(B, S, D).astype(x.dtype), aux
+    return out.reshape(B, S, D).astype(x.dtype), \
+        _aux_loss(probs, expert_idx)
 
 
 def moe_ffn_reference(x: jax.Array, params: PyTree, config: MoEConfig
@@ -159,7 +256,7 @@ def moe_ffn_reference(x: jax.Array, params: PyTree, config: MoEConfig
     dt = c.dtype
     xt = x.reshape(-1, D).astype(dt)
     _probs, gate_vals, expert_idx = _route(xt, params["router"],
-                                           c.top_k)
+                                           c.top_k, c.norm_topk)
 
     def per_expert(e):
         h = xt.astype(dt) @ params["w_gate"][e].astype(dt)

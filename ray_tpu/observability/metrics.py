@@ -433,6 +433,25 @@ def serve_engine_counters():
             boundaries=[0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                         5.0, 10.0, 30.0],
             tag_keys=("deployment",)),
+        # A model with experts only (a dense one never touches these).
+        "moe_expert_rows": Counter(
+            "ray_tpu_serve_moe_expert_rows_total",
+            "(token, expert) assignments the grouped matmuls computed, "
+            "summed over layers; padding and inactive rows take none",
+            tag_keys=("deployment", "program")),
+        "moe_experts_touched": Counter(
+            "ray_tpu_serve_moe_experts_touched_total",
+            "(step, layer, expert) triples in which the expert had a "
+            "row, i.e. its three matrices had to be read",
+            tag_keys=("deployment", "program")),
+        "moe_load_imbalance": Histogram(
+            "ray_tpu_serve_moe_expert_load_imbalance",
+            "per chunk or prefill group: rows of the busiest expert of "
+            "a layer / mean rows per expert of that layer, worst layer "
+            "(1 = even)",
+            boundaries=[1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0,
+                        32.0, 64.0],
+            tag_keys=("deployment", "program")),
     })
 
 

@@ -220,6 +220,21 @@ def _attend_group(slots: int, slot_bytes: int) -> int:
     return group
 
 
+def _expert_load(expert_rows):
+    """What a device program hands back about its experts, read at the
+    harvest that exists: ``expert_rows`` (..., L, E) int32, the rows each
+    layer's experts computed, per step of a chunk or for a prefill group
+    -> (the (L, E) histogram summed over the steps, the number of
+    (step, layer, expert) triples that had a row).  ``()`` for a dense
+    model, whose programs return nothing more than they did."""
+    if expert_rows is None:
+        return ()
+    import jax.numpy as jnp
+
+    rows = expert_rows.reshape((-1,) + expert_rows.shape[-2:])
+    return (rows.sum(0), jnp.sum(rows > 0, dtype=jnp.int32))
+
+
 class LLMServer:
     """Deployment body: ``serve.run(serve.deployment(LLMServer).bind())``.
 
@@ -383,11 +398,11 @@ class LLMServer:
         cfg = self.cfg
 
         def prefill(params, cache, tokens, lengths, slots):
-            last_logits, ks, vs = llama.prefill_forward(
-                params, tokens, lengths, cfg)
+            last_logits, ks, vs, rows = llama.prefill_forward(
+                params, tokens, lengths, cfg, return_expert_rows=True)
             cache = llama.insert_prefill(cache, ks, vs, slots)
             first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            return cache, first
+            return cache, first, _expert_load(rows)
 
         def decode_k(params, cache, tok_dev, len_dev,
                      ov_tok, ov_len, ov_mask, active, k, s_active):
@@ -395,10 +410,11 @@ class LLMServer:
             lens = jnp.where(ov_mask, ov_len, len_dev)
             step = self._make_decode_step(params, s_active, active,
                                           llama, jax, jnp)
-            (ck, cv, tok, lens), toks = jax.lax.scan(
+            (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
                 step, (cache["k"], cache["v"], tok, lens), None,
                 length=k)
-            return {"k": ck, "v": cv}, toks, tok, lens
+            return {"k": ck, "v": cv}, toks, tok, lens, \
+                _expert_load(rows)
 
         self._prefill = jax.jit(prefill, donate_argnums=(1,))
         # tok_dev/len_dev (args 2, 3) are always overwritten by the
@@ -420,8 +436,13 @@ class LLMServer:
         gathered index == absolute position, which is what keeps the
         two planes' tokens bit-identical.  ``cfg`` overrides the
         target config (the speculative DRAFT model reuses this step on
-        its own dense cache)."""
+        its own dense cache).  The step's ys are ``(tokens, expert
+        rows)``: the (L, E) rows each layer's experts computed, None for
+        a dense model.  Experts compute ``active`` slots only, and read
+        their ``[L, E, ...]`` matrices in place (the stacks are closed
+        over, not sliced by the layer scan)."""
         cfg = cfg or self.cfg
+        sliced, stacks = llama.split_expert_stacks(params["layers"], cfg)
 
         def step(carry, _):
             ck, cv, tok, lens = carry
@@ -477,13 +498,14 @@ class LLMServer:
                     vv[:, 0].astype(cv.dtype), mode="drop",
                     indices_are_sorted=True, unique_indices=True)
                 attn = attend(q, ck, cv, l)
-                x = llama._attn_out_mlp(x, attn, layer, cfg)
-                return (x, ck, cv), None
+                x, _aux, expert_rows = llama.attn_out_ffn(
+                    x, attn, {**layer, **stacks}, cfg,
+                    valid=active[:, None], layer_index=l)
+                return (x, ck, cv), expert_rows
 
-            (x, ck, cv), _ = jax.lax.scan(
+            (x, ck, cv), expert_rows = jax.lax.scan(
                 body, (x, ck, cv),
-                (params["layers"],
-                 jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+                (sliced, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
             x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
             head = (params["embed_tokens"].astype(cfg.dtype).T
                     if cfg.tie_embeddings
@@ -492,7 +514,7 @@ class LLMServer:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt = jnp.where(active, nxt, tok)
             lens = lens + active.astype(jnp.int32)
-            return (ck, cv, nxt, lens), nxt
+            return (ck, cv, nxt, lens), (nxt, expert_rows)
 
         return step
 
@@ -604,8 +626,8 @@ class LLMServer:
         def prefill_cold(params, pool, tokens, lengths, write_bt):
             # Same computation as the dense plane's prefill (bit-equal
             # first tokens + K/V rows); only the insert differs.
-            last_logits, ks, vs = llama.prefill_forward(
-                params, tokens, lengths, cfg)
+            last_logits, ks, vs, rows = llama.prefill_forward(
+                params, tokens, lengths, cfg, return_expert_rows=True)
             nw = write_bt.shape[1]
             flat = write_bt.reshape(-1)
             pool = {
@@ -616,7 +638,7 @@ class LLMServer:
                              rows_to_blocks(pad_rows(vs, nw), nw)),
             }
             first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            return pool, first
+            return pool, first, _expert_load(rows)
 
         def prefill_warm(params, pool, tokens, lengths, pos0,
                          prefix_bt, write_bt):
@@ -641,9 +663,13 @@ class LLMServer:
                 [prefix_pos[None, :] < pos0[:, None],
                  jnp.ones((G, P), bool)], axis=1)
             scale = cfg.head_dim ** -0.5
+            valid = jnp.arange(P, dtype=jnp.int32)[None, :] \
+                < lengths[:, None]
+            sliced, stacks = llama.split_expert_stacks(
+                params["layers"], cfg)
 
             def body(x, layer_and_prefix):
-                layer, ckp_l, cvp_l = layer_and_prefix
+                layer, l, ckp_l, cvp_l = layer_and_prefix
                 q, k, v = llama._qkv_rope(x, layer, sin, cos, cfg)
                 keys = jnp.concatenate(
                     [ckp_l, k.astype(ckp_l.dtype)], axis=1)
@@ -652,11 +678,15 @@ class LLMServer:
                 attn = _masked_attend(q, keys, vals, positions,
                                       key_abs, key_valid, scale, jnp,
                                       jax)
-                x = llama._attn_out_mlp(x, attn, layer, cfg)
-                return x, (k, v)
+                x, _aux, rows = llama.attn_out_ffn(
+                    x, attn, {**layer, **stacks}, cfg, valid=valid,
+                    layer_index=l)
+                return x, (k, v, rows)
 
-            x, (ks, vs) = jax.lax.scan(body, x,
-                                       (params["layers"], ckp, cvp))
+            x, (ks, vs, rows) = jax.lax.scan(
+                body, x,
+                (sliced, jnp.arange(cfg.n_layers, dtype=jnp.int32),
+                 ckp, cvp))
             x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
             last = jnp.take_along_axis(
                 x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
@@ -674,7 +704,7 @@ class LLMServer:
                 **set_blocks(pool, "v", flat,
                              rows_to_blocks(pad_rows(vs, nw), nw)),
             }
-            return pool, first
+            return pool, first, _expert_load(rows)
 
         def decode_paged(params, pool, tok_dev, len_dev, ov_tok,
                          ov_len, ov_mask, active, bt, k):
@@ -685,11 +715,11 @@ class LLMServer:
             cv = gather(pool, "v", bt)
             step = self._make_decode_step(params, nb * bs, active,
                                           llama, jax, jnp)
-            (ck, cv, tok, lens), toks = jax.lax.scan(
+            (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
                 step, (ck, cv, tok, lens), None, length=k)
             pool = {**pool, **scatter(pool, "k", bt, ck),
                     **scatter(pool, "v", bt, cv)}
-            return pool, toks, tok, lens
+            return pool, toks, tok, lens, _expert_load(rows)
 
         def inject(pool, kb, vb, dest):
             # Handoff blocks arrive FULL PRECISION (the prefill side
@@ -721,9 +751,11 @@ class LLMServer:
             written = onehot.any(axis=1)[:, :, None, None]
             proj = onehot.astype(dt)
             scale = cfg.head_dim ** -0.5
+            sliced, stacks = llama.split_expert_stacks(
+                params["layers"], cfg)
 
             def body(x, layer_and_cache):
-                layer, ck_l, cv_l = layer_and_cache
+                layer, l, ck_l, cv_l = layer_and_cache
                 q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg)
                 # One-hot projection places the T fresh rows at their
                 # absolute positions (like insert_prefill, scatters
@@ -736,11 +768,15 @@ class LLMServer:
                                  cv_l)
                 attn = llama._cache_attend(q, ck_l, cv_l, positions,
                                            scale)
-                x = llama._attn_out_mlp(x, attn, layer, cfg)
+                x, _aux, _rows = llama.attn_out_ffn(
+                    x, attn, {**layer, **stacks}, cfg,
+                    valid=active[:, None], layer_index=l)
                 return x, (ck_l, cv_l)
 
-            x, (ck, cv) = jax.lax.scan(lambda x, i: body(x, i), x,
-                                       (params["layers"], ck, cv))
+            x, (ck, cv) = jax.lax.scan(
+                body, x,
+                (sliced, jnp.arange(cfg.n_layers, dtype=jnp.int32),
+                 ck, cv))
             x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
             head = (params["embed_tokens"].astype(dt).T
                     if cfg.tie_embeddings
@@ -837,7 +873,7 @@ class LLMServer:
                           s_active):
             step = self._make_decode_step(params, s_active, active,
                                           llama, jax, jnp, cfg=dcfg)
-            (ck, cv, tok, pos), toks = jax.lax.scan(
+            (ck, cv, tok, pos), (toks, _rows) = jax.lax.scan(
                 step, (cache["k"], cache["v"], tok, pos), None,
                 length=k)
             return {"k": ck, "v": cv}, toks
@@ -864,16 +900,16 @@ class LLMServer:
                     nw = -(-bucket // bs)
                     pad_bt = jnp.full((g, nw), self._pad_block,
                                       jnp.int32)  # all writes dropped
-                    self.pool, _f = self._prefill_cold(
+                    self.pool, _f, _m = self._prefill_cold(
                         self.params, self.pool, toks, lengths, pad_bt)
                     pre = jnp.full((g, self._np_max), self._pad_block,
                                    jnp.int32)
-                    self.pool, _f = self._prefill_warm(
+                    self.pool, _f, _m = self._prefill_warm(
                         self.params, self.pool, toks, lengths,
                         jnp.zeros(g, jnp.int32), pre, pad_bt)
                 else:
                     slots = jnp.full(g, -1, jnp.int32)  # writes nothing
-                    self.cache, _first = self._prefill(
+                    self.cache, _first, _m = self._prefill(
                         self.params, self.cache, toks, lengths, slots)
                 if self.spec_k:
                     self.draft_cache = self._draft_prefill(
@@ -896,7 +932,7 @@ class LLMServer:
                         jnp.zeros((self.max_slots, self.spec_k),
                                   jnp.int32), active, bt)
                 else:
-                    self.pool, _t, self._tok_dev, self._len_dev = \
+                    self.pool, _t, self._tok_dev, self._len_dev, _m = \
                         self._decode_paged(
                             self.params, self.pool, self._tok_dev,
                             self._len_dev, ov, ov, ovm, active, bt,
@@ -915,7 +951,7 @@ class LLMServer:
             jax.block_until_ready(self.pool["k"])
         else:
             for sa in self.decode_buckets:
-                self.cache, _t, self._tok_dev, self._len_dev = \
+                self.cache, _t, self._tok_dev, self._len_dev, _m = \
                     self._decode_k(self.params, self.cache,
                                    self._tok_dev, self._len_dev, ov,
                                    ov, ovm, active,
@@ -1277,7 +1313,9 @@ class LLMServer:
 
     def _launch_prefill_group(self, g, bucket, warm, group, jnp):
         toks = np.zeros((g, bucket), np.int32)
-        lens = np.ones(g, np.int32)
+        # rows past the group's members are padding: length 0, so that
+        # no position of theirs is real (experts compute real ones only)
+        lens = np.zeros(g, np.int32)
         members = []
         if not self.paged:
             slots = np.full(g, -1, np.int32)
@@ -1289,11 +1327,11 @@ class LLMServer:
                 members.append((j, slot, req))
             t0 = time.perf_counter()
             with _device.annotation("serve.prefill"):
-                self.cache, first = self._prefill(
+                self.cache, first, load = self._prefill(
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(lens), jnp.asarray(slots))
             self._prefill_launched(first, members, t0, bucket, g,
-                                   int(lens[:len(group)].sum()))
+                                   int(lens[:len(group)].sum()), load)
             return
         bs = self.block_size
         nw = -(-bucket // bs)
@@ -1316,12 +1354,12 @@ class LLMServer:
         t0 = time.perf_counter()
         with _device.annotation("serve.prefill"):
             if warm:
-                self.pool, first = self._prefill_warm(
+                self.pool, first, load = self._prefill_warm(
                     self.params, self.pool, jnp.asarray(toks),
                     jnp.asarray(lens), jnp.asarray(pos0s),
                     jnp.asarray(pre_bt), jnp.asarray(write_bt))
             else:
-                self.pool, first = self._prefill_cold(
+                self.pool, first, load = self._prefill_cold(
                     self.params, self.pool, jnp.asarray(toks),
                     jnp.asarray(lens), jnp.asarray(write_bt))
         if self.spec_k:
@@ -1335,7 +1373,7 @@ class LLMServer:
             fb = self._bucket(max(len(req.prompt)
                                   for _s, req, _p in group))
             dtoks = np.zeros((g, fb), np.int32)
-            dlens = np.ones(g, np.int32)
+            dlens = np.zeros(g, np.int32)
             dslots = np.full(g, -1, np.int32)
             for j, (slot, req, _pos0) in enumerate(group):
                 P = len(req.prompt)
@@ -1347,22 +1385,24 @@ class LLMServer:
                 jnp.asarray(dtoks), jnp.asarray(dlens),
                 jnp.asarray(dslots))
         self._prefill_launched(first, members, t0, bucket, g,
-                               int(lens[:len(group)].sum()))
+                               int(lens[:len(group)].sum()), load)
 
-    def _prefill_launched(self, first, members, t0, bucket, g, n_tok):
+    def _prefill_launched(self, first, members, t0, bucket, g, n_tok,
+                          load):
         """After the (async) launch: stamp the group's requests and
         queue it for _harvest_prefills.  ``n_tok``: prompt positions the
-        group was asked to compute (suffixes only, on a warm group)."""
+        group was asked to compute (suffixes only, on a warm group);
+        ``load``: the program's expert load, still on the device."""
         for _j, _slot, req in members:
             req.t_prefill_launched = t0
             req.prefill_shape = (bucket, g)
         self._pending_prefills.append(
-            (first, members, t0, bucket, g, n_tok))
+            (first, members, t0, bucket, g, n_tok, load))
 
     def _harvest_prefills(self):
         """Materialize queued prefill first-tokens into request streams
         and decode overrides."""
-        for first, members, t0, bucket, g, n_tok in \
+        for first, members, t0, bucket, g, n_tok, load in \
                 self._pending_prefills:
             first = np.asarray(first)
             now = time.perf_counter()
@@ -1395,7 +1435,7 @@ class LLMServer:
                 if len(req.tokens) >= req.max_new_tokens:
                     self._finish(slot)
             self._record_prefill_group(t0, now, bucket, g,
-                                       len(members), n_tok)
+                                       len(members), n_tok, load)
         self._pending_prefills.clear()
 
     def _extract_kv(self, req: _Request, table) -> None:
@@ -1780,7 +1820,7 @@ class LLMServer:
                 blocks = self.slot_table[s].blocks[:nb]
                 bt[s, :len(blocks)] = blocks
             with _device.annotation("serve.decode_chunk"):
-                self.pool, toks, self._tok_dev, self._len_dev = \
+                self.pool, toks, self._tok_dev, self._len_dev, load = \
                     self._decode_paged(self.params, self.pool,
                                        self._tok_dev, self._len_dev,
                                        *ov_args, jnp.asarray(bt),
@@ -1789,7 +1829,7 @@ class LLMServer:
         else:
             sa = self._decode_bucket()
             with _device.annotation("serve.decode_chunk"):
-                self.cache, toks, self._tok_dev, self._len_dev = \
+                self.cache, toks, self._tok_dev, self._len_dev, load = \
                     self._decode_k(self.params, self.cache,
                                    self._tok_dev, self._len_dev,
                                    *ov_args, k=int(k),
@@ -1800,13 +1840,13 @@ class LLMServer:
         # What the chunk was launched over (serve.chunk's args).
         info = (len(snapshot), int(self.slot_waiting.sum()),
                 len(self._backlog), int(sa))
-        return (toks, snapshot, k, t0, info)
+        return (toks, snapshot, k, t0, info, load)
 
     def _process(self, pending):
         """Materialize a finished chunk's tokens (blocks until the
         device call completes — by then the NEXT chunk is already
         queued) and route them to their requests."""
-        toks_dev, snapshot, k, t0, info = pending
+        toks_dev, snapshot, k, t0, info, load = pending
         # Declared sync boundary: this is THE pipeline's harvest
         # point — the next chunk is already dispatched, so blocking
         # here overlaps host routing with device compute.
@@ -1840,7 +1880,7 @@ class LLMServer:
                 req.harvests.append((now, len(req.tokens)))
             if finished:
                 self._finish(slot)
-        self._record_chunk(t0, now, k, info, kept)
+        self._record_chunk(t0, now, k, info, kept, load)
 
     # ------------------------------------ spans and counters (off-launch)
     def _span(self, name: str, t0: float, t1: float,
@@ -1902,11 +1942,33 @@ class LLMServer:
             if end is None:
                 break
 
+    def _expert_attrs(self, load: tuple, program: str) -> Dict[str, int]:
+        """A device program's expert load (``_expert_load``), read where
+        its tokens were just read (the program is done: no new sync), as
+        span attributes and the ``ray_tpu_serve_moe_*`` series.  Nothing
+        for a dense model."""
+        if not load:
+            return {}
+        rows = np.asarray(load[0])                       # (L, E)
+        attrs = {"expert_rows": int(rows.sum()),
+                 "expert_rows_max": int(rows.max()),
+                 "experts_touched": int(load[1])}
+        m = self._engine_metrics
+        tags = {**self._tags, "program": program}
+        m["moe_expert_rows"].inc(attrs["expert_rows"], tags=tags)
+        m["moe_experts_touched"].inc(attrs["experts_touched"], tags=tags)
+        if attrs["expert_rows"]:
+            m["moe_load_imbalance"].observe(
+                float((rows.max(1) / np.maximum(rows.mean(1), 1e-9)).max()),
+                tags=tags)
+        return attrs
+
     def _record_chunk(self, t0: float, t1: float, k: int, info: tuple,
-                      kept: int) -> None:
+                      kept: int, load: tuple = ()) -> None:
         """``serve.chunk`` (launch -> harvest returned) and the decode
         counters: token-steps computed (k x max_slots, whatever is
-        occupied) against tokens kept (appended to a live request)."""
+        occupied) against tokens kept (appended to a live request); for
+        a model with experts, the rows they computed."""
         if not _tracing.enabled():
             return
         computed = k * self.max_slots
@@ -1917,14 +1979,17 @@ class LLMServer:
         self._span("serve.chunk", t0, t1, {
             "k": k, "active": active, "waiting": waiting,
             "backlog": backlog, "s_active": s_active,
-            "tokens_kept": kept, "token_steps": computed},
+            "tokens_kept": kept, "token_steps": computed,
+            **self._expert_attrs(load, "decode")},
             f"{self._lane}/chunks")
 
     def _record_prefill_group(self, t0: float, t1: float, bucket: int,
-                              rows: int, real: int, tokens: int) -> None:
+                              rows: int, real: int, tokens: int,
+                              load: tuple = ()) -> None:
         """``serve.prefill_group`` (launch -> harvest) and the prefill
         counters: prompt tokens against the rows x bucket positions the
-        padded group computed."""
+        padded group computed; for a model with experts, the rows they
+        computed."""
         if not _tracing.enabled():
             return
         computed = rows * bucket
@@ -1933,7 +1998,8 @@ class LLMServer:
         m["prefill_padded_tokens"].inc(computed, tags=self._tags)
         self._span("serve.prefill_group", t0, t1, {
             "bucket": bucket, "rows": real, "rows_padded": rows,
-            "prompt_tokens": tokens, "token_positions": computed},
+            "prompt_tokens": tokens, "token_positions": computed,
+            **self._expert_attrs(load, "prefill")},
             f"{self._lane}/prefills")
 
     # ----------------------------------------- disaggregation (KV handoff)
