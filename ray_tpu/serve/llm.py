@@ -98,13 +98,39 @@ from ..observability import device as _device
 from ..observability import timeline as _timeline
 from ..observability import tracing as _tracing
 
-# Prefill group sizes (prompts per call, padded with slot=-1).  Each
-# call costs a device round trip serialized against decode chunks, so
-# saturated admission batches at the widest size; a light wave takes the
-# smallest size that fits (a padded group computes ALL its rows, so a
-# 1-request wave through a 32-wide group would pay 32 prompts of
-# latency).  Each size × prompt bucket is one compile, warmed at init.
-PREFILL_GROUPS = (4, 32)
+# Prefill row ladder: a wave's prompts are prefilled in padded groups of
+# ``rows x bucket`` positions, rows from this ladder, bucket from the
+# engine's ``prefill_buckets``; each pair is one program, warmed at init
+# (``prefill_shapes``).  A padded group computes ALL its positions, and
+# launches are asynchronous (the device is never idle between them: PERF.md
+# section 5), so what a wave costs is the positions it computes plus what
+# every launch pays whatever its size; ``cut_prefill_wave`` picks the
+# groups that make that least.  Measured on a v5e (PERF.md section 6,
+# PR 27), which is why the ladder is not every power of two: a program
+# costs ~0.45 s of every engine start, a 2-row program takes 0.65-0.95 of
+# the 4-row one's time (``insert_prefill`` copies the cache's first
+# ``bucket`` positions whenever a group has two rows or more; a 1-row
+# group is written in place), and nothing above 8 rows saved device time
+# on any cell's waves.
+PREFILL_GROUPS = (1, 4, 8)
+
+# The most positions a group of more than one row computes.  From about
+# here up a launch is compute-bound (8 x 256: 23 us a position; 8 x 1,024:
+# 27), so a wider group saves only its share of a launch, while its
+# program's scratch -- that copy of the cache -- is what bounds a dense
+# engine's slot count (PERF.md section 4).
+_GROUP_POSITIONS = 2048
+
+# What one prefill launch costs whatever its size, in positions: a pass
+# over the weights and, from two rows up, ``insert_prefill``'s pass over
+# the cache.  Measured on a v5e (PERF.md section 6, PR 27): the warmed
+# programs of the three serve cells, timed alone, fit launch + positions
+# x 25-28 us with a launch of 178 (40 slots x 1,280), 362 (120 slots x
+# 512) and 725 positions (the same cache under 7 GB of experts).
+# Replaying the cells' waves against those measured times, any constant
+# from 150 to 600 came within 7% of the cut that knows every program's
+# time; 300 is the least bad for the three together.
+_LAUNCH_POSITIONS = 300
 
 # A decode step's attention reads one layer's attended K and V for a
 # GROUP of slots at a time.  XLA does not read a slice of the stacked
@@ -210,6 +236,67 @@ class _Request:
                 pass
 
 
+def _bucket_for(n: int, buckets: Tuple[int, ...]) -> int:
+    """The smallest of the ascending ``buckets`` that holds ``n``."""
+    for b in buckets:
+        if n <= b:
+            return b
+    raise ValueError(n)
+
+
+def _group_fits(rows: int, bucket: int, rungs: Tuple[int, ...]) -> bool:
+    """The least rung carries any bucket (every prompt has to be
+    prefilled); more rows only up to ``_GROUP_POSITIONS`` positions."""
+    return rows == rungs[0] or rows * bucket <= _GROUP_POSITIONS
+
+
+def prefill_shapes(rungs: Tuple[int, ...], buckets: Tuple[int, ...],
+                   max_slots: int) -> List[Tuple[int, int]]:
+    """Every ``(rows, bucket)`` a wave's cut can come out with, which is
+    what warm-up compiles (``rungs`` and ``buckets`` ascending).  A wave
+    holds at most ``max_slots`` prompts, so no rung past the first that
+    covers them."""
+    top = next((r for r in rungs if r >= max_slots), rungs[-1])
+    return [(r, b) for r in rungs if r <= top for b in buckets
+            if _group_fits(r, b, rungs)]
+
+
+def cut_prefill_wave(lengths: List[int], buckets: Tuple[int, ...],
+                     rungs: Tuple[int, ...]
+                     ) -> List[Tuple[int, int, List[int]]]:
+    """Cut one wave into the padded groups that compute the fewest
+    positions.  ``lengths[i]``: positions entry ``i`` has to prefill (each
+    at most the largest bucket); ``buckets`` and ``rungs``: ascending.
+    -> ``[(rows, bucket, [i, ...])]``, shortest members first, every
+    ``(rows, bucket)`` one of ``prefill_shapes``.
+
+    Entries sorted by length are cut into runs; a run of m takes the
+    smallest rung >= m and the bucket of its longest member (a shorter
+    prompt may ride a longer bucket's spare row), and costs rows x bucket
+    + ``_LAUNCH_POSITIONS``.  The least total over all cuts, by a table
+    over the sorted prefix: O(n x largest rung), pure host arithmetic."""
+    order = sorted(range(len(lengths)), key=lengths.__getitem__)
+    bucket_of = [_bucket_for(lengths[i], buckets) for i in order]
+    # rung_for[m]: the smallest rung holding m members
+    rung_for = [0] + [next(r for r in rungs if r >= m)
+                      for m in range(1, rungs[-1] + 1)]
+    best = [0] * (len(order) + 1)    # least cost of the first i entries
+    last = [0] * (len(order) + 1)    # members of the run that ends at i
+    for i in range(1, len(order) + 1):
+        bucket = bucket_of[i - 1]
+        best[i], last[i] = min(
+            (best[i - m] + rung_for[m] * bucket + _LAUNCH_POSITIONS, m)
+            for m in range(1, min(i, rungs[-1]) + 1)
+            if _group_fits(rung_for[m], bucket, rungs))
+    groups = []
+    i = len(order)
+    while i:
+        m = last[i]
+        groups.append((rung_for[m], bucket_of[i - 1], order[i - m:i]))
+        i -= m
+    return groups[::-1]
+
+
 def _attend_group(slots: int, slot_bytes: int) -> int:
     """Slots a decode step attends at a time: the most, dividing the
     slot count, whose attended K (or V) of one layer fits
@@ -309,8 +396,8 @@ class LLMServer:
         self._tags = {"deployment": serve_deployment or "llm"}
         self._lane = f"llm:{serve_deployment or 'llm'}"
         self._timeline_pid: Optional[str] = None
-        # Prefill group ladder (compile-matrix knob: each size × bucket
-        # × {cold, warm} is one warmed compile).
+        # Prefill row ladder (each rung × bucket × {cold, warm} the cut
+        # can emit is one warmed compile: prefill_shapes).
         self.prefill_groups = tuple(sorted(
             prefill_groups or PREFILL_GROUPS))
         # Attended-prefix buckets: powers of two from the smallest
@@ -891,30 +978,34 @@ class LLMServer:
         import jax
 
         jnp = self._jnp
-        for g in self.prefill_groups:
-            lengths = jnp.ones(g, jnp.int32)
-            for bucket in self.buckets:
-                toks = jnp.zeros((g, bucket), jnp.int32)
-                if self.paged:
-                    bs = self.block_size
-                    nw = -(-bucket // bs)
-                    pad_bt = jnp.full((g, nw), self._pad_block,
-                                      jnp.int32)  # all writes dropped
-                    self.pool, _f, _m = self._prefill_cold(
-                        self.params, self.pool, toks, lengths, pad_bt)
-                    pre = jnp.full((g, self._np_max), self._pad_block,
-                                   jnp.int32)
-                    self.pool, _f, _m = self._prefill_warm(
-                        self.params, self.pool, toks, lengths,
-                        jnp.zeros(g, jnp.int32), pre, pad_bt)
-                else:
-                    slots = jnp.full(g, -1, jnp.int32)  # writes nothing
-                    self.cache, _first, _m = self._prefill(
-                        self.params, self.cache, toks, lengths, slots)
-                if self.spec_k:
-                    self.draft_cache = self._draft_prefill(
-                        self.draft_params, self.draft_cache, toks,
-                        lengths, jnp.full(g, -1, jnp.int32))
+
+        def filled(shape, value):
+            # from the host, as a launch's inputs are: a jnp.full of each
+            # new shape would be one more program to fetch
+            return jnp.asarray(np.full(shape, value, np.int32))
+
+        for g, bucket in prefill_shapes(self.prefill_groups, self.buckets,
+                                        self.max_slots):
+            lengths = filled(g, 1)
+            toks = filled((g, bucket), 0)
+            if self.paged:
+                bs = self.block_size
+                nw = -(-bucket // bs)
+                pad_bt = filled((g, nw), self._pad_block)  # writes dropped
+                self.pool, _f, _m = self._prefill_cold(
+                    self.params, self.pool, toks, lengths, pad_bt)
+                pre = filled((g, self._np_max), self._pad_block)
+                self.pool, _f, _m = self._prefill_warm(
+                    self.params, self.pool, toks, lengths, filled(g, 0),
+                    pre, pad_bt)
+            else:
+                slots = filled(g, -1)  # writes nothing
+                self.cache, _first, _m = self._prefill(
+                    self.params, self.cache, toks, lengths, slots)
+            if self.spec_k:
+                self.draft_cache = self._draft_prefill(
+                    self.draft_params, self.draft_cache, toks,
+                    lengths, filled(g, -1))
         active = jnp.zeros(self.max_slots, bool)  # no-op decode
         ov = jnp.zeros(self.max_slots, jnp.int32)
         ovm = jnp.zeros(self.max_slots, bool)
@@ -1035,10 +1126,7 @@ class LLMServer:
 
     # ---------------------------------------------------------- scheduler
     def _bucket(self, n: int) -> int:
-        for b in self.buckets:
-            if n <= b:
-                return b
-        raise ValueError(n)
+        return _bucket_for(n, self.buckets)
 
     def _decode_bucket(self) -> int:
         """Smallest attended-prefix bucket covering every active slot's
@@ -1163,17 +1251,17 @@ class LLMServer:
         self._backlog = keep
 
     def _admit_wave(self):
-        """Move backlog requests into free slots: one prefill call per
-        (padded) group of PREFILL_GROUP same-shape prompts.  The calls
-        are launched async (they queue behind the in-flight chunk) and
-        their first tokens are harvested in a later _process."""
+        """Move backlog requests into free slots and launch the wave's
+        prefills, cut into padded groups by ``_launch_prefills``.  The
+        calls are launched async (they queue behind the in-flight chunk)
+        and their first tokens are harvested in a later _process."""
         self._drain_queue()
         self._admission_pass()
         if not self._backlog:
             return
         free = [s for s in range(self.max_slots)
                 if self.slot_req[s] is None]
-        wave: List[tuple] = []  # (slot, req, bucket, pos0)
+        wave: List[tuple] = []  # (slot, req, positions, pos0)
         while free and self._backlog:
             req = self._backlog[0]
             slot = free[0]
@@ -1216,7 +1304,7 @@ class LLMServer:
             self._bind(slot, req)
             self.slot_len[slot] = P
             self.slot_waiting[slot] = True
-            return (slot, req, self._bucket(P), 0)
+            return (slot, req, P, 0)
         from .kv_cache import BlockTable
 
         if req.preseed is not None:
@@ -1254,7 +1342,7 @@ class LLMServer:
         # at HARVEST, not here — a same-wave request hitting the trie
         # now could gather blocks whose prefill hasn't executed yet
         # (grouped prefills launch in arbitrary order within a wave).
-        return (slot, req, self._bucket(P - pos0), pos0)
+        return (slot, req, P - pos0, pos0)
 
     def _bind(self, slot: int, req: _Request) -> None:
         """``req`` takes ``slot``: its wait for a slot ends here."""
@@ -1292,24 +1380,32 @@ class LLMServer:
         self._ov_mask[slot] = True
 
     def _launch_prefills(self, wave: List[tuple]):
+        """``wave``: (slot, request, positions to prefill, pos0) per
+        entry.  The two paged prefill programs have different
+        signatures, so warm entries (a prefix-cache hit: pos0 > 0, the
+        positions are the suffix) and cold ones are cut apart; dense
+        ignores pos0 entirely."""
         jnp = self._jnp
-        # Group by (bucket, warm?) — the two paged prefill programs
-        # have different signatures; dense ignores pos0 entirely.
-        by_shape: Dict[tuple, List[tuple]] = {}
-        for slot, req, bucket, pos0 in wave:
-            key = (bucket, self.paged and pos0 > 0)
-            by_shape.setdefault(key, []).append((slot, req, pos0))
-        for (bucket, warm), entries in by_shape.items():
-            i = 0
-            while i < len(entries):
-                rest = len(entries) - i
-                g = next((gg for gg in self.prefill_groups
-                          if gg >= rest),
-                         self.prefill_groups[-1])
-                group = entries[i:i + g]
-                i += g
-                self._launch_prefill_group(g, bucket, warm, group,
-                                           jnp)
+        for warm in (False, True):
+            entries = [e for e in wave
+                       if (self.paged and e[3] > 0) == warm]
+            for g, bucket, members in cut_prefill_wave(
+                    [n for _slot, _req, n, _pos0 in entries],
+                    self.buckets, self.prefill_groups):
+                self._launch_prefill_group(
+                    g, bucket, warm, [entries[i] for i in members], jnp)
+        if self.spec_k:
+            # The draft always prefills the FULL prompt (its dense
+            # cache is per-slot; prefix-cache hits only skip TARGET
+            # compute), so the wave is cut once more, by whole prompts.
+            # No prompt is longer than the largest bucket: generate()
+            # rejects those at ingress, and spec engines refuse
+            # decode_ingest (the only path that bypasses that guard).
+            for g, bucket, members in cut_prefill_wave(
+                    [len(req.prompt) for _slot, req, _n, _pos0 in wave],
+                    self.buckets, self.prefill_groups):
+                self._launch_draft_prefill(
+                    g, bucket, [wave[i] for i in members], jnp)
 
     def _launch_prefill_group(self, g, bucket, warm, group, jnp):
         toks = np.zeros((g, bucket), np.int32)
@@ -1319,7 +1415,7 @@ class LLMServer:
         members = []
         if not self.paged:
             slots = np.full(g, -1, np.int32)
-            for j, (slot, req, _pos0) in enumerate(group):
+            for j, (slot, req, _n, _pos0) in enumerate(group):
                 P = len(req.prompt)
                 toks[j, :P] = req.prompt
                 lens[j] = P
@@ -1338,7 +1434,7 @@ class LLMServer:
         write_bt = np.full((g, nw), self._pad_block, np.int32)
         pos0s = np.zeros(g, np.int32)
         pre_bt = np.full((g, self._np_max), self._pad_block, np.int32)
-        for j, (slot, req, pos0) in enumerate(group):
+        for j, (slot, req, _n, pos0) in enumerate(group):
             P = len(req.prompt)
             suffix = req.prompt[pos0:]
             toks[j, :len(suffix)] = suffix
@@ -1362,30 +1458,21 @@ class LLMServer:
                 self.pool, first, load = self._prefill_cold(
                     self.params, self.pool, jnp.asarray(toks),
                     jnp.asarray(lens), jnp.asarray(write_bt))
-        if self.spec_k:
-            # The draft always prefills the FULL prompt (its dense
-            # cache is per-slot; prefix-cache hits only skip TARGET
-            # compute) — so a warm target group still drafts cold.
-            # _bucket(full P) cannot raise here: generate() rejects
-            # prompts longer than the largest bucket at ingress, and
-            # spec engines refuse decode_ingest (the only prompt path
-            # that bypasses that guard).
-            fb = self._bucket(max(len(req.prompt)
-                                  for _s, req, _p in group))
-            dtoks = np.zeros((g, fb), np.int32)
-            dlens = np.zeros(g, np.int32)
-            dslots = np.full(g, -1, np.int32)
-            for j, (slot, req, _pos0) in enumerate(group):
-                P = len(req.prompt)
-                dtoks[j, :P] = req.prompt
-                dlens[j] = P
-                dslots[j] = slot
-            self.draft_cache = self._draft_prefill(
-                self.draft_params, self.draft_cache,
-                jnp.asarray(dtoks), jnp.asarray(dlens),
-                jnp.asarray(dslots))
         self._prefill_launched(first, members, t0, bucket, g,
                                int(lens[:len(group)].sum()), load)
+
+    def _launch_draft_prefill(self, g, bucket, group, jnp):
+        toks = np.zeros((g, bucket), np.int32)
+        lens = np.zeros(g, np.int32)
+        slots = np.full(g, -1, np.int32)
+        for j, (slot, req, _n, _pos0) in enumerate(group):
+            P = len(req.prompt)
+            toks[j, :P] = req.prompt
+            lens[j] = P
+            slots[j] = slot
+        self.draft_cache = self._draft_prefill(
+            self.draft_params, self.draft_cache, jnp.asarray(toks),
+            jnp.asarray(lens), jnp.asarray(slots))
 
     def _prefill_launched(self, first, members, t0, bucket, g, n_tok,
                           load):
