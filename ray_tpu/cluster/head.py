@@ -483,7 +483,7 @@ class HeadServer:
             "repl_heartbeat": self._repl_heartbeat,
             "repl_seed": self._repl_seed,  # raylint: disable=journaled-mutation -- full-snapshot re-seed: the state replaces the tables wholesale and is folded into a local snapshot + fresh WAL segment atomically
             "repl_events": self._repl_events,
-            "repl_status": self._repl_status,  # raylint: disable=rpc-protocol -- driven by tools/vcluster.py, bench.py and ops tooling (out of package)
+            "repl_status": self._repl_status,  # raylint: disable=rpc-protocol -- driven by tools/vcluster.py and ops tooling (out of package)
             "repl_control": self._repl_control,  # raylint: disable=rpc-protocol -- chaos/ops hook driven by tools/vcluster.py (partition_heads, detach_standby)
             "promote": self._promote_rpc,  # raylint: disable=rpc-protocol -- driven by tools/vcluster.py promote() and failover runbooks (out of package)
             "ping": lambda p: "pong",  # raylint: disable=rpc-protocol -- liveness probe for out-of-package callers (tests, ops tooling, vcluster)

@@ -71,9 +71,8 @@ class LlamaConfig:
     # overheads) at the cost of compile time.
     scan_unroll: int = 1
     # Flash-attention tile sizes (None = kernel default, currently
-    # 1024).  Exposed as a config knob so the MFU sweep
-    # (profile_mfu.py --attn-block) can tune them per chip/shape and
-    # the winner can be recorded on the preset.
+    # 1024).  Exposed as a config knob so that a sweep can tune them
+    # per chip/shape and the winner can be recorded on the preset.
     attn_block_q: Optional[int] = None
     attn_block_k: Optional[int] = None
     # >0 enables REAL pipeline parallelism when the active mesh has a
@@ -306,8 +305,7 @@ def _remat_policy(config: LlamaConfig):
     FFN activation ``silu(gate)*up`` next to the flash residuals —
     backward skips recomputing the two up-projection matmuls at
     +intermediate_size bf16/token of residual memory (the next sweep
-    point past "attn" when HBM headroom allows; profile_mfu.py
-    --remat-policy compares them)."""
+    point past "attn" when HBM headroom allows)."""
     if config.remat_policy not in REMAT_POLICIES:
         raise ValueError(
             f"unknown remat_policy {config.remat_policy!r} "
@@ -525,6 +523,14 @@ def _attn_out_mlp(x: jax.Array, attn: jax.Array,
     return attn_out_ffn(x, attn, layer, config)[0]
 
 
+def lm_head(params: PyTree, config: LlamaConfig) -> jax.Array:
+    """The (hidden, vocab) output matrix: the embedding transposed where
+    the config ties them.  The one place that choice is made."""
+    if config.tie_embeddings:
+        return params["embed_tokens"].astype(config.dtype).T
+    return params["lm_head"].astype(config.dtype)
+
+
 def decoder_layer(x: jax.Array, layer: Dict[str, jax.Array],
                   sin: jax.Array, cos: jax.Array, positions: jax.Array,
                   config: LlamaConfig,
@@ -629,11 +635,7 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
 
     with jax.named_scope("head_loss"):
         x = rms_norm(x, params["final_norm"], c.norm_eps)
-        if c.tie_embeddings:
-            head = params["embed_tokens"].astype(c.dtype).T
-        else:
-            head = params["lm_head"].astype(c.dtype)
-        logits = matmul(x, head)
+        logits = matmul(x, lm_head(params, c))
         logits = with_logical_constraint(logits, "batch", "seq",
                                          "vocab")
     if return_aux:
@@ -766,8 +768,7 @@ def make_train_step(config: LlamaConfig, optimizer=None,
     ``fused=True`` replaces the optax chain with the single-pass fused
     AdamW (``train/optim.py``): identical hyperparameters and clip
     semantics as ``default_optimizer()``, ~6 tree passes fewer of
-    param-sized HBM traffic in the optimizer slice of the step (the
-    ``profile_mfu.py`` ``opt_overhead_s`` phase measures it).  Loss
+    param-sized HBM traffic in the optimizer slice of the step.  Loss
     parity with the optax step is a tier-1 gate."""
     import optax
 
@@ -929,6 +930,60 @@ def dequantize_kv_blocks(stored: jax.Array, scale: jax.Array,
             * scale[..., None]).astype(out_dtype)
 
 
+def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
+               kv_step: Callable, positions: Optional[jax.Array] = None,
+               kv_layers: Any = None, valid: Optional[jax.Array] = None,
+               lengths: Optional[jax.Array] = None):
+    """The forward pass that serving shares: embed, rope table, a scan
+    over the layers of ``_qkv_rope`` -> ``kv_step`` -> ``attn_out_ffn``,
+    final norm, head.  What a decoder layer is made of lives here; the
+    callers differ only in ``kv_step``, what a layer does with its fresh
+    K/V rows and what its queries attend.
+
+    tokens: (B, S); positions: (B, S) absolute, each row's 0..S-1 when
+    left out.  ``kv_step(q, k, v, positions, kv_layer) -> (attn, ys)``:
+    q (B, S, Hq, D) and k, v (B, S, Hkv, D) roped, ``kv_layer`` this
+    layer's slice of ``kv_layers`` (leading dim L: a cache scanned a layer
+    at a time).  ``valid`` (broadcastable to (B, S)) marks the rows that
+    are real: experts compute no others, and read their ``[L, E, ...]``
+    matrices in place at the layer's index (the stacks are closed over,
+    not sliced by the scan).  With ``lengths`` (B,) the logits are those
+    of each row's last real position alone, (B, V), and ``valid``
+    defaults to position < length; without, (B, S, V).
+
+    Returns ``(logits, ys stacked over the layers, expert rows)``: the
+    (L, E) int32 rows each layer's experts computed, None for a dense
+    config."""
+    c = config
+    x = params["embed_tokens"].astype(c.dtype)[tokens]
+    if positions is None:
+        positions = jnp.broadcast_to(
+            jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
+            tokens.shape)
+    sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
+    if valid is None and lengths is not None:
+        valid = positions < lengths[:, None]
+    sliced, stacks = split_expert_stacks(params["layers"], c)
+
+    def body(x, layer_index_cache):
+        layer, l, kv_layer = layer_index_cache
+        q, k, v = _qkv_rope(x, layer, sin, cos, c)
+        attn, ys = kv_step(q, k, v, positions, kv_layer)
+        x, _aux, rows = attn_out_ffn(x, attn, {**layer, **stacks}, c,
+                                     valid=valid, layer_index=l)
+        return x, (ys, rows)
+
+    x, (ys, expert_rows) = jax.lax.scan(
+        body, x,
+        (sliced, jnp.arange(c.n_layers, dtype=jnp.int32), kv_layers))
+    x = rms_norm(x, params["final_norm"], c.norm_eps)
+    if lengths is None:
+        return matmul(x, lm_head(params, c)), ys, expert_rows
+    last = jnp.take_along_axis(
+        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)  # (B,1,H)
+    return matmul(last, lm_head(params, c))[:, 0], ys, expert_rows
+
+
 def prefill_forward(params: PyTree, tokens: jax.Array,
                     lengths: jax.Array, config: LlamaConfig,
                     return_expert_rows: bool = False):
@@ -948,32 +1003,11 @@ def prefill_forward(params: PyTree, tokens: jax.Array,
     length 0); ``return_expert_rows`` adds a fourth result, the (L, E)
     int32 rows each layer's experts computed (None for a dense
     config)."""
-    c = config
-    G, P = tokens.shape
-    dt = c.dtype
-    x = params["embed_tokens"].astype(dt)[tokens]
-    positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None, :],
-                                 (G, P))
-    sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
-    valid = positions < lengths[:, None]
-    sliced, stacks = split_expert_stacks(params["layers"], c)
+    def kv_step(q, k, v, positions, _cache):
+        return dot_attention(q, k, v, positions), (k, v)
 
-    def body(x, layer_and_index):
-        layer, l = layer_and_index
-        q, k, v = _qkv_rope(x, layer, sin, cos, c)
-        attn = dot_attention(q, k, v, positions)
-        x, _aux, rows = attn_out_ffn(x, attn, {**layer, **stacks}, c,
-                                     valid=valid, layer_index=l)
-        return x, (k, v, rows)
-
-    x, (ks, vs, expert_rows) = jax.lax.scan(
-        body, x, (sliced, jnp.arange(c.n_layers, dtype=jnp.int32)))
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)  # (G,1,H)
-    head = (params["embed_tokens"].astype(dt).T if c.tie_embeddings
-            else params["lm_head"].astype(dt))
-    last_logits = matmul(last, head)[:, 0]
+    last_logits, (ks, vs), expert_rows = layer_walk(
+        params, tokens, config, kv_step, lengths=lengths)
     if return_expert_rows:
         return last_logits, ks, vs, expert_rows
     return last_logits, ks, vs
@@ -1003,10 +1037,15 @@ def insert_prefill(cache: Dict[str, jax.Array], ks: jax.Array,
     return {"k": ins(cache["k"], ks), "v": ins(cache["v"], vs)}
 
 
-def _cache_attend(q, ck, cv, q_positions, scale):
+def _cache_attend(q, ck, cv, q_positions, scale, key_positions=None,
+                  key_valid=None):
     """q: (B, T, Hq, D); ck/cv: (B, S, Hkv, D); q_positions: (B, T).
     Causal against absolute cache positions: key j visible to query at
-    position p iff j <= p."""
+    position p iff j <= p.  With EXPLICIT ``key_positions`` /
+    ``key_valid`` (both (B, S)) a key's position is what they say, not
+    its index — the warm (prefix-hit) prefill attends [gathered prefix
+    blocks || suffix], where a key's gathered index no longer equals its
+    absolute position for the suffix half."""
     B, T, Hq, D = q.shape
     S = ck.shape[1]
     Hkv = ck.shape[2]
@@ -1014,9 +1053,14 @@ def _cache_attend(q, ck, cv, q_positions, scale):
     qg = q.reshape(B, T, Hkv, group, D)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck,
                         preferred_element_type=jnp.float32) * scale
-    key_pos = jnp.arange(S, dtype=jnp.int32)
-    mask = key_pos[None, None, None, None, :] <= \
-        q_positions[:, None, None, :, None]
+    if key_positions is None:
+        key_pos = jnp.arange(S, dtype=jnp.int32)
+        mask = key_pos[None, None, None, None, :] <= \
+            q_positions[:, None, None, :, None]
+    else:
+        mask = (key_valid[:, None, None, None, :]
+                & (key_positions[:, None, None, None, :]
+                   <= q_positions[:, None, None, :, None]))
     scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, cv,
@@ -1032,42 +1076,25 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
 
     tokens: (B, T) int32; positions: (B, T) absolute positions (a
     slot's current length .. +T-1).  Writes the new K/V into the cache
-    at those positions and returns (logits (B, T, V), new_cache).
-    T=prompt_bucket for prefill, T=1 for decode — each T compiles
-    once."""
-    c = config
-    B, T = tokens.shape
-    dt = c.dtype
-    x = params["embed_tokens"].astype(dt)[tokens]
-    sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
-    scale = c.head_dim ** -0.5
+    at those positions and returns (logits (B, T, V), new_cache).  No
+    program that serves runs it; tests hold T > 1 through a cache to the
+    reference with it."""
+    scale = config.head_dim ** -0.5
 
-    def body(x, layer_and_cache):
-        layer, ck_l, cv_l = layer_and_cache
-        q, k, v = _qkv_rope(x, layer, sin, cos, c)
+    # The T new K/V rows go into each slot's cache at its own positions
+    # (per-slot write offsets = data, shapes static).
+    def write(cache_bslice, rows, pos0):
+        return jax.lax.dynamic_update_slice(
+            cache_bslice, rows, (pos0, jnp.int32(0), jnp.int32(0)))
 
-        # Scatter the T new K/V rows into each slot's cache at its own
-        # positions (per-slot write offsets = data, shapes static).
-        def write(cache_bslice, rows, pos0):
-            return jax.lax.dynamic_update_slice(
-                cache_bslice, rows, (pos0, jnp.int32(0), jnp.int32(0)))
+    def kv_step(q, k, v, positions, cache_l):
+        ck_l, cv_l = cache_l
+        ck_l = jax.vmap(write)(ck_l, k.astype(ck_l.dtype), positions[:, 0])
+        cv_l = jax.vmap(write)(cv_l, v.astype(cv_l.dtype), positions[:, 0])
+        return (_cache_attend(q, ck_l, cv_l, positions, scale),
+                (ck_l, cv_l))
 
-        pos0 = positions[:, 0]
-        ck_l = jax.vmap(write)(ck_l, k.astype(ck_l.dtype), pos0)
-        cv_l = jax.vmap(write)(cv_l, v.astype(cv_l.dtype), pos0)
-
-        attn = _cache_attend(q, ck_l, cv_l, positions, scale)
-        x, _aux, _rows = attn_out_ffn(x, attn, layer, c)
-        return x, (ck_l, cv_l)
-
-    def scan_body(x, inputs):
-        x, new_cache = body(x, inputs)
-        return x, new_cache
-
-    x, (new_k, new_v) = jax.lax.scan(
-        scan_body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    head = (params["embed_tokens"].astype(dt).T if c.tie_embeddings
-            else params["lm_head"].astype(dt))
-    logits = matmul(x, head)
+    logits, (new_k, new_v), _rows = layer_walk(
+        params, tokens, config, kv_step, positions=positions,
+        kv_layers=(cache["k"], cache["v"]))
     return logits, {"k": new_k, "v": new_v}

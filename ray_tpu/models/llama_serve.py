@@ -1,0 +1,458 @@
+"""What the chip executes when a llama-family config is served.
+
+Every device program of ``serve/llm.py``'s scheduler is built here, one
+``build_*`` function each, from a ``LlamaConfig`` alone (the paged
+plane's from a ``BlockPool``: config, block size, block format): no
+engine, thread or weights are needed to lower one.  By the cache kept:
+
+- a per-slot cache ``(L, B, S, Hkv, D)`` (``llama.init_kv_cache``):
+  ``prefill``, ``decode_k``;
+- a block pool ``(N, L, bs, Hkv, D)`` (``llama.init_paged_kv_cache``):
+  ``prefill_cold``, ``prefill_warm``, ``decode_paged``, ``inject`` (and
+  its inverse ``BlockPool.extract``), ``spec_verify``;
+- the speculative draft's own dense cache: ``draft_prefill``,
+  ``draft_propose``.
+
+The prefills and ``spec_verify`` are ``llama.layer_walk`` with their own
+K/V step: what a decoder layer is made of is ``models/llama.py``'s
+business, in what layout its K/V lie and what its queries attend is this
+module's.  The device trace names a program's module after its inner
+function (``jit_prefill``, ``jit_decode_k``): the benchmark's readers
+find them by that name.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import llama
+from ray_tpu.models.llama import LlamaConfig
+
+# A decode step's attention reads one layer's attended K and V for a
+# GROUP of slots at a time.  XLA does not read a slice of the stacked
+# cache from inside the attention: it copies the slice out first, to HBM
+# if it is large, on chip (VMEM) if it is small enough.  This is the
+# "small enough" for one of K and V.  On a v5e at the benchmark's widths
+# (PERF.md section 6, PR 24) slices up to 25 MB were staged on chip, but
+# from 16 MiB up XLA also parked a weight stack there and moved it out
+# and back in every layer; at 4 to 12 MiB it did neither.
+# tests/test_decode_inplace.py holds both at the real widths.
+_ATTEND_GROUP_BYTES = 8 << 20
+
+
+def _attend_group(slots: int, slot_bytes: int) -> int:
+    """Slots a decode step attends at a time: the most, dividing the
+    slot count, whose attended K (or V) of one layer fits
+    ``_ATTEND_GROUP_BYTES``."""
+    group = max(1, min(slots, _ATTEND_GROUP_BYTES // slot_bytes))
+    while slots % group:
+        group -= 1
+    return group
+
+
+def _expert_load(expert_rows):
+    """What a device program hands back about its experts, read at the
+    harvest that exists: ``expert_rows`` (..., L, E) int32, the rows each
+    layer's experts computed, per step of a chunk or for a prefill group
+    -> (the (L, E) histogram summed over the steps, the number of
+    (step, layer, expert) triples that had a row).  ``()`` for a dense
+    model, whose programs return nothing more than they did."""
+    if expert_rows is None:
+        return ()
+    rows = expert_rows.reshape((-1,) + expert_rows.shape[-2:])
+    return (rows.sum(0), jnp.sum(rows > 0, dtype=jnp.int32))
+
+
+def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
+    """The shared per-token decode step (scan body): a row write of
+    each slot's new K/V at its current position, cache attention
+    over the first ``s_active`` positions, greedy argmax fed back
+    in-graph.  The carry holds the WHOLE stacked (L, B, S, Hkv, D)
+    K and V through the token loop and the layer loop, so XLA's
+    while loops alias them in place: a step reads each layer's
+    attended prefix once and writes B rows per layer, nothing of
+    the cache's shape is rebuilt (120 rows scatter in ~15 us on a v5e;
+    PERF.md section 5).  IDENTICAL math for the dense
+    cache and the paged gathered layout — block ordering makes
+    gathered index == absolute position, which is what keeps the
+    two planes' tokens bit-identical.  The speculative DRAFT model
+    runs this step with its own ``cfg`` on its own dense cache.  The
+    step's ys are ``(tokens, expert rows)``: the (L, E) rows each
+    layer's experts computed, None for a dense model.  Experts compute
+    ``active`` slots only, and read their ``[L, E, ...]`` matrices in
+    place (the stacks are closed over, not sliced by the layer scan).
+
+    This step is ``llama.layer_walk`` written out, its K/V the carry of
+    the layer scan: the walk, handed a carry, compiled to the same sizes
+    but not to the same text as the program the benchmark's cells have
+    measured since PR 24 (this step has cliffs: PERF.md section 6)."""
+
+    sliced, stacks = llama.split_expert_stacks(params["layers"], cfg)
+
+    def step(carry, _):
+        ck, cv, tok, lens = carry
+        dt = cfg.dtype
+        x = params["embed_tokens"].astype(dt)[tok][:, None]
+        sin, cos = llama.rope_table(lens[:, None], cfg.head_dim,
+                                    cfg.rope_theta)
+        # Inactive slots MUST not write: a just-admitted slot's
+        # prefill may already have landed (it sits out this
+        # chunk awaiting its first token) and a stale-position
+        # write would corrupt its fresh rows.  Nor does a slot
+        # past the attended prefix.  Their row goes out of range
+        # and the scatter drops it.
+        slots = tok.shape[0]
+        rows = jnp.arange(slots, dtype=jnp.int32)
+        pos = jnp.where(active & (lens < s_active), lens,
+                        ck.shape[2])
+        scale = cfg.head_dim ** -0.5
+        group = _attend_group(
+            slots, s_active * cfg.n_kv_heads * cfg.head_dim
+            * jnp.dtype(ck.dtype).itemsize)
+
+        def attend(q, ck, cv, l):
+            # A group of slots at a time, so that a group's
+            # attended K and V of the layer are staged on chip:
+            # asked for all slots at once, XLA copies the layer's
+            # whole prefix out to HBM first and reads it back.
+            def prefix(c, lo):
+                return jax.lax.dynamic_slice(
+                    c, (l, lo, 0, 0, 0),
+                    (1, group, s_active) + c.shape[3:])[0]
+
+            def one(lo):
+                return llama._cache_attend(
+                    jax.lax.dynamic_slice_in_dim(q, lo, group),
+                    prefix(ck, lo), prefix(cv, lo),
+                    jax.lax.dynamic_slice_in_dim(
+                        lens, lo, group)[:, None], scale)
+
+            out = jax.lax.map(one, jnp.arange(
+                0, slots, group, dtype=jnp.int32))
+            return out.reshape(q.shape)
+
+        def body(carry, layer_and_index):
+            x, ck, cv = carry
+            layer, l = layer_and_index
+            q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg)
+            # Write before attend: the new row is among the keys.
+            ck = ck.at[l, rows, pos].set(
+                kk[:, 0].astype(ck.dtype), mode="drop",
+                indices_are_sorted=True, unique_indices=True)
+            cv = cv.at[l, rows, pos].set(
+                vv[:, 0].astype(cv.dtype), mode="drop",
+                indices_are_sorted=True, unique_indices=True)
+            attn = attend(q, ck, cv, l)
+            x, _aux, expert_rows = llama.attn_out_ffn(
+                x, attn, {**layer, **stacks}, cfg,
+                valid=active[:, None], layer_index=l)
+            return (x, ck, cv), expert_rows
+
+        (x, ck, cv), expert_rows = jax.lax.scan(
+            body, (x, ck, cv),
+            (sliced, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = llama.matmul(x, llama.lm_head(params, cfg))[:, 0]
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        nxt = jnp.where(active, nxt, tok)
+        lens = lens + active.astype(jnp.int32)
+        return (ck, cv, nxt, lens), (nxt, expert_rows)
+
+    return step
+
+
+# ------------------------------------------------------------- dense plane
+def build_prefill(cfg: LlamaConfig) -> Callable:
+    def prefill(params, cache, tokens, lengths, slots):
+        last_logits, ks, vs, rows = llama.prefill_forward(
+            params, tokens, lengths, cfg, return_expert_rows=True)
+        cache = llama.insert_prefill(cache, ks, vs, slots)
+        first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        return cache, first, _expert_load(rows)
+
+    return jax.jit(prefill, donate_argnums=(1,))
+
+
+def build_decode_k(cfg: LlamaConfig) -> Callable:
+    def decode_k(params, cache, tok_dev, len_dev,
+                 ov_tok, ov_len, ov_mask, active, k, s_active):
+        tok = jnp.where(ov_mask, ov_tok, tok_dev)
+        lens = jnp.where(ov_mask, ov_len, len_dev)
+        step = decode_step(cfg, params, s_active, active)
+        (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
+            step, (cache["k"], cache["v"], tok, lens), None,
+            length=k)
+        return {"k": ck, "v": cv}, toks, tok, lens, \
+            _expert_load(rows)
+
+    # tok_dev/len_dev (args 2, 3) are always overwritten by the
+    # returned carries at every call site: donate them too.
+    return jax.jit(decode_k, donate_argnums=(1, 2, 3),
+                   static_argnames=("k", "s_active"))
+
+
+# ------------------------------------------------------------- paged plane
+class BlockPool:
+    """The layout of a pool of ``block_size``-token blocks stored as
+    ``kv_quant`` says (``llama.init_paged_kv_cache``), and its reads and
+    writes as the paged programs trace them.  Block tables pad with an
+    out-of-range index: gathers clip (garbage, masked), scatters drop
+    (no write)."""
+
+    def __init__(self, cfg: LlamaConfig, block_size: int,
+                 kv_quant: Optional[str]):
+        from ray_tpu.serve.kv_cache import kv_quant_info
+
+        self.cfg, self.bs = cfg, block_size
+        self.fmt = kv_quant_info(kv_quant)
+
+    def gather(self, pool, name, bt):
+        """Gathered compute-dtype blocks (L, B, nb*bs, Hkv, D);
+        quantized pools dequantize here (stored * per-block-head
+        scale), so everything downstream of the gather is
+        plane-agnostic."""
+        N, L, bs, Hkv, D = pool[name].shape
+        B, nb = bt.shape
+        g = jnp.take(pool[name], bt.reshape(-1), axis=0, mode="clip")
+        g = g.reshape(B, nb, L, bs, Hkv, D)
+        g = g.transpose(2, 0, 1, 3, 4, 5).reshape(L, B, nb * bs, Hkv, D)
+        if self.fmt is None:
+            return g
+        s = jnp.take(pool[name + "_scale"], bt.reshape(-1), axis=0,
+                     mode="clip")               # (B*nb, L, bs, Hkv)
+        s = s.reshape(B, nb, L, bs, Hkv).transpose(
+            2, 0, 1, 3, 4).reshape(L, B, nb * bs, Hkv)
+        return llama.dequantize_kv_blocks(g, s, self.cfg.dtype)
+
+    def set_blocks(self, pool, name, flat, updates):
+        """Store block updates ((M, L, bs, Hkv, D), compute dtype)
+        at ``flat`` indices; quantized pools quantize on the way in
+        (scale written next to the block)."""
+        if self.fmt is None:
+            return {name: pool[name].at[flat].set(
+                updates.astype(pool[name].dtype), mode="drop")}
+        q, sc = llama.quantize_kv_blocks(
+            updates, self.fmt.qmax, jnp.dtype(self.fmt.dtype_name))
+        return {
+            name: pool[name].at[flat].set(q, mode="drop"),
+            name + "_scale": pool[name + "_scale"].at[flat].set(
+                sc, mode="drop"),
+        }
+
+    def store(self, pool, flat, kb, vb):
+        """The pool with K and V blocks ``flat`` replaced."""
+        return {**pool, **self.set_blocks(pool, "k", flat, kb),
+                **self.set_blocks(pool, "v", flat, vb)}
+
+    def scatter(self, pool, bt, ck, cv):
+        """K/V in the gathered layout (``gather``'s; a prefill's rows
+        (L, G, P, Hkv, D) are that layout with the last block to pad
+        out) written back as whole BLOCKS (block-granular indices, the
+        layout XLA handles well)."""
+        B, nb = bt.shape
+
+        def blocks(g):
+            L, _, S, Hkv, D = g.shape
+            if S != nb * self.bs:
+                g = jnp.pad(g, ((0, 0), (0, 0), (0, nb * self.bs - S),
+                                (0, 0), (0, 0)))
+            u = g.reshape(L, B, nb, self.bs, Hkv, D)
+            return u.transpose(1, 2, 0, 3, 4, 5).reshape(
+                B * nb, L, self.bs, Hkv, D)
+
+        return self.store(pool, bt.reshape(-1), blocks(ck), blocks(cv))
+
+    def extract(self, pool, idx):
+        """``inject``'s inverse: blocks ``idx`` of the pool, K and V,
+        each (n, L, bs, Hkv, D) at FULL PRECISION, so that a quantized
+        prefill replica can feed a bf16 decode replica (and vice
+        versa); the ingest side requantizes on inject.  Gathered ON
+        DEVICE — materializing the whole pool to host would move the
+        full pool bytes per request on a real accelerator."""
+        def blocks(name):
+            stored = jnp.take(pool[name], idx, axis=0)
+            if self.fmt is None:
+                return stored
+            return llama.dequantize_kv_blocks(
+                stored, jnp.take(pool[name + "_scale"], idx, axis=0),
+                self.cfg.dtype)
+
+        return blocks("k"), blocks("v")
+
+
+def build_prefill_cold(blocks: BlockPool) -> Callable:
+    cfg = blocks.cfg
+
+    def prefill_cold(params, pool, tokens, lengths, write_bt):
+        # Same computation as the dense plane's prefill (bit-equal
+        # first tokens + K/V rows); only the insert differs.
+        last_logits, ks, vs, rows = llama.prefill_forward(
+            params, tokens, lengths, cfg, return_expert_rows=True)
+        pool = blocks.scatter(pool, write_bt, ks, vs)
+        first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        return pool, first, _expert_load(rows)
+
+    return jax.jit(prefill_cold, donate_argnums=(1,))
+
+
+def build_prefill_warm(blocks: BlockPool) -> Callable:
+    cfg = blocks.cfg
+
+    def prefill_warm(params, pool, tokens, lengths, pos0,
+                     prefix_bt, write_bt):
+        # Prefix-cache hit: the SUFFIX attends the gathered shared
+        # blocks plus itself — the shared prefix is never
+        # recomputed (the whole point of COW prefix sharing).
+        G, P = tokens.shape
+        Sp = prefix_bt.shape[1] * blocks.bs
+        suffix = jnp.arange(P, dtype=jnp.int32)[None, :]
+        positions = pos0[:, None] + suffix
+        prefix_pos = jnp.arange(Sp, dtype=jnp.int32)
+        key_abs = jnp.concatenate(
+            [jnp.broadcast_to(prefix_pos[None, :], (G, Sp)),
+             positions], axis=1)
+        key_valid = jnp.concatenate(
+            [prefix_pos[None, :] < pos0[:, None],
+             jnp.ones((G, P), bool)], axis=1)
+        scale = cfg.head_dim ** -0.5
+
+        def kv_step(q, k, v, positions, prefix_l):
+            ckp_l, cvp_l = prefix_l
+            keys = jnp.concatenate(
+                [ckp_l, k.astype(ckp_l.dtype)], axis=1)
+            vals = jnp.concatenate(
+                [cvp_l, v.astype(cvp_l.dtype)], axis=1)
+            attn = llama._cache_attend(q, keys, vals, positions, scale,
+                                       key_abs, key_valid)
+            return attn, (k, v)
+
+        last_logits, (ks, vs), rows = llama.layer_walk(
+            params, tokens, cfg, kv_step, positions=positions,
+            kv_layers=(blocks.gather(pool, "k", prefix_bt),
+                       blocks.gather(pool, "v", prefix_bt)),
+            valid=suffix < lengths[:, None], lengths=lengths)
+        first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        pool = blocks.scatter(pool, write_bt, ks, vs)
+        return pool, first, _expert_load(rows)
+
+    return jax.jit(prefill_warm, donate_argnums=(1,))
+
+
+def build_decode_paged(blocks: BlockPool) -> Callable:
+    cfg = blocks.cfg
+
+    def decode_paged(params, pool, tok_dev, len_dev, ov_tok,
+                     ov_len, ov_mask, active, bt, k):
+        tok = jnp.where(ov_mask, ov_tok, tok_dev)
+        lens = jnp.where(ov_mask, ov_len, len_dev)
+        ck = blocks.gather(pool, "k", bt)
+        cv = blocks.gather(pool, "v", bt)
+        step = decode_step(cfg, params, bt.shape[1] * blocks.bs, active)
+        (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
+            step, (ck, cv, tok, lens), None, length=k)
+        pool = blocks.scatter(pool, bt, ck, cv)
+        return pool, toks, tok, lens, _expert_load(rows)
+
+    # tok_dev/len_dev (args 2, 3) are always overwritten by the
+    # returned carries at every call site: donate them too.
+    return jax.jit(decode_paged, donate_argnums=(1, 2, 3),
+                   static_argnames=("k",))
+
+
+def build_inject(blocks: BlockPool) -> Callable:
+
+    def inject(pool, kb, vb, dest):
+        # Handoff blocks arrive FULL PRECISION (the prefill side
+        # dequantizes on extract), so quantized and bf16 engines
+        # interoperate across a disaggregated pair.
+        return blocks.store(pool, dest, kb, vb)
+
+    return jax.jit(inject, donate_argnums=(0,))
+
+
+def build_spec_verify(blocks: BlockPool) -> Callable:
+    cfg = blocks.cfg
+
+    def spec_verify(params, pool, tokens, positions, active, bt):
+        """Target-model verification of a draft proposal: T tokens
+        per slot in ONE pass over the gathered block layout.
+        tokens/positions: (B, T) — [last accepted, d1..d_{T-1}] at
+        absolute positions; returns the target's greedy token for
+        positions+1 (B, T) and writes the inputs' K/V at their
+        positions (gathered index == absolute position, same
+        invariant as the decode step — which is what keeps spec
+        output bit-identical to plain greedy decode)."""
+        S = bt.shape[1] * blocks.bs
+        key_pos = jnp.arange(S, dtype=jnp.int32)
+        onehot = ((key_pos[None, None, :]
+                   == positions[:, :, None])
+                  & active[:, None, None])            # (B, T, S)
+        written = onehot.any(axis=1)[:, :, None, None]
+        proj = onehot.astype(cfg.dtype)
+        scale = cfg.head_dim ** -0.5
+
+        def kv_step(q, kk, vv, positions, cache_l):
+            ck_l, cv_l = cache_l
+            # One-hot projection places the T fresh rows at their
+            # absolute positions (like insert_prefill, scatters
+            # would serialize on TPU).
+            up_k = jnp.einsum("bts,bthd->bshd", proj, kk)
+            up_v = jnp.einsum("bts,bthd->bshd", proj, vv)
+            ck_l = jnp.where(written, up_k.astype(ck_l.dtype),
+                             ck_l)
+            cv_l = jnp.where(written, up_v.astype(cv_l.dtype),
+                             cv_l)
+            attn = llama._cache_attend(q, ck_l, cv_l, positions,
+                                       scale)
+            return attn, (ck_l, cv_l)
+
+        logits, (ck, cv), _rows = llama.layer_walk(
+            params, tokens, cfg, kv_step, positions=positions,
+            kv_layers=(blocks.gather(pool, "k", bt),
+                       blocks.gather(pool, "v", bt)),
+            valid=active[:, None])
+        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return blocks.scatter(pool, bt, ck, cv), toks
+
+    return jax.jit(spec_verify, donate_argnums=(1,))
+
+
+# ------------------------------------------------------- draft plane (spec)
+# The speculative draft keeps a DENSE per-slot cache (the draft is small —
+# paging it buys nothing) and rides the SAME decode step as the dense
+# plane, its cache that step's carry with the rows written in place, so its
+# cache bookkeeping inherits the write-before-attend invariant.
+def truncated_draft(cfg: LlamaConfig, params, n: int):
+    """Layer-truncated self-draft ``(config, params)``: the target's
+    first ``n`` layers + its own norm/head.  Zero extra weights, and the
+    shared residual stream keeps draft/target argmaxes correlated even
+    for untrained params (the accept-rate floor the spec tests rely
+    on)."""
+    return (dataclasses.replace(cfg, n_layers=n),
+            {**params, "layers": jax.tree.map(lambda x: x[:n],
+                                              params["layers"])})
+
+
+def build_draft_prefill(dcfg: LlamaConfig) -> Callable:
+    def draft_prefill(params, cache, tokens, lengths, slots):
+        _logits, ks, vs = llama.prefill_forward(params, tokens,
+                                                lengths, dcfg)
+        return llama.insert_prefill(cache, ks, vs, slots)
+
+    return jax.jit(draft_prefill, donate_argnums=(1,))
+
+
+def build_draft_propose(dcfg: LlamaConfig) -> Callable:
+    def draft_propose(params, cache, tok, pos, active, k, s_active):
+        step = decode_step(dcfg, params, s_active, active)
+        (ck, cv, tok, pos), (toks, _rows) = jax.lax.scan(
+            step, (cache["k"], cache["v"], tok, pos), None,
+            length=k)
+        return {"k": ck, "v": cv}, toks
+
+    return jax.jit(draft_propose, donate_argnums=(1,),
+                   static_argnames=("k", "s_active"))

@@ -45,12 +45,11 @@ scheduling decisions read.  Four surfaces, one module:
 4. **Model-plane series** — :func:`record_train_step` (per-step
    tokens/s + MFU from the train loop) and :func:`record_program_ema`
    (the serve engine's per-program execution-time EMAs) make the
-   numbers ``profile_mfu.py`` measures offline continuously queryable;
-   ``ray_tpu top`` renders them live.
+   model plane's numbers continuously queryable; ``ray_tpu top``
+   renders them live.
 
 ``disable()`` turns sampling, the compile listener, and annotations
-into no-ops — the ``device_telemetry_overhead_pct`` bench phase
-measures the plane's cost that way (guard < 5%).
+into no-ops.
 
 Env knobs:
   RAY_TPU_DEVICE_TELEMETRY       0 disables the whole plane
@@ -148,8 +147,7 @@ def _device_metrics():
 
 
 def model_plane_metrics():
-    """The model-plane gauges (train step + serve engine hot loops):
-    the live counterpart of ``profile_mfu.py``'s offline numbers."""
+    """The model-plane gauges (train step + serve engine hot loops)."""
     from . import metrics as _metrics
 
     return _metrics.metric_group("model_plane", lambda: {
@@ -356,7 +354,7 @@ _COMPILE_EVENT_SUFFIX = "backend_compile_duration"
 def _install_compile_listener() -> None:
     """Register the jax.monitoring duration listener once per process.
     jax offers no unregister, so the callback itself gates on
-    ``_enabled`` (disable() must be a true no-op for the bench)."""
+    ``_enabled`` (disable() must be a true no-op)."""
     global _listener_installed
     if _listener_installed:
         return
@@ -490,8 +488,7 @@ def record_train_step(tokens: int, step_s: float,
                       n_devices: int = 1) -> None:
     """Publish one training step's model-plane gauges: tokens/s
     always, MFU when the chip roofline is known (6N dense-LM
-    approximation — the same convention as bench.py /
-    profile_mfu.py).  ``tokens`` is the WHOLE step's token count, so
+    approximation).  ``tokens`` is the WHOLE step's token count, so
     a multi-chip gang must pass its ``device_kind`` and distinct
     chip count — the roofline denominator is per chip, and
     resolving it from THIS process's devices would be the driver's

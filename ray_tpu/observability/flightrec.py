@@ -73,8 +73,7 @@ def enable() -> None:
 
 
 def disable() -> None:
-    """Make the snapshot loop a no-op (the ``flightrec_overhead_pct``
-    bench phase toggles the plane cluster-wide this way)."""
+    """Make the snapshot loop a no-op."""
     global _enabled
     _enabled = False
 

@@ -22,8 +22,7 @@ land in ``observability.timeline`` tagged with all three ids, so the
 merged cluster timeline can stitch one distributed pass together.
 
 ``disable()`` turns the whole plane into no-ops (``current`` → None,
-ids → None, spans untagged) — the ``obs_overhead_pct`` bench phase
-measures its cost this way.
+ids → None, spans untagged).
 """
 
 from __future__ import annotations
@@ -46,8 +45,8 @@ from . import timeline as _timeline
 _ctx_var: "contextvars.ContextVar[Optional[TraceCtx]]" = \
     contextvars.ContextVar("ray_tpu_trace", default=None)
 # RAY_TPU_TRACING=0 disables the plane process-wide (worker
-# subprocesses inherit it through the environment — how the bench
-# measures a whole cluster untraced).
+# subprocesses inherit it through the environment: a whole cluster
+# runs untraced).
 _enabled = os.environ.get("RAY_TPU_TRACING", "1").lower() not in (
     "0", "false", "off")
 

@@ -52,7 +52,7 @@ _enabled = True
 
 
 def enable() -> None:
-    """(Re-)enable ingest process-wide (the bench toggle)."""
+    """(Re-)enable ingest process-wide."""
     global _enabled
     _enabled = True
 

@@ -24,16 +24,10 @@ whose per-call host↔device round trip is tens of milliseconds:
   the two planes (tests/test_kv_cache.py parity gate): the gathered
   block layout equals the dense layout position-for-position, and the
   cold prefill path runs the same ``prefill_forward`` computation.
-- A decode step writes ONE K/V row per slot, in place: the stacked
-  cache is the carry of the token loop and of the layer loop, each
-  layer scatters B rows of (Hkv, D) at the slots' own positions and
-  its attention reads the attended prefix of that layer, a group of
-  slots at a time — no select over a layer, no slice-out and
-  write-back of the prefix (120 rows scatter in ~15 us on a v5e;
-  PERF.md section 5).  The paged plane
-  gathers its block tables into the same layout, runs the same step
-  on the gathered buffer and scatters whole BLOCKS back
-  (block-granular indices, the layout XLA handles well).
+- The device programs are built by ``models/llama_serve.py`` from the
+  config alone; this module is their scheduler.  Both planes run ONE
+  decode step (``llama_serve.decode_step``: one K/V row per slot
+  written in place), the paged plane on its gathered block tables.
 - Prefill runs plain causal attention WITHIN the prompt (no cache
   read), inserts K/V via a one-hot slot projection (dense) or a
   block-table scatter (paged) at static offsets, and returns the
@@ -76,10 +70,9 @@ whose per-call host↔device round trip is tens of milliseconds:
   shapes and decode buckets are compiled at init (warmup=True) so no
   request ever pays a compile.
 
-Speed: not measured on current code (``PERF.md`` is where a measured
-number will be stated).  ``chip_smoke.py`` drives both planes on the
-chip at the 125M engine shape and checks that no request pays a
-compile.
+Speed: ``PERF.md`` (the dense plane's cells).  ``chip_smoke.py`` drives
+both planes on the chip at the 125M engine shape and checks that no
+request pays a compile.
 """
 
 from __future__ import annotations
@@ -131,17 +124,6 @@ _GROUP_POSITIONS = 2048
 # from 150 to 600 came within 7% of the cut that knows every program's
 # time; 300 is the least bad for the three together.
 _LAUNCH_POSITIONS = 300
-
-# A decode step's attention reads one layer's attended K and V for a
-# GROUP of slots at a time.  XLA does not read a slice of the stacked
-# cache from inside the attention: it copies the slice out first, to HBM
-# if it is large, on chip (VMEM) if it is small enough.  This is the
-# "small enough" for one of K and V.  On a v5e at the benchmark's widths
-# (PERF.md section 6, PR 24) slices up to 25 MB were staged on chip, but
-# from 16 MiB up XLA also parked a weight stack there and moved it out
-# and back in every layer; at 4 to 12 MiB it did neither.
-# tests/test_decode_inplace.py holds both at the real widths.
-_ATTEND_GROUP_BYTES = 8 << 20
 
 # How aggressively the feasibility shed fires: a request is shed when
 # its remaining budget is under this fraction of the ESTIMATED time to
@@ -297,31 +279,6 @@ def cut_prefill_wave(lengths: List[int], buckets: Tuple[int, ...],
     return groups[::-1]
 
 
-def _attend_group(slots: int, slot_bytes: int) -> int:
-    """Slots a decode step attends at a time: the most, dividing the
-    slot count, whose attended K (or V) of one layer fits
-    ``_ATTEND_GROUP_BYTES``."""
-    group = max(1, min(slots, _ATTEND_GROUP_BYTES // slot_bytes))
-    while slots % group:
-        group -= 1
-    return group
-
-
-def _expert_load(expert_rows):
-    """What a device program hands back about its experts, read at the
-    harvest that exists: ``expert_rows`` (..., L, E) int32, the rows each
-    layer's experts computed, per step of a chunk or for a prefill group
-    -> (the (L, E) histogram summed over the steps, the number of
-    (step, layer, expert) triples that had a row).  ``()`` for a dense
-    model, whose programs return nothing more than they did."""
-    if expert_rows is None:
-        return ()
-    import jax.numpy as jnp
-
-    rows = expert_rows.reshape((-1,) + expert_rows.shape[-2:])
-    return (rows.sum(0), jnp.sum(rows > 0, dtype=jnp.int32))
-
-
 class LLMServer:
     """Deployment body: ``serve.run(serve.deployment(LLMServer).bind())``.
 
@@ -360,7 +317,7 @@ class LLMServer:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import llama
+        from ray_tpu.models import llama, llama_serve
 
         if role not in ("both", "prefill", "decode"):
             raise ValueError(f"unknown role {role!r}")
@@ -428,17 +385,18 @@ class LLMServer:
 
         self.kv_quant = kv_quant
         if self.paged:
-            self._init_paged(block_size, num_blocks, llama, jax, jnp)
+            self._init_paged(block_size, num_blocks)
         else:
             if kv_quant is not None:
                 raise ValueError("kv_quant requires the paged KV "
                                  "plane (paged=True)")
             self.cache = llama.init_kv_cache(self.cfg, max_slots,
                                              max_len)
-            self._build_dense(llama, jax, jnp)
+            self._prefill = llama_serve.build_prefill(self.cfg)
+            self._decode_k = llama_serve.build_decode_k(self.cfg)
         if self.spec_k:
             self._init_draft(draft_preset, draft_layers, draft_params,
-                             seed, llama, jax, jnp)
+                             seed)
 
         self._jnp = jnp
         # Device-resident carries between chunk launches.
@@ -476,148 +434,26 @@ class LLMServer:
         self._decode_targets: List[Any] = []
         self._decode_rr = 0
         self._decode_refresh = 0.0
-        self._membership_version = -1
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
-    # ------------------------------------------------------- dense plane
-    def _build_dense(self, llama, jax, jnp):
-        cfg = self.cfg
-
-        def prefill(params, cache, tokens, lengths, slots):
-            last_logits, ks, vs, rows = llama.prefill_forward(
-                params, tokens, lengths, cfg, return_expert_rows=True)
-            cache = llama.insert_prefill(cache, ks, vs, slots)
-            first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            return cache, first, _expert_load(rows)
-
-        def decode_k(params, cache, tok_dev, len_dev,
-                     ov_tok, ov_len, ov_mask, active, k, s_active):
-            tok = jnp.where(ov_mask, ov_tok, tok_dev)
-            lens = jnp.where(ov_mask, ov_len, len_dev)
-            step = self._make_decode_step(params, s_active, active,
-                                          llama, jax, jnp)
-            (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
-                step, (cache["k"], cache["v"], tok, lens), None,
-                length=k)
-            return {"k": ck, "v": cv}, toks, tok, lens, \
-                _expert_load(rows)
-
-        self._prefill = jax.jit(prefill, donate_argnums=(1,))
-        # tok_dev/len_dev (args 2, 3) are always overwritten by the
-        # returned carries at every call site: donate them too.
-        self._decode_k = jax.jit(decode_k, donate_argnums=(1, 2, 3),
-                                 static_argnames=("k", "s_active"))
-
-    def _make_decode_step(self, params, s_active, active, llama, jax,
-                          jnp, cfg=None):
-        """The shared per-token decode step (scan body): a row write of
-        each slot's new K/V at its current position, cache attention
-        over the first ``s_active`` positions, greedy argmax fed back
-        in-graph.  The carry holds the WHOLE stacked (L, B, S, Hkv, D)
-        K and V through the token loop and the layer loop, so XLA's
-        while loops alias them in place: a step reads each layer's
-        attended prefix once and writes B rows per layer, nothing of
-        the cache's shape is rebuilt.  IDENTICAL math for the dense
-        cache and the paged gathered layout — block ordering makes
-        gathered index == absolute position, which is what keeps the
-        two planes' tokens bit-identical.  ``cfg`` overrides the
-        target config (the speculative DRAFT model reuses this step on
-        its own dense cache).  The step's ys are ``(tokens, expert
-        rows)``: the (L, E) rows each layer's experts computed, None for
-        a dense model.  Experts compute ``active`` slots only, and read
-        their ``[L, E, ...]`` matrices in place (the stacks are closed
-        over, not sliced by the layer scan)."""
-        cfg = cfg or self.cfg
-        sliced, stacks = llama.split_expert_stacks(params["layers"], cfg)
-
-        def step(carry, _):
-            ck, cv, tok, lens = carry
-            dt = cfg.dtype
-            x = params["embed_tokens"].astype(dt)[tok][:, None]
-            sin, cos = llama.rope_table(lens[:, None], cfg.head_dim,
-                                        cfg.rope_theta)
-            # Inactive slots MUST not write: a just-admitted slot's
-            # prefill may already have landed (it sits out this
-            # chunk awaiting its first token) and a stale-position
-            # write would corrupt its fresh rows.  Nor does a slot
-            # past the attended prefix.  Their row goes out of range
-            # and the scatter drops it.
-            slots = tok.shape[0]
-            rows = jnp.arange(slots, dtype=jnp.int32)
-            pos = jnp.where(active & (lens < s_active), lens,
-                            ck.shape[2])
-            scale = cfg.head_dim ** -0.5
-            group = _attend_group(
-                slots, s_active * cfg.n_kv_heads * cfg.head_dim
-                * jnp.dtype(ck.dtype).itemsize)
-
-            def attend(q, ck, cv, l):
-                # A group of slots at a time, so that a group's
-                # attended K and V of the layer are staged on chip:
-                # asked for all slots at once, XLA copies the layer's
-                # whole prefix out to HBM first and reads it back.
-                def prefix(c, lo):
-                    return jax.lax.dynamic_slice(
-                        c, (l, lo, 0, 0, 0),
-                        (1, group, s_active) + c.shape[3:])[0]
-
-                def one(lo):
-                    return llama._cache_attend(
-                        jax.lax.dynamic_slice_in_dim(q, lo, group),
-                        prefix(ck, lo), prefix(cv, lo),
-                        jax.lax.dynamic_slice_in_dim(
-                            lens, lo, group)[:, None], scale)
-
-                out = jax.lax.map(one, jnp.arange(
-                    0, slots, group, dtype=jnp.int32))
-                return out.reshape(q.shape)
-
-            def body(carry, layer_and_index):
-                x, ck, cv = carry
-                layer, l = layer_and_index
-                q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg)
-                # Write before attend: the new row is among the keys.
-                ck = ck.at[l, rows, pos].set(
-                    kk[:, 0].astype(ck.dtype), mode="drop",
-                    indices_are_sorted=True, unique_indices=True)
-                cv = cv.at[l, rows, pos].set(
-                    vv[:, 0].astype(cv.dtype), mode="drop",
-                    indices_are_sorted=True, unique_indices=True)
-                attn = attend(q, ck, cv, l)
-                x, _aux, expert_rows = llama.attn_out_ffn(
-                    x, attn, {**layer, **stacks}, cfg,
-                    valid=active[:, None], layer_index=l)
-                return (x, ck, cv), expert_rows
-
-            (x, ck, cv), expert_rows = jax.lax.scan(
-                body, (x, ck, cv),
-                (sliced, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-            x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-            head = (params["embed_tokens"].astype(cfg.dtype).T
-                    if cfg.tie_embeddings
-                    else params["lm_head"].astype(cfg.dtype))
-            logits = llama.matmul(x, head)[:, 0]
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(active, nxt, tok)
-            lens = lens + active.astype(jnp.int32)
-            return (ck, cv, nxt, lens), (nxt, expert_rows)
-
-        return step
-
     # ------------------------------------------------------- paged plane
-    def _init_paged(self, block_size, num_blocks, llama, jax, jnp):
-        from .kv_cache import (KVBlockAllocator, PrefixCache,
-                               kv_quant_info)
+    def _init_paged(self, block_size, num_blocks):
+        from ray_tpu.models import llama, llama_serve
+
+        from .kv_cache import KVBlockAllocator, PrefixCache
 
         cfg = self.cfg
         bs = int(block_size)
         if bs < 1:
             raise ValueError("block_size must be >= 1")
         self.block_size = bs
-        fmt = kv_quant_info(self.kv_quant)
-        self._kv_fmt = fmt
-        qdt = jnp.dtype(fmt.dtype_name) if fmt else None
+        self._blocks = llama_serve.BlockPool(cfg, bs, self.kv_quant)
+        self._prefill_cold = llama_serve.build_prefill_cold(self._blocks)
+        self._prefill_warm = llama_serve.build_prefill_warm(self._blocks)
+        self._decode_paged = llama_serve.build_decode_paged(self._blocks)
+        self._inject = llama_serve.build_inject(self._blocks)
+        self._spec_verify = llama_serve.build_spec_verify(self._blocks)
         max_blocks_per_req = -(-self.max_len // bs)
         if num_blocks is None:
             # Capacity parity with the dense plane by default; size it
@@ -644,253 +480,11 @@ class LLMServer:
         # Warm-prefill prefix buckets: one static gather width.
         self._np_max = max(1, (max(self.buckets) - 1) // bs)
 
-        def gather_raw(pool_t, bt):
-            N, L, bsz, Hkv, D = pool_t.shape
-            B, nb = bt.shape
-            g = jnp.take(pool_t, bt.reshape(-1), axis=0, mode="clip")
-            g = g.reshape(B, nb, L, bsz, Hkv, D)
-            return g.transpose(2, 0, 1, 3, 4, 5).reshape(
-                L, B, nb * bsz, Hkv, D)
-
-        def gather(pool, name, bt):
-            """Gathered compute-dtype blocks (L, B, nb*bs, Hkv, D);
-            quantized pools dequantize here (stored * per-block-head
-            scale), so everything downstream of the gather is
-            plane-agnostic."""
-            g = gather_raw(pool[name], bt)
-            if fmt is None:
-                return g
-            B, nb = bt.shape
-            s = jnp.take(pool[name + "_scale"], bt.reshape(-1), axis=0,
-                         mode="clip")               # (B*nb, L, bs, Hkv)
-            L, Hkv = s.shape[1], s.shape[3]
-            s = s.reshape(B, nb, L, bs, Hkv).transpose(
-                2, 0, 1, 3, 4).reshape(L, B, nb * bs, Hkv)
-            return (g.astype(jnp.float32)
-                    * s[..., None]).astype(cfg.dtype)
-
-        def set_blocks(pool, name, flat, updates):
-            """Store block updates ((M, L, bs, Hkv, D), compute dtype)
-            at ``flat`` indices; quantized pools quantize on the way in
-            (scale written next to the block)."""
-            if fmt is None:
-                return {name: pool[name].at[flat].set(
-                    updates.astype(pool[name].dtype), mode="drop")}
-            q, sc = llama.quantize_kv_blocks(updates, fmt.qmax, qdt)
-            return {
-                name: pool[name].at[flat].set(q, mode="drop"),
-                name + "_scale": pool[name + "_scale"].at[flat].set(
-                    sc, mode="drop"),
-            }
-
-        def scatter(pool, name, bt, g):
-            L = pool[name].shape[1]
-            B, nb = bt.shape
-            u = g.reshape(L, B, nb, bs, -1,
-                          cfg.head_dim).transpose(1, 2, 0, 3, 4, 5)
-            return set_blocks(pool, name, bt.reshape(-1),
-                              u.reshape(B * nb, L, bs, -1,
-                                        cfg.head_dim))
-
-        self._gather_kv = gather
-        self._set_kv_blocks = set_blocks
-
-        def rows_to_blocks(rows, nw):
-            # (L, G, Ppad, H, D) -> (G*nw, L, bs, H, D) scatter updates
-            L, G, Ppad, Hkv, D = rows.shape
-            u = rows.transpose(1, 0, 2, 3, 4).reshape(
-                G, L, nw, bs, Hkv, D)
-            return u.transpose(0, 2, 1, 3, 4, 5).reshape(
-                G * nw, L, bs, Hkv, D)
-
-        def pad_rows(rows, nw):
-            L, G, P, Hkv, D = rows.shape
-            if P == nw * bs:
-                return rows
-            return jnp.pad(rows, ((0, 0), (0, 0), (0, nw * bs - P),
-                                  (0, 0), (0, 0)))
-
-        def prefill_cold(params, pool, tokens, lengths, write_bt):
-            # Same computation as the dense plane's prefill (bit-equal
-            # first tokens + K/V rows); only the insert differs.
-            last_logits, ks, vs, rows = llama.prefill_forward(
-                params, tokens, lengths, cfg, return_expert_rows=True)
-            nw = write_bt.shape[1]
-            flat = write_bt.reshape(-1)
-            pool = {
-                **pool,
-                **set_blocks(pool, "k", flat,
-                             rows_to_blocks(pad_rows(ks, nw), nw)),
-                **set_blocks(pool, "v", flat,
-                             rows_to_blocks(pad_rows(vs, nw), nw)),
-            }
-            first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            return pool, first, _expert_load(rows)
-
-        def prefill_warm(params, pool, tokens, lengths, pos0,
-                         prefix_bt, write_bt):
-            # Prefix-cache hit: the SUFFIX attends the gathered shared
-            # blocks plus itself — the shared prefix is never
-            # recomputed (the whole point of COW prefix sharing).
-            G, P = tokens.shape
-            Sp = prefix_bt.shape[1] * bs
-            dt = cfg.dtype
-            positions = pos0[:, None] + jnp.arange(
-                P, dtype=jnp.int32)[None, :]
-            sin, cos = llama.rope_table(positions, cfg.head_dim,
-                                        cfg.rope_theta)
-            x = params["embed_tokens"].astype(dt)[tokens]
-            ckp = gather(pool, "k", prefix_bt)
-            cvp = gather(pool, "v", prefix_bt)
-            prefix_pos = jnp.arange(Sp, dtype=jnp.int32)
-            key_abs = jnp.concatenate(
-                [jnp.broadcast_to(prefix_pos[None, :], (G, Sp)),
-                 positions], axis=1)
-            key_valid = jnp.concatenate(
-                [prefix_pos[None, :] < pos0[:, None],
-                 jnp.ones((G, P), bool)], axis=1)
-            scale = cfg.head_dim ** -0.5
-            valid = jnp.arange(P, dtype=jnp.int32)[None, :] \
-                < lengths[:, None]
-            sliced, stacks = llama.split_expert_stacks(
-                params["layers"], cfg)
-
-            def body(x, layer_and_prefix):
-                layer, l, ckp_l, cvp_l = layer_and_prefix
-                q, k, v = llama._qkv_rope(x, layer, sin, cos, cfg)
-                keys = jnp.concatenate(
-                    [ckp_l, k.astype(ckp_l.dtype)], axis=1)
-                vals = jnp.concatenate(
-                    [cvp_l, v.astype(cvp_l.dtype)], axis=1)
-                attn = _masked_attend(q, keys, vals, positions,
-                                      key_abs, key_valid, scale, jnp,
-                                      jax)
-                x, _aux, rows = llama.attn_out_ffn(
-                    x, attn, {**layer, **stacks}, cfg, valid=valid,
-                    layer_index=l)
-                return x, (k, v, rows)
-
-            x, (ks, vs, rows) = jax.lax.scan(
-                body, x,
-                (sliced, jnp.arange(cfg.n_layers, dtype=jnp.int32),
-                 ckp, cvp))
-            x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-            last = jnp.take_along_axis(
-                x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
-            head = (params["embed_tokens"].astype(dt).T
-                    if cfg.tie_embeddings
-                    else params["lm_head"].astype(dt))
-            first = jnp.argmax(llama.matmul(last, head)[:, 0],
-                               axis=-1).astype(jnp.int32)
-            nw = write_bt.shape[1]
-            flat = write_bt.reshape(-1)
-            pool = {
-                **pool,
-                **set_blocks(pool, "k", flat,
-                             rows_to_blocks(pad_rows(ks, nw), nw)),
-                **set_blocks(pool, "v", flat,
-                             rows_to_blocks(pad_rows(vs, nw), nw)),
-            }
-            return pool, first, _expert_load(rows)
-
-        def decode_paged(params, pool, tok_dev, len_dev, ov_tok,
-                         ov_len, ov_mask, active, bt, k):
-            tok = jnp.where(ov_mask, ov_tok, tok_dev)
-            lens = jnp.where(ov_mask, ov_len, len_dev)
-            nb = bt.shape[1]
-            ck = gather(pool, "k", bt)
-            cv = gather(pool, "v", bt)
-            step = self._make_decode_step(params, nb * bs, active,
-                                          llama, jax, jnp)
-            (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
-                step, (ck, cv, tok, lens), None, length=k)
-            pool = {**pool, **scatter(pool, "k", bt, ck),
-                    **scatter(pool, "v", bt, cv)}
-            return pool, toks, tok, lens, _expert_load(rows)
-
-        def inject(pool, kb, vb, dest):
-            # Handoff blocks arrive FULL PRECISION (the prefill side
-            # dequantizes on extract), so quantized and bf16 engines
-            # interoperate across a disaggregated pair.
-            return {**pool, **set_blocks(pool, "k", dest, kb),
-                    **set_blocks(pool, "v", dest, vb)}
-
-        def spec_verify(params, pool, tokens, positions, active, bt):
-            """Target-model verification of a draft proposal: T tokens
-            per slot in ONE pass over the gathered block layout.
-            tokens/positions: (B, T) — [last accepted, d1..d_{T-1}] at
-            absolute positions; returns the target's greedy token for
-            positions+1 (B, T) and writes the inputs' K/V at their
-            positions (gathered index == absolute position, same
-            invariant as the decode step — which is what keeps spec
-            output bit-identical to plain greedy decode)."""
-            dt = cfg.dtype
-            S = bt.shape[1] * bs
-            ck = gather(pool, "k", bt)
-            cv = gather(pool, "v", bt)
-            x = params["embed_tokens"].astype(dt)[tokens]
-            sin, cos = llama.rope_table(positions, cfg.head_dim,
-                                        cfg.rope_theta)
-            key_pos = jnp.arange(S, dtype=jnp.int32)
-            onehot = ((key_pos[None, None, :]
-                       == positions[:, :, None])
-                      & active[:, None, None])            # (B, T, S)
-            written = onehot.any(axis=1)[:, :, None, None]
-            proj = onehot.astype(dt)
-            scale = cfg.head_dim ** -0.5
-            sliced, stacks = llama.split_expert_stacks(
-                params["layers"], cfg)
-
-            def body(x, layer_and_cache):
-                layer, l, ck_l, cv_l = layer_and_cache
-                q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg)
-                # One-hot projection places the T fresh rows at their
-                # absolute positions (like insert_prefill, scatters
-                # would serialize on TPU).
-                up_k = jnp.einsum("bts,bthd->bshd", proj, kk)
-                up_v = jnp.einsum("bts,bthd->bshd", proj, vv)
-                ck_l = jnp.where(written, up_k.astype(ck_l.dtype),
-                                 ck_l)
-                cv_l = jnp.where(written, up_v.astype(cv_l.dtype),
-                                 cv_l)
-                attn = llama._cache_attend(q, ck_l, cv_l, positions,
-                                           scale)
-                x, _aux, _rows = llama.attn_out_ffn(
-                    x, attn, {**layer, **stacks}, cfg,
-                    valid=active[:, None], layer_index=l)
-                return x, (ck_l, cv_l)
-
-            x, (ck, cv) = jax.lax.scan(
-                body, x,
-                (sliced, jnp.arange(cfg.n_layers, dtype=jnp.int32),
-                 ck, cv))
-            x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-            head = (params["embed_tokens"].astype(dt).T
-                    if cfg.tie_embeddings
-                    else params["lm_head"].astype(dt))
-            logits = llama.matmul(x, head)
-            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            pool = {**pool, **scatter(pool, "k", bt, ck),
-                    **scatter(pool, "v", bt, cv)}
-            return pool, toks
-
-        self._prefill_cold = jax.jit(prefill_cold, donate_argnums=(1,))
-        self._prefill_warm = jax.jit(prefill_warm, donate_argnums=(1,))
-        # tok_dev/len_dev (args 2, 3) are always overwritten by the
-        # returned carries at every call site: donate them too.
-        self._decode_paged = jax.jit(decode_paged,
-                                     donate_argnums=(1, 2, 3),
-                                     static_argnames=("k",))
-        self._inject = jax.jit(inject, donate_argnums=(0,))
-        self._spec_verify = jax.jit(spec_verify, donate_argnums=(1,))
-
     def _publish_pool_bytes(self) -> None:
         try:
-            from ..observability.metrics import kv_cache_counters
-
             nbytes = sum(int(x.size) * x.dtype.itemsize
                          for x in self.pool.values())
-            kv_cache_counters()["pool_bytes"].set(
+            self._kv_metrics["pool_bytes"].set(
                 nbytes, tags={"pool": self._deployment or "llm",
                               "dtype": self.kv_quant or "bf16"})
         except Exception:
@@ -898,14 +492,13 @@ class LLMServer:
 
     # -------------------------------------------------- draft plane (spec)
     def _init_draft(self, draft_preset, draft_layers, draft_params,
-                    seed, llama, jax, jnp):
+                    seed):
         """Build the speculative draft: its config/params, a DENSE
-        per-slot KV cache (the draft is small — paging it buys
-        nothing), and the propose/prefill programs.  The draft rides
-        the SAME decode step as the dense plane, its cache that step's
-        carry with the rows written in place, so its cache bookkeeping
-        inherits the write-before-attend invariant."""
-        import dataclasses
+        per-slot KV cache and the propose/prefill programs."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import llama, llama_serve
 
         cfg = self.cfg
         if draft_preset is not None:
@@ -923,25 +516,13 @@ class LLMServer:
                 lambda x: x.astype(dcfg.dtype)
                 if x.dtype == jnp.float32 else x, draft_params)
         else:
-            # Layer-truncated self-draft: the target's first n layers
-            # + its own norm/head.  Zero extra weights, and the shared
-            # residual stream keeps draft/target argmaxes correlated
-            # even for untrained params (the accept-rate floor the
-            # bench relies on).
             n = draft_layers or max(1, cfg.n_layers // 4)
             if not 0 < n < cfg.n_layers:
                 raise ValueError(
                     f"draft_layers={n} must be in [1, "
                     f"{cfg.n_layers - 1}]")
-            dcfg = dataclasses.replace(cfg, n_layers=n)
-            dparams = {
-                "embed_tokens": self.params["embed_tokens"],
-                "layers": jax.tree.map(lambda x: x[:n],
-                                       self.params["layers"]),
-                "final_norm": self.params["final_norm"],
-            }
-            if not cfg.tie_embeddings:
-                dparams["lm_head"] = self.params["lm_head"]
+            dcfg, dparams = llama_serve.truncated_draft(
+                cfg, self.params, n)
         self.draft_cfg = dcfg
         self.draft_params = dparams
         self.draft_cache = llama.init_kv_cache(dcfg, self.max_slots,
@@ -951,25 +532,8 @@ class LLMServer:
         self._spec_accepted = 0
         self._spec_tok_ema: Optional[float] = None
 
-        def draft_prefill(params, cache, tokens, lengths, slots):
-            _logits, ks, vs = llama.prefill_forward(params, tokens,
-                                                    lengths, dcfg)
-            return llama.insert_prefill(cache, ks, vs, slots)
-
-        def draft_propose(params, cache, tok, pos, active, k,
-                          s_active):
-            step = self._make_decode_step(params, s_active, active,
-                                          llama, jax, jnp, cfg=dcfg)
-            (ck, cv, tok, pos), (toks, _rows) = jax.lax.scan(
-                step, (cache["k"], cache["v"], tok, pos), None,
-                length=k)
-            return {"k": ck, "v": cv}, toks
-
-        self._draft_prefill = jax.jit(draft_prefill,
-                                      donate_argnums=(1,))
-        self._draft_propose = jax.jit(
-            draft_propose, donate_argnums=(1,),
-            static_argnames=("k", "s_active"))
+        self._draft_prefill = llama_serve.build_draft_prefill(dcfg)
+        self._draft_propose = llama_serve.build_draft_propose(dcfg)
 
     # ------------------------------------------------------------ warmup
     def _warmup(self):
@@ -1125,9 +689,6 @@ class LLMServer:
         return not self._stop.is_set()
 
     # ---------------------------------------------------------- scheduler
-    def _bucket(self, n: int) -> int:
-        return _bucket_for(n, self.buckets)
-
     def _decode_bucket(self) -> int:
         """Smallest attended-prefix bucket covering every active slot's
         end position after this chunk (dense plane)."""
@@ -1136,16 +697,21 @@ class LLMServer:
             if self.slot_req[s] is not None:
                 high = max(high,
                            int(self.slot_len[s]) + self.decode_chunk)
-        for b in self.decode_buckets:
-            if high <= b:
-                return b
-        return self.decode_buckets[-1]
+        return _bucket_for(min(high, self.max_len), self.decode_buckets)
 
     def _nb_bucket(self, nb: int) -> int:
-        for b in self._nb_buckets:
-            if nb <= b:
-                return b
-        return self._nb_buckets[-1]
+        return _bucket_for(min(nb, self._nb_buckets[-1]), self._nb_buckets)
+
+    def _block_tables(self, snapshot) -> np.ndarray:
+        """The running slots' block tables, padded to one bucketed
+        width: (max_slots, nb) int32."""
+        nb = self._nb_bucket(max(
+            len(self.slot_table[s]) for s, _r, _l in snapshot))
+        bt = np.full((self.max_slots, nb), self._pad_block, np.int32)
+        for s, _req, _l in snapshot:
+            blocks = self.slot_table[s].blocks[:nb]
+            bt[s, :len(blocks)] = blocks
+        return bt
 
     # ----------------------------------------------- admission (EDF plane)
     def _see(self, req: _Request):
@@ -1528,27 +1094,11 @@ class LLMServer:
     def _extract_kv(self, req: _Request, table) -> None:
         """Copy a finished prefill-role request's prompt blocks out of
         the pool (host copies: the pool buffer is donated into the
-        next device call, so views must not escape this thread).
-        The gather runs ON DEVICE — materializing the whole pool to
-        host would move the full pool bytes per request on a real
-        accelerator (np.asarray only aliases on the CPU backend)."""
-        jnp = self._jnp
+        next device call, so views must not escape this thread;
+        np.asarray only aliases on the CPU backend)."""
         n = -(-len(req.prompt) // self.block_size)
-        idx = jnp.asarray(np.asarray(table.blocks[:n], np.int32))
-        kb = jnp.take(self.pool["k"], idx, axis=0)
-        vb = jnp.take(self.pool["v"], idx, axis=0)
-        if self._kv_fmt is not None:
-            # Handoffs travel FULL PRECISION so a quantized prefill
-            # replica can feed a bf16 decode replica (and vice versa);
-            # the ingest side requantizes on inject.
-            from ray_tpu.models import llama
-
-            kb = llama.dequantize_kv_blocks(
-                kb, jnp.take(self.pool["k_scale"], idx, axis=0),
-                self.cfg.dtype)
-            vb = llama.dequantize_kv_blocks(
-                vb, jnp.take(self.pool["v_scale"], idx, axis=0),
-                self.cfg.dtype)
+        kb, vb = self._blocks.extract(self.pool, self._jnp.asarray(
+            np.asarray(table.blocks[:n], np.int32)))
         req.kv = (np.asarray(kb), np.asarray(vb))
 
     def _finish(self, slot: int):
@@ -1635,14 +1185,8 @@ class LLMServer:
                     self._process(pending)  # overlaps the launched chunk
                 self._harvest_prefills()
                 pending = launched
-                if pending is None and not any(
-                        r is not None for r in self.slot_req) \
-                        and not self._backlog:
-                    # Idle: block for work instead of spinning.
-                    try:
-                        self._see(self._queue.get(timeout=0.05))
-                    except queue.Empty:
-                        pass
+                if pending is None:
+                    self._wait_if_idle()
         except BaseException as e:  # noqa: BLE001
             self._fatal(e)
 
@@ -1657,16 +1201,20 @@ class LLMServer:
             while not self._stop.is_set():
                 self._admit_wave()
                 self._harvest_prefills()
-                did = self._spec_round()
-                if not did and not any(
-                        r is not None for r in self.slot_req) \
-                        and not self._backlog:
-                    try:
-                        self._see(self._queue.get(timeout=0.05))
-                    except queue.Empty:
-                        pass
+                if not self._spec_round():
+                    self._wait_if_idle()
         except BaseException as e:  # noqa: BLE001
             self._fatal(e)
+
+    def _wait_if_idle(self):
+        """No slot occupied, nothing backlogged: block for work instead
+        of spinning."""
+        if not any(r is not None for r in self.slot_req) \
+                and not self._backlog:
+            try:
+                self._see(self._queue.get(timeout=0.05))
+            except queue.Empty:
+                pass
 
     def _slot_ctx(self, req: _Request) -> int:
         return len(req.prompt) + len(req.tokens)
@@ -1702,8 +1250,7 @@ class LLMServer:
             pos[s] = self._slot_ctx(req) - 1
             high = max(high, int(pos[s]) + k + 1)
         t0 = time.perf_counter()
-        sa = next((b for b in self.decode_buckets if high <= b),
-                  self.decode_buckets[-1])
+        sa = _bucket_for(min(high, self.max_len), self.decode_buckets)
         info = (len(snapshot), int(self.slot_waiting.sum()),
                 len(self._backlog), int(sa))
         with _device.annotation("serve.spec_draft"):
@@ -1725,12 +1272,7 @@ class LLMServer:
             if k > 1:
                 vtoks[s, 1:] = dtoks[:k - 1, s]
             vpos[s] = pos[s] + np.arange(k, dtype=np.int32)
-        nb = self._nb_bucket(max(
-            len(self.slot_table[s]) for s, _r, _l in snapshot))
-        bt = np.full((B, nb), self._pad_block, np.int32)
-        for s, _req, _l in snapshot:
-            blocks = self.slot_table[s].blocks[:nb]
-            bt[s, :len(blocks)] = blocks
+        bt = self._block_tables(snapshot)
         with _device.annotation("serve.spec_verify"):
             self.pool, g_dev = self._spec_verify(
                 self.params, self.pool, jnp.asarray(vtoks),
@@ -1899,20 +1441,14 @@ class LLMServer:
         # shows the launch stamped with the ambient trace id, so
         # device slices correlate with the cluster timeline.
         if self.paged:
-            nb = self._nb_bucket(max(
-                len(self.slot_table[s]) for s, _r, _l in snapshot))
-            bt = np.full((self.max_slots, nb), self._pad_block,
-                         np.int32)
-            for s, _req, _l in snapshot:
-                blocks = self.slot_table[s].blocks[:nb]
-                bt[s, :len(blocks)] = blocks
+            bt = self._block_tables(snapshot)
             with _device.annotation("serve.decode_chunk"):
                 self.pool, toks, self._tok_dev, self._len_dev, load = \
                     self._decode_paged(self.params, self.pool,
                                        self._tok_dev, self._len_dev,
                                        *ov_args, jnp.asarray(bt),
                                        k=int(k))
-            sa = nb * self.block_size
+            sa = bt.shape[1] * self.block_size
         else:
             sa = self._decode_bucket()
             with _device.annotation("serve.decode_chunk"):
@@ -2030,10 +1566,10 @@ class LLMServer:
                 break
 
     def _expert_attrs(self, load: tuple, program: str) -> Dict[str, int]:
-        """A device program's expert load (``_expert_load``), read where
-        its tokens were just read (the program is done: no new sync), as
-        span attributes and the ``ray_tpu_serve_moe_*`` series.  Nothing
-        for a dense model."""
+        """A device program's expert load (``llama_serve._expert_load``),
+        read where its tokens were just read (the program is done: no
+        new sync), as span attributes and the ``ray_tpu_serve_moe_*``
+        series.  Nothing for a dense model."""
         if not load:
             return {}
         rows = np.asarray(load[0])                       # (L, E)
@@ -2328,26 +1864,3 @@ class LLMServer:
         if stop is not None:
             stop.set()
 
-
-def _masked_attend(q, keys, vals, q_pos, key_abs, key_valid, scale,
-                   jnp, jax):
-    """Cache attention with EXPLICIT key positions/validity — the warm
-    (prefix-hit) prefill attends [gathered prefix blocks || suffix],
-    where a key's gathered index no longer equals its absolute
-    position for the suffix half.  q: (G, P, Hq, D); keys/vals:
-    (G, S, Hkv, D); q_pos: (G, P); key_abs/key_valid: (G, S)."""
-    G, P, Hq, D = q.shape
-    Hkv = keys.shape[2]
-    group = Hq // Hkv
-    qg = q.reshape(G, P, Hkv, group, D)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, keys,
-                        preferred_element_type=jnp.float32) * scale
-    mask = (key_valid[:, None, None, None, :]
-            & (key_abs[:, None, None, None, :]
-               <= q_pos[:, None, None, :, None]))
-    scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1).astype(vals.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vals,
-                     preferred_element_type=jnp.float32).astype(
-        vals.dtype)
-    return out.reshape(G, P, Hq, D)

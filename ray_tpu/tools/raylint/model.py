@@ -561,10 +561,6 @@ class _ParseCache:
 
     _memo: Dict[str, ast.Module] = {}
     _MAX_ENTRIES = 4096  # ~40 MiB worst case; clear-all on overflow
-    # Process-lifetime hit/miss counters: bench.py's raylint phase
-    # reports the hit rate so the memo's payoff is tracked across PRs.
-    _hits = 0
-    _misses = 0
 
     def __init__(self, enabled: bool):
         self._enabled = enabled
@@ -573,15 +569,6 @@ class _ParseCache:
     def open(cls, root: str) -> "_ParseCache":
         return cls(os.environ.get("RAY_TPU_RAYLINT_CACHE", "") != "0")
 
-    @classmethod
-    def stats(cls) -> Dict[str, int]:
-        return {"hits": cls._hits, "misses": cls._misses}
-
-    @classmethod
-    def reset_stats(cls) -> None:
-        cls._hits = 0
-        cls._misses = 0
-
     @staticmethod
     def _key(raw: bytes) -> str:
         return hashlib.sha1(raw).hexdigest()
@@ -589,12 +576,7 @@ class _ParseCache:
     def get(self, raw: bytes) -> Optional[ast.Module]:
         if not self._enabled:
             return None
-        tree = self._memo.get(self._key(raw))
-        if tree is None:
-            _ParseCache._misses += 1
-        else:
-            _ParseCache._hits += 1
-        return tree
+        return self._memo.get(self._key(raw))
 
     def put(self, raw: bytes, tree: ast.Module) -> None:
         if not self._enabled:

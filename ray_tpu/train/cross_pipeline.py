@@ -410,8 +410,7 @@ class CrossSlicePipeline:
             # tokens/s gauge.
             step_s = _time.perf_counter() - t0
             # Model-plane series: per-step tokens/s (+ MFU where the
-            # chip roofline is known) — profile_mfu.py's numbers,
-            # live.  The roofline is the GANG's: kind + distinct chip
+            # chip roofline is known).  The roofline is the GANG's: kind + distinct chip
             # count come from the stage workers, not the driver (a
             # CPU driver orchestrating TPU stages would otherwise
             # never export MFU, and a multi-stage gang would report

@@ -6,9 +6,8 @@ bias-corrected update tree, a weight-decay tree, and the final
 ``apply_updates`` tree — and every intermediate tree is a full set of
 f32 param-sized HBM buffers XLA must materialize between
 transformations.  At the 435M bench the optimizer slice of the step is
-pure HBM bandwidth (measured via ``profile_mfu.py``'s
-``step_s - grad_s``), so the fused variant computes the SAME math in
-ONE ``tree_map`` pass per leaf:
+pure HBM bandwidth (step time less gradient time), so the fused
+variant computes the SAME math in ONE ``tree_map`` pass per leaf:
 
     gscale    = min(1, clip / ||g||)          (one global reduction)
     mu        = b1*mu + (1-b1)*g'

@@ -151,13 +151,6 @@ def test_untileable_shape_raises_on_tpu_instead_of_einsum(monkeypatch):
     assert not hasattr(fa, "_einsum_fallback")
 
 
-def test_bench_refuses_to_run_without_the_chip():
-    proc = _run([sys.executable, os.path.join(REPO, "bench.py")],
-                env={"JAX_PLATFORMS": "cpu"}, cwd=REPO)
-    assert proc.returncode != 0
-    assert "'cpu'" in proc.stderr and '"metric"' not in proc.stdout
-
-
 # ------------------------------------------------------- chip_smoke.py
 def test_chip_smoke_exits_nonzero_naming_a_cpu_platform():
     proc = _run([sys.executable, os.path.join(REPO, "chip_smoke.py")],

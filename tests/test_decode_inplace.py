@@ -98,10 +98,12 @@ def engine(request, monkeypatch):
     attended as one group of slots; 8 KiB makes it groups of 2 slots at
     64 positions and of 1 slot beyond, as the real widths are attended in
     groups on the chip."""
+    from ray_tpu.models import llama_serve
     from ray_tpu.serve import llm
 
     if request.param is not None:
-        monkeypatch.setattr(llm, "_ATTEND_GROUP_BYTES", request.param)
+        monkeypatch.setattr(llama_serve, "_ATTEND_GROUP_BYTES",
+                            request.param)
     servers = []
 
     def build(**kw):
@@ -203,14 +205,14 @@ def test_row_write_matches_masked_select_bit_for_bit(case, engine):
 
 
 def test_attend_group_divides_the_slots_and_fits_the_budget(monkeypatch):
-    from ray_tpu.serve import llm
+    from ray_tpu.models import llama_serve
 
     mib = 1 << 20
-    monkeypatch.setattr(llm, "_ATTEND_GROUP_BYTES", 8 * mib)
-    assert llm._attend_group(120, mib) == 8            # cell 3 at 512
-    assert llm._attend_group(32, 5 * mib // 2) == 2    # cell 4 at 1,280
-    assert llm._attend_group(4, 64 * 64) == 4          # a toy: one group
-    assert llm._attend_group(7, 100 * mib) == 1        # a prime, too large
+    monkeypatch.setattr(llama_serve, "_ATTEND_GROUP_BYTES", 8 * mib)
+    assert llama_serve._attend_group(120, mib) == 8    # cell 3 at 512
+    assert llama_serve._attend_group(32, 5 * mib // 2) == 2   # cell 4
+    assert llama_serve._attend_group(4, 64 * 64) == 4  # a toy: one group
+    assert llama_serve._attend_group(7, 100 * mib) == 1  # prime, too large
 
 
 # ------------------------------------------- the real widths, for the chip
@@ -298,7 +300,7 @@ def test_decode_k_at_real_widths_updates_the_cache_in_place(
     from benchmarks.tests.test_aot_real_widths import (_engine_programs,
                                                        _json)
 
-    from ray_tpu.serve.llm import _ATTEND_GROUP_BYTES
+    from ray_tpu.models.llama_serve import _ATTEND_GROUP_BYTES
 
     engine = _json("workloads", cell)["engine"]
     slots, max_len = engine["max_slots"], engine["max_len"]

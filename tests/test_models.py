@@ -74,28 +74,59 @@ def test_fused_optimizer_loss_parity(cfg):
     (train/optim.py) reproduces the optax chain's trajectory — loss,
     grad norm, and params track to float tolerance over real steps
     (it IS the same math: clip trigger semantics, bias correction,
-    decoupled weight decay)."""
+    decoupled weight decay).
+
+    The TRAJECTORY is held in float32 compute, where it stays within
+    1e-7.  In bfloat16 compute (the debug config's own) the two part for
+    a reason that is not the optimizer's: from the same state a fused
+    step lands within one float32 ulp (1.5e-8) of the optax step, and
+    that ulp flips the bfloat16 rounding of a weight here and there (2
+    weights after step 2, 1,633 after step 7), each a 2^-8 change of the
+    weight as the forward pass sees it; by step 7 ``grad_norm`` has
+    parted by 1.1e-3 and the loss by 9e-5 (measured; in float32 compute
+    no rounded weight ever differs).  So in bfloat16 every step is held
+    from the SAME state, to that ulp."""
+    import dataclasses
+
+    from ray_tpu.train.optim import FusedAdamWState
+
     toks = jax.random.randint(jax.random.key(5), (8, 32), 0,
                               cfg.vocab_size)
     batch = {"tokens": toks}
-    ref = init_train_state(jax.random.key(0), cfg)
-    ref_step = make_train_step(cfg, donate=False)
-    fused = init_train_state(jax.random.key(0), cfg, fused=True)
-    fused_step = make_train_step(cfg, donate=False, fused=True)
+
+    def pair(c):
+        return (init_train_state(jax.random.key(0), c),
+                make_train_step(c, donate=False),
+                init_train_state(jax.random.key(0), c, fused=True),
+                make_train_step(c, donate=False, fused=True))
+
+    ref, ref_step, fused, fused_step = pair(
+        dataclasses.replace(cfg, dtype=jnp.float32))
     for i in range(8):
         ref, mr = ref_step(ref, batch)
         fused, mf = fused_step(fused, batch)
-        # Float-reassociation drift compounds through the steps
-        # (~5e-5 relative by step 8); the gate is trajectory parity,
-        # not bit equality.
-        np.testing.assert_allclose(float(mf["loss"]),
-                                   float(mr["loss"]), rtol=1e-3)
-        np.testing.assert_allclose(float(mf["grad_norm"]),
-                                   float(mr["grad_norm"]), rtol=1e-3)
+        for name in ("loss", "grad_norm"):
+            np.testing.assert_allclose(float(mf[name]), float(mr[name]),
+                                       rtol=1e-5)
     for a, b in zip(jax.tree.leaves(fused["params"]),
                     jax.tree.leaves(ref["params"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4)
+                                   atol=1e-6)
+
+    ref, ref_step, _fused, fused_step = pair(cfg)
+    for i in range(8):
+        adam = ref["opt_state"][1][0]       # chain(clip, adamw(...))
+        same = {"params": ref["params"], "step": ref["step"],
+                "opt_state": FusedAdamWState(adam.count, adam.mu, adam.nu)}
+        ref, mr = ref_step(ref, batch)
+        stepped, mf = fused_step(same, batch)
+        for name in ("loss", "grad_norm"):
+            np.testing.assert_allclose(float(mf[name]), float(mr[name]),
+                                       rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(stepped["params"]),
+                        jax.tree.leaves(ref["params"])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-7)
     with pytest.raises(ValueError, match="fused"):
         make_train_step(cfg, optimizer=llama.default_optimizer(),
                         fused=True)
@@ -131,22 +162,16 @@ def test_remat_policy_attn_ffn_matches_full(cfg):
 
 
 def test_remat_policy_registry_consistent():
-    """Unknown policies fail with the catalog named, and the MFU
-    sweep CLI's (deliberately jax-free) duplicate of the catalog
-    stays in sync with models.llama.REMAT_POLICIES."""
+    """Unknown policies fail with the catalog named, and every policy
+    of the catalog resolves."""
     import dataclasses
-    import re
 
     with pytest.raises(ValueError, match="unknown remat_policy"):
         llama._remat_policy(dataclasses.replace(
             LlamaConfig.debug(), remat_policy="bogus"))
-    src = open("profile_mfu.py").read()
-    m = re.search(r"choices=\(([^)]*)\),\s*\n\s*help=\"sweep value",
-                  src)
-    assert m, "profile_mfu.py --remat-policy choices not found"
-    cli = tuple(s.strip().strip('"') for s in m.group(1).split(",")
-                if s.strip())
-    assert cli == llama.REMAT_POLICIES, (cli, llama.REMAT_POLICIES)
+    for policy in llama.REMAT_POLICIES:
+        assert callable(llama._remat_policy(dataclasses.replace(
+            LlamaConfig.debug(), remat_policy=policy)))
 
 
 def test_attn_block_override_matches_default(cfg):

@@ -279,10 +279,17 @@ def test_router_routes_around_saturated_replica(serve_session):
 
 
 def test_backpressure_typed_when_every_replica_full(serve_session):
+    # The replica's actor runs on a thread of this process, so the body
+    # and the test share these: the counts below assume an ORDER, and
+    # the events make it hold instead of racing for it.
+    executing, release = threading.Event(), threading.Event()
+
     @serve.deployment(max_ongoing_requests=1, max_queued_requests=1)
     class Slow:
         async def __call__(self, x):
-            await asyncio.sleep(0.5)
+            executing.set()
+            while not release.is_set():
+                await asyncio.sleep(0.01)
             return x
 
     before = _metric_total("ray_tpu_backpressure_rejections")
@@ -296,6 +303,14 @@ def test_backpressure_typed_when_every_replica_full(serve_session):
         except BackPressureError as e:
             t_rej.append(time.monotonic() - t0)
             rejected.append(e)
+        if i == 0:
+            # The second request is sent once the replica has taken the
+            # first off its one-slot mailbox (a call counts against the
+            # mailbox until it is dequeued): sent earlier it is rejected,
+            # and on a loaded box so are all that follow.  Nor does the
+            # first finish, and free a place, before the last is sent.
+            assert executing.wait(10.0)
+    release.set()
     assert len(accepted) == 2 and len(rejected) == 4
     for e in rejected:
         assert e.retry_after_s is not None and e.retry_after_s > 0

@@ -19,7 +19,7 @@ batching it, so ``chaos.partition_node(substr, dur)`` and
 ``chaos.drop_heartbeats(frac)`` hit exactly the nodes a real
 per-node client would lose.
 
-The soak protocol (test_vcluster.py, bench.py ``head_ops_per_s``):
+The soak protocol (test_vcluster.py):
 
     vc = VCluster(n_nodes=300, lease_ttl_s=2.0, hb_interval_s=0.5)
     vc.start()
