@@ -31,27 +31,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
-
-# A decode step's attention reads one layer's attended K and V for a
-# GROUP of slots at a time.  XLA does not read a slice of the stacked
-# cache from inside the attention: it copies the slice out first, to HBM
-# if it is large, on chip (VMEM) if it is small enough.  This is the
-# "small enough" for one of K and V.  On a v5e at the benchmark's widths
-# (PERF.md section 6, PR 24) slices up to 25 MB were staged on chip, but
-# from 16 MiB up XLA also parked a weight stack there and moved it out
-# and back in every layer; at 4 to 12 MiB it did neither.
-# tests/test_decode_inplace.py holds both at the real widths.
-_ATTEND_GROUP_BYTES = 8 << 20
-
-
-def _attend_group(slots: int, slot_bytes: int) -> int:
-    """Slots a decode step attends at a time: the most, dividing the
-    slot count, whose attended K (or V) of one layer fits
-    ``_ATTEND_GROUP_BYTES``."""
-    group = max(1, min(slots, _ATTEND_GROUP_BYTES // slot_bytes))
-    while slots % group:
-        group -= 1
-    return group
+from ray_tpu.ops.decode_attention import decode_attention
 
 
 def _expert_load(expert_rows):
@@ -70,13 +50,17 @@ def _expert_load(expert_rows):
 def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     """The shared per-token decode step (scan body): a row write of
     each slot's new K/V at its current position, cache attention
-    over the first ``s_active`` positions, greedy argmax fed back
-    in-graph.  The carry holds the WHOLE stacked (L, B, S, Hkv, D)
-    K and V through the token loop and the layer loop, so XLA's
-    while loops alias them in place: a step reads each layer's
-    attended prefix once and writes B rows per layer, nothing of
-    the cache's shape is rebuilt (120 rows scatter in ~15 us on a v5e;
-    PERF.md section 5).  IDENTICAL math for the dense
+    over the row's keys among the first ``s_active`` positions
+    (``ops/decode_attention.py``: one Mosaic call a layer whose operand
+    is the whole cache), greedy argmax fed back in-graph.  The carry
+    holds the WHOLE stacked (L, B, S, Hkv, D) K and V through the token
+    loop and the layer loop, so XLA's while loops alias them in place: a
+    step reads each live row's keys once, as far as the row is long, and
+    writes B rows per layer; nothing of the cache's, a layer's or a
+    prefix's shape is made (120 rows scatter in ~15 us on a v5e;
+    PERF.md section 5; ``tests/test_decode_inplace.py`` holds it at the
+    real widths).  ``s_active`` bounds the keys of a row that has run
+    past its bucket.  IDENTICAL math for the dense
     cache and the paged gathered layout — block ordering makes
     gathered index == absolute position, which is what keeps the
     two planes' tokens bit-identical.  The speculative DRAFT model
@@ -110,30 +94,6 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         pos = jnp.where(active & (lens < s_active), lens,
                         ck.shape[2])
         scale = cfg.head_dim ** -0.5
-        group = _attend_group(
-            slots, s_active * cfg.n_kv_heads * cfg.head_dim
-            * jnp.dtype(ck.dtype).itemsize)
-
-        def attend(q, ck, cv, l):
-            # A group of slots at a time, so that a group's
-            # attended K and V of the layer are staged on chip:
-            # asked for all slots at once, XLA copies the layer's
-            # whole prefix out to HBM first and reads it back.
-            def prefix(c, lo):
-                return jax.lax.dynamic_slice(
-                    c, (l, lo, 0, 0, 0),
-                    (1, group, s_active) + c.shape[3:])[0]
-
-            def one(lo):
-                return llama._cache_attend(
-                    jax.lax.dynamic_slice_in_dim(q, lo, group),
-                    prefix(ck, lo), prefix(cv, lo),
-                    jax.lax.dynamic_slice_in_dim(
-                        lens, lo, group)[:, None], scale)
-
-            out = jax.lax.map(one, jnp.arange(
-                0, slots, group, dtype=jnp.int32))
-            return out.reshape(q.shape)
 
         def body(carry, layer_and_index):
             x, ck, cv = carry
@@ -146,7 +106,10 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
             cv = cv.at[l, rows, pos].set(
                 vv[:, 0].astype(cv.dtype), mode="drop",
                 indices_are_sorted=True, unique_indices=True)
-            attn = attend(q, ck, cv, l)
+            # The kernel reads the carry where it lies, each row as far
+            # as it is long; an inactive row's zeros are discarded below.
+            attn = decode_attention(q[:, 0], ck, cv, l, lens, active,
+                                    s_active=s_active, scale=scale)[:, None]
             x, _aux, expert_rows = llama.attn_out_ffn(
                 x, attn, {**layer, **stacks}, cfg,
                 valid=active[:, None], layer_index=l)

@@ -418,6 +418,16 @@ def serve_engine_counters():
             "decode-chunk token-steps computed (chunk length x "
             "max_slots per chunk); kept / computed = slot utilization",
             tag_keys=("deployment",)),
+        "decode_kv_positions_attended": Counter(
+            "ray_tpu_serve_decode_kv_positions_attended_total",
+            "cache positions the live rows held when a decode chunk was "
+            "launched: what its attention has to read",
+            tag_keys=("deployment",)),
+        "decode_kv_positions_bucket": Counter(
+            "ray_tpu_serve_decode_kv_positions_bucket_total",
+            "max_slots x attended length bucket per decode chunk: what "
+            "reading every slot's whole bucket costs; attended / bucket "
+            "= share of it that is needed", tag_keys=("deployment",)),
         "prefill_prompt_tokens": Counter(
             "ray_tpu_serve_prefill_prompt_tokens_total",
             "prompt tokens the prefill programs were asked to compute",

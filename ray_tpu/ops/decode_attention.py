@@ -1,0 +1,260 @@
+"""Decode attention for TPU (Pallas): one query a row against the K/V
+cache where it lies, each row read only as far as it is long.
+
+A decode step attends ONE new query per slot against that slot's cached
+keys.  Written in XLA, the step first copies a layer's attended prefix
+out of the stacked ``(L, B, S, Hkv, D)`` cache (a ``dynamic_slice`` has a
+static size: the whole length bucket of every slot, live or not) and
+then reads the copy again to attend it.  This kernel's operand is the
+whole stack:
+
+- K and V stay in HBM (``memory_space=ANY``); the layer index and the
+  keys each row attends come as scalar-prefetch operands, and the kernel
+  issues its own copies of ``block_k`` positions at a time, double
+  buffered, the next block (of this row or of the next row that attends
+  anything) in flight while this one is computed.  Blocks past a row's
+  last key are neither fetched nor computed; a row that attends nothing
+  costs a scalar comparison and gives zeros.
+- The cache's rows are ``(position, kv head)`` pairs of ``D`` lanes
+  (``(L, B, S * Hkv, D)``: the same bytes, no copy).  Slicing one head's
+  rows out of a block would be a strided relayout of packed bf16; instead
+  ALL query heads are multiplied against ALL of a block's rows in one
+  matmul and the pairs whose kv head is not the query head's own are
+  masked (``bias``).  The MXU's cost is the latching of K's tiles, which
+  is the same either way, and the masked probabilities are exact zeros,
+  so ``P @ V`` over the same rows is the grouped product.
+- Online softmax over a row's blocks: scores and statistics in float32,
+  probabilities cast to the cache's dtype before ``P @ V`` with float32
+  accumulation (``llama._cache_attend``'s precisions, another order of
+  summation).  Keys past a row's length are masked by SELECTION, in the
+  scores and in V, so whatever lies there (stale rows, NaN) changes
+  nothing.
+
+GQA is ``group = Hq // Hkv`` query heads per kv head in ``bias``; MHA is
+``group == 1``.  ``block_k`` follows from the bytes of a position.
+
+Interpret mode runs the same kernel on the CPU for the test suite; what
+decides is ``flash_attention._use_interpret``, looked up at call time (a
+test that compiles for a described chip steers that one function).
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# The MODULE: ``ray_tpu.ops.flash_attention`` as an attribute is the
+# function of that name.
+_flash = importlib.import_module("ray_tpu.ops.flash_attention")
+
+LANES, NEG_INF = _flash.LANES, _flash.NEG_INF
+# One of K and V of one block: 128 positions of GQA 8 x 128 in bf16, 64
+# of MHA 16 x 128.  A row's last block is read whole, so a larger block
+# reads more past the row's end, and a block costs ~0.1 us beside its
+# bytes at ~750 GB/s: on a v5e 256 KiB read the benchmark's rows fastest
+# (PERF.md section 6, PR 29: 4.6 ms a step against 5.1 at 512 KiB and
+# 6.4 at 1 MiB).  Four of them (K and V, double buffered) and a block's
+# (Hq, block_k * Hkv) float32 scores stay far inside the scoped VMEM.
+_BLOCK_BYTES = 256 << 10
+# Query heads are padded to whole bf16 sublane tiles.
+_HEAD_TILE = 16
+
+
+def block_k(s: int, hkv: int, d: int, itemsize: int) -> int:
+    """Positions per block: the power of two whose K fills
+    ``_BLOCK_BYTES``, at most the cache's length."""
+    bk = max(8, _BLOCK_BYTES // (hkv * d * itemsize))
+    return min(1 << (bk.bit_length() - 1), s)
+
+
+def _tiles(hkv: int, d: int) -> bool:
+    """Whether Mosaic can read the cache as ``(S * Hkv, D)`` rows: whole
+    lanes, and kv heads that fill their sublane tile (XLA pads a
+    second-minor dimension of 6 to 8, and the rows are then not
+    contiguous)."""
+    return d % LANES == 0 and hkv % 8 == 0
+
+
+def _kernel(layer_ref, n_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sems, m_scr, l_scr, acc_scr,
+            *, bk, hkv, s_len, scale):
+    slots = q_ref.shape[0]
+    width = bk * hkv
+    layer = layer_ref[0]
+
+    def next_row(r):
+        """The first row at or after ``r`` that attends a key."""
+        return jax.lax.while_loop(
+            lambda r: (r < slots) & (n_ref[jnp.minimum(r, slots - 1)] == 0),
+            lambda r: r + 1, r)
+
+    def first_pos(j):
+        # The last block of a cache whose length bk does not divide is
+        # moved back inside it; the keys it shares with the block before
+        # are masked below.
+        return jnp.minimum(j * bk, s_len - bk)
+
+    def copies(r, j, slot):
+        rows = pl.ds(pl.multiple_of(first_pos(j) * hkv, hkv), width)
+        return (pltpu.make_async_copy(k_hbm.at[layer, r, rows],
+                                      kbuf.at[slot], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, r, rows],
+                                      vbuf.at[slot], sems.at[1, slot]))
+
+    def start(r, j, slot):
+        for copy in copies(r, j, slot):
+            copy.start()
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+    r0 = next_row(jnp.int32(0))
+
+    @pl.when(r0 < slots)
+    def _first():
+        start(r0, 0, 0)
+
+    def block(state):
+        r, j, slot = state
+        n = n_ref[r]
+        last = (j + 1) * bk >= n
+        r_next = jax.lax.cond(last, lambda: next_row(r + 1), lambda: r)
+        j_next = jnp.where(last, 0, j + 1)
+
+        @pl.when(r_next < slots)
+        def _prefetch():
+            start(r_next, j_next, 1 - slot)
+
+        @pl.when(j == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        # Rows (position, kv head) of this block that are keys of this
+        # row and were not in the block before: [lo, hi) of ``width``.
+        lo = (j * bk - first_pos(j)) * hkv
+        hi = (n - first_pos(j)) * hkv
+        copy_k, copy_v = copies(r, j, slot)
+        copy_k.wait()
+        q = q_ref[r]
+        s = jax.lax.dot_general(q, kbuf[slot], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * scale + bias_ref[...]
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where((col >= lo) & (col < hi), s, NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        copy_v.wait()
+        v = vbuf[slot]
+        row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(row < hi, v, jnp.zeros_like(v))
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        @pl.when(last)
+        def _finalize():
+            # A padded query head's row is masked everywhere: its sum
+            # is the block's width, and it is cut off outside.
+            o_ref[r] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+
+        return r_next, j_next, 1 - slot
+
+    jax.lax.while_loop(lambda state: state[0] < slots, block,
+                       (r0, jnp.int32(0), jnp.int32(0)))
+
+
+def _head_bias(hq_pad: int, hq: int, hkv: int, bk: int) -> np.ndarray:
+    """(hq_pad, bk * hkv) float32: 0 where a block row's kv head is the
+    query head's own, ``NEG_INF`` elsewhere."""
+    own = np.arange(hq_pad) // (hq // hkv)
+    own[hq:] = -1
+    head = np.arange(bk * hkv) % hkv
+    return np.where(own[:, None] == head[None, :], 0.0,
+                    NEG_INF).astype(np.float32)
+
+
+def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                     layer: jax.Array, lens: jax.Array, active: jax.Array,
+                     *, s_active: int, scale: float) -> jax.Array:
+    """One query a row against layer ``layer`` of a stacked cache.
+
+    q: (B, Hq, D); ck/cv: the WHOLE (L, B, S, Hkv, D) cache, the row of
+    position ``lens`` already written; lens: (B,) int32; active: (B,)
+    bool.  Row b attends keys ``[0, min(lens[b] + 1, s_active))`` if it
+    is active and gives zeros if not.  -> (B, Hq, D) in the cache's
+    dtype.
+
+    On a TPU a cache Mosaic cannot read as rows (kv heads that do not
+    fill a sublane tile, a head that is not whole lanes) is attended by
+    XLA, as ``llama._cache_attend`` over the layer's prefix."""
+    B, hq, d = q.shape
+    L, _, S, hkv, _ = ck.shape
+    if hq % hkv:
+        raise ValueError(f"Hq={hq} not a multiple of Hkv={hkv}")
+    n = jnp.where(active, jnp.minimum(lens + 1, s_active), 0)
+    interpret = _flash._use_interpret()
+    if not interpret and not _tiles(hkv, d):
+        return _xla_decode_attention(q, ck, cv, layer, n, s_active, scale)
+
+    bk = block_k(S, hkv, d, ck.dtype.itemsize)
+    hq_pad = -(-hq // _HEAD_TILE) * _HEAD_TILE
+    rows = (L, B, S * hkv, d)
+    kernel = functools.partial(_kernel, bk=bk, hkv=hkv, s_len=S,
+                               scale=scale)
+    whole = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    attend = pl.pallas_call(
+        kernel,
+        name="decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[
+                whole((B, hq_pad, d), lambda i, *_: (0, 0, 0)),
+                whole((hq_pad, bk * hkv), lambda i, *_: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=whole((B, hq_pad, d), lambda i, *_: (0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, bk * hkv, d), ck.dtype),
+                pltpu.VMEM((2, bk * hkv, d), cv.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hq_pad, LANES), jnp.float32),
+                pltpu.VMEM((hq_pad, LANES), jnp.float32),
+                pltpu.VMEM((hq_pad, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, hq_pad, d), cv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )
+    q = jnp.pad(q.astype(ck.dtype), ((0, 0), (0, hq_pad - hq), (0, 0)))
+    with jax.named_scope("decode_attention"):
+        out = attend(jnp.asarray(layer, jnp.int32).reshape(1), n, q,
+                     jnp.asarray(_head_bias(hq_pad, hq, hkv, bk)),
+                     ck.reshape(rows), cv.reshape(rows))
+    return out[:, :hq]
+
+
+def _xla_decode_attention(q, ck, cv, layer, n, s_active, scale):
+    from ray_tpu.models.llama import _cache_attend
+
+    def prefix(c):
+        return jax.lax.dynamic_slice(
+            c, (layer, 0, 0, 0, 0), (1,) + c.shape[1:2] + (s_active,)
+            + c.shape[3:])[0]
+
+    out = _cache_attend(q[:, None], prefix(ck), prefix(cv),
+                        (n - 1)[:, None], scale)[:, 0]
+    return jnp.where((n > 0)[:, None, None], out, jnp.zeros_like(out))

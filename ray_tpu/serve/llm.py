@@ -1252,7 +1252,7 @@ class LLMServer:
         t0 = time.perf_counter()
         sa = _bucket_for(min(high, self.max_len), self.decode_buckets)
         info = (len(snapshot), int(self.slot_waiting.sum()),
-                len(self._backlog), int(sa))
+                len(self._backlog), int(sa), int(pos.sum()))
         with _device.annotation("serve.spec_draft"):
             self.draft_cache, dts = self._draft_propose(
                 self.draft_params, self.draft_cache, jnp.asarray(tok),
@@ -1462,7 +1462,8 @@ class LLMServer:
             self.slot_len[s] += k
         # What the chunk was launched over (serve.chunk's args).
         info = (len(snapshot), int(self.slot_waiting.sum()),
-                len(self._backlog), int(sa))
+                len(self._backlog), int(sa),
+                sum(len0 for _s, _req, len0 in snapshot))
         return (toks, snapshot, k, t0, info, load)
 
     def _process(self, pending):
@@ -1590,19 +1591,27 @@ class LLMServer:
                       kept: int, load: tuple = ()) -> None:
         """``serve.chunk`` (launch -> harvest returned) and the decode
         counters: token-steps computed (k x max_slots, whatever is
-        occupied) against tokens kept (appended to a live request); for
-        a model with experts, the rows they computed."""
+        occupied) against tokens kept (appended to a live request); the
+        cache positions the live rows held at launch (what the decode
+        attention has to read) against max_slots x s_active (the
+        attended bucket of every slot); for a model with experts, the
+        rows they computed."""
         if not _tracing.enabled():
             return
         computed = k * self.max_slots
+        active, waiting, backlog, s_active, attended = info
+        bucket = self.max_slots * s_active
         m = self._engine_metrics
         m["decode_tokens_kept"].inc(kept, tags=self._tags)
         m["decode_slot_steps"].inc(computed, tags=self._tags)
-        active, waiting, backlog, s_active = info
+        m["decode_kv_positions_attended"].inc(attended, tags=self._tags)
+        m["decode_kv_positions_bucket"].inc(bucket, tags=self._tags)
         self._span("serve.chunk", t0, t1, {
             "k": k, "active": active, "waiting": waiting,
             "backlog": backlog, "s_active": s_active,
             "tokens_kept": kept, "token_steps": computed,
+            "kv_positions_attended": attended,
+            "kv_positions_bucket": bucket,
             **self._expert_attrs(load, "decode")},
             f"{self._lane}/chunks")
 

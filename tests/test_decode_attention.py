@@ -1,0 +1,151 @@
+"""``ops/decode_attention.py`` interpreted on the CPU against
+``llama._cache_attend`` over the layer's attended prefix: the same keys,
+the same precisions, another order of summation."""
+
+import importlib
+
+import numpy as np
+import pytest
+
+# name: L, B, S, Hq, Hkv, D, layer, s_active
+_SHAPES = {
+    "gqa_16_8_x128": (2, 8, 512, 16, 8, 128, 0, 512),
+    "mha_16_16_x128": (2, 8, 256, 16, 16, 128, 0, 256),
+    "toy_hkv2_d16": (2, 8, 128, 4, 2, 16, 0, 128),
+    "layer_2_of_a_stack": (3, 8, 128, 4, 2, 16, 2, 128),
+    "bucket_shorter_than_cache": (2, 8, 512, 16, 8, 128, 1, 256),
+    "block_does_not_divide_cache": (1, 8, 200, 16, 8, 128, 0, 200),
+}
+_TOL = 2e-2       # bf16: eight bits of mantissa on values of order one
+
+
+def _lengths(bk, s_active):
+    """0, 1, around a block's edge, the bucket's last position, past it."""
+    return np.asarray([0, 1, bk - 1, bk, bk + 1, s_active - 1,
+                       s_active, s_active + 7], np.int32)
+
+
+def _inputs(shape, seed=0):
+    import jax
+    import jax.numpy as jnp
+
+    L, B, S, hq, hkv, d, _layer, _s_active = shape
+    kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(kq, (B, hq, d), jnp.bfloat16),
+            jax.random.normal(kk, (L, B, S, hkv, d), jnp.bfloat16),
+            jax.random.normal(kv, (L, B, S, hkv, d), jnp.bfloat16))
+
+
+def _reference(q, ck, cv, layer, lens, active, s_active):
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    out = llama._cache_attend(
+        q[:, None], ck[layer, :, :s_active], cv[layer, :, :s_active],
+        lens[:, None], q.shape[-1] ** -0.5)[:, 0]
+    return jnp.where(active[:, None, None], out, 0)
+
+
+@pytest.mark.parametrize("rows", ["edge_lengths", "some_inactive",
+                                  "nan_past_the_length"])
+@pytest.mark.parametrize("name", list(_SHAPES))
+def test_kernel_agrees_with_cache_attend(name, rows):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import decode_attention as da
+
+    shape = _SHAPES[name]
+    L, B, S, hq, hkv, d, layer, s_active = shape
+    q, ck, cv = _inputs(shape, seed=len(name))
+    bk = da.block_k(S, hkv, d, ck.dtype.itemsize)
+    lens = jnp.asarray(_lengths(min(bk, s_active - 2), s_active))
+    active = jnp.asarray(
+        [True, False, True, True, False, True, True, False]
+        if rows == "some_inactive" else [True] * B)
+    want = _reference(q, ck, cv, layer, lens, active, s_active)
+    if rows == "nan_past_the_length":
+        # What lies past a row's last key must be masked by selection:
+        # a probability of zero times NaN is NaN.
+        past = jnp.arange(S)[None, :] > lens[:, None]
+        ck, cv = (jnp.where(past[None, :, :, None, None], jnp.nan, c)
+                  for c in (ck, cv))
+    got = jax.jit(da.decode_attention,
+                  static_argnames=("s_active", "scale"))(
+        q, ck, cv, jnp.int32(layer), lens, active, s_active=s_active,
+        scale=d ** -0.5)
+    assert got.shape == want.shape and got.dtype == cv.dtype
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=_TOL, rtol=_TOL)
+    assert (got[~np.asarray(active)] == 0).all()
+
+
+def test_nothing_active_gives_zeros_and_reads_nothing():
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import decode_attention as da
+
+    shape = _SHAPES["toy_hkv2_d16"]
+    q, ck, cv = _inputs(shape)
+    nan = jnp.full_like(ck, jnp.nan)         # a read would show
+    got = da.decode_attention(
+        q, nan, nan, jnp.int32(1), jnp.arange(8, dtype=jnp.int32),
+        jnp.zeros(8, bool), s_active=128, scale=0.25)
+    assert (np.asarray(got, np.float32) == 0).all()
+
+
+def test_xla_path_for_a_cache_mosaic_cannot_tile_agrees_too():
+    """On a TPU, kv heads that do not fill a sublane tile (the default
+    preset's 6) are attended by XLA: same keys, same zeros."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import decode_attention as da
+
+    assert da._tiles(8, 128) and da._tiles(16, 128)
+    assert not da._tiles(6, 128) and not da._tiles(8, 64)
+    shape = _SHAPES["layer_2_of_a_stack"]
+    _L, B, _S, _hq, _hkv, d, layer, s_active = shape
+    q, ck, cv = _inputs(shape)
+    lens = jnp.asarray(_lengths(32, s_active))
+    active = jnp.asarray([True, True, False, True] * 2)
+    n = jnp.where(active, jnp.minimum(lens + 1, s_active), 0)
+    got = da._xla_decode_attention(q, ck, cv, jnp.int32(layer), n,
+                                   s_active, d ** -0.5)
+    want = _reference(q, ck, cv, layer, lens, active, s_active)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_block_follows_from_the_bytes_of_a_position():
+    from ray_tpu.ops.decode_attention import block_k
+
+    assert block_k(512, 8, 128, 2) == 128       # GQA 16/8 x 128, bf16
+    assert block_k(512, 16, 128, 2) == 64       # MHA 16/16 x 128
+    assert block_k(1280, 8, 128, 2) == 128
+    assert block_k(64, 2, 16, 2) == 64          # a toy: the whole cache
+    assert block_k(512, 8, 128, 4) == 64        # a float32 cache
+
+
+def test_interpret_is_asked_of_flash_attention_at_call_time(monkeypatch):
+    """The benchmark's compile-for-a-described-chip tests steer kernels
+    to Mosaic by replacing ``flash_attention._use_interpret``."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import decode_attention as da
+
+    flash = importlib.import_module("ray_tpu.ops.flash_attention")
+
+    class Asked(Exception):
+        pass
+
+    def asked():
+        raise Asked
+
+    monkeypatch.setattr(flash, "_use_interpret", asked)
+    q, ck, cv = _inputs(_SHAPES["toy_hkv2_d16"])
+    with pytest.raises(Asked):
+        da.decode_attention(q, ck, cv, jnp.int32(0),
+                            jnp.zeros(8, jnp.int32), jnp.ones(8, bool),
+                            s_active=128, scale=0.25)

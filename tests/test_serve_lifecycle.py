@@ -16,7 +16,9 @@ PHASES = ("serve.wait_boundary", "serve.wait_slot", "serve.wait_prefill",
 COUNTERS = ("ray_tpu_serve_decode_tokens_kept_total",
             "ray_tpu_serve_decode_slot_steps_total",
             "ray_tpu_serve_prefill_prompt_tokens_total",
-            "ray_tpu_serve_prefill_padded_tokens_total")
+            "ray_tpu_serve_prefill_padded_tokens_total",
+            "ray_tpu_serve_decode_kv_positions_attended_total",
+            "ray_tpu_serve_decode_kv_positions_bucket_total")
 ENGINE = dict(model_preset="debug", max_slots=4, max_len=128,
               prefill_buckets=(32, 64), decode_chunk=4,
               prefill_groups=(2, 4))
@@ -150,7 +152,16 @@ def test_request_life_under_the_handles_trace(ray_start_regular,
     grown = [after[c] - before[c] for c in COUNTERS]
     assert grown == [kept, steps,
                      sum(g["args"]["prompt_tokens"] for g in groups),
-                     sum(g["args"]["token_positions"] for g in groups)]
+                     sum(g["args"]["token_positions"] for g in groups),
+                     sum(c["args"]["kv_positions_attended"] for c in chunks),
+                     sum(c["args"]["kv_positions_bucket"] for c in chunks)]
+    # a live row holds at least its prompt and less than the bucket
+    assert all(c["args"]["kv_positions_bucket"]
+               == ENGINE["max_slots"] * c["args"]["s_active"]
+               and 4 * c["args"]["active"]
+               <= c["args"]["kv_positions_attended"]
+               < c["args"]["active"] * c["args"]["s_active"]
+               for c in chunks)
     waits = metrics.serve_engine_counters()["queue_wait"].buckets(
         {"deployment": "LLMServer"})
     assert sum(waits) - sum(waits_before) == 7
@@ -186,6 +197,38 @@ def test_every_plane_stamps_the_same_boundaries(fresh_timeline, flavour):
     assert sum(c["args"]["tokens_kept"] for c in chunks) == 5 * 10 - 5
     k = flavour.get("spec_k", 8)
     assert all(c["args"]["token_steps"] == k * 4 for c in chunks)
+
+
+@pytest.mark.parametrize("flavour", [dict(paged=False),
+                                     dict(paged=True, block_size=8)])
+def test_a_chunk_counts_the_positions_its_rows_hold(fresh_timeline,
+                                                    flavour):
+    """One request alone: the chunks launched while it lives find its
+    row 5, 9, 13... positions long, one chunk's steps more each time
+    (the one-deep pipeline may launch one chunk past its end), against
+    ``max_slots x s_active`` positions of bucket."""
+    from ray_tpu.serve.llm import LLMServer
+
+    server = LLMServer(model_preset="debug", max_slots=4, max_len=64,
+                       prefill_buckets=(16,), decode_chunk=4,
+                       prefill_groups=(4,), **flavour)
+    try:
+        out, = _generate(server, [{"prompt": [7, 8, 9, 10, 11],
+                                   "max_new_tokens": 10}])
+    finally:
+        server.shutdown()
+    assert len(out["tokens"]) == 10
+    chunks = sorted(_spans("serve.chunk"), key=lambda e: e["ts"])
+    assert len(chunks) >= 3         # 1 token of prefill + 4 + 4 + 1
+    for i, c in enumerate(chunks):
+        args = c["args"]
+        assert args["active"] == 1
+        assert args["kv_positions_attended"] == 5 + 4 * i
+        assert args["kv_positions_bucket"] == 4 * args["s_active"]
+        assert args["kv_positions_attended"] < args["s_active"]
+    attended = sum(c["args"]["kv_positions_attended"] for c in chunks)
+    bucket = sum(c["args"]["kv_positions_bucket"] for c in chunks)
+    assert 0 < attended / bucket < 0.25     # one row of four, part full
 
 
 def test_shed_and_preempted_requests_leave_outcome_and_count(
