@@ -14,7 +14,9 @@ weights from a seed):
 - serve: ``serve.run(serve.deployment(LLMServer).bind(...))`` with
   ``llama_125m`` on BOTH KV planes (dense and paged): one single
   request, then a small concurrent batch, through
-  ``handle.generate.remote(...).result()``;
+  ``handle.generate.remote(...).result()``; and the same on the dense
+  plane with the toy hybrid ``hybrid_debug`` (Mamba-2 layers beside
+  attention: a recurrent state beside the K/V cache);
 - with >= 4 devices also the four-chip phase: the same train path under
   ``MeshSpec(fsdp=4)`` and ``MeshSpec(fsdp=2, tensor=2)``, and ring
   attention compiled once over ``seq=4``.
@@ -59,6 +61,9 @@ SERVE_ENGINE = dict(model_preset="llama_125m", max_slots=112, max_len=256,
                     prefill_buckets=(32,), decode_chunk=16)
 PAGED_ENGINE = dict(paged=True, block_size=64, max_slots=336,
                     num_blocks=1 + 112 * (256 // 64))
+# A toy hybrid (Mamba-2 layers beside attention, LlamaConfig.hybrid_debug):
+# a recurrent state beside the K/V cache through the same dense plane.
+HYBRID_ENGINE = dict(model_preset="hybrid_debug", max_slots=16, max_len=128)
 
 
 class SmokeFailure(Exception):
@@ -471,7 +476,8 @@ def serve_phase(paged: bool, engine: Optional[dict] = None,
 
 
 # ------------------------------------------------------- process plumbing
-SINGLE_CHIP_PHASES = ("train", "serve_dense", "serve_paged")
+SINGLE_CHIP_PHASES = ("train", "serve_dense", "serve_paged",
+                      "serve_hybrid")
 FOUR_CHIP_PHASES = ("train_fsdp4", "train_fsdp2_tensor2", "ring4")
 
 
@@ -483,6 +489,8 @@ def run_phase(name: str) -> Dict[str, Any]:
                           **train_phase()},
         "serve_dense": lambda: serve_phase(paged=False),
         "serve_paged": lambda: serve_phase(paged=True),
+        "serve_hybrid": lambda: serve_phase(paged=False,
+                                            engine=HYBRID_ENGINE),
         "train_fsdp4": lambda: train_phase(mesh=MeshSpec(fsdp=4)),
         "train_fsdp2_tensor2": lambda: train_phase(
             mesh=MeshSpec(fsdp=2, tensor=2)),

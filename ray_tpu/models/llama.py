@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 PyTree = Any
+LAYER_KINDS = ("attention", "mamba")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,6 +98,74 @@ class LlamaConfig:
     # RMSNorm on q and on k, each over its WHOLE projection, before the
     # split into heads and before RoPE (OLMoE).
     qk_norm: bool = False
+    # ONE PERIOD of the layer stack, a kind per layer: "attention" or
+    # "mamba" (a Mamba-2 mixer, models/mamba2.py, in place of attention;
+    # every layer keeps its FFN).  n_layers is a whole number of periods
+    # and the layer scans run a period an iteration.  () is a period of
+    # one attention layer: the plain decoder.
+    layer_pattern: Tuple[str, ...] = ()
+    # Rotary position embedding on q and k (False: NoPE, Granite 4).
+    rope: bool = True
+    # Softmax scale in place of head_dim ** -0.5, and the Granite
+    # multipliers: on the embedding, on both residual branches, and the
+    # divisor of the logits.
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # The Mamba-2 layers' sizes (one group of ssm_state dimensions shared
+    # by all heads), the chunk their prefill scans by, and the type their
+    # recurrent state is STORED in by the serving cache (the recurrence's
+    # arithmetic is float32 either way).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    ssm_state_dtype: Any = jnp.float32
+    # The type the SERVING programs carry the residual stream in (None:
+    # ``dtype``).  Every branch is added to it, so its rounding is the
+    # largest single source of a deep model's distance from a float32
+    # reference (measured for granite-4.0-h-micro: PERF.md section 6,
+    # PR 30); matmul operands are ``dtype`` either way.
+    stream_dtype: Any = None
+
+    def __post_init__(self):
+        # a configuration file's lists and type names, made hashable
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        for name in ("dtype", "stream_dtype", "ssm_state_dtype"):
+            if isinstance(getattr(self, name), str):
+                object.__setattr__(self, name,
+                                   jnp.dtype(getattr(self, name)).type)
+        unknown = set(self.layer_pattern) - set(LAYER_KINDS)
+        if unknown:
+            raise ValueError(f"layer_pattern: unknown kinds {unknown} "
+                             f"(choose from {LAYER_KINDS})")
+        if self.n_layers % len(self.period):
+            raise ValueError(
+                f"n_layers={self.n_layers} is not a whole number of "
+                f"periods of {len(self.period)} layers")
+        if "mamba" in self.layer_pattern and self.ssm_heads < 1:
+            raise ValueError("a mamba layer needs ssm_heads")
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The kinds of one period of layers."""
+        return self.layer_pattern or ("attention",)
+
+    @property
+    def period_len(self) -> int:
+        return len(self.period)
+
+    def layers_of(self, kind: str) -> int:
+        """How many of the n_layers are of ``kind``."""
+        return (self.n_layers // self.period_len) * self.period.count(kind)
+
+    @property
+    def attn_scale(self) -> float:
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return self.head_dim ** -0.5
 
     @property
     def q_dim(self) -> int:
@@ -120,6 +189,20 @@ class LlamaConfig:
     def moe_debug(cls, **kw) -> "LlamaConfig":
         """Tiny MoE config (expert-parallel dryruns/tests on CPU)."""
         base = dict(moe_experts=4, moe_top_k=2)
+        base.update(kw)
+        return cls.debug(**base)
+
+    @classmethod
+    def hybrid_debug(cls, **kw) -> "LlamaConfig":
+        """Tiny hybrid of Granite 4.0-H's shape (tests, ``chip_smoke``):
+        two periods of (mamba, mamba, attention), NoPE, the four Granite
+        multipliers, prefill chunks of 8."""
+        base = dict(n_layers=6, layer_pattern=("mamba", "mamba",
+                                               "attention"),
+                    rope=False, attention_multiplier=1.0 / 16,
+                    embedding_multiplier=12.0, residual_multiplier=0.22,
+                    logits_scaling=8.0, ssm_heads=4, ssm_head_dim=16,
+                    ssm_state=16, ssm_chunk=8)
         base.update(kw)
         return cls.debug(**base)
 
@@ -217,6 +300,10 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     if config.qk_norm:
         axes["layers"]["q_norm"] = ("layers", None)
         axes["layers"]["k_norm"] = ("layers", None)
+    if config.layers_of("mamba"):
+        from ray_tpu.models import mamba2
+
+        axes["layers"].update(mamba2.param_axes(config))
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -233,14 +320,17 @@ def init_dense(key, shape, fan_in, dtype=jnp.float32):
 def init_params(rng: jax.Array, config: LlamaConfig,
                 dtype: Any = jnp.float32) -> PyTree:
     """Initialize the stacked-layer param pytree (truncated-normal,
-    fan-in scaled; norms at 1)."""
+    fan-in scaled; norms at 1).  A leaf that every layer has (the two
+    norms, the FFN) is stacked over all n_layers; one that only a kind
+    of layer has (``ATTENTION_LEAVES``, a Mamba mixer's ``ssm_*``) over
+    the layers of that kind, in their order."""
     c = config
     keys = jax.random.split(rng, 8)
 
     def dense(key, shape, fan_in):
         return init_dense(key, shape, fan_in, dtype)
 
-    L = c.n_layers
+    L, La = c.n_layers, c.layers_of("attention")
     if c.moe_experts > 0:
         E = c.moe_experts
         ffn = {
@@ -264,23 +354,42 @@ def init_params(rng: jax.Array, config: LlamaConfig,
             "w_down": dense(keys[7], (L, c.intermediate_size, c.hidden_size),
                             c.intermediate_size),
         }
+    embed_tokens = dense(keys[0], (c.vocab_size, c.hidden_size),
+                         c.hidden_size)
+    if c.embedding_multiplier != 1.0:
+        # Drawn so that the embedding the LAYERS see (x the multiplier)
+        # is the one every other configuration starts from.  Drawn at
+        # that scale itself and tied to the head, a row's own logit is
+        # multiplier x |row|^2 ahead of the rest and the model repeats
+        # its input whatever its layers or its states compute (measured
+        # at granite-4.0-h-micro's widths: PERF.md section 6, PR 30).
+        embed_tokens = (embed_tokens.astype(jnp.float32)
+                        / c.embedding_multiplier).astype(dtype)
     params = {
-        "embed_tokens": dense(keys[0], (c.vocab_size, c.hidden_size),
-                              c.hidden_size),
+        "embed_tokens": embed_tokens,
         "layers": {
             "attn_norm": jnp.ones((L, c.hidden_size), dtype),
-            "wq": dense(keys[1], (L, c.hidden_size, c.q_dim), c.hidden_size),
-            "wk": dense(keys[2], (L, c.hidden_size, c.kv_dim), c.hidden_size),
-            "wv": dense(keys[3], (L, c.hidden_size, c.kv_dim), c.hidden_size),
-            "wo": dense(keys[4], (L, c.q_dim, c.hidden_size), c.q_dim),
+            "wq": dense(keys[1], (La, c.hidden_size, c.q_dim),
+                        c.hidden_size),
+            "wk": dense(keys[2], (La, c.hidden_size, c.kv_dim),
+                        c.hidden_size),
+            "wv": dense(keys[3], (La, c.hidden_size, c.kv_dim),
+                        c.hidden_size),
+            "wo": dense(keys[4], (La, c.q_dim, c.hidden_size), c.q_dim),
             "mlp_norm": jnp.ones((L, c.hidden_size), dtype),
             **ffn,
         },
         "final_norm": jnp.ones((c.hidden_size,), dtype),
     }
     if c.qk_norm:
-        params["layers"]["q_norm"] = jnp.ones((L, c.q_dim), dtype)
-        params["layers"]["k_norm"] = jnp.ones((L, c.kv_dim), dtype)
+        params["layers"]["q_norm"] = jnp.ones((La, c.q_dim), dtype)
+        params["layers"]["k_norm"] = jnp.ones((La, c.kv_dim), dtype)
+    if c.layers_of("mamba"):
+        from ray_tpu.models import mamba2
+
+        params["layers"].update(mamba2.init_params(
+            jax.random.fold_in(rng, 98), c, c.layers_of("mamba"), dtype,
+            dense))
     if not c.tie_embeddings:
         params["lm_head"] = dense(
             jax.random.fold_in(rng, 99), (c.hidden_size, c.vocab_size),
@@ -371,7 +480,8 @@ def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
 
 
 def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                  positions: jax.Array) -> jax.Array:
+                  positions: jax.Array,
+                  scale: Optional[float] = None) -> jax.Array:
     """Reference einsum attention, causal, GQA via head broadcast.
 
     q: (B, S, Hq, D); k/v: (B, S, Hkv, D).  All-jnp so XLA fuses; the
@@ -383,7 +493,7 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qg = q.reshape(B, S, Hkv, group, D)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32)
-    scores = scores * (D ** -0.5)
+    scores = scores * (D ** -0.5 if scale is None else scale)
     # Causal mask on absolute positions (supports packed/offset pos).
     mask = positions[:, None, None, :, None] >= positions[:, None, None,
                                                           None, :]
@@ -427,7 +537,7 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     c = config
     B, S, _ = x.shape
     dt = c.dtype
-    h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+    h = rms_norm(x, layer["attn_norm"], c.norm_eps).astype(dt)
     q = matmul(h, layer["wq"].astype(dt))
     k = matmul(h, layer["wk"].astype(dt))
     if c.qk_norm:
@@ -437,8 +547,9 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     k = k.reshape(B, S, c.n_kv_heads, c.head_dim)
     v = matmul(h, layer["wv"].astype(dt)).reshape(B, S, c.n_kv_heads,
                                                   c.head_dim)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
+    if c.rope:
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
     q = with_logical_constraint(q, "batch", "seq", "heads", "head_dim")
     k = with_logical_constraint(k, "batch", "seq", "kv_heads", "head_dim")
     return q, k, v
@@ -458,6 +569,14 @@ def split_expert_stacks(layers: Dict[str, jax.Array],
             {k: layers[k] for k in EXPERT_STACKS})
 
 
+def residual_add(x: jax.Array, branch: jax.Array,
+                 config: LlamaConfig) -> jax.Array:
+    """``x + residual_multiplier * branch``: both branches of a layer."""
+    if config.residual_multiplier != 1.0:
+        branch = branch * config.residual_multiplier
+    return x + branch.astype(x.dtype)
+
+
 def attn_out_ffn(x: jax.Array, attn: jax.Array,
                  layer: Dict[str, jax.Array], config: LlamaConfig,
                  valid: Optional[jax.Array] = None,
@@ -466,7 +585,18 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
     the config says: THE function every path calls (training forward,
     ``prefill_forward``, ``forward_with_cache``, the serve programs; the
     conventions shared with ``_qkv_rope`` live here).  Constraints are
-    no-ops outside a mesh.
+    no-ops outside a mesh.  Returns what ``ffn_half`` does."""
+    B, S, _ = x.shape
+    x = residual_add(x, matmul(attn.reshape(B, S, config.q_dim),
+                               layer["wo"].astype(config.dtype)), config)
+    return ffn_half(x, layer, config, valid, layer_index)
+
+
+def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
+             config: LlamaConfig, valid: Optional[jax.Array] = None,
+             layer_index: Optional[jax.Array] = None):
+    """The FFN half of a layer, after whichever mixer (attention's output
+    projection, a Mamba-2 mixer) has been added to ``x``.
 
     Returns ``(x, aux, expert_rows)``: the layer's Switch aux loss and
     the rows each expert computed ((E,) int32) — a constant 0 and None
@@ -475,11 +605,9 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
     the expert matrices in ``layer`` are the whole ``[L, E, ...]`` stacks
     (``split_expert_stacks``), read in place."""
     c = config
-    B, S, _ = x.shape
     dt = c.dtype
-    x = x + matmul(attn.reshape(B, S, c.q_dim), layer["wo"].astype(dt))
     x = with_logical_constraint(x, "batch", "seq", None)
-    h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
+    h = rms_norm(x, layer["mlp_norm"], c.norm_eps).astype(dt)
     if c.moe_experts == 0:
         gate = matmul(h, layer["w_gate"].astype(dt))
         up = matmul(h, layer["w_up"].astype(dt))
@@ -489,7 +617,7 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
 
         ff = checkpoint_name(jax.nn.silu(gate) * up, "ffn_act")
         ff = with_logical_constraint(ff, "batch", "seq", "mlp")
-        x = x + matmul(ff, layer["w_down"].astype(dt))
+        x = residual_add(x, matmul(ff, layer["w_down"].astype(dt)), c)
         return (with_logical_constraint(x, "batch", "seq", None),
                 jnp.zeros((), jnp.float32), None)
     from ray_tpu.models import moe
@@ -510,7 +638,7 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
     else:
         ff, aux, expert_rows = moe.moe_ffn_dropless(
             h, moe_params, mcfg, valid=valid, layer_index=layer_index)
-    x = x + ff.astype(x.dtype)
+    x = residual_add(x, ff, c)
     return with_logical_constraint(x, "batch", "seq", None), aux, \
         expert_rows
 
@@ -529,6 +657,118 @@ def lm_head(params: PyTree, config: LlamaConfig) -> jax.Array:
     if config.tie_embeddings:
         return params["embed_tokens"].astype(config.dtype).T
     return params["lm_head"].astype(config.dtype)
+
+
+def head_logits(x: jax.Array, params: PyTree,
+                config: LlamaConfig) -> jax.Array:
+    """Logits of normed hidden states, divided by ``logits_scaling``."""
+    logits = matmul(x, lm_head(params, config))
+    if config.logits_scaling != 1.0:
+        logits = logits / config.logits_scaling
+    return logits
+
+
+def embed(params: PyTree, tokens: jax.Array,
+          config: LlamaConfig) -> jax.Array:
+    """Token embeddings times ``embedding_multiplier``, in the type the
+    residual stream is carried in."""
+    x = params["embed_tokens"].astype(config.dtype)[tokens]
+    if config.embedding_multiplier != 1.0:
+        x = x * config.embedding_multiplier
+    return x.astype(config.stream_dtype or config.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The layer pattern: a scan iteration is one PERIOD of layers
+# ---------------------------------------------------------------------------
+
+ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+
+
+def _leaf_kind(name: str) -> Optional[str]:
+    """The kind of layer that alone has this leaf (None: every layer)."""
+    if name in ATTENTION_LEAVES:
+        return "attention"
+    return "mamba" if name.startswith("ssm_") else None
+
+
+def by_period(tree: PyTree, config: LlamaConfig) -> PyTree:
+    """Leaves stacked over layers (all of them, or those of one kind:
+    weights, a cache) -> ``(periods, how many a period holds, ...)``, what
+    a scan over periods slices.  A period of ONE layer is the layer: the
+    tree as it is, and ``period_layers`` / ``stack_period`` /
+    ``merge_periods`` add and take away nothing either, so a plain
+    decoder's scan is traced as it always was."""
+    if config.period_len == 1:
+        return tree
+    periods = config.n_layers // config.period_len
+    return jax.tree.map(
+        lambda x: x.reshape((periods, x.shape[0] // periods) + x.shape[1:]),
+        tree)
+
+
+def merge_periods(tree: PyTree, config: LlamaConfig) -> PyTree:
+    """``by_period``'s inverse, for what a scan over periods stacked."""
+    if config.period_len == 1:
+        return tree
+    return jax.tree.map(
+        lambda x: x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:]), tree)
+
+
+def scanned_layers(layers: Dict[str, jax.Array], config: LlamaConfig):
+    """What a scan over periods takes as xs of the layer stacks: for a
+    plain decoder the stacks themselves (a period is a layer: the scan
+    slices it, as it always has); for a pattern NOTHING -- ``period_layers``
+    reads each layer of the period out of the stacks where they lie.  (A
+    period's slice of a stack, read by several layers, is materialised by
+    XLA: at granite-4.0-h-micro's widths 1.6 GB of weights copied out a
+    period, seen in the HLO compiled for a v5e.)"""
+    return layers if config.period_len == 1 else None
+
+
+def period_layers(layers: Dict[str, jax.Array], period, p: jax.Array,
+                  config: LlamaConfig):
+    """Per layer of period ``p``: ``(kind, index among the period's layers
+    of that kind, its leaves)``.  ``layers``: the whole stacks; ``period``:
+    ``scanned_layers``' slice."""
+    if config.period_len == 1:
+        return [(config.period[0], 0, period)]
+    seen = {kind: 0 for kind in LAYER_KINDS}
+    out = []
+    for j, kind in enumerate(config.period):
+        i = seen[kind]
+        seen[kind] += 1
+        at = {None: layer_index(p, config.period_len, j),
+              kind: layer_index(p, config.period.count(kind), i)}
+        out.append((kind, i, {
+            name: jax.lax.dynamic_index_in_dim(
+                leaf, at[_leaf_kind(name)], 0, keepdims=False)
+            for name, leaf in layers.items()
+            if _leaf_kind(name) in (None, kind)}))
+    return out
+
+
+def layer_of(period: PyTree, i: int, config: LlamaConfig) -> PyTree:
+    """The ``i``-th layer's slice of a period's slice of a ``by_period``
+    tree of one kind (a cache)."""
+    if config.period_len == 1:
+        return period
+    return jax.tree.map(lambda x: x[i], period)
+
+
+def stack_period(items, config: LlamaConfig):
+    """A period's per-layer results as one tree with a leading axis
+    (None where the period has no such layer or the results are None)."""
+    if not items or items[0] is None:
+        return None
+    if config.period_len == 1:
+        return items[0]
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *items)
+
+
+def layer_index(p: jax.Array, per_period: int, i: int) -> jax.Array:
+    """Period ``p``'s ``i``-th layer of ``per_period``, among all."""
+    return p if per_period == 1 else p * per_period + i
 
 
 def decoder_layer(x: jax.Array, layer: Dict[str, jax.Array],
@@ -556,6 +796,13 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     With ``return_aux=True`` returns (logits, aux) where aux is the
     summed MoE load-balancing loss over layers (0.0 for dense)."""
     c = config
+    if (c.layers_of("mamba") or c.attention_multiplier is not None
+            or c.embedding_multiplier != 1.0 or c.logits_scaling != 1.0):
+        raise NotImplementedError(
+            "llama.forward (training) computes a stack of one kind of "
+            "attention layer with the default scale, embedding and "
+            "logits: a config with state-space layers or the Granite "
+            "multipliers is served only (llama_serve.build_*)")
     if positions is not None and c.attention_impl != "dot":
         # flash/ring mask on raw row index, not positions — packed or
         # offset sequences would silently attend across boundaries.
@@ -857,7 +1104,8 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
     shape and batch are static, per-slot positions are data)."""
     c = config
     dt = dtype or c.dtype
-    shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
+    shape = (c.layers_of("attention"), batch, max_len, c.n_kv_heads,
+             c.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
@@ -935,27 +1183,33 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
                kv_layers: Any = None, valid: Optional[jax.Array] = None,
                lengths: Optional[jax.Array] = None):
     """The forward pass that serving shares: embed, rope table, a scan
-    over the layers of ``_qkv_rope`` -> ``kv_step`` -> ``attn_out_ffn``,
-    final norm, head.  What a decoder layer is made of lives here; the
-    callers differ only in ``kv_step``, what a layer does with its fresh
-    K/V rows and what its queries attend.
+    over the PERIODS of the layer pattern -- per attention layer
+    ``_qkv_rope`` -> ``kv_step`` -> ``attn_out_ffn``, per Mamba layer
+    ``mamba2.prefill`` -> ``ffn_half`` --, final norm, head.  What a
+    decoder layer is made of lives here; the callers differ only in
+    ``kv_step``, what an attention layer does with its fresh K/V rows and
+    what its queries attend.  (A plain decoder's period is one layer.)
 
     tokens: (B, S); positions: (B, S) absolute, each row's 0..S-1 when
     left out.  ``kv_step(q, k, v, positions, kv_layer) -> (attn, ys)``:
     q (B, S, Hq, D) and k, v (B, S, Hkv, D) roped, ``kv_layer`` this
-    layer's slice of ``kv_layers`` (leading dim L: a cache scanned a layer
-    at a time).  ``valid`` (broadcastable to (B, S)) marks the rows that
-    are real: experts compute no others, and read their ``[L, E, ...]``
-    matrices in place at the layer's index (the stacks are closed over,
-    not sliced by the scan).  With ``lengths`` (B,) the logits are those
-    of each row's last real position alone, (B, V), and ``valid``
-    defaults to position < length; without, (B, S, V).
+    layer's slice of ``kv_layers`` (leading dim: the attention layers; a
+    cache scanned a layer at a time).  ``valid`` (broadcastable to (B,
+    S)) marks the rows that are real: experts compute no others, and
+    read their ``[L, E, ...]`` matrices in place at the layer's index
+    (the stacks are closed over, not sliced by the scan).  With
+    ``lengths`` (B,) the logits are those of each row's last real
+    position alone, (B, V), ``valid`` defaults to position < length, and
+    a Mamba layer's states are those of that position; without, (B, S,
+    V) and every position is real.  A Mamba layer starts from an empty
+    state: only a cold prefill walks one.
 
-    Returns ``(logits, ys stacked over the layers, expert rows)``: the
-    (L, E) int32 rows each layer's experts computed, None for a dense
-    config."""
+    Returns ``(logits, ys stacked over the attention layers, expert
+    rows, Mamba states)``: the (L, E) int32 rows each layer's experts
+    computed, None for a dense config; ``(recurrent, conv)`` states
+    stacked over the Mamba layers, None where there are none."""
     c = config
-    x = params["embed_tokens"].astype(c.dtype)[tokens]
+    x = embed(params, tokens, c)
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
@@ -964,24 +1218,48 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     if valid is None and lengths is not None:
         valid = positions < lengths[:, None]
     sliced, stacks = split_expert_stacks(params["layers"], c)
+    plen = c.period_len
 
-    def body(x, layer_index_cache):
-        layer, l, kv_layer = layer_index_cache
-        q, k, v = _qkv_rope(x, layer, sin, cos, c)
-        attn, ys = kv_step(q, k, v, positions, kv_layer)
-        x, _aux, rows = attn_out_ffn(x, attn, {**layer, **stacks}, c,
-                                     valid=valid, layer_index=l)
-        return x, (ys, rows)
+    def body(x, period_index_cache):
+        period, p, kv_period = period_index_cache
+        kv_ys, ssm_ys, rows = [], [], []
+        for j, (kind, i, layer) in enumerate(
+                period_layers(sliced, period, p, c)):
+            layer = {**layer, **stacks}
+            if kind == "attention":
+                q, k, v = _qkv_rope(x, layer, sin, cos, c)
+                attn, ys = kv_step(q, k, v, positions,
+                                   layer_of(kv_period, i, c))
+                kv_ys.append(ys)
+                x, _aux, rows_j = attn_out_ffn(
+                    x, attn, layer, c, valid=valid,
+                    layer_index=layer_index(p, plen, j))
+            else:
+                from ray_tpu.models import mamba2
 
-    x, (ys, expert_rows) = jax.lax.scan(
+                out, ys = mamba2.prefill(
+                    rms_norm(x, layer["attn_norm"], c.norm_eps).astype(
+                        c.dtype), layer, c, lengths)
+                ssm_ys.append(ys)
+                x, _aux, rows_j = ffn_half(
+                    residual_add(x, out, c), layer, c, valid=valid,
+                    layer_index=layer_index(p, plen, j))
+            rows.append(rows_j)
+        return x, (stack_period(kv_ys, c), stack_period(rows, c),
+                   stack_period(ssm_ys, c))
+
+    x, stacked = jax.lax.scan(
         body, x,
-        (sliced, jnp.arange(c.n_layers, dtype=jnp.int32), kv_layers))
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
+        (scanned_layers(sliced, c),
+         jnp.arange(c.n_layers // plen, dtype=jnp.int32),
+         by_period(kv_layers, c)))
+    ys, expert_rows, ssm_ys = merge_periods(stacked, c)
+    x = rms_norm(x, params["final_norm"], c.norm_eps).astype(c.dtype)
     if lengths is None:
-        return matmul(x, lm_head(params, c)), ys, expert_rows
+        return head_logits(x, params, c), ys, expert_rows, ssm_ys
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)  # (B,1,H)
-    return matmul(last, lm_head(params, c))[:, 0], ys, expert_rows
+    return head_logits(last, params, c)[:, 0], ys, expert_rows, ssm_ys
 
 
 def prefill_forward(params: PyTree, tokens: jax.Array,
@@ -1003,14 +1281,26 @@ def prefill_forward(params: PyTree, tokens: jax.Array,
     length 0); ``return_expert_rows`` adds a fourth result, the (L, E)
     int32 rows each layer's experts computed (None for a dense
     config)."""
-    def kv_step(q, k, v, positions, _cache):
-        return dot_attention(q, k, v, positions), (k, v)
-
-    last_logits, (ks, vs), expert_rows = layer_walk(
-        params, tokens, config, kv_step, lengths=lengths)
+    last_logits, ks, vs, expert_rows, _states = prefill_with_states(
+        params, tokens, lengths, config)
     if return_expert_rows:
         return last_logits, ks, vs, expert_rows
     return last_logits, ks, vs
+
+
+def prefill_with_states(params: PyTree, tokens: jax.Array,
+                        lengths: jax.Array, config: LlamaConfig):
+    """``prefill_forward`` with everything a serving cache takes in:
+    ``(last_logits, ks, vs, expert rows, Mamba states)`` -- ks/vs over
+    the attention layers alone; the states ``(recurrent (Lm, G, N, nh x
+    hd), conv (Lm, K - 1, G, conv_dim))`` as of each row's last real
+    position, None for a model without Mamba layers."""
+    def kv_step(q, k, v, positions, _cache):
+        return dot_attention(q, k, v, positions, config.attn_scale), (k, v)
+
+    last_logits, (ks, vs), expert_rows, states = layer_walk(
+        params, tokens, config, kv_step, lengths=lengths)
+    return last_logits, ks, vs, expert_rows, states
 
 
 def insert_prefill(cache: Dict[str, jax.Array], ks: jax.Array,
@@ -1079,7 +1369,11 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
     at those positions and returns (logits (B, T, V), new_cache).  No
     program that serves runs it; tests hold T > 1 through a cache to the
     reference with it."""
-    scale = config.head_dim ** -0.5
+    if config.layers_of("mamba"):
+        raise NotImplementedError(
+            "forward_with_cache holds K/V alone; a config with state-space "
+            "layers runs through llama_serve.build_prefill / build_decode_k")
+    scale = config.attn_scale
 
     # The T new K/V rows go into each slot's cache at its own positions
     # (per-slot write offsets = data, shapes static).
@@ -1094,7 +1388,7 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
         return (_cache_attend(q, ck_l, cv_l, positions, scale),
                 (ck_l, cv_l))
 
-    logits, (new_k, new_v), _rows = layer_walk(
+    logits, (new_k, new_v), _rows, _states = layer_walk(
         params, tokens, config, kv_step, positions=positions,
         kv_layers=(cache["k"], cache["v"]))
     return logits, {"k": new_k, "v": new_v}

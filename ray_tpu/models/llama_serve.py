@@ -5,8 +5,9 @@ Every device program of ``serve/llm.py``'s scheduler is built here, one
 plane's from a ``BlockPool``: config, block size, block format): no
 engine, thread or weights are needed to lower one.  By the cache kept:
 
-- a per-slot cache ``(L, B, S, Hkv, D)`` (``llama.init_kv_cache``):
-  ``prefill``, ``decode_k``;
+- a per-slot cache (``init_cache``: K and V ``(La, B, S, Hkv, D)`` over
+  the attention layers and, for a model with Mamba-2 layers, each slot's
+  recurrent and conv states beside them): ``prefill``, ``decode_k``;
 - a block pool ``(N, L, bs, Hkv, D)`` (``llama.init_paged_kv_cache``):
   ``prefill_cold``, ``prefill_warm``, ``decode_paged``, ``inject`` (and
   its inverse ``BlockPool.extract``), ``spec_verify``;
@@ -15,10 +16,12 @@ engine, thread or weights are needed to lower one.  By the cache kept:
 
 The prefills and ``spec_verify`` are ``llama.layer_walk`` with their own
 K/V step: what a decoder layer is made of is ``models/llama.py``'s
-business, in what layout its K/V lie and what its queries attend is this
-module's.  The device trace names a program's module after its inner
-function (``jit_prefill``, ``jit_decode_k``): the benchmark's readers
-find them by that name.
+business (and ``models/mamba2.py``'s), in what layout its K/V and states
+lie and what its queries attend is this module's.  The block pool and the
+draft hold K/V alone: ``serve/llm.py`` refuses a config with state-space
+layers on those planes.  The device trace names a program's module after
+its inner function (``jit_prefill``, ``jit_decode_k``): the benchmark's
+readers find them by that name.
 """
 
 from __future__ import annotations
@@ -47,6 +50,65 @@ def _expert_load(expert_rows):
     return (rows.sum(0), jnp.sum(rows > 0, dtype=jnp.int32))
 
 
+# ------------------------------------------------------------ the cache
+# THE place that knows what a dense-plane serving cache is made of.  The
+# scheduler (serve/llm.py) holds the tree these functions build and hands
+# it back to the programs; it never names a leaf.
+def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
+    """The per-slot cache of a config: K and V ``(La, B, S, Hkv, D)``
+    over its attention layers and, for its Mamba layers, each slot's
+    recurrent state ``ssm (Lm, B, N, nh x hd)`` (stored as
+    ``cfg.ssm_state_dtype``) and conv window ``conv (Lm, K - 1, B,
+    conv_dim)``.  A state is not positional: ``build_prefill`` replaces a
+    slot's whole state, ``decode_step`` advances it in place."""
+    cache = llama.init_kv_cache(cfg, slots, max_len)
+    if cfg.layers_of("mamba"):
+        from ray_tpu.models import mamba2
+
+        cache.update(mamba2.init_state(cfg, cfg.layers_of("mamba"), slots))
+    return cache
+
+
+def cache_pools(cfg: LlamaConfig, slots: int, max_len: int):
+    """``{pool: (bytes, storage type)}`` of ``init_cache``'s tree: ``kv``
+    (K and V together) and, for a model with Mamba layers, ``ssm`` and
+    ``conv``."""
+    shapes = jax.eval_shape(lambda: init_cache(cfg, slots, max_len))
+    pools = {}
+    for name, leaf in shapes.items():
+        pool = "kv" if name in ("k", "v") else name
+        nbytes = int(leaf.size) * leaf.dtype.itemsize
+        pools[pool] = (pools.get(pool, (0,))[0] + nbytes, str(leaf.dtype))
+    return pools
+
+
+def state_bytes_per_slot(cfg: LlamaConfig):
+    """``{"ssm": ..., "conv": ...}`` bytes one slot's states hold over
+    all Mamba layers ({} for a model without them): what a decode step
+    reads and writes for a slot it advances."""
+    return {pool: nbytes for pool, (nbytes, _) in
+            cache_pools(cfg, 1, 1).items() if pool != "kv"}
+
+
+def insert_states(cache, states, slots):
+    """A prefill group's final states into its slots, wholesale (a reused
+    slot inherits nothing of the request before).  states: ``(recurrent
+    (Lm, G, N, nh x hd), conv (Lm, K - 1, G, conv_dim))``; slots (G,), a
+    negative one drops its row."""
+    ssm, conv = states
+    B = cache["ssm"].shape[1]
+    rows = jnp.where(slots < 0, B, slots)      # out of range: dropped
+    return {
+        **cache,
+        "ssm": cache["ssm"].at[:, rows].set(
+            ssm.astype(cache["ssm"].dtype), mode="drop",
+            unique_indices=True),
+        "conv": cache["conv"].at[:, :, rows].set(
+            conv.astype(cache["conv"].dtype), mode="drop",
+            unique_indices=True),
+    }
+
+
 def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     """The shared per-token decode step (scan body): a row write of
     each slot's new K/V at its current position, cache attention
@@ -70,17 +132,27 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     ``active`` slots only, and read their ``[L, E, ...]`` matrices in
     place (the stacks are closed over, not sliced by the layer scan).
 
+    The layer scan runs a PERIOD of ``cfg.layer_pattern`` an iteration (a
+    plain decoder's period is one attention layer: the scan it always
+    was).  A Mamba-2 layer of the period advances its layer of the
+    stacked recurrent and conv states, which ride the carry after the
+    lengths -- ``(ck, cv, tok, lens, ssm, conv)`` -- through both loops
+    and are updated in place (``mamba2.decode``,
+    ``ops/ssm_state_update.py``); an inactive slot's states are left as
+    they are.
+
     This step is ``llama.layer_walk`` written out, its K/V the carry of
     the layer scan: the walk, handed a carry, compiled to the same sizes
     but not to the same text as the program the benchmark's cells have
     measured since PR 24 (this step has cliffs: PERF.md section 6)."""
 
     sliced, stacks = llama.split_expert_stacks(params["layers"], cfg)
+    plen = cfg.period_len
+    n_attn, n_ssm = cfg.period.count("attention"), cfg.period.count("mamba")
 
     def step(carry, _):
-        ck, cv, tok, lens = carry
-        dt = cfg.dtype
-        x = params["embed_tokens"].astype(dt)[tok][:, None]
+        ck, cv, tok, lens, *state = carry
+        x = llama.embed(params, tok, cfg)[:, None]
         sin, cos = llama.rope_table(lens[:, None], cfg.head_dim,
                                     cfg.rope_theta)
         # Inactive slots MUST not write: a just-admitted slot's
@@ -88,52 +160,94 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         # chunk awaiting its first token) and a stale-position
         # write would corrupt its fresh rows.  Nor does a slot
         # past the attended prefix.  Their row goes out of range
-        # and the scatter drops it.
+        # and the scatter drops it.  (Nor may an inactive slot's
+        # recurrent state advance: mamba2.decode.)
         slots = tok.shape[0]
         rows = jnp.arange(slots, dtype=jnp.int32)
         pos = jnp.where(active & (lens < s_active), lens,
                         ck.shape[2])
-        scale = cfg.head_dim ** -0.5
+        scale = cfg.attn_scale
 
-        def body(carry, layer_and_index):
-            x, ck, cv = carry
-            layer, l = layer_and_index
-            q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg)
-            # Write before attend: the new row is among the keys.
-            ck = ck.at[l, rows, pos].set(
-                kk[:, 0].astype(ck.dtype), mode="drop",
-                indices_are_sorted=True, unique_indices=True)
-            cv = cv.at[l, rows, pos].set(
-                vv[:, 0].astype(cv.dtype), mode="drop",
-                indices_are_sorted=True, unique_indices=True)
-            # The kernel reads the carry where it lies, each row as far
-            # as it is long; an inactive row's zeros are discarded below.
-            attn = decode_attention(q[:, 0], ck, cv, l, lens, active,
-                                    s_active=s_active, scale=scale)[:, None]
-            x, _aux, expert_rows = llama.attn_out_ffn(
-                x, attn, {**layer, **stacks}, cfg,
-                valid=active[:, None], layer_index=l)
-            return (x, ck, cv), expert_rows
+        def body(carry, period_and_index):
+            x, ck, cv, *state = carry
+            period, p = period_and_index
+            expert_rows = []
+            for j, (kind, i, layer) in enumerate(
+                    llama.period_layers(sliced, period, p, cfg)):
+                layer = {**layer, **stacks}
+                if kind == "attention":
+                    l = llama.layer_index(p, n_attn, i)
+                    q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg)
+                    # Write before attend: the new row is among the keys.
+                    ck = ck.at[l, rows, pos].set(
+                        kk[:, 0].astype(ck.dtype), mode="drop",
+                        indices_are_sorted=True, unique_indices=True)
+                    cv = cv.at[l, rows, pos].set(
+                        vv[:, 0].astype(cv.dtype), mode="drop",
+                        indices_are_sorted=True, unique_indices=True)
+                    # The kernel reads the carry where it lies, each row
+                    # as far as it is long; an inactive row's zeros are
+                    # discarded below.
+                    attn = decode_attention(
+                        q[:, 0], ck, cv, l, lens, active,
+                        s_active=s_active, scale=scale)[:, None]
+                    x, _aux, rows_j = llama.attn_out_ffn(
+                        x, attn, layer, cfg, valid=active[:, None],
+                        layer_index=llama.layer_index(p, plen, j))
+                else:
+                    from ray_tpu.models import mamba2
 
-        (x, ck, cv), expert_rows = jax.lax.scan(
-            body, (x, ck, cv),
-            (sliced, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-        x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = llama.matmul(x, llama.lm_head(params, cfg))[:, 0]
+                    out, *state = mamba2.decode(
+                        llama.rms_norm(x, layer["attn_norm"],
+                                       cfg.norm_eps).astype(cfg.dtype),
+                        layer, cfg, *state, llama.layer_index(p, n_ssm, i),
+                        active)
+                    x, _aux, rows_j = llama.ffn_half(
+                        llama.residual_add(x, out, cfg), layer, cfg,
+                        valid=active[:, None],
+                        layer_index=llama.layer_index(p, plen, j))
+                expert_rows.append(rows_j)
+            return (x, ck, cv, *state), llama.stack_period(expert_rows,
+                                                           cfg)
+
+        (x, ck, cv, *state), expert_rows = jax.lax.scan(
+            body, (x, ck, cv, *state),
+            (llama.scanned_layers(sliced, cfg),
+             jnp.arange(cfg.n_layers // plen, dtype=jnp.int32)))
+        expert_rows = llama.merge_periods(expert_rows, cfg)
+        x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps).astype(
+            cfg.dtype)
+        logits = llama.head_logits(x, params, cfg)[:, 0]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         nxt = jnp.where(active, nxt, tok)
         lens = lens + active.astype(jnp.int32)
-        return (ck, cv, nxt, lens), (nxt, expert_rows)
+        return (ck, cv, nxt, lens, *state), (nxt, expert_rows)
 
     return step
+
+
+def _carry(cache, tok, lens):
+    """A cache tree as ``decode_step``'s carry: K, V, the tokens, the
+    lengths, then the Mamba states if the model has them."""
+    return (cache["k"], cache["v"], tok, lens,
+            *(cache[name] for name in ("ssm", "conv") if name in cache))
+
+
+def _uncarry(carry):
+    ck, cv, tok, lens, *state = carry
+    return ({"k": ck, "v": cv, **dict(zip(("ssm", "conv"), state))},
+            tok, lens)
 
 
 # ------------------------------------------------------------- dense plane
 def build_prefill(cfg: LlamaConfig) -> Callable:
     def prefill(params, cache, tokens, lengths, slots):
-        last_logits, ks, vs, rows = llama.prefill_forward(
-            params, tokens, lengths, cfg, return_expert_rows=True)
-        cache = llama.insert_prefill(cache, ks, vs, slots)
+        last_logits, ks, vs, rows, states = llama.prefill_with_states(
+            params, tokens, lengths, cfg)
+        kv = llama.insert_prefill(cache, ks, vs, slots)
+        cache = {**cache, **kv}
+        if states is not None:
+            cache = insert_states(cache, states, slots)
         first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
         return cache, first, _expert_load(rows)
 
@@ -146,11 +260,10 @@ def build_decode_k(cfg: LlamaConfig) -> Callable:
         tok = jnp.where(ov_mask, ov_tok, tok_dev)
         lens = jnp.where(ov_mask, ov_len, len_dev)
         step = decode_step(cfg, params, s_active, active)
-        (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
-            step, (cache["k"], cache["v"], tok, lens), None,
-            length=k)
-        return {"k": ck, "v": cv}, toks, tok, lens, \
-            _expert_load(rows)
+        carry, (toks, rows) = jax.lax.scan(
+            step, _carry(cache, tok, lens), None, length=k)
+        cache, tok, lens = _uncarry(carry)
+        return cache, toks, tok, lens, _expert_load(rows)
 
     # tok_dev/len_dev (args 2, 3) are always overwritten by the
     # returned carries at every call site: donate them too.
@@ -281,7 +394,7 @@ def build_prefill_warm(blocks: BlockPool) -> Callable:
         key_valid = jnp.concatenate(
             [prefix_pos[None, :] < pos0[:, None],
              jnp.ones((G, P), bool)], axis=1)
-        scale = cfg.head_dim ** -0.5
+        scale = cfg.attn_scale
 
         def kv_step(q, k, v, positions, prefix_l):
             ckp_l, cvp_l = prefix_l
@@ -293,7 +406,7 @@ def build_prefill_warm(blocks: BlockPool) -> Callable:
                                        key_abs, key_valid)
             return attn, (k, v)
 
-        last_logits, (ks, vs), rows = llama.layer_walk(
+        last_logits, (ks, vs), rows, _states = llama.layer_walk(
             params, tokens, cfg, kv_step, positions=positions,
             kv_layers=(blocks.gather(pool, "k", prefix_bt),
                        blocks.gather(pool, "v", prefix_bt)),
@@ -356,7 +469,7 @@ def build_spec_verify(blocks: BlockPool) -> Callable:
                   & active[:, None, None])            # (B, T, S)
         written = onehot.any(axis=1)[:, :, None, None]
         proj = onehot.astype(cfg.dtype)
-        scale = cfg.head_dim ** -0.5
+        scale = cfg.attn_scale
 
         def kv_step(q, kk, vv, positions, cache_l):
             ck_l, cv_l = cache_l
@@ -373,7 +486,7 @@ def build_spec_verify(blocks: BlockPool) -> Callable:
                                        scale)
             return attn, (ck_l, cv_l)
 
-        logits, (ck, cv), _rows = llama.layer_walk(
+        logits, (ck, cv), _rows, _states = llama.layer_walk(
             params, tokens, cfg, kv_step, positions=positions,
             kv_layers=(blocks.gather(pool, "k", bt),
                        blocks.gather(pool, "v", bt)),

@@ -389,6 +389,12 @@ def kv_cache_counters():
             "device bytes held by the paged KV pool (quantized pools "
             "include their per-block scale tensors)",
             tag_keys=("pool", "dtype")),
+        "state_pool_bytes": Gauge(
+            "ray_tpu_state_pool_bytes",
+            "device bytes held by the per-slot recurrent (ssm) and conv "
+            "states of a model with state-space layers, beside its K/V "
+            "under ray_tpu_kv_pool_bytes",
+            tag_keys=("pool", "kind", "dtype")),
         "spec_proposed": Counter(
             "ray_tpu_spec_decode_proposed_tokens",
             "draft-model tokens proposed to the verifier",
@@ -443,6 +449,14 @@ def serve_engine_counters():
             boundaries=[0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                         5.0, 10.0, 30.0],
             tag_keys=("deployment",)),
+        # A model with state-space layers only.
+        "state_bytes": Counter(
+            "ray_tpu_serve_state_bytes_total",
+            "recurrent (kind=ssm) and conv (kind=conv) state bytes the "
+            "decode chunks had to read and write: per (slot, step) that "
+            "advanced a state, one read and one write of the slot's "
+            "states over all state-space layers",
+            tag_keys=("deployment", "kind")),
         # A model with experts only (a dense one never touches these).
         "moe_expert_rows": Counter(
             "ray_tpu_serve_moe_expert_rows_total",

@@ -28,6 +28,13 @@ whose per-call host↔device round trip is tens of milliseconds:
   config alone; this module is their scheduler.  Both planes run ONE
   decode step (``llama_serve.decode_step``: one K/V row per slot
   written in place), the paged plane on its gathered block tables.
+  The dense plane's cache is an opaque tree (``llama_serve.init_cache``):
+  for a model with state-space layers it holds each slot's recurrent
+  and conv states beside K/V, a prefill replaces a slot's whole state
+  and a chunk advances the active slots' in place.  Such a model is
+  served by the dense plane alone: blocks, shared prefixes, a draft's
+  rewind, the K/V hand-off and ``kv_quant`` all need rows by position,
+  and refuse it at construction.
 - Prefill runs plain causal attention WITHIN the prompt (no cache
   read), inserts K/V via a one-hot slot projection (dense) or a
   block-table scatter (paged) at static offsets, and returns the
@@ -336,6 +343,24 @@ class LLMServer:
                     "disaggregated replicas)")
         preset = getattr(llama.LlamaConfig, model_preset)
         self.cfg = preset(max_seq_len=max_len)
+        if self.cfg.layers_of("mamba"):
+            # The planes built on K/V being positional rows cannot hold a
+            # recurrent state; the dense plane (per-slot states beside
+            # K/V: llama_serve.init_cache) serves such a model.
+            asked = [what for what, on in (
+                ("paged blocks and prefix sharing (paged=True)", paged),
+                ("kv_quant", kv_quant is not None),
+                ("speculative decoding (spec_k)", self.spec_k > 0),
+                ("prefill/decode disaggregation (role)", role != "both"),
+            ) if on]
+            if asked:
+                raise ValueError(
+                    f"{model_preset} has state-space layers, whose "
+                    f"recurrent state is one array a slot, not rows by "
+                    f"position: it cannot be cut into blocks, shared by "
+                    f"prefix, rewound after a rejected draft, handed off "
+                    f"as K/V blocks or quantized as K/V rows.  Refused: "
+                    f"{'; '.join(asked)}")
         self.max_slots = max_slots
         self.max_len = max_len
         self.buckets = tuple(sorted(b for b in prefill_buckets
@@ -390,14 +415,22 @@ class LLMServer:
             if kv_quant is not None:
                 raise ValueError("kv_quant requires the paged KV "
                                  "plane (paged=True)")
-            self.cache = llama.init_kv_cache(self.cfg, max_slots,
-                                             max_len)
+            self.cache = llama_serve.init_cache(self.cfg, max_slots,
+                                                max_len)
             self._prefill = llama_serve.build_prefill(self.cfg)
             self._decode_k = llama_serve.build_decode_k(self.cfg)
         if self.spec_k:
             self._init_draft(draft_preset, draft_layers, draft_params,
                              seed)
 
+        # A model with state-space layers: the bytes one slot's states
+        # hold ({} for any other: nothing is emitted for it), and the
+        # pools' sizes for the operator.
+        self._state_bytes = llama_serve.state_bytes_per_slot(self.cfg)
+        self._state_tags = {kind: {**self._tags, "kind": kind}
+                            for kind in self._state_bytes}
+        if self._state_bytes:
+            self._publish_state_pool()
         self._jnp = jnp
         # Device-resident carries between chunk launches.
         self._tok_dev = jnp.zeros(max_slots, jnp.int32)
@@ -479,6 +512,24 @@ class LLMServer:
             {-(-b // bs) for b in self.decode_buckets}))
         # Warm-prefill prefix buckets: one static gather width.
         self._np_max = max(1, (max(self.buckets) - 1) // bs)
+
+    def _publish_state_pool(self) -> None:
+        """The dense cache of a model with state-space layers, by what
+        it holds: K/V under ``ray_tpu_kv_pool_bytes``, the recurrent and
+        conv states under ``ray_tpu_state_pool_bytes``."""
+        from ray_tpu.models import llama_serve
+
+        self._pools = llama_serve.cache_pools(self.cfg, self.max_slots,
+                                              self.max_len)
+        name = self._deployment or "llm"
+        for pool, (nbytes, dtype) in self._pools.items():
+            if pool == "kv":
+                self._kv_metrics["pool_bytes"].set(
+                    nbytes, tags={"pool": name, "dtype": dtype})
+            else:
+                self._kv_metrics["state_pool_bytes"].set(
+                    nbytes, tags={"pool": name, "kind": pool,
+                                  "dtype": dtype})
 
     def _publish_pool_bytes(self) -> None:
         try:
@@ -612,7 +663,7 @@ class LLMServer:
                                    ov, ovm, active,
                                    k=self.decode_chunk,
                                    s_active=int(sa))
-            jax.block_until_ready(self.cache["k"])
+            jax.block_until_ready(self.cache)
 
     # ------------------------------------------------------------ serving
     async def generate(self, request: Dict[str, Any]) -> Dict[str, Any]:
@@ -1612,8 +1663,26 @@ class LLMServer:
             "tokens_kept": kept, "token_steps": computed,
             "kv_positions_attended": attended,
             "kv_positions_bucket": bucket,
+            **self._state_attrs(k * active),
             **self._expert_attrs(load, "decode")},
             f"{self._lane}/chunks")
+
+    def _state_attrs(self, rows: int) -> Dict[str, int]:
+        """A chunk's traffic in recurrent and conv state, host side, from
+        what the launch held: ``rows`` (slot, step) pairs advanced a
+        state, each one read and one write of the slot's states over all
+        Mamba layers -- the bytes that have to move, and all that do
+        (``ops/ssm_state_update.py`` touches no other slot).  Nothing for
+        a model without such layers."""
+        if not self._state_bytes:
+            return {}
+        total = 0
+        for kind, per_slot in self._state_bytes.items():
+            moved = 2 * rows * per_slot
+            total += moved
+            self._engine_metrics["state_bytes"].inc(
+                moved, tags=self._state_tags[kind])
+        return {"state_rows_updated": rows, "state_bytes": total}
 
     def _record_prefill_group(self, t0: float, t1: float, bucket: int,
                               rows: int, real: int, tokens: int,
@@ -1628,10 +1697,16 @@ class LLMServer:
         m = self._engine_metrics
         m["prefill_prompt_tokens"].inc(tokens, tags=self._tags)
         m["prefill_padded_tokens"].inc(computed, tags=self._tags)
+        scan = {}
+        if self._state_bytes:
+            # chunks of the state-space scan the padded group computed,
+            # a Mamba layer
+            scan["scan_chunks"] = rows * -(-bucket // min(
+                bucket, self.cfg.ssm_chunk))
         self._span("serve.prefill_group", t0, t1, {
             "bucket": bucket, "rows": real, "rows_padded": rows,
             "prompt_tokens": tokens, "token_positions": computed,
-            **self._expert_attrs(load, "prefill")},
+            **scan, **self._expert_attrs(load, "prefill")},
             f"{self._lane}/prefills")
 
     # ----------------------------------------- disaggregation (KV handoff)
@@ -1801,7 +1876,14 @@ class LLMServer:
 
         out = {k: v for k, v in metrics_summary().items()
                if k.startswith(("ray_tpu_kv_", "ray_tpu_prefix_",
-                                "ray_tpu_spec_"))}
+                                "ray_tpu_spec_", "ray_tpu_state_"))}
+        if self._state_bytes:
+            out["state_pool"] = {
+                **{f"{pool}_bytes": nbytes
+                   for pool, (nbytes, _) in self._pools.items()},
+                **{f"{pool}_dtype": dtype
+                   for pool, (_, dtype) in self._pools.items()},
+                "bytes_per_slot": dict(self._state_bytes)}
         if self.paged:
             out["allocator"] = {
                 "used": self.allocator.used_blocks,
@@ -1863,8 +1945,7 @@ class LLMServer:
         try:
             import jax
 
-            jax.block_until_ready(
-                self.pool["k"] if self.paged else self.cache["k"])
+            jax.block_until_ready(self.pool if self.paged else self.cache)
         except Exception:
             pass
 

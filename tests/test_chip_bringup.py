@@ -219,3 +219,18 @@ def test_chip_smoke_serve_phase_at_debug_size(clean_runtime, paged):
                                  n_concurrent=3, prompt_len=8,
                                  max_new_tokens=8)
     assert out["requests"] == 4
+
+
+def test_chip_smoke_serve_hybrid_phase(clean_runtime):
+    """``serve_hybrid``: the toy hybrid (a recurrent state beside the K/V
+    cache) through ``serve.run`` on the dense plane, as the phase runs it
+    on the chip but with fewer slots."""
+    import chip_smoke
+
+    engine = dict(chip_smoke.HYBRID_ENGINE, max_slots=4, max_len=64,
+                  prefill_buckets=(16,), decode_chunk=8,
+                  prefill_groups=(4,))
+    out = chip_smoke.serve_phase(paged=False, engine=engine,
+                                 n_concurrent=3, prompt_len=8,
+                                 max_new_tokens=8)
+    assert out["requests"] == 4

@@ -359,3 +359,41 @@ def test_decode_k_at_real_widths_updates_the_cache_in_place(
     more = dict(_engine_programs(cell, one_chip, max_slots=more_slots))
     for s_active in (refused_before, max_len):
         more[f"decode_k s_active={s_active}"]()
+
+
+def test_decode_k_of_a_hybrid_updates_the_recurrent_state_in_place(
+        one_chip, compiled_for_the_chip):
+    """The state's case of "nothing of the cache's shape is copied in a
+    loop", at granite-4.0-h-micro's widths (80 slots x 512): what the
+    token loop and the period loop make that has the stacked recurrent
+    state's shape -- (36, slots, 128, 4096) float32, 6.0 GB -- is the
+    result of the ``ssm_state_update`` kernel alone, one Mosaic call a
+    Mamba layer of the period, whose state operand is aliased to it;
+    nothing makes a layer's or a slot's worth of it; the conv windows are
+    written by fusions on the carry; and K/V keep their two row scatters
+    an attention layer."""
+    from benchmarks.tests.test_granite_cell import CELL, _engine_programs
+    from benchmarks.tests.test_aot_real_widths import _json
+
+    engine = _json("workloads", CELL)["engine"]
+    slots, max_len = engine["max_slots"], engine["max_len"]
+    state = (36, slots, 128, 4096)
+    programs = dict(_engine_programs(one_chip))
+    compiled = programs[f"decode_k s_active={max_len}"]()
+    state_bytes = int(np.prod(state, dtype=np.int64)) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes / 4
+    kernels, scatters = [], 0
+    for inst, opcode, root, arrays in _while_body_results(
+            compiled.as_text()):
+        for dims, nbytes in arrays:
+            if dims == state:
+                assert opcode == "custom-call", (inst, opcode, root)
+                kernels.append(inst)
+            elif dims == (4, slots, max_len, 8, 64):
+                assert (opcode, root) == ("fusion", "scatter"), inst
+                scatters += 1
+            else:
+                assert dims[-2:] != state[-2:], (inst, opcode, dims)
+    assert len(kernels) == 9 and all(
+        k.startswith("ssm_state_update") for k in kernels)
+    assert scatters == 2                          # K and V
