@@ -12,7 +12,9 @@ leave the chip.  Design points:
   same K/V blocks.  The backward expands K/V to q-heads (2 extra bf16
   copies) and group-sums dK/dV — simple and still HBM-light.
 - Causal blocks strictly above the diagonal are skipped via
-  ``pl.when`` + index-map redirect (no DMA, no compute).
+  ``pl.when`` + index-map redirect (no DMA, no compute).  Of a block the
+  diagonal crosses, dq and dk/dv compute the causal strips only
+  (``DIAG_STRIP``); the forward computes it whole and masks it.
 - f32 accumulators in VMEM scratch; running (m, l) kept lane-replicated
   (shape (block_q, 128)) per TPU layout rules.
 - lse is saved for the backward (recompute-based, à la FA-2).
@@ -35,12 +37,21 @@ from ray_tpu.parallel.sharding import shard_over_mesh
 
 LANES = 128
 # 1024 x 1024 tiles: forward, dq and dk/dv all compile through Mosaic
-# at the train shape (B8 S2048 H8 D128) under libtpu 0.0.34 within the
-# default scoped-VMEM limit (chip run, PR 21).  The choice of 1024 over
-# 512 / 256 rests on a pre-PR-1 record taken under another compiler
-# (fewer grid steps amortize per-step sequencing overhead; 2048-wide
-# blocks did not fit VMEM) and has not been re-measured: PERF.md.
+# at both train cells' shapes within the default scoped-VMEM limit
+# (benchmarks/tests/test_aot_real_widths.py).  Read again by
+# tools/flash_sweep.py (chip run, PR 31; PERF.md section 6): with
+# 512 x 512 tiles the three kernels take x 1.37 of their time with
+# 1024 x 1024 at both shapes (D = 64, S = 2,048: 8.41 against 6.12 ms;
+# D = 128, S = 4,096: 3.55 against 2.60 ms; no strips in either), and
+# x 1.5 with strips of 256 in both: four times the grid steps, each with
+# its own waits for blocks.
 DEFAULT_BLOCK = 1024
+# Width of the causal strips in which dq and dk/dv walk a tile that the
+# diagonal crosses (``_diag_strips``).  The same sweep: 256 read the
+# shortest dq + dk/dv at both shapes (3.69 and 1.69 ms; 512: 3.79 and
+# 1.72; none: 4.35 and 1.87); at 128 dk/dv takes longer than with no
+# strips at all (2.78 against 2.37 ms at D = 64).
+DIAG_STRIP = 256
 NEG_INF = -1e30
 
 
@@ -81,25 +92,94 @@ def _supported(sq: int, sk: int, d: int) -> bool:
 # Forward
 # ---------------------------------------------------------------------------
 
+def _diag_strip(block_q: int, block_k: int) -> Optional[int]:
+    """Strip width for a tile the diagonal crosses, or None where the
+    tile is computed whole and masked by a select: tiles that are not
+    square (the diagonal's place in them is not static) or that hold no
+    two strips."""
+    if block_q != block_k or block_q <= DIAG_STRIP or block_q % DIAG_STRIP:
+        return None
+    return DIAG_STRIP
+
+
+def _diag_strips(block: int, strip: int, by: str):
+    """The strips ``(rows, cols)`` of a square diagonal tile that hold
+    what lies on or below the diagonal: ``by="cols"`` keys ``[c*g,
+    (c+1)*g)`` meet the rows from ``c*g`` on, ``by="rows"`` rows ``[r*g,
+    (r+1)*g)`` meet the keys before ``(r+1)*g``.  A strip's only elements
+    above the diagonal are in its ``strip x strip`` corner ON it."""
+    if by == "rows":
+        return [((lo, lo + strip), (0, lo + strip))
+                for lo in range(0, block, strip)]
+    return [((lo, block), (lo, lo + strip)) for lo in range(0, block, strip)]
+
+
+def causal_computed_share(seq: int, block_q: Optional[int] = None,
+                          block_k: Optional[int] = None,
+                          strip: Optional[int] = None) -> float:
+    """Share of the ``seq x seq`` score square that a causal kernel
+    computes (a causal mask needs ``(1 + 1/seq) / 2`` of it): whole tiles
+    below the diagonal, and of a tile the diagonal crosses its strips of
+    width ``strip`` (default: what dq and dk/dv walk, ``_diag_strip``) or,
+    with ``strip`` the tile's own width as in the forward, all of it."""
+    bq, bk = _block_sizes(seq, seq, block_q, block_k)
+    if strip is None:
+        strip = _diag_strip(bq, bk) or bq
+    elif bq != bk or bq % strip:
+        raise ValueError(f"no strips of {strip} in a {bq} x {bk} tile")
+    computed = 0
+    for q0 in range(0, seq, bq):
+        for k0 in range(0, seq, bk):
+            if k0 > q0 + bq - 1:            # above the diagonal: skipped
+                continue
+            if k0 + bk - 1 > q0 and strip < bq:
+                computed += sum((r1 - r0) * (c1 - c0) for (r0, r1), (c0, c1)
+                                in _diag_strips(bq, strip, "cols"))
+            else:
+                computed += bq * bk
+    return computed / (seq * seq)
+
+
+def _causal_mask(s, row0, col0):
+    """Scores of keys after their query -> NEG_INF; ``row0`` / ``col0``
+    place the block's first row and column on one axis."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(rows >= cols, s, NEG_INF)
+
+
 def _causal_dispatch(compute, causal, should_run, qi, ki,
-                     block_q, block_k):
-    """Run ``compute(masked=...)`` under pl.when: causal kernels mask
-    only blocks the diagonal crosses (fully-below-diagonal blocks skip
-    the iota/where VPU work)."""
+                     block_q, block_k, strips=None):
+    """Run ``compute(rows, cols, mask)`` under pl.when over what a tile
+    has to compute.  ``rows`` / ``cols`` are static ``(start, stop)``
+    within the tile; ``mask`` is None or the ``(row0, col0)`` that
+    ``_causal_mask`` takes.  Tiles below the diagonal and non-causal
+    tiles are one unmasked call.  A tile the diagonal crosses is computed
+    whole and masked, unless the kernel asks for ``strips`` (``"rows"`` or
+    ``"cols"``) and the tile is square: then it is walked in
+    ``_diag_strips``, nothing above them is computed, and a masked score
+    adds an exact zero either way, so the sums are the same."""
+    whole = (0, block_q), (0, block_k)
     if causal:
         on_diag = ki * block_k + block_k - 1 > qi * block_q
 
         @pl.when(should_run & jnp.logical_not(on_diag))
         def _below():
-            compute(masked=False)
+            compute(*whole, None)
 
         @pl.when(should_run & on_diag)
         def _diag():
-            compute(masked=True)
+            strip = strips and _diag_strip(block_q, block_k)
+            if not strip:
+                compute(*whole, (qi * block_q, ki * block_k))
+                return
+            # Square, crossed and run: qi == ki, the diagonal is the tile's.
+            for rows, cols in _diag_strips(block_q, strip, strips):
+                compute(rows, cols, (rows[0], cols[0]))
     else:
         @pl.when(should_run)
         def _full():
-            compute(masked=False)
+            compute(*whole, None)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -122,29 +202,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         should_run = True
         last_k = nk - 1
 
-    def _compute(masked):
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
+    def _compute(rows, cols, mask):
+        r, c = slice(*rows), slice(*cols)
+        q = q_ref[0, 0, r, :]
+        k = k_ref[0, 0, c, :]
+        v = v_ref[0, 0, c, :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        if masked:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        m_prev = m_scr[:, :1]
+        if mask is not None:
+            s = _causal_mask(s, *mask)
+        m_prev = m_scr[r, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        l_new = alpha * l_scr[r, :1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[r, :] = acc_scr[r, :] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[r, :] = jnp.broadcast_to(m_new, (m_new.shape[0], LANES))
+        l_scr[r, :] = jnp.broadcast_to(l_new, (l_new.shape[0], LANES))
 
+    # No strips here: a row's softmax bookkeeping, not its scores, is most
+    # of this kernel's time, so strips by rows gain nothing and strips by
+    # columns (one more online-softmax step each) lose (PERF.md section 6).
     _causal_dispatch(_compute, causal, should_run, qi, ki,
                      block_q, block_k)
 
@@ -246,31 +326,28 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         should_run = True
         last_k = nk - 1
 
-    def _compute(masked):
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :1]
-        delta = delta_ref[0, 0, :, :1]
+    def _compute(rows, cols, mask):
+        r, c = slice(*rows), slice(*cols)
+        q = q_ref[0, 0, r, :]
+        k = k_ref[0, 0, c, :]
+        v = v_ref[0, 0, c, :]
+        do = do_ref[0, 0, r, :]
+        lse = lse_ref[0, 0, r, :1]
+        delta = delta_ref[0, 0, r, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        if masked:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+        if mask is not None:
+            s = _causal_mask(s, *mask)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+        dq_scr[r, :] = dq_scr[r, :] + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     _causal_dispatch(_compute, causal, should_run, qi, ki,
-                     block_q, block_k)
+                     block_q, block_k, strips="rows")
 
     @pl.when(ki == last_k)
     def _finalize():
@@ -294,35 +371,32 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         should_run = True
 
-    def _compute(masked):
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :1]
-        delta = delta_ref[0, 0, :, :1]
+    def _compute(rows, cols, mask):
+        r, c = slice(*rows), slice(*cols)
+        q = q_ref[0, 0, r, :]
+        k = k_ref[0, 0, c, :]
+        v = v_ref[0, 0, c, :]
+        do = do_ref[0, 0, r, :]
+        lse = lse_ref[0, 0, r, :1]
+        delta = delta_ref[0, 0, r, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        if masked:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+        if mask is not None:
+            s = _causal_mask(s, *mask)
         p = jnp.exp(s - lse)
         pt = p.astype(do.dtype)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+        dv_scr[c, :] = dv_scr[c, :] + jax.lax.dot_general(
             pt, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - delta)).astype(q.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+        dk_scr[c, :] = dk_scr[c, :] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     _causal_dispatch(_compute, causal, should_run, qi, ki,
-                     block_q, block_k)
+                     block_q, block_k, strips="cols")
 
     @pl.when(qi == nq - 1)
     def _finalize():

@@ -2,6 +2,7 @@
 (interpret mode on CPU; the same code paths run compiled on TPU)."""
 
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +79,78 @@ def test_flash_bf16_close():
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("S,Hq,Hkv,D,block_q,block_k,strips", [
+    (2048, 15, 5, 64, None, None, 4),    # smollm2's heads; 1024^2 tiles, one below
+    (1024, 16, 8, 128, 512, 512, 2),     # internlm2's heads; 512^2 tiles
+    (1024, 4, 2, 64, 256, 512, None),    # not square: whole tile and a select
+    (1000, 4, 2, 64, None, None, 4),     # through the causal pad to 1024
+])
+def test_flash_strips_match_reference(S, Hq, Hkv, D, block_q, block_k,
+                                      strips):
+    """Forward and the three gradients where a tile the diagonal crosses
+    is walked in several strips (and where it is not)."""
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    padded = S + -S % fa.LANES
+    bq, bk = fa._block_sizes(padded, padded, block_q, block_k)
+    strip = fa._diag_strip(bq, bk)
+    assert (strip and bq // strip) == strips
+    q, k, v = _rand_qkv(jax.random.key(7), 1, S, Hq, Hkv, D)
+    w = jax.random.normal(jax.random.key(8), q.shape)
+    pos = _positions(1, S)
+
+    def ref(q, k, v):
+        return dot_attention(q, k, v, pos)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block_q,
+                               block_k=block_k)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    g_fl = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, name in zip(g_fl, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("by", ["cols", "rows"])
+def test_diag_strips_cover_the_causal_half_once(by):
+    """No masked element contributes, no unmasked element is skipped: the
+    strips of a diagonal tile cover every element on or below the
+    diagonal exactly once, what they hold above it lies in their corner
+    ON the diagonal, and ``causal_computed_share`` is the covered area."""
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    block, strip = 1024, 256
+    strips = fa._diag_strips(block, strip, by)
+    assert len(strips) == block // strip
+    seen = np.zeros((block, block), np.int32)
+    for (r0, r1), (c0, c1) in strips:
+        rows = np.arange(r0, r1)[:, None]
+        cols = np.arange(c0, c1)[None, :]
+        above = cols > rows
+        lo = max(r0, c0)              # the corner: [lo, lo + strip) squared
+        assert not above[rows[:, 0] >= lo + strip].any()
+        assert not above[:, cols[0] < lo].any()
+        # what _causal_mask(s, r0, c0) keeps of the strip
+        seen[r0:r1, c0:c1] += ~above
+    np.testing.assert_array_equal(seen, np.tri(block, dtype=np.int32))
+    area = sum((r1 - r0) * (c1 - c0) for (r0, r1), (c0, c1) in strips)
+    assert area / block ** 2 == (1 + strip / block) / 2 == 0.625
+    # 2,048: four tiles, one skipped, one whole, two in strips
+    assert fa.causal_computed_share(2048, 1024, 1024, 256) == \
+        (block ** 2 + 2 * area) / 2048 ** 2 == 0.5625
+    assert fa.causal_computed_share(2048, 1024, 1024, 1024) == 0.75
+    assert fa.causal_computed_share(4096, 1024, 1024, 256) == 0.53125
+    assert fa.causal_computed_share(4096, 1024, 1024, 1024) == 0.625
+    assert fa.causal_computed_share(2048) == fa.causal_computed_share(
+        2048, strip=fa.DIAG_STRIP)
+    assert fa.causal_computed_share(1024, 256, 512) == 0.75   # not square
 
 
 @pytest.mark.parametrize("seq_shards", [2, 4])

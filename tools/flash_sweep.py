@@ -1,0 +1,80 @@
+"""The three flash kernels alone on the chip, over tile and strip widths.
+
+    chiprun -- python3 -m tools.flash_sweep
+
+Both train cells' shapes (PERF.md section 4), causal, bf16; per (block,
+strip) the device time a call of forward, dq and dk/dv, read from a trace
+by the kernels' names with the benchmark's own reader, and the score elements
+the three compute a second.  ``strip`` is dq's and dk/dv's; ``strip ==
+block`` is a diagonal tile computed whole and masked by a select, as the
+forward always computes it.  What it read last stands over
+``DEFAULT_BLOCK`` in ``ray_tpu/ops/flash_attention.py``.
+"""
+
+import importlib
+import tempfile
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.lib import flash_names, trace_reduce
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+SHAPES = [(8, 2048, 15, 5, 64), (1, 4096, 16, 8, 128)]  # B, S, Hq, Hkv, D
+KERNELS = tuple(flash_names.KERNEL_OPS)                  # fwd, dq, dkdv
+
+
+def kernel_ms(fn, args, calls=10):
+    """Device ms a call of each kernel inside ``fn``, from a trace."""
+    jax.block_until_ready(fn(*args))            # compile, warm
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            jax.block_until_ready([fn(*args) for _ in range(calls)])
+        trace = trace_reduce.read(trace_dir)
+    return {kernel: 1e3 * flash_names.kernel_seconds(trace, kernel) / calls
+            for kernel in KERNELS}
+
+
+def sweep(blocks=(512, 1024), strips=(128, 256, 512, None)):
+    shipped = fa.DIAG_STRIP
+    try:
+        _sweep(blocks, strips)
+    finally:
+        fa.DIAG_STRIP = shipped
+
+
+def _sweep(blocks, strips):
+    print("B S Hq Hkv D block strip fwd_ms dq_ms dkdv_ms Gelem/s")
+    for B, S, Hq, Hkv, D in SHAPES:
+        keys = jax.random.split(jax.random.key(S), 4)
+        q, do = (jax.random.normal(k, (B, Hq, S, D), jnp.bfloat16)
+                 for k in keys[:2])
+        k, v = (jax.random.normal(k, (B, Hkv, S, D), jnp.bfloat16)
+                for k in keys[2:])
+        for block in blocks:
+            for strip in dict.fromkeys(s or block for s in strips):
+                fa.DIAG_STRIP = strip           # read when a kernel is traced
+                kw = dict(causal=True, block_q=block, block_k=block,
+                          interpret=fa._use_interpret())
+
+                @jax.jit
+                def three(q, k, v, do):
+                    o, lse = fa._fwd(q, k, v, **kw)
+                    kf, vf = (jnp.repeat(x, Hq // Hkv, axis=1)
+                              for x in (k, v))
+                    return o, fa._bwd_impl(q, kf, vf, o, lse, do, **kw)
+
+                ms = kernel_ms(three, (q, k, v, do))
+                share = fa.causal_computed_share    # the forward: no strips
+                elems = B * Hq * S * S * (share(S, block, block, block)
+                                          + 2 * share(S, block, block, strip))
+                print(B, S, Hq, Hkv, D, block, strip,
+                      *(f"{ms[kernel]:.3f}" for kernel in KERNELS),
+                      f"{elems / (sum(ms.values()) or 1) / 1e6:.1f}",
+                      flush=True)
+
+
+if __name__ == "__main__":
+    if jax.default_backend() != "tpu":
+        raise SystemExit("flash_sweep times the compiled kernels: tpu only")
+    sweep()
