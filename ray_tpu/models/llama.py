@@ -35,7 +35,10 @@ import jax.numpy as jnp
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 PyTree = Any
-LAYER_KINDS = ("attention", "mamba")
+LAYER_KINDS = ("attention", "mamba", "window")
+# The kinds whose mixer is attention: they share ``ATTENTION_LEAVES``,
+# stacked over all of them in their order.
+ATTENDING_KINDS = ("attention", "window")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,17 +98,30 @@ class LlamaConfig:
     # the softmax's probabilities as they are (OLMoE: norm_topk_prob
     # false).
     moe_norm_topk: bool = True
+    # What the router reads: "ffn", the rows the experts multiply (the
+    # stream after attention, normed), or "layer", the layer's INPUT,
+    # before the attention norm and un-normed (SmallThinker).  And the
+    # experts' gate activation: "silu" or "relu".
+    moe_router_input: str = "ffn"
+    moe_activation: str = "silu"
     # RMSNorm on q and on k, each over its WHOLE projection, before the
     # split into heads and before RoPE (OLMoE).
     qk_norm: bool = False
-    # ONE PERIOD of the layer stack, a kind per layer: "attention" or
+    # ONE PERIOD of the layer stack, a kind per layer: "attention",
     # "mamba" (a Mamba-2 mixer, models/mamba2.py, in place of attention;
-    # every layer keeps its FFN).  n_layers is a whole number of periods
-    # and the layer scans run a period an iteration.  () is a period of
-    # one attention layer: the plain decoder.
+    # every layer keeps its FFN) or "window" (attention over the last
+    # ``window_size`` keys, the query's own among them; served only).
+    # n_layers is a whole number of periods and the layer scans run a
+    # period an iteration.  () is a period of one attention layer: the
+    # plain decoder.
     layer_pattern: Tuple[str, ...] = ()
-    # Rotary position embedding on q and k (False: NoPE, Granite 4).
+    window_size: int = 0
+    # Rotary position embedding on q and k (False: NoPE, Granite 4), and
+    # the kinds of attending layer that go without it where the others
+    # rotate (SmallThinker: the global layers are NoPE, the window
+    # layers rotate).
     rope: bool = True
+    nope_kinds: Tuple[str, ...] = ()
     # Softmax scale in place of head_dim ** -0.5, and the Granite
     # multipliers: on the embedding, on both residual branches, and the
     # divisor of the logits.
@@ -133,6 +149,7 @@ class LlamaConfig:
     def __post_init__(self):
         # a configuration file's lists and type names, made hashable
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        object.__setattr__(self, "nope_kinds", tuple(self.nope_kinds))
         for name in ("dtype", "stream_dtype", "ssm_state_dtype"):
             if isinstance(getattr(self, name), str):
                 object.__setattr__(self, name,
@@ -147,6 +164,19 @@ class LlamaConfig:
                 f"periods of {len(self.period)} layers")
         if "mamba" in self.layer_pattern and self.ssm_heads < 1:
             raise ValueError("a mamba layer needs ssm_heads")
+        if "window" in self.layer_pattern:
+            if self.window_size < 1:
+                raise ValueError("a window layer needs window_size")
+            if "mamba" in self.layer_pattern:
+                raise ValueError(
+                    "no serving cache holds window rings beside "
+                    "recurrent states: window and mamba layers do not mix")
+        if set(self.nope_kinds) - set(ATTENDING_KINDS):
+            raise ValueError(f"nope_kinds: choose from {ATTENDING_KINDS}")
+        if self.moe_router_input not in ("ffn", "layer"):
+            raise ValueError(f"moe_router_input {self.moe_router_input!r}")
+        if self.moe_activation not in ("silu", "relu"):
+            raise ValueError(f"moe_activation {self.moe_activation!r}")
 
     @property
     def period(self) -> Tuple[str, ...]:
@@ -160,6 +190,14 @@ class LlamaConfig:
     def layers_of(self, kind: str) -> int:
         """How many of the n_layers are of ``kind``."""
         return (self.n_layers // self.period_len) * self.period.count(kind)
+
+    def attending_layers(self) -> int:
+        """How many of the n_layers attend (hold ``ATTENTION_LEAVES``)."""
+        return sum(self.layers_of(kind) for kind in ATTENDING_KINDS)
+
+    def ropes(self, kind: str) -> bool:
+        """Whether an attending layer of ``kind`` rotates q and k."""
+        return self.rope and kind not in self.nope_kinds
 
     @property
     def attn_scale(self) -> float:
@@ -330,7 +368,7 @@ def init_params(rng: jax.Array, config: LlamaConfig,
     def dense(key, shape, fan_in):
         return init_dense(key, shape, fan_in, dtype)
 
-    L, La = c.n_layers, c.layers_of("attention")
+    L, La = c.n_layers, c.attending_layers()
     if c.moe_experts > 0:
         E = c.moe_experts
         ffn = {
@@ -481,11 +519,14 @@ def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
 
 def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   positions: jax.Array,
-                  scale: Optional[float] = None) -> jax.Array:
+                  scale: Optional[float] = None,
+                  window: Optional[int] = None) -> jax.Array:
     """Reference einsum attention, causal, GQA via head broadcast.
 
     q: (B, S, Hq, D); k/v: (B, S, Hkv, D).  All-jnp so XLA fuses; the
-    flash/ring impls are drop-in replacements (ray_tpu.ops).
+    flash/ring impls are drop-in replacements (ray_tpu.ops).  With
+    ``window`` a query sees the last ``window`` keys only, its own among
+    them.
     """
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -497,6 +538,9 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # Causal mask on absolute positions (supports packed/offset pos).
     mask = positions[:, None, None, :, None] >= positions[:, None, None,
                                                           None, :]
+    if window is not None:
+        mask &= (positions[:, None, None, :, None]
+                 - positions[:, None, None, None, :]) < window
     scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v,
@@ -530,10 +574,11 @@ def _get_attention_fn(config) -> Callable:
 
 
 def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
-              config: LlamaConfig):
+              config: LlamaConfig, kind: str = "attention"):
     """Shared by the training forward and the KV-cache decode path —
     the conventions here (f32 MXU accumulation via matmul, bf16 rope)
-    must stay identical across both."""
+    must stay identical across both.  ``kind``: the attending layer's,
+    which decides whether it rotates (``LlamaConfig.ropes``)."""
     c = config
     B, S, _ = x.shape
     dt = c.dtype
@@ -547,7 +592,7 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     k = k.reshape(B, S, c.n_kv_heads, c.head_dim)
     v = matmul(h, layer["wv"].astype(dt)).reshape(B, S, c.n_kv_heads,
                                                   c.head_dim)
-    if c.rope:
+    if c.ropes(kind):
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
     q = with_logical_constraint(q, "batch", "seq", "heads", "head_dim")
@@ -556,6 +601,9 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
 
 
 EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+# A serving prefill longer than this attends through the flash forward:
+# at 4,096 positions one row's (28, S, S) float32 scores are 1.9 GB.
+FLASH_PREFILL_FROM = 2048
 
 
 def split_expert_stacks(layers: Dict[str, jax.Array],
@@ -585,18 +633,23 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
     the config says: THE function every path calls (training forward,
     ``prefill_forward``, ``forward_with_cache``, the serve programs; the
     conventions shared with ``_qkv_rope`` live here).  Constraints are
-    no-ops outside a mesh.  Returns what ``ffn_half`` does."""
+    no-ops outside a mesh.  Returns what ``ffn_half`` does.  ``x`` is the
+    layer's input: what a router placed before attention reads."""
     B, S, _ = x.shape
+    route_x = x if config.moe_router_input == "layer" else None
     x = residual_add(x, matmul(attn.reshape(B, S, config.q_dim),
                                layer["wo"].astype(config.dtype)), config)
-    return ffn_half(x, layer, config, valid, layer_index)
+    return ffn_half(x, layer, config, valid, layer_index, route_x)
 
 
 def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
              config: LlamaConfig, valid: Optional[jax.Array] = None,
-             layer_index: Optional[jax.Array] = None):
+             layer_index: Optional[jax.Array] = None,
+             route_x: Optional[jax.Array] = None):
     """The FFN half of a layer, after whichever mixer (attention's output
-    projection, a Mamba-2 mixer) has been added to ``x``.
+    projection, a Mamba-2 mixer) has been added to ``x``.  ``route_x``:
+    what the router reads where that is not the normed ``x``
+    (``moe_router_input``).
 
     Returns ``(x, aux, expert_rows)``: the layer's Switch aux loss and
     the rows each expert computed ((E,) int32) — a constant 0 and None
@@ -627,7 +680,8 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
                          intermediate_size=c.intermediate_size,
                          n_experts=c.moe_experts, top_k=c.moe_top_k,
                          capacity_factor=c.moe_capacity_factor,
-                         norm_topk=c.moe_norm_topk, dtype=dt)
+                         norm_topk=c.moe_norm_topk,
+                         activation=c.moe_activation, dtype=dt)
     moe_params = {k: layer[k] for k in ("router",) + EXPERT_STACKS}
     mesh = current_mesh()
     if mesh is not None and mesh.shape.get("expert", 1) > 1:
@@ -637,7 +691,8 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
         expert_rows = None
     else:
         ff, aux, expert_rows = moe.moe_ffn_dropless(
-            h, moe_params, mcfg, valid=valid, layer_index=layer_index)
+            h, moe_params, mcfg, valid=valid, layer_index=layer_index,
+            route_x=route_x)
     x = residual_add(x, ff, c)
     return with_logical_constraint(x, "batch", "seq", None), aux, \
         expert_rows
@@ -686,10 +741,16 @@ ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
 
 
 def _leaf_kind(name: str) -> Optional[str]:
-    """The kind of layer that alone has this leaf (None: every layer)."""
+    """The kind of layer that alone has this leaf (None: every layer);
+    "attention" stands for every attending kind (``_leaf_group``)."""
     if name in ATTENTION_LEAVES:
         return "attention"
     return "mamba" if name.startswith("ssm_") else None
+
+
+def _leaf_group(kind: str) -> str:
+    """The kind under whose name a layer of ``kind`` finds its leaves."""
+    return "attention" if kind in ATTENDING_KINDS else kind
 
 
 def by_period(tree: PyTree, config: LlamaConfig) -> PyTree:
@@ -730,21 +791,24 @@ def period_layers(layers: Dict[str, jax.Array], period, p: jax.Array,
                   config: LlamaConfig):
     """Per layer of period ``p``: ``(kind, index among the period's layers
     of that kind, its leaves)``.  ``layers``: the whole stacks; ``period``:
-    ``scanned_layers``' slice."""
+    ``scanned_layers``' slice.  The attending kinds share their leaves'
+    stacks, in the layers' order."""
     if config.period_len == 1:
         return [(config.period[0], 0, period)]
+    groups = [_leaf_group(kind) for kind in config.period]
     seen = {kind: 0 for kind in LAYER_KINDS}
     out = []
-    for j, kind in enumerate(config.period):
+    for j, (kind, group) in enumerate(zip(config.period, groups)):
         i = seen[kind]
         seen[kind] += 1
         at = {None: layer_index(p, config.period_len, j),
-              kind: layer_index(p, config.period.count(kind), i)}
+              group: layer_index(p, groups.count(group),
+                                 groups[:j].count(group))}
         out.append((kind, i, {
             name: jax.lax.dynamic_index_in_dim(
                 leaf, at[_leaf_kind(name)], 0, keepdims=False)
             for name, leaf in layers.items()
-            if _leaf_kind(name) in (None, kind)}))
+            if _leaf_kind(name) in (None, group)}))
     return out
 
 
@@ -797,12 +861,16 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     summed MoE load-balancing loss over layers (0.0 for dense)."""
     c = config
     if (c.layers_of("mamba") or c.attention_multiplier is not None
-            or c.embedding_multiplier != 1.0 or c.logits_scaling != 1.0):
+            or c.embedding_multiplier != 1.0 or c.logits_scaling != 1.0
+            or c.layers_of("window") or c.nope_kinds
+            or c.moe_router_input != "ffn"):
         raise NotImplementedError(
             "llama.forward (training) computes a stack of one kind of "
             "attention layer with the default scale, embedding and "
-            "logits: a config with state-space layers or the Granite "
-            "multipliers is served only (llama_serve.build_*)")
+            "logits, its router after attention: a config with "
+            "state-space or window layers, a kind without RoPE, a router "
+            "on the layer's input or the Granite multipliers is served "
+            "only (llama_serve.build_*)")
     if positions is not None and c.attention_impl != "dot":
         # flash/ring mask on raw row index, not positions — packed or
         # offset sequences would silently attend across boundaries.
@@ -1181,7 +1249,8 @@ def dequantize_kv_blocks(stored: jax.Array, scale: jax.Array,
 def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
                kv_step: Callable, positions: Optional[jax.Array] = None,
                kv_layers: Any = None, valid: Optional[jax.Array] = None,
-               lengths: Optional[jax.Array] = None):
+               lengths: Optional[jax.Array] = None,
+               window_step: Optional[Callable] = None):
     """The forward pass that serving shares: embed, rope table, a scan
     over the PERIODS of the layer pattern -- per attention layer
     ``_qkv_rope`` -> ``kv_step`` -> ``attn_out_ffn``, per Mamba layer
@@ -1202,12 +1271,15 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     position alone, (B, V), ``valid`` defaults to position < length, and
     a Mamba layer's states are those of that position; without, (B, S,
     V) and every position is real.  A Mamba layer starts from an empty
-    state: only a cold prefill walks one.
+    state: only a cold prefill walks one.  A window layer is an attention
+    layer whose queries and fresh rows go to ``window_step(q, k, v,
+    positions) -> (attn, ys)`` instead (no cache is walked for one).
 
     Returns ``(logits, ys stacked over the attention layers, expert
-    rows, Mamba states)``: the (L, E) int32 rows each layer's experts
-    computed, None for a dense config; ``(recurrent, conv)`` states
-    stacked over the Mamba layers, None where there are none."""
+    rows, Mamba states, window ys)``: the (L, E) int32 rows each layer's
+    experts computed, None for a dense config; ``(recurrent, conv)``
+    states stacked over the Mamba layers and ``window_step``'s ys over
+    the window layers, None where there are none."""
     c = config
     x = embed(params, tokens, c)
     if positions is None:
@@ -1222,15 +1294,19 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
 
     def body(x, period_index_cache):
         period, p, kv_period = period_index_cache
-        kv_ys, ssm_ys, rows = [], [], []
+        kv_ys, ssm_ys, win_ys, rows = [], [], [], []
         for j, (kind, i, layer) in enumerate(
                 period_layers(sliced, period, p, c)):
             layer = {**layer, **stacks}
-            if kind == "attention":
-                q, k, v = _qkv_rope(x, layer, sin, cos, c)
-                attn, ys = kv_step(q, k, v, positions,
-                                   layer_of(kv_period, i, c))
-                kv_ys.append(ys)
+            if kind in ATTENDING_KINDS:
+                q, k, v = _qkv_rope(x, layer, sin, cos, c, kind)
+                if kind == "window":
+                    attn, ys = window_step(q, k, v, positions)
+                    win_ys.append(ys)
+                else:
+                    attn, ys = kv_step(q, k, v, positions,
+                                       layer_of(kv_period, i, c))
+                    kv_ys.append(ys)
                 x, _aux, rows_j = attn_out_ffn(
                     x, attn, layer, c, valid=valid,
                     layer_index=layer_index(p, plen, j))
@@ -1246,20 +1322,21 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
                     layer_index=layer_index(p, plen, j))
             rows.append(rows_j)
         return x, (stack_period(kv_ys, c), stack_period(rows, c),
-                   stack_period(ssm_ys, c))
+                   stack_period(ssm_ys, c), stack_period(win_ys, c))
 
     x, stacked = jax.lax.scan(
         body, x,
         (scanned_layers(sliced, c),
          jnp.arange(c.n_layers // plen, dtype=jnp.int32),
          by_period(kv_layers, c)))
-    ys, expert_rows, ssm_ys = merge_periods(stacked, c)
+    ys, expert_rows, ssm_ys, win_ys = merge_periods(stacked, c)
     x = rms_norm(x, params["final_norm"], c.norm_eps).astype(c.dtype)
     if lengths is None:
-        return head_logits(x, params, c), ys, expert_rows, ssm_ys
+        return head_logits(x, params, c), ys, expert_rows, ssm_ys, win_ys
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)  # (B,1,H)
-    return head_logits(last, params, c)[:, 0], ys, expert_rows, ssm_ys
+    return (head_logits(last, params, c)[:, 0], ys, expert_rows, ssm_ys,
+            win_ys)
 
 
 def prefill_forward(params: PyTree, tokens: jax.Array,
@@ -1281,8 +1358,8 @@ def prefill_forward(params: PyTree, tokens: jax.Array,
     length 0); ``return_expert_rows`` adds a fourth result, the (L, E)
     int32 rows each layer's experts computed (None for a dense
     config)."""
-    last_logits, ks, vs, expert_rows, _states = prefill_with_states(
-        params, tokens, lengths, config)
+    last_logits, ks, vs, expert_rows, _states, _window = \
+        prefill_with_states(params, tokens, lengths, config)
     if return_expert_rows:
         return last_logits, ks, vs, expert_rows
     return last_logits, ks, vs
@@ -1291,16 +1368,39 @@ def prefill_forward(params: PyTree, tokens: jax.Array,
 def prefill_with_states(params: PyTree, tokens: jax.Array,
                         lengths: jax.Array, config: LlamaConfig):
     """``prefill_forward`` with everything a serving cache takes in:
-    ``(last_logits, ks, vs, expert rows, Mamba states)`` -- ks/vs over
-    the attention layers alone; the states ``(recurrent (Lm, G, N, nh x
-    hd), conv (Lm, K - 1, G, conv_dim))`` as of each row's last real
-    position, None for a model without Mamba layers."""
-    def kv_step(q, k, v, positions, _cache):
-        return dot_attention(q, k, v, positions, config.attn_scale), (k, v)
+    ``(last_logits, ks, vs, expert rows, Mamba states, window K/V)`` --
+    ks/vs over the attention layers alone; the states ``(recurrent (Lm,
+    G, N, nh x hd), conv (Lm, K - 1, G, conv_dim))`` as of each row's
+    last real position, None for a model without Mamba layers; the
+    window layers' ``(ks, vs)`` at every position of the prompt (which
+    of them a cache keeps is the cache's business), None without such
+    layers.
 
-    last_logits, (ks, vs), expert_rows, states = layer_walk(
-        params, tokens, config, kv_step, lengths=lengths)
-    return last_logits, ks, vs, expert_rows, states
+    Attention is the masked einsum while its (G, Hq, P, P) float32
+    scores are small, and the flash forward (``ops/flash_attention.py``,
+    scores never leave the chip; a window layer's tiles outside its band
+    are skipped) for prompts longer than ``FLASH_PREFILL_FROM``."""
+    scale = config.attn_scale
+    if tokens.shape[1] > FLASH_PREFILL_FROM:  # raylint: disable=recompile-hazard -- the engine's prefill shapes are its buckets, each warmed once; which attention a bucket takes is fixed with its shape
+        from ray_tpu.ops.flash_attention import flash_prefill_attention
+
+        def attend(q, k, v, positions, window):
+            return flash_prefill_attention(q, k, v, scale=scale,
+                                           window=window)
+    else:
+        def attend(q, k, v, positions, window):
+            return dot_attention(q, k, v, positions, scale, window)
+
+    def kv_step(q, k, v, positions, _cache):
+        return attend(q, k, v, positions, None), (k, v)
+
+    def window_step(q, k, v, positions):
+        return attend(q, k, v, positions, config.window_size), (k, v)
+
+    last_logits, (ks, vs), expert_rows, states, window = layer_walk(
+        params, tokens, config, kv_step, lengths=lengths,
+        window_step=window_step)
+    return last_logits, ks, vs, expert_rows, states, window
 
 
 def insert_prefill(cache: Dict[str, jax.Array], ks: jax.Array,
@@ -1369,10 +1469,11 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
     at those positions and returns (logits (B, T, V), new_cache).  No
     program that serves runs it; tests hold T > 1 through a cache to the
     reference with it."""
-    if config.layers_of("mamba"):
+    if config.layers_of("mamba") or config.layers_of("window"):
         raise NotImplementedError(
-            "forward_with_cache holds K/V alone; a config with state-space "
-            "layers runs through llama_serve.build_prefill / build_decode_k")
+            "forward_with_cache holds one K/V stack alone; a config with "
+            "state-space or window layers runs through "
+            "llama_serve.build_prefill / build_decode_k")
     scale = config.attn_scale
 
     # The T new K/V rows go into each slot's cache at its own positions
@@ -1388,7 +1489,7 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
         return (_cache_attend(q, ck_l, cv_l, positions, scale),
                 (ck_l, cv_l))
 
-    logits, (new_k, new_v), _rows, _states = layer_walk(
+    logits, (new_k, new_v), _rows, _states, _window = layer_walk(
         params, tokens, config, kv_step, positions=positions,
         kv_layers=(cache["k"], cache["v"]))
     return logits, {"k": new_k, "v": new_v}

@@ -7,7 +7,9 @@ engine, thread or weights are needed to lower one.  By the cache kept:
 
 - a per-slot cache (``init_cache``: K and V ``(La, B, S, Hkv, D)`` over
   the attention layers and, for a model with Mamba-2 layers, each slot's
-  recurrent and conv states beside them): ``prefill``, ``decode_k``;
+  recurrent and conv states beside them; for a model with window layers
+  a pool of every position for its full layers and a ring of the last
+  ``window_size`` for its window layers): ``prefill``, ``decode_k``;
 - a block pool ``(N, L, bs, Hkv, D)`` (``llama.init_paged_kv_cache``):
   ``prefill_cold``, ``prefill_warm``, ``decode_paged``, ``inject`` (and
   its inverse ``BlockPool.extract``), ``spec_verify``;
@@ -61,6 +63,8 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     ``cfg.ssm_state_dtype``) and conv window ``conv (Lm, K - 1, B,
     conv_dim)``.  A state is not positional: ``build_prefill`` replaces a
     slot's whole state, ``decode_step`` advances it in place."""
+    if cfg.layers_of("window"):
+        return _init_window_cache(cfg, slots, max_len)
     cache = llama.init_kv_cache(cfg, slots, max_len)
     if cfg.layers_of("mamba"):
         from ray_tpu.models import mamba2
@@ -69,14 +73,41 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     return cache
 
 
+def _init_window_cache(cfg: LlamaConfig, slots: int, max_len: int):
+    """A model with window layers keeps two K/V pools: ``k`` / ``v``,
+    every position of its full layers, and ``wk`` / ``wv``, a RING of
+    the last ``ring_len`` positions of its window layers (position ``p``
+    in row ``p mod ring_len``).  Both are stored as the rows the decode
+    kernel reads, ``(layers, B, positions * Hkv, D)``: with 4 kv heads a
+    ``(..., positions, 4, D)`` leaf is padded to the sublane tile and
+    occupies a multiple of its bytes."""
+    def pool(layers, positions):
+        return jnp.zeros((layers, slots, positions * cfg.n_kv_heads,
+                          cfg.head_dim), cfg.dtype)
+
+    full = cfg.layers_of("attention"), max_len
+    ring = cfg.layers_of("window"), ring_len(cfg, max_len)
+    return {"k": pool(*full), "v": pool(*full),
+            "wk": pool(*ring), "wv": pool(*ring)}
+
+
+def ring_len(cfg: LlamaConfig, max_len: int) -> int:
+    """Positions a window layer's ring holds a slot."""
+    return min(cfg.window_size, max_len)
+
+
 def cache_pools(cfg: LlamaConfig, slots: int, max_len: int):
     """``{pool: (bytes, storage type)}`` of ``init_cache``'s tree: ``kv``
     (K and V together) and, for a model with Mamba layers, ``ssm`` and
-    ``conv``."""
+    ``conv``; for a model with window layers ``kv_full`` and
+    ``kv_window``."""
     shapes = jax.eval_shape(lambda: init_cache(cfg, slots, max_len))
+    pool_of = {"k": "kv_full" if "wk" in shapes else "kv",
+               "wk": "kv_window"}
+    pool_of.update(v=pool_of["k"], wv="kv_window")
     pools = {}
     for name, leaf in shapes.items():
-        pool = "kv" if name in ("k", "v") else name
+        pool = pool_of.get(name, name)
         nbytes = int(leaf.size) * leaf.dtype.itemsize
         pools[pool] = (pools.get(pool, (0,))[0] + nbytes, str(leaf.dtype))
     return pools
@@ -87,7 +118,7 @@ def state_bytes_per_slot(cfg: LlamaConfig):
     all Mamba layers ({} for a model without them): what a decode step
     reads and writes for a slot it advances."""
     return {pool: nbytes for pool, (nbytes, _) in
-            cache_pools(cfg, 1, 1).items() if pool != "kv"}
+            cache_pools(cfg, 1, 1).items() if not pool.startswith("kv")}
 
 
 def insert_states(cache, states, slots):
@@ -141,6 +172,12 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     ``ops/ssm_state_update.py``); an inactive slot's states are left as
     they are.
 
+    A model with window layers carries its two pools as rows (``init_
+    cache``): ``ck`` / ``cv`` the full layers' and, after the lengths,
+    the window layers' rings.  A window layer writes its new row at
+    ``length mod ring`` and attends its first ``min(length + 1, ring)``
+    ring rows through the same kernel.
+
     This step is ``llama.layer_walk`` written out, its K/V the carry of
     the layer scan: the walk, handed a carry, compiled to the same sizes
     but not to the same text as the program the benchmark's cells have
@@ -149,6 +186,7 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     sliced, stacks = llama.split_expert_stacks(params["layers"], cfg)
     plen = cfg.period_len
     n_attn, n_ssm = cfg.period.count("attention"), cfg.period.count("mamba")
+    n_win, hkv = cfg.period.count("window"), cfg.n_kv_heads
 
     def step(carry, _):
         ck, cv, tok, lens, *state = carry
@@ -162,11 +200,14 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         # past the attended prefix.  Their row goes out of range
         # and the scatter drops it.  (Nor may an inactive slot's
         # recurrent state advance: mamba2.decode.)
-        slots = tok.shape[0]
-        rows = jnp.arange(slots, dtype=jnp.int32)
+        rows = jnp.arange(tok.shape[0], dtype=jnp.int32)
         pos = jnp.where(active & (lens < s_active), lens,
                         ck.shape[2])
         scale = cfg.attn_scale
+        at = {"attention": pos}           # out of range past any row
+        if n_win:
+            ring = state[0].shape[2] // hkv
+            at["window"] = jnp.where(pos < ck.shape[2], lens % ring, ring)
 
         def body(carry, period_and_index):
             x, ck, cv, *state = carry
@@ -175,22 +216,27 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
             for j, (kind, i, layer) in enumerate(
                     llama.period_layers(sliced, period, p, cfg)):
                 layer = {**layer, **stacks}
-                if kind == "attention":
-                    l = llama.layer_index(p, n_attn, i)
-                    q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg)
+                if kind != "mamba":
+                    # The layer's pool: the carry's K/V or, for a window
+                    # layer, the rings after the lengths.
+                    ringed = kind == "window"
+                    pk, pv = state if ringed else (ck, cv)
+                    l = llama.layer_index(p, n_win if ringed else n_attn, i)
+                    q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg,
+                                                kind)
                     # Write before attend: the new row is among the keys.
-                    ck = ck.at[l, rows, pos].set(
-                        kk[:, 0].astype(ck.dtype), mode="drop",
-                        indices_are_sorted=True, unique_indices=True)
-                    cv = cv.at[l, rows, pos].set(
-                        vv[:, 0].astype(cv.dtype), mode="drop",
-                        indices_are_sorted=True, unique_indices=True)
+                    pk = _write(pk, l, rows, at[kind], kk[:, 0])
+                    pv = _write(pv, l, rows, at[kind], vv[:, 0])
                     # The kernel reads the carry where it lies, each row
                     # as far as it is long; an inactive row's zeros are
                     # discarded below.
                     attn = decode_attention(
-                        q[:, 0], ck, cv, l, lens, active,
-                        s_active=s_active, scale=scale)[:, None]
+                        q[:, 0], pk, pv, l, lens, active,
+                        s_active=s_active, scale=scale, hkv=hkv)[:, None]
+                    if ringed:
+                        state = [pk, pv]
+                    else:
+                        ck, cv = pk, pv
                     x, _aux, rows_j = llama.attn_out_ffn(
                         x, attn, layer, cfg, valid=active[:, None],
                         layer_index=llama.layer_index(p, plen, j))
@@ -226,26 +272,88 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     return step
 
 
+def _write(pool, l, slots, pos, new):
+    """``new`` (B, Hkv, D) as slot ``slots[b]``'s position ``pos[b]`` of
+    layer ``l`` of a pool ``(layers, B, positions, Hkv, D)`` or of its
+    rows ``(layers, B, positions * Hkv, D)``; a position out of range
+    writes nothing."""
+    if pool.ndim == 4:
+        hkv = new.shape[1]
+        pos = pos[:, None] * hkv + jnp.arange(hkv, dtype=jnp.int32)[None, :]
+        slots = slots[:, None]
+    return pool.at[l, slots, pos].set(
+        new.astype(pool.dtype), mode="drop", indices_are_sorted=True,
+        unique_indices=True)
+
+
+# What rides the carry after the lengths, if the cache has it: a model
+# has Mamba states or window rings, never both.
+_CARRIED = (("ssm", "conv"), ("wk", "wv"))
+
+
 def _carry(cache, tok, lens):
     """A cache tree as ``decode_step``'s carry: K, V, the tokens, the
-    lengths, then the Mamba states if the model has them."""
+    lengths, then the Mamba states or the window rings if the model has
+    them."""
     return (cache["k"], cache["v"], tok, lens,
-            *(cache[name] for name in ("ssm", "conv") if name in cache))
+            *(cache[name] for names in _CARRIED for name in names
+              if name in cache))
 
 
-def _uncarry(carry):
+def _uncarry(carry, cache):
+    """The carry as a cache tree with ``cache``'s leaves."""
     ck, cv, tok, lens, *state = carry
-    return ({"k": ck, "v": cv, **dict(zip(("ssm", "conv"), state))},
-            tok, lens)
+    names = next((names for names in _CARRIED if names[0] in cache), ())
+    return {"k": ck, "v": cv, **dict(zip(names, state))}, tok, lens
 
 
 # ------------------------------------------------------------- dense plane
+def _insert_rows(pool, new, slots):
+    """A prefill group's rows into a pool of rows, from each slot's first
+    position on.  pool ``(layers, B, positions * Hkv, D)``; new ``(layers,
+    G, P, Hkv, D)``, ``P`` at most the pool's positions; slots (G,), a
+    negative one drops its row.  A slot's rows are written where they
+    lie (``insert_prefill`` copies every slot's first ``P`` positions)."""
+    layers, G, P, hkv, d = new.shape
+    new = new.reshape(layers, G, 1, P * hkv, d).astype(pool.dtype)
+    for g in range(G):
+        at = (0, jnp.maximum(slots[g], 0), 0, 0)
+        held = jax.lax.dynamic_slice(pool, at, new[:, g].shape)
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.where(slots[g] >= 0, new[:, g], held), at)
+    return pool
+
+
+def _ring_rows(rows, lengths, ring: int):
+    """What a ring of ``ring`` positions holds once a prompt is in: of
+    rows ``(layers, G, P, Hkv, D)`` by position, per ring row r the LAST
+    position below the prompt's length that is r mod ``ring`` (ring rows
+    no position has reached yet take what lies at r).  A prompt bucket
+    the ring holds whole is written as it is."""
+    P = rows.shape[2]
+    if P <= ring:
+        return rows
+    r = jnp.arange(ring, dtype=jnp.int32)[None, :]
+    laps = jnp.maximum((lengths[:, None] - 1 - r) // ring, 0)
+    return jnp.take_along_axis(
+        rows, (r + ring * laps)[None, :, :, None, None], axis=2)
+
+
 def build_prefill(cfg: LlamaConfig) -> Callable:
     def prefill(params, cache, tokens, lengths, slots):
-        last_logits, ks, vs, rows, states = llama.prefill_with_states(
-            params, tokens, lengths, cfg)
-        kv = llama.insert_prefill(cache, ks, vs, slots)
-        cache = {**cache, **kv}
+        last_logits, ks, vs, rows, states, window = \
+            llama.prefill_with_states(params, tokens, lengths, cfg)
+        if window is not None:
+            ring = cache["wk"].shape[2] // cfg.n_kv_heads
+            cache = {
+                "k": _insert_rows(cache["k"], ks, slots),
+                "v": _insert_rows(cache["v"], vs, slots),
+                "wk": _insert_rows(
+                    cache["wk"], _ring_rows(window[0], lengths, ring), slots),
+                "wv": _insert_rows(
+                    cache["wv"], _ring_rows(window[1], lengths, ring), slots)}
+        else:
+            cache = {**cache, **llama.insert_prefill(cache, ks, vs, slots)}
         if states is not None:
             cache = insert_states(cache, states, slots)
         first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
@@ -262,7 +370,7 @@ def build_decode_k(cfg: LlamaConfig) -> Callable:
         step = decode_step(cfg, params, s_active, active)
         carry, (toks, rows) = jax.lax.scan(
             step, _carry(cache, tok, lens), None, length=k)
-        cache, tok, lens = _uncarry(carry)
+        cache, tok, lens = _uncarry(carry, cache)
         return cache, toks, tok, lens, _expert_load(rows)
 
     # tok_dev/len_dev (args 2, 3) are always overwritten by the
@@ -406,7 +514,7 @@ def build_prefill_warm(blocks: BlockPool) -> Callable:
                                        key_abs, key_valid)
             return attn, (k, v)
 
-        last_logits, (ks, vs), rows, _states = llama.layer_walk(
+        last_logits, (ks, vs), rows, _states, _window = llama.layer_walk(
             params, tokens, cfg, kv_step, positions=positions,
             kv_layers=(blocks.gather(pool, "k", prefix_bt),
                        blocks.gather(pool, "v", prefix_bt)),
@@ -486,7 +594,7 @@ def build_spec_verify(blocks: BlockPool) -> Callable:
                                        scale)
             return attn, (ck_l, cv_l)
 
-        logits, (ck, cv), _rows, _states = llama.layer_walk(
+        logits, (ck, cv), _rows, _states, _window = llama.layer_walk(
             params, tokens, cfg, kv_step, positions=positions,
             kv_layers=(blocks.gather(pool, "k", bt),
                        blocks.gather(pool, "v", bt)),

@@ -48,7 +48,13 @@ class MoEConfig:
     # use the softmax's probabilities as they are (OLMoE's
     # ``norm_topk_prob: false``).
     norm_topk: bool = True
+    # The experts' gate activation: "silu" (SwiGLU) or "relu" (ReGLU).
+    activation: str = "silu"
     dtype: Any = jnp.bfloat16
+
+    @property
+    def act(self):
+        return {"silu": jax.nn.silu, "relu": jax.nn.relu}[self.activation]
 
 
 def init_moe_params(rng: jax.Array, config: MoEConfig,
@@ -111,7 +117,8 @@ def _aux_loss(probs: jax.Array, expert_idx: jax.Array) -> jax.Array:
 
 def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
                      valid: Optional[jax.Array] = None,
-                     layer_index: Optional[jax.Array] = None
+                     layer_index: Optional[jax.Array] = None,
+                     route_x: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x: (B, S, D) → (out (B, S, D), aux_loss scalar, expert_rows (E,)
     int32: the rows each expert computed).
@@ -128,7 +135,10 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     touched experts' tiles from the stack where it lies.  (Slicing a
     layer out first makes XLA copy ``[E, D, H]`` per matrix per layer,
     since a custom call cannot read through a dynamic slice.)  The
-    router in ``params`` is the layer's own either way."""
+    router in ``params`` is the layer's own either way.
+
+    ``route_x`` (B, S, D): what the router reads where that is not the
+    rows the experts multiply (a router placed before attention)."""
     c = config
     B, S, D = x.shape
     T = B * S
@@ -136,8 +146,9 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     dt = c.dtype
     xt = x.reshape(T, D).astype(dt)
 
-    probs, gate_vals, expert_idx = _route(xt, params["router"], K,
-                                          c.norm_topk)
+    probs, gate_vals, expert_idx = _route(
+        xt if route_x is None else route_x.reshape(T, D),
+        params["router"], K, c.norm_topk)
     flat = expert_idx.reshape(T * K)
     if valid is not None:
         valid = jnp.broadcast_to(valid, (B, S)).reshape(T)
@@ -165,7 +176,7 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
                                   preferred_element_type=jnp.float32)
 
     rows = xt[order // K]                                  # (T*K, D)
-    act = jax.nn.silu(grouped(rows, w_gate).astype(dt)) \
+    act = c.act(grouped(rows, w_gate).astype(dt)) \
         * grouped(rows, w_up).astype(dt)
     out = grouped(act, w_down)                             # (T*K, D) f32
     # Un-sort (order is a permutation) and sum under the gates.  Rows
@@ -234,7 +245,7 @@ def moe_ffn(x: jax.Array, params: PyTree, config: MoEConfig
 
     h = _einsum("ecd,edh->ech", expert_in, params["w_gate"].astype(dt))
     u = _einsum("ecd,edh->ech", expert_in, params["w_up"].astype(dt))
-    act = jax.nn.silu(h) * u
+    act = c.act(h) * u
     expert_out = _einsum("ech,ehd->ecd", act,
                          params["w_down"].astype(dt))
     expert_out = with_logical_constraint(expert_out,
@@ -261,7 +272,7 @@ def moe_ffn_reference(x: jax.Array, params: PyTree, config: MoEConfig
     def per_expert(e):
         h = xt.astype(dt) @ params["w_gate"][e].astype(dt)
         u = xt.astype(dt) @ params["w_up"][e].astype(dt)
-        return (jax.nn.silu(h) * u) @ params["w_down"][e].astype(dt)
+        return (c.act(h) * u) @ params["w_down"][e].astype(dt)
 
     all_out = jnp.stack([per_expert(e)
                          for e in range(c.n_experts)])  # (E, T, D)

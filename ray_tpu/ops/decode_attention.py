@@ -33,6 +33,15 @@ whole stack:
 GQA is ``group = Hq // Hkv`` query heads per kv head in ``bias``; MHA is
 ``group == 1``.  ``block_k`` follows from the bytes of a position.
 
+A cache of kv heads that do not fill a sublane tile (4 of them) is handed
+over already AS rows, ``(L, B, S * Hkv, D)``: stored with a dimension of
+4 before the lanes it would be padded to a tile, occupy a multiple of its
+bytes and not be contiguous.  The kernel is the same.  A ring of the last
+``S`` positions (a window layer's cache: position ``p`` in row ``p mod
+S``) is read through it unchanged: its rows carry their RoPE from when
+they were written and softmax does not ask for their order, so a row
+attends its first ``min(length, S)`` ring rows.
+
 Interpret mode runs the same kernel on the CPU for the test suite; what
 decides is ``flash_attention._use_interpret``, looked up at call time (a
 test that compiles for a described chip steers that one function).
@@ -42,6 +51,7 @@ from __future__ import annotations
 
 import functools
 import importlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -73,17 +83,17 @@ def block_k(s: int, hkv: int, d: int, itemsize: int) -> int:
     return min(1 << (bk.bit_length() - 1), s)
 
 
-def _tiles(hkv: int, d: int) -> bool:
+def _tiles(hkv: int, d: int, as_rows: bool = False) -> bool:
     """Whether Mosaic can read the cache as ``(S * Hkv, D)`` rows: whole
     lanes, and kv heads that fill their sublane tile (XLA pads a
     second-minor dimension of 6 to 8, and the rows are then not
-    contiguous)."""
-    return d % LANES == 0 and hkv % 8 == 0
+    contiguous) unless the cache is stored as rows already."""
+    return d % LANES == 0 and (as_rows or hkv % 8 == 0)
 
 
 def _kernel(layer_ref, n_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
             kbuf, vbuf, sems, m_scr, l_scr, acc_scr,
-            *, bk, hkv, s_len, scale):
+            *, bk, hkv, s_len, scale, align):
     slots = q_ref.shape[0]
     width = bk * hkv
     layer = layer_ref[0]
@@ -101,7 +111,7 @@ def _kernel(layer_ref, n_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
         return jnp.minimum(j * bk, s_len - bk)
 
     def copies(r, j, slot):
-        rows = pl.ds(pl.multiple_of(first_pos(j) * hkv, hkv), width)
+        rows = pl.ds(pl.multiple_of(first_pos(j) * hkv, align), width)
         return (pltpu.make_async_copy(k_hbm.at[layer, r, rows],
                                       kbuf.at[slot], sems.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[layer, r, rows],
@@ -186,32 +196,44 @@ def _head_bias(hq_pad: int, hq: int, hkv: int, bk: int) -> np.ndarray:
 
 def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
                      layer: jax.Array, lens: jax.Array, active: jax.Array,
-                     *, s_active: int, scale: float) -> jax.Array:
+                     *, s_active: int, scale: float,
+                     hkv: int = 0) -> jax.Array:
     """One query a row against layer ``layer`` of a stacked cache.
 
-    q: (B, Hq, D); ck/cv: the WHOLE (L, B, S, Hkv, D) cache, the row of
-    position ``lens`` already written; lens: (B,) int32; active: (B,)
-    bool.  Row b attends keys ``[0, min(lens[b] + 1, s_active))`` if it
-    is active and gives zeros if not.  -> (B, Hq, D) in the cache's
-    dtype.
+    q: (B, Hq, D); ck/cv: the WHOLE (L, B, S, Hkv, D) cache, or with
+    ``hkv`` its rows (L, B, S * Hkv, D), the row of position ``lens``
+    already written; lens: (B,) int32; active: (B,) bool.  Row b attends
+    keys ``[0, min(lens[b] + 1, s_active, S))`` if it is active and gives
+    zeros if not.  -> (B, Hq, D) in the cache's dtype.
 
     On a TPU a cache Mosaic cannot read as rows (kv heads that do not
     fill a sublane tile, a head that is not whole lanes) is attended by
     XLA, as ``llama._cache_attend`` over the layer's prefix."""
     B, hq, d = q.shape
-    L, _, S, hkv, _ = ck.shape
+    as_rows = ck.ndim == 4
+    if as_rows:
+        L, S = ck.shape[0], ck.shape[2] // hkv
+    else:
+        L, _, S, hkv, _ = ck.shape
     if hq % hkv:
         raise ValueError(f"Hq={hq} not a multiple of Hkv={hkv}")
+    s_active = min(s_active, S)
     n = jnp.where(active, jnp.minimum(lens + 1, s_active), 0)
     interpret = _flash._use_interpret()
-    if not interpret and not _tiles(hkv, d):
+    if not interpret and not _tiles(hkv, d, as_rows):
+        if as_rows:
+            ck, cv = (c.reshape(L, B, S, hkv, d) for c in (ck, cv))
         return _xla_decode_attention(q, ck, cv, layer, n, s_active, scale)
 
     bk = block_k(S, hkv, d, ck.dtype.itemsize)
     hq_pad = -(-hq // _HEAD_TILE) * _HEAD_TILE
     rows = (L, B, S * hkv, d)
+    # What a block's first row is a multiple of, for Mosaic to prove the
+    # copy starts on a sublane tile: with 4 kv heads the heads alone do
+    # not say so, the block and the cache's length do.
+    align = hkv * math.gcd(bk, S) if as_rows else hkv
     kernel = functools.partial(_kernel, bk=bk, hkv=hkv, s_len=S,
-                               scale=scale)
+                               scale=scale, align=align)
     whole = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     attend = pl.pallas_call(
         kernel,

@@ -148,8 +148,16 @@ def _causal_mask(s, row0, col0):
     return jnp.where(rows >= cols, s, NEG_INF)
 
 
+def _band_mask(s, row0, col0, window):
+    """``_causal_mask`` and, beside it, keys ``window`` or more before
+    their query -> NEG_INF."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where((rows >= cols) & (rows - cols < window), s, NEG_INF)
+
+
 def _causal_dispatch(compute, causal, should_run, qi, ki,
-                     block_q, block_k, strips=None):
+                     block_q, block_k, strips=None, window=None):
     """Run ``compute(rows, cols, mask)`` under pl.when over what a tile
     has to compute.  ``rows`` / ``cols`` are static ``(start, stop)``
     within the tile; ``mask`` is None or the ``(row0, col0)`` that
@@ -158,10 +166,16 @@ def _causal_dispatch(compute, causal, should_run, qi, ki,
     whole and masked, unless the kernel asks for ``strips`` (``"rows"`` or
     ``"cols"``) and the tile is square: then it is walked in
     ``_diag_strips``, nothing above them is computed, and a masked score
-    adds an exact zero either way, so the sums are the same."""
+    adds an exact zero either way, so the sums are the same.  With
+    ``window`` (the forward alone) a tile the band's older edge crosses
+    is masked whole as well."""
     whole = (0, block_q), (0, block_k)
     if causal:
         on_diag = ki * block_k + block_k - 1 > qi * block_q
+        if window is not None:
+            # the tile's last row minus its first column: the farthest
+            # any of its keys lies behind its query
+            on_diag |= qi * block_q + block_q - 1 - ki * block_k >= window
 
         @pl.when(should_run & jnp.logical_not(on_diag))
         def _below():
@@ -183,7 +197,8 @@ def _causal_dispatch(compute, causal, should_run, qi, ki,
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, block_q, block_k, nk, causal):
+                m_scr, l_scr, acc_scr, *, block_q, block_k, nk, causal,
+                window=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -201,6 +216,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     else:
         should_run = True
         last_k = nk - 1
+    if window is not None:
+        # Nor tiles whose every key is ``window`` or more behind every
+        # row.  A row that a crossed tile masks whole counts NEG_INF -
+        # NEG_INF = 0 there; the tile of its own key, which always comes
+        # after, scales that away (alpha = 0).
+        should_run &= ki * block_k + block_k - 1 > qi * block_q - window
 
     def _compute(rows, cols, mask):
         r, c = slice(*rows), slice(*cols)
@@ -209,8 +230,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         v = v_ref[0, 0, c, :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        if mask is not None:
+        if mask is not None and window is None:
             s = _causal_mask(s, *mask)
+        elif mask is not None:
+            s = _band_mask(s, *mask, window)
         m_prev = m_scr[r, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -226,7 +249,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     # of this kernel's time, so strips by rows gain nothing and strips by
     # columns (one more online-softmax step each) lose (PERF.md section 6).
     _causal_dispatch(_compute, causal, should_run, qi, ki,
-                     block_q, block_k)
+                     block_q, block_k, window=window)
 
     @pl.when(ki == last_k)
     def _finalize():
@@ -244,9 +267,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0, :, :] = lse
 
 
-def _fwd(q, k, v, *, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
+         name="flash_attention_fwd"):
     """q: (B, Hq, Sq, D) pre-scaled; k/v: (B, Hkv, Sk, D).
-    Returns o (B, Hq, Sq, D), lse (B, Hq, Sq, 1) f32."""
+    Returns o (B, Hq, Sq, D), lse (B, Hq, Sq, 1) f32.  ``window`` (causal
+    only): a query sees its last ``window`` keys, its own among them."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
     group = Hq // Hkv
@@ -258,20 +283,28 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret):
         return (b, h, qi, 0)
 
     def kv_map(b, h, qi, ki):
-        if causal:
+        if causal and window is None:
             # Skipped above-diagonal blocks: redirect the prefetch to
             # block 0 (it will be needed for the next q row).
             ki = jax.lax.select(bk * ki <= bq * qi + bq - 1, ki, 0)
+        elif causal:
+            # Skipped blocks fetch nothing new: those behind the band
+            # wait on its first block, those above the diagonal stay on
+            # the diagonal's.
+            first = jnp.maximum((bq * qi - window + 1) // bk, 0)
+            ki = jnp.clip(ki, first, (bq * qi + bq - 1) // bk)
         return (b, h // group, ki, 0)
 
     def o_map(b, h, qi, ki):
         return (b, h, qi, 0)
 
     kernel = functools.partial(_fwd_kernel, block_q=bq, block_k=bk,
-                               nk=nk, causal=causal)
+                               nk=nk, causal=causal,
+                               **({} if window is None
+                                  else {"window": window}))
     fwd = pl.pallas_call(
         kernel,
-        name="flash_attention_fwd",
+        name=name,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), q_map),
@@ -625,6 +658,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 f"<= {LANES} or a multiple of it); use "
                 f"attention_impl='dot' for this shape")
     return flash(q, k, v)
+
+
+def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                            scale: float,
+                            window: Optional[int] = None) -> jax.Array:
+    """The forward alone, for a serving prefill: causal, a query seeing
+    its last ``window`` keys where one is given (tiles outside the band
+    are neither fetched nor computed).  q: (B, S, Hq, D); k/v: (B, S,
+    Hkv, D), positions 0..S-1; ``scale`` multiplies the scores.  The
+    device trace shows the kernel under this function's name."""
+    B, S, Hq, D = q.shape
+    if Hq % k.shape[2]:
+        raise ValueError(f"Hq={Hq} not a multiple of Hkv={k.shape[2]}")
+    interpret = _use_interpret()
+    if not interpret and not _supported(S, S, D):
+        raise ValueError(f"flash_prefill_attention cannot tile S={S}, "
+                         f"D={D} on TPU")
+    qt = jnp.transpose(q, (0, 2, 1, 3)) * jnp.asarray(scale, q.dtype)
+    o, _lse = _fwd(qt, jnp.transpose(k, (0, 2, 1, 3)),
+                   jnp.transpose(v, (0, 2, 1, 3)), causal=True,
+                   block_q=None, block_k=None, interpret=interpret,
+                   window=window, name="flash_prefill_attention")
+    return jnp.transpose(o, (0, 2, 1, 3))
 
 
 def flash_attention_causal(q, k, v, positions=None,

@@ -343,24 +343,33 @@ class LLMServer:
                     "disaggregated replicas)")
         preset = getattr(llama.LlamaConfig, model_preset)
         self.cfg = preset(max_seq_len=max_len)
-        if self.cfg.layers_of("mamba"):
-            # The planes built on K/V being positional rows cannot hold a
-            # recurrent state; the dense plane (per-slot states beside
-            # K/V: llama_serve.init_cache) serves such a model.
-            asked = [what for what, on in (
-                ("paged blocks and prefix sharing (paged=True)", paged),
-                ("kv_quant", kv_quant is not None),
-                ("speculative decoding (spec_k)", self.spec_k > 0),
-                ("prefill/decode disaggregation (role)", role != "both"),
-            ) if on]
-            if asked:
-                raise ValueError(
-                    f"{model_preset} has state-space layers, whose "
-                    f"recurrent state is one array a slot, not rows by "
-                    f"position: it cannot be cut into blocks, shared by "
-                    f"prefix, rewound after a rejected draft, handed off "
-                    f"as K/V blocks or quantized as K/V rows.  Refused: "
-                    f"{'; '.join(asked)}")
+        # The planes built on K/V being one row a position for every
+        # layer hold neither a recurrent state nor a ring; the dense plane
+        # (per-slot states or rings beside K/V: llama_serve.init_cache)
+        # serves such a model.
+        asked = [what for what, on in (
+            ("paged blocks and prefix sharing (paged=True)", paged),
+            ("kv_quant", kv_quant is not None),
+            ("speculative decoding (spec_k)", self.spec_k > 0),
+            ("prefill/decode disaggregation (role)", role != "both"),
+        ) if on]
+        if asked and self.cfg.layers_of("mamba"):
+            raise ValueError(
+                f"{model_preset} has state-space layers, whose "
+                f"recurrent state is one array a slot, not rows by "
+                f"position: it cannot be cut into blocks, shared by "
+                f"prefix, rewound after a rejected draft, handed off "
+                f"as K/V blocks or quantized as K/V rows.  Refused: "
+                f"{'; '.join(asked)}")
+        if asked and self.cfg.layers_of("window"):
+            raise ValueError(
+                f"{model_preset} has window layers, whose K/V is a "
+                f"ring of the last {self.cfg.window_size} positions a "
+                f"slot beside the full layers' pool: a block table "
+                f"holds one position a row for every layer, a shared "
+                f"prefix outlives no ring, and a rejected draft's rows "
+                f"have overwritten what they would rewind to.  "
+                f"Refused: {'; '.join(asked)}")
         self.max_slots = max_slots
         self.max_len = max_len
         self.buckets = tuple(sorted(b for b in prefill_buckets
@@ -429,7 +438,14 @@ class LLMServer:
         self._state_bytes = llama_serve.state_bytes_per_slot(self.cfg)
         self._state_tags = {kind: {**self._tags, "kind": kind}
                             for kind in self._state_bytes}
-        if self._state_bytes:
+        # A model with window layers: the positions a ring holds a slot
+        # (0 for any other), and how many layers read each pool.
+        self._ring = 0
+        if self.cfg.layers_of("window") and not self.paged:
+            self._ring = llama_serve.ring_len(self.cfg, max_len)
+            self._pool_layers = (self.cfg.layers_of("attention"),
+                                 self.cfg.layers_of("window"))
+        if self._state_bytes or self._ring:
             self._publish_state_pool()
         self._jnp = jnp
         # Device-resident carries between chunk launches.
@@ -514,9 +530,11 @@ class LLMServer:
         self._np_max = max(1, (max(self.buckets) - 1) // bs)
 
     def _publish_state_pool(self) -> None:
-        """The dense cache of a model with state-space layers, by what
-        it holds: K/V under ``ray_tpu_kv_pool_bytes``, the recurrent and
-        conv states under ``ray_tpu_state_pool_bytes``."""
+        """The dense cache of a model with state-space or window layers,
+        by what it holds: K/V under ``ray_tpu_kv_pool_bytes`` (a model
+        with window layers: ``<deployment>.kv_full`` and
+        ``<deployment>.kv_window``), the recurrent and conv states under
+        ``ray_tpu_state_pool_bytes``."""
         from ray_tpu.models import llama_serve
 
         self._pools = llama_serve.cache_pools(self.cfg, self.max_slots,
@@ -526,6 +544,9 @@ class LLMServer:
             if pool == "kv":
                 self._kv_metrics["pool_bytes"].set(
                     nbytes, tags={"pool": name, "dtype": dtype})
+            elif pool.startswith("kv_"):
+                self._kv_metrics["pool_bytes"].set(
+                    nbytes, tags={"pool": f"{name}.{pool}", "dtype": dtype})
             else:
                 self._kv_metrics["state_pool_bytes"].set(
                     nbytes, tags={"pool": name, "kind": pool,
@@ -1303,7 +1324,7 @@ class LLMServer:
         t0 = time.perf_counter()
         sa = _bucket_for(min(high, self.max_len), self.decode_buckets)
         info = (len(snapshot), int(self.slot_waiting.sum()),
-                len(self._backlog), int(sa), int(pos.sum()))
+                len(self._backlog), int(sa), int(pos.sum()), 0)
         with _device.annotation("serve.spec_draft"):
             self.draft_cache, dts = self._draft_propose(
                 self.draft_params, self.draft_cache, jnp.asarray(tok),
@@ -1514,7 +1535,8 @@ class LLMServer:
         # What the chunk was launched over (serve.chunk's args).
         info = (len(snapshot), int(self.slot_waiting.sum()),
                 len(self._backlog), int(sa),
-                sum(len0 for _s, _req, len0 in snapshot))
+                sum(len0 for _s, _req, len0 in snapshot),
+                sum(min(len0, self._ring) for _s, _req, len0 in snapshot))
         return (toks, snapshot, k, t0, info, load)
 
     def _process(self, pending):
@@ -1650,7 +1672,7 @@ class LLMServer:
         if not _tracing.enabled():
             return
         computed = k * self.max_slots
-        active, waiting, backlog, s_active, attended = info
+        active, waiting, backlog, s_active, attended, ringed = info
         bucket = self.max_slots * s_active
         m = self._engine_metrics
         m["decode_tokens_kept"].inc(kept, tags=self._tags)
@@ -1663,9 +1685,27 @@ class LLMServer:
             "tokens_kept": kept, "token_steps": computed,
             "kv_positions_attended": attended,
             "kv_positions_bucket": bucket,
+            **self._window_attrs(attended, ringed, s_active),
             **self._state_attrs(k * active),
             **self._expert_attrs(load, "decode")},
             f"{self._lane}/chunks")
+
+    def _window_attrs(self, attended: int, ringed: int,
+                      s_active: int) -> Dict[str, int]:
+        """A chunk's keys by pool, for a model with window layers (nothing
+        for any other): the positions its live rows held at launch, as a
+        full layer reads them and as a ring does (``min(length, ring)``
+        each), the layers that read each pool, and each pool's attended
+        bucket a slot."""
+        if not self._ring:
+            return {}
+        full_layers, window_layers = self._pool_layers
+        return {"kv_full_positions_attended": attended,
+                "kv_window_positions_attended": ringed,
+                "kv_full_bucket": s_active,
+                "kv_window_bucket": min(s_active, self._ring),
+                "kv_full_layers": full_layers,
+                "kv_window_layers": window_layers}
 
     def _state_attrs(self, rows: int) -> Dict[str, int]:
         """A chunk's traffic in recurrent and conv state, host side, from
@@ -1703,6 +1743,12 @@ class LLMServer:
             # a Mamba layer
             scan["scan_chunks"] = rows * -(-bucket // min(
                 bucket, self.cfg.ssm_chunk))
+        if self._ring:
+            # of the bucket's score square, the share inside a window
+            # layer's band (what its attention has to compute)
+            w = min(self._ring, bucket)
+            scan["window_band_share"] = round(
+                w * (2 * bucket - w + 1) / (bucket * bucket), 4)
         self._span("serve.prefill_group", t0, t1, {
             "bucket": bucket, "rows": real, "rows_padded": rows,
             "prompt_tokens": tokens, "token_positions": computed,
@@ -1877,6 +1923,12 @@ class LLMServer:
         out = {k: v for k, v in metrics_summary().items()
                if k.startswith(("ray_tpu_kv_", "ray_tpu_prefix_",
                                 "ray_tpu_spec_", "ray_tpu_state_"))}
+        if self._ring:
+            out["kv_pools"] = {
+                pool: {"bytes": nbytes, "dtype": dtype,
+                       "bytes_per_slot": nbytes // self.max_slots}
+                for pool, (nbytes, dtype) in self._pools.items()}
+            out["kv_pools"]["kv_window"]["ring_positions"] = self._ring
         if self._state_bytes:
             out["state_pool"] = {
                 **{f"{pool}_bytes": nbytes
