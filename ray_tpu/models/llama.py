@@ -1403,30 +1403,6 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
     return last_logits, ks, vs, expert_rows, states, window
 
 
-def insert_prefill(cache: Dict[str, jax.Array], ks: jax.Array,
-                   vs: jax.Array, slots: jax.Array) -> Dict[str, jax.Array]:
-    """Insert prefilled K/V rows into slot caches without per-slot
-    scatters (XLA TPU serializes those): a one-hot slot projection
-    spreads the group onto the batch axis, then a STATIC row-range
-    select writes rows [0, P).  slots: (G,) int32; a negative slot
-    drops that group member (partial-group padding)."""
-    B = cache["k"].shape[1]
-    P = ks.shape[2]
-    onehot = (slots[:, None] ==
-              jnp.arange(B, dtype=jnp.int32)[None, :])
-    proj = onehot.astype(cache["k"].dtype)
-    written = onehot.any(axis=0)[None, :, None, None, None]
-
-    def ins(full, rows):
-        spread = jnp.einsum("gb,lgphd->lbphd", proj,
-                            rows.astype(full.dtype))
-        cur = jax.lax.slice_in_dim(full, 0, P, axis=2)
-        new = jnp.where(written, spread, cur)
-        return jax.lax.dynamic_update_slice_in_dim(full, new, 0, axis=2)
-
-    return {"k": ins(cache["k"], ks), "v": ins(cache["v"], vs)}
-
-
 def _cache_attend(q, ck, cv, q_positions, scale, key_positions=None,
                   key_valid=None):
     """q: (B, T, Hq, D); ck/cv: (B, S, Hkv, D); q_positions: (B, T).

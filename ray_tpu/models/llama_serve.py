@@ -309,18 +309,30 @@ def _uncarry(carry, cache):
 
 # ------------------------------------------------------------- dense plane
 def _insert_rows(pool, new, slots):
-    """A prefill group's rows into a pool of rows, from each slot's first
-    position on.  pool ``(layers, B, positions * Hkv, D)``; new ``(layers,
-    G, P, Hkv, D)``, ``P`` at most the pool's positions; slots (G,), a
-    negative one drops its row.  A slot's rows are written where they
-    lie (``insert_prefill`` copies every slot's first ``P`` positions)."""
-    layers, G, P, hkv, d = new.shape
-    new = new.reshape(layers, G, 1, P * hkv, d).astype(pool.dtype)
+    """A prefill group's rows into a pool, from each slot's first position
+    on, every row written where it lies.  pool ``(layers, B, positions,
+    Hkv, D)`` or its rows ``(layers, B, positions * Hkv, D)``; new
+    ``(layers, G, P, Hkv, D)``, ``P`` at most the pool's positions; slots
+    (G,), a negative one (the padding of a rung) writes nothing.  Per
+    member one slot's first ``P`` positions are read, selected against
+    the slot's sign and written back: nothing of the pool's shape, nor of
+    ``(layers, B, P, ...)``, is made whatever ``G`` is, which is what a
+    dense engine's slot count rests on (PERF.md section 4;
+    ``tests/test_prefill_inplace.py`` holds it at the real widths).  The
+    pool is never reshaped: its two layouts tile differently on the chip,
+    and a view of one as the other copies the whole pool in and out."""
+    layers, G = new.shape[:2]
+    # the group as the pool is stored: by position, or as rows
+    new = new.reshape((layers, G, -1) + pool.shape[3:]).astype(pool.dtype)
+    one_slot = (layers, 1) + new.shape[2:]
     for g in range(G):
-        at = (0, jnp.maximum(slots[g], 0), 0, 0)
-        held = jax.lax.dynamic_slice(pool, at, new[:, g].shape)
+        at = (0, jnp.maximum(slots[g], 0)) + (0,) * (pool.ndim - 2)
+        held = jax.lax.dynamic_slice(pool, at, one_slot)
+        # A member is cut out as a slice: indexed out of ``(layers, G, 1,
+        # ...)`` the v5e compiler lays a 5-D K out slot-major and copies
+        # the whole pool into that layout and back (AOT, PR 33).
         pool = jax.lax.dynamic_update_slice(
-            pool, jnp.where(slots[g] >= 0, new[:, g], held), at)
+            pool, jnp.where(slots[g] >= 0, new[:, g:g + 1], held), at)
     return pool
 
 
@@ -353,7 +365,8 @@ def build_prefill(cfg: LlamaConfig) -> Callable:
                 "wv": _insert_rows(
                     cache["wv"], _ring_rows(window[1], lengths, ring), slots)}
         else:
-            cache = {**cache, **llama.insert_prefill(cache, ks, vs, slots)}
+            cache = {**cache, "k": _insert_rows(cache["k"], ks, slots),
+                     "v": _insert_rows(cache["v"], vs, slots)}
         if states is not None:
             cache = insert_states(cache, states, slots)
         first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
@@ -582,8 +595,7 @@ def build_spec_verify(blocks: BlockPool) -> Callable:
         def kv_step(q, kk, vv, positions, cache_l):
             ck_l, cv_l = cache_l
             # One-hot projection places the T fresh rows at their
-            # absolute positions (like insert_prefill, scatters
-            # would serialize on TPU).
+            # absolute positions (scatters would serialize on TPU).
             up_k = jnp.einsum("bts,bthd->bshd", proj, kk)
             up_v = jnp.einsum("bts,bthd->bshd", proj, vv)
             ck_l = jnp.where(written, up_k.astype(ck_l.dtype),
@@ -625,7 +637,8 @@ def build_draft_prefill(dcfg: LlamaConfig) -> Callable:
     def draft_prefill(params, cache, tokens, lengths, slots):
         _logits, ks, vs = llama.prefill_forward(params, tokens,
                                                 lengths, dcfg)
-        return llama.insert_prefill(cache, ks, vs, slots)
+        return {"k": _insert_rows(cache["k"], ks, slots),
+                "v": _insert_rows(cache["v"], vs, slots)}
 
     return jax.jit(draft_prefill, donate_argnums=(1,))
 

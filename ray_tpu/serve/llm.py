@@ -105,31 +105,22 @@ from ..observability import tracing as _tracing
 # launches are asynchronous (the device is never idle between them: PERF.md
 # section 5), so what a wave costs is the positions it computes plus what
 # every launch pays whatever its size; ``cut_prefill_wave`` picks the
-# groups that make that least.  Measured on a v5e (PERF.md section 6,
-# PR 27), which is why the ladder is not every power of two: a program
-# costs ~0.45 s of every engine start, a 2-row program takes 0.65-0.95 of
-# the 4-row one's time (``insert_prefill`` copies the cache's first
-# ``bucket`` positions whenever a group has two rows or more; a 1-row
-# group is written in place), and nothing above 8 rows saved device time
-# on any cell's waves.
+# groups that make that least.  The ladder is not every power of two: a
+# program costs ~0.45 s of every engine start and nothing above 8 rows
+# saved device time on any cell's waves; it was fitted on a v5e (PR 27)
+# with the old insert's copy of the cache inside every multi-row launch
+# and is due a refit now that the copy is gone (PERF.md section 7).
 PREFILL_GROUPS = (1, 4, 8)
 
-# The most positions a group of more than one row computes.  From about
-# here up a launch is compute-bound (8 x 256: 23 us a position; 8 x 1,024:
-# 27), so a wider group saves only its share of a launch, while its
-# program's scratch -- that copy of the cache -- is what bounds a dense
-# engine's slot count (PERF.md section 4).
+# The most positions a group of more than one row computes: from about
+# here up a launch is compute-bound, so a wider group saves only its share
+# of a launch; fitted with the copy inside, refit in PERF.md section 7.
 _GROUP_POSITIONS = 2048
 
-# What one prefill launch costs whatever its size, in positions: a pass
-# over the weights and, from two rows up, ``insert_prefill``'s pass over
-# the cache.  Measured on a v5e (PERF.md section 6, PR 27): the warmed
-# programs of the three serve cells, timed alone, fit launch + positions
-# x 25-28 us with a launch of 178 (40 slots x 1,280), 362 (120 slots x
-# 512) and 725 positions (the same cache under 7 GB of experts).
-# Replaying the cells' waves against those measured times, any constant
-# from 150 to 600 came within 7% of the cut that knows every program's
-# time; 300 is the least bad for the three together.
+# What one prefill launch costs whatever its size, in positions (a pass
+# over the weights): any constant from 150 to 600 cut the cells' waves
+# within 7% of the best; fitted with the copy inside, refit in PERF.md
+# section 7.
 _LAUNCH_POSITIONS = 300
 
 # How aggressively the feasibility shed fires: a request is shed when

@@ -471,36 +471,57 @@ def test_spans_counters_and_pools_for_a_windowed_model_and_only_for_one(
 # and ``decode_k`` at toy widths (kernels interpreted) of a plain, a
 # grouped-query untied, two expert and two hybrid configurations, as the
 # commit before the window kind (888cb52, PR 31) lowered them, by sha256.
-# A later PR that means to change a program records its new text here.
+# A later PR that means to change a program records its new text here:
+# PR 33 did for the six ``prefill`` programs (a group's rows written where
+# they lie, ``llama_serve._insert_rows``; the ``decode_k`` texts are
+# PR 31's still) and for the windowed one below.
 _BEFORE = {
     "dense": (LlamaConfig.debug,
-              {}, "d5ada6ecb64348c2", "451d4209906be1e0"),
+              {}, "9cea23cd675cc71a", "451d4209906be1e0"),
     "dense_untied_gqa": (LlamaConfig.debug,
                          dict(tie_embeddings=False, n_kv_heads=1),
-                         "13984c3cd55668e7", "836058ef106088be"),
+                         "5b383136284f778a", "836058ef106088be"),
     "moe": (LlamaConfig.moe_debug, {},
-            "f35f5c6e9baefcc8", "488ee7a67f842d7e"),
+            "a6acd292520feb78", "488ee7a67f842d7e"),
     "olmoe_like": (LlamaConfig.moe_debug,
                    dict(moe_norm_topk=False, qk_norm=True, moe_top_k=3),
-                   "90839820bc4376c4", "18c2dd9cf37184c1"),
+                   "75aefc941011707b", "18c2dd9cf37184c1"),
     "hybrid": (LlamaConfig.hybrid_debug, {},
-               "4e21c5db5bd745b2", "a0cebe2ffa1301dd"),
+               "8714e32043223842", "a0cebe2ffa1301dd"),
     "hybrid_f32_stream": (LlamaConfig.hybrid_debug,
                           dict(stream_dtype="float32"),
-                          "d347ac68937879a6", "3f29528740f257e6"),
+                          "a7acc3dfa685fa99", "3f29528740f257e6"),
 }
 
 
-@pytest.mark.parametrize("name", sorted(_BEFORE))
-def test_the_configurations_before_build_and_lower_what_they_did(name):
-    preset, kw, prefill_sha, decode_sha = _BEFORE[name]
-    cfg = preset(**kw)
+def _abstract(cfg):
+    """The parameter and cache trees of a config as shapes (4 slots x 64),
+    for ``.lower``."""
     shapes = lambda tree: jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
     params = shapes(jax.eval_shape(
         lambda k: llama.init_params(k, cfg, cfg.dtype), jax.random.key(0)))
     cache = shapes(jax.eval_shape(
         lambda: llama_serve.init_cache(cfg, 4, 64)))
+    return params, cache
+
+
+def _lowered_prefill(cfg, params, cache, rows):
+    group = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    return llama_serve.build_prefill(cfg).lower(
+        params, cache, jax.ShapeDtypeStruct((rows, 16), jnp.int32), group,
+        group).as_text()
+
+
+def _sha(text):
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", sorted(_BEFORE))
+def test_the_configurations_before_build_and_lower_what_they_did(name):
+    preset, kw, prefill_sha, decode_sha = _BEFORE[name]
+    cfg = preset(**kw)
+    params, cache = _abstract(cfg)
     kv = (cfg.layers_of("attention"), 4, 64, cfg.n_kv_heads, cfg.head_dim)
     assert cache["k"].shape == cache["v"].shape == kv
     assert set(cache) == ({"k", "v", "ssm", "conv"}
@@ -508,12 +529,24 @@ def test_the_configurations_before_build_and_lower_what_they_did(name):
     assert params["layers"]["wq"].shape[0] == cfg.layers_of("attention")
     ints = jax.ShapeDtypeStruct((4,), jnp.int32)
     bools = jax.ShapeDtypeStruct((4,), jnp.bool_)
-    group = jax.ShapeDtypeStruct((2,), jnp.int32)
-    prefill = llama_serve.build_prefill(cfg).lower(
-        params, cache, jax.ShapeDtypeStruct((2, 16), jnp.int32), group,
-        group).as_text()
+    prefill = _lowered_prefill(cfg, params, cache, rows=2)
     decode = llama_serve.build_decode_k(cfg).lower(
         params, cache, ints, ints, ints, ints, bools, bools, k=4,
         s_active=32).as_text()
-    sha = lambda text: hashlib.sha256(text.encode()).hexdigest()[:16]
-    assert (sha(prefill), sha(decode)) == (prefill_sha, decode_sha)
+    assert (_sha(prefill), _sha(decode)) == (prefill_sha, decode_sha)
+
+
+@pytest.mark.parametrize("rows,prefill_sha", [(1, "d23f00925e2d73ab"),
+                                              (2, "7036e14381dfafce")])
+def test_the_windowed_prefills_lowered_text_is_recorded(rows, prefill_sha):
+    """The insert is one function with one shape rule for both layouts of
+    a pool since PR 33: a member is cut out of the group as a slice.  A
+    model with window layers, whose pools are stored as rows, lowered a
+    member indexed out of ``(layers, G, 1, rows, D)`` at the commit before
+    (d9c05df, PR 32: 472bf6d68c59c0a3 / 98bd8d52841b61c8); the text
+    recorded here differs from that in the insert's reshape, slice and
+    select (and the numbering of what follows them), and at cell 7's real
+    widths both compile for a v5e to the same optimised HLO, instruction
+    for instruction (AOT, PR 33: PERF.md section 6)."""
+    cfg = _cfg()
+    assert _sha(_lowered_prefill(cfg, *_abstract(cfg), rows)) == prefill_sha
