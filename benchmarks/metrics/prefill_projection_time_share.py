@@ -1,0 +1,7 @@
+"""Own device time of the ops under the projection scopes (``qkv_proj``,
+``attn_out``, ``ssm_proj``, ``ssm_out``) / device time of the prefill programs.
+"""
+
+from benchmarks.lib import scope_names
+
+read = scope_names.time_share("prefill", "projection")

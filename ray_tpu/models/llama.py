@@ -573,6 +573,7 @@ def _get_attention_fn(config) -> Callable:
     raise ValueError(f"unknown attention_impl {impl!r}")
 
 
+@jax.named_scope("qkv_proj")
 def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
               config: LlamaConfig, kind: str = "attention"):
     """Shared by the training forward and the KV-cache decode path —
@@ -637,11 +638,14 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
     layer's input: what a router placed before attention reads."""
     B, S, _ = x.shape
     route_x = x if config.moe_router_input == "layer" else None
-    x = residual_add(x, matmul(attn.reshape(B, S, config.q_dim),
-                               layer["wo"].astype(config.dtype)), config)
+    with jax.named_scope("attn_out"):
+        x = residual_add(x, matmul(attn.reshape(B, S, config.q_dim),
+                                   layer["wo"].astype(config.dtype)),
+                         config)
     return ffn_half(x, layer, config, valid, layer_index, route_x)
 
 
+@jax.named_scope("ffn")
 def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
              config: LlamaConfig, valid: Optional[jax.Array] = None,
              layer_index: Optional[jax.Array] = None,
@@ -656,7 +660,11 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     for a dense config.  ``valid`` (broadcastable to (B, S)) marks the
     rows that are real; experts compute no others.  With ``layer_index``
     the expert matrices in ``layer`` are the whole ``[L, E, ...]`` stacks
-    (``split_expert_stacks``), read in place."""
+    (``split_expert_stacks``), read in place.
+
+    All of it is scope ``ffn``; an expert layer's ``router``,
+    ``expert_dispatch`` and ``expert_ffn`` (``models/moe.py``) lie inside
+    it, and the innermost scope is an op's own."""
     c = config
     dt = c.dtype
     x = with_logical_constraint(x, "batch", "seq", None)
@@ -714,6 +722,7 @@ def lm_head(params: PyTree, config: LlamaConfig) -> jax.Array:
     return params["lm_head"].astype(config.dtype)
 
 
+@jax.named_scope("head")
 def head_logits(x: jax.Array, params: PyTree,
                 config: LlamaConfig) -> jax.Array:
     """Logits of normed hidden states, divided by ``logits_scaling``."""
@@ -723,6 +732,7 @@ def head_logits(x: jax.Array, params: PyTree,
     return logits
 
 
+@jax.named_scope("embed")
 def embed(params: PyTree, tokens: jax.Array,
           config: LlamaConfig) -> jax.Array:
     """Token embeddings times ``embedding_multiplier``, in the type the
@@ -888,11 +898,13 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     # gather's output inherits the table's D-sharding and the SPMD
     # partitioner falls into "involuntary full rematerialization"
     # resharding it to (batch, seq) (observed in the 8-way dryrun).
-    emb = with_logical_constraint(
-        params["embed_tokens"].astype(c.dtype), "vocab", None)
-    x = emb[tokens]
-    x = with_logical_constraint(x, "batch", "seq", None)
-    sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
+    with jax.named_scope("embed"):
+        emb = with_logical_constraint(
+            params["embed_tokens"].astype(c.dtype), "vocab", None)
+        x = emb[tokens]
+        x = with_logical_constraint(x, "batch", "seq", None)
+    with jax.named_scope("qkv_proj"):
+        sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
 
     moe = c.moe_experts > 0
 
@@ -944,9 +956,13 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             h, aux_l = block(h, layer_params)
             return (h, aux + aux_l), None
 
-        (x, aux_total), _ = jax.lax.scan(
-            scan_body, (x, aux_total), params["layers"],
-            unroll=c.scan_unroll)
+        # ``layer_scan``: the loop's own slicing of a layer's weights and
+        # stacking of what a layer saves; an op inside a block's scope
+        # keeps that one, the innermost.
+        with jax.named_scope("layer_scan"):
+            (x, aux_total), _ = jax.lax.scan(
+                scan_body, (x, aux_total), params["layers"],
+                unroll=c.scan_unroll)
 
     with jax.named_scope("head_loss"):
         x = rms_norm(x, params["final_norm"], c.norm_eps)
@@ -987,12 +1003,12 @@ def loss_fn(params: PyTree, batch: Dict[str, jax.Array],
         gold = jnp.take_along_axis(logits, targets[..., None],
                                    axis=-1).squeeze(-1)
         nll = logz - gold
-    mask = batch.get("loss_mask")
-    if mask is None:
-        ce = jnp.mean(nll)
-    else:
-        mask = mask[:, 1:].astype(jnp.float32)
-        ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        mask = batch.get("loss_mask")
+        if mask is None:
+            ce = jnp.mean(nll)
+        else:
+            mask = mask[:, 1:].astype(jnp.float32)
+            ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     if config.moe_experts > 0:
         # Per-layer mean so the weight is depth-invariant.
         ce = ce + config.moe_aux_weight * aux / config.n_layers
@@ -1122,7 +1138,8 @@ def make_train_step(config: LlamaConfig, optimizer=None,
             params = optax.apply_updates(state["params"], updates)
         new_state = {"params": params, "opt_state": opt_state,
                      "step": state["step"] + 1}
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            gnorm = optax.global_norm(grads)
         return new_state, {"loss": loss, "grad_norm": gnorm,
                            "step": new_state["step"]}
 
@@ -1138,16 +1155,27 @@ class _AnnotatedStep:
     cluster timeline.  No-op cost when the device plane is disabled
     (shared nullcontext); everything else of the jitted program's
     surface (``lower``/``trace``/donation semantics) passes through
-    untouched via delegation."""
+    untouched via delegation.  With tracing enabled the first dispatch
+    also registers the program with the shapes of that call
+    (``device.register_program``: what ``device.program_scopes`` later
+    reads the step's instruction -> scope map from)."""
 
-    __slots__ = ("_jitted",)
+    __slots__ = ("_jitted", "_registered")
 
     def __init__(self, jitted: Callable):
         self._jitted = jitted
+        self._registered = False
 
     def __call__(self, state, batch):
         from ray_tpu.observability import device as _device
 
+        if not self._registered:
+            self._registered = True
+            from ray_tpu.observability import tracing as _tracing
+
+            if _tracing.enabled():
+                _device.register_program("train.step", self._jitted,
+                                         (state, batch))
         with _device.annotation("train.step"):
             return self._jitted(state, batch)
 
@@ -1286,7 +1314,8 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
             tokens.shape)
-    sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
+    with jax.named_scope("qkv_proj"):
+        sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
     if valid is None and lengths is not None:
         valid = positions < lengths[:, None]
     sliced, stacks = split_expert_stacks(params["layers"], c)
@@ -1300,43 +1329,52 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             layer = {**layer, **stacks}
             if kind in ATTENDING_KINDS:
                 q, k, v = _qkv_rope(x, layer, sin, cos, c, kind)
-                if kind == "window":
-                    attn, ys = window_step(q, k, v, positions)
-                    win_ys.append(ys)
-                else:
-                    attn, ys = kv_step(q, k, v, positions,
-                                       layer_of(kv_period, i, c))
-                    kv_ys.append(ys)
+                # what a step writes of K/V it scopes ``kv_write`` itself
+                with jax.named_scope("attention"):
+                    if kind == "window":
+                        attn, ys = window_step(q, k, v, positions)
+                        win_ys.append(ys)
+                    else:
+                        attn, ys = kv_step(q, k, v, positions,
+                                           layer_of(kv_period, i, c))
+                        kv_ys.append(ys)
                 x, _aux, rows_j = attn_out_ffn(
                     x, attn, layer, c, valid=valid,
                     layer_index=layer_index(p, plen, j))
             else:
                 from ray_tpu.models import mamba2
 
-                out, ys = mamba2.prefill(
-                    rms_norm(x, layer["attn_norm"], c.norm_eps).astype(
-                        c.dtype), layer, c, lengths)
+                with jax.named_scope("ssm_proj"):
+                    h = rms_norm(x, layer["attn_norm"],
+                                 c.norm_eps).astype(c.dtype)
+                out, ys = mamba2.prefill(h, layer, c, lengths)
                 ssm_ys.append(ys)
+                with jax.named_scope("ssm_out"):
+                    x = residual_add(x, out, c)
                 x, _aux, rows_j = ffn_half(
-                    residual_add(x, out, c), layer, c, valid=valid,
+                    x, layer, c, valid=valid,
                     layer_index=layer_index(p, plen, j))
             rows.append(rows_j)
         return x, (stack_period(kv_ys, c), stack_period(rows, c),
                    stack_period(ssm_ys, c), stack_period(win_ys, c))
 
-    x, stacked = jax.lax.scan(
-        body, x,
-        (scanned_layers(sliced, c),
-         jnp.arange(c.n_layers // plen, dtype=jnp.int32),
-         by_period(kv_layers, c)))
-    ys, expert_rows, ssm_ys, win_ys = merge_periods(stacked, c)
-    x = rms_norm(x, params["final_norm"], c.norm_eps).astype(c.dtype)
-    if lengths is None:
-        return head_logits(x, params, c), ys, expert_rows, ssm_ys, win_ys
-    last = jnp.take_along_axis(
-        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)  # (B,1,H)
-    return (head_logits(last, params, c)[:, 0], ys, expert_rows, ssm_ys,
-            win_ys)
+    with jax.named_scope("layer_scan"):
+        x, stacked = jax.lax.scan(
+            body, x,
+            (scanned_layers(sliced, c),
+             jnp.arange(c.n_layers // plen, dtype=jnp.int32),
+             by_period(kv_layers, c)))
+        ys, expert_rows, ssm_ys, win_ys = merge_periods(stacked, c)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], c.norm_eps).astype(c.dtype)
+        if lengths is None:
+            return (head_logits(x, params, c), ys, expert_rows, ssm_ys,
+                    win_ys)
+        last = jnp.take_along_axis(
+            x, jnp.maximum(lengths - 1, 0)[:, None, None],
+            axis=1)                                          # (B,1,H)
+        return (head_logits(last, params, c)[:, 0], ys, expert_rows,
+                ssm_ys, win_ys)
 
 
 def prefill_forward(params: PyTree, tokens: jax.Array,
@@ -1403,6 +1441,7 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
     return last_logits, ks, vs, expert_rows, states, window
 
 
+@jax.named_scope("attention")
 def _cache_attend(q, ck, cv, q_positions, scale, key_positions=None,
                   key_valid=None):
     """q: (B, T, Hq, D); ck/cv: (B, S, Hkv, D); q_positions: (B, T).
@@ -1460,8 +1499,11 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
 
     def kv_step(q, k, v, positions, cache_l):
         ck_l, cv_l = cache_l
-        ck_l = jax.vmap(write)(ck_l, k.astype(ck_l.dtype), positions[:, 0])
-        cv_l = jax.vmap(write)(cv_l, v.astype(cv_l.dtype), positions[:, 0])
+        with jax.named_scope("kv_write"):
+            ck_l = jax.vmap(write)(ck_l, k.astype(ck_l.dtype),
+                                   positions[:, 0])
+            cv_l = jax.vmap(write)(cv_l, v.astype(cv_l.dtype),
+                                   positions[:, 0])
         return (_cache_attend(q, ck_l, cv_l, positions, scale),
                 (ck_l, cv_l))
 

@@ -39,6 +39,7 @@ from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.decode_attention import decode_attention
 
 
+@jax.named_scope("expert_dispatch")
 def _expert_load(expert_rows):
     """What a device program hands back about its experts, read at the
     harvest that exists: ``expert_rows`` (..., L, E) int32, the rows each
@@ -121,6 +122,7 @@ def state_bytes_per_slot(cfg: LlamaConfig):
             cache_pools(cfg, 1, 1).items() if not pool.startswith("kv")}
 
 
+@jax.named_scope("kv_write")
 def insert_states(cache, states, slots):
     """A prefill group's final states into its slots, wholesale (a reused
     slot inherits nothing of the request before).  states: ``(recurrent
@@ -191,8 +193,9 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     def step(carry, _):
         ck, cv, tok, lens, *state = carry
         x = llama.embed(params, tok, cfg)[:, None]
-        sin, cos = llama.rope_table(lens[:, None], cfg.head_dim,
-                                    cfg.rope_theta)
+        with jax.named_scope("qkv_proj"):
+            sin, cos = llama.rope_table(lens[:, None], cfg.head_dim,
+                                        cfg.rope_theta)
         # Inactive slots MUST not write: a just-admitted slot's
         # prefill may already have landed (it sits out this
         # chunk awaiting its first token) and a stale-position
@@ -200,14 +203,16 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         # past the attended prefix.  Their row goes out of range
         # and the scatter drops it.  (Nor may an inactive slot's
         # recurrent state advance: mamba2.decode.)
-        rows = jnp.arange(tok.shape[0], dtype=jnp.int32)
-        pos = jnp.where(active & (lens < s_active), lens,
-                        ck.shape[2])
-        scale = cfg.attn_scale
-        at = {"attention": pos}           # out of range past any row
-        if n_win:
-            ring = state[0].shape[2] // hkv
-            at["window"] = jnp.where(pos < ck.shape[2], lens % ring, ring)
+        with jax.named_scope("kv_write"):
+            rows = jnp.arange(tok.shape[0], dtype=jnp.int32)
+            pos = jnp.where(active & (lens < s_active), lens,
+                            ck.shape[2])
+            scale = cfg.attn_scale
+            at = {"attention": pos}       # out of range past any row
+            if n_win:
+                ring = state[0].shape[2] // hkv
+                at["window"] = jnp.where(pos < ck.shape[2], lens % ring,
+                                         ring)
 
         def body(carry, period_and_index):
             x, ck, cv, *state = carry
@@ -230,9 +235,11 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                     # The kernel reads the carry where it lies, each row
                     # as far as it is long; an inactive row's zeros are
                     # discarded below.
-                    attn = decode_attention(
-                        q[:, 0], pk, pv, l, lens, active,
-                        s_active=s_active, scale=scale, hkv=hkv)[:, None]
+                    with jax.named_scope("attention"):
+                        attn = decode_attention(
+                            q[:, 0], pk, pv, l, lens, active,
+                            s_active=s_active, scale=scale,
+                            hkv=hkv)[:, None]
                     if ringed:
                         state = [pk, pv]
                     else:
@@ -243,35 +250,41 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                 else:
                     from ray_tpu.models import mamba2
 
+                    with jax.named_scope("ssm_proj"):
+                        h = llama.rms_norm(x, layer["attn_norm"],
+                                           cfg.norm_eps).astype(cfg.dtype)
                     out, *state = mamba2.decode(
-                        llama.rms_norm(x, layer["attn_norm"],
-                                       cfg.norm_eps).astype(cfg.dtype),
-                        layer, cfg, *state, llama.layer_index(p, n_ssm, i),
-                        active)
+                        h, layer, cfg, *state,
+                        llama.layer_index(p, n_ssm, i), active)
+                    with jax.named_scope("ssm_out"):
+                        x = llama.residual_add(x, out, cfg)
                     x, _aux, rows_j = llama.ffn_half(
-                        llama.residual_add(x, out, cfg), layer, cfg,
-                        valid=active[:, None],
+                        x, layer, cfg, valid=active[:, None],
                         layer_index=llama.layer_index(p, plen, j))
                 expert_rows.append(rows_j)
             return (x, ck, cv, *state), llama.stack_period(expert_rows,
                                                            cfg)
 
-        (x, ck, cv, *state), expert_rows = jax.lax.scan(
-            body, (x, ck, cv, *state),
-            (llama.scanned_layers(sliced, cfg),
-             jnp.arange(cfg.n_layers // plen, dtype=jnp.int32)))
-        expert_rows = llama.merge_periods(expert_rows, cfg)
-        x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps).astype(
-            cfg.dtype)
-        logits = llama.head_logits(x, params, cfg)[:, 0]
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(active, nxt, tok)
-        lens = lens + active.astype(jnp.int32)
+        with jax.named_scope("layer_scan"):
+            (x, ck, cv, *state), expert_rows = jax.lax.scan(
+                body, (x, ck, cv, *state),
+                (llama.scanned_layers(sliced, cfg),
+                 jnp.arange(cfg.n_layers // plen, dtype=jnp.int32)))
+            expert_rows = llama.merge_periods(expert_rows, cfg)
+        with jax.named_scope("head"):
+            x = llama.rms_norm(x, params["final_norm"],
+                               cfg.norm_eps).astype(cfg.dtype)
+            logits = llama.head_logits(x, params, cfg)[:, 0]
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            nxt = jnp.where(active, nxt, tok)
+            lens = lens + active.astype(jnp.int32)
         return (ck, cv, nxt, lens, *state), (nxt, expert_rows)
 
     return step
 
 
+@jax.named_scope("kv_write")
 def _write(pool, l, slots, pos, new):
     """``new`` (B, Hkv, D) as slot ``slots[b]``'s position ``pos[b]`` of
     layer ``l`` of a pool ``(layers, B, positions, Hkv, D)`` or of its
@@ -308,6 +321,7 @@ def _uncarry(carry, cache):
 
 
 # ------------------------------------------------------------- dense plane
+@jax.named_scope("kv_write")
 def _insert_rows(pool, new, slots):
     """A prefill group's rows into a pool, from each slot's first position
     on, every row written where it lies.  pool ``(layers, B, positions,
@@ -336,6 +350,7 @@ def _insert_rows(pool, new, slots):
     return pool
 
 
+@jax.named_scope("kv_write")
 def _ring_rows(rows, lengths, ring: int):
     """What a ring of ``ring`` positions holds once a prompt is in: of
     rows ``(layers, G, P, Hkv, D)`` by position, per ring row r the LAST
@@ -369,7 +384,8 @@ def build_prefill(cfg: LlamaConfig) -> Callable:
                      "v": _insert_rows(cache["v"], vs, slots)}
         if states is not None:
             cache = insert_states(cache, states, slots)
-        first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
         return cache, first, _expert_load(rows)
 
     return jax.jit(prefill, donate_argnums=(1,))
@@ -378,11 +394,14 @@ def build_prefill(cfg: LlamaConfig) -> Callable:
 def build_decode_k(cfg: LlamaConfig) -> Callable:
     def decode_k(params, cache, tok_dev, len_dev,
                  ov_tok, ov_len, ov_mask, active, k, s_active):
-        tok = jnp.where(ov_mask, ov_tok, tok_dev)
-        lens = jnp.where(ov_mask, ov_len, len_dev)
-        step = decode_step(cfg, params, s_active, active)
-        carry, (toks, rows) = jax.lax.scan(
-            step, _carry(cache, tok, lens), None, length=k)
+        # ``sample``: which token and length a slot goes on from, and the
+        # token loop's own collecting of the k steps' tokens
+        with jax.named_scope("sample"):
+            tok = jnp.where(ov_mask, ov_tok, tok_dev)
+            lens = jnp.where(ov_mask, ov_len, len_dev)
+            step = decode_step(cfg, params, s_active, active)
+            carry, (toks, rows) = jax.lax.scan(
+                step, _carry(cache, tok, lens), None, length=k)
         cache, tok, lens = _uncarry(carry, cache)
         return cache, toks, tok, lens, _expert_load(rows)
 
@@ -440,11 +459,13 @@ class BlockPool:
                 sc, mode="drop"),
         }
 
+    @jax.named_scope("kv_write")
     def store(self, pool, flat, kb, vb):
         """The pool with K and V blocks ``flat`` replaced."""
         return {**pool, **self.set_blocks(pool, "k", flat, kb),
                 **self.set_blocks(pool, "v", flat, vb)}
 
+    @jax.named_scope("kv_write")
     def scatter(self, pool, bt, ck, cv):
         """K/V in the gathered layout (``gather``'s; a prefill's rows
         (L, G, P, Hkv, D) are that layout with the last block to pad
@@ -490,7 +511,8 @@ def build_prefill_cold(blocks: BlockPool) -> Callable:
         last_logits, ks, vs, rows = llama.prefill_forward(
             params, tokens, lengths, cfg, return_expert_rows=True)
         pool = blocks.scatter(pool, write_bt, ks, vs)
-        first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
         return pool, first, _expert_load(rows)
 
     return jax.jit(prefill_cold, donate_argnums=(1,))
@@ -532,7 +554,8 @@ def build_prefill_warm(blocks: BlockPool) -> Callable:
             kv_layers=(blocks.gather(pool, "k", prefix_bt),
                        blocks.gather(pool, "v", prefix_bt)),
             valid=suffix < lengths[:, None], lengths=lengths)
-        first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
         pool = blocks.scatter(pool, write_bt, ks, vs)
         return pool, first, _expert_load(rows)
 
@@ -544,13 +567,15 @@ def build_decode_paged(blocks: BlockPool) -> Callable:
 
     def decode_paged(params, pool, tok_dev, len_dev, ov_tok,
                      ov_len, ov_mask, active, bt, k):
-        tok = jnp.where(ov_mask, ov_tok, tok_dev)
-        lens = jnp.where(ov_mask, ov_len, len_dev)
+        with jax.named_scope("sample"):
+            tok = jnp.where(ov_mask, ov_tok, tok_dev)
+            lens = jnp.where(ov_mask, ov_len, len_dev)
         ck = blocks.gather(pool, "k", bt)
         cv = blocks.gather(pool, "v", bt)
         step = decode_step(cfg, params, bt.shape[1] * blocks.bs, active)
-        (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
-            step, (ck, cv, tok, lens), None, length=k)
+        with jax.named_scope("sample"):
+            (ck, cv, tok, lens), (toks, rows) = jax.lax.scan(
+                step, (ck, cv, tok, lens), None, length=k)
         pool = blocks.scatter(pool, bt, ck, cv)
         return pool, toks, tok, lens, _expert_load(rows)
 
@@ -596,12 +621,13 @@ def build_spec_verify(blocks: BlockPool) -> Callable:
             ck_l, cv_l = cache_l
             # One-hot projection places the T fresh rows at their
             # absolute positions (scatters would serialize on TPU).
-            up_k = jnp.einsum("bts,bthd->bshd", proj, kk)
-            up_v = jnp.einsum("bts,bthd->bshd", proj, vv)
-            ck_l = jnp.where(written, up_k.astype(ck_l.dtype),
-                             ck_l)
-            cv_l = jnp.where(written, up_v.astype(cv_l.dtype),
-                             cv_l)
+            with jax.named_scope("kv_write"):
+                up_k = jnp.einsum("bts,bthd->bshd", proj, kk)
+                up_v = jnp.einsum("bts,bthd->bshd", proj, vv)
+                ck_l = jnp.where(written, up_k.astype(ck_l.dtype),
+                                 ck_l)
+                cv_l = jnp.where(written, up_v.astype(cv_l.dtype),
+                                 cv_l)
             attn = llama._cache_attend(q, ck_l, cv_l, positions,
                                        scale)
             return attn, (ck_l, cv_l)
@@ -611,7 +637,8 @@ def build_spec_verify(blocks: BlockPool) -> Callable:
             kv_layers=(blocks.gather(pool, "k", bt),
                        blocks.gather(pool, "v", bt)),
             valid=active[:, None])
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return blocks.scatter(pool, bt, ck, cv), toks
 
     return jax.jit(spec_verify, donate_argnums=(1,))
@@ -646,9 +673,10 @@ def build_draft_prefill(dcfg: LlamaConfig) -> Callable:
 def build_draft_propose(dcfg: LlamaConfig) -> Callable:
     def draft_propose(params, cache, tok, pos, active, k, s_active):
         step = decode_step(dcfg, params, s_active, active)
-        (ck, cv, tok, pos), (toks, _rows) = jax.lax.scan(
-            step, (cache["k"], cache["v"], tok, pos), None,
-            length=k)
+        with jax.named_scope("sample"):
+            (ck, cv, tok, pos), (toks, _rows) = jax.lax.scan(
+                step, (cache["k"], cache["v"], tok, pos), None,
+                length=k)
         return {"k": ck, "v": cv}, toks
 
     return jax.jit(draft_propose, donate_argnums=(1,),

@@ -107,6 +107,7 @@ def init_state(c, layers: int, batch: int) -> Dict[str, jax.Array]:
     }
 
 
+@jax.named_scope("ssm_proj")
 def _project(h: jax.Array, layer, c):
     """z, the conv's input xBC, the raw dt.  The published in-projection
     is ONE matrix ``[z | xBC | dt]``; its dt columns are a leaf of their
@@ -122,6 +123,7 @@ def _project(h: jax.Array, layer, c):
             matmul(h, layer["ssm_dt"].astype(c.dtype), jnp.float32))
 
 
+@jax.named_scope("ssm_conv")
 def _conv_act(window, layer):
     """``silu`` of the depthwise conv over ``window`` ((K, ..., conv_dim),
     oldest tap first), float32."""
@@ -140,6 +142,7 @@ def _dt_a(dt_raw, layer, live):
             -jnp.exp(layer["ssm_A_log"].astype(jnp.float32)))
 
 
+@jax.named_scope("ssm_out")
 def _gated_out(y, z, layer, c):
     """``W_out (RMSNorm(y * silu(z)) * w)``: y float32 (..., nh, hd)."""
     from ray_tpu.models.llama import matmul, rms_norm
@@ -218,26 +221,32 @@ def prefill(h: jax.Array, layer, c, lengths: Optional[jax.Array]):
     if lengths is None:
         lengths = jnp.full((G,), P, jnp.int32)
     z, xbc_in, dt_raw = _project(h, layer, c)
-    padded = jnp.pad(xbc_in, ((0, 0), (K - 1, 0), (0, 0)))
-    # the chunk matmuls' operands, in the compute type
-    xbc = _conv_act([padded[:, k:k + P] for k in range(K)],
-                    layer).astype(c.dtype)
-    # padded[i] is position i - (K - 1): the last K - 1 real inputs
-    taps = lengths[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-    conv_state = jnp.take_along_axis(
-        padded, taps[:, :, None], axis=1).transpose(1, 0, 2)
-    x = xbc[..., :d_inner].reshape(G, P, nh, hd)
-    B, C = xbc[..., d_inner:d_inner + N], xbc[..., d_inner + N:]
-    live = (jnp.arange(P, dtype=jnp.int32)[None, :]
-            < lengths[:, None])[..., None]
-    dt, A = _dt_a(dt_raw, layer, live)
+    with jax.named_scope("ssm_conv"):
+        padded = jnp.pad(xbc_in, ((0, 0), (K - 1, 0), (0, 0)))
+        # the chunk matmuls' operands, in the compute type
+        xbc = _conv_act([padded[:, k:k + P] for k in range(K)],
+                        layer).astype(c.dtype)
+        # padded[i] is position i - (K - 1): the last K - 1 real inputs
+        taps = lengths[:, None] \
+            + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+        conv_state = jnp.take_along_axis(
+            padded, taps[:, :, None], axis=1).transpose(1, 0, 2)
     with jax.named_scope("ssm_scan"):
+        x = xbc[..., :d_inner].reshape(G, P, nh, hd)
+        B, C = xbc[..., d_inner:d_inner + N], xbc[..., d_inner + N:]
+        live = (jnp.arange(P, dtype=jnp.int32)[None, :]
+                < lengths[:, None])[..., None]
+        dt, A = _dt_a(dt_raw, layer, live)
         y, state = ssd_chunked(x, dt, A, B, C, c.ssm_chunk)
-    y = y + layer["ssm_D"].astype(jnp.float32)[:, None] * x.astype(
-        jnp.float32)
-    state = state.transpose(0, 3, 1, 2).reshape(G, N, d_inner)
-    return _gated_out(y, z, layer, c), (state.astype(c.ssm_state_dtype),
-                                        conv_state.astype(c.dtype))
+        y = y + layer["ssm_D"].astype(jnp.float32)[:, None] * x.astype(
+            jnp.float32)
+        state = state.transpose(0, 3, 1, 2).reshape(G, N, d_inner)
+    out = _gated_out(y, z, layer, c)
+    with jax.named_scope("ssm_scan"):
+        state = state.astype(c.ssm_state_dtype)
+    with jax.named_scope("ssm_conv"):
+        conv_state = conv_state.astype(c.dtype)
+    return out, (state, conv_state)
 
 
 def decode(h: jax.Array, layer, c, ssm: jax.Array, conv: jax.Array,
@@ -254,18 +263,19 @@ def decode(h: jax.Array, layer, c, ssm: jax.Array, conv: jax.Array,
     d_inner = nh * hd
     f32 = jnp.float32
     z, xbc_in, dt_raw = _project(h[:, 0], layer, c)
-    old = jax.lax.dynamic_index_in_dim(conv, m, 0, keepdims=False)
-    window = jnp.concatenate([old, xbc_in[None].astype(conv.dtype)], 0)
-    xbc = _conv_act(window, layer)
-    conv = jax.lax.dynamic_update_index_in_dim(
-        conv, jnp.where(active[None, :, None], window[1:], old), m, 0)
-    x = xbc[:, :d_inner].reshape(-1, nh, hd)
-    dt, A = _dt_a(dt_raw, layer, active[:, None])
+    with jax.named_scope("ssm_conv"):
+        old = jax.lax.dynamic_index_in_dim(conv, m, 0, keepdims=False)
+        window = jnp.concatenate([old, xbc_in[None].astype(conv.dtype)], 0)
+        xbc = _conv_act(window, layer)
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, jnp.where(active[None, :, None], window[1:], old), m, 0)
     with jax.named_scope("ssm_state_update"):
+        x = xbc[:, :d_inner].reshape(-1, nh, hd)
+        dt, A = _dt_a(dt_raw, layer, active[:, None])
         ssm, y = ssm_state_update(
             ssm, m, active,
             jnp.repeat(jnp.exp(dt * A), hd, axis=1),
             (dt[..., None] * x).reshape(-1, d_inner),
             xbc[:, d_inner:d_inner + N], xbc[:, d_inner + N:])
-    y = y.reshape(-1, nh, hd) + layer["ssm_D"].astype(f32)[:, None] * x
+        y = y.reshape(-1, nh, hd) + layer["ssm_D"].astype(f32)[:, None] * x
     return _gated_out(y, z, layer, c)[:, None], ssm, conv

@@ -93,6 +93,7 @@ def _einsum(eq, *args):
     return out.astype(args[0].dtype)
 
 
+@jax.named_scope("router")
 def _route(xt: jax.Array, router: jax.Array, k: int, norm_topk: bool):
     """The one routing function (dropless, dense dispatch and the parity
     reference cannot drift): f32 softmax over all experts, top-k, the k
@@ -107,6 +108,7 @@ def _route(xt: jax.Array, router: jax.Array, k: int, norm_topk: bool):
     return probs, gate_vals, expert_idx
 
 
+@jax.named_scope("router")
 def _aux_loss(probs: jax.Array, expert_idx: jax.Array) -> jax.Array:
     """Switch load-balancing loss: E * sum_e(share of tokens whose first
     choice is e * mean router probability of e)."""
@@ -149,46 +151,51 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     probs, gate_vals, expert_idx = _route(
         xt if route_x is None else route_x.reshape(T, D),
         params["router"], K, c.norm_topk)
-    flat = expert_idx.reshape(T * K)
-    if valid is not None:
-        valid = jnp.broadcast_to(valid, (B, S)).reshape(T)
-        # behind every group: sorted last, counted by no expert
-        flat = jnp.where(jnp.repeat(valid, K), flat, E)
-    order = jnp.argsort(flat)            # stable: a token's K stay in order
-    expert_rows = jnp.sum(
-        flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
-        axis=0, dtype=jnp.int32)
+    with jax.named_scope("expert_dispatch"):
+        flat = expert_idx.reshape(T * K)
+        if valid is not None:
+            valid = jnp.broadcast_to(valid, (B, S)).reshape(T)
+            # behind every group: sorted last, counted by no expert
+            flat = jnp.where(jnp.repeat(valid, K), flat, E)
+        order = jnp.argsort(flat)        # stable: a token's K stay in order
+        expert_rows = jnp.sum(
+            flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
 
-    w_gate, w_up, w_down = (params[k].astype(dt)
-                            for k in ("w_gate", "w_up", "w_down"))
-    group_sizes = expert_rows
-    if layer_index is not None:
-        L = w_gate.shape[0]
-        group_sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((L * E,), jnp.int32), expert_rows,
-            (layer_index * E,))
-        w_gate, w_up, w_down = (w.reshape((L * E,) + w.shape[2:])
-                                for w in (w_gate, w_up, w_down))
+    with jax.named_scope("expert_ffn"):
+        w_gate, w_up, w_down = (params[k].astype(dt)
+                                for k in ("w_gate", "w_up", "w_down"))
+        group_sizes = expert_rows
+        if layer_index is not None:
+            L = w_gate.shape[0]
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((L * E,), jnp.int32), expert_rows,
+                (layer_index * E,))
+            w_gate, w_up, w_down = (w.reshape((L * E,) + w.shape[2:])
+                                    for w in (w_gate, w_up, w_down))
 
     def grouped(rows, w):
         # f32 accumulation, as llama.matmul
         return jax.lax.ragged_dot(rows, w, group_sizes,
                                   preferred_element_type=jnp.float32)
 
-    rows = xt[order // K]                                  # (T*K, D)
-    act = c.act(grouped(rows, w_gate).astype(dt)) \
-        * grouped(rows, w_up).astype(dt)
-    out = grouped(act, w_down)                             # (T*K, D) f32
+    with jax.named_scope("expert_dispatch"):
+        rows = xt[order // K]                              # (T*K, D)
+    with jax.named_scope("expert_ffn"):
+        act = c.act(grouped(rows, w_gate).astype(dt)) \
+            * grouped(rows, w_up).astype(dt)
+        out = grouped(act, w_down)                         # (T*K, D) f32
     # Un-sort (order is a permutation) and sum under the gates.  Rows
     # past the last group were not computed: select, do not multiply.
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(T * K, dtype=order.dtype), unique_indices=True)
-    out = out[inverse].reshape(T, K, D)
-    out = jnp.sum(out * gate_vals[..., None], axis=1)
-    if valid is not None:
-        out = jnp.where(valid[:, None], out, 0.0)
-    return (out.reshape(B, S, D).astype(x.dtype),
-            _aux_loss(probs, expert_idx), expert_rows)
+    with jax.named_scope("expert_dispatch"):
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * K, dtype=order.dtype), unique_indices=True)
+        out = out[inverse].reshape(T, K, D)
+        out = jnp.sum(out * gate_vals[..., None], axis=1)
+        if valid is not None:
+            out = jnp.where(valid[:, None], out, 0.0)
+        out = out.reshape(B, S, D).astype(x.dtype)
+    return out, _aux_loss(probs, expert_idx), expert_rows
 
 
 def moe_ffn(x: jax.Array, params: PyTree, config: MoEConfig

@@ -4,7 +4,7 @@ story.
 The host tiers (tracing/timeline, logs/profiling, TSDB/alerts) watch
 the *framework*; this module watches the *chips* it exists to drive —
 the signals MegaScale-style production diagnosis and Pathways-scale
-scheduling decisions read.  Four surfaces, one module:
+scheduling decisions read.  Five surfaces, one module:
 
 1. **HBM sampler** — a per-process daemon thread enumerates the local
    JAX devices every ``RAY_TPU_DEVICE_SAMPLE_S`` seconds and sets the
@@ -48,6 +48,20 @@ scheduling decisions read.  Four surfaces, one module:
    model plane's numbers continuously queryable; ``ray_tpu top``
    renders them live.
 
+5. **Device seconds by scope** — a device trace names a fused op
+   ``%fusion.362``; the program's own name for that work (the
+   ``jax.named_scope`` it was traced under: ``ffn``, ``qkv_proj``,
+   ``optimizer``) reaches only the ``op_name`` metadata of the compiled
+   instruction, which the trace does not carry.  So the program keeps
+   the map: :func:`register_program` remembers a hot-path jitted
+   program with the shapes of one call (the train step at its first
+   dispatch, the serve engine's programs at warm-up; only while
+   tracing is enabled), :func:`program_scopes` lowers each once,
+   through the compile cache, and reads ``instruction -> (scope,
+   phase)`` off the compiled text (:func:`scope_of`, :data:`SCOPES`),
+   and :func:`capture_device_trace` puts that map into its bundle as
+   ``scopes.json``.  Never on a launch, harvest or start path.
+
 ``disable()`` turns sampling, the compile listener, and annotations
 into no-ops.
 
@@ -63,6 +77,7 @@ from __future__ import annotations
 import contextlib
 import io
 import os
+import re
 import sys
 import threading
 import time
@@ -408,7 +423,11 @@ def capture_device_trace(duration_s: float = 1.0,
     ``jax.profiler.start_trace`` → sleep → ``stop_trace``, then zip
     the TensorBoard-loadable output directory into one artifact.
     Returns ``{name, data (zip bytes), files, duration_s, trace_id}``.
-    Serialized per process — jax allows one active trace."""
+    Serialized per process — jax allows one active trace.  With tracing
+    enabled the bundle also holds ``scopes.json``, :func:`program_scopes`
+    of the programs this process registered: what joins the trace's
+    ``%fusion.N`` events to the program's own names
+    (docs/observability.md, "device seconds by scope")."""
     import shutil
     import tempfile
     import zipfile
@@ -443,6 +462,14 @@ def capture_device_trace(duration_s: float = 1.0,
                         files += 1
         finally:
             shutil.rmtree(out_dir, ignore_errors=True)
+    if tracing.enabled():
+        # After stop_trace and outside the capture lock: this lowers
+        # every registered program once (a cache fetch each).
+        import json
+
+        with zipfile.ZipFile(buf, "a", zipfile.ZIP_DEFLATED) as zf:
+            zf.writestr("scopes.json", json.dumps(program_scopes()))
+        files += 1
     name = "device-trace-%d-%d.zip" % (os.getpid(),
                                        int(time.time() * 1000))
     return {"name": name, "data": buf.getvalue(), "files": files,
@@ -478,6 +505,280 @@ def annotation(name: str):
         return jax.profiler.TraceAnnotation(name)
     except Exception:
         return _NULL_CTX
+
+
+# ------------------------------------------------- device seconds by scope
+
+# THE vocabulary: every ``jax.named_scope`` of the model code that a
+# metric is defined on (models/llama.py, llama_serve.py, moe.py,
+# mamba2.py, ops/*; docs/observability.md has the table of where each
+# is).  The innermost of these words in an instruction's ``op_name`` is
+# the instruction's scope.
+SCOPES = (
+    "layer_scan", "embed", "qkv_proj",
+    "attention", "flash_attention.fwd", "flash_attention.dq",
+    "flash_attention.dkdv", "decode_attention",
+    "kv_write", "attn_out", "ffn",
+    "router", "expert_dispatch", "expert_ffn",
+    "ssm_proj", "ssm_conv", "ssm_scan", "ssm_state_update", "ssm_out",
+    "head", "sample", "head_loss", "optimizer",
+)
+PHASES = ("forward", "backward", "remat")
+# What XLA lowers with a kernel of its own it also names itself, op_name
+# and all, so no scope reaches it.  The one such primitive the models
+# call is ``jax.lax.ragged_dot`` (``moe.moe_ffn_dropless``, under
+# ``expert_ffn``): the grouped matmul, and the small kernel that turns
+# the group sizes into its grid.
+_COMPILER_NAMED = {"ragged-dot-none": "expert_ffn",
+                   "ragged-dot-metadata": "expert_dispatch"}
+_SCOPE_OF_WORD = {**{word: word for word in SCOPES}, **_COMPILER_NAMED}
+# ``jvp(ffn)``, ``transpose(jvp(ffn))`` -> ``ffn``: a transformation
+# wraps the one name-stack element inside it.
+_WRAPPED = re.compile(r"^(?:[\w.\-]+\()*([^()]*)\)*$")
+
+
+def scope_of(op_name: Optional[str]):
+    """``(scope | None, phase)`` of an instruction's ``op_name``
+    (``jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/
+    rematted_computation/ffn/dot_general``): the innermost element of
+    the path that is a word of :data:`SCOPES`, through any
+    ``jvp(...)`` / ``transpose(...)`` around it; the phase ``remat``
+    where the path holds ``rematted_computation``, else ``backward``
+    where it holds ``transpose(``, else ``forward``.  Pure string
+    work."""
+    path = op_name or ""
+    if "rematted_computation" in path:
+        phase = "remat"
+    elif "transpose(" in path:
+        phase = "backward"
+    else:
+        phase = "forward"
+    for element in reversed(path.split("/")):
+        m = _WRAPPED.match(element)
+        if m and m.group(1) in _SCOPE_OF_WORD:
+            return _SCOPE_OF_WORD[m.group(1)], phase
+    return None, phase
+
+
+# One instruction of a compiled module's text:
+#   [ROOT ]%name = <shape> opcode(operands...), attributes
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[^ ]+) = (.*?) ([a-z][a-z\-]*)\(")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=(%[^ ,)]+)")
+_TO_APPLY = re.compile(r"\bto_apply=(%[^ ,)]+)")
+# Computations whose instructions run as events of their own, under the
+# instruction that names them: a loop's, a conditional's branches.
+_RUNS = re.compile(r"\b(?:body|condition|true_computation|"
+                   r"false_computation)=(%[^ ,)]+)"
+                   r"|\bbranch_computations=\{([^}]*)\}")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[^ ]+) \(.*\{\s*$")
+# Instructions no device event stands for.
+_NOT_EXECUTED = frozenset(("parameter", "constant", "get-tuple-element",
+                           "tuple", "bitcast"))
+
+
+def instruction_key(text: str) -> Optional[str]:
+    """``%fusion.362 fusion (f32[960], bf16[8,2048,960])`` from an
+    instruction's text: its name, opcode and result shape without
+    layouts.  What a compiled module's text and a device trace's event
+    name agree on: the trace prints the operands with their shapes and
+    the compiled text without, and neither the metadata nor the backend
+    config reaches the trace, so the whole text is no key; the name is
+    unique within a module, and the shape keeps two programs under one
+    module name (``jit_prefill`` at two buckets) apart."""
+    m = _INSTRUCTION.match(text)
+    if not m:
+        return None
+    return f"{m.group(1)} {m.group(3)} {_LAYOUT.sub('', m.group(2))}"
+
+
+def scopes_of_text(hlo_text: str) -> Dict[str, List[str]]:
+    """``{instruction_key: [scope or "unscoped", phase]}`` for every
+    instruction of a compiled module's text that a device event can
+    stand for (those of fused computations and reducers are left out:
+    the fusion is the event).  A fusion whose own ``op_name`` holds no
+    word of the vocabulary takes the scope that most of its fused
+    computation's instructions carry; what then has none takes the
+    scope of the loop, call or conditional it runs under (the compiler
+    expands a scatter into a ``while`` that keeps the scatter's
+    ``op_name`` over a body that has none)."""
+    computations: Dict[str, List[str]] = {}
+    current: Optional[List[str]] = None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            current = computations.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            current.append(line)
+    inner = set()   # fused computations and reducers: not events
+    for lines in computations.values():
+        for line in lines:
+            inner.update(_CALLS.findall(line))
+            if " call(" not in line:
+                inner.update(_TO_APPLY.findall(line))
+
+    def own(line):
+        m = _OP_NAME.search(line)
+        return scope_of(m.group(1) if m else None)
+
+    def majority(name):
+        votes: Dict[tuple, int] = {}
+        for line in computations.get(name, ()):
+            scope, phase = own(line)
+            if scope is not None:
+                votes[scope, phase] = votes.get((scope, phase), 0) + 1
+        return max(votes, key=votes.get) if votes else None
+
+    out: Dict[str, List[str]] = {}
+    under: Dict[str, tuple] = {}    # computation -> what it runs under
+    # The text defines a computation before its caller: callers first.
+    for name in reversed(list(computations)):
+        if name in inner:
+            continue
+        for line in computations[name]:
+            m = _INSTRUCTION.match(line)
+            if not m or m.group(3) in _NOT_EXECUTED:
+                continue
+            scope, phase = own(line)
+            if scope is None and m.group(3) == "fusion":
+                fused = _CALLS.search(line)
+                voted = majority(fused.group(1)) if fused else None
+                if voted is not None:
+                    scope, phase = voted
+            if scope is None and name in under:
+                scope, phase = under[name]
+            out[instruction_key(line)] = [scope or "unscoped", phase]
+            if scope is not None:
+                runs = [n for one, many in _RUNS.findall(line)
+                        for n in [one, *re.findall(r"%[^ ,]+", many)] if n]
+                if m.group(3) == "call":
+                    runs += _TO_APPLY.findall(line)
+                for callee in runs:
+                    under.setdefault(callee, (scope, phase))
+    return out
+
+
+class _Program:
+    """A registered program: what lowers it again, then what that gave."""
+
+    __slots__ = ("name", "lower", "module", "scopes")
+
+    def __init__(self, name: str, lower):
+        self.name = name
+        self.lower = lower     # () -> compiled text; dropped once called
+        self.module: Optional[str] = None
+        self.scopes: Optional[Dict[str, List[str]]] = None
+
+
+_PROGRAMS_MAX = 256
+_programs_lock = threading.Lock()
+_programs: Dict[Any, _Program] = {}    # by (name, program, shapes)
+
+
+def register_program(name: str, jitted, args, **static) -> None:
+    """Remember a jitted hot-path program with the SHAPES of one call,
+    for :func:`program_scopes`: every array of ``args`` as a
+    ``ShapeDtypeStruct`` with its sharding (no array is kept alive),
+    the static keyword arguments as they are, and the mesh and sharding
+    rules ambient at this call (a train step's trace reads
+    ``current_mesh()``).  Lowers nothing.  A no-op with tracing
+    disabled; its callers (``LLMServer._warmup``, the train step's
+    first dispatch) do not even call it then.  Must never raise."""
+    from . import tracing
+
+    if not tracing.enabled():
+        return
+    try:
+        import jax
+
+        from ..parallel.sharding import (current_mesh, current_rules,
+                                         use_mesh, use_sharding_rules)
+
+        def shape(x):
+            if isinstance(x, jax.Array):
+                # An uncommitted array lowers as a shape alone does; its
+                # default device spelled out would be another module
+                # text, and a miss in the compile cache.
+                return jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, weak_type=x.weak_type,
+                    sharding=x.sharding if x.committed else None)
+            if hasattr(x, "shape") and hasattr(x, "dtype"):
+                return jax.ShapeDtypeStruct(x.shape, x.dtype)
+            return x
+
+        shapes = jax.tree.map(shape, args)
+        leaves, treedef = jax.tree.flatten(shapes)
+        key = (name, id(jitted), treedef, tuple(
+            (x.shape, str(x.dtype), x.sharding)
+            if isinstance(x, jax.ShapeDtypeStruct) else x
+            for x in leaves), tuple(sorted(static.items())))
+        mesh, rules = current_mesh(), current_rules()
+
+        def lower() -> str:
+            with use_mesh(mesh), use_sharding_rules(rules):
+                return jitted.lower(*shapes, **static).compile(
+                    ).as_text() or ""
+
+        with _programs_lock:
+            if key in _programs:
+                return
+            while len(_programs) >= _PROGRAMS_MAX:
+                _programs.pop(next(iter(_programs)))
+            _programs[key] = _Program(name, lower)
+    except Exception:
+        pass  # telemetry must never break a launch
+
+
+def registered_programs() -> List[str]:
+    """Names of the registered programs, one per registered shape."""
+    with _programs_lock:
+        return [program.name for program in _programs.values()]
+
+
+def clear_programs() -> None:
+    """Forget every registered program (tests)."""
+    with _programs_lock:
+        _programs.clear()
+
+
+def program_scopes() -> Dict[str, Dict[str, List[str]]]:
+    """``{module name: {instruction_key: [scope, phase]}}`` of every
+    registered program (programs under one module name, ``jit_prefill``
+    at each bucket, share one table; the first registered wins a key
+    two of them give).  Each program is lowered and compiled from its
+    registered shapes ONCE -- the module text is the one that ran, so
+    jax's own executable cache answers in a process that still holds
+    it (0.06-1.4 s for the 1-18 programs of a benchmark cell, measured
+    on a v5e), the persistent compile cache otherwise -- and what
+    lowered it is dropped; later calls return what the first read.  For
+    after a capture or a benchmark window, never for a launch, harvest
+    or start path."""
+    with _programs_lock:
+        programs = list(_programs.values())
+    out: Dict[str, Dict[str, List[str]]] = {}
+    for program in programs:
+        if program.scopes is None:
+            try:
+                text = program.lower()
+            except Exception as e:  # noqa: BLE001
+                import logging
+
+                logging.getLogger(__name__).warning(
+                    "program_scopes: %s does not lower again: %s",
+                    program.name, e)
+                text = ""
+            m = re.match(r"HloModule ([^ ,]+)", text)
+            program.module = m.group(1) if m else program.name
+            program.scopes = scopes_of_text(text)
+            program.lower = None
+        table = out.setdefault(program.module, {})
+        for key, value in program.scopes.items():
+            table.setdefault(key, value)
+    return out
 
 
 # ---------------------------------------------------- model-plane emit

@@ -611,6 +611,13 @@ class LLMServer:
             # new shape would be one more program to fetch
             return jnp.asarray(np.full(shape, value, np.int32))
 
+        def warm(name, program, *args, **static):
+            # With tracing on, the shapes of each warmed program are kept
+            # for device.program_scopes (nothing is lowered here).
+            if _tracing.enabled():
+                _device.register_program(name, program, args, **static)
+            return program(*args, **static)
+
         for g, bucket in prefill_shapes(self.prefill_groups, self.buckets,
                                         self.max_slots):
             lengths = filled(g, 1)
@@ -619,18 +626,22 @@ class LLMServer:
                 bs = self.block_size
                 nw = -(-bucket // bs)
                 pad_bt = filled((g, nw), self._pad_block)  # writes dropped
-                self.pool, _f, _m = self._prefill_cold(
+                self.pool, _f, _m = warm(
+                    "serve.prefill_cold", self._prefill_cold,
                     self.params, self.pool, toks, lengths, pad_bt)
                 pre = filled((g, self._np_max), self._pad_block)
-                self.pool, _f, _m = self._prefill_warm(
+                self.pool, _f, _m = warm(
+                    "serve.prefill_warm", self._prefill_warm,
                     self.params, self.pool, toks, lengths, filled(g, 0),
                     pre, pad_bt)
             else:
                 slots = filled(g, -1)  # writes nothing
-                self.cache, _first, _m = self._prefill(
+                self.cache, _first, _m = warm(
+                    "serve.prefill", self._prefill,
                     self.params, self.cache, toks, lengths, slots)
             if self.spec_k:
-                self.draft_cache = self._draft_prefill(
+                self.draft_cache = warm(
+                    "serve.draft_prefill", self._draft_prefill,
                     self.draft_params, self.draft_cache, toks,
                     lengths, filled(g, -1))
         active = jnp.zeros(self.max_slots, bool)  # no-op decode
@@ -643,7 +654,8 @@ class LLMServer:
                 if self.spec_k:
                     # The spec scheduler replaces decode chunks with
                     # verify passes — warm those per bucket instead.
-                    self.pool, _t = self._spec_verify(
+                    self.pool, _t = warm(
+                        "serve.spec_verify", self._spec_verify,
                         self.params, self.pool,
                         jnp.zeros((self.max_slots, self.spec_k),
                                   jnp.int32),
@@ -651,10 +663,10 @@ class LLMServer:
                                   jnp.int32), active, bt)
                 else:
                     self.pool, _t, self._tok_dev, self._len_dev, _m = \
-                        self._decode_paged(
-                            self.params, self.pool, self._tok_dev,
-                            self._len_dev, ov, ov, ovm, active, bt,
-                            k=self.decode_chunk)
+                        warm("serve.decode_paged", self._decode_paged,
+                             self.params, self.pool, self._tok_dev,
+                             self._len_dev, ov, ov, ovm, active, bt,
+                             k=self.decode_chunk)
                 kb = jnp.zeros(
                     (nb, self.cfg.n_layers, self.block_size,
                      self.cfg.n_kv_heads, self.cfg.head_dim),
@@ -663,18 +675,20 @@ class LLMServer:
                 self.pool = self._inject(self.pool, kb, kb, dest)
             if self.spec_k:
                 for sa in self.decode_buckets:
-                    self.draft_cache, _t = self._draft_propose(
+                    self.draft_cache, _t = warm(
+                        "serve.draft_propose", self._draft_propose,
                         self.draft_params, self.draft_cache, ov, ov,
                         active, k=self.spec_k, s_active=int(sa))
             jax.block_until_ready(self.pool["k"])
         else:
             for sa in self.decode_buckets:
                 self.cache, _t, self._tok_dev, self._len_dev, _m = \
-                    self._decode_k(self.params, self.cache,
-                                   self._tok_dev, self._len_dev, ov,
-                                   ov, ovm, active,
-                                   k=self.decode_chunk,
-                                   s_active=int(sa))
+                    warm("serve.decode_k", self._decode_k,
+                         self.params, self.cache,
+                         self._tok_dev, self._len_dev, ov,
+                         ov, ovm, active,
+                         k=self.decode_chunk,
+                         s_active=int(sa))
             jax.block_until_ready(self.cache)
 
     # ------------------------------------------------------------ serving
