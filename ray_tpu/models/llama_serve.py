@@ -14,7 +14,9 @@ engine, thread or weights are needed to lower one.  By the cache kept:
   ``prefill_cold``, ``prefill_warm``, ``decode_paged``, ``inject`` (and
   its inverse ``BlockPool.extract``), ``spec_verify``;
 - the speculative draft's own dense cache: ``draft_prefill``,
-  ``draft_propose``.
+  ``draft_propose``;
+- no cache at all: ``seat``, a prefill group's first tokens and lengths
+  into the token and length carries of ``decode_k`` / ``decode_paged``.
 
 The prefills and ``spec_verify`` are ``llama.layer_walk`` with their own
 K/V step: what a decoder layer is made of is ``models/llama.py``'s
@@ -196,10 +198,10 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         with jax.named_scope("qkv_proj"):
             sin, cos = llama.rope_table(lens[:, None], cfg.head_dim,
                                         cfg.rope_theta)
-        # Inactive slots MUST not write: a just-admitted slot's
-        # prefill may already have landed (it sits out this
-        # chunk awaiting its first token) and a stale-position
-        # write would corrupt its fresh rows.  Nor does a slot
+        # Inactive slots MUST not write: an occupied slot that is not
+        # in this launch (LLMServer.slot_waiting) may hold a
+        # prefill's fresh rows, and a stale-position
+        # write would corrupt them.  Nor does a slot
         # past the attended prefix.  Their row goes out of range
         # and the scatter drops it.  (Nor may an inactive slot's
         # recurrent state advance: mamba2.decode.)
@@ -409,6 +411,22 @@ def build_decode_k(cfg: LlamaConfig) -> Callable:
     # returned carries at every call site: donate them too.
     return jax.jit(decode_k, donate_argnums=(1, 2, 3),
                    static_argnames=("k", "s_active"))
+
+
+def build_seat() -> Callable:
+    """A prefill group's rows into the decode programs' carries (both
+    planes' programs take ``tok_dev, len_dev`` first): ``first`` (G,), the group's
+    first tokens as its prefill returned them, and ``lens`` (G,), the
+    rows' lengths, at ``slots`` (G,).  A negative slot (the padding of a
+    rung, a row that is not to decode) goes out of range and is dropped:
+    left negative it would wrap to the last slot."""
+    def seat(tok_dev, len_dev, first, lens, slots):
+        with jax.named_scope("sample"):
+            at = jnp.where(slots >= 0, slots, tok_dev.shape[0])
+            return (tok_dev.at[at].set(first, mode="drop"),
+                    len_dev.at[at].set(lens, mode="drop"))
+
+    return jax.jit(seat, donate_argnums=(0, 1))
 
 
 # ------------------------------------------------------------- paged plane

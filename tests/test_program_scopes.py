@@ -213,8 +213,11 @@ def test_an_engine_registers_what_it_warms(preset, must_hold):
                                     server.max_slots)
         assert names.count("serve.prefill") == len(shapes)
         assert names.count("serve.decode_k") == len(server.decode_buckets)
+        assert names.count("serve.seat") == len({g for g, _ in shapes})
         scopes = device.program_scopes()
-        assert set(scopes) == {"jit_prefill", "jit_decode_k"}
+        assert set(scopes) == {"jit_prefill", "jit_decode_k", "jit_seat"}
+        assert {v[0] for v in scopes["jit_seat"].values()} <= {
+            "sample", "unscoped"}
         held = {v[0] for table in scopes.values() for v in table.values()}
         assert must_hold <= held, held
         assert held <= set(device.SCOPES) | {"unscoped"}
