@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -145,11 +146,60 @@ class LlamaConfig:
     # reference (measured for granite-4.0-h-micro: PERF.md section 6,
     # PR 30); matmul operands are ``dtype`` either way.
     stream_dtype: Any = None
+    # LATENT attention (DeepSeek-V2's MLA; served only, dense plane), on
+    # with kv_lora_rank > 0.  Queries go down to q_lora_rank, are normed
+    # and come up to n_heads x (qk_nope_head_dim + qk_rope_head_dim); keys
+    # and values go down to ONE row of kv_lora_rank (normed) +
+    # qk_rope_head_dim (roped, shared by all heads) a token, which is all
+    # the serving cache keeps, and come up to qk_nope_head_dim and
+    # v_head_dim a head.  head_dim is then the q/k head, nope + rope; RoPE
+    # turns the rope parts only, their pairs taken interleaved (2i, 2i+1).
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # A published ``rope_scaling`` block of type "yarn" (factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim): ``rope_frequencies``.  None: plain RoPE.
+    rope_scaling: Any = None
+    # Layers with a dense FFN of width intermediate_size BEFORE the
+    # expert layers (DeepSeek's first_k_dense_replace): their weights are
+    # ``params["dense_layers"]``, stacked apart, and every walk runs them
+    # as a prologue outside the period scan (``parts``).
+    first_dense_layers: int = 0
+    # An expert's width where it is not intermediate_size (0: it is), and
+    # the width of the shared expert every token passes beside its routed
+    # ones (n_shared_experts x the expert width, as ONE SwiGLU; 0: none).
+    moe_intermediate_size: int = 0
+    moe_shared_size: int = 0
+    # Group-limited routing: the experts are moe_groups groups of
+    # consecutive ones, a group scores its best expert, only the
+    # moe_top_groups best groups' experts can be chosen (0: no groups).
+    # The chosen gates are multiplied by moe_routed_scale.
+    moe_groups: int = 0
+    moe_top_groups: int = 0
+    moe_routed_scale: float = 1.0
+    # One chip's SHARE of the experts: ``(first, count)`` of the
+    # moe_experts are held here (their matrices are ``[L, count, ...]``),
+    # the router scores all moe_experts, and the layer adds what its own
+    # experts give for the tokens routed to them; what the experts
+    # elsewhere would add is left out.  (): every expert is here.
+    moe_held: Tuple[int, ...] = ()
+    # Positions a dropless dispatch takes at once (0: all of them): a
+    # longer prompt's expert FFN runs a chunk at a time, so that its
+    # sorted rows and their un-sorted copy (positions x top-k x hidden
+    # each) are a chunk's and not the prompt's.
+    moe_dispatch_chunk: int = 0
 
     def __post_init__(self):
         # a configuration file's lists and type names, made hashable
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
         object.__setattr__(self, "nope_kinds", tuple(self.nope_kinds))
+        object.__setattr__(self, "moe_held", tuple(self.moe_held))
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
         for name in ("dtype", "stream_dtype", "ssm_state_dtype"):
             if isinstance(getattr(self, name), str):
                 object.__setattr__(self, name,
@@ -158,10 +208,11 @@ class LlamaConfig:
         if unknown:
             raise ValueError(f"layer_pattern: unknown kinds {unknown} "
                              f"(choose from {LAYER_KINDS})")
-        if self.n_layers % len(self.period):
+        if (self.n_layers - self.first_dense_layers) % len(self.period):
             raise ValueError(
                 f"n_layers={self.n_layers} is not a whole number of "
                 f"periods of {len(self.period)} layers")
+        self._check_latent_and_share()
         if "mamba" in self.layer_pattern and self.ssm_heads < 1:
             raise ValueError("a mamba layer needs ssm_heads")
         if "window" in self.layer_pattern:
@@ -178,6 +229,56 @@ class LlamaConfig:
         if self.moe_activation not in ("silu", "relu"):
             raise ValueError(f"moe_activation {self.moe_activation!r}")
 
+    def _check_latent_and_share(self):
+        """Refuse what no program here computes of latent attention,
+        leading dense layers and an expert share."""
+        patterned = self.period != ("attention",)
+        if self.kv_lora_rank:
+            if patterned:
+                raise ValueError(
+                    "latent attention keeps one latent row a token and "
+                    "layer: no serving cache holds it beside window rings "
+                    "or recurrent states (layer_pattern must be empty)")
+            if min(self.q_lora_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim, self.v_head_dim) < 1:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, qk_nope_head_dim, "
+                    "qk_rope_head_dim and v_head_dim (a model without "
+                    "query compression is not built)")
+            if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
+                raise ValueError(
+                    f"head_dim={self.head_dim} is not qk_nope_head_dim + "
+                    f"qk_rope_head_dim")
+            if self.qk_norm or not self.rope or self.nope_kinds:
+                raise ValueError("latent attention has its own norms and "
+                                 "always rotates its rope part")
+        if self.rope_scaling is not None:
+            kind = dict(self.rope_scaling).get("type")
+            if kind != "yarn":
+                raise ValueError(f"rope_scaling type {kind!r}: only "
+                                 f"'yarn' is built")
+        if self.first_dense_layers:
+            if patterned or self.moe_experts == 0:
+                raise ValueError(
+                    "first_dense_layers is a prologue before a stack of "
+                    "expert layers of one kind")
+            if not 0 < self.first_dense_layers < self.n_layers:
+                raise ValueError("first_dense_layers must leave a layer")
+        if self.moe_groups:
+            if (self.moe_experts % self.moe_groups
+                    or not 0 < self.moe_top_groups <= self.moe_groups):
+                raise ValueError(
+                    f"moe_groups={self.moe_groups} must divide "
+                    f"moe_experts={self.moe_experts} and hold "
+                    f"moe_top_groups={self.moe_top_groups}")
+        if self.moe_held:
+            first, count = self.moe_held
+            if not (0 <= first and 0 < count
+                    and first + count <= self.moe_experts):
+                raise ValueError(
+                    f"moe_held={self.moe_held} is not (first, count) "
+                    f"within moe_experts={self.moe_experts}")
+
     @property
     def period(self) -> Tuple[str, ...]:
         """The kinds of one period of layers."""
@@ -191,6 +292,53 @@ class LlamaConfig:
         """How many of the n_layers are of ``kind``."""
         return (self.n_layers // self.period_len) * self.period.count(kind)
 
+    def parts(self):
+        """The layer stacks a walk runs in turn, ``(config of the part,
+        its key in params, its first layer's index among all)``: the
+        leading dense layers, if any, as a dense model of that many layers
+        (``params["dense_layers"]``), then the scanned stack
+        (``params["layers"]``).  A part's config is a plain one: the code
+        that walks a stack never asks which part it is in."""
+        k = self.first_dense_layers
+        if not k:
+            return [(self, "layers", 0)]
+        return [
+            (dataclasses.replace(
+                self, n_layers=k, first_dense_layers=0, moe_experts=0,
+                moe_shared_size=0, moe_groups=0, moe_top_groups=0,
+                moe_held=()), "dense_layers", 0),
+            (dataclasses.replace(self, n_layers=self.n_layers - k,
+                                 first_dense_layers=0), "layers", k)]
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """``(first, count)`` of the experts whose matrices are here."""
+        return self.moe_held or (0, self.moe_experts)
+
+    @property
+    def rope_dim(self) -> int:
+        """The width RoPE turns: the head, or a latent head's rope part."""
+        return self.qk_rope_head_dim or self.head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Values a token and layer keeps in a latent cache, as STORED:
+        kv_lora_rank + qk_rope_head_dim (576 for DeepSeek-V2) padded with
+        zeros to whole lanes (640).  The chip lays a 576-wide minor
+        dimension out as 640 whatever the leaf says (Mosaic reads the pool
+        as ``...x640``, and refuses a 576-wide slice of it), so the leaf
+        says what it occupies."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def o_dim(self) -> int:
+        """What the output projection reads: every head's values."""
+        return self.n_heads * (self.v_head_dim or self.head_dim)
+
     def attending_layers(self) -> int:
         """How many of the n_layers attend (hold ``ATTENTION_LEAVES``)."""
         return sum(self.layers_of(kind) for kind in ATTENDING_KINDS)
@@ -203,6 +351,11 @@ class LlamaConfig:
     def attn_scale(self) -> float:
         if self.attention_multiplier is not None:
             return self.attention_multiplier
+        if self.rope_scaling is not None:
+            # DeepSeek's YaRN: the scores carry mscale(all dims) squared
+            scaling = dict(self.rope_scaling)
+            return self.head_dim ** -0.5 * yarn_mscale(
+                scaling["factor"], scaling.get("mscale_all_dim", 0)) ** 2
         return self.head_dim ** -0.5
 
     @property
@@ -309,6 +462,13 @@ class LlamaConfig:
 
 def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     """Pytree (matching init_params) of per-dim logical axis names."""
+    if config.first_dense_layers:
+        axes = None
+        for part, key, _ in config.parts():
+            part_axes = param_logical_axes(part)
+            axes = axes or part_axes
+            axes[key] = part_axes["layers"]
+        return axes
     if config.moe_experts > 0:
         ffn_axes = {
             "router": ("layers", None, "expert"),
@@ -338,6 +498,20 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     if config.qk_norm:
         axes["layers"]["q_norm"] = ("layers", None)
         axes["layers"]["k_norm"] = ("layers", None)
+    if config.moe_shared_size:
+        axes["layers"].update(
+            ws_gate=("layers", "embed", "mlp"),
+            ws_up=("layers", "embed", "mlp"),
+            ws_down=("layers", "mlp", "embed"))
+    if config.kv_lora_rank:
+        for name in ("wq", "wk", "wv"):
+            del axes["layers"][name]
+        axes["layers"].update(
+            wq_a=("layers", "embed", None), q_a_norm=("layers", None),
+            wq_b=("layers", None, "heads"),
+            wkv_a=("layers", "embed", None), kv_a_norm=("layers", None),
+            wk_b=("layers", "heads", None, None),
+            wv_b=("layers", "heads", None, None))
     if config.layers_of("mamba"):
         from ray_tpu.models import mamba2
 
@@ -368,21 +542,34 @@ def init_params(rng: jax.Array, config: LlamaConfig,
     def dense(key, shape, fan_in):
         return init_dense(key, shape, fan_in, dtype)
 
+    if c.first_dense_layers:
+        # Each part is drawn as the plain model its config is; the leading
+        # dense layers from a key of their own.
+        (dense_part, _, _), (rest, _, _) = c.parts()
+        params = init_params(rng, rest, dtype)
+        params["dense_layers"] = init_params(
+            jax.random.fold_in(rng, 97), dense_part, dtype)["layers"]
+        return params
     L, La = c.n_layers, c.attending_layers()
     if c.moe_experts > 0:
-        E = c.moe_experts
+        # The router scores every expert; the matrices are those of the
+        # experts held here (all of them unless ``moe_held``).
+        E, (_, Eh), F = c.moe_experts, c.held_experts, c.expert_width
         ffn = {
             "router": dense(keys[5], (L, c.hidden_size, E), c.hidden_size),
-            "w_gate": dense(keys[6],
-                            (L, E, c.hidden_size, c.intermediate_size),
+            "w_gate": dense(keys[6], (L, Eh, c.hidden_size, F),
                             c.hidden_size),
             "w_up": dense(jax.random.fold_in(keys[6], 1),
-                          (L, E, c.hidden_size, c.intermediate_size),
-                          c.hidden_size),
-            "w_down": dense(keys[7],
-                            (L, E, c.intermediate_size, c.hidden_size),
-                            c.intermediate_size),
+                          (L, Eh, c.hidden_size, F), c.hidden_size),
+            "w_down": dense(keys[7], (L, Eh, F, c.hidden_size), F),
         }
+        if c.moe_shared_size:
+            Fs, ks = c.moe_shared_size, jax.random.split(
+                jax.random.fold_in(rng, 96), 3)
+            ffn.update(
+                ws_gate=dense(ks[0], (L, c.hidden_size, Fs), c.hidden_size),
+                ws_up=dense(ks[1], (L, c.hidden_size, Fs), c.hidden_size),
+                ws_down=dense(ks[2], (L, Fs, c.hidden_size), Fs))
     else:
         ffn = {
             "w_gate": dense(keys[5], (L, c.hidden_size, c.intermediate_size),
@@ -422,6 +609,11 @@ def init_params(rng: jax.Array, config: LlamaConfig,
     if c.qk_norm:
         params["layers"]["q_norm"] = jnp.ones((La, c.q_dim), dtype)
         params["layers"]["k_norm"] = jnp.ones((La, c.kv_dim), dtype)
+    if c.kv_lora_rank:
+        for name in ("wq", "wk", "wv", "wo"):
+            del params["layers"][name]
+        params["layers"].update(_init_latent_attention(
+            jax.random.fold_in(rng, 95), c, La, dtype, dense))
     if c.layers_of("mamba"):
         from ray_tpu.models import mamba2
 
@@ -433,6 +625,28 @@ def init_params(rng: jax.Array, config: LlamaConfig,
             jax.random.fold_in(rng, 99), (c.hidden_size, c.vocab_size),
             c.hidden_size)
     return params
+
+
+def _init_latent_attention(rng, c: LlamaConfig, La: int, dtype, dense):
+    """A latent attention's leaves over ``La`` layers (DeepSeek-V2's
+    names beside ours): ``wq_a`` W_DQ, ``q_a_norm``, ``wq_b`` W_UQ (a
+    head's columns: nope then rope), ``wkv_a`` W_DKV (columns: the
+    kv_lora_rank compressed, then the rope part), ``kv_a_norm``, and W_UKV
+    as the two matrices the absorbed decode multiplies by, a head apart:
+    ``wk_b`` (H, nope, rank), a head's W_UK transposed, and ``wv_b`` (H,
+    rank, v), its W_UV; ``wo`` reads H x v_head_dim."""
+    ks = jax.random.split(rng, 6)
+    D, H, R, Rq = c.hidden_size, c.n_heads, c.kv_lora_rank, c.q_lora_rank
+    return {
+        "wq_a": dense(ks[0], (La, D, Rq), D),
+        "q_a_norm": jnp.ones((La, Rq), dtype),
+        "wq_b": dense(ks[1], (La, Rq, H * c.head_dim), Rq),
+        "wkv_a": dense(ks[2], (La, D, R + c.qk_rope_head_dim), D),
+        "kv_a_norm": jnp.ones((La, R), dtype),
+        "wk_b": dense(ks[3], (La, H, c.qk_nope_head_dim, R), R),
+        "wv_b": dense(ks[4], (La, H, R, c.v_head_dim), R),
+        "wo": dense(ks[5], (La, c.o_dim, D), c.o_dim),
+    }
 
 
 def param_count(params: PyTree) -> int:
@@ -493,13 +707,60 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (x * scale.astype(jnp.float32)).astype(dtype)
 
 
-def rope_table(positions: jax.Array, head_dim: int,
-               theta: float) -> Tuple[jax.Array, jax.Array]:
-    """(sin, cos) tables, shape (..., seq, head_dim/2), float32."""
-    freqs = theta ** (-jnp.arange(0, head_dim // 2, dtype=jnp.float32)
-                      / (head_dim // 2))
-    angles = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.sin(angles), jnp.cos(angles)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 for a
+    factor that does not stretch)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(scaling: Dict[str, Any], dim: int,
+                          theta: float) -> Tuple[int, int]:
+    """``(low, high)``: the pair indices between which YaRN blends the
+    stretched frequencies into the published ones -- the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context."""
+    def pair_of(turns):
+        return (dim * math.log(scaling["original_max_position_embeddings"]
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(pair_of(scaling["beta_fast"])), 0),
+            min(math.ceil(pair_of(scaling["beta_slow"])), dim - 1))
+
+
+def rope_frequencies(head_dim: int, theta: float, scaling: Any = None):
+    """``(inv_freq (head_dim / 2,) float32, factor on cos and sin)``.
+    Plain RoPE: ``theta ** (-2i / head_dim)`` and 1.  YaRN (``scaling``: a
+    published rope_scaling block, a dict or its items): pair i keeps its
+    frequency ``f`` below ``low``, takes ``f / factor`` above ``high`` and
+    a linear blend between; cos and sin carry ``mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)``."""
+    import numpy as np
+
+    pairs = np.arange(0, head_dim // 2, dtype=np.float64)
+    freqs = theta ** (-pairs / (head_dim // 2))
+    if scaling is None:
+        return freqs.astype(np.float32), 1.0
+    scaling = dict(scaling)
+    low, high = yarn_correction_range(scaling, head_dim, theta)
+    keep = 1.0 - np.clip((pairs - low) / max(high - low, 1e-3), 0.0, 1.0)
+    freqs = freqs / scaling["factor"] * (1.0 - keep) + freqs * keep
+    return freqs.astype(np.float32), (
+        yarn_mscale(scaling["factor"], scaling.get("mscale", 1))
+        / yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0)))
+
+
+def rope_table(positions: jax.Array, head_dim: int, theta: float,
+               scaling: Any = None) -> Tuple[jax.Array, jax.Array]:
+    """(sin, cos) tables, shape (..., seq, head_dim/2), float32; with
+    ``scaling`` YaRN's (``rope_frequencies``)."""
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, head_dim // 2, dtype=jnp.float32)
+                          / (head_dim // 2))
+        angles = positions.astype(jnp.float32)[..., None] * freqs
+        return jnp.sin(angles), jnp.cos(angles)
+    freqs, factor = rope_frequencies(head_dim, theta, scaling)
+    angles = positions.astype(jnp.float32)[..., None] * jnp.asarray(freqs)
+    return jnp.sin(angles) * factor, jnp.cos(angles) * factor
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
@@ -545,7 +806,7 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v,
                      preferred_element_type=jnp.float32).astype(v.dtype)
-    return out.reshape(B, S, Hq, D)
+    return out.reshape(B, S, Hq, v.shape[-1])
 
 
 def _get_attention_fn(config) -> Callable:
@@ -601,6 +862,137 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     return q, k, v
 
 
+def _rope_interleaved(x: jax.Array, sin, cos) -> jax.Array:
+    """RoPE on pairs taken INTERLEAVED, ``(2i, 2i+1)`` turning by pair
+    i's angle (DeepSeek-V2's public code): the pairs are brought to the
+    rotate-half order and turned by ``apply_rope``; the result stays in
+    that order, which a dot product of two such rows does not see.  x:
+    (batch, seq, heads, rope_dim)."""
+    return apply_rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1),
+                      sin, cos)
+
+
+# Heads a latent prefill expands and attends at once: a 12,288-token
+# prompt's 128 heads of 192-wide q and k (laid out as 256 lanes), v and
+# the result are 2.4 GB whole, 0.6 GB a group of 32 (AOT for a v5e, PR 37).
+LATENT_HEAD_GROUP = 32
+
+
+@jax.named_scope("qkv_proj")
+def latent_down(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
+                config: LlamaConfig):
+    """A latent attention layer's DOWN projections, shared by prefill and
+    decode: x (B, S, D) -> ``(c_q (B, S, q_lora_rank) normed, latent (B, S,
+    latent_row))``.  ``latent`` is what the cache keeps of a token:
+    ``c_kv`` after its norm, ``k_rope`` after RoPE, zeros to whole lanes."""
+    c = config
+    B, S, _ = x.shape
+    dt = c.dtype
+    h = rms_norm(x, layer["attn_norm"], c.norm_eps).astype(dt)
+    cq = rms_norm(matmul(h, layer["wq_a"].astype(dt)), layer["q_a_norm"],
+                  c.norm_eps)
+    kv = matmul(h, layer["wkv_a"].astype(dt))
+    ckv, k_rope = jnp.split(kv, [c.kv_lora_rank], axis=-1)
+    ckv = rms_norm(ckv, layer["kv_a_norm"], c.norm_eps)
+    k_rope = _rope_interleaved(k_rope[:, :, None], sin, cos)[:, :, 0]
+    pad = c.latent_row - c.kv_lora_rank - c.qk_rope_head_dim
+    latent = jnp.concatenate(
+        [ckv, k_rope, jnp.zeros((B, S, pad), dt)], axis=-1)
+    return cq, latent
+
+
+@jax.named_scope("qkv_proj")
+def latent_queries(cq: jax.Array, wq_b: jax.Array, sin, cos,
+                   config: LlamaConfig):
+    """Queries UP from ``c_q`` for the heads whose columns ``wq_b`` (Rq,
+    heads, nope + rope) holds -> ``(q_nope (B, S, heads, nope), q_rope (B,
+    S, heads, rope) roped)``."""
+    q = jnp.einsum("bsr,rhd->bshd", cq, wq_b.astype(config.dtype),
+                   preferred_element_type=jnp.float32).astype(config.dtype)
+    q_nope, q_rope = jnp.split(q, [config.qk_nope_head_dim], axis=-1)
+    return q_nope, _rope_interleaved(q_rope, sin, cos)
+
+
+def _wq_b_heads(layer, config: LlamaConfig) -> jax.Array:
+    """``wq_b`` a head apart: (Rq, H, nope + rope)."""
+    return layer["wq_b"].reshape(-1, config.n_heads, config.head_dim)
+
+
+@jax.named_scope("mla_expand")
+def latent_expand(latent, q_rope, wk_b, wv_b, config: LlamaConfig):
+    """The EXPANDED path's keys and values for the heads of ``wk_b`` (heads,
+    nope, R) / ``wv_b`` (heads, R, v): ``[k_nope_i ; v_i] = c_kv W_UKV``,
+    the one roped key part copied to every head -> k (B, S, heads, nope +
+    rope), v (B, S, heads, v_head_dim).  ``q_rope``: for its shape."""
+    c = config
+    dt = c.dtype
+    ckv = latent[..., :c.kv_lora_rank]
+    k_rope = latent[..., c.kv_lora_rank:c.kv_lora_rank + c.qk_rope_head_dim]
+    k_nope = jnp.einsum("bsc,hdc->bshd", ckv, wk_b.astype(dt),
+                        preferred_element_type=jnp.float32).astype(dt)
+    v = jnp.einsum("bsc,hcd->bshd", ckv, wv_b.astype(dt),
+                   preferred_element_type=jnp.float32).astype(dt)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None], q_rope.shape)], -1)
+    return k, v
+
+
+def latent_attend_expanded(cq, latent, layer, sin, cos, config: LlamaConfig,
+                           attend: Callable) -> jax.Array:
+    """A prompt's latent attention in the EXPANDED form: queries, keys
+    and values a head, attended by ``attend(q, k, v) -> (B, S, heads,
+    v_head_dim)`` as plain multi-head attention with a 192-wide q/k head
+    and a 128-wide v head.  A long prompt's heads go a group of
+    ``LATENT_HEAD_GROUP`` at a time, one group after another."""
+    c = config
+    H = c.n_heads
+    wq_b = _wq_b_heads(layer, c)
+
+    def heads(wq, wk, wv):
+        q_nope, q_rope = latent_queries(cq, wq, sin, cos, c)
+        k, v = latent_expand(latent, q_rope, wk, wv, c)
+        with jax.named_scope("attention"):
+            return attend(jnp.concatenate([q_nope, q_rope], -1), k, v)
+
+    group = LATENT_HEAD_GROUP
+    if cq.shape[1] <= FLASH_PREFILL_FROM or H <= group or H % group:
+        return heads(wq_b, layer["wk_b"], layer["wv_b"])
+
+    def one(g):
+        cut = lambda w, axis: jax.lax.dynamic_slice_in_dim(
+            w, g * group, group, axis)
+        return heads(cut(wq_b, 1), cut(layer["wk_b"], 0),
+                     cut(layer["wv_b"], 0))
+
+    out = jax.lax.map(one, jnp.arange(H // group))     # (G, B, S, group, v)
+    return jnp.moveaxis(out, 0, 2).reshape(out.shape[1:3] + (H, -1))
+
+
+@jax.named_scope("mla_absorb")
+def latent_absorb_query(q_nope, q_rope, layer: Dict[str, jax.Array],
+                        config: LlamaConfig) -> jax.Array:
+    """The ABSORBED path's queries (decode): ``q~_i = W_UK,i^T q_nope_i``,
+    so that a head's score against a cached row is one dot product of
+    ``[q~_i ; q_rope_i ; 0]`` with the row as it lies.  q_nope (B, H,
+    nope), q_rope (B, H, rope) -> (B, H, latent_row)."""
+    c = config
+    qt = jnp.einsum("bhd,hdc->bhc", q_nope, layer["wk_b"].astype(c.dtype),
+                    preferred_element_type=jnp.float32).astype(c.dtype)
+    pad = c.latent_row - c.kv_lora_rank - c.qk_rope_head_dim
+    return jnp.concatenate(
+        [qt, q_rope, jnp.zeros(q_rope.shape[:2] + (pad,), c.dtype)], -1)
+
+
+@jax.named_scope("mla_absorb")
+def latent_absorb_values(u: jax.Array, layer: Dict[str, jax.Array],
+                         config: LlamaConfig) -> jax.Array:
+    """``o_i = W_UV,i u_i``: u (B, H, kv_lora_rank), a head's
+    probability-weighted sum of the cached ``c_kv`` -> (B, H, v_head_dim)."""
+    return jnp.einsum("bhc,hcd->bhd", u, layer["wv_b"].astype(config.dtype),
+                      preferred_element_type=jnp.float32
+                      ).astype(config.dtype)
+
+
 EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 # A serving prefill longer than this attends through the flash forward:
 # at 4,096 positions one row's (28, S, S) float32 scores are 1.9 GB.
@@ -639,7 +1031,7 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
     B, S, _ = x.shape
     route_x = x if config.moe_router_input == "layer" else None
     with jax.named_scope("attn_out"):
-        x = residual_add(x, matmul(attn.reshape(B, S, config.q_dim),
+        x = residual_add(x, matmul(attn.reshape(B, S, config.o_dim),
                                    layer["wo"].astype(config.dtype)),
                          config)
     return ffn_half(x, layer, config, valid, layer_index, route_x)
@@ -685,11 +1077,13 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     from ray_tpu.parallel.sharding import current_mesh
 
     mcfg = moe.MoEConfig(hidden_size=c.hidden_size,
-                         intermediate_size=c.intermediate_size,
+                         intermediate_size=c.expert_width,
                          n_experts=c.moe_experts, top_k=c.moe_top_k,
                          capacity_factor=c.moe_capacity_factor,
                          norm_topk=c.moe_norm_topk,
-                         activation=c.moe_activation, dtype=dt)
+                         activation=c.moe_activation, dtype=dt,
+                         groups=c.moe_groups, top_groups=c.moe_top_groups,
+                         routed_scale=c.moe_routed_scale, held=c.moe_held)
     moe_params = {k: layer[k] for k in ("router",) + EXPERT_STACKS}
     mesh = current_mesh()
     if mesh is not None and mesh.shape.get("expert", 1) > 1:
@@ -697,13 +1091,60 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
         # constraint lowers to the all-to-all (it has a capacity).
         ff, aux = moe.moe_ffn(h, moe_params, mcfg)
         expert_rows = None
+    elif c.moe_dispatch_chunk and h.shape[1] > c.moe_dispatch_chunk:
+        ff, aux, expert_rows = _dispatch_in_chunks(
+            h, moe_params, mcfg, valid, layer_index, route_x,
+            c.moe_dispatch_chunk)
     else:
         ff, aux, expert_rows = moe.moe_ffn_dropless(
             h, moe_params, mcfg, valid=valid, layer_index=layer_index,
             route_x=route_x)
+    if c.moe_shared_size:
+        # the shared expert: a plain SwiGLU every token passes, added to
+        # what its routed experts gave
+        with jax.named_scope("shared_expert"):
+            shared = jax.nn.silu(matmul(h, layer["ws_gate"].astype(dt))) \
+                * matmul(h, layer["ws_up"].astype(dt))
+            ff = ff + matmul(shared, layer["ws_down"].astype(dt))
     x = residual_add(x, ff, c)
     return with_logical_constraint(x, "batch", "seq", None), aux, \
         expert_rows
+
+
+def _dispatch_in_chunks(h, moe_params, mcfg, valid, layer_index, route_x,
+                        chunk: int):
+    """``moe.moe_ffn_dropless`` over h (B, S, D), at most ``chunk``
+    positions at a time and one chunk after another (a dispatch mixes no
+    positions): ``(ff, aux averaged, expert rows summed)``.  The chunks
+    are equal: the fewest that divide S into whole sublane tiles."""
+    from ray_tpu.models import moe
+
+    B, S, D = h.shape
+    n = next((n for n in range(-(-S // chunk), S // 8 + 1)
+              if S % (8 * n) == 0), None)
+    if n is None:
+        raise ValueError(f"no equal chunks of at most {chunk} positions "
+                         f"divide a prefill of {S}")
+    chunk = S // n
+
+    def chunks(a):
+        return jnp.moveaxis(a.reshape(B, S // chunk, chunk, *a.shape[2:]),
+                            1, 0)
+
+    valid = jnp.ones((B, S), bool) if valid is None \
+        else jnp.broadcast_to(valid, (B, S))
+    xs = (chunks(h), chunks(valid)) + (
+        () if route_x is None else (chunks(route_x),))
+
+    def one(args):
+        return moe.moe_ffn_dropless(
+            args[0], moe_params, mcfg, valid=args[1],
+            layer_index=layer_index,
+            route_x=args[2] if len(args) > 2 else None)
+
+    ff, aux, rows = jax.lax.map(one, xs)
+    return (jnp.moveaxis(ff, 0, 1).reshape(B, S, D), aux.mean(),
+            rows.sum(0))
 
 
 def _attn_out_mlp(x: jax.Array, attn: jax.Array,
@@ -873,14 +1314,17 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     if (c.layers_of("mamba") or c.attention_multiplier is not None
             or c.embedding_multiplier != 1.0 or c.logits_scaling != 1.0
             or c.layers_of("window") or c.nope_kinds
-            or c.moe_router_input != "ffn"):
+            or c.moe_router_input != "ffn" or c.kv_lora_rank
+            or c.rope_scaling is not None or c.first_dense_layers
+            or c.moe_held):
         raise NotImplementedError(
             "llama.forward (training) computes a stack of one kind of "
             "attention layer with the default scale, embedding and "
             "logits, its router after attention: a config with "
             "state-space or window layers, a kind without RoPE, a router "
-            "on the layer's input or the Granite multipliers is served "
-            "only (llama_serve.build_*)")
+            "on the layer's input, the Granite multipliers, latent "
+            "attention, scaled RoPE, leading dense layers or an expert "
+            "share is served only (llama_serve.build_*)")
     if positions is not None and c.attention_impl != "dot":
         # flash/ring mask on raw row index, not positions — packed or
         # offset sequences would silently attend across boundaries.
@@ -1301,7 +1745,11 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     V) and every position is real.  A Mamba layer starts from an empty
     state: only a cold prefill walks one.  A window layer is an attention
     layer whose queries and fresh rows go to ``window_step(q, k, v,
-    positions) -> (attn, ys)`` instead (no cache is walked for one).
+    positions) -> (attn, ys)`` instead (no cache is walked for one).  A
+    model with latent attention attends EXPANDED (``latent_attend_
+    expanded``: ``kv_step`` gets a group of heads' q, k and v and no
+    cache) and its ys are the latent rows.  Leading dense layers are
+    walked first, as a stack of their own (``LlamaConfig.parts``).
 
     Returns ``(logits, ys stacked over the attention layers, expert
     rows, Mamba states, window ys)``: the (L, E) int32 rows each layer's
@@ -1315,56 +1763,90 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
             tokens.shape)
     with jax.named_scope("qkv_proj"):
-        sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
+        sin, cos = rope_table(positions, c.rope_dim, c.rope_theta,
+                              c.rope_scaling)
     if valid is None and lengths is not None:
         valid = positions < lengths[:, None]
-    sliced, stacks = split_expert_stacks(params["layers"], c)
-    plen = c.period_len
+    def walk_part(x, c, layers, kv_layers):
+        """One stack of layers (``c``: that part's config), scanned a
+        period an iteration."""
+        sliced, stacks = split_expert_stacks(layers, c)
+        plen = c.period_len
 
-    def body(x, period_index_cache):
-        period, p, kv_period = period_index_cache
-        kv_ys, ssm_ys, win_ys, rows = [], [], [], []
-        for j, (kind, i, layer) in enumerate(
-                period_layers(sliced, period, p, c)):
-            layer = {**layer, **stacks}
-            if kind in ATTENDING_KINDS:
-                q, k, v = _qkv_rope(x, layer, sin, cos, c, kind)
-                # what a step writes of K/V it scopes ``kv_write`` itself
-                with jax.named_scope("attention"):
-                    if kind == "window":
-                        attn, ys = window_step(q, k, v, positions)
-                        win_ys.append(ys)
-                    else:
-                        attn, ys = kv_step(q, k, v, positions,
-                                           layer_of(kv_period, i, c))
-                        kv_ys.append(ys)
-                x, _aux, rows_j = attn_out_ffn(
-                    x, attn, layer, c, valid=valid,
-                    layer_index=layer_index(p, plen, j))
-            else:
-                from ray_tpu.models import mamba2
+        def body(x, period_index_cache):
+            period, p, kv_period = period_index_cache
+            kv_ys, ssm_ys, win_ys, rows = [], [], [], []
+            for j, (kind, i, layer) in enumerate(
+                    period_layers(sliced, period, p, c)):
+                layer = {**layer, **stacks}
+                if c.kv_lora_rank:
+                    # latent attention, expanded: attended as heads of
+                    # their own keys and values; kept: the latent rows
+                    cq, latent = latent_down(x, layer, sin, cos, c)
+                    attn = latent_attend_expanded(
+                        cq, latent, layer, sin, cos, c,
+                        lambda q, k, v: kv_step(q, k, v, positions,
+                                                None)[0])
+                    kv_ys.append(latent)
+                    x, _aux, rows_j = attn_out_ffn(
+                        x, attn, layer, c, valid=valid,
+                        layer_index=layer_index(p, plen, j))
+                elif kind in ATTENDING_KINDS:
+                    q, k, v = _qkv_rope(x, layer, sin, cos, c, kind)
+                    # what a step writes of K/V it scopes ``kv_write``
+                    # itself
+                    with jax.named_scope("attention"):
+                        if kind == "window":
+                            attn, ys = window_step(q, k, v, positions)
+                            win_ys.append(ys)
+                        else:
+                            attn, ys = kv_step(q, k, v, positions,
+                                               layer_of(kv_period, i, c))
+                            kv_ys.append(ys)
+                    x, _aux, rows_j = attn_out_ffn(
+                        x, attn, layer, c, valid=valid,
+                        layer_index=layer_index(p, plen, j))
+                else:
+                    from ray_tpu.models import mamba2
 
-                with jax.named_scope("ssm_proj"):
-                    h = rms_norm(x, layer["attn_norm"],
-                                 c.norm_eps).astype(c.dtype)
-                out, ys = mamba2.prefill(h, layer, c, lengths)
-                ssm_ys.append(ys)
-                with jax.named_scope("ssm_out"):
-                    x = residual_add(x, out, c)
-                x, _aux, rows_j = ffn_half(
-                    x, layer, c, valid=valid,
-                    layer_index=layer_index(p, plen, j))
-            rows.append(rows_j)
-        return x, (stack_period(kv_ys, c), stack_period(rows, c),
-                   stack_period(ssm_ys, c), stack_period(win_ys, c))
+                    with jax.named_scope("ssm_proj"):
+                        h = rms_norm(x, layer["attn_norm"],
+                                     c.norm_eps).astype(c.dtype)
+                    out, ys = mamba2.prefill(h, layer, c, lengths)
+                    ssm_ys.append(ys)
+                    with jax.named_scope("ssm_out"):
+                        x = residual_add(x, out, c)
+                    x, _aux, rows_j = ffn_half(
+                        x, layer, c, valid=valid,
+                        layer_index=layer_index(p, plen, j))
+                rows.append(rows_j)
+            return x, (stack_period(kv_ys, c), stack_period(rows, c),
+                       stack_period(ssm_ys, c), stack_period(win_ys, c))
 
-    with jax.named_scope("layer_scan"):
-        x, stacked = jax.lax.scan(
-            body, x,
-            (scanned_layers(sliced, c),
-             jnp.arange(c.n_layers // plen, dtype=jnp.int32),
-             by_period(kv_layers, c)))
-        ys, expert_rows, ssm_ys, win_ys = merge_periods(stacked, c)
+        with jax.named_scope("layer_scan"):
+            x, stacked = jax.lax.scan(
+                body, x,
+                (scanned_layers(sliced, c),
+                 jnp.arange(c.n_layers // plen, dtype=jnp.int32),
+                 by_period(kv_layers, c)))
+            return x, merge_periods(stacked, c)
+
+    # The leading dense layers, if the model has them, then the scanned
+    # stack: the same walk over each part's own weights.
+    if not c.first_dense_layers:
+        x, (ys, expert_rows, ssm_ys, win_ys) = walk_part(
+            x, c, params["layers"], kv_layers)
+    else:
+        outs = []
+        for part, key, l0 in c.parts():
+            x, out = walk_part(
+                x, part, params[key],
+                jax.tree.map(lambda a: a[l0:l0 + part.n_layers], kv_layers))
+            outs.append(out)
+        ys = jax.tree.map(lambda *a: jnp.concatenate(a), *(o[0] for o in outs))
+        # a dense part computes no expert's rows; states and window rows
+        # belong to patterns, which have no prologue
+        expert_rows, ssm_ys, win_ys = outs[-1][1], None, None
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.norm_eps).astype(c.dtype)
         if lengths is None:
@@ -1412,7 +1894,9 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
     last real position, None for a model without Mamba layers; the
     window layers' ``(ks, vs)`` at every position of the prompt (which
     of them a cache keeps is the cache's business), None without such
-    layers.
+    layers.  A model with latent attention attends the EXPANDED form and
+    returns its latent rows ``(L, G, P, latent_row)`` as ``ks``, None as
+    ``vs``.
 
     Attention is the masked einsum while its (G, Hq, P, P) float32
     scores are small, and the flash forward (``ops/flash_attention.py``,
@@ -1422,9 +1906,13 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
     if tokens.shape[1] > FLASH_PREFILL_FROM:  # raylint: disable=recompile-hazard -- the engine's prefill shapes are its buckets, each warmed once; which attention a bucket takes is fixed with its shape
         from ray_tpu.ops.flash_attention import flash_prefill_attention
 
+        # A latent model's call writes no softmax statistics: 128 heads'
+        # width-1 ``lse`` is laid out 128 lanes wide, 768 MB at 12,288
+        # positions that only a backward pass reads.
         def attend(q, k, v, positions, window):
             return flash_prefill_attention(q, k, v, scale=scale,
-                                           window=window)
+                                           window=window,
+                                           lse=not config.kv_lora_rank)
     else:
         def attend(q, k, v, positions, window):
             return dot_attention(q, k, v, positions, scale, window)
@@ -1435,9 +1923,11 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
     def window_step(q, k, v, positions):
         return attend(q, k, v, positions, config.window_size), (k, v)
 
-    last_logits, (ks, vs), expert_rows, states, window = layer_walk(
+    last_logits, ys, expert_rows, states, window = layer_walk(
         params, tokens, config, kv_step, lengths=lengths,
         window_step=window_step)
+    # latent attention keeps ONE leaf: its rows come back as ``ks``
+    ks, vs = (ys, None) if config.kv_lora_rank else ys
     return last_logits, ks, vs, expert_rows, states, window
 
 
@@ -1484,11 +1974,12 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
     at those positions and returns (logits (B, T, V), new_cache).  No
     program that serves runs it; tests hold T > 1 through a cache to the
     reference with it."""
-    if config.layers_of("mamba") or config.layers_of("window"):
+    if (config.layers_of("mamba") or config.layers_of("window")
+            or config.kv_lora_rank):
         raise NotImplementedError(
             "forward_with_cache holds one K/V stack alone; a config with "
-            "state-space or window layers runs through "
-            "llama_serve.build_prefill / build_decode_k")
+            "state-space or window layers or latent attention runs "
+            "through llama_serve.build_prefill / build_decode_k")
     scale = config.attn_scale
 
     # The T new K/V rows go into each slot's cache at its own positions

@@ -9,7 +9,9 @@ engine, thread or weights are needed to lower one.  By the cache kept:
   the attention layers and, for a model with Mamba-2 layers, each slot's
   recurrent and conv states beside them; for a model with window layers
   a pool of every position for its full layers and a ring of the last
-  ``window_size`` for its window layers): ``prefill``, ``decode_k``;
+  ``window_size`` for its window layers; for a model with latent
+  attention ONE leaf of a latent row a token and layer in place of K and
+  V): ``prefill``, ``decode_k``;
 - a block pool ``(N, L, bs, Hkv, D)`` (``llama.init_paged_kv_cache``):
   ``prefill_cold``, ``prefill_warm``, ``decode_paged``, ``inject`` (and
   its inverse ``BlockPool.extract``), ``spec_verify``;
@@ -31,6 +33,7 @@ readers find them by that name.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -39,19 +42,27 @@ import jax.numpy as jnp
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.decode_attention import decode_attention
+from ray_tpu.ops.mla_decode_attention import mla_decode_attention
 
 
 @jax.named_scope("expert_dispatch")
-def _expert_load(expert_rows):
+def _expert_load(expert_rows, held: bool = False):
     """What a device program hands back about its experts, read at the
     harvest that exists: ``expert_rows`` (..., L, E) int32, the rows each
     layer's experts computed, per step of a chunk or for a prefill group
     -> (the (L, E) histogram summed over the steps, the number of
     (step, layer, expert) triples that had a row).  ``()`` for a dense
-    model, whose programs return nothing more than they did."""
+    model, whose programs return nothing more than they did.  ``held``: a
+    model that holds a share of its experts, whose rows end with the
+    count routed to experts elsewhere (``moe.moe_ffn_dropless``); that
+    count, summed, is then a third result and E the experts held."""
     if expert_rows is None:
         return ()
     rows = expert_rows.reshape((-1,) + expert_rows.shape[-2:])
+    if held:
+        rows, elsewhere = rows[..., :-1], rows[..., -1]
+        return (rows.sum(0), jnp.sum(rows > 0, dtype=jnp.int32),
+                elsewhere.sum())
     return (rows.sum(0), jnp.sum(rows > 0, dtype=jnp.int32))
 
 
@@ -68,6 +79,12 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     slot's whole state, ``decode_step`` advances it in place."""
     if cfg.layers_of("window"):
         return _init_window_cache(cfg, slots, max_len)
+    if cfg.kv_lora_rank:
+        # Latent attention keeps ONE leaf: a row of ``latent_row`` values a
+        # token and layer (``c_kv`` normed, ``k_rope`` roped, zeros to
+        # whole lanes) in place of K and V a head.
+        return {"latent": jnp.zeros(
+            (cfg.n_layers, slots, max_len, cfg.latent_row), cfg.dtype)}
     cache = llama.init_kv_cache(cfg, slots, max_len)
     if cfg.layers_of("mamba"):
         from ray_tpu.models import mamba2
@@ -103,7 +120,7 @@ def cache_pools(cfg: LlamaConfig, slots: int, max_len: int):
     """``{pool: (bytes, storage type)}`` of ``init_cache``'s tree: ``kv``
     (K and V together) and, for a model with Mamba layers, ``ssm`` and
     ``conv``; for a model with window layers ``kv_full`` and
-    ``kv_window``."""
+    ``kv_window``; for a model with latent attention ``latent`` alone."""
     shapes = jax.eval_shape(lambda: init_cache(cfg, slots, max_len))
     pool_of = {"k": "kv_full" if "wk" in shapes else "kv",
                "wk": "kv_window"}
@@ -121,7 +138,8 @@ def state_bytes_per_slot(cfg: LlamaConfig):
     all Mamba layers ({} for a model without them): what a decode step
     reads and writes for a slot it advances."""
     return {pool: nbytes for pool, (nbytes, _) in
-            cache_pools(cfg, 1, 1).items() if not pool.startswith("kv")}
+            cache_pools(cfg, 1, 1).items()
+            if not pool.startswith("kv") and pool != "latent"}
 
 
 @jax.named_scope("kv_write")
@@ -182,12 +200,18 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     ``length mod ring`` and attends its first ``min(length + 1, ring)``
     ring rows through the same kernel.
 
+    A model with latent attention carries its one leaf where K lies (no
+    V): a layer writes the new latent row and attends ABSORBED, every
+    head's ``[q~ ; q_rope]`` against the rows as they lie
+    (``ops/mla_decode_attention.py``, one call a layer).  Leading dense
+    layers (``LlamaConfig.parts``) run as a scan of their own before the
+    scanned stack, through the same body.
+
     This step is ``llama.layer_walk`` written out, its K/V the carry of
     the layer scan: the walk, handed a carry, compiled to the same sizes
     but not to the same text as the program the benchmark's cells have
     measured since PR 24 (this step has cliffs: PERF.md section 6)."""
 
-    sliced, stacks = llama.split_expert_stacks(params["layers"], cfg)
     plen = cfg.period_len
     n_attn, n_ssm = cfg.period.count("attention"), cfg.period.count("mamba")
     n_win, hkv = cfg.period.count("window"), cfg.n_kv_heads
@@ -196,8 +220,8 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         ck, cv, tok, lens, *state = carry
         x = llama.embed(params, tok, cfg)[:, None]
         with jax.named_scope("qkv_proj"):
-            sin, cos = llama.rope_table(lens[:, None], cfg.head_dim,
-                                        cfg.rope_theta)
+            sin, cos = llama.rope_table(lens[:, None], cfg.rope_dim,
+                                        cfg.rope_theta, cfg.rope_scaling)
         # Inactive slots MUST not write: an occupied slot that is not
         # in this launch (LLMServer.slot_waiting) may hold a
         # prefill's fresh rows, and a stale-position
@@ -216,20 +240,45 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                 at["window"] = jnp.where(pos < ck.shape[2], lens % ring,
                                          ring)
 
-        def body(carry, period_and_index):
+        # ``part``: the stack this body walks (the leading dense layers or
+        # the scanned stack, ``LlamaConfig.parts``), as a plain config;
+        # ``l0``: its first layer's index in the cache.
+        def body(carry, period_and_index, part, sliced, stacks, l0):
             x, ck, cv, *state = carry
             period, p = period_and_index
             expert_rows = []
             for j, (kind, i, layer) in enumerate(
-                    llama.period_layers(sliced, period, p, cfg)):
+                    llama.period_layers(sliced, period, p, part)):
                 layer = {**layer, **stacks}
-                if kind != "mamba":
+                if part.kv_lora_rank:
+                    # Latent attention, ABSORBED: the row written, then
+                    # every head's [q~ ; q_rope] against the rows as they
+                    # lie, read once for scores and values both.
+                    cq, latent = llama.latent_down(x, layer, sin, cos, part)
+                    q_nope, q_rope = llama.latent_queries(
+                        cq, llama._wq_b_heads(layer, part), sin, cos, part)
+                    l = p + l0
+                    ck = _write(ck, l, rows, pos, latent[:, 0, None])
+                    q = llama.latent_absorb_query(
+                        q_nope[:, 0], q_rope[:, 0], layer, part)
+                    with jax.named_scope("attention"):
+                        u = mla_decode_attention(
+                            q, ck, l, lens, active, s_active=s_active,
+                            scale=scale, v_width=part.kv_lora_rank)
+                    attn = llama.latent_absorb_values(u, layer,
+                                                      part)[:, None]
+                    x, _aux, rows_j = llama.attn_out_ffn(
+                        x, attn, layer, part, valid=active[:, None],
+                        layer_index=llama.layer_index(p, plen, j))
+                elif kind != "mamba":
                     # The layer's pool: the carry's K/V or, for a window
                     # layer, the rings after the lengths.
                     ringed = kind == "window"
                     pk, pv = state if ringed else (ck, cv)
                     l = llama.layer_index(p, n_win if ringed else n_attn, i)
-                    q, kk, vv = llama._qkv_rope(x, layer, sin, cos, cfg,
+                    if l0:
+                        l = l + l0
+                    q, kk, vv = llama._qkv_rope(x, layer, sin, cos, part,
                                                 kind)
                     # Write before attend: the new row is among the keys.
                     pk = _write(pk, l, rows, at[kind], kk[:, 0])
@@ -247,7 +296,7 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                     else:
                         ck, cv = pk, pv
                     x, _aux, rows_j = llama.attn_out_ffn(
-                        x, attn, layer, cfg, valid=active[:, None],
+                        x, attn, layer, part, valid=active[:, None],
                         layer_index=llama.layer_index(p, plen, j))
                 else:
                     from ray_tpu.models import mamba2
@@ -265,13 +314,20 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                         layer_index=llama.layer_index(p, plen, j))
                 expert_rows.append(rows_j)
             return (x, ck, cv, *state), llama.stack_period(expert_rows,
-                                                           cfg)
+                                                           part)
 
+        # The leading dense layers, if the model has them, then the
+        # scanned stack: the same body over each part's own weights (a
+        # dense part computes no expert's rows).
         with jax.named_scope("layer_scan"):
-            (x, ck, cv, *state), expert_rows = jax.lax.scan(
-                body, (x, ck, cv, *state),
-                (llama.scanned_layers(sliced, cfg),
-                 jnp.arange(cfg.n_layers // plen, dtype=jnp.int32)))
+            for part, key, l0 in cfg.parts():
+                sliced, stacks = llama.split_expert_stacks(params[key], part)
+                (x, ck, cv, *state), expert_rows = jax.lax.scan(
+                    functools.partial(body, part=part, sliced=sliced,
+                                      stacks=stacks, l0=l0),
+                    (x, ck, cv, *state),
+                    (llama.scanned_layers(sliced, part),
+                     jnp.arange(part.n_layers // plen, dtype=jnp.int32)))
             expert_rows = llama.merge_periods(expert_rows, cfg)
         with jax.named_scope("head"):
             x = llama.rms_norm(x, params["final_norm"],
@@ -310,6 +366,8 @@ def _carry(cache, tok, lens):
     """A cache tree as ``decode_step``'s carry: K, V, the tokens, the
     lengths, then the Mamba states or the window rings if the model has
     them."""
+    if "latent" in cache:       # the one leaf, where K lies; no V
+        return (cache["latent"], None, tok, lens)
     return (cache["k"], cache["v"], tok, lens,
             *(cache[name] for names in _CARRIED for name in names
               if name in cache))
@@ -318,6 +376,8 @@ def _carry(cache, tok, lens):
 def _uncarry(carry, cache):
     """The carry as a cache tree with ``cache``'s leaves."""
     ck, cv, tok, lens, *state = carry
+    if "latent" in cache:
+        return {"latent": ck}, tok, lens
     names = next((names for names in _CARRIED if names[0] in cache), ())
     return {"k": ck, "v": cv, **dict(zip(names, state))}, tok, lens
 
@@ -372,7 +432,9 @@ def build_prefill(cfg: LlamaConfig) -> Callable:
     def prefill(params, cache, tokens, lengths, slots):
         last_logits, ks, vs, rows, states, window = \
             llama.prefill_with_states(params, tokens, lengths, cfg)
-        if window is not None:
+        if cfg.kv_lora_rank:
+            cache = {"latent": _insert_rows(cache["latent"], ks, slots)}
+        elif window is not None:
             ring = cache["wk"].shape[2] // cfg.n_kv_heads
             cache = {
                 "k": _insert_rows(cache["k"], ks, slots),
@@ -388,7 +450,7 @@ def build_prefill(cfg: LlamaConfig) -> Callable:
             cache = insert_states(cache, states, slots)
         with jax.named_scope("sample"):
             first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-        return cache, first, _expert_load(rows)
+        return cache, first, _expert_load(rows, bool(cfg.moe_held))
 
     return jax.jit(prefill, donate_argnums=(1,))
 
@@ -405,7 +467,8 @@ def build_decode_k(cfg: LlamaConfig) -> Callable:
             carry, (toks, rows) = jax.lax.scan(
                 step, _carry(cache, tok, lens), None, length=k)
         cache, tok, lens = _uncarry(carry, cache)
-        return cache, toks, tok, lens, _expert_load(rows)
+        return cache, toks, tok, lens, _expert_load(rows,
+                                                    bool(cfg.moe_held))
 
     # tok_dev/len_dev (args 2, 3) are always overwritten by the
     # returned carries at every call site: donate them too.

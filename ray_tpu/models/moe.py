@@ -51,6 +51,17 @@ class MoEConfig:
     # The experts' gate activation: "silu" (SwiGLU) or "relu" (ReGLU).
     activation: str = "silu"
     dtype: Any = jnp.bfloat16
+    # Group-limited routing (DeepSeek-V2's group_limited_greedy): the
+    # experts are ``groups`` groups of consecutive ones, a group scores
+    # its best expert, and only the ``top_groups`` best groups' experts
+    # can be chosen (0: no groups).  The chosen gates x ``routed_scale``.
+    groups: int = 0
+    top_groups: int = 0
+    routed_scale: float = 1.0
+    # ``(first, count)``: the experts whose matrices are HERE (one chip's
+    # share; the stacks are ``[count, ...]``), of the ``n_experts`` the
+    # router scores.  (): all of them.
+    held: Tuple[int, ...] = ()
 
     @property
     def act(self):
@@ -94,17 +105,32 @@ def _einsum(eq, *args):
 
 
 @jax.named_scope("router")
-def _route(xt: jax.Array, router: jax.Array, k: int, norm_topk: bool):
+def _route(xt: jax.Array, router: jax.Array, k: int, norm_topk: bool,
+           groups: int = 0, top_groups: int = 0, scale: float = 1.0):
     """The one routing function (dropless, dense dispatch and the parity
     reference cannot drift): f32 softmax over all experts, top-k, the k
-    gates renormalised to sum to one or left as they are."""
+    gates renormalised to sum to one or left as they are, then x
+    ``scale``.  With ``groups`` the top-k is taken among the experts of
+    the ``top_groups`` groups whose best expert scores highest; the
+    others' probabilities count as 0."""
     logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
                         router.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)          # (T, E)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)  # (T, K)
+    choose_from = probs
+    if groups:
+        T, E = probs.shape
+        best = probs.reshape(T, groups, E // groups).max(-1)   # (T, G)
+        _, kept = jax.lax.top_k(best, top_groups)
+        in_kept = jnp.zeros((T, groups), bool).at[
+            jnp.arange(T)[:, None], kept].set(True)
+        choose_from = jnp.where(jnp.repeat(in_kept, E // groups, axis=1),
+                                probs, 0.0)
+    gate_vals, expert_idx = jax.lax.top_k(choose_from, k)  # (T, K)
     if norm_topk:
         gate_vals = gate_vals / jnp.maximum(
             gate_vals.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gate_vals = gate_vals * scale
     return probs, gate_vals, expert_idx
 
 
@@ -125,6 +151,13 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     """x: (B, S, D) → (out (B, S, D), aux_loss scalar, expert_rows (E,)
     int32: the rows each expert computed).
 
+    With ``config.held`` = (first, count) the expert matrices are those of
+    experts ``first .. first + count`` alone and the result is THEIR part
+    of the layer's: a token's assignments to experts elsewhere are dropped
+    before the sort, so the grouped matmuls' rows are the held experts'
+    rows; ``expert_rows`` is then (count + 1,): the held experts' rows
+    and, LAST, the count of real assignments routed elsewhere.
+
     ``valid`` (broadcastable to (B, S), bool): rows that are real.  The
     others (prompt padding, inactive decode slots) get a zero and are
     assigned to no expert, so the grouped matmuls do not compute them
@@ -144,17 +177,30 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     c = config
     B, S, D = x.shape
     T = B * S
-    E, K = c.n_experts, c.top_k
+    K = c.top_k
+    # the groups of the grouped matmuls: the experts held here
+    first, E = c.held or (0, c.n_experts)
     dt = c.dtype
     xt = x.reshape(T, D).astype(dt)
 
     probs, gate_vals, expert_idx = _route(
         xt if route_x is None else route_x.reshape(T, D),
-        params["router"], K, c.norm_topk)
+        params["router"], K, c.norm_topk, c.groups, c.top_groups,
+        c.routed_scale)
     with jax.named_scope("expert_dispatch"):
         flat = expert_idx.reshape(T * K)
         if valid is not None:
             valid = jnp.broadcast_to(valid, (B, S)).reshape(T)
+        if c.held:
+            # An assignment to an expert elsewhere is dropped BEFORE the
+            # sort: behind every group, like a row that is not real, and
+            # counted apart.
+            flat = flat - first
+            here = (flat >= 0) & (flat < E)
+            real = True if valid is None else jnp.repeat(valid, K)
+            elsewhere = jnp.sum(real & ~here, dtype=jnp.int32)
+            flat = jnp.where(here, flat, E)
+        if valid is not None:
             # behind every group: sorted last, counted by no expert
             flat = jnp.where(jnp.repeat(valid, K), flat, E)
         order = jnp.argsort(flat)        # stable: a token's K stay in order
@@ -174,24 +220,39 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
             w_gate, w_up, w_down = (w.reshape((L * E,) + w.shape[2:])
                                     for w in (w_gate, w_up, w_down))
 
-    def grouped(rows, w):
+    def grouped(rows, w, out_dtype=jnp.float32):
         # f32 accumulation, as llama.matmul
         return jax.lax.ragged_dot(rows, w, group_sizes,
-                                  preferred_element_type=jnp.float32)
+                                  preferred_element_type=out_dtype)
 
     with jax.named_scope("expert_dispatch"):
         rows = xt[order // K]                              # (T*K, D)
     with jax.named_scope("expert_ffn"):
         act = c.act(grouped(rows, w_gate).astype(dt)) \
             * grouped(rows, w_up).astype(dt)
-        out = grouped(act, w_down)                         # (T*K, D) f32
+        # (T*K, D) float32; a share's in ``dt``: three in four of its rows
+        # are not computed, and at a 12,288-token prompt's 73,728 rows of
+        # 5,120 the float32 result and its un-sorted copy are 2.8 GB
+        out = grouped(act, w_down, dt if c.held else jnp.float32)
     # Un-sort (order is a permutation) and sum under the gates.  Rows
     # past the last group were not computed: select, do not multiply.
     with jax.named_scope("expert_dispatch"):
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(T * K, dtype=order.dtype), unique_indices=True)
-        out = out[inverse].reshape(T, K, D)
-        out = jnp.sum(out * gate_vals[..., None], axis=1)
+        if c.held:
+            # A token's K results side by side, (T, K x D): as (T, K, D)
+            # the chip pads K = 6 to a sublane tile.  An assignment
+            # elsewhere was not computed: select.
+            out = out[inverse].reshape(T, K * D)
+            computed = (flat < E).reshape(T, K)
+            out = sum(
+                jnp.where(computed[:, k, None], out[:, k * D:(k + 1) * D],
+                          0).astype(jnp.float32) * gate_vals[:, k, None]
+                for k in range(K))
+            expert_rows = jnp.concatenate([expert_rows, elsewhere[None]])
+        else:
+            out = out[inverse].reshape(T, K, D)
+            out = jnp.sum(out * gate_vals[..., None], axis=1)
         if valid is not None:
             out = jnp.where(valid[:, None], out, 0.0)
         out = out.reshape(B, S, D).astype(x.dtype)
@@ -212,6 +273,11 @@ def moe_ffn(x: jax.Array, params: PyTree, config: MoEConfig
     tokens per expert × mean router prob per expert × E); add it to
     the training loss scaled by ~1e-2."""
     c = config
+    if c.held:
+        raise NotImplementedError(
+            "moe_ffn (dense dispatch under an expert mesh axis) holds "
+            "every expert: a share of the experts (held) runs through "
+            "moe_ffn_dropless")
     B, S, D = x.shape
     T = B * S
     E, K = c.n_experts, c.top_k
@@ -219,7 +285,8 @@ def moe_ffn(x: jax.Array, params: PyTree, config: MoEConfig
     xt = x.reshape(T, D).astype(dt)
 
     probs, gate_vals, expert_idx = _route(xt, params["router"], K,
-                                          c.norm_topk)
+                                          c.norm_topk, c.groups,
+                                          c.top_groups, c.routed_scale)
 
     capacity = int(max(1, round(T * K / E * c.capacity_factor)))
 
@@ -274,7 +341,8 @@ def moe_ffn_reference(x: jax.Array, params: PyTree, config: MoEConfig
     dt = c.dtype
     xt = x.reshape(-1, D).astype(dt)
     _probs, gate_vals, expert_idx = _route(xt, params["router"],
-                                           c.top_k, c.norm_topk)
+                                           c.top_k, c.norm_topk, c.groups,
+                                           c.top_groups, c.routed_scale)
 
     def per_expert(e):
         h = xt.astype(dt) @ params["w_gate"][e].astype(dt)

@@ -519,7 +519,8 @@ SCOPES = (
     "attention", "flash_attention.fwd", "flash_attention.dq",
     "flash_attention.dkdv", "decode_attention",
     "kv_write", "attn_out", "ffn",
-    "router", "expert_dispatch", "expert_ffn",
+    "router", "expert_dispatch", "expert_ffn", "shared_expert",
+    "mla_absorb", "mla_expand", "mla_decode_attention",
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_state_update", "ssm_out",
     "head", "sample", "head_loss", "optimizer",
 )

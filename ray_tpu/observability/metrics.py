@@ -463,6 +463,15 @@ def serve_engine_counters():
             "(token, expert) assignments the grouped matmuls computed, "
             "summed over layers; padding and inactive rows take none",
             tag_keys=("deployment", "program")),
+        # A model that holds a share of its experts only (one chip of
+        # those that share a layer): a series of its own, since a label on
+        # ``moe_expert_rows`` would re-key every other model's series.
+        "moe_expert_rows_elsewhere": Counter(
+            "ray_tpu_serve_moe_expert_rows_elsewhere_total",
+            "(token, expert) assignments the router gave to experts that "
+            "are NOT held here (another chip's share), summed over "
+            "layers: left out of this chip's part of the layer's result",
+            tag_keys=("deployment", "program")),
         "moe_experts_touched": Counter(
             "ray_tpu_serve_moe_experts_touched_total",
             "(step, layer, expert) triples in which the expert had a "

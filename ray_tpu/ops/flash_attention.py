@@ -261,19 +261,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0, 0, :, :] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
         # lse output is width-1 (not lane-replicated): a (B,H,S,LANES)
         # f32 lse is 134 MB/layer of pure HBM traffic at bench shapes.
-        lse = jnp.where(l > 0.0,
-                        m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-37)),
-                        NEG_INF)
-        lse_ref[0, 0, :, :] = lse
+        if lse_ref is not None:
+            lse = jnp.where(l > 0.0,
+                            m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-37)),
+                            NEG_INF)
+            lse_ref[0, 0, :, :] = lse
 
 
 def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
-         name="flash_attention_fwd"):
-    """q: (B, Hq, Sq, D) pre-scaled; k/v: (B, Hkv, Sk, D).
-    Returns o (B, Hq, Sq, D), lse (B, Hq, Sq, 1) f32.  ``window`` (causal
+         name="flash_attention_fwd", with_lse=True):
+    """q: (B, Hq, Sq, D) pre-scaled; k: (B, Hkv, Sk, D); v: (B, Hkv, Sk,
+    Dv), a head of its own width where the model's values have one
+    (latent attention: 192-wide q/k, 128-wide v).
+    Returns o (B, Hq, Sq, Dv), lse (B, Hq, Sq, 1) f32 (None without
+    ``with_lse``: a forward nothing differentiates).  ``window`` (causal
     only): a query sees its last ``window`` keys, its own among them."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
+    Dv = v.shape[-1]
     group = Hq // Hkv
     bq, bk = _block_sizes(Sq, Sk, block_q, block_k)
     nq, nk = Sq // bq, Sk // bk
@@ -302,6 +307,17 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
                                nk=nk, causal=causal,
                                **({} if window is None
                                   else {"window": window}))
+    out_specs = [pl.BlockSpec((1, 1, bq, Dv), o_map),
+                 pl.BlockSpec((1, 1, bq, 1), o_map)]
+    out_shape = [jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
+                 jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32)]
+    if not with_lse:
+        with_stats = kernel
+
+        def kernel(q_ref, k_ref, v_ref, o_ref, *scratch):
+            with_stats(q_ref, k_ref, v_ref, o_ref, None, *scratch)
+
+        out_specs, out_shape = out_specs[:1], out_shape[:1]
     fwd = pl.pallas_call(
         kernel,
         name=name,
@@ -309,20 +325,14 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), q_map),
             pl.BlockSpec((1, 1, bk, D), kv_map),
-            pl.BlockSpec((1, 1, bk, D), kv_map),
+            pl.BlockSpec((1, 1, bk, Dv), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, D), o_map),
-            pl.BlockSpec((1, 1, bq, 1), o_map),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -334,8 +344,8 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     # name is pushed as that); this scope lands in the HLO's ``op_name``
     # metadata only (PERF.md section 3).
     with jax.named_scope("flash_attention.fwd"):
-        o, lse = fwd(q, k, v)
-    return o, lse
+        o, *lse = fwd(q, k, v)
+    return o, (lse[0] if lse else None)
 
 
 # ---------------------------------------------------------------------------
@@ -661,25 +671,32 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                            scale: float,
-                            window: Optional[int] = None) -> jax.Array:
+                            scale: float, window: Optional[int] = None,
+                            lse: bool = True) -> jax.Array:
     """The forward alone, for a serving prefill: causal, a query seeing
     its last ``window`` keys where one is given (tiles outside the band
     are neither fetched nor computed).  q: (B, S, Hq, D); k/v: (B, S,
-    Hkv, D), positions 0..S-1; ``scale`` multiplies the scores.  The
+    Hkv, D), or v of a head width of its own (B, S, Hkv, Dv), positions
+    0..S-1; ``scale`` multiplies the scores; ``lse=False`` leaves the
+    softmax statistics, which only a backward pass reads, unwritten.  The
     device trace shows the kernel under this function's name."""
     B, S, Hq, D = q.shape
     if Hq % k.shape[2]:
         raise ValueError(f"Hq={Hq} not a multiple of Hkv={k.shape[2]}")
     interpret = _use_interpret()
-    if not interpret and not _supported(S, S, D):
+    # The forward alone also takes a head that is whole sublane tiles but
+    # not whole lanes (latent attention's 192-wide q/k beside a 128-wide
+    # v): Mosaic lays it out as 256 lanes (AOT for a v5e, PR 37).
+    tiles = _supported(S, S, LANES if D % 64 == 0 else D)
+    if not interpret and not tiles:
         raise ValueError(f"flash_prefill_attention cannot tile S={S}, "
                          f"D={D} on TPU")
     qt = jnp.transpose(q, (0, 2, 1, 3)) * jnp.asarray(scale, q.dtype)
     o, _lse = _fwd(qt, jnp.transpose(k, (0, 2, 1, 3)),
                    jnp.transpose(v, (0, 2, 1, 3)), causal=True,
                    block_q=None, block_k=None, interpret=interpret,
-                   window=window, name="flash_prefill_attention")
+                   window=window, name="flash_prefill_attention",
+                   with_lse=lse)
     return jnp.transpose(o, (0, 2, 1, 3))
 
 

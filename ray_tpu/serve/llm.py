@@ -365,6 +365,12 @@ class LLMServer:
                 f"prefix outlives no ring, and a rejected draft's rows "
                 f"have overwritten what they would rewind to.  "
                 f"Refused: {'; '.join(asked)}")
+        if asked and self.cfg.kv_lora_rank:
+            raise ValueError(
+                f"{model_preset} has latent attention, whose cache is "
+                f"one latent row a token and layer read by a kernel of "
+                f"its own: no block pool, draft or K/V handoff holds "
+                f"such rows.  Refused: {'; '.join(asked)}")
         self.max_slots = max_slots
         self.max_len = max_len
         self.buckets = tuple(sorted(b for b in prefill_buckets
@@ -441,7 +447,11 @@ class LLMServer:
             self._ring = llama_serve.ring_len(self.cfg, max_len)
             self._pool_layers = (self.cfg.layers_of("attention"),
                                  self.cfg.layers_of("window"))
-        if self._state_bytes or self._ring:
+        # A model with latent attention: the bytes a position's latent
+        # rows hold over all layers, as stored (0 for any other).
+        self._latent_bytes = llama_serve.cache_pools(
+            self.cfg, 1, 1).get("latent", (0,))[0]
+        if self._state_bytes or self._ring or self._latent_bytes:
             self._publish_state_pool()
         self._jnp = jnp
         # Device-resident carries between chunk launches, and the
@@ -533,7 +543,8 @@ class LLMServer:
         """The dense cache of a model with state-space or window layers,
         by what it holds: K/V under ``ray_tpu_kv_pool_bytes`` (a model
         with window layers: ``<deployment>.kv_full`` and
-        ``<deployment>.kv_window``), the recurrent and conv states under
+        ``<deployment>.kv_window``; one with latent attention:
+        ``<deployment>.latent``), the recurrent and conv states under
         ``ray_tpu_state_pool_bytes``."""
         from ray_tpu.models import llama_serve
 
@@ -544,7 +555,7 @@ class LLMServer:
             if pool == "kv":
                 self._kv_metrics["pool_bytes"].set(
                     nbytes, tags={"pool": name, "dtype": dtype})
-            elif pool.startswith("kv_"):
+            elif pool.startswith("kv_") or pool == "latent":
                 self._kv_metrics["pool_bytes"].set(
                     nbytes, tags={"pool": f"{name}.{pool}", "dtype": dtype})
             else:
@@ -1726,6 +1737,12 @@ class LLMServer:
                  "experts_touched": int(load[1])}
         m = self._engine_metrics
         tags = {**self._tags, "program": program}
+        if len(load) > 2:
+            # a share of the experts: the rows routed to experts elsewhere
+            # beside the held experts' own
+            attrs["expert_rows_elsewhere"] = int(load[2])
+            m["moe_expert_rows_elsewhere"].inc(
+                attrs["expert_rows_elsewhere"], tags=tags)
         m["moe_expert_rows"].inc(attrs["expert_rows"], tags=tags)
         m["moe_experts_touched"].inc(attrs["experts_touched"], tags=tags)
         if attrs["expert_rows"]:
@@ -1762,6 +1779,8 @@ class LLMServer:
             "kv_positions_attended": attended,
             "kv_positions_bucket": bucket,
             **self._window_attrs(attended, ringed, s_active),
+            **({"latent_bytes": attended * self._latent_bytes}
+               if self._latent_bytes else {}),
             **self._state_attrs(k * active),
             **self._expert_attrs(load, "decode")},
             f"{self._lane}/chunks")
@@ -1999,12 +2018,16 @@ class LLMServer:
         out = {k: v for k, v in metrics_summary().items()
                if k.startswith(("ray_tpu_kv_", "ray_tpu_prefix_",
                                 "ray_tpu_spec_", "ray_tpu_state_"))}
-        if self._ring:
+        if self._ring or self._latent_bytes:
             out["kv_pools"] = {
                 pool: {"bytes": nbytes, "dtype": dtype,
                        "bytes_per_slot": nbytes // self.max_slots}
                 for pool, (nbytes, dtype) in self._pools.items()}
+        if self._ring:
             out["kv_pools"]["kv_window"]["ring_positions"] = self._ring
+        if self._latent_bytes:
+            out["kv_pools"]["latent"]["bytes_per_position"] = \
+                self._latent_bytes
         if self._state_bytes:
             out["state_pool"] = {
                 **{f"{pool}_bytes": nbytes
