@@ -424,6 +424,13 @@ def serve_engine_counters():
             "decode-chunk token-steps computed (chunk length x "
             "max_slots per chunk); kept / computed = slot utilization",
             tag_keys=("deployment",)),
+        "slots_released_early": Counter(
+            "ray_tpu_serve_slots_released_early_total",
+            "decode-chunk rows in a slot handed to the next request "
+            "before its last tenant's final chunk was processed (the "
+            "host knew the end from the lengths it holds); ~1 per "
+            "finished request while requests wait for slots",
+            tag_keys=("deployment",)),
         "decode_kv_positions_attended": Counter(
             "ray_tpu_serve_decode_kv_positions_attended_total",
             "cache positions the live rows held when a decode chunk was "

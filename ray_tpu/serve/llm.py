@@ -57,7 +57,12 @@ whose per-call host↔device round trip is tens of milliseconds:
   its first tokens (still a device array) and its rows' lengths are
   scattered into those carries at the group's slots
   (``llama_serve.build_seat``), so the rows decode in the very next
-  chunk, before the host has read a first token.
+  chunk, before the host has read a first token.  And a slot is VACATED
+  before the host has read its tenant's last token: there is no stop
+  token, so the lengths the host holds say when a tenant must end inside
+  the chunk in flight (``_ends_by``), and at that boundary the slot goes
+  to the next request (``_release_ending``): no chunk decodes a
+  finished tenant.
 - PREFILL/DECODE DISAGGREGATION: ``role="prefill"`` replicas compute
   KV blocks and first tokens, then hand the blocks to a
   ``role="decode"`` peer (same-host: shm channel ring; cross-host:
@@ -157,7 +162,7 @@ class _Request:
                  "on_done", "deadline", "arrival", "want_kv", "kv",
                  "preseed", "rid", "trace", "t_seen", "t_admitted",
                  "t_prefill_launched", "prefill_shape", "harvests",
-                 "t_done", "outcome", "preemptions", "slot")
+                 "t_done", "outcome", "preemptions", "slot", "released")
 
     _arrival_counter = 0
     _arrival_lock = threading.Lock()
@@ -190,6 +195,10 @@ class _Request:
         self.outcome: Optional[str] = None   # ok | shed | error
         self.preemptions = 0
         self.slot: Optional[int] = None
+        # Its slot went to the next request before its last chunk was
+        # processed (LLMServer._release_ending): that chunk's tokens are
+        # still its own, unlike a preempted request's.
+        self.released = False
         self.error: Optional[BaseException] = None
         self.done = False
         # Completion callback (asyncio wakeup) fired after event.set —
@@ -468,6 +477,12 @@ class LLMServer:
         # Prefill results pending first-token extraction:
         # (first_tokens_devicearray, [(group_index, slot, req)], t0).
         self._pending_prefills: List[tuple] = []
+        # (slot, request, length at launch) of the rows of the decode
+        # chunk launched last, and the requests released from their slots
+        # at the last boundary (_release_ending), until that chunk is
+        # processed.
+        self._in_flight_rows: List[tuple] = []
+        self._ending: List[_Request] = []
         # Rate estimators feeding the feasibility shed (EMA seconds).
         self._chunk_ema: Optional[float] = None
         self._prefill_ema: Optional[float] = None
@@ -1243,7 +1258,10 @@ class LLMServer:
             np.asarray(table.blocks[:n], np.int32)))
         req.kv = (np.asarray(kb), np.asarray(vb))
 
-    def _finish(self, slot: int):
+    def _vacate(self, slot: int) -> Optional[_Request]:
+        """``slot`` stops being its tenant's: the host's state of it is
+        cleared and, on the paged plane, its block table released (a
+        prefill-role tenant's blocks copied out first).  -> the tenant."""
         req = self.slot_req[slot]
         self.slot_req[slot] = None
         self.slot_len[slot] = 0
@@ -1256,9 +1274,48 @@ class LLMServer:
                         and req.error is None:
                     self._extract_kv(req, table)
                 table.release()
+        return req
+
+    def _retire(self, req: _Request):
+        """A request that held a slot is done with it, whoever holds the
+        slot now."""
+        req.done = True
+        self._conclude(req)
+
+    def _finish(self, slot: int):
+        req = self._vacate(slot)
         if req is not None:
-            req.done = True
-            self._conclude(req)
+            self._retire(req)
+
+    def _release_ending(self) -> frozenset:
+        """At a chunk boundary, before admission: vacate every slot whose
+        tenant's last token lies inside the chunk in flight (``_ends_by``
+        its length, which that chunk's launch advanced), so that this
+        iteration's ``_admit_wave`` hands it to the next request and the
+        chunk launched now decodes that one -- or, with no one waiting,
+        leaves the slot out -- where it decoded a finished tenant for
+        all its steps.  The device runs what was launched in
+        order: the chunk in flight has written the old tenant's last rows
+        and holds its tokens in its own result before the new tenant's
+        prefill overwrites the slot's rows (states, rings, latent rows;
+        on the paged plane the blocks just released) and its seat the
+        carries.  ``_process`` of that chunk routes the tokens by the
+        request its snapshot holds and concludes it (``released``).  A
+        tenant whose K/V is read after its end (``want_kv``) keeps its
+        slot until then; a request of one token is in no chunk.  A tenant
+        of a chunk in flight has no prefill pending (its first token was
+        read in the iteration that admitted it), so ``_harvest_prefills``
+        meets no released request.  -> the slots vacated."""
+        self._ending = []   # the last boundary's: retired by _process
+        early = []
+        for slot, req, _len0 in self._in_flight_rows:
+            if self.slot_req[slot] is req and not req.want_kv \
+                    and self._ends_by(req, int(self.slot_len[slot])):
+                self._vacate(slot)
+                req.released = True
+                self._ending.append(req)
+                early.append(slot)
+        return frozenset(early)
 
     def _preempt(self, slot: int):
         """Pool pressure: evict the running request in ``slot`` back to
@@ -1298,6 +1355,11 @@ class LLMServer:
             if req is not None:
                 req.error = e
                 self._finish(slot)
+        # tenants released from their slots, their last chunk in flight
+        for req in self._ending:
+            if not req.done:
+                req.error = e
+                self._retire(req)
         for req in self._backlog:
             req.error = e
             self._conclude(req)
@@ -1324,9 +1386,12 @@ class LLMServer:
                 # prefill seats its rows on the device, so the chunk
                 # launched next decodes them: the device runs C(i-1),
                 # P(i), C(i) with P(i)'s rows in C(i), and the host has
-                # waited for nothing.
+                # waited for nothing.  A slot whose tenant ends inside
+                # C(i-1) is free for P(i) already: the host knows the end
+                # from the lengths it holds, before it has C(i-1)'s tokens.
+                early = self._release_ending()
                 self._admit_wave()
-                launched = self._launch_chunk()
+                launched = self._launch_chunk(early)
                 if pending is not None:
                     self._process(pending)  # overlaps the launched chunk
                 # P(i)'s first tokens, for the streams: before C(i) is
@@ -1400,7 +1465,7 @@ class LLMServer:
         t0 = time.perf_counter()
         sa = _bucket_for(min(high, self.max_len), self.decode_buckets)
         info = (len(snapshot), int(self.slot_waiting.sum()),
-                len(self._backlog), int(sa), int(pos.sum()), 0, 0)
+                len(self._backlog), int(sa), int(pos.sum()), 0, 0, 0)
         with _device.annotation("serve.spec_draft"):
             self.draft_cache, dts = self._draft_propose(
                 self.draft_params, self.draft_cache, jnp.asarray(tok),
@@ -1512,11 +1577,12 @@ class LLMServer:
             while True:
                 try:
                     # Clamp at the model horizon AND the request's own
-                    # budget: near the end of a sequence the one-deep
-                    # pipeline launches a chunk past the positions any
-                    # kept step will touch (writes beyond the table
-                    # drop, reads stay under lens), so growing for
-                    # them would over-allocate one block per request.
+                    # budget: a request's last chunk (and, for a tenant
+                    # that keeps its slot to its end, one chunk more)
+                    # runs past the positions any kept step will touch
+                    # (writes beyond the table drop, reads stay under
+                    # lens), so growing for them would over-allocate
+                    # one block per request.
                     if spec:
                         base = (len(req.prompt) + len(req.tokens)
                                 + k - 1)
@@ -1566,17 +1632,21 @@ class LLMServer:
                 best = s
         return best
 
-    def _launch_chunk(self):
+    def _launch_chunk(self, early: frozenset = frozenset()):
         """Issue the next decode chunk (async) over the device's own
         carries: where a slot goes on from, the chunk before it or its
         prefill's seat left there (host overrides only for a slot whose
-        K/V were handed over: ``_apply_preseed``).  Returns the in-flight
-        handle or None if no slot is active."""
+        K/V were handed over: ``_apply_preseed``).  ``early``: the slots
+        this iteration vacated before their tenants' last chunk was
+        processed (``_release_ending``), for the count of the launch's
+        rows that sit in one.  Returns the in-flight handle or None if no
+        slot is active."""
         jnp = self._jnp
         snapshot, active = self._active_snapshot()
         if self.paged:
             while snapshot and not self._grow_tables(snapshot):
                 snapshot, active = self._active_snapshot()
+        self._in_flight_rows = snapshot
         if not snapshot:
             return None
         self._kv_metrics["batch_occupancy"].set(len(snapshot),
@@ -1621,8 +1691,20 @@ class LLMServer:
                 # seated this iteration: the host has no token of theirs
                 # yet (a handed-over row has none either, but no prefill)
                 sum(1 for _s, req, _len0 in snapshot
-                    if not req.tokens and req.preseed is None))
+                    if not req.tokens and req.preseed is None),
+                sum(1 for s, _req, _len0 in snapshot if s in early))
         return (toks, snapshot, k, t0, info, load)
+
+    def _ends_by(self, req: _Request, length: int) -> bool:
+        """Whether ``req`` has its last token once its slot holds
+        ``length`` positions.  There is no stop token: a request ends at
+        ``max_new_tokens`` or at the horizon, and a slot that holds
+        ``length`` has made ``length - len(prompt)`` tokens behind the
+        first (which a handed-over row brought with it, uncounted).
+        ``_process`` asks token by token, ``_release_ending`` of the
+        length a launched chunk will leave: the one rule for both."""
+        budget = req.max_new_tokens - (req.preseed is None)
+        return length >= min(len(req.prompt) + budget, self.max_len - 1)
 
     def _process(self, pending):
         """Materialize a finished chunk's tokens (blocks until the
@@ -1643,14 +1725,13 @@ class LLMServer:
         for slot, req, len0 in snapshot:
             if req is None or req.done:
                 continue
-            if self.slot_req[slot] is not req:
+            if self.slot_req[slot] is not req and not req.released:
                 continue  # preempted after this chunk launched
             had = len(req.tokens)
             finished = False
             for step in range(k):
                 req.tokens.append(int(toks[step, slot]))
-                if (len(req.tokens) >= req.max_new_tokens
-                        or len0 + step + 1 >= self.max_len - 1):
+                if self._ends_by(req, len0 + step + 1):
                     finished = True
                     break
             if req.t_first_token is None:
@@ -1660,7 +1741,11 @@ class LLMServer:
             kept += len(req.tokens) - had
             if req.harvests is not None:
                 req.harvests.append((now, len(req.tokens)))
-            if finished:
+            if finished and req.released:
+                # its slot has been the next tenant's since the launch
+                # behind this chunk: nothing of the slot is touched
+                self._retire(req)
+            elif finished:
                 self._finish(slot)
         self._record_chunk(t0, now, k, info, kept, load)
 
@@ -1755,8 +1840,10 @@ class LLMServer:
                       kept: int, load: tuple = ()) -> None:
         """``serve.chunk`` (launch -> harvest returned) and the decode
         counters: the rows the launch held (``seated`` of them straight
-        from a prefill launched in the same iteration; ``waiting``: slots
-        occupied beside them that sat out); token-steps computed (k x
+        from a prefill launched in the same iteration, ``released_early``
+        of them in a slot vacated in that iteration before its last
+        tenant's final chunk was processed; ``waiting``: slots occupied
+        beside them that sat out); token-steps computed (k x
         max_slots, whatever is occupied) against tokens kept (appended to
         a live request); the cache positions the live rows held at launch
         (what the decode attention has to read) against max_slots x
@@ -1765,16 +1852,19 @@ class LLMServer:
         if not _tracing.enabled():
             return
         computed = k * self.max_slots
-        active, waiting, backlog, s_active, attended, ringed, seated = info
+        (active, waiting, backlog, s_active, attended, ringed, seated,
+         released_early) = info
         bucket = self.max_slots * s_active
         m = self._engine_metrics
+        m["slots_released_early"].inc(released_early, tags=self._tags)
         m["decode_tokens_kept"].inc(kept, tags=self._tags)
         m["decode_slot_steps"].inc(computed, tags=self._tags)
         m["decode_kv_positions_attended"].inc(attended, tags=self._tags)
         m["decode_kv_positions_bucket"].inc(bucket, tags=self._tags)
         self._span("serve.chunk", t0, t1, {
             "k": k, "active": active, "seated": seated,
-            "waiting": waiting, "backlog": backlog, "s_active": s_active,
+            "released_early": released_early, "waiting": waiting,
+            "backlog": backlog, "s_active": s_active,
             "tokens_kept": kept, "token_steps": computed,
             "kv_positions_attended": attended,
             "kv_positions_bucket": bucket,

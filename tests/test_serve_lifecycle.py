@@ -18,7 +18,8 @@ COUNTERS = ("ray_tpu_serve_decode_tokens_kept_total",
             "ray_tpu_serve_prefill_prompt_tokens_total",
             "ray_tpu_serve_prefill_padded_tokens_total",
             "ray_tpu_serve_decode_kv_positions_attended_total",
-            "ray_tpu_serve_decode_kv_positions_bucket_total")
+            "ray_tpu_serve_decode_kv_positions_bucket_total",
+            "ray_tpu_serve_slots_released_early_total")
 ENGINE = dict(model_preset="debug", max_slots=4, max_len=128,
               prefill_buckets=(32, 64), decode_chunk=4,
               prefill_groups=(2, 4))
@@ -154,7 +155,8 @@ def test_request_life_under_the_handles_trace(ray_start_regular,
                      sum(g["args"]["prompt_tokens"] for g in groups),
                      sum(g["args"]["token_positions"] for g in groups),
                      sum(c["args"]["kv_positions_attended"] for c in chunks),
-                     sum(c["args"]["kv_positions_bucket"] for c in chunks)]
+                     sum(c["args"]["kv_positions_bucket"] for c in chunks),
+                     sum(c["args"]["released_early"] for c in chunks)]
     # a live row holds at least its prompt and less than the bucket
     assert all(c["args"]["kv_positions_bucket"]
                == ENGINE["max_slots"] * c["args"]["s_active"]
@@ -205,7 +207,8 @@ def test_a_chunk_counts_the_positions_its_rows_hold(fresh_timeline,
                                                     flavour):
     """One request alone: the chunks launched while it lives find its
     row 5, 9, 13... positions long, one chunk's steps more each time
-    (the one-deep pipeline may launch one chunk past its end), against
+    (and none is launched past its end: its slot is vacated while the
+    chunk that holds its last token is in flight), against
     ``max_slots x s_active`` positions of bucket."""
     from ray_tpu.serve.llm import LLMServer
 
@@ -219,7 +222,7 @@ def test_a_chunk_counts_the_positions_its_rows_hold(fresh_timeline,
         server.shutdown()
     assert len(out["tokens"]) == 10
     chunks = sorted(_spans("serve.chunk"), key=lambda e: e["ts"])
-    assert len(chunks) >= 3         # 1 token of prefill + 4 + 4 + 1
+    assert len(chunks) == 3         # 1 token of prefill + 4 + 4 + 1
     for i, c in enumerate(chunks):
         args = c["args"]
         assert args["active"] == 1
@@ -229,6 +232,67 @@ def test_a_chunk_counts_the_positions_its_rows_hold(fresh_timeline,
     attended = sum(c["args"]["kv_positions_attended"] for c in chunks)
     bucket = sum(c["args"]["kv_positions_bucket"] for c in chunks)
     assert 0 < attended / bucket < 0.25     # one row of four, part full
+
+
+@pytest.mark.parametrize("flavour", [dict(paged=False),
+                                     dict(paged=True, block_size=8)])
+def test_a_released_request_keeps_its_life_and_hands_over_its_slot(
+        fresh_timeline, flavour):
+    """Two requests on ONE slot: the first ends inside a chunk in flight
+    and its slot goes to the second at that boundary (``released_early``).
+    The first's four phases still cover its life exactly, to the harvest
+    of the chunk that holds its last token; the second's wait for a slot
+    ends at the hand-over, before that harvest, and its prefill is
+    launched behind that chunk."""
+    from ray_tpu.serve.llm import LLMServer
+
+    server = LLMServer(model_preset="debug", max_slots=1, max_len=64,
+                       prefill_buckets=(16,), decode_chunk=4,
+                       prefill_groups=(1,), warmup=False, **flavour)
+    before = _counters("llm")
+    try:
+        first, second = _generate(server, [
+            {"prompt": [3, 4, 5, 6, 7], "max_new_tokens": 11},
+            {"prompt": [8, 9, 10], "max_new_tokens": 6}])
+        # a request's waiter wakes before its last chunk's span is
+        # written: one more, of one token, goes through the loop first
+        _generate(server, [{"prompt": [1], "max_new_tokens": 1}])
+    finally:
+        server.shutdown()
+    assert len(first["tokens"]) == 11 and len(second["tokens"]) == 6
+    old, new, _settle = sorted(_spans("serve.request"),
+                               key=lambda e: e["ts"] + e["dur"])
+    assert old["args"]["prompt_tokens"] == 5
+    assert old["args"]["slot"] == new["args"]["slot"] == 0
+    for e in (old, new):
+        assert e["args"]["outcome"] == "ok"
+        phases = _life(e)
+        assert [p["name"] for p in phases] == list(PHASES)
+        assert phases[0]["ts"] == pytest.approx(e["ts"], abs=1.0)
+        for a, b in zip(phases, phases[1:]):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1.0)
+        assert sum(p["dur"] for p in phases) == pytest.approx(
+            e["dur"], abs=1.0)
+    chunks = sorted(_spans("serve.chunk"), key=lambda e: e["ts"])
+    # 10 tokens behind the first in chunks of 4, then 5 behind the
+    # second's: no chunk over a finished tenant
+    assert [c["args"]["released_early"] for c in chunks] == [0, 0, 0, 1, 0]
+    assert [c["args"]["tokens_kept"] for c in chunks] == [4, 4, 2, 4, 1]
+    last_of_old = chunks[2]
+    old_end = old["ts"] + old["dur"]
+    assert old_end == pytest.approx(
+        last_of_old["ts"] + last_of_old["dur"], abs=500.0)
+    # the hand-over: the second is bound to the slot while the first's
+    # last chunk is in flight, so before the first is done
+    wait_slot, wait_prefill = _life(new)[1:3]
+    handed_over = wait_slot["ts"] + wait_slot["dur"]
+    assert last_of_old["ts"] < handed_over < old_end
+    launched = wait_prefill["ts"] + wait_prefill["args"]["launch_ms"] * 1e3
+    assert handed_over <= launched < old_end
+    assert chunks[3]["ts"] > launched and chunks[3]["args"]["seated"] == 1
+    grown = _counters("llm")
+    assert grown["ray_tpu_serve_slots_released_early_total"] - before[
+        "ray_tpu_serve_slots_released_early_total"] == 1
 
 
 def test_shed_and_preempted_requests_leave_outcome_and_count(
