@@ -36,10 +36,42 @@ import jax.numpy as jnp
 from ray_tpu.parallel.sharding import with_logical_constraint
 
 PyTree = Any
-LAYER_KINDS = ("attention", "mamba", "window")
+LAYER_KINDS = ("attention", "mamba", "window", "conv")
+# Leaves that stay float32 whatever type the weights are served in.
+FLOAT32_LEAVES = ("router_bias",)
+# The deviation a router's selection bias is drawn with: of the order of
+# the distance between a token's 4th and 5th sigmoid scores of 32 under
+# this initialiser (median 0.019, mean 0.026 at hidden 2,048), so that the
+# bias changes the choice for a measurable share of tokens (35% a layer
+# there).  A zero bias would leave the mechanism idle.
+ROUTER_BIAS_STD = 0.02
 # The kinds whose mixer is attention: they share ``ATTENTION_LEAVES``,
 # stacked over all of them in their order.
 ATTENDING_KINDS = ("attention", "window")
+
+
+def _runs(kinds):
+    """A list of layer kinds cut into runs of whole periods: ``[(first
+    index, one period's kinds, periods)]``.  From the front, the period
+    that repeats (twice or more) over the most layers, the shorter of two
+    that cover the same; a stretch in which nothing repeats is one period
+    of its own.  LFM2's 22 expert layers (A C C C) x 4, (A C C) x 2 are
+    two runs; their first 12 one."""
+    kinds, out, at = tuple(kinds), [], 0
+    while at < len(kinds):
+        rest = kinds[at:]
+        best = (len(rest), 1)                   # nothing repeats
+        covered = 0
+        for p in range(1, len(rest) // 2 + 1):
+            m = 1
+            while rest[m * p:(m + 1) * p] == rest[:p]:
+                m += 1
+            if m > 1 and m * p > covered:
+                best, covered = (p, m), m * p
+        p, m = best
+        out.append((at, rest[:p], m))
+        at += p * m
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,9 +137,18 @@ class LlamaConfig:
     # experts' gate activation: "silu" or "relu".
     moe_router_input: str = "ffn"
     moe_activation: str = "silu"
+    # How the router scores: "softmax" over all experts, or "sigmoid" an
+    # expert.  With moe_router_bias the top-k are those of score + a bias
+    # an expert (leaf ``router_bias``, float32) and the gates are the
+    # chosen SCORES, without it (LFM2).
+    moe_router_score: str = "softmax"
+    moe_router_bias: bool = False
     # RMSNorm on q and on k, each over its WHOLE projection, before the
-    # split into heads and before RoPE (OLMoE).
+    # split into heads and before RoPE (OLMoE); or over each HEAD's
+    # head_dim, one weight of head_dim shared by the heads, before RoPE
+    # (qk_head_norm: LFM2).
     qk_norm: bool = False
+    qk_head_norm: bool = False
     # ONE PERIOD of the layer stack, a kind per layer: "attention",
     # "mamba" (a Mamba-2 mixer, models/mamba2.py, in place of attention;
     # every layer keeps its FFN) or "window" (attention over the last
@@ -117,6 +158,16 @@ class LlamaConfig:
     # plain decoder.
     layer_pattern: Tuple[str, ...] = ()
     window_size: int = 0
+    # A kind for EVERY layer, as a published ``layer_types`` lists them
+    # ("full_attention" reads as "attention"), where the stack is not whole
+    # periods of one pattern: ``parts`` cuts the list into runs of whole
+    # periods, each walked as a stack of its own.  "conv": a gated short
+    # convolution of ``conv_taps`` taps in place of attention
+    # (models/shortconv.py; served only).  Leading dense layers
+    # (first_dense_layers) are then the list's first entries, of whatever
+    # kind it says.
+    layer_types: Tuple[str, ...] = ()
+    conv_taps: int = 3
     # Rotary position embedding on q and k (False: NoPE, Granite 4), and
     # the kinds of attending layer that go without it where the others
     # rotate (SmallThinker: the global layers are NoPE, the window
@@ -195,6 +246,9 @@ class LlamaConfig:
     def __post_init__(self):
         # a configuration file's lists and type names, made hashable
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        object.__setattr__(self, "layer_types", tuple(
+            "attention" if kind == "full_attention" else kind
+            for kind in self.layer_types))
         object.__setattr__(self, "nope_kinds", tuple(self.nope_kinds))
         object.__setattr__(self, "moe_held", tuple(self.moe_held))
         if isinstance(self.rope_scaling, dict):
@@ -204,24 +258,43 @@ class LlamaConfig:
             if isinstance(getattr(self, name), str):
                 object.__setattr__(self, name,
                                    jnp.dtype(getattr(self, name)).type)
-        unknown = set(self.layer_pattern) - set(LAYER_KINDS)
-        if unknown:
-            raise ValueError(f"layer_pattern: unknown kinds {unknown} "
-                             f"(choose from {LAYER_KINDS})")
-        if (self.n_layers - self.first_dense_layers) % len(self.period):
+        kinds = set(self.layer_pattern) | set(self.layer_types)
+        if kinds - set(LAYER_KINDS):
+            raise ValueError(
+                f"layer_pattern: unknown kinds {kinds - set(LAYER_KINDS)} "
+                f"(choose from {LAYER_KINDS})")
+        if self.layer_types:
+            if self.layer_pattern or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types names each of the n_layers="
+                    f"{self.n_layers} layers (it has "
+                    f"{len(self.layer_types)}) and stands in place of "
+                    f"layer_pattern")
+        elif (self.n_layers - self.first_dense_layers) % len(self.period):
             raise ValueError(
                 f"n_layers={self.n_layers} is not a whole number of "
                 f"periods of {len(self.period)} layers")
         self._check_latent_and_share()
-        if "mamba" in self.layer_pattern and self.ssm_heads < 1:
+        if "mamba" in kinds and self.ssm_heads < 1:
             raise ValueError("a mamba layer needs ssm_heads")
-        if "window" in self.layer_pattern:
-            if self.window_size < 1:
-                raise ValueError("a window layer needs window_size")
-            if "mamba" in self.layer_pattern:
-                raise ValueError(
-                    "no serving cache holds window rings beside "
-                    "recurrent states: window and mamba layers do not mix")
+        if "conv" in kinds and self.conv_taps < 2:
+            raise ValueError("a conv layer needs conv_taps of 2 or more")
+        if "window" in kinds and self.window_size < 1:
+            raise ValueError("a window layer needs window_size")
+        if len(kinds & {"window", "mamba", "conv"}) > 1:
+            raise ValueError(
+                "no serving cache holds window rings, recurrent states "
+                "and conv states beside each other: window, mamba and "
+                "conv layers do not mix")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm is over the whole projection, "
+                             "qk_head_norm over a head: choose one")
+        if self.moe_router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_router_score {self.moe_router_score!r}")
+        if self.moe_groups and (self.moe_router_score != "softmax"
+                                or self.moe_router_bias):
+            raise ValueError("group-limited routing is built for a softmax "
+                             "router without a selection bias")
         if set(self.nope_kinds) - set(ATTENDING_KINDS):
             raise ValueError(f"nope_kinds: choose from {ATTENDING_KINDS}")
         if self.moe_router_input not in ("ffn", "layer"):
@@ -232,7 +305,8 @@ class LlamaConfig:
     def _check_latent_and_share(self):
         """Refuse what no program here computes of latent attention,
         leading dense layers and an expert share."""
-        patterned = self.period != ("attention",)
+        patterned = bool(self.layer_types) \
+            or self.period != ("attention",)
         if self.kv_lora_rank:
             if patterned:
                 raise ValueError(
@@ -249,7 +323,8 @@ class LlamaConfig:
                 raise ValueError(
                     f"head_dim={self.head_dim} is not qk_nope_head_dim + "
                     f"qk_rope_head_dim")
-            if self.qk_norm or not self.rope or self.nope_kinds:
+            if (self.qk_norm or self.qk_head_norm or not self.rope
+                    or self.nope_kinds):
                 raise ValueError("latent attention has its own norms and "
                                  "always rotates its rope part")
         if self.rope_scaling is not None:
@@ -258,10 +333,11 @@ class LlamaConfig:
                 raise ValueError(f"rope_scaling type {kind!r}: only "
                                  f"'yarn' is built")
         if self.first_dense_layers:
-            if patterned or self.moe_experts == 0:
+            if (patterned and not self.layer_types) or self.moe_experts == 0:
                 raise ValueError(
                     "first_dense_layers is a prologue before a stack of "
-                    "expert layers of one kind")
+                    "expert layers of one kind, or the first entries of "
+                    "layer_types")
             if not 0 < self.first_dense_layers < self.n_layers:
                 raise ValueError("first_dense_layers must leave a layer")
         if self.moe_groups:
@@ -282,6 +358,9 @@ class LlamaConfig:
     @property
     def period(self) -> Tuple[str, ...]:
         """The kinds of one period of layers."""
+        if self.layer_types:
+            raise ValueError("a config with layer_types has a period a "
+                             "part (parts())")
         return self.layer_pattern or ("attention",)
 
     @property
@@ -290,25 +369,49 @@ class LlamaConfig:
 
     def layers_of(self, kind: str) -> int:
         """How many of the n_layers are of ``kind``."""
+        if self.layer_types:
+            return self.layer_types.count(kind)
         return (self.n_layers // self.period_len) * self.period.count(kind)
+
+    def layers_before(self, layer: int, kind: str) -> int:
+        """How many of the layers before ``layer`` keep their cache rows
+        or state where a layer of ``kind`` does: where a part's first
+        layer of that kind lies in the cache's stack."""
+        if not self.layer_types:
+            # one kind of layer behind leading dense ones of the same kind
+            return layer
+        group = _leaf_group(kind)
+        return sum(_leaf_group(k) == group
+                   for k in self.layer_types[:layer])
 
     def parts(self):
         """The layer stacks a walk runs in turn, ``(config of the part,
         its key in params, its first layer's index among all)``: the
         leading dense layers, if any, as a dense model of that many layers
         (``params["dense_layers"]``), then the scanned stack
-        (``params["layers"]``).  A part's config is a plain one: the code
-        that walks a stack never asks which part it is in."""
+        (``params["layers"]``).  A part's config is a plain one, whole
+        periods of one ``layer_pattern``: the code that walks a stack
+        never asks which part it is in.  A config with ``layer_types`` is
+        cut into as many parts as its list has runs of whole periods
+        (``_runs``): further dense parts are ``dense_layers_1`` ..., further
+        expert parts ``layers_1`` ... ."""
         k = self.first_dense_layers
-        if not k:
+        if not k and not self.layer_types:
             return [(self, "layers", 0)]
-        return [
-            (dataclasses.replace(
-                self, n_layers=k, first_dense_layers=0, moe_experts=0,
-                moe_shared_size=0, moe_groups=0, moe_top_groups=0,
-                moe_held=()), "dense_layers", 0),
-            (dataclasses.replace(self, n_layers=self.n_layers - k,
-                                 first_dense_layers=0), "layers", k)]
+        # (leading dense layers without a list: a stack of attention layers)
+        kinds = self.layer_types or ("attention",) * self.n_layers
+        dense = dict(moe_experts=0, moe_shared_size=0, moe_groups=0,
+                     moe_top_groups=0, moe_held=(), moe_router_bias=False)
+        out = []
+        for key, first, stack, fields in (("dense_layers", 0, kinds[:k], dense),
+                                          ("layers", k, kinds[k:], {})):
+            for i, (start, pattern, periods) in enumerate(_runs(stack)):
+                out.append((dataclasses.replace(
+                    self, n_layers=len(pattern) * periods, layer_types=(),
+                    layer_pattern=() if pattern == ("attention",)
+                    else pattern, first_dense_layers=0, **fields),
+                    key if i == 0 else f"{key}_{i}", first + start))
+        return out
 
     @property
     def expert_width(self) -> int:
@@ -462,13 +565,11 @@ class LlamaConfig:
 
 def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     """Pytree (matching init_params) of per-dim logical axis names."""
-    if config.first_dense_layers:
-        axes = None
-        for part, key, _ in config.parts():
-            part_axes = param_logical_axes(part)
-            axes = axes or part_axes
-            axes[key] = part_axes["layers"]
-        return axes
+    if config.first_dense_layers or config.layer_types:
+        parts = {key: param_logical_axes(part)
+                 for part, key, _ in config.parts()}
+        return {**parts["layers"],
+                **{key: axes["layers"] for key, axes in parts.items()}}
     if config.moe_experts > 0:
         ffn_axes = {
             "router": ("layers", None, "expert"),
@@ -495,9 +596,11 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         },
         "final_norm": (None,),
     }
-    if config.qk_norm:
+    if config.qk_norm or config.qk_head_norm:
         axes["layers"]["q_norm"] = ("layers", None)
         axes["layers"]["k_norm"] = ("layers", None)
+    if config.moe_router_bias:
+        axes["layers"]["router_bias"] = ("layers", "expert")
     if config.moe_shared_size:
         axes["layers"].update(
             ws_gate=("layers", "embed", "mlp"),
@@ -516,6 +619,13 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         from ray_tpu.models import mamba2
 
         axes["layers"].update(mamba2.param_axes(config))
+    if config.layers_of("conv"):
+        from ray_tpu.models import shortconv
+
+        axes["layers"].update(shortconv.param_axes(config))
+    if not config.attending_layers():
+        for name in ATTENTION_LEAVES:
+            axes["layers"].pop(name, None)
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -542,13 +652,16 @@ def init_params(rng: jax.Array, config: LlamaConfig,
     def dense(key, shape, fan_in):
         return init_dense(key, shape, fan_in, dtype)
 
-    if c.first_dense_layers:
-        # Each part is drawn as the plain model its config is; the leading
-        # dense layers from a key of their own.
-        (dense_part, _, _), (rest, _, _) = c.parts()
-        params = init_params(rng, rest, dtype)
-        params["dense_layers"] = init_params(
-            jax.random.fold_in(rng, 97), dense_part, dtype)["layers"]
+    if c.first_dense_layers or c.layer_types:
+        # Each part is drawn as the plain model its config is: ``layers``
+        # (with the embedding, the final norm and the head) from the key
+        # itself, every other part from a key of its own.
+        parts = {key: part for part, key, _ in c.parts()}
+        params = init_params(rng, parts.pop("layers"), dtype)
+        for i, (key, part) in enumerate(parts.items()):
+            params[key] = init_params(
+                jax.random.fold_in(rng, 97 if key == "dense_layers"
+                                   else 970 + i), part, dtype)["layers"]
         return params
     L, La = c.n_layers, c.attending_layers()
     if c.moe_experts > 0:
@@ -609,6 +722,12 @@ def init_params(rng: jax.Array, config: LlamaConfig,
     if c.qk_norm:
         params["layers"]["q_norm"] = jnp.ones((La, c.q_dim), dtype)
         params["layers"]["k_norm"] = jnp.ones((La, c.kv_dim), dtype)
+    if c.qk_head_norm:
+        params["layers"]["q_norm"] = jnp.ones((La, c.head_dim), dtype)
+        params["layers"]["k_norm"] = jnp.ones((La, c.head_dim), dtype)
+    if c.moe_experts > 0 and c.moe_router_bias:
+        params["layers"]["router_bias"] = ROUTER_BIAS_STD * jax.random.normal(
+            jax.random.fold_in(rng, 94), (L, c.moe_experts), jnp.float32)
     if c.kv_lora_rank:
         for name in ("wq", "wk", "wv", "wo"):
             del params["layers"][name]
@@ -620,6 +739,16 @@ def init_params(rng: jax.Array, config: LlamaConfig,
         params["layers"].update(mamba2.init_params(
             jax.random.fold_in(rng, 98), c, c.layers_of("mamba"), dtype,
             dense))
+    if c.layers_of("conv"):
+        from ray_tpu.models import shortconv
+
+        params["layers"].update(shortconv.init_params(
+            jax.random.fold_in(rng, 93), c, c.layers_of("conv"), dense))
+    if not La:
+        # a stack without an attending layer has no attention leaves: a
+        # scan slices every leaf of its stack
+        for name in ATTENTION_LEAVES:
+            params["layers"].pop(name, None)
     if not c.tie_embeddings:
         params["lm_head"] = dense(
             jax.random.fold_in(rng, 99), (c.hidden_size, c.vocab_size),
@@ -854,6 +983,9 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     k = k.reshape(B, S, c.n_kv_heads, c.head_dim)
     v = matmul(h, layer["wv"].astype(dt)).reshape(B, S, c.n_kv_heads,
                                                   c.head_dim)
+    if c.qk_head_norm:
+        q = rms_norm(q, layer["q_norm"], c.norm_eps)
+        k = rms_norm(k, layer["k_norm"], c.norm_eps)
     if c.ropes(kind):
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
@@ -1083,8 +1215,10 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
                          norm_topk=c.moe_norm_topk,
                          activation=c.moe_activation, dtype=dt,
                          groups=c.moe_groups, top_groups=c.moe_top_groups,
-                         routed_scale=c.moe_routed_scale, held=c.moe_held)
-    moe_params = {k: layer[k] for k in ("router",) + EXPERT_STACKS}
+                         routed_scale=c.moe_routed_scale, held=c.moe_held,
+                         score=c.moe_router_score)
+    moe_params = {k: layer[k] for k in ("router",) + EXPERT_STACKS
+                  + (("router_bias",) if c.moe_router_bias else ())}
     mesh = current_mesh()
     if mesh is not None and mesh.shape.get("expert", 1) > 1:
         # Expert-parallel training: the dense dispatch, whose sharding
@@ -1196,6 +1330,8 @@ def _leaf_kind(name: str) -> Optional[str]:
     "attention" stands for every attending kind (``_leaf_group``)."""
     if name in ATTENTION_LEAVES:
         return "attention"
+    if name.startswith("conv_"):
+        return "conv"
     return "mamba" if name.startswith("ssm_") else None
 
 
@@ -1281,6 +1417,19 @@ def stack_period(items, config: LlamaConfig):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *items)
 
 
+def state_mixer(kind: str):
+    """Of a kind of layer that keeps a state a slot: ``(its module --
+    ``prefill`` and ``decode`` --, the scope of its pre-norm and
+    in-projection, the scope of its residual add)``."""
+    if kind == "conv":
+        from ray_tpu.models import shortconv
+
+        return shortconv, "conv_proj", "conv_out"
+    from ray_tpu.models import mamba2
+
+    return mamba2, "ssm_proj", "ssm_out"
+
+
 def layer_index(p: jax.Array, per_period: int, i: int) -> jax.Array:
     """Period ``p``'s ``i``-th layer of ``per_period``, among all."""
     return p if per_period == 1 else p * per_period + i
@@ -1316,15 +1465,19 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             or c.layers_of("window") or c.nope_kinds
             or c.moe_router_input != "ffn" or c.kv_lora_rank
             or c.rope_scaling is not None or c.first_dense_layers
-            or c.moe_held):
+            or c.moe_held or c.layer_types or c.layers_of("conv")
+            or c.qk_head_norm or c.moe_router_score != "softmax"
+            or c.moe_router_bias):
         raise NotImplementedError(
             "llama.forward (training) computes a stack of one kind of "
             "attention layer with the default scale, embedding and "
-            "logits, its router after attention: a config with "
-            "state-space or window layers, a kind without RoPE, a router "
-            "on the layer's input, the Granite multipliers, latent "
-            "attention, scaled RoPE, leading dense layers or an expert "
-            "share is served only (llama_serve.build_*)")
+            "logits, its softmax router after attention: a config with "
+            "state-space, short-convolution or window layers, a list of "
+            "layer_types, a kind without RoPE, a q/k norm a head, a "
+            "router on the layer's input, a sigmoid router or a "
+            "selection bias, the Granite multipliers, latent attention, "
+            "scaled RoPE, leading dense layers or an expert share is "
+            "served only (llama_serve.build_*)")
     if positions is not None and c.attention_impl != "dot":
         # flash/ring mask on raw row index, not positions — packed or
         # offset sequences would silently attend across boundaries.
@@ -1725,8 +1878,9 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
                window_step: Optional[Callable] = None):
     """The forward pass that serving shares: embed, rope table, a scan
     over the PERIODS of the layer pattern -- per attention layer
-    ``_qkv_rope`` -> ``kv_step`` -> ``attn_out_ffn``, per Mamba layer
-    ``mamba2.prefill`` -> ``ffn_half`` --, final norm, head.  What a
+    ``_qkv_rope`` -> ``kv_step`` -> ``attn_out_ffn``, per Mamba or
+    short-convolution layer its ``prefill`` (``state_mixer``) ->
+    ``ffn_half`` --, final norm, head.  What a
     decoder layer is made of lives here; the callers differ only in
     ``kv_step``, what an attention layer does with its fresh K/V rows and
     what its queries attend.  (A plain decoder's period is one layer.)
@@ -1749,13 +1903,16 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     model with latent attention attends EXPANDED (``latent_attend_
     expanded``: ``kv_step`` gets a group of heads' q, k and v and no
     cache) and its ys are the latent rows.  Leading dense layers are
-    walked first, as a stack of their own (``LlamaConfig.parts``).
+    walked first, as a stack of their own, and a stack that is not one
+    pattern throughout a run of whole periods at a time
+    (``LlamaConfig.parts``).
 
     Returns ``(logits, ys stacked over the attention layers, expert
     rows, Mamba states, window ys)``: the (L, E) int32 rows each layer's
     experts computed, None for a dense config; ``(recurrent, conv)``
-    states stacked over the Mamba layers and ``window_step``'s ys over
-    the window layers, None where there are none."""
+    states stacked over the Mamba layers (``(conv,)`` over the
+    short-convolution layers) and ``window_step``'s ys over the window
+    layers, None where there are none."""
     c = config
     x = embed(params, tokens, c)
     if positions is None:
@@ -1807,14 +1964,14 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
                         x, attn, layer, c, valid=valid,
                         layer_index=layer_index(p, plen, j))
                 else:
-                    from ray_tpu.models import mamba2
-
-                    with jax.named_scope("ssm_proj"):
+                    # a layer that keeps a state: Mamba-2, short convolution
+                    mixer, proj_scope, out_scope = state_mixer(kind)
+                    with jax.named_scope(proj_scope):
                         h = rms_norm(x, layer["attn_norm"],
                                      c.norm_eps).astype(c.dtype)
-                    out, ys = mamba2.prefill(h, layer, c, lengths)
+                    out, ys = mixer.prefill(h, layer, c, lengths)
                     ssm_ys.append(ys)
-                    with jax.named_scope("ssm_out"):
+                    with jax.named_scope(out_scope):
                         x = residual_add(x, out, c)
                     x, _aux, rows_j = ffn_half(
                         x, layer, c, valid=valid,
@@ -1832,21 +1989,25 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             return x, merge_periods(stacked, c)
 
     # The leading dense layers, if the model has them, then the scanned
-    # stack: the same walk over each part's own weights.
-    if not c.first_dense_layers:
+    # stack (a stack that is not whole periods: its runs of them): the
+    # same walk over each part's own weights.
+    if not (c.first_dense_layers or c.layer_types):
         x, (ys, expert_rows, ssm_ys, win_ys) = walk_part(
             x, c, params["layers"], kv_layers)
     else:
         outs = []
         for part, key, l0 in c.parts():
+            a0 = c.layers_before(l0, "attention")
             x, out = walk_part(
                 x, part, params[key],
-                jax.tree.map(lambda a: a[l0:l0 + part.n_layers], kv_layers))
+                jax.tree.map(
+                    lambda a: a[a0:a0 + part.attending_layers()], kv_layers))
             outs.append(out)
-        ys = jax.tree.map(lambda *a: jnp.concatenate(a), *(o[0] for o in outs))
-        # a dense part computes no expert's rows; states and window rows
-        # belong to patterns, which have no prologue
-        expert_rows, ssm_ys, win_ys = outs[-1][1], None, None
+        # Each result over the parts that have it, in the layers' order (a
+        # dense part computes no expert's rows, a part without attending
+        # layers keeps no K/V).
+        ys, expert_rows, ssm_ys, win_ys = (
+            over_parts([o[i] for o in outs]) for i in range(4))
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.norm_eps).astype(c.dtype)
         if lengths is None:
@@ -1857,6 +2018,15 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             axis=1)                                          # (B,1,H)
         return (head_logits(last, params, c)[:, 0], ys, expert_rows,
                 ssm_ys, win_ys)
+
+
+def over_parts(results):
+    """One result of ``layer_walk`` over the parts that have it (None:
+    none has), concatenated along the layers where several do."""
+    first, *more = [r for r in results if r is not None] or [None]
+    if not more:
+        return first
+    return jax.tree.map(lambda *a: jnp.concatenate(a), first, *more)
 
 
 def prefill_forward(params: PyTree, tokens: jax.Array,
@@ -1975,11 +2145,13 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
     program that serves runs it; tests hold T > 1 through a cache to the
     reference with it."""
     if (config.layers_of("mamba") or config.layers_of("window")
-            or config.kv_lora_rank):
+            or config.kv_lora_rank or config.layers_of("conv")
+            or config.layer_types):
         raise NotImplementedError(
             "forward_with_cache holds one K/V stack alone; a config with "
-            "state-space or window layers or latent attention runs "
-            "through llama_serve.build_prefill / build_decode_k")
+            "state-space, short-convolution or window layers, a list of "
+            "layer_types or latent attention runs through "
+            "llama_serve.build_prefill / build_decode_k")
     scale = config.attn_scale
 
     # The T new K/V rows go into each slot's cache at its own positions

@@ -7,7 +7,8 @@ engine, thread or weights are needed to lower one.  By the cache kept:
 
 - a per-slot cache (``init_cache``: K and V ``(La, B, S, Hkv, D)`` over
   the attention layers and, for a model with Mamba-2 layers, each slot's
-  recurrent and conv states beside them; for a model with window layers
+  recurrent and conv states beside them, for one with short-convolution
+  layers its conv states alone; for a model with window layers
   a pool of every position for its full layers and a ring of the last
   ``window_size`` for its window layers; for a model with latent
   attention ONE leaf of a latent row a token and layer in place of K and
@@ -75,7 +76,8 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     over its attention layers and, for its Mamba layers, each slot's
     recurrent state ``ssm (Lm, B, N, nh x hd)`` (stored as
     ``cfg.ssm_state_dtype``) and conv window ``conv (Lm, K - 1, B,
-    conv_dim)``.  A state is not positional: ``build_prefill`` replaces a
+    conv_dim)``; for its short-convolution layers ``conv (Lc, taps - 1,
+    B, D)`` alone.  A state is not positional: ``build_prefill`` replaces a
     slot's whole state, ``decode_step`` advances it in place."""
     if cfg.layers_of("window"):
         return _init_window_cache(cfg, slots, max_len)
@@ -90,6 +92,11 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
         from ray_tpu.models import mamba2
 
         cache.update(mamba2.init_state(cfg, cfg.layers_of("mamba"), slots))
+    if cfg.layers_of("conv"):
+        from ray_tpu.models import shortconv
+
+        cache.update(shortconv.init_state(cfg, cfg.layers_of("conv"),
+                                          slots))
     return cache
 
 
@@ -135,8 +142,9 @@ def cache_pools(cfg: LlamaConfig, slots: int, max_len: int):
 
 def state_bytes_per_slot(cfg: LlamaConfig):
     """``{"ssm": ..., "conv": ...}`` bytes one slot's states hold over
-    all Mamba layers ({} for a model without them): what a decode step
-    reads and writes for a slot it advances."""
+    all the layers that keep one (Mamba layers: both; short-convolution
+    layers: ``conv`` alone; {} for a model without such layers): what a
+    decode step reads and writes for a slot it advances."""
     return {pool: nbytes for pool, (nbytes, _) in
             cache_pools(cfg, 1, 1).items()
             if not pool.startswith("kv") and pool != "latent"}
@@ -145,21 +153,19 @@ def state_bytes_per_slot(cfg: LlamaConfig):
 @jax.named_scope("kv_write")
 def insert_states(cache, states, slots):
     """A prefill group's final states into its slots, wholesale (a reused
-    slot inherits nothing of the request before).  states: ``(recurrent
-    (Lm, G, N, nh x hd), conv (Lm, K - 1, G, conv_dim))``; slots (G,), a
-    negative one drops its row."""
-    ssm, conv = states
-    B = cache["ssm"].shape[1]
+    slot inherits nothing of the request before).  states: one array a
+    state leaf of the cache, in ``_state_names``' order -- ``(recurrent
+    (Lm, G, N, nh x hd), conv (Lm, K - 1, G, conv_dim))`` of Mamba layers,
+    ``(conv (Lc, K - 1, G, D),)`` of short-convolution layers; slots (G,),
+    a negative one drops its row."""
+    names = _state_names(cache)
+    B = cache[names[0]].shape[_SLOT_AXIS[names[0]]]
     rows = jnp.where(slots < 0, B, slots)      # out of range: dropped
-    return {
-        **cache,
-        "ssm": cache["ssm"].at[:, rows].set(
-            ssm.astype(cache["ssm"].dtype), mode="drop",
-            unique_indices=True),
-        "conv": cache["conv"].at[:, :, rows].set(
-            conv.astype(cache["conv"].dtype), mode="drop",
-            unique_indices=True),
-    }
+    at = {1: (slice(None), rows), 2: (slice(None), slice(None), rows)}
+    return {**cache, **{
+        name: cache[name].at[at[_SLOT_AXIS[name]]].set(
+            new.astype(cache[name].dtype), mode="drop", unique_indices=True)
+        for name, new in zip(names, states)}}
 
 
 def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
@@ -192,7 +198,8 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     lengths -- ``(ck, cv, tok, lens, ssm, conv)`` -- through both loops
     and are updated in place (``mamba2.decode``,
     ``ops/ssm_state_update.py``); an inactive slot's states are left as
-    they are.
+    they are.  A short-convolution layer does the same with the one state
+    it keeps, ``(ck, cv, tok, lens, conv)`` (``shortconv.decode``).
 
     A model with window layers carries its two pools as rows (``init_
     cache``): ``ck`` / ``cv`` the full layers' and, after the lengths,
@@ -204,17 +211,16 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     V): a layer writes the new latent row and attends ABSORBED, every
     head's ``[q~ ; q_rope]`` against the rows as they lie
     (``ops/mla_decode_attention.py``, one call a layer).  Leading dense
-    layers (``LlamaConfig.parts``) run as a scan of their own before the
-    scanned stack, through the same body.
+    layers, and each run of whole periods of a stack that is not one
+    pattern throughout (``LlamaConfig.parts``), run as a scan of their
+    own, one after the other, through the same body.
 
     This step is ``llama.layer_walk`` written out, its K/V the carry of
     the layer scan: the walk, handed a carry, compiled to the same sizes
     but not to the same text as the program the benchmark's cells have
     measured since PR 24 (this step has cliffs: PERF.md section 6)."""
 
-    plen = cfg.period_len
-    n_attn, n_ssm = cfg.period.count("attention"), cfg.period.count("mamba")
-    n_win, hkv = cfg.period.count("window"), cfg.n_kv_heads
+    n_win, hkv = cfg.layers_of("window"), cfg.n_kv_heads
 
     def step(carry, _):
         ck, cv, tok, lens, *state = carry
@@ -241,11 +247,15 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                                          ring)
 
         # ``part``: the stack this body walks (the leading dense layers or
-        # the scanned stack, ``LlamaConfig.parts``), as a plain config;
-        # ``l0``: its first layer's index in the cache.
+        # a run of whole periods, ``LlamaConfig.parts``), as a plain
+        # config; ``l0``: its first layer's index among all.
         def body(carry, period_and_index, part, sliced, stacks, l0):
             x, ck, cv, *state = carry
             period, p = period_and_index
+            plen = part.period_len
+            n_of = part.period.count
+            # where the part's first attending layer lies in the cache
+            a0 = cfg.layers_before(l0, "attention")
             expert_rows = []
             for j, (kind, i, layer) in enumerate(
                     llama.period_layers(sliced, period, p, part)):
@@ -257,7 +267,7 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                     cq, latent = llama.latent_down(x, layer, sin, cos, part)
                     q_nope, q_rope = llama.latent_queries(
                         cq, llama._wq_b_heads(layer, part), sin, cos, part)
-                    l = p + l0
+                    l = p + a0
                     ck = _write(ck, l, rows, pos, latent[:, 0, None])
                     q = llama.latent_absorb_query(
                         q_nope[:, 0], q_rope[:, 0], layer, part)
@@ -270,14 +280,14 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                     x, _aux, rows_j = llama.attn_out_ffn(
                         x, attn, layer, part, valid=active[:, None],
                         layer_index=llama.layer_index(p, plen, j))
-                elif kind != "mamba":
+                elif kind in llama.ATTENDING_KINDS:
                     # The layer's pool: the carry's K/V or, for a window
                     # layer, the rings after the lengths.
                     ringed = kind == "window"
                     pk, pv = state if ringed else (ck, cv)
-                    l = llama.layer_index(p, n_win if ringed else n_attn, i)
-                    if l0:
-                        l = l + l0
+                    l = llama.layer_index(p, n_of(kind), i)
+                    if a0:
+                        l = l + a0
                     q, kk, vv = llama._qkv_rope(x, layer, sin, cos, part,
                                                 kind)
                     # Write before attend: the new row is among the keys.
@@ -299,18 +309,22 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                         x, attn, layer, part, valid=active[:, None],
                         layer_index=llama.layer_index(p, plen, j))
                 else:
-                    from ray_tpu.models import mamba2
-
-                    with jax.named_scope("ssm_proj"):
+                    # A layer that keeps a state (Mamba-2, short
+                    # convolution): its layer of the stacked states, in
+                    # place.
+                    mixer, proj_scope, out_scope = llama.state_mixer(kind)
+                    with jax.named_scope(proj_scope):
                         h = llama.rms_norm(x, layer["attn_norm"],
                                            cfg.norm_eps).astype(cfg.dtype)
-                    out, *state = mamba2.decode(
-                        h, layer, cfg, *state,
-                        llama.layer_index(p, n_ssm, i), active)
-                    with jax.named_scope("ssm_out"):
+                    m = llama.layer_index(p, n_of(kind), i)
+                    if l0:
+                        m = m + cfg.layers_before(l0, kind)
+                    out, *state = mixer.decode(h, layer, part, *state, m,
+                                               active)
+                    with jax.named_scope(out_scope):
                         x = llama.residual_add(x, out, cfg)
                     x, _aux, rows_j = llama.ffn_half(
-                        x, layer, cfg, valid=active[:, None],
+                        x, layer, part, valid=active[:, None],
                         layer_index=llama.layer_index(p, plen, j))
                 expert_rows.append(rows_j)
             return (x, ck, cv, *state), llama.stack_period(expert_rows,
@@ -320,15 +334,18 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         # scanned stack: the same body over each part's own weights (a
         # dense part computes no expert's rows).
         with jax.named_scope("layer_scan"):
+            expert_rows = []
             for part, key, l0 in cfg.parts():
                 sliced, stacks = llama.split_expert_stacks(params[key], part)
-                (x, ck, cv, *state), expert_rows = jax.lax.scan(
+                (x, ck, cv, *state), rows_part = jax.lax.scan(
                     functools.partial(body, part=part, sliced=sliced,
                                       stacks=stacks, l0=l0),
                     (x, ck, cv, *state),
                     (llama.scanned_layers(sliced, part),
-                     jnp.arange(part.n_layers // plen, dtype=jnp.int32)))
-            expert_rows = llama.merge_periods(expert_rows, cfg)
+                     jnp.arange(part.n_layers // part.period_len,
+                                dtype=jnp.int32)))
+                expert_rows.append(llama.merge_periods(rows_part, part))
+            expert_rows = llama.over_parts(expert_rows)
         with jax.named_scope("head"):
             x = llama.rms_norm(x, params["final_norm"],
                                cfg.norm_eps).astype(cfg.dtype)
@@ -358,19 +375,25 @@ def _write(pool, l, slots, pos, new):
 
 
 # What rides the carry after the lengths, if the cache has it: a model
-# has Mamba states or window rings, never both.
-_CARRIED = (("ssm", "conv"), ("wk", "wv"))
+# has Mamba states, conv states or window rings, never two of them.  And
+# the axis of a state leaf that counts the slots.
+_CARRIED = (("ssm", "conv"), ("conv",), ("wk", "wv"))
+_SLOT_AXIS = {"ssm": 1, "conv": 2}
+
+
+def _state_names(cache):
+    """The leaves of ``cache`` that ride the carry after the lengths."""
+    return next((names for names in _CARRIED if names[0] in cache), ())
 
 
 def _carry(cache, tok, lens):
     """A cache tree as ``decode_step``'s carry: K, V, the tokens, the
-    lengths, then the Mamba states or the window rings if the model has
+    lengths, then the states or the window rings if the model has
     them."""
     if "latent" in cache:       # the one leaf, where K lies; no V
         return (cache["latent"], None, tok, lens)
     return (cache["k"], cache["v"], tok, lens,
-            *(cache[name] for names in _CARRIED for name in names
-              if name in cache))
+            *(cache[name] for name in _state_names(cache)))
 
 
 def _uncarry(carry, cache):
@@ -378,8 +401,8 @@ def _uncarry(carry, cache):
     ck, cv, tok, lens, *state = carry
     if "latent" in cache:
         return {"latent": ck}, tok, lens
-    names = next((names for names in _CARRIED if names[0] in cache), ())
-    return {"k": ck, "v": cv, **dict(zip(names, state))}, tok, lens
+    return ({"k": ck, "v": cv, **dict(zip(_state_names(cache), state))},
+            tok, lens)
 
 
 # ------------------------------------------------------------- dense plane

@@ -62,6 +62,10 @@ class MoEConfig:
     # share; the stacks are ``[count, ...]``), of the ``n_experts`` the
     # router scores.  (): all of them.
     held: Tuple[int, ...] = ()
+    # How the router scores an expert: "softmax" over all of them, or
+    # "sigmoid" each alone.  With a ``router_bias`` leaf in the params the
+    # top-k are those of score + bias and the gates the chosen scores.
+    score: str = "softmax"
 
     @property
     def act(self):
@@ -106,15 +110,31 @@ def _einsum(eq, *args):
 
 @jax.named_scope("router")
 def _route(xt: jax.Array, router: jax.Array, k: int, norm_topk: bool,
-           groups: int = 0, top_groups: int = 0, scale: float = 1.0):
+           groups: int = 0, top_groups: int = 0, scale: float = 1.0,
+           score: str = "softmax", bias: Optional[jax.Array] = None):
     """The one routing function (dropless, dense dispatch and the parity
     reference cannot drift): f32 softmax over all experts, top-k, the k
     gates renormalised to sum to one or left as they are, then x
     ``scale``.  With ``groups`` the top-k is taken among the experts of
     the ``top_groups`` groups whose best expert scores highest; the
-    others' probabilities count as 0."""
+    others' probabilities count as 0.  ``score`` "sigmoid": an expert's
+    score is the sigmoid of its own logit.  ``bias`` (E,) float32: the k
+    experts are those with the largest score + bias, the gates their
+    SCORES without it, renormalised over ``sum + 1e-6`` (LFM2)."""
     logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
                         router.astype(jnp.float32))
+    if score == "sigmoid" or bias is not None:
+        probs = jax.nn.sigmoid(logits) if score == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        choose_from = probs if bias is None \
+            else probs + bias.astype(jnp.float32)
+        _, expert_idx = jax.lax.top_k(choose_from, k)
+        gate_vals = jnp.take_along_axis(probs, expert_idx, axis=-1)
+        if norm_topk:
+            gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-6)
+        if scale != 1.0:
+            gate_vals = gate_vals * scale
+        return probs, gate_vals, expert_idx
     probs = jax.nn.softmax(logits, axis=-1)          # (T, E)
     choose_from = probs
     if groups:
@@ -186,7 +206,7 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     probs, gate_vals, expert_idx = _route(
         xt if route_x is None else route_x.reshape(T, D),
         params["router"], K, c.norm_topk, c.groups, c.top_groups,
-        c.routed_scale)
+        c.routed_scale, c.score, params.get("router_bias"))
     with jax.named_scope("expert_dispatch"):
         flat = expert_idx.reshape(T * K)
         if valid is not None:
@@ -284,9 +304,9 @@ def moe_ffn(x: jax.Array, params: PyTree, config: MoEConfig
     dt = c.dtype
     xt = x.reshape(T, D).astype(dt)
 
-    probs, gate_vals, expert_idx = _route(xt, params["router"], K,
-                                          c.norm_topk, c.groups,
-                                          c.top_groups, c.routed_scale)
+    probs, gate_vals, expert_idx = _route(
+        xt, params["router"], K, c.norm_topk, c.groups, c.top_groups,
+        c.routed_scale, c.score, params.get("router_bias"))
 
     capacity = int(max(1, round(T * K / E * c.capacity_factor)))
 
@@ -340,9 +360,9 @@ def moe_ffn_reference(x: jax.Array, params: PyTree, config: MoEConfig
     B, S, D = x.shape
     dt = c.dtype
     xt = x.reshape(-1, D).astype(dt)
-    _probs, gate_vals, expert_idx = _route(xt, params["router"],
-                                           c.top_k, c.norm_topk, c.groups,
-                                           c.top_groups, c.routed_scale)
+    _probs, gate_vals, expert_idx = _route(
+        xt, params["router"], c.top_k, c.norm_topk, c.groups, c.top_groups,
+        c.routed_scale, c.score, params.get("router_bias"))
 
     def per_expert(e):
         h = xt.astype(dt) @ params["w_gate"][e].astype(dt)

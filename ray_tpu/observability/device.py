@@ -522,6 +522,7 @@ SCOPES = (
     "router", "expert_dispatch", "expert_ffn", "shared_expert",
     "mla_absorb", "mla_expand", "mla_decode_attention",
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_state_update", "ssm_out",
+    "conv_proj", "short_conv", "conv_out",
     "head", "sample", "head_loss", "optimizer",
 )
 PHASES = ("forward", "backward", "remat")

@@ -29,8 +29,9 @@ whose per-call host↔device round trip is tens of milliseconds:
   decode step (``llama_serve.decode_step``: one K/V row per slot
   written in place), the paged plane on its gathered block tables.
   The dense plane's cache is an opaque tree (``llama_serve.init_cache``):
-  for a model with state-space layers it holds each slot's recurrent
-  and conv states beside K/V, a prefill replaces a slot's whole state
+  for a model with layers that keep a state (state-space: a recurrent
+  and a conv state; short convolution: a conv state alone) it holds each
+  slot's states beside K/V, a prefill replaces a slot's whole state
   and a chunk advances the active slots' in place.  Such a model is
   served by the dense plane alone: blocks, shared prefixes, a draft's
   rewind, the K/V hand-off and ``kv_quant`` all need rows by position,
@@ -357,14 +358,17 @@ class LLMServer:
             ("speculative decoding (spec_k)", self.spec_k > 0),
             ("prefill/decode disaggregation (role)", role != "both"),
         ) if on]
-        if asked and self.cfg.layers_of("mamba"):
+        stateful = [name for kind, name in (
+            ("mamba", "state-space"), ("conv", "short-convolution"))
+            if self.cfg.layers_of(kind)]
+        if asked and stateful:
             raise ValueError(
-                f"{model_preset} has state-space layers, whose "
-                f"recurrent state is one array a slot, not rows by "
-                f"position: it cannot be cut into blocks, shared by "
-                f"prefix, rewound after a rejected draft, handed off "
-                f"as K/V blocks or quantized as K/V rows.  Refused: "
-                f"{'; '.join(asked)}")
+                f"{model_preset} has layers that keep a state a slot "
+                f"({', '.join(stateful)}): a recurrent or conv state is "
+                f"one array a slot, not rows by position: it cannot be "
+                f"cut into blocks, shared by prefix, rewound after a "
+                f"rejected draft, handed off as K/V blocks or quantized "
+                f"as K/V rows.  Refused: {'; '.join(asked)}")
         if asked and self.cfg.layers_of("window"):
             raise ValueError(
                 f"{model_preset} has window layers, whose K/V is a "
@@ -414,9 +418,12 @@ class LLMServer:
             params = llama.init_params(jax.random.key(seed), self.cfg)
         # One-time cast: per-use .astype(c.dtype) in the forward becomes
         # a no-op; identical numerics, half the weight bytes per step.
-        self.params = jax.tree.map(
-            lambda x: x.astype(self.cfg.dtype)
-            if x.dtype == jnp.float32 else x, params)
+        # (but for the leaves that stay float32: a router's selection bias)
+        self.params = jax.tree_util.tree_map_with_path(
+            lambda path, x: x.astype(self.cfg.dtype)
+            if x.dtype == jnp.float32
+            and getattr(path[-1], "key", None) not in llama.FLOAT32_LEAVES
+            else x, params)
 
         # Host-authoritative slot state (device carries mirror it
         # between chunk launches).
@@ -443,9 +450,10 @@ class LLMServer:
             self._init_draft(draft_preset, draft_layers, draft_params,
                              seed)
 
-        # A model with state-space layers: the bytes one slot's states
-        # hold ({} for any other: nothing is emitted for it), and the
-        # pools' sizes for the operator.
+        # A model with layers that keep a state (state-space, short
+        # convolution): the bytes one slot's states hold ({} for any
+        # other: nothing is emitted for it), and the pools' sizes for the
+        # operator.
         self._state_bytes = llama_serve.state_bytes_per_slot(self.cfg)
         self._state_tags = {kind: {**self._tags, "kind": kind}
                             for kind in self._state_bytes}
@@ -555,12 +563,13 @@ class LLMServer:
         self._np_max = max(1, (max(self.buckets) - 1) // bs)
 
     def _publish_state_pool(self) -> None:
-        """The dense cache of a model with state-space or window layers,
-        by what it holds: K/V under ``ray_tpu_kv_pool_bytes`` (a model
-        with window layers: ``<deployment>.kv_full`` and
-        ``<deployment>.kv_window``; one with latent attention:
-        ``<deployment>.latent``), the recurrent and conv states under
-        ``ray_tpu_state_pool_bytes``."""
+        """The dense cache of a model with layers that keep a state or
+        with window layers, by what it holds: K/V under
+        ``ray_tpu_kv_pool_bytes`` (a model with window layers:
+        ``<deployment>.kv_full`` and ``<deployment>.kv_window``; one with
+        latent attention: ``<deployment>.latent``), the recurrent and conv
+        states under ``ray_tpu_state_pool_bytes`` (a model with
+        short-convolution layers: ``conv`` alone)."""
         from ray_tpu.models import llama_serve
 
         self._pools = llama_serve.cache_pools(self.cfg, self.max_slots,
@@ -1896,9 +1905,9 @@ class LLMServer:
         """A chunk's traffic in recurrent and conv state, host side, from
         what the launch held: ``rows`` (slot, step) pairs advanced a
         state, each one read and one write of the slot's states over all
-        Mamba layers -- the bytes that have to move, and all that do
-        (``ops/ssm_state_update.py`` touches no other slot).  Nothing for
-        a model without such layers."""
+        the layers that keep one -- the bytes that have to move
+        (``ops/ssm_state_update.py`` touches no other slot's recurrent
+        state).  Nothing for a model without such layers."""
         if not self._state_bytes:
             return {}
         total = 0
@@ -1923,7 +1932,7 @@ class LLMServer:
         m["prefill_prompt_tokens"].inc(tokens, tags=self._tags)
         m["prefill_padded_tokens"].inc(computed, tags=self._tags)
         scan = {}
-        if self._state_bytes:
+        if "ssm" in self._state_bytes:
             # chunks of the state-space scan the padded group computed,
             # a Mamba layer
             scan["scan_chunks"] = rows * -(-bucket // min(
