@@ -97,7 +97,7 @@ class LlamaConfig:
     # dims (XLA's dots_with_no_batch_dims_saveable — but it saves the
     # F32 dot results, ~830 MB/layer at bench shapes: OOM on one v5e);
     # "attn" saves only the flash kernel's residuals (q/k/v/o bf16 +
-    # width-1 lse, ~129 MB/layer) so backward skips re-running the
+    # lane-dense f32 lse, ~129 MB/layer) so backward skips re-running the
     # attention forward while still rematerializing the FFN — the best
     # measured time/memory point on v5e; ignored when remat=False.
     remat_policy: str = "full"
@@ -2076,9 +2076,9 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
     if tokens.shape[1] > FLASH_PREFILL_FROM:  # raylint: disable=recompile-hazard -- the engine's prefill shapes are its buckets, each warmed once; which attention a bucket takes is fixed with its shape
         from ray_tpu.ops.flash_attention import flash_prefill_attention
 
-        # A latent model's call writes no softmax statistics: 128 heads'
-        # width-1 ``lse`` is laid out 128 lanes wide, 768 MB at 12,288
-        # positions that only a backward pass reads.
+        # A latent model's call writes no softmax statistics: only a
+        # backward pass reads ``lse`` (6 MB for 128 heads at 12,288
+        # positions since it is lane-dense, 768 MB as a width-1 column).
         def attend(q, k, v, positions, window):
             return flash_prefill_attention(q, k, v, scale=scale,
                                            window=window,

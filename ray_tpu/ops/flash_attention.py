@@ -17,7 +17,16 @@ leave the chip.  Design points:
   (``DIAG_STRIP``); the forward computes it whole and masks it.
 - f32 accumulators in VMEM scratch; running (m, l) kept lane-replicated
   (shape (block_q, 128)) per TPU layout rules.
-- lse is saved for the backward (recompute-based, à la FA-2).
+- lse is saved for the backward (recompute-based, à la FA-2).  It and
+  the backward's ``delta`` cross HBM lane-dense, ``(B, H, 1, S)`` float32
+  (4 KB a 1,024-row block; as ``(B, H, S, 1)`` the chip padded them
+  128-fold).  The kernels turn them: the forward writes the row from its
+  lane-replicated (m, l) once a q block at ``_finalize``, dq makes of it
+  the column its score tile broadcasts against once a q block
+  (``_store_stats`` / ``_load_stats``: 128 x 128 transposes), dk/dv
+  computes its tile keys x queries and takes the row as it is.  A q
+  block that is not whole lanes keeps the width-1 column
+  (``_stats_dense``).
 
 Interpret mode runs the same kernels on CPU for the simulated-mesh
 test suite.
@@ -89,6 +98,86 @@ def _supported(sq: int, sk: int, d: int) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# The per-row softmax statistics (lse, delta) in HBM and in VMEM
+# ---------------------------------------------------------------------------
+
+# ``lse`` and ``delta`` are one float32 a query row.  A q block of whole
+# lanes keeps them lane-dense, ``(B, H, 1, S)`` with position ``p`` at
+# ``[0, p]``: a 1,024-row block is a 4 KB copy and the array is dense in
+# HBM (``T(1,128)``; AOT, PR 41).  As a width-1 column ``(B, H, S, 1)``
+# the tiled layout padded them 128-fold: 125.8 MB a layer each at 8 x 15
+# x 2,048 for 0.98 MB of numbers, a 512 KB copy a block.  Chosen over
+# ``(B, H, S / 128, 128)``, which read the same to 0.002 ms in all three
+# kernels at both train cells' shapes (tools/flash_sweep.py, chip run, PR
+# 41; PERF.md section 6), because its block ``(1, block_q)`` is legal for
+# every ``block_q`` of whole lanes, where ``(block_q / 128, 128)`` has to
+# be 8 rows or the whole sequence.  A block that is NOT whole lanes
+# (``block_q % 128``: interpret-mode tests, a short or odd Sq) keeps the
+# width-1 column.  ``block_q`` alone decides, here, never a caller.
+
+def _stats_dense(block_q: int) -> bool:
+    return block_q % LANES == 0
+
+
+def _stats_shape(b: int, h: int, sq: int, block_q: int):
+    return (b, h, 1, sq) if _stats_dense(block_q) else (b, h, sq, 1)
+
+
+def _stats_spec(shape, block_q: int, q_map):
+    """BlockSpec of a q block's statistics in an array of ``shape`` (a
+    column, or rows of whole lanes a block); ``q_map`` is the index map
+    of the block's ``(1, 1, block_q, D)`` rows."""
+    if shape[-1] == 1:
+        return pl.BlockSpec((1, 1, block_q, 1), q_map)
+
+    def row_map(*grid):
+        b, h, qi, _ = q_map(*grid)
+        return (b, h, 0, qi)
+
+    return pl.BlockSpec((1, 1, 1, block_q + -block_q % LANES), row_map)
+
+
+def _stats_rows(x, block_q: int):
+    """Statistics as lane-dense rows whatever the block: the lane-dense
+    layout as it is; a width-1 column (XLA's copy, of a short or odd
+    sequence) with each block's ``block_q`` values at the head of a row of
+    whole lanes, ``(B, H, 1, blocks x lanes)``."""
+    if _stats_dense(block_q):
+        return x
+    b, h = x.shape[:2]
+    x = jnp.pad(x.reshape(b, h, -1, block_q),
+                ((0, 0), (0, 0), (0, 0), (0, -block_q % LANES)))
+    return x.reshape(b, h, 1, -1)
+
+
+# A queries x keys score tile wants a row's statistic on every lane of
+# the row; the lane-dense block has 128 rows' on the lanes of one sublane.
+# The turn between them is a 128 x 128 transpose through the XLU of the
+# 128 values replicated: exact, and once a q block.
+
+def _store_stats(ref, rep):
+    """Write a q block's statistics from ``rep`` (block_q, LANES),
+    lane-replicated as ``m_scr`` / ``l_scr`` are."""
+    if ref.shape[-1] == 1:
+        ref[0, 0, :, :] = rep[:, :1]
+        return
+    for lo in range(0, rep.shape[0], LANES):
+        ref[0, 0, :, lo:lo + LANES] = rep[lo:lo + LANES, :].T[:1, :]
+
+
+def _load_stats(ref, scr):
+    """Turn a q block's statistics into ``scr`` (block_q, LANES),
+    lane-replicated: ``scr[rows, :1]`` is the column a score tile
+    broadcasts against, sliced by rows as ``q_ref`` is."""
+    if ref.shape[-1] == 1:
+        scr[:] = jnp.broadcast_to(ref[0, 0, :, :], scr.shape)
+        return
+    for lo in range(0, scr.shape[0], LANES):
+        scr[lo:lo + LANES, :] = jnp.broadcast_to(
+            ref[0, 0, :, lo:lo + LANES], (LANES, LANES)).T
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
@@ -140,11 +229,13 @@ def causal_computed_share(seq: int, block_q: Optional[int] = None,
     return computed / (seq * seq)
 
 
-def _causal_mask(s, row0, col0):
+def _causal_mask(s, row0, col0, query_axis=0):
     """Scores of keys after their query -> NEG_INF; ``row0`` / ``col0``
-    place the block's first row and column on one axis."""
-    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    place the block's first query and first key on one axis, queries along
+    ``query_axis`` of ``s`` (1: the tile is keys x queries)."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, query_axis)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                           1 - query_axis)
     return jnp.where(rows >= cols, s, NEG_INF)
 
 
@@ -259,13 +350,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # merging ignores them.
         l_safe = jnp.where(l > 0.0, l, 1.0)
         o_ref[0, 0, :, :] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # lse output is width-1 (not lane-replicated): a (B,H,S,LANES)
-        # f32 lse is 134 MB/layer of pure HBM traffic at bench shapes.
         if lse_ref is not None:
-            lse = jnp.where(l > 0.0,
-                            m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-37)),
-                            NEG_INF)
-            lse_ref[0, 0, :, :] = lse
+            # on every lane of a row, as m and l are kept; the lane-dense
+            # block takes the 128 x 128 turn of it (``_store_stats``)
+            l_rep = l_scr[:]
+            _store_stats(lse_ref, jnp.where(
+                l_rep > 0.0, m_scr[:] + jnp.log(jnp.maximum(l_rep, 1e-37)),
+                NEG_INF))
 
 
 def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
@@ -273,8 +364,10 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     """q: (B, Hq, Sq, D) pre-scaled; k: (B, Hkv, Sk, D); v: (B, Hkv, Sk,
     Dv), a head of its own width where the model's values have one
     (latent attention: 192-wide q/k, 128-wide v).
-    Returns o (B, Hq, Sq, Dv), lse (B, Hq, Sq, 1) f32 (None without
-    ``with_lse``: a forward nothing differentiates).  ``window`` (causal
+    Returns o (B, Hq, Sq, Dv), lse f32 (None without ``with_lse``: a
+    forward nothing differentiates) as ``_stats_shape`` lays it out:
+    (B, Hq, 1, Sq) where the q block is whole lanes, else (B, Hq, Sq, 1);
+    ``lse.reshape(B, Hq, Sq)`` is a row's in either.  ``window`` (causal
     only): a query sees its last ``window`` keys, its own among them."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -307,10 +400,11 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
                                nk=nk, causal=causal,
                                **({} if window is None
                                   else {"window": window}))
+    lse_shape = _stats_shape(B, Hq, Sq, bq)
     out_specs = [pl.BlockSpec((1, 1, bq, Dv), o_map),
-                 pl.BlockSpec((1, 1, bq, 1), o_map)]
+                 _stats_spec(lse_shape, bq, o_map)]
     out_shape = [jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
-                 jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32)]
+                 jax.ShapeDtypeStruct(lse_shape, jnp.float32)]
     if not with_lse:
         with_stats = kernel
 
@@ -353,13 +447,15 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, block_q, block_k, nk, causal):
+               dq_scr, lse_scr, delta_scr, *, block_q, block_k, nk, causal):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        _load_stats(lse_ref, lse_scr)
+        _load_stats(delta_ref, delta_scr)
 
     if causal:
         should_run = ki * block_k <= qi * block_q + block_q - 1
@@ -375,8 +471,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0, c, :]
         v = v_ref[0, 0, c, :]
         do = do_ref[0, 0, r, :]
-        lse = lse_ref[0, 0, r, :1]
-        delta = delta_ref[0, 0, r, :1]
+        lse = lse_scr[r, :1]
+        delta = delta_scr[r, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if mask is not None:
@@ -415,27 +511,32 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         should_run = True
 
     def _compute(rows, cols, mask):
+        # The tile keys x queries, ``K Q^T``: a query's statistic is then
+        # wanted on the query's LANE, where the lane-dense row has it, so
+        # this kernel turns nothing; and dv = P^T dO, dk = dS^T Q take
+        # ``pt`` / ``dst`` as they are made, where P and dS had to go
+        # through the XLU transposed (chip run, PR 41: 2.29 -> 1.84 ms at
+        # 8 x 15 x 2,048 x 64).  Same products, same float32 sums.
         r, c = slice(*rows), slice(*cols)
         q = q_ref[0, 0, r, :]
         k = k_ref[0, 0, c, :]
         v = v_ref[0, 0, c, :]
         do = do_ref[0, 0, r, :]
-        lse = lse_ref[0, 0, r, :1]
-        delta = delta_ref[0, 0, r, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if mask is not None:
-            s = _causal_mask(s, *mask)
-        p = jnp.exp(s - lse)
-        pt = p.astype(do.dtype)
-        dv_scr[c, :] = dv_scr[c, :] + jax.lax.dot_general(
-            pt, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        lse = lse_ref[0, 0, :, r]
+        delta = delta_ref[0, 0, :, r]
+        st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)
+        if mask is not None:
+            st = _causal_mask(st, *mask, query_axis=1)
+        pt = jnp.exp(st - lse)
+        dv_scr[c, :] = dv_scr[c, :] + jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
         dk_scr[c, :] = dk_scr[c, :] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            dst, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     _causal_dispatch(_compute, causal, should_run, qi, ki,
@@ -449,15 +550,17 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
               interpret):
-    """All inputs (B, Hq, S, D) (k/v pre-expanded to q heads); returns
-    (dq, dk, dv) at q-head granularity, un-scaled."""
+    """q, k, v, o, do (B, Hq, S, D) (k/v pre-expanded to q heads), lse as
+    ``_fwd`` returns it for these blocks; returns (dq, dk, dv) at q-head
+    granularity, un-scaled.  ``delta`` is made in ``lse``'s layout: no
+    (B, Hq, S, 1) value exists where the q block is whole lanes."""
     B, Hq, Sq, D = q.shape
     Sk = k.shape[2]
     bq, bk = _block_sizes(Sq, Sk, block_q, block_k)
     nq, nk = Sq // bq, Sk // bk
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # (B, Hq, Sq, 1)
+                    axis=-1).reshape(lse.shape)
 
     def q_map(b, h, qi, ki):
         return (b, h, qi, 0)
@@ -477,12 +580,14 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             pl.BlockSpec((1, 1, bk, D), k_map_q),
             pl.BlockSpec((1, 1, bk, D), k_map_q),
             pl.BlockSpec((1, 1, bq, D), q_map),
-            pl.BlockSpec((1, 1, bq, 1), q_map),
-            pl.BlockSpec((1, 1, bq, 1), q_map),
+            _stats_spec(lse.shape, bq, q_map),
+            _stats_spec(lse.shape, bq, q_map),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), q_map),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+                        pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
@@ -501,6 +606,7 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             qi = jax.lax.select(bq * qi + bq - 1 >= bk * ki, qi, nq - 1)
         return (b, h, qi, 0)
 
+    lse_rows, delta_rows = _stats_rows(lse, bq), _stats_rows(delta, bq)
     dkdv_call = pl.pallas_call(
         functools.partial(_dkdv_kernel, block_q=bq, block_k=bk, nq=nq,
                           causal=causal),
@@ -511,8 +617,8 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             pl.BlockSpec((1, 1, bk, D), kv_map),
             pl.BlockSpec((1, 1, bk, D), kv_map),
             pl.BlockSpec((1, 1, bq, D), q_map_kv),
-            pl.BlockSpec((1, 1, bq, 1), q_map_kv),
-            pl.BlockSpec((1, 1, bq, 1), q_map_kv),
+            _stats_spec(lse_rows.shape, bq, q_map_kv),
+            _stats_spec(lse_rows.shape, bq, q_map_kv),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, D), kv_map),
@@ -532,7 +638,7 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
         interpret=interpret,
     )
     with jax.named_scope("flash_attention.dkdv"):
-        dk, dv = dkdv_call(q, k, v, do, lse, delta)
+        dk, dv = dkdv_call(q, k, v, do, lse_rows, delta_rows)
     return dq, dk, dv
 
 
@@ -619,6 +725,8 @@ def _flash(q, k, v, causal, block_q, block_k):
     kt = _named_packed(kt, "flash_k")
     vt = _named_packed(vt, "flash_v")
     o = _named_packed(o, "flash_o")
+    # lane-dense, lse has nothing to pack: the residual the "attn" remat
+    # policy saves IS the kernels' operand (a width-1 column still packs)
     lse = _named_packed(lse, "flash_lse")
     out = _flash_core(qt, kt, vt, o, lse, causal, block_q, block_k)
     return jnp.transpose(out, (0, 2, 1, 3))

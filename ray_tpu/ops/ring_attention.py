@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import (LANES, NEG_INF, _bwd_impl, _fwd,
+from .flash_attention import (NEG_INF, _bwd_impl, _fwd,
                               _use_interpret, flash_attention)
 
 PPERM_AXIS_DOC = "seq"
@@ -37,12 +37,14 @@ PPERM_AXIS_DOC = "seq"
 
 def _merge(o_acc, lse_acc, o_c, lse_c):
     """Merge two normalized partial attention results.
-    o: (B,H,S,D) f32; lse: (B,H,S,LANES) f32 (lane-replicated)."""
+    o: (B,H,S,D) f32; lse: f32 in the layout the flash kernels keep it
+    in, a row's position the same in row-major order in each of them."""
     m = jnp.maximum(lse_acc, lse_c)
     a = jnp.exp(lse_acc - m)
     b = jnp.exp(lse_c - m)
     denom = a + b
-    o = (o_acc * a[..., :1] + o_c * b[..., :1]) / denom[..., :1]
+    a, b, per_row = (x.reshape(*o_acc.shape[:3], 1) for x in (a, b, denom))
+    o = (o_acc * a + o_c * b) / per_row
     return o, m + jnp.log(denom)
 
 

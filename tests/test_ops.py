@@ -119,6 +119,59 @@ def test_flash_strips_match_reference(S, Hq, Hkv, D, block_q, block_k,
                                    atol=1e-4, rtol=1e-4, err_msg=name)
 
 
+@pytest.mark.parametrize("Hq,Hkv", [(4, 2), (2, 2)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("S", [256, 1024, 2048])
+def test_flash_statistics_are_lane_dense_and_round_trip(S, Hq, Hkv):
+    """The forward's ``lse`` is ``(B, H, 1, S)``, position ``p`` at
+    ``[0, p]``, and is the log-sum-exp of the reference's causal scores
+    there; handed on in that layout (``delta`` takes it), dq and dk/dv turn
+    it back and give the reference's gradients."""
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    B, D = 1, 32
+    q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in _rand_qkv(
+        jax.random.key(S + Hq), B, S, Hq, Hkv, D))
+    do = jax.random.normal(jax.random.key(11), q.shape)
+    k_full, v_full = (jnp.repeat(x, Hq // Hkv, axis=1) for x in (k, v))
+    kw = dict(causal=True, block_q=None, block_k=None, interpret=True)
+    assert fa._stats_dense(fa._block_sizes(S, S, None, None)[0])
+
+    def ref(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+        s = jnp.where(jnp.tri(S, dtype=bool), s, -jnp.inf)
+        return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v),
+                jax.nn.logsumexp(s, axis=-1))
+
+    (o_ref, lse_ref), vjp = jax.vjp(ref, q, k_full, v_full)
+    o, lse = fa._fwd(q, k, v, **kw)
+    assert lse.shape == (B, Hq, 1, S) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse[:, :, 0, :]),
+                               np.asarray(lse_ref), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               atol=2e-5, rtol=2e-5)
+    grads = fa._bwd_impl(q, k_full, v_full, o, lse, do, **kw)
+    for a, b, name in zip(grads, vjp((do, jnp.zeros_like(lse_ref))), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("Sq,block_q", [(64, None), (256, 32), (192, 64)])
+def test_flash_statistics_keep_the_column_under_a_lane_of_rows(Sq, block_q):
+    """A q block that is not whole lanes keeps the width-1 column, and
+    both layouts hold a row's statistic at the same row-major place (what
+    the ring's merge leans on)."""
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in _rand_qkv(
+        jax.random.key(Sq), 1, Sq, 2, 2, 32))
+    kw = dict(causal=True, block_k=None, interpret=True)
+    o, lse = fa._fwd(q, k, v, block_q=block_q, **kw)
+    assert lse.shape == (1, 2, Sq, 1)
+    if Sq % 128 == 0:
+        _, dense = fa._fwd(q, k, v, block_q=128, **kw)
+        assert dense.shape == (1, 2, 1, Sq)
+        np.testing.assert_allclose(np.asarray(dense).reshape(lse.shape),
+                                   np.asarray(lse), atol=1e-6, rtol=1e-6)
+
+
 @pytest.mark.parametrize("by", ["cols", "rows"])
 def test_diag_strips_cover_the_causal_half_once(by):
     """No masked element contributes, no unmasked element is skipped: the

@@ -4,10 +4,12 @@
 
 Both train cells' shapes (PERF.md section 4), causal, bf16; per (block,
 strip) the device time a call of forward, dq and dk/dv, read from a trace
-by the kernels' names with the benchmark's own reader, and the score elements
-the three compute a second.  ``strip`` is dq's and dk/dv's; ``strip ==
-block`` is a diagonal tile computed whole and masked by a select, as the
-forward always computes it.  What it read last stands over
+by the kernels' names with the benchmark's own reader, beside each the MB
+the call moves between HBM and VMEM by its own BlockSpecs
+(``blockspec_bytes``) and, last, the GB/s of the three together and the
+score elements they compute a second.  ``strip`` is dq's and dk/dv's;
+``strip == block`` is a diagonal tile computed whole and masked by a
+select, as the forward always computes it.  What it read last stands over
 ``DEFAULT_BLOCK`` in ``ray_tpu/ops/flash_attention.py``.
 """
 
@@ -16,6 +18,8 @@ import tempfile
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.extend.core import jaxpr_as_fun
 
 from benchmarks.lib import flash_names, trace_reduce
 
@@ -35,6 +39,31 @@ def kernel_ms(fn, args, calls=10):
             for kernel in KERNELS}
 
 
+def blockspec_bytes(fn, *args):
+    """Bytes every ``pallas_call`` directly inside ``fn`` moves between
+    HBM and VMEM, by its name: each operand's and result's block, its
+    minor dimension padded to whole lanes as the tiled layout stores it,
+    once for every step of the grid at which its index map names another
+    block than at the step before (what the pipeline copies)."""
+    moved = {}
+    for eqn in jax.make_jaxpr(fn)(*args).jaxpr.eqns:
+        if eqn.primitive.name != "pallas_call":
+            continue
+        mapping = eqn.params["grid_mapping"]
+        steps = np.indices(mapping.grid).reshape(len(mapping.grid), -1)
+        total = 0
+        for block in mapping.block_mappings:
+            index_map = jaxpr_as_fun(block.index_map_jaxpr)
+            index = np.stack(jax.vmap(index_map)(*steps), axis=1)
+            copies = 1 + np.count_nonzero((index[1:] != index[:-1]).any(1))
+            *outer, lanes = block.block_aval.shape
+            lanes += -lanes % fa.LANES
+            total += (copies * int(np.prod(outer)) * lanes
+                      * block.array_aval.dtype.itemsize)
+        moved[eqn.params["name"]] = total
+    return moved
+
+
 def sweep(blocks=(512, 1024), strips=(128, 256, 512, None)):
     shipped = fa.DIAG_STRIP
     try:
@@ -44,7 +73,9 @@ def sweep(blocks=(512, 1024), strips=(128, 256, 512, None)):
 
 
 def _sweep(blocks, strips):
-    print("B S Hq Hkv D block strip fwd_ms dq_ms dkdv_ms Gelem/s")
+    print("B S Hq Hkv D block strip", *(f"{kernel}_ms {kernel}_MB"
+                                        for kernel in KERNELS),
+          "GB/s Gelem/s")
     for B, S, Hq, Hkv, D in SHAPES:
         keys = jax.random.split(jax.random.key(S), 4)
         q, do = (jax.random.normal(k, (B, Hq, S, D), jnp.bfloat16)
@@ -57,21 +88,25 @@ def _sweep(blocks, strips):
                 kw = dict(causal=True, block_q=block, block_k=block,
                           interpret=fa._use_interpret())
 
-                @jax.jit
                 def three(q, k, v, do):
                     o, lse = fa._fwd(q, k, v, **kw)
                     kf, vf = (jnp.repeat(x, Hq // Hkv, axis=1)
                               for x in (k, v))
                     return o, fa._bwd_impl(q, kf, vf, o, lse, do, **kw)
 
-                ms = kernel_ms(three, (q, k, v, do))
+                ms = kernel_ms(jax.jit(three), (q, k, v, do))
+                by_name = blockspec_bytes(three, q, k, v, do)
+                moved = {kernel: by_name[f"flash_attention_{kernel}"]
+                         for kernel in KERNELS}
                 share = fa.causal_computed_share    # the forward: no strips
                 elems = B * Hq * S * S * (share(S, block, block, block)
                                           + 2 * share(S, block, block, strip))
+                seconds = sum(ms.values()) / 1e3 or 1
                 print(B, S, Hq, Hkv, D, block, strip,
-                      *(f"{ms[kernel]:.3f}" for kernel in KERNELS),
-                      f"{elems / (sum(ms.values()) or 1) / 1e6:.1f}",
-                      flush=True)
+                      *(f"{ms[kernel]:.3f} {moved[kernel] / 1e6:.1f}"
+                        for kernel in KERNELS),
+                      f"{sum(moved.values()) / seconds / 1e9:.1f}",
+                      f"{elems / seconds / 1e9:.1f}", flush=True)
 
 
 if __name__ == "__main__":
