@@ -7,10 +7,14 @@ leave the chip.  Design points:
 
 - Layout (B, H, S, D) inside the kernel (S on sublanes, D on lanes);
   the public wrapper takes the model's (B, S, H, D) and transposes.
-- GQA without materializing K/V per q-head in the forward: the kv
-  BlockSpec index-maps ``head // group`` so grouped q-heads share the
-  same K/V blocks.  The backward expands K/V to q-heads (2 extra bf16
-  copies) and group-sums dK/dV — simple and still HBM-light.
+- GQA without materializing K/V per q-head, forward and backward: the
+  kv BlockSpec index-maps ``head // group`` so grouped q-heads share the
+  same K/V blocks (forward, dq).  dk/dv's grid is over KV heads and its
+  one reduction axis walks the group's q heads, each q block of each, so
+  the group is added in the float32 accumulator and K/V are fetched once
+  a kv head.  dq, dk and dv leave the kernels in the type the caller
+  asks for (``_bwd_impl``'s ``out_dtype``): rounded once, in
+  ``_finalize``, with no float32 copy in HBM for XLA to sum and cast.
 - Causal blocks strictly above the diagonal are skipped via
   ``pl.when`` + index-map redirect (no DMA, no compute).  Of a block the
   diagonal crosses, dq and dk/dv compute the causal strips only
@@ -495,11 +499,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr,
-                 *, block_q, block_k, nq, causal):
+                 *, block_q, block_k, nq, group, causal):
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    # The reduction walks the kv head's ``group`` q heads, ``nq`` q blocks
+    # of each (``_bwd_impl``'s ``q_map_kv`` picks the head): ``step`` is
+    # q block ``step % nq`` of whichever head it is.  Head by head, not
+    # block by block: 1.667 against 1.717 ms at 8 x 15 / 5 x 2,048 x 64,
+    # level at 1 x 16 / 8 x 4,096 x 128 (tools/flash_sweep.py, chip run,
+    # PR 42).
+    step = pl.program_id(3)
+    qi = jax.lax.rem(step, nq)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -542,20 +553,24 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _causal_dispatch(_compute, causal, should_run, qi, ki,
                      block_q, block_k, strips="cols")
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == group * nq - 1)
     def _finalize():
         dk_ref[0, 0, :, :] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
-              interpret):
-    """q, k, v, o, do (B, Hq, S, D) (k/v pre-expanded to q heads), lse as
-    ``_fwd`` returns it for these blocks; returns (dq, dk, dv) at q-head
-    granularity, un-scaled.  ``delta`` is made in ``lse``'s layout: no
-    (B, Hq, S, 1) value exists where the q block is whole lanes."""
+              interpret, out_dtype):
+    """q, o, do (B, Hq, Sq, D); k, v (B, Hkv, Sk, D), ``Hq // Hkv`` q
+    heads to a kv head (1: the same program, a one-head walk); lse as
+    ``_fwd`` returns it for these blocks.  Returns dq (B, Hq, Sq, D) and
+    dk, dv (B, Hkv, Sk, D), un-scaled, in ``out_dtype``: each a float32
+    accumulator rounded once as it leaves VMEM.  ``delta`` is made in
+    ``lse``'s layout: no (B, Hq, S, 1) value exists where the q block is
+    whole lanes."""
     B, Hq, Sq, D = q.shape
-    Sk = k.shape[2]
+    _, Hkv, Sk, _ = k.shape
+    group = Hq // Hkv
     bq, bk = _block_sizes(Sq, Sk, block_q, block_k)
     nq, nk = Sq // bq, Sk // bk
 
@@ -568,7 +583,7 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
     def k_map_q(b, h, qi, ki):
         if causal:
             ki = jax.lax.select(bk * ki <= bq * qi + bq - 1, ki, 0)
-        return (b, h, ki, 0)
+        return (b, h // group, ki, 0)
 
     dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=bq, block_k=bk, nk=nk,
@@ -584,7 +599,7 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             _stats_spec(lse.shape, bq, q_map),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), q_map),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), out_dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
                         pltpu.VMEM((bq, LANES), jnp.float32),
                         pltpu.VMEM((bq, LANES), jnp.float32)],
@@ -596,22 +611,24 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
     with jax.named_scope("flash_attention.dq"):
         dq = dq_call(q, k, v, do, lse, delta)
 
-    def kv_map(b, h, ki, qi):
+    def kv_map(b, h, ki, r):
+        # the same block through the whole walk: fetched once a kv head
         return (b, h, ki, 0)
 
-    def q_map_kv(b, h, ki, qi):
+    def q_map_kv(b, h, ki, r):
+        qi = r % nq
         if causal:
-            # Above-diagonal (skipped) blocks: redirect prefetch to the
-            # last q block, which is always executed.
-            qi = jax.lax.select(bq * qi + bq - 1 >= bk * ki, qi, nq - 1)
-        return (b, h, qi, 0)
+            # Above-diagonal (skipped) blocks: redirect the prefetch to
+            # the same q head's first block that runs, the next one wanted.
+            qi = jnp.clip(qi, bk * ki // bq, nq - 1)
+        return (b, h * group + r // nq, qi, 0)
 
     lse_rows, delta_rows = _stats_rows(lse, bq), _stats_rows(delta, bq)
     dkdv_call = pl.pallas_call(
         functools.partial(_dkdv_kernel, block_q=bq, block_k=bk, nq=nq,
-                          causal=causal),
+                          group=group, causal=causal),
         name="flash_attention_dkdv",
-        grid=(B, Hq, nk, nq),
+        grid=(B, Hkv, nk, group * nq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), q_map_kv),
             pl.BlockSpec((1, 1, bk, D), kv_map),
@@ -625,8 +642,8 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
             pl.BlockSpec((1, 1, bk, D), kv_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Sk, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, Sk, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, Sk, D), out_dtype),
+            jax.ShapeDtypeStruct((B, Hkv, Sk, D), out_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
@@ -671,25 +688,16 @@ def _flash_core_fwd(qt, kt, vt, o, lse, causal, block_q, block_k):
 
 def _flash_core_bwd(causal, block_q, block_k, res, g):
     qt, kt, vt, o, lse = res
-    B, Hq, Sq, D = qt.shape
-    Hkv = kt.shape[1]
-    group = Hq // Hkv
-    do = g  # already (B, Hq, Sq, D)
-    k_full = jnp.repeat(kt, group, axis=1)
-    v_full = jnp.repeat(vt, group, axis=1)
-    dq, dk, dv = _bwd_impl(qt, k_full, v_full, o, lse, do,
-                           causal=causal, block_q=block_q,
-                           block_k=block_k, interpret=_use_interpret())
-    # dq is returned w.r.t. the PRE-SCALED qt: the outer qt = q * scale
-    # chain applies the scale factor during transposition (the old
-    # whole-function custom_vjp had to undo it by hand).
-    dk = dk.reshape(B, Hkv, group, -1, D).sum(axis=2)
-    dv = dv.reshape(B, Hkv, group, -1, D).sum(axis=2)
+    # ``g`` is already (B, Hq, Sq, D).  dq is returned w.r.t. the
+    # PRE-SCALED qt: the outer qt = q * scale chain applies the scale
+    # factor during transposition (the old whole-function custom_vjp had
+    # to undo it by hand).
+    dq, dk, dv = _bwd_impl(qt, kt, vt, o, lse, g, causal=causal,
+                           block_q=block_q, block_k=block_k,
+                           interpret=_use_interpret(), out_dtype=qt.dtype)
     # o and lse are functions of q/k/v computed under stop_gradient in
     # the primal; their cotangents are structurally zero.
-    return (dq.astype(qt.dtype), dk.astype(kt.dtype),
-            dv.astype(vt.dtype), jnp.zeros_like(o),
-            jnp.zeros_like(lse))
+    return dq, dk, dv, jnp.zeros_like(o), jnp.zeros_like(lse)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)

@@ -94,7 +94,6 @@ def _ring_bwd(axis_name, axis_size, res, g):
     qt, kt, vt, o, lse = res
     B, Hq, S, D = qt.shape
     Hkv = kt.shape[1]
-    group = Hq // Hkv
     scale = D ** -0.5
     do = jnp.transpose(g, (0, 2, 1, 3))
     idx = jax.lax.axis_index(axis_name)
@@ -109,14 +108,13 @@ def _ring_bwd(axis_name, axis_size, res, g):
             k_rot, v_rot, dk_rot, dv_rot = _rotate(
                 [k_rot, v_rot, dk_rot, dv_rot], axis_name, axis_size)
         src = (idx - step) % axis_size
-        k_full = jnp.repeat(k_rot, group, axis=1)
-        v_full = jnp.repeat(v_rot, group, axis=1)
+        # The kernels do the GQA (K/V taken at kv-head granularity, the
+        # group's dk/dv added in their accumulator); float32 out, because
+        # the chunks of the ring's steps are added here before the one cast.
         dq_c, dk_c, dv_c = _bwd_impl(
-            qt, k_full, v_full, o.astype(qt.dtype), lse, do,
+            qt, k_rot, v_rot, o.astype(qt.dtype), lse, do,
             causal=(step == 0), block_q=None, block_k=None,
-            interpret=interp)
-        dk_c = dk_c.reshape(B, Hkv, group, S, D).sum(axis=2)
-        dv_c = dv_c.reshape(B, Hkv, group, S, D).sum(axis=2)
+            interpret=interp, out_dtype=jnp.float32)
         if step == 0:
             dq = dq + dq_c
             dk_rot = dk_rot + dk_c

@@ -3,8 +3,11 @@ compiled for a v5e that is described, not attached (as
 ``benchmarks/tests/test_aot_real_widths.py`` compiles): ``lse`` and
 ``delta`` reach the three Mosaic calls lane-dense, ``(B, H, 1, S)``
 float32, and nothing of ``(B, H, S, 1)`` — which the tiled layout pads
-128-fold — is made anywhere in the program.  Nothing runs, so nothing here
-is a speed.
+128-fold — is made anywhere in the program; and the backward does its own
+GQA: dq and dk/dv take K and V per KV head and give dq per q head, dk and
+dv per KV head, in the model's type, so no float32 gradient and no K or V
+expanded to the q heads crosses HBM.  Nothing runs, so nothing here is a
+speed.
 
 The topology is described inside a fixture and the compiles run in the
 test's own process: the TPU library loads once, in the worker that gets
@@ -12,6 +15,7 @@ this file.
 """
 
 import importlib
+import math
 import os
 import re
 
@@ -79,6 +83,58 @@ def _held_to_the_lane_dense_layout(hlo, batch, seq, heads):
     assert not _column_values(hlo, seq)
 
 
+def _held_to_the_kernels_own_gqa(hlo, batch, seq, heads, kv_heads, head_dim):
+    """The three Mosaic calls' types: K and V reach every one of them per
+    KV head, and the gradients leave dq and dk/dv in bf16 at their
+    consumer's granularity."""
+    per_q = f"bf16[{batch},{heads},{seq},{head_dim}]"
+    per_kv = f"bf16[{batch},{kv_heads},{seq},{head_dim}]"
+    calls = _mosaic_calls(hlo)
+    assert calls["flash_attention_dq"][0] == [per_q]
+    assert calls["flash_attention_dkdv"][0] == [per_kv, per_kv]
+    for results, operands in calls.values():
+        assert operands[:3] == [per_q, per_kv, per_kv]           # q, k, v
+
+
+def _entry_values(hlo):
+    """name -> (result type text, operand names, is a Mosaic call) of
+    every instruction of the ENTRY computation: the values that exist in
+    HBM, not those inside a fusion."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    values = {}
+    for line in entry.splitlines()[1:]:
+        made = re.match(r"\s*(?:ROOT )?%([\w.-]+) = (.*?) [\w-]+\((.*)", line)
+        if made:
+            name, result, rest = made.groups()
+            values[name] = (result, re.findall(r"%([\w.-]+)", rest),
+                            MOSAIC in line)
+    return values
+
+
+def _elements(result):
+    return [math.prod(map(int, dims.split(","))) if dims else 1
+            for dims in re.findall(r"\w+\[([\d,]*)\]", result)]
+
+
+def _nothing_per_q_head_but_what_the_kernels_want(hlo, batch, seq, heads,
+                                                  head_dim):
+    """Of the values in HBM none is ``f32[B, Hq, S, D]`` (a gradient
+    written to be summed or rounded by XLA) and none of that many elements
+    is made from K or V outside a kernel (an expansion to the q heads,
+    under whatever shape)."""
+    values = _entry_values(hlo)
+    assert f"f32[{batch},{heads},{seq},{head_dim}]" not in " ".join(
+        result for result, _, _ in values.values())
+    from_kv = set()
+    for name, (result, operands, mosaic) in values.items():   # in order
+        if name in ("k.1", "v.1") or (
+                not mosaic and from_kv.intersection(operands)):
+            from_kv.add(name)
+            assert batch * heads * seq * head_dim not in _elements(result), \
+                (name, result)
+    assert {"k.1", "v.1"} < from_kv
+
+
 @pytest.mark.parametrize("batch,seq,heads,kv_heads,head_dim", [
     (8, 2048, 15, 5, 64),     # smollm2-360m.train-1chip
     (1, 4096, 16, 8, 128),    # internlm2-1.8b.train-fsdp4, one chip's share
@@ -103,6 +159,9 @@ def test_the_gradient_holds_no_statistics_column(
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
     _held_to_the_lane_dense_layout(hlo, batch, seq, heads)
+    _held_to_the_kernels_own_gqa(hlo, batch, seq, heads, kv_heads, head_dim)
+    _nothing_per_q_head_but_what_the_kernels_want(hlo, batch, seq, heads,
+                                                  head_dim)
 
 
 def test_the_smollm2_step_saves_the_kernels_own_operand(
@@ -116,5 +175,6 @@ def test_the_smollm2_step_saves_the_kernels_own_operand(
     hlo = _train_step("smollm2-360m", "train-1chip", None,
                       topo.devices).as_text()
     _held_to_the_lane_dense_layout(hlo, 8, 2048, 15)
+    _held_to_the_kernels_own_gqa(hlo, 8, 2048, 15, 5, 64)
     assert "f32[32,8,15,1,2048]" in hlo          # the saved residual
     assert not re.search(r"f32\[32,8,15,\d+,128\]", hlo)   # nor a packed one

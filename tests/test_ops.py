@@ -26,6 +26,19 @@ def _positions(B, S):
     return jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
 
+def _gqa_reference(q, k, v, *, causal):
+    """Plain float32 attention in the kernels' layout, q (B, Hq, S, D)
+    pre-scaled, k/v (B, Hkv, S, D): ``(o, lse)``.  Differentiating it
+    gives dk/dv per KV head (the repeat's transpose sums the group)."""
+    S, group = q.shape[2], q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    if causal:
+        s = jnp.where(jnp.tri(S, dtype=bool), s, -jnp.inf)
+    return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v),
+            jax.nn.logsumexp(s, axis=-1))
+
+
 @pytest.mark.parametrize("B,S,Hq,Hkv,D", [
     (2, 128, 4, 4, 64),     # MHA
     (2, 256, 8, 2, 32),     # GQA 4:1
@@ -131,27 +144,86 @@ def test_flash_statistics_are_lane_dense_and_round_trip(S, Hq, Hkv):
     q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in _rand_qkv(
         jax.random.key(S + Hq), B, S, Hq, Hkv, D))
     do = jax.random.normal(jax.random.key(11), q.shape)
-    k_full, v_full = (jnp.repeat(x, Hq // Hkv, axis=1) for x in (k, v))
     kw = dict(causal=True, block_q=None, block_k=None, interpret=True)
     assert fa._stats_dense(fa._block_sizes(S, S, None, None)[0])
-
-    def ref(q, k, v):
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
-        s = jnp.where(jnp.tri(S, dtype=bool), s, -jnp.inf)
-        return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v),
-                jax.nn.logsumexp(s, axis=-1))
-
-    (o_ref, lse_ref), vjp = jax.vjp(ref, q, k_full, v_full)
+    (o_ref, lse_ref), vjp = jax.vjp(
+        functools.partial(_gqa_reference, causal=True), q, k, v)
     o, lse = fa._fwd(q, k, v, **kw)
     assert lse.shape == (B, Hq, 1, S) and lse.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(lse[:, :, 0, :]),
                                np.asarray(lse_ref), atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                atol=2e-5, rtol=2e-5)
-    grads = fa._bwd_impl(q, k_full, v_full, o, lse, do, **kw)
+    grads = fa._bwd_impl(q, k, v, o, lse, do, out_dtype=jnp.float32, **kw)
     for a, b, name in zip(grads, vjp((do, jnp.zeros_like(lse_ref))), "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("out_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("Hq,Hkv", [(2, 2), (4, 2), (15, 5)],
+                         ids=["group1", "group2", "group3"])
+def test_flash_backward_does_its_own_gqa(Hq, Hkv, causal, out_dtype):
+    """``_bwd_impl`` takes K/V at kv-head granularity and returns dq per q
+    head, dk/dv per KV head (the group added in the kernel's float32
+    accumulator), in the type its caller asks for: float32 (the ring's)
+    is the float32 reference's gradient; bf16 (the model's) is that same
+    float32 result rounded once.  Two q and two k blocks, so the walk over
+    a group's heads crosses skipped, diagonal and whole tiles."""
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    B, S, D, block = 1, 256, 32, 128
+    q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in _rand_qkv(
+        jax.random.key(Hq), B, S, Hq, Hkv, D))
+    do = jax.random.normal(jax.random.key(12), q.shape)
+    kw = dict(causal=causal, block_q=block, block_k=block, interpret=True)
+    o, lse = fa._fwd(q, k, v, **kw)
+    exact = fa._bwd_impl(q, k, v, o, lse, do, out_dtype=jnp.float32, **kw)
+    if out_dtype == jnp.float32:
+        (_, lse_ref), vjp = jax.vjp(
+            functools.partial(_gqa_reference, causal=causal), q, k, v)
+        grads, want, close = exact, vjp((do, jnp.zeros_like(lse_ref))), 1e-4
+    else:
+        grads = fa._bwd_impl(q, k, v, o, lse, do, out_dtype=out_dtype, **kw)
+        want, close = [g.astype(out_dtype) for g in exact], 0
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+    for a, b, name in zip(grads, want, "qkv"):
+        assert a.dtype == out_dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=close, rtol=close, err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("Hq,Hkv", [(2, 2), (4, 2), (15, 5)],
+                         ids=["group1", "group2", "group3"])
+def test_flash_gqa_gradients_in_the_models_type(Hq, Hkv, causal):
+    """``flash_attention``'s gradients on bf16 inputs arrive as bf16 at
+    the model's granularity, (B, S, Hq | Hkv, D), and are the float32
+    reference's on the same (rounded) inputs to bf16's precision."""
+    B, S, D = 1, 256, 32
+    q, k, v = _rand_qkv(jax.random.key(Hkv), B, S, Hq, Hkv, D,
+                        dtype=jnp.bfloat16)
+    w = jax.random.normal(jax.random.key(13), q.shape)
+
+    def ref(q, k, v):
+        out, _ = _gqa_reference(*(jnp.transpose(x, (0, 2, 1, 3)) for x in (
+            q * D ** -0.5, k, v)), causal=causal)
+        return jnp.transpose(out, (0, 2, 1, 3))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=128,
+                               block_k=128)
+
+    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    g_fl = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, x, name in zip(g_fl, g_ref, (q, k, v), "qkv"):
+        assert a.dtype == jnp.bfloat16 and a.shape == x.shape
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=4e-2, rtol=4e-2, err_msg=name)
 
 
 @pytest.mark.parametrize("Sq,block_q", [(64, None), (256, 32), (192, 64)])

@@ -11,9 +11,22 @@ score elements they compute a second.  ``strip`` is dq's and dk/dv's;
 ``strip == block`` is a diagonal tile computed whole and masked by a
 select, as the forward always computes it.  What it read last stands over
 ``DEFAULT_BLOCK`` in ``ray_tpu/ops/flash_attention.py``.
+
+    chiprun -- python3 -m tools.flash_sweep grad
+
+alone times what a model's layer runs, the forward and backward of the
+public ``flash_attention`` (the model's ``(B, S, H, D)`` layout, shipped
+tiles) in one jitted program: the device ms a call of the whole program,
+of the three kernels in it, and the difference — XLA's side of attention,
+the transposes, ``delta`` and whatever copies the kernels' operands and
+results need, which computes nothing.  It touches the public function
+alone, so this file copied into an older checkout reads that one the same
+way.
+With no argument both tables are printed, this one first.
 """
 
 import importlib
+import sys
 import tempfile
 
 import jax
@@ -29,14 +42,16 @@ KERNELS = tuple(flash_names.KERNEL_OPS)                  # fwd, dq, dkdv
 
 
 def kernel_ms(fn, args, calls=10):
-    """Device ms a call of each kernel inside ``fn``, from a trace."""
+    """Device ms a call of each kernel inside ``fn`` and, under
+    ``"whole"``, of all of ``fn`` (the time an op ran), from a trace."""
     jax.block_until_ready(fn(*args))            # compile, warm
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
             jax.block_until_ready([fn(*args) for _ in range(calls)])
         trace = trace_reduce.read(trace_dir)
-    return {kernel: 1e3 * flash_names.kernel_seconds(trace, kernel) / calls
-            for kernel in KERNELS}
+    ms = {kernel: 1e3 * flash_names.kernel_seconds(trace, kernel) / calls
+          for kernel in KERNELS}
+    return {**ms, "whole": 1e3 * trace.busy_s / calls}
 
 
 def blockspec_bytes(fn, *args):
@@ -64,6 +79,33 @@ def blockspec_bytes(fn, *args):
     return moved
 
 
+def _inputs(seed, q_shape, kv_shape):
+    """bf16 ``q, k, v, do`` (``do`` shaped as ``q``) from ``seed``."""
+    keys = jax.random.split(jax.random.key(seed), 4)
+    q, do = (jax.random.normal(k, q_shape, jnp.bfloat16) for k in keys[:2])
+    k, v = (jax.random.normal(k, kv_shape, jnp.bfloat16) for k in keys[2:])
+    return q, k, v, do
+
+
+def grad_sweep():
+    """``jit`` of ``flash_attention``'s forward and vjp as a layer calls
+    them: whole, the three kernels, and XLA's side (the difference)."""
+    print("B S Hq Hkv D whole_ms", *(f"{kernel}_ms" for kernel in KERNELS),
+          "xla_side_ms")
+
+    def layer(q, k, v, do):
+        o, vjp = jax.vjp(fa.flash_attention, q, k, v)
+        return o, vjp(do)
+
+    for B, S, Hq, Hkv, D in SHAPES:
+        ms = kernel_ms(jax.jit(layer),
+                       _inputs(S, (B, S, Hq, D), (B, S, Hkv, D)))
+        kernels = sum(ms[kernel] for kernel in KERNELS)
+        print(B, S, Hq, Hkv, D, f"{ms['whole']:.3f}",
+              *(f"{ms[kernel]:.3f}" for kernel in KERNELS),
+              f"{ms['whole'] - kernels:.3f}", flush=True)
+
+
 def sweep(blocks=(512, 1024), strips=(128, 256, 512, None)):
     shipped = fa.DIAG_STRIP
     try:
@@ -77,11 +119,7 @@ def _sweep(blocks, strips):
                                         for kernel in KERNELS),
           "GB/s Gelem/s")
     for B, S, Hq, Hkv, D in SHAPES:
-        keys = jax.random.split(jax.random.key(S), 4)
-        q, do = (jax.random.normal(k, (B, Hq, S, D), jnp.bfloat16)
-                 for k in keys[:2])
-        k, v = (jax.random.normal(k, (B, Hkv, S, D), jnp.bfloat16)
-                for k in keys[2:])
+        q, k, v, do = _inputs(S, (B, Hq, S, D), (B, Hkv, S, D))
         for block in blocks:
             for strip in dict.fromkeys(s or block for s in strips):
                 fa.DIAG_STRIP = strip           # read when a kernel is traced
@@ -90,9 +128,8 @@ def _sweep(blocks, strips):
 
                 def three(q, k, v, do):
                     o, lse = fa._fwd(q, k, v, **kw)
-                    kf, vf = (jnp.repeat(x, Hq // Hkv, axis=1)
-                              for x in (k, v))
-                    return o, fa._bwd_impl(q, kf, vf, o, lse, do, **kw)
+                    return o, fa._bwd_impl(q, k, v, o, lse, do, **kw,
+                                           out_dtype=q.dtype)
 
                 ms = kernel_ms(jax.jit(three), (q, k, v, do))
                 by_name = blockspec_bytes(three, q, k, v, do)
@@ -101,7 +138,7 @@ def _sweep(blocks, strips):
                 share = fa.causal_computed_share    # the forward: no strips
                 elems = B * Hq * S * S * (share(S, block, block, block)
                                           + 2 * share(S, block, block, strip))
-                seconds = sum(ms.values()) / 1e3 or 1
+                seconds = sum(ms[kernel] for kernel in KERNELS) / 1e3 or 1
                 print(B, S, Hq, Hkv, D, block, strip,
                       *(f"{ms[kernel]:.3f} {moved[kernel] / 1e6:.1f}"
                         for kernel in KERNELS),
@@ -112,4 +149,6 @@ def _sweep(blocks, strips):
 if __name__ == "__main__":
     if jax.default_backend() != "tpu":
         raise SystemExit("flash_sweep times the compiled kernels: tpu only")
-    sweep()
+    grad_sweep()
+    if sys.argv[1:] != ["grad"]:
+        sweep()
