@@ -5,9 +5,9 @@ Design (idiomatic jax/XLA, not a torch translation):
 - **Functional**: params are a plain pytree; ``forward(params, tokens)``
   is pure and jit/pjit-friendly.
 - **Scan over layers**: per-layer weights are stacked on a leading
-  ``layers`` dim and the block runs under ``jax.lax.scan`` — one trace,
-  O(1) compile time in depth, and the ``layers`` dim is the natural
-  pipeline-parallel shard axis.
+  ``layers`` dim and ONE block (``layer_block``) runs under
+  ``jax.lax.scan`` (``walk_layers``) — one trace, O(1) compile time in
+  depth, and the ``layers`` dim is the natural pipeline-parallel shard axis.
 - **Logical shardings**: every weight/activation dim carries a logical
   axis name resolved by :mod:`ray_tpu.parallel.sharding`; the same model
   runs DP/FSDP/TP/SP by swapping rule tables.
@@ -107,11 +107,6 @@ class LlamaConfig:
     # across adjacent layers (fewer loop-carried DUS/sequencing
     # overheads) at the cost of compile time.
     scan_unroll: int = 1
-    # Flash-attention tile sizes (None = kernel default, currently
-    # 1024).  Exposed as a config knob so that a sweep can tune them
-    # per chip/shape and the winner can be recorded on the preset.
-    attn_block_q: Optional[int] = None
-    attn_block_k: Optional[int] = None
     # >0 enables REAL pipeline parallelism when the active mesh has a
     # pipe axis of size >1: the layer stack runs as a GPipe microbatch
     # schedule over pipe stages (parallel/pipeline.py) instead of one
@@ -449,6 +444,29 @@ class LlamaConfig:
     def ropes(self, kind: str) -> bool:
         """Whether an attending layer of ``kind`` rotates q and k."""
         return self.rope and kind not in self.nope_kinds
+
+    @property
+    def one_kv_stack(self) -> bool:
+        """Every layer keeps K and V rows by position, in one stack (what
+        ``forward_with_cache`` holds): no other kind, list or latent cache."""
+        return not (self.layer_types or self.kv_lora_rank
+                    or any(self.layers_of(kind) for kind in LAYER_KINDS
+                           if kind != "attention"))
+
+    @property
+    def plain_decoder(self) -> bool:
+        """What ``llama.forward`` TRAINS and a pipeline stage runs; any
+        other config is served only.  ``forward`` goes through the walk that
+        serves them (``walk_layers``), which computes most of these terms:
+        they are refused because no test and no cell holds their backward,
+        not for want of a code path (ROADMAP Queue 2)."""
+        return self.one_kv_stack and not (
+            self.attention_multiplier is not None
+            or self.embedding_multiplier != 1.0 or self.logits_scaling != 1.0
+            or self.nope_kinds or self.moe_router_input != "ffn"
+            or self.rope_scaling is not None or self.first_dense_layers
+            or self.moe_held or self.qk_head_norm
+            or self.moe_router_score != "softmax" or self.moe_router_bias)
 
     @property
     def attn_scale(self) -> float:
@@ -938,22 +956,15 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, S, Hq, v.shape[-1])
 
 
-def _get_attention_fn(config) -> Callable:
-    """Resolve a config (or bare impl name) to the attention callable.
-    For the flash path the config's ``attn_block_q``/``attn_block_k``
-    tile sizes are bound in (the MFU sweep's tuning knob)."""
-    impl = config if isinstance(config, str) else config.attention_impl
+def _get_attention_fn(config: LlamaConfig) -> Callable:
+    """The attention callable of a config's ``attention_impl``."""
+    impl = config.attention_impl
     if impl == "dot":
         return dot_attention
     try:
         if impl == "flash":
             from ray_tpu.ops.flash_attention import flash_attention_causal
-            if isinstance(config, str):
-                return flash_attention_causal
-            return functools.partial(
-                flash_attention_causal,
-                block_q=config.attn_block_q,
-                block_k=config.attn_block_k)
+            return flash_attention_causal
         if impl == "ring":
             from ray_tpu.ops.ring_attention import ring_attention_causal
             return ring_attention_causal
@@ -1154,12 +1165,10 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
                  layer: Dict[str, jax.Array], config: LlamaConfig,
                  valid: Optional[jax.Array] = None,
                  layer_index: Optional[jax.Array] = None):
-    """Output projection + FFN half of the block, dense or experts as
-    the config says: THE function every path calls (training forward,
-    ``prefill_forward``, ``forward_with_cache``, the serve programs; the
-    conventions shared with ``_qkv_rope`` live here).  Constraints are
-    no-ops outside a mesh.  Returns what ``ffn_half`` does.  ``x`` is the
-    layer's input: what a router placed before attention reads."""
+    """Output projection + FFN half of an attending layer
+    (``layer_block``'s; the conventions shared with ``_qkv_rope`` live
+    here).  Returns what ``ffn_half`` does.  ``x`` is the layer's input:
+    what a router placed before attention reads."""
     B, S, _ = x.shape
     route_x = x if config.moe_router_input == "layer" else None
     with jax.named_scope("attn_out"):
@@ -1279,14 +1288,6 @@ def _dispatch_in_chunks(h, moe_params, mcfg, valid, layer_index, route_x,
     ff, aux, rows = jax.lax.map(one, xs)
     return (jnp.moveaxis(ff, 0, 1).reshape(B, S, D), aux.mean(),
             rows.sum(0))
-
-
-def _attn_out_mlp(x: jax.Array, attn: jax.Array,
-                  layer: Dict[str, jax.Array],
-                  config: LlamaConfig) -> jax.Array:
-    """``attn_out_ffn``'s residual stream alone (the name the test-local
-    reference of tests/test_decode_inplace.py calls)."""
-    return attn_out_ffn(x, attn, layer, config)[0]
 
 
 def lm_head(params: PyTree, config: LlamaConfig) -> jax.Array:
@@ -1435,22 +1436,184 @@ def layer_index(p: jax.Array, per_period: int, i: int) -> jax.Array:
     return p if per_period == 1 else p * per_period + i
 
 
-def decoder_layer(x: jax.Array, layer: Dict[str, jax.Array],
-                  sin: jax.Array, cos: jax.Array, positions: jax.Array,
-                  config: LlamaConfig,
-                  attention_fn: Callable
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """One block: ``(x, the layer's aux loss)`` (0 for a dense one)."""
-    q, k, v = _qkv_rope(x, layer, sin, cos, config)
-    with jax.named_scope("attention"):
-        attn = attention_fn(q, k, v, positions)
-    x, aux, _rows = attn_out_ffn(x, attn, layer, config)
-    return x, aux
+def layer_block(x, layer, kind: str, config: LlamaConfig, sin, cos,
+                attend: Callable, state_step: Optional[Callable] = None,
+                valid=None, at=None):
+    """One layer of ``kind``: THE place that says what a decoder layer is
+    made of, for training, the prefills and the decode step alike.  What
+    the fresh rows meet (themselves, a cache, a carried state) is the
+    caller's: ``attend(q, k, v)`` (latent attention: ``attend(cq,
+    latent)``) and ``state_step(mixer, h)``, closures of the scan body that
+    owns the cache or the carry, return ``(the mixer's output, ys)``, ``ys``
+    what the caller keeps; an ``attend`` scopes its ``kv_write`` and
+    ``attention`` itself.  ``valid``: ``ffn_half``'s, or (B,), whole rows;
+    ``at``: ``layer_index``'s arguments where ``layer`` holds the experts'
+    whole stacks.  Returns ``(x, aux, expert rows, ys)``."""
+    c = config
+
+    def rows_and_place():       # the FFN half's, traced after the mixer
+        return dict(
+            valid=valid[:, None] if getattr(valid, "ndim", 0) == 1
+            else valid,
+            layer_index=None if at is None else layer_index(*at))
+
+    if c.kv_lora_rank or kind in ATTENDING_KINDS:
+        fresh = (latent_down(x, layer, sin, cos, c) if c.kv_lora_rank
+                 else _qkv_rope(x, layer, sin, cos, c, kind))
+        attn, ys = attend(*fresh)
+        return attn_out_ffn(x, attn, layer, c, **rows_and_place()) + (ys,)
+    mixer, proj_scope, out_scope = state_mixer(kind)
+    with jax.named_scope(proj_scope):
+        h = rms_norm(x, layer["attn_norm"], c.norm_eps).astype(c.dtype)
+    out, ys = state_step(mixer, h)
+    with jax.named_scope(out_scope):
+        x = residual_add(x, out, c)
+    return ffn_half(x, layer, c, **rows_and_place()) + (ys,)
+
+
+def rope_for(positions: jax.Array, config: LlamaConfig):
+    """``rope_table`` of a config at ``positions`` (..., S)."""
+    with jax.named_scope("qkv_proj"):
+        return rope_table(positions, config.rope_dim, config.rope_theta,
+                          config.rope_scaling)
+
+
+def walk_block(sin, cos, positions, kv_step: Callable, window_step=None,
+               valid=None, lengths=None) -> Callable:
+    """``layer_block`` over whole sequences with ``layer_walk``'s steps:
+    ``block(x, layer, its slice of the cache walked, at, kind, config)``."""
+    def block(x, layer, kv_layer, at, kind, c):
+        def attend(q, k, v):
+            with jax.named_scope("attention"):
+                if kind == "window":
+                    return window_step(q, k, v, positions)
+                return kv_step(q, k, v, positions, kv_layer)
+
+        def attend_expanded(cq, latent):
+            # latent attention, expanded: attended as heads of their own
+            # keys and values; kept: the latent rows
+            return latent_attend_expanded(
+                cq, latent, layer, sin, cos, c,
+                lambda q, k, v: kv_step(q, k, v, positions, None)[0]), latent
+
+        return layer_block(
+            x, layer, kind, c, sin, cos,
+            attend_expanded if c.kv_lora_rank else attend,
+            lambda mixer, h: mixer.prefill(h, layer, c, lengths), valid, at)
+
+    return block
+
+
+def walk_layers(carry, params: PyTree, config: LlamaConfig, block: Callable,
+                kv_layers: Any = None, scan_experts: bool = False):
+    """The layers of ``config`` over ``carry``, the middle of every forward
+    pass: a scan over the PERIODS of the layer pattern, ``block(carry,
+    layer, kv_layer, at, kind, the part's config) -> (carry, expert rows,
+    ys)`` a layer (``walk_block`` on the residual stream; what else rides
+    the carry is the caller's), each of ``config.parts()`` a scan of its
+    own over ``params[its key]``.  ``scan_experts``: the ``[L, E, ...]``
+    expert matrices are sliced by the scan like every other leaf, ``at``
+    None (training: what the dense dispatch under an ``expert`` mesh axis
+    and the backward take); else closed over and read in place (serving).
+    -> ``(carry, (ys over the attention layers, (L, E) expert rows, ys over
+    the state-keeping layers, ys over the window layers))``, None for none."""
+    def walk_part(carry, c, layers, kv_layers):         # c: the part's
+        sliced, stacks = (layers, {}) if scan_experts \
+            else split_expert_stacks(layers, c)
+        plen = c.period_len
+
+        def body(carry, period_index_cache):
+            period, p, kv_period = period_index_cache
+            ys, rows = {kind: [] for kind in LAYER_KINDS}, []
+            for j, (kind, i, layer) in enumerate(
+                    period_layers(sliced, period, p, c)):
+                carry, rows_j, ys_j = block(
+                    carry, {**layer, **stacks},
+                    layer_of(kv_period, i, c) if kind == "attention"
+                    else None,
+                    None if scan_experts else (p, plen, j), kind, c)
+                rows.append(rows_j)
+                ys[kind].append(ys_j)
+            # (mamba and conv layers do not mix: one list holds a state)
+            return carry, (stack_period(ys["attention"], c),
+                           stack_period(rows, c),
+                           stack_period(ys["mamba"] + ys["conv"], c),
+                           stack_period(ys["window"], c))
+
+        # ``layer_scan``: the loop's own slicing of a layer's weights and
+        # stacking of what a layer saves; an op inside a block's scope
+        # keeps that one, the innermost.
+        with jax.named_scope("layer_scan"):
+            carry, stacked = jax.lax.scan(
+                body, carry,
+                (scanned_layers(sliced, c),
+                 jnp.arange(c.n_layers // plen, dtype=jnp.int32),
+                 by_period(kv_layers, c)), unroll=c.scan_unroll)
+            return carry, merge_periods(stacked, c)
+
+    outs = []
+    for part, key, l0 in config.parts():
+        a0 = config.layers_before(l0, "attention")
+        carry, out = walk_part(
+            carry, part, params[key],
+            jax.tree.map(
+                lambda a: a[a0:a0 + part.attending_layers()], kv_layers))
+        outs.append(out)
+    # Each result over the parts that have it, in the layers' order (a
+    # dense part computes no expert's rows, a part without attending
+    # layers keeps no K/V).
+    return carry, tuple(over_parts([o[i] for o in outs]) for i in range(4))
 
 
 # ---------------------------------------------------------------------------
 # Forward / loss
 # ---------------------------------------------------------------------------
+
+def embed_sharded(params: PyTree, tokens, config: LlamaConfig):
+    """Training's lookup (``forward``, a pipeline's first stage)."""
+    # ZeRO-3 semantics for the lookup: all-gather the fsdp-sharded
+    # embed dim of the table BEFORE the gather.  Without this the
+    # gather's output inherits the table's D-sharding and the SPMD
+    # partitioner falls into "involuntary full rematerialization"
+    # resharding it to (batch, seq) (observed in the 8-way dryrun).
+    with jax.named_scope("embed"):
+        emb = with_logical_constraint(
+            params["embed_tokens"].astype(config.dtype), "vocab", None)
+        return with_logical_constraint(emb[tokens], "batch", "seq", None)
+
+
+def head_loss_logits(x, params: PyTree, config: LlamaConfig):
+    """Training's final norm and head, in the scope ``loss_fn`` goes on in."""
+    with jax.named_scope("head_loss"):
+        x = rms_norm(x, params["final_norm"], config.norm_eps)
+        return with_logical_constraint(
+            matmul(x, lm_head(params, config)), "batch", "seq", "vocab")
+
+
+def train_block(config: LlamaConfig, sin, cos, positions) -> Callable:
+    """``walk_block`` as training runs it: the rows attended as they are,
+    nothing kept, under the config's remat."""
+    attention_fn = _get_attention_fn(config)
+    block = walk_block(
+        sin, cos, positions, lambda q, k, v, positions, _cache: (
+            attention_fn(q, k, v, positions), None))
+    if config.remat:
+        block = jax.checkpoint(block, policy=_remat_policy(config),
+                               static_argnums=(4, 5))
+    return block
+
+
+def train_layers(x, params: PyTree, config: LlamaConfig, positions):
+    """``walk_layers`` as training runs it: x (B, S, D) -> ``(x, aux sum)``."""
+    block = train_block(config, *rope_for(positions, config), positions)
+
+    def summing(carry, *layer_args):
+        x, aux, rows, ys = block(carry[0], *layer_args)
+        return (x, carry[1] + aux), rows, ys
+
+    return walk_layers((x, jnp.zeros((), jnp.float32)), params, config,
+                       summing, scan_experts=True)[0]
+
 
 def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             positions: Optional[jax.Array] = None,
@@ -1460,24 +1623,14 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     With ``return_aux=True`` returns (logits, aux) where aux is the
     summed MoE load-balancing loss over layers (0.0 for dense)."""
     c = config
-    if (c.layers_of("mamba") or c.attention_multiplier is not None
-            or c.embedding_multiplier != 1.0 or c.logits_scaling != 1.0
-            or c.layers_of("window") or c.nope_kinds
-            or c.moe_router_input != "ffn" or c.kv_lora_rank
-            or c.rope_scaling is not None or c.first_dense_layers
-            or c.moe_held or c.layer_types or c.layers_of("conv")
-            or c.qk_head_norm or c.moe_router_score != "softmax"
-            or c.moe_router_bias):
+    if not c.plain_decoder:
         raise NotImplementedError(
             "llama.forward (training) computes a stack of one kind of "
-            "attention layer with the default scale, embedding and "
-            "logits, its softmax router after attention: a config with "
-            "state-space, short-convolution or window layers, a list of "
-            "layer_types, a kind without RoPE, a q/k norm a head, a "
-            "router on the layer's input, a sigmoid router or a "
-            "selection bias, the Granite multipliers, latent attention, "
-            "scaled RoPE, leading dense layers or an expert share is "
-            "served only (llama_serve.build_*)")
+            "attention layer with the default scale, embedding, logits and "
+            "router (LlamaConfig.plain_decoder): a config with state-space, "
+            "short-convolution or window layers, latent attention, a list "
+            "of layer_types or any other term of that property is served "
+            "only (llama_serve.build_*)")
     if positions is not None and c.attention_impl != "dot":
         # flash/ring mask on raw row index, not positions — packed or
         # offset sequences would silently attend across boundaries.
@@ -1488,38 +1641,14 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
-    attention_fn = _get_attention_fn(c)
-
-    # ZeRO-3 semantics for the lookup: all-gather the fsdp-sharded
-    # embed dim of the table BEFORE the gather.  Without this the
-    # gather's output inherits the table's D-sharding and the SPMD
-    # partitioner falls into "involuntary full rematerialization"
-    # resharding it to (batch, seq) (observed in the 8-way dryrun).
-    with jax.named_scope("embed"):
-        emb = with_logical_constraint(
-            params["embed_tokens"].astype(c.dtype), "vocab", None)
-        x = emb[tokens]
-        x = with_logical_constraint(x, "batch", "seq", None)
-    with jax.named_scope("qkv_proj"):
-        sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
-
-    moe = c.moe_experts > 0
-
-    def make_block(sin, cos, positions):
-        block = functools.partial(
-            decoder_layer, sin=sin, cos=cos, positions=positions,
-            config=c, attention_fn=attention_fn)
-        if c.remat:
-            block = jax.checkpoint(block, policy=_remat_policy(c))
-        return block
+    x = embed_sharded(params, tokens, c)
 
     from ray_tpu.parallel.sharding import current_mesh
 
     mesh = current_mesh()
-    aux_total = jnp.zeros((), jnp.float32)
     if (c.pipeline_microbatches > 0 and mesh is not None
             and mesh.shape.get("pipe", 1) > 1):
-        if moe:
+        if c.moe_experts > 0:
             raise NotImplementedError(
                 "MoE layers inside pipeline stages are not supported "
                 "yet (the GPipe schedule carries no aux accumulator); "
@@ -1538,37 +1667,21 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
         # The block closes over batch-shaped sin/cos/positions; a
         # microbatch needs the broadcastable single-row versions, which
         # are only equivalent for the default arange layout.
-        block = make_block(sin[:1], cos[:1], positions[:1])
+        sin, cos = rope_for(positions, c)
+        block = train_block(c, sin[:1], cos[:1], positions[:1])
         batch_axes = [a for a in ("data", "fsdp") if a in mesh.shape
                       and mesh.shape[a] > 1]
         x = pipeline_layers(
-            lambda h, layer: block(h, layer)[0], params["layers"], x,
+            lambda h, layer: block(h, layer, None, None, "attention", c)[0],
+            params["layers"], x,
             mesh=mesh, num_microbatches=c.pipeline_microbatches,
             batch_axes=batch_axes)
+        aux_total = jnp.zeros((), jnp.float32)
     else:
-        block = make_block(sin, cos, positions)
+        x, aux_total = train_layers(x, params, c, positions)
 
-        def scan_body(carry, layer_params):
-            h, aux = carry
-            h, aux_l = block(h, layer_params)
-            return (h, aux + aux_l), None
-
-        # ``layer_scan``: the loop's own slicing of a layer's weights and
-        # stacking of what a layer saves; an op inside a block's scope
-        # keeps that one, the innermost.
-        with jax.named_scope("layer_scan"):
-            (x, aux_total), _ = jax.lax.scan(
-                scan_body, (x, aux_total), params["layers"],
-                unroll=c.scan_unroll)
-
-    with jax.named_scope("head_loss"):
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = matmul(x, lm_head(params, c))
-        logits = with_logical_constraint(logits, "batch", "seq",
-                                         "vocab")
-    if return_aux:
-        return logits, aux_total
-    return logits
+    logits = head_loss_logits(x, params, c)
+    return (logits, aux_total) if return_aux else logits
 
 
 def loss_fn(params: PyTree, batch: Dict[str, jax.Array],
@@ -1577,7 +1690,6 @@ def loss_fn(params: PyTree, batch: Dict[str, jax.Array],
     optional loss_mask (B,S)."""
     tokens = batch["tokens"]
     positions = batch.get("positions")
-    aux = jnp.zeros((), jnp.float32)
     if positions is None:
         # Run the forward at the full sequence length and drop the last
         # position's logits, instead of slicing tokens to S-1: a
@@ -1593,7 +1705,14 @@ def loss_fn(params: PyTree, batch: Dict[str, jax.Array],
         logits, aux = forward(params, tokens[:, :-1], config,
                               positions=positions[:, :-1],
                               return_aux=True)
-    targets = tokens[:, 1:]
+    return next_token_loss(logits, batch, config, aux)
+
+
+def next_token_loss(logits: jax.Array, batch: Dict[str, jax.Array],
+                    config: LlamaConfig, aux=0.0) -> jax.Array:
+    """``loss_fn``'s mean cross-entropy of logits (B, S - 1, V) against the
+    batch's next tokens, plus the aux loss of a config with experts."""
+    targets = batch["tokens"][:, 1:]
     with jax.named_scope("head_loss"):
         logits = logits.astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
@@ -1876,138 +1995,46 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
                kv_layers: Any = None, valid: Optional[jax.Array] = None,
                lengths: Optional[jax.Array] = None,
                window_step: Optional[Callable] = None):
-    """The forward pass that serving shares: embed, rope table, a scan
-    over the PERIODS of the layer pattern -- per attention layer
-    ``_qkv_rope`` -> ``kv_step`` -> ``attn_out_ffn``, per Mamba or
-    short-convolution layer its ``prefill`` (``state_mixer``) ->
-    ``ffn_half`` --, final norm, head.  What a
-    decoder layer is made of lives here; the callers differ only in
-    ``kv_step``, what an attention layer does with its fresh K/V rows and
-    what its queries attend.  (A plain decoder's period is one layer.)
+    """The forward pass that serving shares: embed, rope table,
+    ``walk_layers`` over ``walk_block``, final norm, head.  The callers
+    differ only in ``kv_step``, what an attention layer does with its fresh
+    K/V rows and what its queries attend.
 
     tokens: (B, S); positions: (B, S) absolute, each row's 0..S-1 when
     left out.  ``kv_step(q, k, v, positions, kv_layer) -> (attn, ys)``:
     q (B, S, Hq, D) and k, v (B, S, Hkv, D) roped, ``kv_layer`` this
     layer's slice of ``kv_layers`` (leading dim: the attention layers; a
     cache scanned a layer at a time).  ``valid`` (broadcastable to (B,
-    S)) marks the rows that are real: experts compute no others, and
-    read their ``[L, E, ...]`` matrices in place at the layer's index
-    (the stacks are closed over, not sliced by the scan).  With
+    S)) marks the rows that are real: experts compute no others.  With
     ``lengths`` (B,) the logits are those of each row's last real
     position alone, (B, V), ``valid`` defaults to position < length, and
     a Mamba layer's states are those of that position; without, (B, S,
     V) and every position is real.  A Mamba layer starts from an empty
-    state: only a cold prefill walks one.  A window layer is an attention
-    layer whose queries and fresh rows go to ``window_step(q, k, v,
-    positions) -> (attn, ys)`` instead (no cache is walked for one).  A
-    model with latent attention attends EXPANDED (``latent_attend_
-    expanded``: ``kv_step`` gets a group of heads' q, k and v and no
-    cache) and its ys are the latent rows.  Leading dense layers are
-    walked first, as a stack of their own, and a stack that is not one
-    pattern throughout a run of whole periods at a time
-    (``LlamaConfig.parts``).
-
-    Returns ``(logits, ys stacked over the attention layers, expert
-    rows, Mamba states, window ys)``: the (L, E) int32 rows each layer's
-    experts computed, None for a dense config; ``(recurrent, conv)``
-    states stacked over the Mamba layers (``(conv,)`` over the
-    short-convolution layers) and ``window_step``'s ys over the window
-    layers, None where there are none."""
+    state: only a cold prefill walks one.  A window layer's queries and
+    fresh rows go to ``window_step(q, k, v, positions) -> (attn, ys)``
+    instead (no cache is walked for one); of a model with latent attention
+    ``kv_step`` gets a group of heads' q, k and v EXPANDED and no cache.
+    -> ``(logits, *walk_layers' results)``, the states ``(recurrent, conv)``
+    over the Mamba or ``(conv,)`` over the short-convolution layers."""
     c = config
     x = embed(params, tokens, c)
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
             tokens.shape)
-    with jax.named_scope("qkv_proj"):
-        sin, cos = rope_table(positions, c.rope_dim, c.rope_theta,
-                              c.rope_scaling)
+    sin, cos = rope_for(positions, c)
     if valid is None and lengths is not None:
         valid = positions < lengths[:, None]
-    def walk_part(x, c, layers, kv_layers):
-        """One stack of layers (``c``: that part's config), scanned a
-        period an iteration."""
-        sliced, stacks = split_expert_stacks(layers, c)
-        plen = c.period_len
+    block = walk_block(sin, cos, positions, kv_step, window_step, valid,
+                       lengths)
 
-        def body(x, period_index_cache):
-            period, p, kv_period = period_index_cache
-            kv_ys, ssm_ys, win_ys, rows = [], [], [], []
-            for j, (kind, i, layer) in enumerate(
-                    period_layers(sliced, period, p, c)):
-                layer = {**layer, **stacks}
-                if c.kv_lora_rank:
-                    # latent attention, expanded: attended as heads of
-                    # their own keys and values; kept: the latent rows
-                    cq, latent = latent_down(x, layer, sin, cos, c)
-                    attn = latent_attend_expanded(
-                        cq, latent, layer, sin, cos, c,
-                        lambda q, k, v: kv_step(q, k, v, positions,
-                                                None)[0])
-                    kv_ys.append(latent)
-                    x, _aux, rows_j = attn_out_ffn(
-                        x, attn, layer, c, valid=valid,
-                        layer_index=layer_index(p, plen, j))
-                elif kind in ATTENDING_KINDS:
-                    q, k, v = _qkv_rope(x, layer, sin, cos, c, kind)
-                    # what a step writes of K/V it scopes ``kv_write``
-                    # itself
-                    with jax.named_scope("attention"):
-                        if kind == "window":
-                            attn, ys = window_step(q, k, v, positions)
-                            win_ys.append(ys)
-                        else:
-                            attn, ys = kv_step(q, k, v, positions,
-                                               layer_of(kv_period, i, c))
-                            kv_ys.append(ys)
-                    x, _aux, rows_j = attn_out_ffn(
-                        x, attn, layer, c, valid=valid,
-                        layer_index=layer_index(p, plen, j))
-                else:
-                    # a layer that keeps a state: Mamba-2, short convolution
-                    mixer, proj_scope, out_scope = state_mixer(kind)
-                    with jax.named_scope(proj_scope):
-                        h = rms_norm(x, layer["attn_norm"],
-                                     c.norm_eps).astype(c.dtype)
-                    out, ys = mixer.prefill(h, layer, c, lengths)
-                    ssm_ys.append(ys)
-                    with jax.named_scope(out_scope):
-                        x = residual_add(x, out, c)
-                    x, _aux, rows_j = ffn_half(
-                        x, layer, c, valid=valid,
-                        layer_index=layer_index(p, plen, j))
-                rows.append(rows_j)
-            return x, (stack_period(kv_ys, c), stack_period(rows, c),
-                       stack_period(ssm_ys, c), stack_period(win_ys, c))
+    def on_stream(x, *layer_args):
+        # serving carries the stream alone: no aux loss, no checkpoint
+        x, _aux, rows, ys = block(x, *layer_args)
+        return x, rows, ys
 
-        with jax.named_scope("layer_scan"):
-            x, stacked = jax.lax.scan(
-                body, x,
-                (scanned_layers(sliced, c),
-                 jnp.arange(c.n_layers // plen, dtype=jnp.int32),
-                 by_period(kv_layers, c)))
-            return x, merge_periods(stacked, c)
-
-    # The leading dense layers, if the model has them, then the scanned
-    # stack (a stack that is not whole periods: its runs of them): the
-    # same walk over each part's own weights.
-    if not (c.first_dense_layers or c.layer_types):
-        x, (ys, expert_rows, ssm_ys, win_ys) = walk_part(
-            x, c, params["layers"], kv_layers)
-    else:
-        outs = []
-        for part, key, l0 in c.parts():
-            a0 = c.layers_before(l0, "attention")
-            x, out = walk_part(
-                x, part, params[key],
-                jax.tree.map(
-                    lambda a: a[a0:a0 + part.attending_layers()], kv_layers))
-            outs.append(out)
-        # Each result over the parts that have it, in the layers' order (a
-        # dense part computes no expert's rows, a part without attending
-        # layers keeps no K/V).
-        ys, expert_rows, ssm_ys, win_ys = (
-            over_parts([o[i] for o in outs]) for i in range(4))
+    x, (ys, expert_rows, ssm_ys, win_ys) = walk_layers(
+        x, params, c, on_stream, kv_layers)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.norm_eps).astype(c.dtype)
         if lengths is None:
@@ -2144,9 +2171,7 @@ def forward_with_cache(params: PyTree, tokens: jax.Array,
     at those positions and returns (logits (B, T, V), new_cache).  No
     program that serves runs it; tests hold T > 1 through a cache to the
     reference with it."""
-    if (config.layers_of("mamba") or config.layers_of("window")
-            or config.kv_lora_rank or config.layers_of("conv")
-            or config.layer_types):
+    if not config.one_kv_stack:
         raise NotImplementedError(
             "forward_with_cache holds one K/V stack alone; a config with "
             "state-space, short-convolution or window layers, a list of "

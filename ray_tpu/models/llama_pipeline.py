@@ -22,14 +22,14 @@ cross-slice pipelines over channels.
 
 from __future__ import annotations
 
-import functools
+import dataclasses
 from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
 
-from .llama import (LlamaConfig, decoder_layer, _get_attention_fn,
-                    matmul, rms_norm, rope_table)
+from .llama import (LlamaConfig, embed_sharded, head_loss_logits,
+                    next_token_loss, train_layers)
 
 PyTree = Any
 
@@ -37,6 +37,10 @@ PyTree = Any
 def check_pipeline_config(config: LlamaConfig, n_stages: int):
     if n_stages < 2:
         raise ValueError("cross-process pipeline needs >= 2 stages")
+    if not config.plain_decoder:
+        raise NotImplementedError(
+            "a pipeline stage runs what llama.forward trains "
+            "(LlamaConfig.plain_decoder): this config is served only")
     if config.n_layers % n_stages:
         raise ValueError(
             f"{config.n_layers} layers not divisible by {n_stages} stages")
@@ -77,24 +81,13 @@ def stage_slice(params: PyTree, stage: int, n_stages: int) -> PyTree:
 
 
 def _run_layers(x: jax.Array, layers: PyTree, config: LlamaConfig):
-    """Scan the stage's stacked layers over ``x`` (B, S, E)."""
-    c = config
-    B, S = x.shape[0], x.shape[1]
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    sin, cos = rope_table(positions, c.head_dim, c.rope_theta)
-    block = functools.partial(
-        decoder_layer, sin=sin, cos=cos, positions=positions, config=c,
-        attention_fn=_get_attention_fn(c))
-    if c.remat:
-        from .llama import _remat_policy
-
-        block = jax.checkpoint(block, policy=_remat_policy(c))
-
-    def body(h, layer):
-        return block(h, layer)[0], None
-
-    x, _ = jax.lax.scan(body, x, layers)
-    return x
+    """The stage's stacked layers over ``x`` (B, S, E): ``forward``'s walk
+    over a model of the stage's own layers."""
+    n = jax.tree.leaves(layers)[0].shape[0]
+    return train_layers(
+        x, {"layers": layers}, dataclasses.replace(config, n_layers=n),
+        jnp.broadcast_to(jnp.arange(x.shape[1], dtype=jnp.int32),
+                         x.shape[:2]))[0]
 
 
 def make_stage_fwd(config: LlamaConfig, first: bool) -> Callable:
@@ -102,8 +95,8 @@ def make_stage_fwd(config: LlamaConfig, first: bool) -> Callable:
     on stage 0, hidden states (B, S, E) downstream."""
 
     def fwd(sl: PyTree, inp: jax.Array) -> jax.Array:
-        x = (sl["embed_tokens"].astype(config.dtype)[inp]
-             if first else inp.astype(config.dtype))
+        x = (embed_sharded(sl, inp, config) if first
+             else inp.astype(config.dtype))
         return _run_layers(x, sl["layers"], config)
 
     return fwd
@@ -120,13 +113,7 @@ def make_stage_fwd_loss(config: LlamaConfig) -> Callable:
     def fwd_loss(sl: PyTree, h_in: jax.Array,
                  tokens: jax.Array) -> jax.Array:
         x = _run_layers(h_in.astype(c.dtype), sl["layers"], c)
-        x = rms_norm(x, sl["final_norm"], c.norm_eps)
-        logits = matmul(x, sl["lm_head"].astype(c.dtype))[:, :-1]
-        targets = tokens[:, 1:]
-        logits = logits.astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, targets[..., None],
-                                   axis=-1).squeeze(-1)
-        return jnp.mean(logz - gold)
+        return next_token_loss(head_loss_logits(x, sl, c)[:, :-1],
+                               {"tokens": tokens}, c)
 
     return fwd_loss

@@ -22,9 +22,9 @@ engine, thread or weights are needed to lower one.  By the cache kept:
   into the token and length carries of ``decode_k`` / ``decode_paged``.
 
 The prefills and ``spec_verify`` are ``llama.layer_walk`` with their own
-K/V step: what a decoder layer is made of is ``models/llama.py``'s
-business (and ``models/mamba2.py``'s), in what layout its K/V and states
-lie and what its queries attend is this module's.  The block pool and the
+K/V step, the decode step ``llama.layer_block`` under its own scan: what a
+layer is made of is ``models/llama.py``'s business, in what layout its K/V
+and states lie and what its queries attend is this module's.  The block pool and the
 draft hold K/V alone: ``serve/llm.py`` refuses a config with state-space
 layers on those planes.  The device trace names a program's module after
 its inner function (``jit_prefill``, ``jit_decode_k``): the benchmark's
@@ -191,43 +191,36 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     ``active`` slots only, and read their ``[L, E, ...]`` matrices in
     place (the stacks are closed over, not sliced by the layer scan).
 
-    The layer scan runs a PERIOD of ``cfg.layer_pattern`` an iteration (a
-    plain decoder's period is one attention layer: the scan it always
-    was).  A Mamba-2 layer of the period advances its layer of the
-    stacked recurrent and conv states, which ride the carry after the
-    lengths -- ``(ck, cv, tok, lens, ssm, conv)`` -- through both loops
-    and are updated in place (``mamba2.decode``,
-    ``ops/ssm_state_update.py``); an inactive slot's states are left as
-    they are.  A short-convolution layer does the same with the one state
-    it keeps, ``(ck, cv, tok, lens, conv)`` (``shortconv.decode``).
-
+    The layer scan runs a PERIOD of ``cfg.layer_pattern`` an iteration,
+    each layer ``llama.layer_block`` with this step's closures over the
+    carry.  A Mamba-2 layer advances its layer of the stacked recurrent
+    and conv states, which ride the carry after the lengths -- ``(ck, cv,
+    tok, lens, ssm, conv)`` -- through both loops and are updated in
+    place (``mamba2.decode``, ``ops/ssm_state_update.py``); an inactive
+    slot's states are left as they are.  A short-convolution layer does
+    the same with the one state it keeps, ``(ck, cv, tok, lens, conv)``.
     A model with window layers carries its two pools as rows (``init_
     cache``): ``ck`` / ``cv`` the full layers' and, after the lengths,
-    the window layers' rings.  A window layer writes its new row at
+    the window layers' rings; a window layer writes its new row at
     ``length mod ring`` and attends its first ``min(length + 1, ring)``
-    ring rows through the same kernel.
+    ring rows through the same kernel.  A model with latent attention
+    carries its one leaf where K lies (no V): a layer writes the new
+    latent row and attends ABSORBED (``ops/mla_decode_attention.py``, one
+    call a layer).  Leading dense layers, and each run of whole periods
+    of a stack that is not one pattern throughout (``LlamaConfig.
+    parts``), run as a scan of their own, one after the other, through
+    the same body.
 
-    A model with latent attention carries its one leaf where K lies (no
-    V): a layer writes the new latent row and attends ABSORBED, every
-    head's ``[q~ ; q_rope]`` against the rows as they lie
-    (``ops/mla_decode_attention.py``, one call a layer).  Leading dense
-    layers, and each run of whole periods of a stack that is not one
-    pattern throughout (``LlamaConfig.parts``), run as a scan of their
-    own, one after the other, through the same body.
-
-    This step is ``llama.layer_walk`` written out, its K/V the carry of
-    the layer scan: the walk, handed a carry, compiled to the same sizes
-    but not to the same text as the program the benchmark's cells have
-    measured since PR 24 (this step has cliffs: PERF.md section 6)."""
+    The scan is this step's own, not ``llama.walk_layers``': the walk,
+    handed K/V as a carry, compiled to the same sizes but not the same text
+    as the program the cells have measured since PR 24 (PERF.md section 6)."""
 
     n_win, hkv = cfg.layers_of("window"), cfg.n_kv_heads
 
     def step(carry, _):
         ck, cv, tok, lens, *state = carry
         x = llama.embed(params, tok, cfg)[:, None]
-        with jax.named_scope("qkv_proj"):
-            sin, cos = llama.rope_table(lens[:, None], cfg.rope_dim,
-                                        cfg.rope_theta, cfg.rope_scaling)
+        sin, cos = llama.rope_for(lens[:, None], cfg)
         # Inactive slots MUST not write: an occupied slot that is not
         # in this launch (LLMServer.slot_waiting) may hold a
         # prefill's fresh rows, and a stale-position
@@ -252,7 +245,6 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         def body(carry, period_and_index, part, sliced, stacks, l0):
             x, ck, cv, *state = carry
             period, p = period_and_index
-            plen = part.period_len
             n_of = part.period.count
             # where the part's first attending layer lies in the cache
             a0 = cfg.layers_before(l0, "attention")
@@ -260,11 +252,12 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
             for j, (kind, i, layer) in enumerate(
                     llama.period_layers(sliced, period, p, part)):
                 layer = {**layer, **stacks}
-                if part.kv_lora_rank:
+
+                def attend_absorbed(cq, latent):
                     # Latent attention, ABSORBED: the row written, then
                     # every head's [q~ ; q_rope] against the rows as they
                     # lie, read once for scores and values both.
-                    cq, latent = llama.latent_down(x, layer, sin, cos, part)
+                    nonlocal ck
                     q_nope, q_rope = llama.latent_queries(
                         cq, llama._wq_b_heads(layer, part), sin, cos, part)
                     l = p + a0
@@ -275,21 +268,14 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                         u = mla_decode_attention(
                             q, ck, l, lens, active, s_active=s_active,
                             scale=scale, v_width=part.kv_lora_rank)
-                    attn = llama.latent_absorb_values(u, layer,
-                                                      part)[:, None]
-                    x, _aux, rows_j = llama.attn_out_ffn(
-                        x, attn, layer, part, valid=active[:, None],
-                        layer_index=llama.layer_index(p, plen, j))
-                elif kind in llama.ATTENDING_KINDS:
+                    return llama.latent_absorb_values(
+                        u, layer, part)[:, None], None
+
+                def attend_pool(q, kk, vv):
                     # The layer's pool: the carry's K/V or, for a window
                     # layer, the rings after the lengths.
-                    ringed = kind == "window"
-                    pk, pv = state if ringed else (ck, cv)
-                    l = llama.layer_index(p, n_of(kind), i)
-                    if a0:
-                        l = l + a0
-                    q, kk, vv = llama._qkv_rope(x, layer, sin, cos, part,
-                                                kind)
+                    nonlocal ck, cv, state
+                    pk, pv = state if kind == "window" else (ck, cv)
                     # Write before attend: the new row is among the keys.
                     pk = _write(pk, l, rows, at[kind], kk[:, 0])
                     pv = _write(pv, l, rows, at[kind], vv[:, 0])
@@ -301,31 +287,32 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                             q[:, 0], pk, pv, l, lens, active,
                             s_active=s_active, scale=scale,
                             hkv=hkv)[:, None]
-                    if ringed:
+                    if kind == "window":
                         state = [pk, pv]
                     else:
                         ck, cv = pk, pv
-                    x, _aux, rows_j = llama.attn_out_ffn(
-                        x, attn, layer, part, valid=active[:, None],
-                        layer_index=llama.layer_index(p, plen, j))
-                else:
-                    # A layer that keeps a state (Mamba-2, short
-                    # convolution): its layer of the stacked states, in
-                    # place.
-                    mixer, proj_scope, out_scope = llama.state_mixer(kind)
-                    with jax.named_scope(proj_scope):
-                        h = llama.rms_norm(x, layer["attn_norm"],
-                                           cfg.norm_eps).astype(cfg.dtype)
+                    return attn, None
+
+                def state_step(mixer, h):
+                    # A state-keeping layer (Mamba-2, short convolution):
+                    # its layer of the stacked states, in place.
+                    nonlocal state
                     m = llama.layer_index(p, n_of(kind), i)
                     if l0:
                         m = m + cfg.layers_before(l0, kind)
                     out, *state = mixer.decode(h, layer, part, *state, m,
                                                active)
-                    with jax.named_scope(out_scope):
-                        x = llama.residual_add(x, out, cfg)
-                    x, _aux, rows_j = llama.ffn_half(
-                        x, layer, part, valid=active[:, None],
-                        layer_index=llama.layer_index(p, plen, j))
+                    return out, None
+
+                if kind in llama.ATTENDING_KINDS and not part.kv_lora_rank:
+                    # ``attend_pool``'s ``l``: the layer's place in its pool
+                    l = llama.layer_index(p, n_of(kind), i)
+                    if a0:
+                        l = l + a0
+                x, _aux, rows_j, _ = llama.layer_block(
+                    x, layer, kind, part, sin, cos,
+                    attend_absorbed if part.kv_lora_rank else attend_pool,
+                    state_step, valid=active, at=(p, part.period_len, j))
                 expert_rows.append(rows_j)
             return (x, ck, cv, *state), llama.stack_period(expert_rows,
                                                            part)

@@ -821,8 +821,8 @@ def flash_attention_causal(q, k, v, positions=None,
                            block_k: Optional[int] = None):
     """Drop-in for models.llama.dot_attention (standard causal layout;
     packed/offset positions must use the dot path).  ``block_q``/
-    ``block_k`` override the kernel tile sizes (LlamaConfig
-    ``attn_block_q``/``attn_block_k``)."""
+    ``block_k`` override the kernel tile sizes (None: the default,
+    ``DEFAULT_BLOCK``)."""
     _check_default_positions(positions, q.shape[1], "flash_attention_causal")
     return flash_attention(q, k, v, causal=True, block_q=block_q,
                            block_k=block_k)

@@ -57,7 +57,7 @@ def _reference_chunk(cfg, params, cache, tok, lens, active, k, s_active):
             cv_l = jnp.where(writemask, vv.astype(cv_l.dtype), cv_l)
             attn = llama._cache_attend(q, ck_l, cv_l, lens[:, None],
                                        scale)
-            return llama._attn_out_mlp(x, attn, layer, cfg), (ck_l, cv_l)
+            return llama.attn_out_ffn(x, attn, layer, cfg)[0], (ck_l, cv_l)
 
         x, (ck, cv) = jax.lax.scan(body, x, (params["layers"], ck, cv))
         x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)

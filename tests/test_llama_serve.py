@@ -114,6 +114,73 @@ def test_programs_lower_from_a_config_alone(kind, plane):
     assert len(names) == (4 if plane == "dense" else 5)
 
 
+def _primitives(jaxpr):
+    """Names of every primitive in a jaxpr, those of its sub-jaxprs
+    (a scan's body, a jitted call) among them."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("kind", list(PRESETS))
+def test_a_serve_program_holds_no_checkpoint(kind):
+    """Training hands the walk its remat as a wrapper of the block;
+    serving hands it none, whatever ``remat`` says (True by default): the
+    prefill and the decode step differentiate nothing, and neither their
+    jaxprs nor their lowered text hold a ``checkpoint`` / ``remat``
+    call."""
+    cfg = _cfg(kind, remat=True)
+    args = {name: low for name, _cache, low in _lowered_dense(cfg)}
+    assert {"prefill", "decode_k"} <= set(args)
+    for name in ("prefill", "decode_k"):
+        text = args[name].as_text(debug_info=True)
+        assert not re.search(r"\bcheckpoint\b|remat", text), name
+    params, cache = _abstract_params(cfg), jax.eval_shape(
+        lambda: llama.init_kv_cache(cfg, SLOTS, MAX_LEN))
+    traced = jax.make_jaxpr(llama_serve.build_prefill(cfg))(
+        params, cache, _arr(jnp.int32, 4, 16), _arr(jnp.int32, 4),
+        _arr(jnp.int32, 4))
+    remat = {"checkpoint", "remat", "remat2"}    # the primitive's names
+    names = _primitives(traced.jaxpr)
+    assert "scan" in names and not names & remat
+    # and training's own walk, under the same config, does hold one
+    loss = jax.make_jaxpr(lambda p, t: llama.forward(p, t, cfg))(
+        params, _arr(jnp.int32, 2, 16))
+    assert _primitives(loss.jaxpr) & remat
+
+
+# -------------------------------------------- a layer is written once
+def test_a_layer_is_made_of_its_pieces_in_one_place():
+    """``attn_out_ffn``, ``ffn_half`` and ``state_mixer`` -- what follows
+    a layer's mixer, and which module a state-keeping kind's mixer is --
+    are called from ``llama.layer_block`` and from nowhere else in the
+    three modules that walk layers (``attn_out_ffn`` is itself the output
+    projection before ``ffn_half``); no ``decoder_layer`` is left."""
+    import inspect
+
+    from ray_tpu.models import llama_pipeline
+
+    called = re.compile(r"(?<!def )(?<![\w`.])(?:llama\.)?"
+                        r"(attn_out_ffn|ffn_half|state_mixer)\(")
+    once = (llama.layer_block, llama.attn_out_ffn)
+    assert set(called.findall(inspect.getsource(once[0]))) == {
+        "attn_out_ffn", "ffn_half", "state_mixer"}
+    assert called.findall(inspect.getsource(once[1])) == ["ffn_half"]
+    for module in (llama, llama_serve, llama_pipeline):
+        source = inspect.getsource(module)
+        assert "def decoder_layer" not in source
+        if module is llama:
+            for fn in once:
+                source = source.replace(inspect.getsource(fn), "")
+        assert called.findall(source) == [], module.__name__
+
+
 # ------------------------------------------- the engine's programs ARE these
 @pytest.mark.parametrize("kind", list(PRESETS))
 def test_decode_k_from_a_config_is_the_engines(kind):

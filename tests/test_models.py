@@ -174,19 +174,25 @@ def test_remat_policy_registry_consistent():
             LlamaConfig.debug(), remat_policy=policy)))
 
 
-def test_attn_block_override_matches_default(cfg):
-    """attn_block_q/k change the flash kernel's tiling only — logits
+def test_attn_block_override_matches_default(cfg, monkeypatch):
+    """The flash kernel's tile sizes change its tiling only — logits
     match the default-blocked kernel (numerics identical up to
-    blocking, asserted loosely in bf16)."""
+    blocking, asserted loosely in bf16).  The tiles are the kernel's
+    arguments, handed to it here; no config field names them."""
     import dataclasses
+    import functools
+
+    from ray_tpu.ops.flash_attention import flash_attention_causal
 
     base = dataclasses.replace(cfg, attention_impl="flash")
-    tuned = dataclasses.replace(base, attn_block_q=16, attn_block_k=16)
     p = init_params(jax.random.key(0), base)
     toks = jax.random.randint(jax.random.key(8), (2, 32), 0,
                               cfg.vocab_size)
     a = forward(p, toks, base)
-    b = forward(p, toks, tuned)
+    monkeypatch.setattr(
+        llama, "_get_attention_fn", lambda config: functools.partial(
+            flash_attention_causal, block_q=16, block_k=16))
+    b = forward(p, toks, base)
     # bf16 logits: one ulp at |logit|~8 is 0.0625 — blocking changes
     # the accumulation order, nothing else.
     np.testing.assert_allclose(np.asarray(a, np.float32),
@@ -293,3 +299,141 @@ def test_moe_sharded_step_matches_single_device(moe_cfg, spec):
 
     np.testing.assert_allclose(float(metrics["loss"]),
                                float(ref_metrics["loss"]), rtol=3e-2)
+
+
+# ------------------------------------------- "served only" in one place
+def _forward_refused_before(c):
+    """``llama.forward``'s condition as it stood before ``plain_decoder``
+    (PR 42), kept as the reference the property is held to."""
+    return bool(
+        c.layers_of("mamba") or c.attention_multiplier is not None
+        or c.embedding_multiplier != 1.0 or c.logits_scaling != 1.0
+        or c.layers_of("window") or c.nope_kinds
+        or c.moe_router_input != "ffn" or c.kv_lora_rank
+        or c.rope_scaling is not None or c.first_dense_layers
+        or c.moe_held or c.layer_types or c.layers_of("conv")
+        or c.qk_head_norm or c.moe_router_score != "softmax"
+        or c.moe_router_bias)
+
+
+def _cache_refused_before(c):
+    """``llama.forward_with_cache``'s, before ``one_kv_stack``."""
+    return bool(c.layers_of("mamba") or c.layers_of("window")
+                or c.kv_lora_rank or c.layers_of("conv") or c.layer_types)
+
+
+_PRESETS = ("debug", "moe_debug", "hybrid_debug", "llama_moe_1b",
+            "llama_125m", "llama_440m", "llama2_7b", "llama3_8b")
+_BENCH_CONFIGS = ("internlm2-1.8b", "smollm2-360m", "olmoe-1b-7b",
+                  "granite-4.0-h-micro", "smallthinker-21b-a3b",
+                  "deepseek-v2", "lfm2-8b-a1b")
+_SSM = dict(ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_chunk=8)
+# one config a term of the two old conditions, and four that neither held
+_TERMS = {
+    "mamba": dict(layer_pattern=("mamba", "attention"), **_SSM),
+    "attention_multiplier": dict(attention_multiplier=0.125),
+    "embedding_multiplier": dict(embedding_multiplier=12.0),
+    "logits_scaling": dict(logits_scaling=8.0),
+    "window": dict(layer_pattern=("attention", "window"), window_size=8),
+    "nope_kinds": dict(nope_kinds=("attention",)),
+    "moe_router_input": dict(moe_experts=4, moe_router_input="layer"),
+    "kv_lora_rank": dict(kv_lora_rank=32, q_lora_rank=16,
+                         qk_nope_head_dim=8, qk_rope_head_dim=8,
+                         v_head_dim=16),
+    "rope_scaling": dict(rope_scaling={
+        "type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
+        "mscale": 0.707, "mscale_all_dim": 0.707,
+        "original_max_position_embeddings": 16}),
+    "first_dense_layers": dict(moe_experts=4, first_dense_layers=1),
+    "moe_held": dict(moe_experts=4, moe_held=(0, 2)),
+    "layer_types": dict(layer_types=("attention", "attention")),
+    "conv": dict(layer_types=("conv", "attention")),
+    "qk_head_norm": dict(qk_head_norm=True),
+    "moe_router_score": dict(moe_experts=4, moe_router_score="sigmoid"),
+    "moe_router_bias": dict(moe_experts=4, moe_router_score="sigmoid",
+                            moe_router_bias=True),
+    "residual_multiplier": dict(residual_multiplier=0.22),
+    "no_rope": dict(rope=False),
+    "qk_norm": dict(qk_norm=True),
+    "stream_dtype": dict(stream_dtype="float32"),
+}
+
+
+def _config(source, name):
+    if source == "preset":
+        return getattr(LlamaConfig, name)()
+    if source == "term":
+        return LlamaConfig.debug(**_TERMS[name])
+    import json
+    import os
+
+    from benchmarks.lib import program, spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs", name + ".json")) as f:
+        return program.llama_config(json.load(f))
+
+
+@pytest.mark.parametrize("source,name", [
+    *[("preset", name) for name in _PRESETS],
+    *[("bench", name) for name in _BENCH_CONFIGS],
+    *[("term", name) for name in _TERMS]])
+def test_served_only_is_one_derived_property(source, name):
+    """``plain_decoder`` refuses for ``forward`` (and a pipeline stage)
+    exactly what its sixteen-term condition refused, ``one_kv_stack`` for
+    ``forward_with_cache`` exactly what its five-term one did: over every
+    preset, every configuration the benchmark holds and one config a
+    term.  The refusals keep their type and the words tests match on."""
+    from ray_tpu.models.llama_pipeline import check_pipeline_config
+
+    c = _config(source, name)
+    assert (not c.plain_decoder) == _forward_refused_before(c)
+    assert (not c.one_kv_stack) == _cache_refused_before(c)
+    assert c.plain_decoder <= c.one_kv_stack
+    if source == "term":
+        assert _forward_refused_before(c) == (
+            name not in ("residual_multiplier", "no_rope", "qk_norm",
+                         "stream_dtype"))
+    toks = jnp.zeros((1, 8), jnp.int32)
+    if not c.plain_decoder:
+        with pytest.raises(NotImplementedError, match="served only"):
+            forward(None, toks, c)
+        # (a pipeline stage computed a plain decoder over whatever
+        # leaves it found, or raised a KeyError, before PR 43)
+        with pytest.raises(NotImplementedError, match="served only"):
+            check_pipeline_config(c, 2)
+    if not c.one_kv_stack:
+        with pytest.raises(NotImplementedError,
+                           match="holds one K/V stack alone"):
+            llama.forward_with_cache(None, toks[:, :1], toks[:, :1], {}, c)
+
+
+# ------------------------------------ training goes through the one walk
+_loss_and_grads = jax.jit(jax.value_and_grad(loss_fn), static_argnums=2)
+
+
+@pytest.fixture(scope="module", params=["debug", "moe_debug"])
+def plain_reference(request):
+    """(preset, params, batch, loss and gradients) in float32 with no
+    remat and no unroll."""
+    make = getattr(LlamaConfig, request.param)
+    base = make(dtype=jnp.float32, remat=False, scan_unroll=1)
+    p = init_params(jax.random.key(0), base)
+    batch = {"tokens": jax.random.randint(jax.random.key(4), (2, 16), 0,
+                                          base.vocab_size)}
+    return make, p, batch, _loss_and_grads(p, batch, base)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(remat=True, scan_unroll=1),
+    dict(remat=True, remat_policy="attn_ffn", scan_unroll=2),
+], ids=["remat", "remat_attn_ffn_unroll2"])
+def test_loss_and_gradients_whatever_remat_and_unroll(plain_reference, kw):
+    """``forward`` is ``walk_layers`` under training's wrapper: the remat
+    of the block and the scan's unroll change what is recomputed and how
+    the loop is laid out, never the numbers (float32, 1e-6)."""
+    make, p, batch, want = plain_reference
+    got = _loss_and_grads(p, batch, make(dtype=jnp.float32, **kw))
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-6),
+        got, want)
