@@ -237,6 +237,14 @@ class LlamaConfig:
     # sorted rows and their un-sorted copy (positions x top-k x hidden
     # each) are a chunk's and not the prompt's.
     moe_dispatch_chunk: int = 0
+    # A learned sparse-attention INDEXER in front of every attention layer
+    # (models/indexer.py; served only, dense plane), on with index_topk >
+    # 0: index_heads index queries of index_head_dim and ONE index key of
+    # that width a token, which the serving cache keeps beside K and V; a
+    # query attends the index_topk keys its index scores rank highest.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         # a configuration file's lists and type names, made hashable
@@ -322,6 +330,16 @@ class LlamaConfig:
                     or self.nope_kinds):
                 raise ValueError("latent attention has its own norms and "
                                  "always rotates its rope part")
+        if self.index_topk:
+            if patterned or self.kv_lora_rank or self.first_dense_layers:
+                raise ValueError(
+                    "an indexer keeps one index key a token and layer "
+                    "beside K and V: no serving cache holds that pool "
+                    "beside window rings, recurrent states or a latent "
+                    "row (one stack of attention layers only)")
+            if min(self.index_heads, self.index_head_dim) < 1:
+                raise ValueError("an indexer needs index_heads and "
+                                 "index_head_dim")
         if self.rope_scaling is not None:
             kind = dict(self.rope_scaling).get("type")
             if kind != "yarn":
@@ -447,9 +465,10 @@ class LlamaConfig:
 
     @property
     def one_kv_stack(self) -> bool:
-        """Every layer keeps K and V rows by position, in one stack (what
-        ``forward_with_cache`` holds): no other kind, list or latent cache."""
-        return not (self.layer_types or self.kv_lora_rank
+        """Every layer keeps K and V rows by position, in one stack, and
+        attends all of them (what ``forward_with_cache`` holds): no other
+        kind, list, latent cache or indexer."""
+        return not (self.layer_types or self.kv_lora_rank or self.index_topk
                     or any(self.layers_of(kind) for kind in LAYER_KINDS
                            if kind != "attention"))
 
@@ -633,6 +652,10 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
             wkv_a=("layers", "embed", None), kv_a_norm=("layers", None),
             wk_b=("layers", "heads", None, None),
             wv_b=("layers", "heads", None, None))
+    if config.index_topk:
+        from ray_tpu.models import indexer
+
+        axes["layers"].update(indexer.param_axes(config))
     if config.layers_of("mamba"):
         from ray_tpu.models import mamba2
 
@@ -751,6 +774,11 @@ def init_params(rng: jax.Array, config: LlamaConfig,
             del params["layers"][name]
         params["layers"].update(_init_latent_attention(
             jax.random.fold_in(rng, 95), c, La, dtype, dense))
+    if c.index_topk:
+        from ray_tpu.models import indexer
+
+        params["layers"].update(indexer.init_params(
+            jax.random.fold_in(rng, 92), c, La, dense))
     if c.layers_of("mamba"):
         from ray_tpu.models import mamba2
 
@@ -928,13 +956,15 @@ def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
 def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   positions: jax.Array,
                   scale: Optional[float] = None,
-                  window: Optional[int] = None) -> jax.Array:
+                  window: Optional[int] = None,
+                  keep: Optional[jax.Array] = None) -> jax.Array:
     """Reference einsum attention, causal, GQA via head broadcast.
 
     q: (B, S, Hq, D); k/v: (B, S, Hkv, D).  All-jnp so XLA fuses; the
     flash/ring impls are drop-in replacements (ray_tpu.ops).  With
     ``window`` a query sees the last ``window`` keys only, its own among
-    them.
+    them; with ``keep`` (B, S, S), nonzero where query row sees key
+    column, those keys of the causal ones only.
     """
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -949,6 +979,8 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if window is not None:
         mask &= (positions[:, None, None, :, None]
                  - positions[:, None, None, None, :]) < window
+    if keep is not None:
+        mask &= keep[:, None, None] != 0
     scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v,
@@ -980,7 +1012,9 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     """Shared by the training forward and the KV-cache decode path —
     the conventions here (f32 MXU accumulation via matmul, bf16 rope)
     must stay identical across both.  ``kind``: the attending layer's,
-    which decides whether it rotates (``LlamaConfig.ropes``)."""
+    which decides whether it rotates (``LlamaConfig.ropes``).  A config
+    with an indexer gives a fourth fresh row beside ``(q, k, v)``: the
+    index queries, index key and weights of ``indexer.project``."""
     c = config
     B, S, _ = x.shape
     dt = c.dtype
@@ -1002,6 +1036,10 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
         k = apply_rope(k, sin, cos)
     q = with_logical_constraint(q, "batch", "seq", "heads", "head_dim")
     k = with_logical_constraint(k, "batch", "seq", "kv_heads", "head_dim")
+    if c.index_topk:
+        from ray_tpu.models import indexer
+
+        return q, k, v, indexer.project(h, layer, c)
     return q, k, v
 
 
@@ -1443,7 +1481,8 @@ def layer_block(x, layer, kind: str, config: LlamaConfig, sin, cos,
     made of, for training, the prefills and the decode step alike.  What
     the fresh rows meet (themselves, a cache, a carried state) is the
     caller's: ``attend(q, k, v)`` (latent attention: ``attend(cq,
-    latent)``) and ``state_step(mixer, h)``, closures of the scan body that
+    latent)``; behind an indexer: ``attend(q, k, v, index)``) and
+    ``state_step(mixer, h)``, closures of the scan body that
     owns the cache or the carry, return ``(the mixer's output, ys)``, ``ys``
     what the caller keeps; an ``attend`` scopes its ``kv_write`` and
     ``attention`` itself.  ``valid``: ``ffn_half``'s, or (B,), whole rows;
@@ -1483,11 +1522,11 @@ def walk_block(sin, cos, positions, kv_step: Callable, window_step=None,
     """``layer_block`` over whole sequences with ``layer_walk``'s steps:
     ``block(x, layer, its slice of the cache walked, at, kind, config)``."""
     def block(x, layer, kv_layer, at, kind, c):
-        def attend(q, k, v):
+        def attend(q, k, v, *index):
             with jax.named_scope("attention"):
                 if kind == "window":
                     return window_step(q, k, v, positions)
-                return kv_step(q, k, v, positions, kv_layer)
+                return kv_step(q, k, v, positions, kv_layer, *index)
 
         def attend_expanded(cq, latent):
             # latent attention, expanded: attended as heads of their own
@@ -2075,7 +2114,7 @@ def prefill_forward(params: PyTree, tokens: jax.Array,
     length 0); ``return_expert_rows`` adds a fourth result, the (L, E)
     int32 rows each layer's experts computed (None for a dense
     config)."""
-    last_logits, ks, vs, expert_rows, _states, _window = \
+    last_logits, ks, vs, expert_rows, _states, _window, _index_keys = \
         prefill_with_states(params, tokens, lengths, config)
     if return_expert_rows:
         return last_logits, ks, vs, expert_rows
@@ -2085,15 +2124,18 @@ def prefill_forward(params: PyTree, tokens: jax.Array,
 def prefill_with_states(params: PyTree, tokens: jax.Array,
                         lengths: jax.Array, config: LlamaConfig):
     """``prefill_forward`` with everything a serving cache takes in:
-    ``(last_logits, ks, vs, expert rows, Mamba states, window K/V)`` --
-    ks/vs over the attention layers alone; the states ``(recurrent (Lm,
+    ``(last_logits, ks, vs, expert rows, Mamba states, window K/V, index
+    keys)`` -- ks/vs over the attention layers alone; the states ``(recurrent (Lm,
     G, N, nh x hd), conv (Lm, K - 1, G, conv_dim))`` as of each row's
     last real position, None for a model without Mamba layers; the
     window layers' ``(ks, vs)`` at every position of the prompt (which
     of them a cache keeps is the cache's business), None without such
     layers.  A model with latent attention attends the EXPANDED form and
     returns its latent rows ``(L, G, P, latent_row)`` as ``ks``, None as
-    ``vs``.
+    ``vs``.  Behind an indexer a query attends the keys its index scores
+    select (``indexer.prefill_keep``: a mask, the attention computes dense
+    masked tiles), and the index keys come back transposed, ``(L, G,
+    index_head_dim, P)``, as a serving cache keeps them; None without one.
 
     Attention is the masked einsum while its (G, Hq, P, P) float32
     scores are small, and the flash forward (``ops/flash_attention.py``,
@@ -2106,16 +2148,25 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
         # A latent model's call writes no softmax statistics: only a
         # backward pass reads ``lse`` (6 MB for 128 heads at 12,288
         # positions since it is lane-dense, 768 MB as a width-1 column).
-        def attend(q, k, v, positions, window):
-            return flash_prefill_attention(q, k, v, scale=scale,
-                                           window=window,
-                                           lse=not config.kv_lora_rank)
+        # Nor does the masked call behind an indexer.
+        def attend(q, k, v, positions, window, keep=None):
+            return flash_prefill_attention(
+                q, k, v, scale=scale, window=window, keep=keep,
+                lse=not (config.kv_lora_rank or config.index_topk))
     else:
-        def attend(q, k, v, positions, window):
-            return dot_attention(q, k, v, positions, scale, window)
+        def attend(q, k, v, positions, window, keep=None):
+            return dot_attention(q, k, v, positions, scale, window, keep)
 
-    def kv_step(q, k, v, positions, _cache):
-        return attend(q, k, v, positions, None), (k, v)
+    def kv_step(q, k, v, positions, _cache, index=None):
+        if index is None:
+            return attend(q, k, v, positions, None), (k, v)
+        from ray_tpu.models import indexer
+
+        qi, ki, w = index
+        ki_t = jnp.swapaxes(ki, 1, 2)
+        keep = indexer.prefill_keep(qi, ki_t, w, config.index_topk)
+        with jax.named_scope("sparse_attention"):
+            return attend(q, k, v, positions, None, keep), (k, v, ki_t)
 
     def window_step(q, k, v, positions):
         return attend(q, k, v, positions, config.window_size), (k, v)
@@ -2124,8 +2175,9 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
         params, tokens, config, kv_step, lengths=lengths,
         window_step=window_step)
     # latent attention keeps ONE leaf: its rows come back as ``ks``
-    ks, vs = (ys, None) if config.kv_lora_rank else ys
-    return last_logits, ks, vs, expert_rows, states, window
+    ks, vs, *index_keys = (ys, None) if config.kv_lora_rank else ys
+    return (last_logits, ks, vs, expert_rows, states, window,
+            index_keys[0] if index_keys else None)
 
 
 @jax.named_scope("attention")

@@ -12,7 +12,8 @@ engine, thread or weights are needed to lower one.  By the cache kept:
   a pool of every position for its full layers and a ring of the last
   ``window_size`` for its window layers; for a model with latent
   attention ONE leaf of a latent row a token and layer in place of K and
-  V): ``prefill``, ``decode_k``;
+  V; for a model with an indexer a pool of one index key a token and
+  layer beside K and V): ``prefill``, ``decode_k``;
 - a block pool ``(N, L, bs, Hkv, D)`` (``llama.init_paged_kv_cache``):
   ``prefill_cold``, ``prefill_warm``, ``decode_paged``, ``inject`` (and
   its inverse ``BlockPool.extract``), ``spec_verify``;
@@ -40,7 +41,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
+from ray_tpu.models import indexer, llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.decode_attention import decode_attention
 from ray_tpu.ops.mla_decode_attention import mla_decode_attention
@@ -87,6 +88,8 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
         # whole lanes) in place of K and V a head.
         return {"latent": jnp.zeros(
             (cfg.n_layers, slots, max_len, cfg.latent_row), cfg.dtype)}
+    if cfg.index_topk:
+        return _init_indexed_cache(cfg, slots, max_len)
     cache = llama.init_kv_cache(cfg, slots, max_len)
     if cfg.layers_of("mamba"):
         from ray_tpu.models import mamba2
@@ -100,6 +103,27 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     return cache
 
 
+def _init_indexed_cache(cfg: LlamaConfig, slots: int, max_len: int):
+    """A model with an indexer keeps a third pool by position beside K
+    and V: ``ik``, ONE index key a token and layer, TRANSPOSED -- ``(L, B,
+    index_head_dim, positions)``, positions along lanes: a 64-wide minor
+    dimension the chip would pad to 128 lanes, and the scores' matmul reads
+    a slot's ``(Di, S)`` as it lies (``models/indexer.py``).  K and V are
+    stored as the rows the decode kernel reads, ``(L, B, positions * Hkv,
+    D)`` (``_init_window_cache``)."""
+    layers = cfg.layers_of("attention")
+    return {"k": _row_pool(cfg, layers, slots, max_len),
+            "v": _row_pool(cfg, layers, slots, max_len),
+            "ik": jnp.zeros((layers, slots, cfg.index_head_dim, max_len),
+                            cfg.dtype)}
+
+
+def _row_pool(cfg: LlamaConfig, layers: int, slots: int, positions: int):
+    """A K or V pool stored as the rows the decode kernel reads."""
+    return jnp.zeros((layers, slots, positions * cfg.n_kv_heads,
+                      cfg.head_dim), cfg.dtype)
+
+
 def _init_window_cache(cfg: LlamaConfig, slots: int, max_len: int):
     """A model with window layers keeps two K/V pools: ``k`` / ``v``,
     every position of its full layers, and ``wk`` / ``wv``, a RING of
@@ -109,8 +133,7 @@ def _init_window_cache(cfg: LlamaConfig, slots: int, max_len: int):
     ``(..., positions, 4, D)`` leaf is padded to the sublane tile and
     occupies a multiple of its bytes."""
     def pool(layers, positions):
-        return jnp.zeros((layers, slots, positions * cfg.n_kv_heads,
-                          cfg.head_dim), cfg.dtype)
+        return _row_pool(cfg, layers, slots, positions)
 
     full = cfg.layers_of("attention"), max_len
     ring = cfg.layers_of("window"), ring_len(cfg, max_len)
@@ -127,10 +150,11 @@ def cache_pools(cfg: LlamaConfig, slots: int, max_len: int):
     """``{pool: (bytes, storage type)}`` of ``init_cache``'s tree: ``kv``
     (K and V together) and, for a model with Mamba layers, ``ssm`` and
     ``conv``; for a model with window layers ``kv_full`` and
-    ``kv_window``; for a model with latent attention ``latent`` alone."""
+    ``kv_window``; for a model with latent attention ``latent`` alone;
+    for a model with an indexer ``index_keys`` beside ``kv``."""
     shapes = jax.eval_shape(lambda: init_cache(cfg, slots, max_len))
     pool_of = {"k": "kv_full" if "wk" in shapes else "kv",
-               "wk": "kv_window"}
+               "wk": "kv_window", "ik": "index_keys"}
     pool_of.update(v=pool_of["k"], wv="kv_window")
     pools = {}
     for name, leaf in shapes.items():
@@ -146,8 +170,7 @@ def state_bytes_per_slot(cfg: LlamaConfig):
     layers: ``conv`` alone; {} for a model without such layers): what a
     decode step reads and writes for a slot it advances."""
     return {pool: nbytes for pool, (nbytes, _) in
-            cache_pools(cfg, 1, 1).items()
-            if not pool.startswith("kv") and pool != "latent"}
+            cache_pools(cfg, 1, 1).items() if pool in ("ssm", "conv")}
 
 
 @jax.named_scope("kv_write")
@@ -206,7 +229,11 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     ring rows through the same kernel.  A model with latent attention
     carries its one leaf where K lies (no V): a layer writes the new
     latent row and attends ABSORBED (``ops/mla_decode_attention.py``, one
-    call a layer).  Leading dense layers, and each run of whole periods
+    call a layer).  A model with an indexer carries its index keys after
+    the lengths, ``(ck, cv, tok, lens, ik)``: a layer writes the new index
+    key beside K and V, scores the row's index keys, selects, and attends
+    the selected keys alone, the others masked (``attend_selected``).
+    Leading dense layers, and each run of whole periods
     of a stack that is not one pattern throughout (``LlamaConfig.
     parts``), run as a scan of their own, one after the other, through
     the same body.
@@ -293,6 +320,36 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                         ck, cv = pk, pv
                     return attn, None
 
+                def attend_selected(q, kk, vv, index):
+                    # Behind an indexer: the new index key written like K
+                    # and V (write before score: the new key is among the
+                    # candidates), the row's index keys scored whole, and
+                    # of the row's keys only those selected attended --
+                    # through the same kernel, which reads the rows as they
+                    # lie and masks the others.  A row no longer than
+                    # ``index_topk`` selects every key: the dense result.
+                    nonlocal ck, cv, state
+                    qi, ki, w = index
+                    ck = _write(ck, l, rows, pos, kk[:, 0])
+                    cv = _write(cv, l, rows, pos, vv[:, 0])
+                    (ik,) = state
+                    ik = _write_index_key(ik, l, rows, pos, ki[:, 0])
+                    state = [ik]
+                    seen = min(s_active, ik.shape[3])
+                    with jax.named_scope("indexer"):
+                        keys_t = jax.lax.dynamic_slice(
+                            ik, (l, 0, 0, 0), (1,) + ik.shape[1:3] + (seen,))
+                    keep = indexer.select(
+                        indexer.scores(qi, keys_t[0], w)[:, 0],
+                        jnp.where(active, jnp.minimum(lens + 1, seen), 0),
+                        part.index_topk)
+                    with jax.named_scope("sparse_attention"):
+                        attn = decode_attention(
+                            q[:, 0], ck, cv, l, lens, active,
+                            s_active=s_active, scale=scale, hkv=hkv,
+                            keep=keep)[:, None]
+                    return attn, None
+
                 def state_step(mixer, h):
                     # A state-keeping layer (Mamba-2, short convolution):
                     # its layer of the stacked states, in place.
@@ -311,7 +368,9 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                         l = l + a0
                 x, _aux, rows_j, _ = llama.layer_block(
                     x, layer, kind, part, sin, cos,
-                    attend_absorbed if part.kv_lora_rank else attend_pool,
+                    attend_absorbed if part.kv_lora_rank
+                    else attend_selected if part.index_topk
+                    else attend_pool,
                     state_step, valid=active, at=(p, part.period_len, j))
                 expert_rows.append(rows_j)
             return (x, ck, cv, *state), llama.stack_period(expert_rows,
@@ -361,10 +420,19 @@ def _write(pool, l, slots, pos, new):
         unique_indices=True)
 
 
+@jax.named_scope("kv_write")
+def _write_index_key(pool, l, slots, pos, new):
+    """``new`` (B, Di) as slot ``slots[b]``'s position ``pos[b]`` of layer
+    ``l`` of an index-key pool ``(layers, B, Di, positions)``; a position
+    out of range writes nothing."""
+    return pool.at[l, slots, :, pos].set(
+        new.astype(pool.dtype), mode="drop", unique_indices=True)
+
+
 # What rides the carry after the lengths, if the cache has it: a model
-# has Mamba states, conv states or window rings, never two of them.  And
-# the axis of a state leaf that counts the slots.
-_CARRIED = (("ssm", "conv"), ("conv",), ("wk", "wv"))
+# has Mamba states, conv states, window rings or index keys, never two of
+# them.  And the axis of a state leaf that counts the slots.
+_CARRIED = (("ssm", "conv"), ("conv",), ("wk", "wv"), ("ik",))
 _SLOT_AXIS = {"ssm": 1, "conv": 2}
 
 
@@ -399,17 +467,26 @@ def _insert_rows(pool, new, slots):
     on, every row written where it lies.  pool ``(layers, B, positions,
     Hkv, D)`` or its rows ``(layers, B, positions * Hkv, D)``; new
     ``(layers, G, P, Hkv, D)``, ``P`` at most the pool's positions; slots
-    (G,), a negative one (the padding of a rung) writes nothing.  Per
-    member one slot's first ``P`` positions are read, selected against
-    the slot's sign and written back: nothing of the pool's shape, nor of
-    ``(layers, B, P, ...)``, is made whatever ``G`` is, which is what a
-    dense engine's slot count rests on (PERF.md section 4;
-    ``tests/test_prefill_inplace.py`` holds it at the real widths).  The
-    pool is never reshaped: its two layouts tile differently on the chip,
-    and a view of one as the other copies the whole pool in and out."""
+    (G,), a negative one (the padding of a rung) writes nothing.  The pool
+    is never reshaped: its two layouts tile differently on the chip, and a
+    view of one as the other copies the whole pool in and out."""
     layers, G = new.shape[:2]
     # the group as the pool is stored: by position, or as rows
-    new = new.reshape((layers, G, -1) + pool.shape[3:]).astype(pool.dtype)
+    return _insert_slices(
+        pool, new.reshape((layers, G, -1) + pool.shape[3:]), slots)
+
+
+def _insert_slices(pool, new, slots):
+    """``new`` ``(layers, G, ...)`` into a pool ``(layers, B, ...)`` of the
+    same rank, member g at slot ``slots[g]`` from the first index of every
+    further axis on (index keys ``(layers, G, Di, P)`` lie as their pool
+    does, positions last).  Per member one slot's slice is read, selected
+    against the slot's sign and written back: nothing of the pool's shape,
+    nor of ``(layers, B, P, ...)``, is made whatever ``G`` is, which is what
+    a dense engine's slot count rests on (PERF.md section 4;
+    ``tests/test_prefill_inplace.py`` holds it at the real widths)."""
+    layers, G = new.shape[:2]
+    new = new.astype(pool.dtype)
     one_slot = (layers, 1) + new.shape[2:]
     for g in range(G):
         at = (0, jnp.maximum(slots[g], 0)) + (0,) * (pool.ndim - 2)
@@ -440,9 +517,14 @@ def _ring_rows(rows, lengths, ring: int):
 
 def build_prefill(cfg: LlamaConfig) -> Callable:
     def prefill(params, cache, tokens, lengths, slots):
-        last_logits, ks, vs, rows, states, window = \
+        last_logits, ks, vs, rows, states, window, index_keys = \
             llama.prefill_with_states(params, tokens, lengths, cfg)
-        if cfg.kv_lora_rank:
+        if index_keys is not None:
+            with jax.named_scope("kv_write"):
+                ik = _insert_slices(cache["ik"], index_keys, slots)
+            cache = {"k": _insert_rows(cache["k"], ks, slots),
+                     "v": _insert_rows(cache["v"], vs, slots), "ik": ik}
+        elif cfg.kv_lora_rank:
             cache = {"latent": _insert_rows(cache["latent"], ks, slots)}
         elif window is not None:
             ring = cache["wk"].shape[2] // cfg.n_kv_heads

@@ -523,6 +523,7 @@ SCOPES = (
     "mla_absorb", "mla_expand", "mla_decode_attention",
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_state_update", "ssm_out",
     "conv_proj", "short_conv", "conv_out",
+    "indexer", "index_select", "sparse_attention",
     "head", "sample", "head_loss", "optimizer",
 )
 PHASES = ("forward", "backward", "remat")

@@ -436,6 +436,14 @@ def serve_engine_counters():
             "cache positions the live rows held when a decode chunk was "
             "launched: what its attention has to read",
             tag_keys=("deployment",)),
+        # A model with an indexer only (attended then counts the positions
+        # SELECTED).
+        "decode_kv_positions_present": Counter(
+            "ray_tpu_serve_decode_kv_positions_present_total",
+            "cache positions the live rows of a model with an indexer held "
+            "when a decode chunk was launched: each is an index key its "
+            "step scores; attended / present = the share of its keys a "
+            "step reads", tag_keys=("deployment",)),
         "decode_kv_positions_bucket": Counter(
             "ray_tpu_serve_decode_kv_positions_bucket_total",
             "max_slots x attended length bucket per decode chunk: what "

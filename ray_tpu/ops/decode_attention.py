@@ -93,7 +93,7 @@ def _tiles(hkv: int, d: int, as_rows: bool = False) -> bool:
 
 def _kernel(layer_ref, n_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
             kbuf, vbuf, sems, m_scr, l_scr, acc_scr,
-            *, bk, hkv, s_len, scale, align):
+            *, bk, hkv, s_len, scale, align, keep_ref=None):
     slots = q_ref.shape[0]
     width = bk * hkv
     layer = layer_ref[0]
@@ -155,6 +155,13 @@ def _kernel(layer_ref, n_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
         s = jax.lax.dot_general(q, kbuf[slot], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale + bias_ref[...]
+        if keep_ref is not None:
+            # A selection that is data: NEG_INF at the rows of this block
+            # the row does not attend.  A block of which it attends nothing
+            # counts NEG_INF - NEG_INF = 0; a block that holds a key it
+            # attends scales that away if it comes after (alpha = 0) and
+            # adds exact zeros if it came before.  Every row attends a key.
+            s = s + keep_ref[r, pl.ds(j, 1), :]
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where((col >= lo) & (col < hi), s, NEG_INF)
         m_prev = m_scr[:, :1]
@@ -197,14 +204,17 @@ def _head_bias(hq_pad: int, hq: int, hkv: int, bk: int) -> np.ndarray:
 def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
                      layer: jax.Array, lens: jax.Array, active: jax.Array,
                      *, s_active: int, scale: float,
-                     hkv: int = 0) -> jax.Array:
+                     hkv: int = 0, keep=None) -> jax.Array:
     """One query a row against layer ``layer`` of a stacked cache.
 
     q: (B, Hq, D); ck/cv: the WHOLE (L, B, S, Hkv, D) cache, or with
     ``hkv`` its rows (L, B, S * Hkv, D), the row of position ``lens``
     already written; lens: (B,) int32; active: (B,) bool.  Row b attends
     keys ``[0, min(lens[b] + 1, s_active, S))`` if it is active and gives
-    zeros if not.  -> (B, Hq, D) in the cache's dtype.
+    zeros if not; with ``keep`` (B, s_active) bool, of those keys the ones
+    it marks alone (a learned selection, ``models/indexer.py``: the rows are
+    read as far as the row is long, and masked).  -> (B, Hq, D) in the
+    cache's dtype.
 
     On a TPU a cache Mosaic cannot read as rows (kv heads that do not
     fill a sublane tile, a head that is not whole lanes) is attended by
@@ -223,7 +233,8 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     if not interpret and not _tiles(hkv, d, as_rows):
         if as_rows:
             ck, cv = (c.reshape(L, B, S, hkv, d) for c in (ck, cv))
-        return _xla_decode_attention(q, ck, cv, layer, n, s_active, scale)
+        return _xla_decode_attention(q, ck, cv, layer, n, s_active, scale,
+                                     keep)
 
     bk = block_k(S, hkv, d, ck.dtype.itemsize)
     hq_pad = -(-hq // _HEAD_TILE) * _HEAD_TILE
@@ -235,6 +246,15 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     kernel = functools.partial(_kernel, bk=bk, hkv=hkv, s_len=S,
                                scale=scale, align=align)
     whole = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    selection = ()
+    if keep is not None:
+        selection = (_keep_blocks(keep, s_active, S, bk, hkv),)
+        unselected = kernel
+
+        def kernel(layer_ref, n_ref, q_ref, bias_ref, keep_ref, *rest):
+            unselected(layer_ref, n_ref, q_ref, bias_ref, *rest,
+                       keep_ref=keep_ref)
+
     attend = pl.pallas_call(
         kernel,
         name="decode_attention",
@@ -244,6 +264,8 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
             in_specs=[
                 whole((B, hq_pad, d), lambda i, *_: (0, 0, 0)),
                 whole((hq_pad, bk * hkv), lambda i, *_: (0, 0)),
+                *(whole(a.shape, lambda i, *_: (0, 0, 0))
+                  for a in selection),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -265,11 +287,26 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     with jax.named_scope("decode_attention"):
         out = attend(jnp.asarray(layer, jnp.int32).reshape(1), n, q,
                      jnp.asarray(_head_bias(hq_pad, hq, hkv, bk)),
-                     ck.reshape(rows), cv.reshape(rows))
+                     *selection, ck.reshape(rows), cv.reshape(rows))
     return out[:, :hq]
 
 
-def _xla_decode_attention(q, ck, cv, layer, n, s_active, scale):
+def _keep_blocks(keep, s_active: int, s_len: int, bk: int, hkv: int):
+    """``keep`` (B, s_active) bool as what the kernel adds to a block's
+    scores: float32 (B, blocks, bk * Hkv), 0 at a block's rows of a
+    position the row attends and ``NEG_INF`` elsewhere, a block's positions
+    those the kernel fetches for it (the last block of a length ``bk`` does
+    not divide is moved back inside)."""
+    blocks = -(-s_active // bk)
+    first = np.minimum(np.arange(blocks) * bk, s_len - bk)
+    pos = first[:, None] + np.arange(bk)[None, :]            # (blocks, bk)
+    inside = jnp.asarray(pos < s_active)
+    kept = keep[:, np.minimum(pos, s_active - 1)] & inside[None]
+    return jnp.repeat(jnp.where(kept, 0.0, NEG_INF).astype(jnp.float32),
+                      hkv, axis=2)
+
+
+def _xla_decode_attention(q, ck, cv, layer, n, s_active, scale, keep=None):
     from ray_tpu.models.llama import _cache_attend
 
     def prefix(c):
@@ -277,6 +314,9 @@ def _xla_decode_attention(q, ck, cv, layer, n, s_active, scale):
             c, (layer, 0, 0, 0, 0), (1,) + c.shape[1:2] + (s_active,)
             + c.shape[3:])[0]
 
+    selection = () if keep is None else (
+        jnp.broadcast_to(jnp.arange(s_active, dtype=jnp.int32), keep.shape),
+        keep)
     out = _cache_attend(q[:, None], prefix(ck), prefix(cv),
-                        (n - 1)[:, None], scale)[:, 0]
+                        (n - 1)[:, None], scale, *selection)[:, 0]
     return jnp.where((n > 0)[:, None, None], out, jnp.zeros_like(out))

@@ -293,7 +293,7 @@ def _causal_dispatch(compute, causal, should_run, qi, ki,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, block_q, block_k, nk, causal,
-                window=None):
+                window=None, keep_ref=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -329,6 +329,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             s = _causal_mask(s, *mask)
         elif mask is not None:
             s = _band_mask(s, *mask, window)
+        if keep_ref is not None:
+            # A mask that is data, below the diagonal too.  A row whose
+            # keys of a tile are all masked counts NEG_INF - NEG_INF = 0
+            # there; a tile that holds a key it sees scales that away
+            # (alpha = 0) if it comes after, and adds exact zeros to it if
+            # it came before.  Every row sees a key.
+            s = jnp.where(keep_ref[0, r, c].astype(jnp.int32) != 0, s,
+                          NEG_INF)
         m_prev = m_scr[r, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -364,7 +372,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
-         name="flash_attention_fwd", with_lse=True):
+         name="flash_attention_fwd", with_lse=True, keep=None):
     """q: (B, Hq, Sq, D) pre-scaled; k: (B, Hkv, Sk, D); v: (B, Hkv, Sk,
     Dv), a head of its own width where the model's values have one
     (latent attention: 192-wide q/k, 128-wide v).
@@ -372,7 +380,9 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     forward nothing differentiates) as ``_stats_shape`` lays it out:
     (B, Hq, 1, Sq) where the q block is whole lanes, else (B, Hq, Sq, 1);
     ``lse.reshape(B, Hq, Sq)`` is a row's in either.  ``window`` (causal
-    only): a query sees its last ``window`` keys, its own among them."""
+    only): a query sees its last ``window`` keys, its own among them.
+    ``keep`` (causal only): int8 (B, Sq, Sk), nonzero where a query sees a
+    key, read a tile at a time beside K and V; every head shares it."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
     Dv = v.shape[-1]
@@ -400,6 +410,9 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     def o_map(b, h, qi, ki):
         return (b, h, qi, 0)
 
+    def keep_map(b, h, qi, ki):
+        return (b, qi, kv_map(b, h, qi, ki)[2])
+
     kernel = functools.partial(_fwd_kernel, block_q=bq, block_k=bk,
                                nk=nk, causal=causal,
                                **({} if window is None
@@ -412,19 +425,29 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     if not with_lse:
         with_stats = kernel
 
-        def kernel(q_ref, k_ref, v_ref, o_ref, *scratch):
-            with_stats(q_ref, k_ref, v_ref, o_ref, None, *scratch)
+        def kernel(q_ref, k_ref, v_ref, o_ref, *scratch, **kw):
+            with_stats(q_ref, k_ref, v_ref, o_ref, None, *scratch, **kw)
 
         out_specs, out_shape = out_specs[:1], out_shape[:1]
+    in_specs = [
+        pl.BlockSpec((1, 1, bq, D), q_map),
+        pl.BlockSpec((1, 1, bk, D), kv_map),
+        pl.BlockSpec((1, 1, bk, Dv), kv_map),
+    ]
+    operands = (q, k, v)
+    if keep is not None:
+        unmasked = kernel
+
+        def kernel(q_ref, k_ref, v_ref, keep_ref, *rest):
+            unmasked(q_ref, k_ref, v_ref, *rest, keep_ref=keep_ref)
+
+        in_specs.append(pl.BlockSpec((1, bq, bk), keep_map))
+        operands += (keep,)
     fwd = pl.pallas_call(
         kernel,
         name=name,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), q_map),
-            pl.BlockSpec((1, 1, bk, D), kv_map),
-            pl.BlockSpec((1, 1, bk, Dv), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -442,7 +465,7 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     # name is pushed as that); this scope lands in the HLO's ``op_name``
     # metadata only (PERF.md section 3).
     with jax.named_scope("flash_attention.fwd"):
-        o, *lse = fwd(q, k, v)
+        o, *lse = fwd(*operands)
     return o, (lse[0] if lse else None)
 
 
@@ -788,14 +811,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                             scale: float, window: Optional[int] = None,
-                            lse: bool = True) -> jax.Array:
+                            lse: bool = True,
+                            keep: Optional[jax.Array] = None) -> jax.Array:
     """The forward alone, for a serving prefill: causal, a query seeing
     its last ``window`` keys where one is given (tiles outside the band
     are neither fetched nor computed).  q: (B, S, Hq, D); k/v: (B, S,
     Hkv, D), or v of a head width of its own (B, S, Hkv, Dv), positions
     0..S-1; ``scale`` multiplies the scores; ``lse=False`` leaves the
-    softmax statistics, which only a backward pass reads, unwritten.  The
-    device trace shows the kernel under this function's name."""
+    softmax statistics, which only a backward pass reads, unwritten.
+    ``keep``: int8 (B, S, S), nonzero where a query sees a key -- a learned
+    selection (``models/indexer.py``): of the causal keys those alone, the
+    tiles computed dense and masked.  The device trace shows the kernel
+    under this function's name, with ``keep`` as
+    ``sparse_prefill_attention``."""
     B, S, Hq, D = q.shape
     if Hq % k.shape[2]:
         raise ValueError(f"Hq={Hq} not a multiple of Hkv={k.shape[2]}")
@@ -811,8 +839,9 @@ def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     o, _lse = _fwd(qt, jnp.transpose(k, (0, 2, 1, 3)),
                    jnp.transpose(v, (0, 2, 1, 3)), causal=True,
                    block_q=None, block_k=None, interpret=interpret,
-                   window=window, name="flash_prefill_attention",
-                   with_lse=lse)
+                   window=window, with_lse=lse, keep=keep,
+                   name="flash_prefill_attention" if keep is None
+                   else "sparse_prefill_attention")
     return jnp.transpose(o, (0, 2, 1, 3))
 
 

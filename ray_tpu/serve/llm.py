@@ -384,6 +384,15 @@ class LLMServer:
                 f"one latent row a token and layer read by a kernel of "
                 f"its own: no block pool, draft or K/V handoff holds "
                 f"such rows.  Refused: {'; '.join(asked)}")
+        if asked and self.cfg.index_topk:
+            raise ValueError(
+                f"{model_preset} has an indexer, which keeps one index key "
+                f"a token and layer beside K and V and attends the "
+                f"{self.cfg.index_topk} keys it selects: a block table "
+                f"holds no index-key pool, a rejected draft's index keys "
+                f"would have to be rewound with its rows, and no handoff "
+                f"or quantized block carries them.  "
+                f"Refused: {'; '.join(asked)}")
         self.max_slots = max_slots
         self.max_len = max_len
         self.buckets = tuple(sorted(b for b in prefill_buckets
@@ -464,6 +473,11 @@ class LLMServer:
             self._ring = llama_serve.ring_len(self.cfg, max_len)
             self._pool_layers = (self.cfg.layers_of("attention"),
                                  self.cfg.layers_of("window"))
+        # A model with an indexer: the keys a query attends at most (0 for
+        # any other).  A launch counts its rows' positions both ways, as it
+        # does for a ring.
+        self._topk = self.cfg.index_topk
+        self._short_read = self._ring or self._topk
         # A model with latent attention: the bytes a position's latent
         # rows hold over all layers, as stored (0 for any other).
         self._latent_bytes = llama_serve.cache_pools(
@@ -1696,7 +1710,8 @@ class LLMServer:
         info = (len(snapshot), int(self.slot_waiting.sum()),
                 len(self._backlog), int(sa),
                 sum(len0 for _s, _req, len0 in snapshot),
-                sum(min(len0, self._ring) for _s, _req, len0 in snapshot),
+                sum(min(len0, self._short_read)
+                    for _s, _req, len0 in snapshot),
                 # seated this iteration: the host has no token of theirs
                 # yet (a handed-over row has none either, but no prefill)
                 sum(1 for _s, req, _len0 in snapshot
@@ -1857,7 +1872,10 @@ class LLMServer:
         a live request); the cache positions the live rows held at launch
         (what the decode attention has to read) against max_slots x
         s_active (the attended bucket of every slot); for a model with
-        experts, the rows they computed."""
+        experts, the rows they computed.  Behind an indexer the positions
+        ATTENDED are those selected (``min(length, index_topk)`` a row, by
+        the host's arithmetic, as a ring's are) and the positions held are
+        ``kv_positions_present``: each of them is an index key scored."""
         if not _tracing.enabled():
             return
         computed = k * self.max_slots
@@ -1865,6 +1883,11 @@ class LLMServer:
          released_early) = info
         bucket = self.max_slots * s_active
         m = self._engine_metrics
+        selection = {}
+        if self._topk:
+            selection = {"kv_positions_present": attended}
+            m["decode_kv_positions_present"].inc(attended, tags=self._tags)
+            attended = ringed
         m["slots_released_early"].inc(released_early, tags=self._tags)
         m["decode_tokens_kept"].inc(kept, tags=self._tags)
         m["decode_slot_steps"].inc(computed, tags=self._tags)
@@ -1876,7 +1899,7 @@ class LLMServer:
             "backlog": backlog, "s_active": s_active,
             "tokens_kept": kept, "token_steps": computed,
             "kv_positions_attended": attended,
-            "kv_positions_bucket": bucket,
+            "kv_positions_bucket": bucket, **selection,
             **self._window_attrs(attended, ringed, s_active),
             **({"latent_bytes": attended * self._latent_bytes}
                if self._latent_bytes else {}),

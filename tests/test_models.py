@@ -313,20 +313,21 @@ def _forward_refused_before(c):
         or c.rope_scaling is not None or c.first_dense_layers
         or c.moe_held or c.layer_types or c.layers_of("conv")
         or c.qk_head_norm or c.moe_router_score != "softmax"
-        or c.moe_router_bias)
+        or c.moe_router_bias or c.index_topk)       # (an indexer: PR 45)
 
 
 def _cache_refused_before(c):
     """``llama.forward_with_cache``'s, before ``one_kv_stack``."""
     return bool(c.layers_of("mamba") or c.layers_of("window")
-                or c.kv_lora_rank or c.layers_of("conv") or c.layer_types)
+                or c.kv_lora_rank or c.layers_of("conv") or c.layer_types
+                or c.index_topk)                    # (an indexer: PR 45)
 
 
 _PRESETS = ("debug", "moe_debug", "hybrid_debug", "llama_moe_1b",
             "llama_125m", "llama_440m", "llama2_7b", "llama3_8b")
 _BENCH_CONFIGS = ("internlm2-1.8b", "smollm2-360m", "olmoe-1b-7b",
                   "granite-4.0-h-micro", "smallthinker-21b-a3b",
-                  "deepseek-v2", "lfm2-8b-a1b")
+                  "deepseek-v2", "lfm2-8b-a1b", "keye-vl-2.0-30b-a3b")
 _SSM = dict(ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_chunk=8)
 # one config a term of the two old conditions, and four that neither held
 _TERMS = {
@@ -352,6 +353,7 @@ _TERMS = {
     "moe_router_score": dict(moe_experts=4, moe_router_score="sigmoid"),
     "moe_router_bias": dict(moe_experts=4, moe_router_score="sigmoid",
                             moe_router_bias=True),
+    "index_topk": dict(index_heads=2, index_head_dim=8, index_topk=4),
     "residual_multiplier": dict(residual_multiplier=0.22),
     "no_rope": dict(rope=False),
     "qk_norm": dict(qk_norm=True),
