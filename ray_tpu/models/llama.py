@@ -2140,7 +2140,8 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
     Attention is the masked einsum while its (G, Hq, P, P) float32
     scores are small, and the flash forward (``ops/flash_attention.py``,
     scores never leave the chip; a window layer's tiles outside its band
-    are skipped) for prompts longer than ``FLASH_PREFILL_FROM``."""
+    are skipped, and the q blocks that lie wholly past a row's length)
+    for prompts longer than ``FLASH_PREFILL_FROM``."""
     scale = config.attn_scale
     if tokens.shape[1] > FLASH_PREFILL_FROM:  # raylint: disable=recompile-hazard -- the engine's prefill shapes are its buckets, each warmed once; which attention a bucket takes is fixed with its shape
         from ray_tpu.ops.flash_attention import flash_prefill_attention
@@ -2148,10 +2149,13 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
         # A latent model's call writes no softmax statistics: only a
         # backward pass reads ``lse`` (6 MB for 128 heads at 12,288
         # positions since it is lane-dense, 768 MB as a width-1 column).
-        # Nor does the masked call behind an indexer.
+        # Nor does the masked call behind an indexer.  Every call is
+        # told the rows' ``lengths``: a q block wholly in a row's padding
+        # runs no tile and comes back as zeros.
         def attend(q, k, v, positions, window, keep=None):
             return flash_prefill_attention(
                 q, k, v, scale=scale, window=window, keep=keep,
+                lengths=lengths,
                 lse=not (config.kv_lora_rank or config.index_topk))
     else:
         def attend(q, k, v, positions, window, keep=None):

@@ -19,6 +19,15 @@ leave the chip.  Design points:
   ``pl.when`` + index-map redirect (no DMA, no compute).  Of a block the
   diagonal crosses, dq and dk/dv compute the causal strips only
   (``DIAG_STRIP``); the forward computes it whole and masks it.
+- In serving a row is a prompt padded to its bucket, and the forward is
+  told where it ends (``flash_prefill_attention``'s ``lengths``, a scalar
+  prefetch): a q block that starts at or past its row's length is declined
+  the same way -- no tile computed, no K/V block fetched for it -- and
+  leaves as zeros.  The kernel sees whether its caller knows the lengths,
+  nothing else: training's rows are full, its three kernels are built
+  without the operand, and every row before a prompt's end sees the tiles
+  it saw, in their order (``causal_computed_share``'s ``length``: what is
+  left of a bucket's tiles).
 - f32 accumulators in VMEM scratch; running (m, l) kept lane-replicated
   (shape (block_q, 128)) per TPU layout rules.
 - lse is saved for the backward (recompute-based, à la FA-2).  It and
@@ -207,21 +216,35 @@ def _diag_strips(block: int, strip: int, by: str):
     return [((lo, block), (lo, lo + strip)) for lo in range(0, block, strip)]
 
 
+def q_blocks_run(seq: int, length: Optional[int] = None,
+                 block_q: Optional[int] = None):
+    """``(q blocks of a row of seq positions, those of them that run)``
+    in the forward: all, or for a row whose real ``length`` the caller
+    tells the kernel (``flash_prefill_attention``'s ``lengths``) the blocks
+    that start before it -- the rest are declined."""
+    bq, _ = _block_sizes(seq, seq, block_q, None)
+    nq = seq // bq
+    return nq, nq if length is None else min(nq, -(-length // bq))
+
+
 def causal_computed_share(seq: int, block_q: Optional[int] = None,
                           block_k: Optional[int] = None,
-                          strip: Optional[int] = None) -> float:
+                          strip: Optional[int] = None,
+                          length: Optional[int] = None) -> float:
     """Share of the ``seq x seq`` score square that a causal kernel
     computes (a causal mask needs ``(1 + 1/seq) / 2`` of it): whole tiles
     below the diagonal, and of a tile the diagonal crosses its strips of
     width ``strip`` (default: what dq and dk/dv walk, ``_diag_strip``) or,
-    with ``strip`` the tile's own width as in the forward, all of it."""
+    with ``strip`` the tile's own width as in the forward, all of it.
+    ``length``: the row is that long and the kernel knows (the serving
+    forward): of its q blocks those that run (``q_blocks_run``)."""
     bq, bk = _block_sizes(seq, seq, block_q, block_k)
     if strip is None:
         strip = _diag_strip(bq, bk) or bq
     elif bq != bk or bq % strip:
         raise ValueError(f"no strips of {strip} in a {bq} x {bk} tile")
     computed = 0
-    for q0 in range(0, seq, bq):
+    for q0 in range(0, bq * q_blocks_run(seq, length, bq)[1], bq):
         for k0 in range(0, seq, bk):
             if k0 > q0 + bq - 1:            # above the diagonal: skipped
                 continue
@@ -293,7 +316,7 @@ def _causal_dispatch(compute, causal, should_run, qi, ki,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, block_q, block_k, nk, causal,
-                window=None, keep_ref=None):
+                window=None, keep_ref=None, lengths_ref=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -317,6 +340,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # NEG_INF = 0 there; the tile of its own key, which always comes
         # after, scales that away (alpha = 0).
         should_run &= ki * block_k + block_k - 1 > qi * block_q - window
+    if lengths_ref is not None:
+        # Nor any tile of a q block that starts at or past its row's
+        # length: padding, whose output nothing reads.  ``_init`` and
+        # ``_finalize`` fire all the same (``last_k`` knows no length), so
+        # the block leaves as zeros, ``lse`` NEG_INF: an all-masked row's.
+        should_run &= qi * block_q < lengths_ref[pl.program_id(0)]
 
     def _compute(rows, cols, mask):
         r, c = slice(*rows), slice(*cols)
@@ -372,7 +401,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
-         name="flash_attention_fwd", with_lse=True, keep=None):
+         name="flash_attention_fwd", with_lse=True, keep=None, lengths=None):
     """q: (B, Hq, Sq, D) pre-scaled; k: (B, Hkv, Sk, D); v: (B, Hkv, Sk,
     Dv), a head of its own width where the model's values have one
     (latent attention: 192-wide q/k, 128-wide v).
@@ -382,7 +411,11 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     ``lse.reshape(B, Hq, Sq)`` is a row's in either.  ``window`` (causal
     only): a query sees its last ``window`` keys, its own among them.
     ``keep`` (causal only): int8 (B, Sq, Sk), nonzero where a query sees a
-    key, read a tile at a time beside K and V; every head shares it."""
+    key, read a tile at a time beside K and V; every head shares it.
+    ``lengths`` (causal only): int32 (B,), a scalar prefetch; a q block
+    that starts at or past its row's length runs no tile and fetches
+    nothing new, and leaves as zeros (``lse`` NEG_INF).  Without it the
+    call is built as it always was: training's rows are full."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
     Dv = v.shape[-1]
@@ -391,10 +424,21 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     nq, nk = Sq // bq, Sk // bk
     grid = (B, Hq, nq, nk)
 
-    def q_map(b, h, qi, ki):
-        return (b, h, qi, 0)
+    # The index maps take the prefetched ``lengths`` last, where there are
+    # any.  A declined q block (``_fwd_kernel``) fetches nothing new: q, K,
+    # V and ``keep`` stay where the last step of the row's last q block
+    # that runs left them.
+    def running(b, qi, ki, lens):
+        if not lens:
+            return qi, ki
+        last = jnp.maximum((lens[0][b] + bq - 1) // bq - 1, 0)
+        return jnp.minimum(qi, last), jax.lax.select(qi > last, nk - 1, ki)
 
-    def kv_map(b, h, qi, ki):
+    def q_map(b, h, qi, ki, *lens):
+        return (b, h, running(b, qi, ki, lens)[0], 0)
+
+    def kv_map(b, h, qi, ki, *lens):
+        qi, ki = running(b, qi, ki, lens)
         if causal and window is None:
             # Skipped above-diagonal blocks: redirect the prefetch to
             # block 0 (it will be needed for the next q row).
@@ -407,11 +451,12 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
             ki = jnp.clip(ki, first, (bq * qi + bq - 1) // bk)
         return (b, h // group, ki, 0)
 
-    def o_map(b, h, qi, ki):
+    def o_map(b, h, qi, ki, *lens):
         return (b, h, qi, 0)
 
-    def keep_map(b, h, qi, ki):
-        return (b, qi, kv_map(b, h, qi, ki)[2])
+    def keep_map(b, h, qi, ki, *lens):
+        return (b, running(b, qi, ki, lens)[0],
+                kv_map(b, h, qi, ki, *lens)[2])
 
     kernel = functools.partial(_fwd_kernel, block_q=bq, block_k=bk,
                                nk=nk, causal=causal,
@@ -438,23 +483,31 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     if keep is not None:
         unmasked = kernel
 
-        def kernel(q_ref, k_ref, v_ref, keep_ref, *rest):
-            unmasked(q_ref, k_ref, v_ref, *rest, keep_ref=keep_ref)
+        def kernel(q_ref, k_ref, v_ref, keep_ref, *rest, **kw):
+            unmasked(q_ref, k_ref, v_ref, *rest, keep_ref=keep_ref, **kw)
 
         in_specs.append(pl.BlockSpec((1, bq, bk), keep_map))
         operands += (keep,)
+    spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=[
+                    pltpu.VMEM((bq, LANES), jnp.float32),
+                    pltpu.VMEM((bq, LANES), jnp.float32),
+                    pltpu.VMEM((bq, Dv), jnp.float32),
+                ])
+    if lengths is not None:
+        unbounded = kernel
+
+        def kernel(lengths_ref, *rest):
+            unbounded(*rest, lengths_ref=lengths_ref)
+
+        spec = {"grid_spec": pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **spec)}
+        operands = (lengths.astype(jnp.int32), *operands)
     fwd = pl.pallas_call(
         kernel,
         name=name,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        **spec,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, Dv), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
@@ -812,7 +865,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                             scale: float, window: Optional[int] = None,
                             lse: bool = True,
-                            keep: Optional[jax.Array] = None) -> jax.Array:
+                            keep: Optional[jax.Array] = None,
+                            lengths: Optional[jax.Array] = None
+                            ) -> jax.Array:
     """The forward alone, for a serving prefill: causal, a query seeing
     its last ``window`` keys where one is given (tiles outside the band
     are neither fetched nor computed).  q: (B, S, Hq, D); k/v: (B, S,
@@ -821,9 +876,13 @@ def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     softmax statistics, which only a backward pass reads, unwritten.
     ``keep``: int8 (B, S, S), nonzero where a query sees a key -- a learned
     selection (``models/indexer.py``): of the causal keys those alone, the
-    tiles computed dense and masked.  The device trace shows the kernel
-    under this function's name, with ``keep`` as
-    ``sparse_prefill_attention``."""
+    tiles computed dense and masked.  ``lengths``: int32 (B,), the rows'
+    real lengths in a padded bucket: a q block wholly past its row's
+    length is neither fetched nor computed and comes back as zeros; every
+    position before the length is what it is without ``lengths``, bit for
+    bit (positions past it in the block it ends in: computed as before).
+    The device trace shows the kernel under this function's name, with
+    ``keep`` as ``sparse_prefill_attention``."""
     B, S, Hq, D = q.shape
     if Hq % k.shape[2]:
         raise ValueError(f"Hq={Hq} not a multiple of Hkv={k.shape[2]}")
@@ -839,7 +898,7 @@ def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     o, _lse = _fwd(qt, jnp.transpose(k, (0, 2, 1, 3)),
                    jnp.transpose(v, (0, 2, 1, 3)), causal=True,
                    block_q=None, block_k=None, interpret=interpret,
-                   window=window, with_lse=lse, keep=keep,
+                   window=window, with_lse=lse, keep=keep, lengths=lengths,
                    name="flash_prefill_attention" if keep is None
                    else "sparse_prefill_attention")
     return jnp.transpose(o, (0, 2, 1, 3))

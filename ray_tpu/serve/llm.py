@@ -1148,8 +1148,7 @@ class LLMServer:
                 self.cache, first, load = self._prefill(
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(lens), jnp.asarray(slots))
-            self._prefill_launched(first, members, t0, bucket, g,
-                                   int(lens[:len(group)].sum()), load)
+            self._prefill_launched(first, members, t0, bucket, lens, load)
             return
         bs = self.block_size
         nw = -(-bucket // bs)
@@ -1180,8 +1179,7 @@ class LLMServer:
                 self.pool, first, load = self._prefill_cold(
                     self.params, self.pool, jnp.asarray(toks),
                     jnp.asarray(lens), jnp.asarray(write_bt))
-        self._prefill_launched(first, members, t0, bucket, g,
-                               int(lens[:len(group)].sum()), load)
+        self._prefill_launched(first, members, t0, bucket, lens, load, warm)
 
     def _launch_draft_prefill(self, g, bucket, group, jnp):
         toks = np.zeros((g, bucket), np.int32)
@@ -1196,19 +1194,20 @@ class LLMServer:
             self.draft_params, self.draft_cache, jnp.asarray(toks),
             jnp.asarray(lens), jnp.asarray(slots))
 
-    def _prefill_launched(self, first, members, t0, bucket, g, n_tok,
-                          load):
+    def _prefill_launched(self, first, members, t0, bucket, lens, load,
+                          warm=False):
         """After the (async) launch: seat the group's rows, stamp its
-        requests and queue it for _harvest_prefills.  ``n_tok``: prompt
-        positions the group was asked to compute (suffixes only, on a
-        warm group); ``load``: the program's expert load, still on the
-        device."""
+        requests and queue it for _harvest_prefills.  ``lens``: the
+        prompt positions each row of the padded group was asked to
+        compute (suffixes only, on a ``warm`` group; 0 for a padding
+        row); ``load``: the program's expert load, still on the device."""
+        g = len(lens)
         self._seat_group(first, members, g)
         for _j, _slot, req in members:
             req.t_prefill_launched = t0
             req.prefill_shape = (bucket, g)
         self._pending_prefills.append(
-            (first, members, t0, bucket, g, n_tok, load))
+            (first, members, t0, bucket, lens, load, warm))
 
     def _seat_group(self, first, members, g):
         """A just-launched prefill group's rows into the decode carries,
@@ -1238,7 +1237,7 @@ class LLMServer:
         a chunk whose ``_process`` comes an iteration after this, so a
         request's first token still reaches it first; a row that sat out
         (``slot_waiting``) is released to the next launch here."""
-        for first, members, t0, bucket, g, n_tok, load in \
+        for first, members, t0, bucket, lens, load, warm in \
                 self._pending_prefills:
             first = np.asarray(first)
             now = time.perf_counter()
@@ -1267,8 +1266,8 @@ class LLMServer:
                 self.slot_waiting[slot] = False
                 if len(req.tokens) >= req.max_new_tokens:
                     self._finish(slot)
-            self._record_prefill_group(t0, now, bucket, g,
-                                       len(members), n_tok, load)
+            self._record_prefill_group(t0, now, bucket, lens,
+                                       len(members), load, warm)
         self._pending_prefills.clear()
 
     def _extract_kv(self, req: _Request, table) -> None:
@@ -1942,14 +1941,17 @@ class LLMServer:
         return {"state_rows_updated": rows, "state_bytes": total}
 
     def _record_prefill_group(self, t0: float, t1: float, bucket: int,
-                              rows: int, real: int, tokens: int,
-                              load: tuple = ()) -> None:
+                              lens: np.ndarray, real: int,
+                              load: tuple = (), warm: bool = False) -> None:
         """``serve.prefill_group`` (launch -> harvest) and the prefill
-        counters: prompt tokens against the rows x bucket positions the
-        padded group computed; for a model with experts, the rows they
-        computed."""
+        counters: prompt tokens (``lens``, a row of the padded group
+        each) against the rows x bucket positions the group computed;
+        for a model with experts, the rows they computed."""
         if not _tracing.enabled():
             return
+        from ray_tpu.models import llama
+
+        rows, tokens = len(lens), int(lens.sum())
         computed = rows * bucket
         m = self._engine_metrics
         m["prefill_prompt_tokens"].inc(tokens, tags=self._tags)
@@ -1966,6 +1968,15 @@ class LLMServer:
             w = min(self._ring, bucket)
             scan["window_band_share"] = round(
                 w * (2 * bucket - w + 1) / (bucket * bucket), 4)
+        if bucket > llama.FLASH_PREFILL_FROM and not warm:
+            # the flash forward's q blocks, a layer and head, and those of
+            # them that start at or past their row's length: declined
+            from ray_tpu.ops.flash_attention import q_blocks_run
+
+            blocks = [q_blocks_run(bucket, int(n)) for n in lens]
+            scan["flash_q_blocks"] = sum(nq for nq, _ in blocks)
+            scan["flash_q_blocks_declined"] = sum(
+                nq - run for nq, run in blocks)
         self._span("serve.prefill_group", t0, t1, {
             "bucket": bucket, "rows": real, "rows_padded": rows,
             "prompt_tokens": tokens, "token_positions": computed,

@@ -7,7 +7,10 @@ float32, and nothing of ``(B, H, S, 1)`` — which the tiled layout pads
 GQA: dq and dk/dv take K and V per KV head and give dq per q head, dk and
 dv per KV head, in the model's type, so no float32 gradient and no K or V
 expanded to the q heads crosses HBM.  Nothing runs, so nothing here is a
-speed.
+speed.  And the rows' lengths are the serving forward's alone: training's
+three calls take the operands they took (no ``s32`` among them), the
+prefill call at cell 8's and cell 7's shapes takes one ``s32[B]`` more
+and returns what it returned.
 
 The topology is described inside a fixture and the compiles run in the
 test's own process: the TPU library loads once, in the worker that gets
@@ -50,13 +53,13 @@ def compiled_for_the_chip(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", enabled)
 
 
-def _mosaic_calls(hlo):
+def _mosaic_calls(hlo, kernels=r"flash_attention_\w+?"):
     """kernel name -> (result types, operand types) of its custom call."""
     calls = {}
     for line in hlo.splitlines():
         if MOSAIC not in line:
             continue
-        name = re.match(r"\s*(?:ROOT )?%(flash_attention_\w+?)[.\d]* = ",
+        name = re.match(rf"\s*(?:ROOT )?%({kernels})[.\d]* = ",
                         line).group(1)
         result = line.split(" custom-call(")[0]
         operands = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
@@ -94,6 +97,8 @@ def _held_to_the_kernels_own_gqa(hlo, batch, seq, heads, kv_heads, head_dim):
     assert calls["flash_attention_dkdv"][0] == [per_kv, per_kv]
     for results, operands in calls.values():
         assert operands[:3] == [per_q, per_kv, per_kv]           # q, k, v
+        # nor a row's length: training's rows are full
+        assert not [o for o in operands if o.startswith("s32")]
 
 
 def _entry_values(hlo):
@@ -178,3 +183,40 @@ def test_the_smollm2_step_saves_the_kernels_own_operand(
     _held_to_the_kernels_own_gqa(hlo, 8, 2048, 15, 5, 64)
     assert "f32[32,8,15,1,2048]" in hlo          # the saved residual
     assert not re.search(r"f32\[32,8,15,\d+,128\]", hlo)   # nor a packed one
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,dv,options,result", [
+    # deepseek-v2.serve-long-prompt: a group of the expanded latent heads
+    (32, 32, 192, 128, dict(lse=False), ["bf16[1,32,12288,128]"]),
+    # smallthinker-21b-a3b.serve-long-prompt: a window layer, lse written
+    (28, 4, 128, 128, dict(window=4096),
+     ["bf16[1,28,12288,128]", "f32[1,28,1,12288]"]),
+])
+def test_the_prefill_call_takes_the_lengths_and_returns_what_it_returned(
+        topo, compiled_for_the_chip, heads, kv_heads, d, dv, options, result):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.flash_attention import flash_prefill_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct((1, 12288, h, w), jnp.bfloat16,
+                                   sharding=one_chip)
+              for h, w in ((heads, d), (kv_heads, d), (kv_heads, dv))]
+    lengths = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    qkv = [f"bf16[1,{h},12288,{w}]"
+           for h, w in ((heads, d), (kv_heads, d), (kv_heads, dv))]
+
+    def call(q, k, v, lengths=None):
+        return flash_prefill_attention(q, k, v, scale=d ** -0.5,
+                                       lengths=lengths, **options)
+
+    untold = _mosaic_calls(jax.jit(call).lower(*shapes).compile().as_text(),
+                           "flash_prefill_attention")
+    told = _mosaic_calls(
+        jax.jit(call).lower(*shapes, lengths).compile().as_text(),
+        "flash_prefill_attention")
+    assert untold == {"flash_prefill_attention": (result, qkv)}
+    assert told == {"flash_prefill_attention": (result, ["s32[1]"] + qkv)}
+

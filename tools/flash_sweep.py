@@ -23,6 +23,19 @@ results need, which computes nothing.  It touches the public function
 alone, so this file copied into an older checkout reads that one the same
 way.
 With no argument both tables are printed, this one first.
+
+    chiprun -- python3 -m tools.flash_sweep prefill
+
+alone times the forward as SERVING calls it (``flash_prefill_attention``,
+one row in a bucket) at the three long-prompt cells' shapes: cell 8's
+expanded latent head group (32 heads, q/k 192, v 128, no ``lse``), cell
+7's window and full layers (28 / 4 x 128, a band of 4,096 or none, with
+``lse``) and cell 10's masked call (32 / 4 x 128, ``keep`` with the 2,048
+most recent keys, no ``lse``); per bucket a few prompt lengths, the device
+ms a call without ``lengths`` (every causal tile of the bucket) and with
+them (q blocks wholly past the prompt's end declined), and beside them the
+share of the bucket's causal tiles that ``causal_computed_share`` says the
+told kernel computes: the ms should follow it.
 """
 
 import importlib
@@ -41,14 +54,19 @@ SHAPES = [(8, 2048, 15, 5, 64), (1, 4096, 16, 8, 128)]  # B, S, Hq, Hkv, D
 KERNELS = tuple(flash_names.KERNEL_OPS)                  # fwd, dq, dkdv
 
 
-def kernel_ms(fn, args, calls=10):
-    """Device ms a call of each kernel inside ``fn`` and, under
-    ``"whole"``, of all of ``fn`` (the time an op ran), from a trace."""
+def _traced(fn, args, calls):
+    """The device trace of ``calls`` calls of ``fn``, warmed first."""
     jax.block_until_ready(fn(*args))            # compile, warm
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
             jax.block_until_ready([fn(*args) for _ in range(calls)])
-        trace = trace_reduce.read(trace_dir)
+        return trace_reduce.read(trace_dir)
+
+
+def kernel_ms(fn, args, calls=10):
+    """Device ms a call of each kernel inside ``fn`` and, under
+    ``"whole"``, of all of ``fn`` (the time an op ran), from a trace."""
+    trace = _traced(fn, args, calls)
     ms = {kernel: 1e3 * flash_names.kernel_seconds(trace, kernel) / calls
           for kernel in KERNELS}
     return {**ms, "whole": 1e3 * trace.busy_s / calls}
@@ -146,9 +164,60 @@ def _sweep(blocks, strips):
                       f"{elems / seconds / 1e9:.1f}", flush=True)
 
 
+PREFILL_KERNEL = r"^%(flash|sparse)_prefill_attention(\.\w+)* = "
+# cell, Hq, Hkv, D, Dv, flash_prefill_attention's options
+PREFILL_SHAPES = [
+    ("8", 32, 32, 192, 128, dict(lse=False)),
+    ("7.window", 28, 4, 128, 128, dict(window=4096)),
+    ("7.full", 28, 4, 128, 128, dict()),
+    ("10", 32, 4, 128, 128, dict(lse=False, keep=2048)),
+]
+# bucket -> prompt lengths: just past the bucket below, the traffic's
+# middle of the bucket, the bucket's end
+PREFILL_LENGTHS = {4096: (2100, 3300, 4096), 8192: (4200, 6144, 8192),
+                   12288: (8300, 10000, 12288)}
+
+
+def prefill_ms(fn, args, calls=5):
+    trace = _traced(fn, args, calls)
+    return 1e3 * trace.seconds_matching(PREFILL_KERNEL) / calls
+
+
+def prefill_sweep():
+    print("cell Hq Hkv D Dv bucket length untold_ms told_ms told/untold "
+          "tiles_told/untold")
+    share = fa.causal_computed_share
+    for cell, Hq, Hkv, D, Dv, options in PREFILL_SHAPES:
+        for S, lengths in PREFILL_LENGTHS.items():
+            q, k, v, _ = _inputs(S, (1, S, Hq, D), (1, S, Hkv, D))
+            v = v[..., :Dv]
+            kw = dict(options, scale=D ** -0.5)
+            if "keep" in kw:        # a selection's size: the most recent
+                at = jnp.arange(S)
+                behind = at[:, None] - at[None, :]
+                kw["keep"] = ((behind >= 0) & (behind < kw["keep"])
+                              ).astype(jnp.int8)[None]
+            untold = prefill_ms(jax.jit(
+                lambda q, k, v: fa.flash_prefill_attention(q, k, v, **kw)),
+                (q, k, v))
+            told = jax.jit(lambda q, k, v, n: fa.flash_prefill_attention(
+                q, k, v, lengths=n, **kw))
+            for n in lengths:
+                ms = prefill_ms(told, (q, k, v, jnp.asarray([n], jnp.int32)))
+                block = fa._block_sizes(S, S, None, None)[0]
+                tiles = (share(S, strip=block, length=n)
+                         / share(S, strip=block))
+                print(cell, Hq, Hkv, D, Dv, S, n, f"{untold:.3f}",
+                      f"{ms:.3f}", f"{ms / untold:.3f}", f"{tiles:.3f}",
+                      flush=True)
+
+
 if __name__ == "__main__":
     if jax.default_backend() != "tpu":
         raise SystemExit("flash_sweep times the compiled kernels: tpu only")
+    if sys.argv[1:] == ["prefill"]:
+        prefill_sweep()
+        raise SystemExit
     grad_sweep()
     if sys.argv[1:] != ["grad"]:
         sweep()
