@@ -32,8 +32,11 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.sharding import with_logical_constraint
+from ray_tpu.parallel.sharding import (axes_entry, current_rules,
+                                       partitioning_mesh,
+                                       with_logical_constraint)
 
 PyTree = Any
 LAYER_KINDS = ("attention", "mamba", "window", "conv")
@@ -1621,12 +1624,96 @@ def embed_sharded(params: PyTree, tokens, config: LlamaConfig):
         return with_logical_constraint(emb[tokens], "batch", "seq", None)
 
 
+def head_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``matmul(x, w)`` of training's head, x (B, S, D) by w (D, V), whose
+    backward reduces the weight's gradient scattered under a mesh that
+    shards w's rows: each device receives only the rows it keeps, and
+    receives them while the head's own backward computes.
+
+    Left to itself XLA sums this one gradient WHOLE on every device, an
+    all-reduce of (D, V) that runs alone: the slice down to a device's
+    rows is fused into the optimizer's reductions before the pass that
+    pairs an all-reduce with its slice runs, and a ``psum_scatter`` is
+    lowered back to the same pair or, scattered over the minor dimension,
+    to a reduce-scatter that runs alone as well (PERF.md section 6,
+    PR 47).  The one collective XLA's TPU backend leaves in flight beside
+    compute is the collective-permute.  So every device forms its partial
+    product in n blocks of rows, one per device of the mesh axes that
+    shard both w's rows and the batch, sends each other device its block,
+    forms its own block while those travel, and adds what arrives: the
+    same float32 partial products summed in float32, cast once after the
+    sum, as the all-reduce had them.  The exchange is awaited where the
+    input's gradient is, before the layers' backward starts, so that it
+    travels under the head's own matmuls while the links are idle.
+
+    Where there is nothing to scatter (no mesh, one device, a manual
+    region, rows that the batch's axes do not shard) this is ``matmul``
+    and its own backward."""
+    mesh = partitioning_mesh()
+    if mesh is None:
+        return matmul(x, w)
+    rules = current_rules()
+    batch, seq, vocab = rules.axes(("batch", "seq", "vocab"))
+    summed = tuple(a for a in batch + seq if mesh.shape[a] > 1)
+    scattered = tuple(a for a in rules.axes(("embed", "vocab"))[0]
+                      if a in summed)
+    n = math.prod(mesh.shape[a] for a in scattered)
+    if n == 1 or w.shape[0] % n:
+        return matmul(x, w)
+    whole = tuple(a for a in summed if a not in scattered)
+    width = w.shape[0] // n
+
+    def scattered_sum(x, g):
+        """This device's rows of the devices' summed ``x^T g``."""
+        me = jax.lax.axis_index(scattered)
+
+        def block(device):
+            """This device's partial product for the rows ``device`` keeps."""
+            rows = jax.lax.dynamic_slice_in_dim(x, device * width, width, 2)
+            return jax.lax.dot_general(rows, g, (((0, 1), (0, 1)), ((), ())),
+                                       preferred_element_type=jnp.float32)
+
+        arriving = [
+            jax.lax.ppermute(block((me + hop) % n), scattered,
+                             [(d, (d + hop) % n) for d in range(n)])
+            for hop in range(1, n)]
+        # The barrier keeps this device's own block out of the fusion that
+        # adds the arrivals up, which would compute it after the wait.
+        total, arriving = jax.lax.optimization_barrier((block(me), arriving))
+        for part in arriving:
+            total = total + part
+        if whole:
+            total = jax.lax.psum(total, whole)
+        return total.astype(w.dtype)
+
+    @jax.custom_vjp
+    def head(x, w):
+        return matmul(x, w)
+
+    def backward(saved, g):
+        x, w = saved
+        dx = jax.lax.dot_general(g, w, (((g.ndim - 1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dw = jax.shard_map(
+            scattered_sum, mesh=mesh,
+            in_specs=(rules.spec(("batch", "seq", None)),
+                      rules.spec(("batch", "seq", "vocab"))),
+            out_specs=P(axes_entry(scattered), axes_entry(vocab)),
+            check_vma=False)(x, g)
+        # Awaited together: without it the scheduler starts the exchange
+        # after the layers' backward, beside the embedding's reduce-scatter.
+        return jax.lax.optimization_barrier((dx.astype(x.dtype), dw))
+
+    head.defvjp(lambda x, w: (matmul(x, w), (x, w)), backward)
+    return head(x, w)
+
+
 def head_loss_logits(x, params: PyTree, config: LlamaConfig):
     """Training's final norm and head, in the scope ``loss_fn`` goes on in."""
     with jax.named_scope("head_loss"):
         x = rms_norm(x, params["final_norm"], config.norm_eps)
         return with_logical_constraint(
-            matmul(x, lm_head(params, config)), "batch", "seq", "vocab")
+            head_matmul(x, lm_head(params, config)), "batch", "seq", "vocab")
 
 
 def train_block(config: LlamaConfig, sin, cos, positions) -> Callable:

@@ -24,6 +24,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Rule = Tuple[str, Union[str, Tuple[str, ...], None]]
 
 
+def axes_entry(axes: Sequence[str]):
+    """Mesh axes of one dim as a PartitionSpec's entry."""
+    return None if not axes else axes[0] if len(axes) == 1 else tuple(axes)
+
+
 class ShardingRules:
     """Ordered logical-axis → mesh-axis mapping."""
 
@@ -35,29 +40,28 @@ class ShardingRules:
             return None
         return self._table.get(logical)
 
-    def spec(self, logical_axes: Sequence[Optional[str]]) -> P:
-        """PartitionSpec for a tuple of per-dim logical names.
+    def axes(self, logical_axes: Sequence[Optional[str]]
+             ) -> Tuple[Tuple[str, ...], ...]:
+        """Per dim of an array with these logical names, the mesh axes it
+        is sharded over (``()``: replicated).
 
         A mesh axis may appear at most once across the dims of one
         array; later duplicates fall back to replication.
         """
         used = set()
-        parts = []
+        out = []
         for name in logical_axes:
-            axes = self.mesh_axes(name)
-            if axes is None:
-                parts.append(None)
-                continue
+            axes = self.mesh_axes(name) or ()
             if isinstance(axes, str):
                 axes = (axes,)
             axes = tuple(a for a in axes if a not in used)
             used.update(axes)
-            if not axes:
-                parts.append(None)
-            elif len(axes) == 1:
-                parts.append(axes[0])
-            else:
-                parts.append(axes)
+            out.append(axes)
+        return tuple(out)
+
+    def spec(self, logical_axes: Sequence[Optional[str]]) -> P:
+        """PartitionSpec for a tuple of per-dim logical names."""
+        parts = [axes_entry(axes) for axes in self.axes(logical_axes)]
         while parts and parts[-1] is None:
             parts.pop()
         return P(*parts)
@@ -152,6 +156,17 @@ def suppress_constraints():
         _ctx.suppress = prev
 
 
+def partitioning_mesh() -> Optional[Mesh]:
+    """The mesh the SPMD partitioner lays the traced code out over: the
+    active one, or None where there is nothing to lay out -- no mesh, one
+    device, or a manual region (suppress_constraints: the enclosing
+    shard_map's specs own the layout)."""
+    mesh = _ctx.mesh
+    if mesh is None or mesh.size == 1 or getattr(_ctx, "suppress", False):
+        return None
+    return mesh
+
+
 def with_logical_constraint(x, *logical_axes: Optional[str],
                             rules: Optional[ShardingRules] = None):
     """``lax.with_sharding_constraint`` by logical axis names.
@@ -160,8 +175,8 @@ def with_logical_constraint(x, *logical_axes: Optional[str],
     single device (tests, single-chip bench), and under
     suppress_constraints() (inside shard_map bodies).
     """
-    mesh = _ctx.mesh
-    if mesh is None or mesh.size == 1 or getattr(_ctx, "suppress", False):
+    mesh = partitioning_mesh()
+    if mesh is None:
         return x
     rules = rules or _ctx.rules
     spec = rules.spec(logical_axes)
@@ -179,8 +194,8 @@ def shard_over_mesh(fn, in_axes: Sequence[Sequence[Optional[str]]],
     ``pallas_call`` inside jit on sharded operands is handed the GLOBAL
     arrays, so every device gathers all of them and does all the
     work."""
-    mesh = _ctx.mesh
-    if mesh is None or mesh.size == 1 or getattr(_ctx, "suppress", False):
+    mesh = partitioning_mesh()
+    if mesh is None:
         return fn
     rules = _ctx.rules
     return jax.shard_map(
