@@ -39,7 +39,8 @@ from ray_tpu.parallel.sharding import (axes_entry, current_rules,
                                        with_logical_constraint)
 
 PyTree = Any
-LAYER_KINDS = ("attention", "mamba", "window", "conv")
+LAYER_KINDS = ("attention", "mamba", "window", "conv", "mamba1", "gmu",
+               "cross")
 # Leaves that stay float32 whatever type the weights are served in.
 FLOAT32_LEAVES = ("router_bias",)
 # The deviation a router's selection bias is drawn with: of the order of
@@ -49,18 +50,35 @@ FLOAT32_LEAVES = ("router_bias",)
 # there).  A zero bias would leave the mechanism idle.
 ROUTER_BIAS_STD = 0.02
 # The kinds whose mixer is attention: they share ``ATTENTION_LEAVES``,
-# stacked over all of them in their order.
-ATTENDING_KINDS = ("attention", "window")
+# stacked over all of them in their order.  A ``cross`` layer has a query
+# and an output projection alone and attends the rows of the K/V layer
+# before it (``LlamaConfig.kv_layer``): it holds no rows of its own.
+ATTENDING_KINDS = ("attention", "window", "cross")
+# The deviation a bias (a LayerNorm's, an attention projection's) is drawn
+# with: a zero bias would leave the term idle under the comparison with
+# the reference.  And that of a differential attention's lambda vectors.
+BIAS_STD = 0.02
+LAMBDA_STD = 0.1
 
 
-def _runs(kinds):
+def _runs(kinds, cuts=()):
     """A list of layer kinds cut into runs of whole periods: ``[(first
     index, one period's kinds, periods)]``.  From the front, the period
     that repeats (twice or more) over the most layers, the shorter of two
     that cover the same; a stretch in which nothing repeats is one period
     of its own.  LFM2's 22 expert layers (A C C C) x 4, (A C C) x 2 are
-    two runs; their first 12 one."""
-    kinds, out, at = tuple(kinds), [], 0
+    two runs; their first 12 one.  No run crosses one of ``cuts`` (a
+    decoder-hybrid-decoder's K/V layer is a run of its own: its prefill
+    stops there)."""
+    kinds = tuple(kinds)
+    cut = next((c for c in sorted(cuts) if 0 < c < len(kinds)), None)
+    if cut is not None:
+        return _runs(kinds[:cut]) + [
+            (cut + at, period, m)
+            for at, period, m in _runs(kinds[cut:],
+                                       [c - cut for c in cuts])]
+
+    out, at = [], 0
     while at < len(kinds):
         rest = kinds[at:]
         best = (len(rest), 1)                   # nothing repeats
@@ -248,6 +266,35 @@ class LlamaConfig:
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # A DECODER-HYBRID-DECODER (SambaY, arXiv:2507.06607; served only,
+    # dense plane), spelled by ``layer_types``: "mamba1" (a Mamba-1 mixer,
+    # models/mamba1.py: ``ssm_inner`` channels of ``ssm_state`` state
+    # dimensions each, ``dt`` of rank ``ssm_dt_rank``; ssm_conv, ssm_chunk
+    # and ssm_state_dtype as for Mamba-2), "gmu" (a gated memory unit: no
+    # state, no token mixing; it gates the scan output of the nearest
+    # Mamba-1 layer before it, which rides the walk's carry) and "cross" (a
+    # query and an output projection alone: it attends the rows of the K/V
+    # layer, the last "attention" layer before the first cross layer, and
+    # keeps none).  From the K/V layer on, only gmu and cross layers
+    # follow, so a prefill runs those layers at each row's last position
+    # alone (``layer_walk``).
+    ssm_inner: int = 0
+    ssm_dt_rank: int = 0
+    # DIFFERENTIAL attention (arXiv:2410.05258) in every attending layer:
+    # query and key heads come in pairs, a pair of value heads is one value
+    # of 2 x head_dim, two softmaxes are subtracted and the difference is
+    # normed (``diff_combine``).  K and V are then kept two heads a row
+    # (``kv_row_heads`` rows of ``kv_row_dim``), queries padded with zeros
+    # to that width on the side of their own key, so that every attention
+    # here computes a pair's two softmaxes as grouped-query heads.
+    diff_attention: bool = False
+    # LayerNorm (mean removed, a weight and a bias) at every norm in place
+    # of RMSNorm, and biases on the attention projections.
+    layer_norm: bool = False
+    attn_bias: bool = False
+    # A part's first layer's index among all (``parts`` sets it): what a
+    # differential layer's lambda_init is a function of.
+    layer_offset: int = 0
 
     def __post_init__(self):
         # a configuration file's lists and type names, made hashable
@@ -287,11 +334,21 @@ class LlamaConfig:
             raise ValueError("a conv layer needs conv_taps of 2 or more")
         if "window" in kinds and self.window_size < 1:
             raise ValueError("a window layer needs window_size")
-        if len(kinds & {"window", "mamba", "conv"}) > 1:
+        if "mamba1" in kinds and min(self.ssm_inner, self.ssm_dt_rank) < 1:
+            raise ValueError("a mamba1 layer needs ssm_inner and "
+                             "ssm_dt_rank")
+        if len(kinds & {"mamba", "mamba1", "conv"}) > 1:
             raise ValueError(
-                "no serving cache holds window rings, recurrent states "
-                "and conv states beside each other: window, mamba and "
-                "conv layers do not mix")
+                "mamba, mamba1 and conv layers keep their states under "
+                "the same leaves of the serving cache (ssm, conv): they "
+                "do not mix, one kind of state-keeping layer a model")
+        if "window" in kinds and kinds & {"mamba", "conv"}:
+            raise ValueError(
+                "window rings beside a recurrent or conv state are built "
+                "and held to a reference for Mamba-1 layers alone (a "
+                "decoder-hybrid-decoder): window and mamba or conv layers "
+                "do not mix")
+        self._check_cross_decoder(kinds)
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm is over the whole projection, "
                              "qk_head_norm over a head: choose one")
@@ -307,6 +364,49 @@ class LlamaConfig:
             raise ValueError(f"moe_router_input {self.moe_router_input!r}")
         if self.moe_activation not in ("silu", "relu"):
             raise ValueError(f"moe_activation {self.moe_activation!r}")
+
+    def _check_cross_decoder(self, kinds):
+        """Refuse a cross or gmu layer with nothing before it to read, and
+        what no prefill here computes of a decoder-hybrid-decoder."""
+        if self.diff_attention and (
+                self.kv_lora_rank or self.index_topk or self.qk_norm
+                or self.qk_head_norm or self.rope or self.n_kv_heads % 2
+                or self.n_heads % (2 * self.n_kv_heads)):
+            raise ValueError(
+                "differential attention is built for plain NoPE heads in "
+                "pairs (rope=False, an even n_kv_heads, n_heads a multiple "
+                "of 2 x n_kv_heads), without q/k norms, latent attention "
+                "or an indexer")
+        if (self.attn_bias or "cross" in kinds) and not self.diff_attention:
+            raise ValueError("biased attention projections and cross "
+                             "layers are built for differential attention "
+                             "alone (diff_attention)")
+        if not kinds & {"gmu", "cross"}:
+            return
+        if not self.layer_types:
+            # (a part's own config: the whole model's list was checked)
+            return
+        types, kv = self.layer_types, self.kv_layer
+        if kv is None:
+            raise ValueError(
+                "a cross layer attends the rows of an attention layer "
+                "before it, and a gmu layer belongs to such a cross-"
+                "decoder: there is no attention layer before a cross layer")
+        if "gmu" in types and ("mamba1" not in types[:kv]
+                               or "gmu" in types[:kv]):
+            raise ValueError("a gmu layer gates the scan output of a "
+                             "mamba1 layer of the self-decoder, before the "
+                             "K/V layer: there is none, or the gmu layer "
+                             "lies before it too")
+        tail = set(types[kv + 1:]) - {"gmu", "cross"}
+        if tail:
+            raise ValueError(
+                f"after the K/V layer only gmu and cross layers follow: a "
+                f"prefill runs them at each row's last position alone, so "
+                f"{sorted(tail)} there would miss every other position")
+        if self.first_dense_layers or self.moe_experts:
+            raise ValueError("a decoder-hybrid-decoder with experts or "
+                             "leading dense layers is not built")
 
     def _check_latent_and_share(self):
         """Refuse what no program here computes of latent attention,
@@ -396,9 +496,7 @@ class LlamaConfig:
         if not self.layer_types:
             # one kind of layer behind leading dense ones of the same kind
             return layer
-        group = _leaf_group(kind)
-        return sum(_leaf_group(k) == group
-                   for k in self.layer_types[:layer])
+        return self.layer_types[:layer].count(kind)
 
     def parts(self):
         """The layer stacks a walk runs in turn, ``(config of the part,
@@ -421,13 +519,42 @@ class LlamaConfig:
         out = []
         for key, first, stack, fields in (("dense_layers", 0, kinds[:k], dense),
                                           ("layers", k, kinds[k:], {})):
-            for i, (start, pattern, periods) in enumerate(_runs(stack)):
+            cuts = () if self.kv_layer is None else tuple(
+                self.kv_layer - first + i for i in (0, 1))
+            for i, (start, pattern, periods) in enumerate(
+                    _runs(stack, cuts)):
+                if self.diff_attention:
+                    fields = {**fields, "layer_offset": first + start}
                 out.append((dataclasses.replace(
                     self, n_layers=len(pattern) * periods, layer_types=(),
                     layer_pattern=() if pattern == ("attention",)
                     else pattern, first_dense_layers=0, **fields),
                     key if i == 0 else f"{key}_{i}", first + start))
         return out
+
+    @property
+    def kv_layer(self) -> Optional[int]:
+        """The index of a decoder-hybrid-decoder's K/V layer: the last
+        attention layer before the first cross layer, whose rows the
+        cross layers attend.  None for every other model (and for a part's
+        own config: the walks ask the whole model's)."""
+        if "cross" not in self.layer_types:
+            return None
+        before = self.layer_types[:self.layer_types.index("cross")]
+        return next((i for i in reversed(range(len(before)))
+                     if before[i] == "attention"), None)
+
+    @property
+    def kv_row_heads(self) -> int:
+        """K (or V) rows a position keeps a layer, as stored and attended:
+        the kv heads, or under differential attention the PAIRS."""
+        return self.n_kv_heads // 2 if self.diff_attention \
+            else self.n_kv_heads
+
+    @property
+    def kv_row_dim(self) -> int:
+        """Their width: the head, or a pair's two heads side by side."""
+        return 2 * self.head_dim if self.diff_attention else self.head_dim
 
     @property
     def expert_width(self) -> int:
@@ -472,6 +599,7 @@ class LlamaConfig:
         attends all of them (what ``forward_with_cache`` holds): no other
         kind, list, latent cache or indexer."""
         return not (self.layer_types or self.kv_lora_rank or self.index_topk
+                    or self.diff_attention
                     or any(self.layers_of(kind) for kind in LAYER_KINDS
                            if kind != "attention"))
 
@@ -488,7 +616,8 @@ class LlamaConfig:
             or self.nope_kinds or self.moe_router_input != "ffn"
             or self.rope_scaling is not None or self.first_dense_layers
             or self.moe_held or self.qk_head_norm
-            or self.moe_router_score != "softmax" or self.moe_router_bias)
+            or self.moe_router_score != "softmax" or self.moe_router_bias
+            or self.layer_norm or self.attn_bias)
 
     @property
     def attn_scale(self) -> float:
@@ -667,12 +796,41 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         from ray_tpu.models import shortconv
 
         axes["layers"].update(shortconv.param_axes(config))
-    if not config.attending_layers():
-        for name in ATTENTION_LEAVES:
-            axes["layers"].pop(name, None)
+    if config.layers_of("mamba1"):
+        from ray_tpu.models import mamba1
+
+        axes["layers"].update(mamba1.param_axes(config))
+    if config.layers_of("gmu"):
+        axes["layers"].update(gmu_in=("layers", "embed", "mlp"),
+                              gmu_out=("layers", "mlp", "embed"))
+    if config.attn_bias:
+        axes["layers"].update(bq=("layers", "heads"),
+                              bk=("layers", "kv_heads"),
+                              bv=("layers", "kv_heads"), bo=("layers", None))
+    if config.diff_attention:
+        axes["layers"].update({name: ("layers", None)
+                               for name in DIFF_LEAVES})
+    if config.layer_norm:
+        axes["layers"].update(attn_norm_bias=("layers", None),
+                              mlp_norm_bias=("layers", None))
+        axes["final_norm_bias"] = (None,)
+    for name in _absent_attention_leaves(config):
+        axes["layers"].pop(name, None)
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
+
+
+def _absent_attention_leaves(config: LlamaConfig):
+    """The ``ATTENTION_LEAVES`` a plain stack does not hold: all of them
+    where no layer attends (a scan slices every leaf of its stack), the
+    key and value projections where every attending layer is a cross
+    layer."""
+    if not config.attending_layers():
+        return ATTENTION_LEAVES
+    if config.layers_of("cross") == config.attending_layers():
+        return ("wk", "wv", "bk", "bv")
+    return ()
 
 
 def init_dense(key, shape, fan_in, dtype=jnp.float32):
@@ -793,11 +951,43 @@ def init_params(rng: jax.Array, config: LlamaConfig,
 
         params["layers"].update(shortconv.init_params(
             jax.random.fold_in(rng, 93), c, c.layers_of("conv"), dense))
-    if not La:
-        # a stack without an attending layer has no attention leaves: a
-        # scan slices every leaf of its stack
-        for name in ATTENTION_LEAVES:
-            params["layers"].pop(name, None)
+    if c.layers_of("mamba1"):
+        from ray_tpu.models import mamba1
+
+        params["layers"].update(mamba1.init_params(
+            jax.random.fold_in(rng, 91), c, c.layers_of("mamba1"), dtype,
+            dense))
+    if c.layers_of("gmu"):
+        Lg, kg = c.layers_of("gmu"), jax.random.split(
+            jax.random.fold_in(rng, 90), 2)
+        params["layers"].update(
+            gmu_in=dense(kg[0], (Lg, c.hidden_size, c.ssm_inner),
+                         c.hidden_size),
+            gmu_out=dense(kg[1], (Lg, c.ssm_inner, c.hidden_size),
+                          c.ssm_inner))
+
+    def drawn(key, shape, std):
+        return (std * jax.random.normal(jax.random.fold_in(rng, key), shape,
+                                        jnp.float32)).astype(dtype)
+
+    if c.attn_bias:
+        params["layers"].update(
+            bq=drawn(80, (La, c.q_dim), BIAS_STD),
+            bk=drawn(81, (La, c.kv_dim), BIAS_STD),
+            bv=drawn(82, (La, c.kv_dim), BIAS_STD),
+            bo=drawn(83, (La, c.hidden_size), BIAS_STD))
+    if c.diff_attention:
+        params["layers"].update({
+            name: drawn(84 + i, (La, c.head_dim), LAMBDA_STD)
+            for i, name in enumerate(DIFF_LEAVES[:4])})
+        params["layers"]["sub_norm"] = jnp.ones((La, 2 * c.head_dim), dtype)
+    if c.layer_norm:
+        params["layers"].update(
+            attn_norm_bias=drawn(88, (L, c.hidden_size), BIAS_STD),
+            mlp_norm_bias=drawn(89, (L, c.hidden_size), BIAS_STD))
+        params["final_norm_bias"] = drawn(79, (c.hidden_size,), BIAS_STD)
+    for name in _absent_attention_leaves(c):
+        params["layers"].pop(name, None)
     if not c.tie_embeddings:
         params["lm_head"] = dense(
             jax.random.fold_in(rng, 99), (c.hidden_size, c.vocab_size),
@@ -883,6 +1073,27 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     x = x * jax.lax.rsqrt(var + eps)
     return (x * scale.astype(jnp.float32)).astype(dtype)
+
+
+def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
+               eps: float) -> jax.Array:
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                          + eps)
+    return (x * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dtype)
+
+
+def norm(x: jax.Array, leaves: Dict[str, jax.Array], name: str,
+         config: LlamaConfig) -> jax.Array:
+    """The norm ``name`` of a layer's (or the model's) leaves: RMSNorm, or
+    LayerNorm with its bias ``<name>_bias`` (``LlamaConfig.layer_norm``)."""
+    if config.layer_norm:
+        return layer_norm(x, leaves[name], leaves[name + "_bias"],
+                          config.norm_eps)
+    return rms_norm(x, leaves[name], config.norm_eps)
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -1021,7 +1232,9 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
     c = config
     B, S, _ = x.shape
     dt = c.dtype
-    h = rms_norm(x, layer["attn_norm"], c.norm_eps).astype(dt)
+    h = norm(x, layer, "attn_norm", c).astype(dt)
+    if c.diff_attention:
+        return _paired_rows(h, layer, c, kind)
     q = matmul(h, layer["wq"].astype(dt))
     k = matmul(h, layer["wk"].astype(dt))
     if c.qk_norm:
@@ -1044,6 +1257,66 @@ def _qkv_rope(x: jax.Array, layer: Dict[str, jax.Array], sin, cos,
 
         return q, k, v, indexer.project(h, layer, c)
     return q, k, v
+
+
+def _paired_rows(h: jax.Array, layer: Dict[str, jax.Array],
+                 config: LlamaConfig, kind: str):
+    """``_qkv_rope`` under differential attention (NoPE, biased
+    projections where the config has them), K and V two heads a row: a
+    pair's keys ``[k_2g | k_2g+1]`` side by side and its ONE value, (B, S,
+    kv_row_heads, 2 D) each, as the projection lays them.  Queries (B, S,
+    H, D) come as wide as such a row, (B, S, H, 2 D): an even head's
+    values on the side of its pair's first key, an odd head's on the side
+    of the second, zeros on the other -- ``q . [k_2g | k_2g+1]`` is then
+    the head's own score, and the 2 H / n_kv_heads query heads that share a
+    row are a grouped-query group of it: every attention here computes a
+    pair's two softmaxes as plain grouped-query heads (``diff_combine``
+    subtracts them).  A cross layer has a query alone."""
+    c, dt = config, config.dtype
+    B, S, _ = h.shape
+
+    def project(w, b):
+        out = matmul(h, layer[w].astype(dt))
+        return out + layer[b].astype(dt) if c.attn_bias else out
+
+    q = project("wq", "bq").reshape(B, S, c.n_heads // 2, 2, c.head_dim)
+    zeros = jnp.zeros_like(q[..., 0, :])
+    q = jnp.stack(
+        [jnp.concatenate([q[..., 0, :], zeros], -1),
+         jnp.concatenate([zeros, q[..., 1, :]], -1)], axis=3
+    ).reshape(B, S, c.n_heads, c.kv_row_dim)
+    if kind == "cross":
+        return q, None, None
+    rows = (B, S, c.kv_row_heads, c.kv_row_dim)
+    return (q, project("wk", "bk").reshape(rows),
+            project("wv", "bv").reshape(rows))
+
+
+@jax.named_scope("diff_combine")
+def diff_combine(attn: jax.Array, layer: Dict[str, jax.Array], depth,
+                 config: LlamaConfig) -> jax.Array:
+    """Differential attention's combination, the one function prefill and
+    decode share: attn (B, S, H, 2 D), head 2j a pair's first softmax over
+    its 2 D-wide value and head 2j + 1 its second ->
+
+        a_j = attn_2j - lambda attn_2j+1
+        lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+        lambda_init = 0.8 - 0.6 exp(-0.3 depth)
+        o_j = RMSNorm(a_j) * sub_norm * (1 - lambda_init)
+
+    (B, S, H / 2, 2 D) in the compute type, computed in float32 from the
+    attention's results as the kernels hand them out (their values'
+    type).  ``depth``: the layer's index among all, traced or not."""
+    f32 = jnp.float32
+    B, S, H, W = attn.shape
+    lam_init = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, f32))
+    dots = [jnp.sum(layer[a].astype(f32) * layer[b].astype(f32))
+            for a, b in (DIFF_LEAVES[:2], DIFF_LEAVES[2:4])]
+    lam = jnp.exp(dots[0]) - jnp.exp(dots[1]) + lam_init
+    pairs = attn.astype(f32).reshape(B, S, H // 2, 2, W)
+    a = pairs[..., 0, :] - lam * pairs[..., 1, :]
+    a = rms_norm(a, layer["sub_norm"], config.norm_eps) * (1.0 - lam_init)
+    return a.astype(config.dtype)
 
 
 def _rope_interleaved(x: jax.Array, sin, cos) -> jax.Array:
@@ -1213,9 +1486,11 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
     B, S, _ = x.shape
     route_x = x if config.moe_router_input == "layer" else None
     with jax.named_scope("attn_out"):
-        x = residual_add(x, matmul(attn.reshape(B, S, config.o_dim),
-                                   layer["wo"].astype(config.dtype)),
-                         config)
+        out = matmul(attn.reshape(B, S, config.o_dim),
+                     layer["wo"].astype(config.dtype))
+        if config.attn_bias:
+            out = out + layer["bo"].astype(config.dtype)
+        x = residual_add(x, out, config)
     return ffn_half(x, layer, config, valid, layer_index, route_x)
 
 
@@ -1242,7 +1517,7 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     c = config
     dt = c.dtype
     x = with_logical_constraint(x, "batch", "seq", None)
-    h = rms_norm(x, layer["mlp_norm"], c.norm_eps).astype(dt)
+    h = norm(x, layer, "mlp_norm", c).astype(dt)
     if c.moe_experts == 0:
         gate = matmul(h, layer["w_gate"].astype(dt))
         up = matmul(h, layer["w_up"].astype(dt))
@@ -1364,7 +1639,11 @@ def embed(params: PyTree, tokens: jax.Array,
 # The layer pattern: a scan iteration is one PERIOD of layers
 # ---------------------------------------------------------------------------
 
-ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+# A differential layer's own leaves: the four lambda vectors (head_dim
+# each) and the weight of the norm over a pair's 2 x head_dim value.
+DIFF_LEAVES = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2", "sub_norm")
+ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm",
+                    "bq", "bk", "bv", "bo") + DIFF_LEAVES
 
 
 def _leaf_kind(name: str) -> Optional[str]:
@@ -1374,12 +1653,17 @@ def _leaf_kind(name: str) -> Optional[str]:
         return "attention"
     if name.startswith("conv_"):
         return "conv"
+    if name.startswith("gmu_"):
+        return "gmu"
     return "mamba" if name.startswith("ssm_") else None
 
 
 def _leaf_group(kind: str) -> str:
-    """The kind under whose name a layer of ``kind`` finds its leaves."""
-    return "attention" if kind in ATTENDING_KINDS else kind
+    """The kind under whose name a layer of ``kind`` finds its leaves
+    (a model has Mamba-2 or Mamba-1 layers, never both: ``ssm_*``)."""
+    if kind in ATTENDING_KINDS:
+        return "attention"
+    return "mamba" if kind == "mamba1" else kind
 
 
 def by_period(tree: PyTree, config: LlamaConfig) -> PyTree:
@@ -1467,6 +1751,12 @@ def state_mixer(kind: str):
         from ray_tpu.models import shortconv
 
         return shortconv, "conv_proj", "conv_out"
+    if kind == "mamba1":
+        # (it also hands back its scan output, the memory a gmu layer
+        # reads: the caller's ``state_step`` keeps it)
+        from ray_tpu.models import mamba1
+
+        return mamba1, "ssm_proj", "ssm_out"
     from ray_tpu.models import mamba2
 
     return mamba2, "ssm_proj", "ssm_out"
@@ -1479,18 +1769,22 @@ def layer_index(p: jax.Array, per_period: int, i: int) -> jax.Array:
 
 def layer_block(x, layer, kind: str, config: LlamaConfig, sin, cos,
                 attend: Callable, state_step: Optional[Callable] = None,
-                valid=None, at=None):
+                valid=None, at=None, memory=None):
     """One layer of ``kind``: THE place that says what a decoder layer is
     made of, for training, the prefills and the decode step alike.  What
     the fresh rows meet (themselves, a cache, a carried state) is the
     caller's: ``attend(q, k, v)`` (latent attention: ``attend(cq,
-    latent)``; behind an indexer: ``attend(q, k, v, index)``) and
-    ``state_step(mixer, h)``, closures of the scan body that
+    latent)``; behind an indexer: ``attend(q, k, v, index)``; a cross
+    layer: ``attend(q, None, None)``, the K/V layer's rows being the
+    caller's) and ``state_step(mixer, h)``, closures of the scan body that
     owns the cache or the carry, return ``(the mixer's output, ys)``, ``ys``
     what the caller keeps; an ``attend`` scopes its ``kv_write`` and
     ``attention`` itself.  ``valid``: ``ffn_half``'s, or (B,), whole rows;
     ``at``: ``layer_index``'s arguments where ``layer`` holds the experts'
-    whole stacks.  Returns ``(x, aux, expert rows, ys)``."""
+    whole stacks (and what a differential layer's depth is counted from);
+    ``memory``: what a gmu layer gates, the scan output of the Mamba-1
+    layer before it at the same positions, (B, S, ssm_inner).  Returns
+    ``(x, aux, expert rows, ys)``."""
     c = config
 
     def rows_and_place():       # the FFN half's, traced after the mixer
@@ -1503,10 +1797,22 @@ def layer_block(x, layer, kind: str, config: LlamaConfig, sin, cos,
         fresh = (latent_down(x, layer, sin, cos, c) if c.kv_lora_rank
                  else _qkv_rope(x, layer, sin, cos, c, kind))
         attn, ys = attend(*fresh)
+        if c.diff_attention:
+            attn = diff_combine(attn, layer,
+                                c.layer_offset + layer_index(*at), c)
         return attn_out_ffn(x, attn, layer, c, **rows_and_place()) + (ys,)
+    if kind == "gmu":
+        with jax.named_scope("gmu"):
+            h = norm(x, layer, "attn_norm", c).astype(c.dtype)
+            gate = jax.nn.silu(matmul(h, layer["gmu_in"].astype(c.dtype),
+                                      jnp.float32))
+            out = matmul((gate * memory).astype(c.dtype),
+                         layer["gmu_out"].astype(c.dtype))
+            x = residual_add(x, out, c)
+        return ffn_half(x, layer, c, **rows_and_place()) + (None,)
     mixer, proj_scope, out_scope = state_mixer(kind)
     with jax.named_scope(proj_scope):
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps).astype(c.dtype)
+        h = norm(x, layer, "attn_norm", c).astype(c.dtype)
     out, ys = state_step(mixer, h)
     with jax.named_scope(out_scope):
         x = residual_add(x, out, c)
@@ -1521,11 +1827,20 @@ def rope_for(positions: jax.Array, config: LlamaConfig):
 
 
 def walk_block(sin, cos, positions, kv_step: Callable, window_step=None,
-               valid=None, lengths=None) -> Callable:
+               valid=None, lengths=None, cross_step=None,
+               memory_at=None) -> Callable:
     """``layer_block`` over whole sequences with ``layer_walk``'s steps:
-    ``block(x, layer, its slice of the cache walked, at, kind, config)``."""
-    def block(x, layer, kv_layer, at, kind, c):
+    ``block(x, layer, its slice of the cache walked, at, kind, config,
+    memory) -> (x, aux, expert rows, ys, memory)``.  ``memory``: the scan
+    output of the last Mamba-1 layer walked (None where the model has no
+    gmu layer to read it), every position's or, with ``memory_at`` (B, 1),
+    that position's alone; a cross layer's queries go to ``cross_step(q,
+    positions) -> attn``."""
+    def block(x, layer, kv_layer, at, kind, c, memory=None):
         def attend(q, k, v, *index):
+            if kind == "cross":
+                with jax.named_scope("cross_attention"):
+                    return cross_step(q, positions), None
             with jax.named_scope("attention"):
                 if kind == "window":
                     return window_step(q, k, v, positions)
@@ -1538,16 +1853,26 @@ def walk_block(sin, cos, positions, kv_step: Callable, window_step=None,
                 cq, latent, layer, sin, cos, c,
                 lambda q, k, v: kv_step(q, k, v, positions, None)[0]), latent
 
+        def state_step(mixer, h):
+            nonlocal memory
+            out, ys, *scan_output = mixer.prefill(h, layer, c, lengths)
+            if scan_output and memory is not None:
+                memory = scan_output[0] if memory_at is None else \
+                    jnp.take_along_axis(scan_output[0],
+                                        memory_at[:, :, None], axis=1)
+            return out, ys
+
         return layer_block(
             x, layer, kind, c, sin, cos,
             attend_expanded if c.kv_lora_rank else attend,
-            lambda mixer, h: mixer.prefill(h, layer, c, lengths), valid, at)
+            state_step, valid, at, memory) + (memory,)
 
     return block
 
 
 def walk_layers(carry, params: PyTree, config: LlamaConfig, block: Callable,
-                kv_layers: Any = None, scan_experts: bool = False):
+                kv_layers: Any = None, scan_experts: bool = False,
+                parts=None):
     """The layers of ``config`` over ``carry``, the middle of every forward
     pass: a scan over the PERIODS of the layer pattern, ``block(carry,
     layer, kv_layer, at, kind, the part's config) -> (carry, expert rows,
@@ -1558,7 +1883,8 @@ def walk_layers(carry, params: PyTree, config: LlamaConfig, block: Callable,
     None (training: what the dense dispatch under an ``expert`` mesh axis
     and the backward take); else closed over and read in place (serving).
     -> ``(carry, (ys over the attention layers, (L, E) expert rows, ys over
-    the state-keeping layers, ys over the window layers))``, None for none."""
+    the state-keeping layers, ys over the window layers))``, None for none.
+    ``parts``: those of ``config.parts()`` to walk (all of them)."""
     def walk_part(carry, c, layers, kv_layers):         # c: the part's
         sliced, stacks = (layers, {}) if scan_experts \
             else split_expert_stacks(layers, c)
@@ -1576,10 +1902,12 @@ def walk_layers(carry, params: PyTree, config: LlamaConfig, block: Callable,
                     None if scan_experts else (p, plen, j), kind, c)
                 rows.append(rows_j)
                 ys[kind].append(ys_j)
-            # (mamba and conv layers do not mix: one list holds a state)
+            # (a model has one kind of state-keeping layer: one list holds
+            # a state)
             return carry, (stack_period(ys["attention"], c),
                            stack_period(rows, c),
-                           stack_period(ys["mamba"] + ys["conv"], c),
+                           stack_period(
+                               ys["mamba"] + ys["mamba1"] + ys["conv"], c),
                            stack_period(ys["window"], c))
 
         # ``layer_scan``: the loop's own slicing of a layer's weights and
@@ -1594,12 +1922,12 @@ def walk_layers(carry, params: PyTree, config: LlamaConfig, block: Callable,
             return carry, merge_periods(stacked, c)
 
     outs = []
-    for part, key, l0 in config.parts():
+    for part, key, l0 in config.parts() if parts is None else parts:
         a0 = config.layers_before(l0, "attention")
         carry, out = walk_part(
             carry, part, params[key],
             jax.tree.map(
-                lambda a: a[a0:a0 + part.attending_layers()], kv_layers))
+                lambda a: a[a0:a0 + part.layers_of("attention")], kv_layers))
         outs.append(out)
     # Each result over the parts that have it, in the layers' order (a
     # dense part computes no expert's rows, a part without attending
@@ -1711,7 +2039,7 @@ def head_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
 def head_loss_logits(x, params: PyTree, config: LlamaConfig):
     """Training's final norm and head, in the scope ``loss_fn`` goes on in."""
     with jax.named_scope("head_loss"):
-        x = rms_norm(x, params["final_norm"], config.norm_eps)
+        x = norm(x, params, "final_norm", config)
         return with_logical_constraint(
             head_matmul(x, lm_head(params, config)), "batch", "seq", "vocab")
 
@@ -1734,7 +2062,7 @@ def train_layers(x, params: PyTree, config: LlamaConfig, positions):
     block = train_block(config, *rope_for(positions, config), positions)
 
     def summing(carry, *layer_args):
-        x, aux, rows, ys = block(carry[0], *layer_args)
+        x, aux, rows, ys, _memory = block(carry[0], *layer_args)
         return (x, carry[1] + aux), rows, ys
 
     return walk_layers((x, jnp.zeros((), jnp.float32)), params, config,
@@ -2151,26 +2479,88 @@ def layer_walk(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     sin, cos = rope_for(positions, c)
     if valid is None and lengths is not None:
         valid = positions < lengths[:, None]
-    block = walk_block(sin, cos, positions, kv_step, window_step, valid,
-                       lengths)
+    if c.kv_layer is not None:
+        x, results = _walk_to_kv_layer(
+            x, params, c, kv_step, window_step, kv_layers, positions, valid,
+            lengths, sin, cos)
+    else:
+        block = walk_block(sin, cos, positions, kv_step, window_step, valid,
+                           lengths)
 
-    def on_stream(x, *layer_args):
-        # serving carries the stream alone: no aux loss, no checkpoint
-        x, _aux, rows, ys = block(x, *layer_args)
-        return x, rows, ys
+        def on_stream(x, *layer_args):
+            # serving carries the stream alone: no aux loss, no checkpoint
+            x, _aux, rows, ys, _memory = block(x, *layer_args)
+            return x, rows, ys
 
-    x, (ys, expert_rows, ssm_ys, win_ys) = walk_layers(
-        x, params, c, on_stream, kv_layers)
+        x, results = walk_layers(x, params, c, on_stream, kv_layers)
+    ys, expert_rows, ssm_ys, win_ys = results
     with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], c.norm_eps).astype(c.dtype)
+        x = norm(x, params, "final_norm", c).astype(c.dtype)
         if lengths is None:
             return (head_logits(x, params, c), ys, expert_rows, ssm_ys,
                     win_ys)
-        last = jnp.take_along_axis(
+        last = x if c.kv_layer is not None else jnp.take_along_axis(
             x, jnp.maximum(lengths - 1, 0)[:, None, None],
             axis=1)                                          # (B,1,H)
         return (head_logits(last, params, c)[:, 0], ys, expert_rows,
                 ssm_ys, win_ys)
+
+
+def _walk_to_kv_layer(x, params, config: LlamaConfig, kv_step, window_step,
+                      kv_layers, positions, valid, lengths, sin, cos):
+    """``layer_walk``'s middle for a decoder-hybrid-decoder: the layers
+    BEFORE the K/V layer over every position (its self-decoder, whose
+    states and window rows a cache takes in) and the K/V layer's key and
+    value rows at every position; then the K/V layer's own query,
+    attention, output projection and FFN, and every gmu and cross layer
+    after it, at the positions whose logits are asked for alone -- with
+    ``lengths`` each row's last real position, (B, 1, H): the rest of a
+    prompt reaches the cross-decoder through the K/V rows and nothing
+    else, so a prefill is linear in the prompt and about half the
+    model's matmuls.  Without ``lengths`` every position goes on (what a
+    test holds the skipping walk to).  -> ``(x at those positions,
+    walk_layers' results)``."""
+    c = config
+    at = None if lengths is None else \
+        jnp.maximum(lengths - 1, 0)[:, None]                    # (B, 1)
+
+    def stepping(block):
+        def on_stream(stream, *layer_args):
+            x, _aux, rows, ys, memory = block(stream[0], *layer_args,
+                                              memory=stream[1])
+            return (x, memory), rows, ys
+        return on_stream
+
+    parts = c.parts()
+    head = [part for part in parts if part[2] < c.kv_layer]
+    B, S = positions.shape
+    memory = jnp.zeros((B, S if at is None else 1, c.ssm_inner), jnp.float32)
+    (x, memory), before = walk_layers(
+        (x, memory), params, c,
+        stepping(walk_block(sin, cos, positions, kv_step, window_step,
+                            valid, lengths, memory_at=at)),
+        kv_layers, parts=head)
+    # The K/V layer is a part of its own (``parts``): its rows at every
+    # position, by the projection every other layer's rows come from.
+    part, key, _ = parts[len(head)]
+    _q, k, v = _qkv_rope(x, jax.tree.map(lambda a: a[0], params[key]),
+                         sin, cos, part)
+    if at is not None:
+        x = jnp.take_along_axis(x, at[:, :, None], axis=1)
+        positions, valid = at, (lengths > 0)[:, None]
+
+    def shared_rows(q, positions):
+        return _cache_attend(q, k, v, positions, c.attn_scale)
+
+    (x, memory), after = walk_layers(
+        (x, memory), params, c,
+        stepping(walk_block(
+            sin, cos, positions,
+            lambda q, _k, _v, positions, _cache: (
+                shared_rows(q, positions), (k, v)),
+            valid=valid, cross_step=shared_rows)),
+        parts=parts[len(head):])
+    return x, tuple(over_parts([a, b]) for a, b in zip(before, after))
 
 
 def over_parts(results):
@@ -2243,7 +2633,8 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
             return flash_prefill_attention(
                 q, k, v, scale=scale, window=window, keep=keep,
                 lengths=lengths,
-                lse=not (config.kv_lora_rank or config.index_topk))
+                lse=not (config.kv_lora_rank or config.index_topk
+                         or config.diff_attention))
     else:
         def attend(q, k, v, positions, window, keep=None):
             return dot_attention(q, k, v, positions, scale, window, keep)

@@ -80,8 +80,17 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     conv_dim)``; for its short-convolution layers ``conv (Lc, taps - 1,
     B, D)`` alone.  A state is not positional: ``build_prefill`` replaces a
     slot's whole state, ``decode_step`` advances it in place."""
-    if cfg.layers_of("window"):
-        return _init_window_cache(cfg, slots, max_len)
+    if cfg.layers_of("window") or cfg.kv_layer is not None:
+        # (a decoder-hybrid-decoder: the pools its kinds of layer ask for
+        # side by side -- the K/V layer's rows, which its cross layers read
+        # and do not own, the window layers' rings, the Mamba-1 states)
+        cache = _init_window_cache(cfg, slots, max_len)
+        if cfg.layers_of("mamba1"):
+            from ray_tpu.models import mamba1
+
+            cache.update(mamba1.init_state(cfg, cfg.layers_of("mamba1"),
+                                           slots))
+        return cache
     if cfg.kv_lora_rank:
         # Latent attention keeps ONE leaf: a row of ``latent_row`` values a
         # token and layer (``c_kv`` normed, ``k_rope`` roped, zeros to
@@ -120,8 +129,8 @@ def _init_indexed_cache(cfg: LlamaConfig, slots: int, max_len: int):
 
 def _row_pool(cfg: LlamaConfig, layers: int, slots: int, positions: int):
     """A K or V pool stored as the rows the decode kernel reads."""
-    return jnp.zeros((layers, slots, positions * cfg.n_kv_heads,
-                      cfg.head_dim), cfg.dtype)
+    return jnp.zeros((layers, slots, positions * cfg.kv_row_heads,
+                      cfg.kv_row_dim), cfg.dtype)
 
 
 def _init_window_cache(cfg: LlamaConfig, slots: int, max_len: int):
@@ -151,7 +160,9 @@ def cache_pools(cfg: LlamaConfig, slots: int, max_len: int):
     (K and V together) and, for a model with Mamba layers, ``ssm`` and
     ``conv``; for a model with window layers ``kv_full`` and
     ``kv_window``; for a model with latent attention ``latent`` alone;
-    for a model with an indexer ``index_keys`` beside ``kv``."""
+    for a model with an indexer ``index_keys`` beside ``kv``; for a
+    decoder-hybrid-decoder ``kv_full`` (the K/V layer's rows), ``kv_window``,
+    ``ssm`` and ``conv``."""
     shapes = jax.eval_shape(lambda: init_cache(cfg, slots, max_len))
     pool_of = {"k": "kv_full" if "wk" in shapes else "kv",
                "wk": "kv_window", "ik": "index_keys"}
@@ -242,7 +253,13 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     handed K/V as a carry, compiled to the same sizes but not the same text
     as the program the cells have measured since PR 24 (PERF.md section 6)."""
 
-    n_win, hkv = cfg.layers_of("window"), cfg.n_kv_heads
+    n_win, hkv = cfg.layers_of("window"), cfg.kv_row_heads
+    # what rides the carry after the lengths, by name
+    names = _carried_names(jax.eval_shape(lambda: init_cache(cfg, 1, 8)))
+    # a decoder-hybrid-decoder's eight reads of the K/V layer's rows (its
+    # own among them) are told apart from its window layers' by their scope
+    full_scope = "cross_attention" if cfg.kv_layer is not None \
+        else "attention"
 
     def step(carry, _):
         ck, cv, tok, lens, *state = carry
@@ -262,7 +279,7 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
             scale = cfg.attn_scale
             at = {"attention": pos}       # out of range past any row
             if n_win:
-                ring = state[0].shape[2] // hkv
+                ring = state[names.index("wk")].shape[2] // hkv
                 at["window"] = jnp.where(pos < ck.shape[2], lens % ring,
                                          ring)
 
@@ -270,11 +287,12 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
         # a run of whole periods, ``LlamaConfig.parts``), as a plain
         # config; ``l0``: its first layer's index among all.
         def body(carry, period_and_index, part, sliced, stacks, l0):
-            x, ck, cv, *state = carry
+            x, ck, cv, *rest = carry
+            state = dict(zip(names, rest))
+            # the scan output of the last Mamba-1 layer, for the gmu layers
+            memory = rest[len(names)] if len(rest) > len(names) else None
             period, p = period_and_index
             n_of = part.period.count
-            # where the part's first attending layer lies in the cache
-            a0 = cfg.layers_before(l0, "attention")
             expert_rows = []
             for j, (kind, i, layer) in enumerate(
                     llama.period_layers(sliced, period, p, part)):
@@ -300,22 +318,29 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
 
                 def attend_pool(q, kk, vv):
                     # The layer's pool: the carry's K/V or, for a window
-                    # layer, the rings after the lengths.
-                    nonlocal ck, cv, state
-                    pk, pv = state if kind == "window" else (ck, cv)
-                    # Write before attend: the new row is among the keys.
-                    pk = _write(pk, l, rows, at[kind], kk[:, 0])
-                    pv = _write(pv, l, rows, at[kind], vv[:, 0])
+                    # layer, the rings after the lengths.  A cross layer
+                    # reads the K/V layer's rows and writes none.
+                    nonlocal ck, cv
+                    if kind == "window":
+                        pk, pv = state["wk"], state["wv"]
+                    else:
+                        pk, pv = ck, cv
+                    if kind != "cross":
+                        # Write before attend: the new row is among the
+                        # keys.
+                        pk = _write(pk, l, rows, at[kind], kk[:, 0])
+                        pv = _write(pv, l, rows, at[kind], vv[:, 0])
                     # The kernel reads the carry where it lies, each row
                     # as far as it is long; an inactive row's zeros are
                     # discarded below.
-                    with jax.named_scope("attention"):
+                    with jax.named_scope("attention" if kind == "window"
+                                         else full_scope):
                         attn = decode_attention(
                             q[:, 0], pk, pv, l, lens, active,
                             s_active=s_active, scale=scale,
                             hkv=hkv)[:, None]
                     if kind == "window":
-                        state = [pk, pv]
+                        state.update(wk=pk, wv=pv)
                     else:
                         ck, cv = pk, pv
                     return attn, None
@@ -328,13 +353,13 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                     # through the same kernel, which reads the rows as they
                     # lie and masks the others.  A row no longer than
                     # ``index_topk`` selects every key: the dense result.
-                    nonlocal ck, cv, state
+                    nonlocal ck, cv
                     qi, ki, w = index
                     ck = _write(ck, l, rows, pos, kk[:, 0])
                     cv = _write(cv, l, rows, pos, vv[:, 0])
-                    (ik,) = state
-                    ik = _write_index_key(ik, l, rows, pos, ki[:, 0])
-                    state = [ik]
+                    ik = _write_index_key(state["ik"], l, rows, pos,
+                                          ki[:, 0])
+                    state["ik"] = ik
                     seen = min(s_active, ik.shape[3])
                     with jax.named_scope("indexer"):
                         keys_t = jax.lax.dynamic_slice(
@@ -351,17 +376,30 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                     return attn, None
 
                 def state_step(mixer, h):
-                    # A state-keeping layer (Mamba-2, short convolution):
-                    # its layer of the stacked states, in place.
-                    nonlocal state
+                    # A state-keeping layer (Mamba-2, Mamba-1, short
+                    # convolution): its layer of the stacked states, in
+                    # place.
+                    nonlocal memory
                     m = llama.layer_index(p, n_of(kind), i)
                     if l0:
                         m = m + cfg.layers_before(l0, kind)
-                    out, *state = mixer.decode(h, layer, part, *state, m,
-                                               active)
+                    held = ("conv",) if kind == "conv" else ("ssm", "conv")
+                    out, *new = mixer.decode(
+                        h, layer, part, *(state[n] for n in held), m, active)
+                    if kind == "mamba1":
+                        *new, scan_output = new
+                        if memory is not None:
+                            memory = scan_output
+                    state.update(zip(held, new))
                     return out, None
 
-                if kind in llama.ATTENDING_KINDS and not part.kv_lora_rank:
+                # where the part's first layer of this kind lies in its
+                # pool (a cross layer: the K/V layer before it)
+                a0 = cfg.layers_before(l0, "attention") - 1 \
+                    if kind == "cross" else cfg.layers_before(l0, kind)
+                if kind == "cross":
+                    l = a0
+                elif kind in llama.ATTENDING_KINDS and not part.kv_lora_rank:
                     # ``attend_pool``'s ``l``: the layer's place in its pool
                     l = llama.layer_index(p, n_of(kind), i)
                     if a0:
@@ -371,30 +409,35 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
                     attend_absorbed if part.kv_lora_rank
                     else attend_selected if part.index_topk
                     else attend_pool,
-                    state_step, valid=active, at=(p, part.period_len, j))
+                    state_step, valid=active, at=(p, part.period_len, j),
+                    memory=memory)
                 expert_rows.append(rows_j)
-            return (x, ck, cv, *state), llama.stack_period(expert_rows,
-                                                           part)
+            return (x, ck, cv, *(state[n] for n in names),
+                    *(() if memory is None else (memory,))), \
+                llama.stack_period(expert_rows, part)
 
         # The leading dense layers, if the model has them, then the
         # scanned stack: the same body over each part's own weights (a
         # dense part computes no expert's rows).
         with jax.named_scope("layer_scan"):
             expert_rows = []
+            memory = (jnp.zeros((tok.shape[0], 1, cfg.ssm_inner),
+                                jnp.float32),) \
+                if cfg.layers_of("gmu") else ()
             for part, key, l0 in cfg.parts():
                 sliced, stacks = llama.split_expert_stacks(params[key], part)
                 (x, ck, cv, *state), rows_part = jax.lax.scan(
                     functools.partial(body, part=part, sliced=sliced,
                                       stacks=stacks, l0=l0),
-                    (x, ck, cv, *state),
+                    (x, ck, cv, *state, *memory),
                     (llama.scanned_layers(sliced, part),
                      jnp.arange(part.n_layers // part.period_len,
                                 dtype=jnp.int32)))
+                state, memory = state[:len(names)], tuple(state[len(names):])
                 expert_rows.append(llama.merge_periods(rows_part, part))
             expert_rows = llama.over_parts(expert_rows)
         with jax.named_scope("head"):
-            x = llama.rms_norm(x, params["final_norm"],
-                               cfg.norm_eps).astype(cfg.dtype)
+            x = llama.norm(x, params, "final_norm", cfg).astype(cfg.dtype)
             logits = llama.head_logits(x, params, cfg)[:, 0]
         with jax.named_scope("sample"):
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -429,16 +472,23 @@ def _write_index_key(pool, l, slots, pos, new):
         new.astype(pool.dtype), mode="drop", unique_indices=True)
 
 
-# What rides the carry after the lengths, if the cache has it: a model
-# has Mamba states, conv states, window rings or index keys, never two of
-# them.  And the axis of a state leaf that counts the slots.
-_CARRIED = (("ssm", "conv"), ("conv",), ("wk", "wv"), ("ik",))
+# What rides the carry after the lengths, if the cache has it, in this
+# order: window rings, recurrent and conv states, index keys (a
+# decoder-hybrid-decoder has rings AND states).  The leaves a prefill
+# replaces wholesale (``insert_states``), and the axis of each that counts
+# the slots.
+_CARRIED = ("wk", "wv", "ssm", "conv", "ik")
 _SLOT_AXIS = {"ssm": 1, "conv": 2}
 
 
-def _state_names(cache):
+def _carried_names(cache):
     """The leaves of ``cache`` that ride the carry after the lengths."""
-    return next((names for names in _CARRIED if names[0] in cache), ())
+    return tuple(name for name in _CARRIED if name in cache)
+
+
+def _state_names(cache):
+    """The state leaves of ``cache``: what ``insert_states`` replaces."""
+    return tuple(name for name in _SLOT_AXIS if name in cache)
 
 
 def _carry(cache, tok, lens):
@@ -448,7 +498,7 @@ def _carry(cache, tok, lens):
     if "latent" in cache:       # the one leaf, where K lies; no V
         return (cache["latent"], None, tok, lens)
     return (cache["k"], cache["v"], tok, lens,
-            *(cache[name] for name in _state_names(cache)))
+            *(cache[name] for name in _carried_names(cache)))
 
 
 def _uncarry(carry, cache):
@@ -456,7 +506,7 @@ def _uncarry(carry, cache):
     ck, cv, tok, lens, *state = carry
     if "latent" in cache:
         return {"latent": ck}, tok, lens
-    return ({"k": ck, "v": cv, **dict(zip(_state_names(cache), state))},
+    return ({"k": ck, "v": cv, **dict(zip(_carried_names(cache), state))},
             tok, lens)
 
 
@@ -527,8 +577,9 @@ def build_prefill(cfg: LlamaConfig) -> Callable:
         elif cfg.kv_lora_rank:
             cache = {"latent": _insert_rows(cache["latent"], ks, slots)}
         elif window is not None:
-            ring = cache["wk"].shape[2] // cfg.n_kv_heads
+            ring = cache["wk"].shape[2] // cfg.kv_row_heads
             cache = {
+                **cache,
                 "k": _insert_rows(cache["k"], ks, slots),
                 "v": _insert_rows(cache["v"], vs, slots),
                 "wk": _insert_rows(

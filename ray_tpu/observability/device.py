@@ -524,6 +524,8 @@ SCOPES = (
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_state_update", "ssm_out",
     "conv_proj", "short_conv", "conv_out",
     "indexer", "index_select", "sparse_attention",
+    "mamba1_scan", "mamba1_state_update", "gmu", "cross_attention",
+    "diff_combine",
     "head", "sample", "head_loss", "optimizer",
 )
 PHASES = ("forward", "backward", "remat")
@@ -535,6 +537,12 @@ PHASES = ("forward", "backward", "remat")
 _COMPILER_NAMED = {"ragged-dot-none": "expert_ffn",
                    "ragged-dot-metadata": "expert_dispatch"}
 _SCOPE_OF_WORD = {**{word: word for word in SCOPES}, **_COMPILER_NAMED}
+# Words that keep what is traced inside them, whatever word lies further
+# in: a model's name for one USE of a kernel or function that carries a
+# word of its own (a decoder-hybrid-decoder's reads of its one full-length
+# K/V pool go through ``decode_attention`` and ``attention`` as every
+# other read does, and the kernel keeps its one name).
+_OUTER_WINS = frozenset(("cross_attention",))
 # ``jvp(ffn)``, ``transpose(jvp(ffn))`` -> ``ffn``: a transformation
 # wraps the one name-stack element inside it.
 _WRAPPED = re.compile(r"^(?:[\w.\-]+\()*([^()]*)\)*$")
@@ -545,7 +553,10 @@ def scope_of(op_name: Optional[str]):
     (``jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/
     rematted_computation/ffn/dot_general``): the innermost element of
     the path that is a word of :data:`SCOPES`, through any
-    ``jvp(...)`` / ``transpose(...)`` around it; the phase ``remat``
+    ``jvp(...)`` / ``transpose(...)`` around it -- or, where the path
+    holds one, the word that keeps what lies inside it
+    (``cross_attention/decode_attention`` -> ``cross_attention``); the
+    phase ``remat``
     where the path holds ``rematted_computation``, else ``backward``
     where it holds ``transpose(``, else ``forward``.  Pure string
     work."""
@@ -556,11 +567,14 @@ def scope_of(op_name: Optional[str]):
         phase = "backward"
     else:
         phase = "forward"
+    innermost = None
     for element in reversed(path.split("/")):
         m = _WRAPPED.match(element)
-        if m and m.group(1) in _SCOPE_OF_WORD:
-            return _SCOPE_OF_WORD[m.group(1)], phase
-    return None, phase
+        word = _SCOPE_OF_WORD.get(m.group(1)) if m else None
+        if word in _OUTER_WINS:
+            return word, phase
+        innermost = innermost or word
+    return innermost, phase
 
 
 # One instruction of a compiled module's text:

@@ -359,7 +359,8 @@ class LLMServer:
             ("prefill/decode disaggregation (role)", role != "both"),
         ) if on]
         stateful = [name for kind, name in (
-            ("mamba", "state-space"), ("conv", "short-convolution"))
+            ("mamba", "state-space"), ("mamba1", "state-space"),
+            ("conv", "short-convolution"))
             if self.cfg.layers_of(kind)]
         if asked and stateful:
             raise ValueError(
@@ -471,7 +472,9 @@ class LLMServer:
         self._ring = 0
         if self.cfg.layers_of("window") and not self.paged:
             self._ring = llama_serve.ring_len(self.cfg, max_len)
-            self._pool_layers = (self.cfg.layers_of("attention"),
+            # (a cross layer reads the full pool and holds no rows in it)
+            self._pool_layers = (self.cfg.layers_of("attention")
+                                 + self.cfg.layers_of("cross"),
                                  self.cfg.layers_of("window"))
         # A model with an indexer: the keys a query attends at most (0 for
         # any other).  A launch counts its rows' positions both ways, as it
@@ -1916,7 +1919,12 @@ class LLMServer:
         if not self._ring:
             return {}
         full_layers, window_layers = self._pool_layers
-        return {"kv_full_positions_attended": attended,
+        shared = {}
+        if self.cfg.kv_layer is not None:
+            # a decoder-hybrid-decoder: the rows read from the ONE pool,
+            # summed over the layers that read it
+            shared = {"shared_kv_positions_attended": attended * full_layers}
+        return {**shared, "kv_full_positions_attended": attended,
                 "kv_window_positions_attended": ringed,
                 "kv_full_bucket": s_active,
                 "kv_window_bucket": min(s_active, self._ring),
@@ -1968,6 +1976,15 @@ class LLMServer:
             w = min(self._ring, bucket)
             scan["window_band_share"] = round(
                 w * (2 * bucket - w + 1) / (bucket * bucket), 4)
+        if self.cfg.kv_layer is not None:
+            # a decoder-hybrid-decoder: positions x layers the prefill did
+            # not compute -- the layers after the K/V layer run at a row's
+            # last position alone (the K/V layer itself, which projects
+            # its rows everywhere and runs the rest at one position,
+            # counts as computed)
+            scan["positions_skipped"] = rows * (bucket - 1) * (
+                self.cfg.n_layers - self.cfg.kv_layer - 1)
+            scan["layers"] = self.cfg.n_layers
         if bucket > llama.FLASH_PREFILL_FROM and not warm:
             # the flash forward's q blocks, a layer and head, and those of
             # them that start at or past their row's length: declined

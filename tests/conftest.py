@@ -152,6 +152,21 @@ def ray_start_regular():
 
 
 @pytest.fixture
+def traced():
+    """Tracing on and an empty timeline, whatever ran before on this
+    worker (a test that turned tracing off, another engine's spans);
+    tracing is left as it was found."""
+    from ray_tpu.observability import timeline, tracing
+
+    was = tracing.enabled()
+    tracing.enable()
+    timeline.clear()
+    yield timeline
+    if not was:
+        tracing.disable()
+
+
+@pytest.fixture
 def shutdown_only():
     import ray_tpu
 

@@ -20,7 +20,7 @@ import pytest
 
 from ray_tpu.models import indexer, llama
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.observability import timeline, tracing
+from ray_tpu.observability import timeline
 
 flash = importlib.import_module("ray_tpu.ops.flash_attention")
 
@@ -204,15 +204,13 @@ def test_prefill_with_states_returns_what_it_returned(
 
 # ------------------------------------------------ serve.prefill_group
 def test_the_prefill_span_counts_the_blocks_the_kernel_declines(
-        small_tiles, monkeypatch):
+        small_tiles, monkeypatch, traced):
     """A bucket past ``FLASH_PREFILL_FROM`` carries ``flash_q_blocks`` and
     ``flash_q_blocks_declined``, the padding rows' among them, by the
     kernel's own block size; a bucket under it carries neither."""
     from ray_tpu.serve import llm
 
-    assert tracing.enabled()
     monkeypatch.setattr(llama, "FLASH_PREFILL_FROM", 16)
-    timeline.clear()
     server = llm.LLMServer(model_preset="debug", max_slots=4, max_len=128,
                            prefill_buckets=(16, 64), decode_chunk=4,
                            prefill_groups=(1, 2), warmup=False)
@@ -221,8 +219,12 @@ def test_the_prefill_span_counts_the_blocks_the_kernel_declines(
         return await asyncio.gather(*[server.generate(r) for r in requests])
 
     try:
-        asyncio.run(run([{"prompt": list(range(1, 1 + n)),
-                          "max_new_tokens": 3} for n in (37, 9, 64)]))
+        # one request a wave: which prompts share a padded group is then
+        # no matter of timing (sent together, 9 rode 37's bucket of 64
+        # whenever the two met in a wave, and declined three blocks there)
+        for n in (37, 9, 64):
+            asyncio.run(run([{"prompt": list(range(1, 1 + n)),
+                              "max_new_tokens": 3}]))
         asyncio.run(run([{"prompt": [7], "max_new_tokens": 1}]))
     finally:
         server.shutdown()

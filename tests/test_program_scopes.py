@@ -41,6 +41,13 @@ def fresh_registry():
     ("jit(decode_k)/sample/while/body/layer_scan/while/body/closed_call/"
      "attention/decode_attention/decode_attention",
      ("decode_attention", "forward")),
+    # a word that keeps what lies inside it: one model's name for a use of
+    # a kernel that carries a word of its own
+    ("jit(decode_k)/sample/while/body/layer_scan/while/body/closed_call/"
+     "cross_attention/decode_attention/decode_attention",
+     ("cross_attention", "forward")),
+    ("jit(prefill)/layer_scan/while/body/cross_attention/attention/"
+     "dot_general", ("cross_attention", "forward")),
     ("jit(prefill)/head/head/dot_general", ("head", "forward")),
     ("jit(f)/optimizer/sub", ("optimizer", "forward")),
     ("jit(step)/head_loss/reduce_sum", ("head_loss", "forward")),
