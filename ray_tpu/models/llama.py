@@ -1067,6 +1067,108 @@ def matmul(x: jax.Array, w: jax.Array, out_dtype: Any = None) -> jax.Array:
     return out.astype(out_dtype or x.dtype)
 
 
+def scattered_grad_matmul(x: jax.Array, w: jax.Array,
+                          w_axes: Tuple[str, str]) -> jax.Array:
+    """``matmul(x, w)``, x (B, S, K) by w (K, N) of logical axes ``w_axes``,
+    whose backward reduces the weight's gradient scattered under a mesh
+    that shards one of w's dimensions over the batch's axes: each device
+    receives only the part it keeps, and receives it while the matmul's
+    own backward computes.  Training's head calls it, and a dense layer's
+    output projection and three FFN matmuls; the q, k and v projections
+    keep ``matmul``: exchanged by hand too the step read 3.9 ms slower
+    than with XLA's three reduce-scatters (PERF.md section 6, PR 50).
+
+    Left to itself XLA sums such a gradient in a reduce-scatter that runs
+    alone on this backend whatever flag is set (inside the layers'
+    backward loop, seven a layer), or, the head's, WHOLE on every device
+    in an all-reduce that runs alone as well (PERF.md section 6, PRs 47
+    and 50).  The one collective XLA's TPU backend leaves in flight beside
+    compute is the collective-permute.  So every device forms its partial
+    product ``x^T g`` in n blocks along the dimension the mesh shards --
+    rows, blocks of x's last dimension, for a weight laid out ``("embed",
+    ...)``; columns, blocks of g's last dimension, for ``(..., "embed")``
+    -- one per device of the mesh axes that shard both that dimension and
+    the batch, sends each other device its block, forms its own block
+    while those travel, and adds what arrives: the same float32 partial
+    products summed in float32, cast once after the sum, as the
+    reduce-scatter had them.  The exchange is awaited where the input's
+    gradient is, so that it travels under this matmul's own backward.
+
+    Where there is nothing to scatter (no mesh, one device, a manual
+    region, no dimension that the batch's axes shard, a width they do not
+    divide) this is ``matmul`` and its own backward."""
+    mesh = partitioning_mesh()
+    if mesh is None or x.ndim != 3:
+        return matmul(x, w)
+    rules = current_rules()
+    batch, seq = rules.axes(("batch", "seq"))
+    summed = tuple(a for a in batch + seq if mesh.shape[a] > 1)
+    sharded = rules.axes(w_axes)
+    side = 0 if any(a in summed for a in sharded[0]) else 1
+    scattered = tuple(a for a in sharded[side] if a in summed)
+    n = math.prod(mesh.shape[a] for a in scattered)
+    if n == 1 or w.shape[side] % n:
+        return matmul(x, w)
+    whole = tuple(a for a in summed if a not in scattered)
+    width = w.shape[side] // n
+    # The operands as the activations are laid out: the batch's axes on the
+    # rows; the dimension that is not scattered keeps what is left of the
+    # weight's axes (``tensor``, under fsdp x tensor), the scattered one is
+    # whole in the operands and over ``scattered`` in the result.
+    other = axes_entry(tuple(a for a in sharded[1 - side]
+                             if a not in batch + seq))
+
+    def laid_out(scattered_dim):
+        return (scattered_dim, other) if side == 0 else (other, scattered_dim)
+
+    in_specs = tuple(P(axes_entry(batch), axes_entry(seq), last)
+                     for last in laid_out(None))
+    out_specs = P(*laid_out(axes_entry(scattered)))
+
+    def scattered_sum(x, g):
+        """This device's part of the devices' summed ``x^T g``."""
+        me = jax.lax.axis_index(scattered)
+
+        def block(device):
+            """This device's partial product for the part ``device`` keeps."""
+            cut = [x, g]
+            cut[side] = jax.lax.dynamic_slice_in_dim(
+                cut[side], device * width, width, 2)
+            return jax.lax.dot_general(*cut, (((0, 1), (0, 1)), ((), ())),
+                                       preferred_element_type=jnp.float32)
+
+        arriving = [
+            jax.lax.ppermute(block((me + hop) % n), scattered,
+                             [(d, (d + hop) % n) for d in range(n)])
+            for hop in range(1, n)]
+        # The barrier keeps this device's own block out of the fusion that
+        # adds the arrivals up, which would compute it after the wait.
+        total, arriving = jax.lax.optimization_barrier((block(me), arriving))
+        for part in arriving:
+            total = total + part
+        if whole:
+            total = jax.lax.psum(total, whole)
+        return total.astype(w.dtype)
+
+    @jax.custom_vjp
+    def scattering(x, w):
+        return matmul(x, w)
+
+    def backward(saved, g):
+        x, w = saved
+        dx = jax.lax.dot_general(g, w, (((g.ndim - 1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dw = jax.shard_map(scattered_sum, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)(x, g)
+        # Awaited together: without it the scheduler starts the exchange
+        # where the gradient is first read, after the layers' backward (the
+        # head's) or at the end of a layer's, with nothing left to cover it.
+        return jax.lax.optimization_barrier((dx.astype(x.dtype), dw))
+
+    scattering.defvjp(lambda x, w: (matmul(x, w), (x, w)), backward)
+    return scattering(x, w)
+
+
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     dtype = x.dtype
     x = x.astype(jnp.float32)
@@ -1486,8 +1588,9 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
     B, S, _ = x.shape
     route_x = x if config.moe_router_input == "layer" else None
     with jax.named_scope("attn_out"):
-        out = matmul(attn.reshape(B, S, config.o_dim),
-                     layer["wo"].astype(config.dtype))
+        out = scattered_grad_matmul(attn.reshape(B, S, config.o_dim),
+                                    layer["wo"].astype(config.dtype),
+                                    ("heads", "embed"))
         if config.attn_bias:
             out = out + layer["bo"].astype(config.dtype)
         x = residual_add(x, out, config)
@@ -1519,15 +1622,18 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     x = with_logical_constraint(x, "batch", "seq", None)
     h = norm(x, layer, "mlp_norm", c).astype(dt)
     if c.moe_experts == 0:
-        gate = matmul(h, layer["w_gate"].astype(dt))
-        up = matmul(h, layer["w_up"].astype(dt))
+        gate = scattered_grad_matmul(h, layer["w_gate"].astype(dt),
+                                     ("embed", "mlp"))
+        up = scattered_grad_matmul(h, layer["w_up"].astype(dt),
+                                   ("embed", "mlp"))
         # Named so the "attn_ffn" remat policy can save it (inert under
         # every other policy and outside jax.checkpoint).
         from jax.ad_checkpoint import checkpoint_name
 
         ff = checkpoint_name(jax.nn.silu(gate) * up, "ffn_act")
         ff = with_logical_constraint(ff, "batch", "seq", "mlp")
-        x = residual_add(x, matmul(ff, layer["w_down"].astype(dt)), c)
+        x = residual_add(x, scattered_grad_matmul(
+            ff, layer["w_down"].astype(dt), ("mlp", "embed")), c)
         return (with_logical_constraint(x, "batch", "seq", None),
                 jnp.zeros((), jnp.float32), None)
     from ray_tpu.models import moe
@@ -1952,96 +2058,14 @@ def embed_sharded(params: PyTree, tokens, config: LlamaConfig):
         return with_logical_constraint(emb[tokens], "batch", "seq", None)
 
 
-def head_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
-    """``matmul(x, w)`` of training's head, x (B, S, D) by w (D, V), whose
-    backward reduces the weight's gradient scattered under a mesh that
-    shards w's rows: each device receives only the rows it keeps, and
-    receives them while the head's own backward computes.
-
-    Left to itself XLA sums this one gradient WHOLE on every device, an
-    all-reduce of (D, V) that runs alone: the slice down to a device's
-    rows is fused into the optimizer's reductions before the pass that
-    pairs an all-reduce with its slice runs, and a ``psum_scatter`` is
-    lowered back to the same pair or, scattered over the minor dimension,
-    to a reduce-scatter that runs alone as well (PERF.md section 6,
-    PR 47).  The one collective XLA's TPU backend leaves in flight beside
-    compute is the collective-permute.  So every device forms its partial
-    product in n blocks of rows, one per device of the mesh axes that
-    shard both w's rows and the batch, sends each other device its block,
-    forms its own block while those travel, and adds what arrives: the
-    same float32 partial products summed in float32, cast once after the
-    sum, as the all-reduce had them.  The exchange is awaited where the
-    input's gradient is, before the layers' backward starts, so that it
-    travels under the head's own matmuls while the links are idle.
-
-    Where there is nothing to scatter (no mesh, one device, a manual
-    region, rows that the batch's axes do not shard) this is ``matmul``
-    and its own backward."""
-    mesh = partitioning_mesh()
-    if mesh is None:
-        return matmul(x, w)
-    rules = current_rules()
-    batch, seq, vocab = rules.axes(("batch", "seq", "vocab"))
-    summed = tuple(a for a in batch + seq if mesh.shape[a] > 1)
-    scattered = tuple(a for a in rules.axes(("embed", "vocab"))[0]
-                      if a in summed)
-    n = math.prod(mesh.shape[a] for a in scattered)
-    if n == 1 or w.shape[0] % n:
-        return matmul(x, w)
-    whole = tuple(a for a in summed if a not in scattered)
-    width = w.shape[0] // n
-
-    def scattered_sum(x, g):
-        """This device's rows of the devices' summed ``x^T g``."""
-        me = jax.lax.axis_index(scattered)
-
-        def block(device):
-            """This device's partial product for the rows ``device`` keeps."""
-            rows = jax.lax.dynamic_slice_in_dim(x, device * width, width, 2)
-            return jax.lax.dot_general(rows, g, (((0, 1), (0, 1)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-
-        arriving = [
-            jax.lax.ppermute(block((me + hop) % n), scattered,
-                             [(d, (d + hop) % n) for d in range(n)])
-            for hop in range(1, n)]
-        # The barrier keeps this device's own block out of the fusion that
-        # adds the arrivals up, which would compute it after the wait.
-        total, arriving = jax.lax.optimization_barrier((block(me), arriving))
-        for part in arriving:
-            total = total + part
-        if whole:
-            total = jax.lax.psum(total, whole)
-        return total.astype(w.dtype)
-
-    @jax.custom_vjp
-    def head(x, w):
-        return matmul(x, w)
-
-    def backward(saved, g):
-        x, w = saved
-        dx = jax.lax.dot_general(g, w, (((g.ndim - 1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        dw = jax.shard_map(
-            scattered_sum, mesh=mesh,
-            in_specs=(rules.spec(("batch", "seq", None)),
-                      rules.spec(("batch", "seq", "vocab"))),
-            out_specs=P(axes_entry(scattered), axes_entry(vocab)),
-            check_vma=False)(x, g)
-        # Awaited together: without it the scheduler starts the exchange
-        # after the layers' backward, beside the embedding's reduce-scatter.
-        return jax.lax.optimization_barrier((dx.astype(x.dtype), dw))
-
-    head.defvjp(lambda x, w: (matmul(x, w), (x, w)), backward)
-    return head(x, w)
-
-
 def head_loss_logits(x, params: PyTree, config: LlamaConfig):
     """Training's final norm and head, in the scope ``loss_fn`` goes on in."""
     with jax.named_scope("head_loss"):
         x = norm(x, params, "final_norm", config)
         return with_logical_constraint(
-            head_matmul(x, lm_head(params, config)), "batch", "seq", "vocab")
+            scattered_grad_matmul(x, lm_head(params, config),
+                                  ("embed", "vocab")),
+            "batch", "seq", "vocab")
 
 
 def train_block(config: LlamaConfig, sin, cos, positions) -> Callable:

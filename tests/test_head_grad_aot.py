@@ -40,23 +40,30 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
+_COMPILED = {}      # (config, traffic) -> the step, for both files' fixtures
+
+
 def _compiled(topo, config, traffic, mesh):
     """The cell's step compiled for the chip from a CPU backend: the flash
     kernels steered to Mosaic, the compile kept out of the persistent
-    cache (which cannot read it back without a chip)."""
+    cache (which cannot read it back without a chip).  Compiled once a
+    process: ``tests/test_layer_grad_aot.py`` reads the same steps."""
     import jax
 
     from benchmarks.tests.test_aot_real_widths import _train_step
 
+    if (config, traffic) in _COMPILED:
+        return _COMPILED[config, traffic]
     flash = importlib.import_module("ray_tpu.ops.flash_attention")
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         with pytest.MonkeyPatch.context() as patch:
             patch.setattr(flash, "_use_interpret", lambda: False)
-            return _train_step(config, traffic, mesh, topo.devices)
+            step = _train_step(config, traffic, mesh, topo.devices)
     finally:
         jax.config.update("jax_enable_compilation_cache", enabled)
+    return _COMPILED.setdefault((config, traffic), step)
 
 
 @pytest.fixture(scope="module")
@@ -134,7 +141,9 @@ def test_the_exchange_is_over_before_the_layers_backward(fsdp4):
     """The permutes are started behind the blocks they carry and awaited
     before the backward's layer loop, with this chip's own block and the
     input's gradient formed in between: nothing of them rides beside the
-    loop's reduce-scatters or the embedding's."""
+    loop's own exchanges (the layers' permutes and the q, k and v
+    projections' reduce-scatters, ``tests/test_layer_grad_aot.py``) or the
+    embedding's reduce-scatter."""
     entry = list(_instructions(fsdp4.as_text().split("\nENTRY ", 1)[1]))
     at = {}
     for i, (name, result, op, line) in enumerate(entry):

@@ -1,4 +1,4 @@
-"""The head's weight gradient under a mesh (``llama.head_matmul``): the
+"""The head's weight gradient under a mesh (``llama.scattered_grad_matmul``): the
 backward forms each device's partial product and reduces it scattered over
 the mesh axes that shard the weight's rows.  On the simulated CPU devices,
 float32, toy widths: loss and every gradient leaf of ``loss_fn`` under the
@@ -68,7 +68,8 @@ def _head_grad_primitives(cfg):
     x = jnp.ones((4, 8, cfg.hidden_size), cfg.dtype)
     w = jnp.ones((cfg.hidden_size, cfg.vocab_size), cfg.dtype)
     return _primitives(jax.make_jaxpr(jax.grad(
-        lambda x, w: llama.head_matmul(x, w).sum(), argnums=(0, 1)))(
+        lambda x, w: llama.scattered_grad_matmul(
+            x, w, ("embed", "vocab")).sum(), argnums=(0, 1)))(
             x, w).jaxpr)
 
 
@@ -119,11 +120,28 @@ def test_the_pipelines_last_stage_under_its_own_mesh():
                                    rtol=1e-4, atol=1e-6)
 
 
-def test_collectives_alone_counts_each_op_once_and_fusions_apart():
+LAYERS = "jit(step)/transpose(jvp(layer_scan))/while/body/closed_call/" \
+         "checkpoint/ffn/shard_map/ppermute"
+HEAD = "jit(step)/transpose(jvp(head_loss))/shard_map/ppermute"
+PIPELINE = "jit(step)/jvp(layer_scan)/shard_map/while/body/ppermute"
+
+
+@pytest.mark.parametrize("op_name,row", [
+    (None, "%collective-permute-start.3"),
+    (LAYERS, "layers' gradient exchange, ffn"),
+    (HEAD, "head's gradient exchange"),
+    (PIPELINE, "%collective-permute-start.3"),
+], ids=["by-op", "layers-exchange", "heads-exchange", "not-an-exchange"])
+def test_collectives_alone_counts_each_op_once_and_fusions_apart(op_name,
+                                                                 row):
     """``tools.collectives_alone.by_op`` on a synthetic chip: an async
     permute in flight under a matmul and alone past its end, a sync
-    all-reduce alone, a fused reduce-scatter that no reader counts."""
-    from tools.collectives_alone import by_op
+    all-reduce alone, a fused reduce-scatter that no reader counts.  The
+    permute is a row of its own unless the compiled step's text gives it
+    the ``op_name`` of a weight gradient's exchange (``exchanges``): the
+    layers' by their scope, apart from the head's and from any other
+    permute."""
+    from tools.collectives_alone import by_op, exchanges
 
     permute = "%collective-permute-start.3 = (f32[512,92544]) " \
               "collective-permute-start(%fusion.1)"
@@ -133,10 +151,15 @@ def test_collectives_alone_counts_each_op_once_and_fusions_apart():
     matmul = "%fusion.9 = bf16[4096,2048] fusion(%p.1), kind=kOutput"
     ops = [(0.0, 0.1, permute), (0.1, 4.0, matmul), (6.0, 9.0, reduce),
            (9.0, 11.0, fused)]
-    collectives, fusions = by_op(ops, [(0.0, 5.0, permute)])
-    assert [(n.split()[0], round(t, 6), round(a, 6), c)
+    compiled = "\n".join(
+        f"  {text}, metadata={{op_name=\"{name}\"}}" for text, name in [
+            (permute, op_name), (matmul, "jit(step)/jvp(ffn)/dot_general")]
+        if name)
+    rows_of = exchanges(compiled)
+    assert list(rows_of.values()) == ([row] if row[0] != "%" else [])
+    collectives, fusions = by_op(ops, [(0.0, 5.0, permute)], rows_of)
+    assert [(n if n == row else n.split()[0], round(t, 6), round(a, 6), c)
             for n, t, a, c in collectives] == [
-        ("%all-reduce.27", 3.0, 3.0, 1),
-        ("%collective-permute-start.3", 5.0, 1.1, 1)]
+        ("%all-reduce.27", 3.0, 3.0, 1), (row, 5.0, 1.1, 1)]
     assert [(n.split()[0], t, c) for n, t, c in fusions] == [
         ("%fusion.18", 2.0, 1)]
