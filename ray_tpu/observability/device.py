@@ -22,11 +22,21 @@ scheduling decisions read.  Five surfaces, one module:
    process that imports jax but leaves the chip to a child (or has yet
    to call ``jax.distributed.initialize``) is left alone.
 
-2. **XLA compile tracking** — a ``jax.monitoring`` duration listener
-   turns every backend compilation into a timeline span
-   (``xla_compile`` on this process's lane, stamped with the ambient
-   trace id) plus ``ray_tpu_xla_compiles_total`` and a duration
-   histogram.  The shipped ``xla-recompile-storm`` default alert
+2. **XLA compile tracking** — ``jax.monitoring`` listeners keep what
+   jax times of a jitted function's first call: the Python trace, the
+   lowering to MLIR and the backend compilation (a fetch from the
+   persistent cache included) as timeline spans ``xla_trace`` /
+   ``xla_lower`` / ``xla_compile`` on this process's lane, each with
+   jax's own start and end and its ``fun_name``, stamped with the
+   ambient trace id and parented to the ambient span; ``xla_compile``
+   says whether the cache answered (``cache_hit``) and what the fetch
+   took (``cache_fetch_s``).  Series: ``ray_tpu_xla_compiles_total``
+   and ``ray_tpu_xla_compile_seconds`` count the BACKEND's part alone,
+   as they always have (the recompile alert and the benchmark's
+   ``setup_compile_s`` read them), and
+   ``ray_tpu_xla_phase_seconds{phase=trace|lower|cache_fetch}`` the
+   host's parts beside it — where a start goes once every executable
+   is cached.  The shipped ``xla-recompile-storm`` default alert
    (observability/alerts.py) fires on a sustained compile rate — the
    "my bucketing is churning shapes" failure mode that silently turns
    a serving replica into a compile farm.
@@ -158,6 +168,14 @@ def _device_metrics():
             "ray_tpu_xla_compile_seconds",
             "XLA backend compilation wall time",
             boundaries=[0.01, 0.1, 0.5, 1.0, 5.0, 30.0, 120.0, 600.0]),
+        "xla_phase_seconds": _metrics.Histogram(
+            "ray_tpu_xla_phase_seconds",
+            "host time of a jitted function's first call, by phase: "
+            "trace (Python -> jaxpr, nested jits inside their caller's), "
+            "lower (jaxpr -> MLIR), cache_fetch (the persistent compile "
+            "cache's answer, part of the backend's seconds)",
+            boundaries=[0.01, 0.1, 0.5, 1.0, 5.0, 30.0, 120.0, 600.0],
+            tag_keys=("phase",)),
     })
 
 
@@ -304,7 +322,7 @@ def sample_once() -> Optional[List[Dict[str, Any]]]:
     tick; the gauges wait for the program's own backend."""
     if not _enabled:
         return None
-    _install_compile_listener()
+    install_compile_listener()
     samples = sample_devices()
     if samples is None:
         return None
@@ -362,14 +380,32 @@ def _sampler_loop(stop: threading.Event) -> None:
 _listener_installed = False
 _listener_lock = threading.Lock()
 
-# The jax.monitoring event that marks one XLA backend compilation.
-_COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+# What jax times of a jitted function's first call
+# (``dispatch.log_elapsed_time``: a scalar when the phase opens, a
+# duration and a time span when it closes, all on the calling thread),
+# and the fetch from the persistent cache, which fires inside the
+# backend's phase.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_FETCH_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SPAN_OF_EVENT = {_TRACE_EVENT: "xla_trace", _LOWER_EVENT: "xla_lower",
+                  _COMPILE_EVENT: "xla_compile"}
+# Per thread: how many traces and lowerings are open (a jitted function
+# called inside another's trace fires events of its own, and so does
+# every jnp function a lowering rule traces: hundreds a program, all
+# inside the phase that is the span), and the cache fetch heard since the
+# last backend phase closed.
+_heard = threading.local()
 
 
-def _install_compile_listener() -> None:
-    """Register the jax.monitoring duration listener once per process.
-    jax offers no unregister, so the callback itself gates on
-    ``_enabled`` (disable() must be a true no-op)."""
+def install_compile_listener() -> None:
+    """Register the jax.monitoring listeners once per process (after
+    the first call: one flag test).  Touches no backend, so a start
+    path calls it at entry (``LLMServer.__init__``, ``JaxTrainer.fit``)
+    and hears its own compilations; the sampler's tick is the fallback.
+    jax offers no unregister, so the callbacks gate on ``_enabled``
+    (disable() must be a true no-op)."""
     global _listener_installed
     if _listener_installed:
         return
@@ -383,30 +419,72 @@ def _install_compile_listener() -> None:
             from jax import monitoring
         except Exception:
             return
+        monitoring.register_scalar_listener(_on_xla_phase_open)
         monitoring.register_event_duration_secs_listener(_on_xla_event)
+        monitoring.register_event_time_span_listener(_on_xla_span)
         _listener_installed = True
 
 
+def _on_xla_phase_open(name: str, _start: float, **_kw) -> None:
+    if name == _TRACE_EVENT or name == _LOWER_EVENT:
+        _heard.open = getattr(_heard, "open", 0) + 1
+
+
 def _on_xla_event(name: str, duration_s: float, **_kw) -> None:
-    """One jax.monitoring duration event.  Only backend compiles are
-    counted (jaxpr tracing / MLIR lowering are host-side sub-phases of
-    the same compilation and would triple-count it)."""
-    if not _enabled or not name.endswith(_COMPILE_EVENT_SUFFIX):
+    """One jax.monitoring duration event: the backend's compilations
+    counted and timed, and the cache's answer kept for the span."""
+    if not _enabled:
         return
     try:
-        m = _device_metrics()
-        m["xla_compiles"].inc(tags={"kind": "backend_compile"})
-        m["xla_compile_seconds"].observe(float(duration_s))
+        if name == _COMPILE_EVENT:
+            m = _device_metrics()
+            m["xla_compiles"].inc(tags={"kind": "backend_compile"})
+            m["xla_compile_seconds"].observe(float(duration_s))
+        elif name == _CACHE_FETCH_EVENT:
+            _heard.cache_fetch_s = float(duration_s)
+            _device_metrics()["xla_phase_seconds"].observe(
+                float(duration_s), tags={"phase": "cache_fetch"})
+    except Exception:
+        pass  # telemetry must never break a compile
+
+
+def _on_xla_span(name: str, start: float, end: float, **kw) -> None:
+    """One phase closed, with jax's own ``time.time()`` readings of its
+    start and end: one timeline span (``xla_trace`` / ``xla_lower`` /
+    ``xla_compile``), and the host phases' series."""
+    span = _SPAN_OF_EVENT.get(name)
+    if span is None:
+        return
+    if span != "xla_compile":
+        _heard.open = still_open = max(0, getattr(_heard, "open", 1) - 1)
+        if still_open:
+            return   # inside a trace or a lowering, which is the span
+    if not _enabled:
+        return
+    try:
+        args: Dict[str, Any] = {"duration_s": round(end - start, 4),
+                                "fun_name": kw.get("fun_name")}
+        if span == "xla_compile":
+            fetch_s = getattr(_heard, "cache_fetch_s", None)
+            _heard.cache_fetch_s = None
+            args["cache_hit"] = fetch_s is not None
+            args["cache_fetch_s"] = round(fetch_s or 0.0, 4)
+        else:
+            _device_metrics()["xla_phase_seconds"].observe(
+                end - start, tags={"phase": span[len("xla_"):]})
         from . import tracing
+
+        if not tracing.enabled():
+            return
         from .timeline import process_pid, record_span
 
-        now = time.time()
-        args: Dict[str, Any] = {"duration_s": round(duration_s, 4)}
         ctx = tracing.current()
         if ctx is not None:
             args["trace_id"] = ctx[0]
-        record_span("xla_compile", now - duration_s, now,
-                    pid=process_pid(), tid="xla-compile", args=args)
+            if ctx[1]:
+                args["parent_span_id"] = ctx[1]
+        record_span(span, start, end, pid=process_pid(),
+                    tid="xla-compile", args=args)
     except Exception:
         pass  # telemetry must never break a compile
 

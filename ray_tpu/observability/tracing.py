@@ -27,6 +27,7 @@ ids → None, spans untagged).
 
 from __future__ import annotations
 
+import functools
 import itertools
 import os
 import random
@@ -126,6 +127,9 @@ class span:
 
         with tracing.span("dag.execute"):
             ...  # submissions inherit the trace
+
+    As a decorator (``@tracing.span("serve.engine_start")``) every call
+    of the function runs inside a span of its own of that name.
     """
 
     __slots__ = ("name", "args", "trace_id", "span_id",
@@ -134,6 +138,15 @@ class span:
     def __init__(self, name: str, args: Optional[Dict[str, Any]] = None):
         self.name = name
         self.args = args
+
+    def __call__(self, fn):
+        name, args = self.name, self.args
+
+        @functools.wraps(fn)
+        def spanned(*a, **kw):
+            with span(name, args):
+                return fn(*a, **kw)
+        return spanned
 
     def __enter__(self) -> "span":
         if not _enabled:

@@ -244,6 +244,24 @@ def _group_fits(rows: int, bucket: int, rungs: Tuple[int, ...]) -> bool:
     return rows == rungs[0] or rows * bucket <= _GROUP_POSITIONS
 
 
+def _with_stack_room(fn):
+    """``fn()``, from a frame with room under it.  CPython (3.11+) keeps a
+    thread's frames in 16 KB chunks and frees a chunk when its first frame
+    returns: a loop of short calls whose frames fall across a chunk's end
+    maps and unmaps memory at every call -- 140 us a call on the chip's
+    host, and jax's tracing and lowering make such calls (``__hash__``,
+    ``__eq__`` from a dict lookup) by the ten thousand.  Where the chunks
+    end depends on the size of every frame above, so any edit to a
+    function on warm-up's call path moved a hybrid model's cached start by
+    a quarter (PERF.md section 6, PR 51).  This frame is 256 KB, gets a
+    512 KB chunk of its own, and what runs under it meets no chunk's end."""
+    return fn()
+
+
+_with_stack_room.__code__ = _with_stack_room.__code__.replace(
+    co_stacksize=1 << 15)
+
+
 def prefill_shapes(rungs: Tuple[int, ...], buckets: Tuple[int, ...],
                    max_slots: int) -> List[Tuple[int, int]]:
     """Every ``(rows, bucket)`` a wave's cut can come out with, which is
@@ -297,6 +315,7 @@ class LLMServer:
     Greedy argmax decoding (serving an untrained model for the perf
     bench; plug a checkpoint via ``params``)."""
 
+    @_tracing.span("serve.engine_start")
     def __init__(self, model_preset: str = "llama_125m",
                  max_slots: int = 64, max_len: int = 512,
                  prefill_buckets=(32, 64, 128, 256), params=None,
@@ -328,6 +347,14 @@ class LLMServer:
         weights, Draft&Verify-style early exit)."""
         import jax
         import jax.numpy as jnp
+
+        # The start's own account (docs/observability.md, "Where a start
+        # goes"): serve.engine_start around this constructor, its build
+        # and warm-up under it, every program's xla_* spans under those.
+        # The build's span is left by hand at `if warmup:`; should the
+        # build raise, serve.engine_start's exit restores the context.
+        _device.install_compile_listener()
+        build = _tracing.span("serve.engine_build").__enter__()
 
         from ray_tpu.models import llama, llama_serve
 
@@ -512,8 +539,10 @@ class LLMServer:
         self._chunk_ema: Optional[float] = None
         self._prefill_ema: Optional[float] = None
 
+        build.__exit__()
         if warmup:
-            self._warmup()
+            with _tracing.span("serve.warmup"):
+                _with_stack_room(self._warmup)
 
         self._queue: "queue.Queue[_Request]" = queue.Queue()
         # Engine ingress bound: the serve replica mailbox
@@ -674,10 +703,21 @@ class LLMServer:
 
         def warm(name, program, *args, **static):
             # With tracing on, the shapes of each warmed program are kept
-            # for device.program_scopes (nothing is lowered here).
+            # for device.program_scopes (nothing is lowered here), and the
+            # call is a span: the HOST's seconds in it (trace, lower, cache
+            # fetch, load, dispatch -- it returns when the program is
+            # enqueued), with jax's xla_* spans inside.
+            attrs = None
             if _tracing.enabled():
                 _device.register_program(name, program, args, **static)
-            return program(*args, **static)
+                attrs = {"program": name, **static}
+                if "prefill" in name:   # (params, cache, tokens[rows, bucket]..
+                    attrs["rows"], attrs["bucket"] = args[2].shape
+            # ONE call site, traced or not: a Mosaic kernel's serialized body
+            # carries its callers' lines, this one among them, and with it
+            # the program's key in the persistent compile cache.
+            with _tracing.span("serve.warm_program", attrs):
+                return program(*args, **static)
 
         firsts = {}    # rung -> first tokens as a prefill returns them
         for g, bucket in prefill_shapes(self.prefill_groups, self.buckets,
@@ -741,7 +781,8 @@ class LLMServer:
                         "serve.draft_propose", self._draft_propose,
                         self.draft_params, self.draft_cache, ov, ov,
                         active, k=self.spec_k, s_active=int(sa))
-            jax.block_until_ready(self.pool["k"])
+            with _tracing.span("serve.warm_wait"):
+                jax.block_until_ready(self.pool["k"])
         else:
             for sa in self.decode_buckets:
                 self.cache, _t, self._tok_dev, self._len_dev, _m = \
@@ -751,7 +792,8 @@ class LLMServer:
                          ov, ovm, active,
                          k=self.decode_chunk,
                          s_active=int(sa))
-            jax.block_until_ready(self.cache)
+            with _tracing.span("serve.warm_wait"):
+                jax.block_until_ready(self.cache)
         if self._seat is not None:
             # One shape a rung, on the carries the decode programs
             # returned; every row padding, so nothing is seated.

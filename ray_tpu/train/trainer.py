@@ -14,14 +14,34 @@ classes.
 
 from __future__ import annotations
 
+import functools
 import os
 import tempfile
+import threading
 from typing import Any, Callable, Dict, Optional
 
+from ..observability import device as _device
+from ..observability import timeline as _timeline
+from ..observability import tracing as _tracing
 from .checkpoint import Checkpoint, CheckpointManager
 from .config import (CheckpointConfig, FailureConfig, Result, RunConfig,
                      ScalingConfig)
 from .worker_group import WorkerGroup, _ReportCollector
+
+
+def _enter_loop(loop_fn: Callable, t_fit: float, trace_id: str, *config):
+    """What a worker calls in the user's loop's place with tracing on
+    (under the loop's own signature): the span ``train.worker_start``,
+    ``fit()`` entered (the driver's clock) -> here (worker group up,
+    mesh built, dataset shards handed out), then the loop.  The first
+    step's compilation accounts for itself (``xla_trace`` /
+    ``xla_lower`` / ``xla_compile``)."""
+    _timeline.record_span(
+        "train.worker_start", t_fit, _timeline.now(),
+        pid=_timeline.process_pid(),
+        tid=threading.current_thread().name,
+        args={"trace_id": trace_id, "span_id": _tracing.new_span_id()})
+    return loop_fn(*config)
 
 
 class JaxTrainer:
@@ -49,6 +69,12 @@ class JaxTrainer:
     def fit(self) -> Result:
         import ray_tpu
 
+        _device.install_compile_listener()
+        loop = self.train_loop_per_worker
+        if _tracing.enabled():
+            loop = functools.update_wrapper(functools.partial(
+                _enter_loop, loop, _timeline.now(),
+                _tracing.for_submission()[0]), loop)
         if not ray_tpu.is_initialized():
             ray_tpu.init()
 
@@ -128,7 +154,7 @@ class JaxTrainer:
                         else:
                             datasets[key] = d
                 refs = group.run_all_async(
-                    "run", self.train_loop_per_worker,
+                    "run", loop,
                     self.train_loop_config, self.scaling_config.mesh,
                     collector, name, storage, datasets,
                     latest_ckpt.path if latest_ckpt else None,

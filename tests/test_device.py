@@ -569,3 +569,372 @@ class TestServeEngineSeries:
             assert got.get("llm,decode_chunk", 0) > 0
         finally:
             eng.shutdown()
+
+
+# ------------------------------------------ where a start's seconds go
+def _spans(since=0.0, names=None):
+    return [e for e in timeline_mod.export_timeline(None)
+            if e.get("ph") == "X" and e["ts"] >= since * 1e6
+            and (names is None or e["name"] in names)]
+
+
+XLA_SPANS = ("xla_trace", "xla_lower", "xla_compile")
+
+
+class TestCompilePhases:
+    def test_first_call_writes_one_span_a_phase_under_the_ambient_span(
+            self):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.observability import tracing
+
+        device_mod.install_compile_listener()
+
+        @jax.jit
+        def inner(v):
+            return jnp.where(v > 0, v, 0.0) * 3.25
+
+        @jax.jit
+        def phases_probe(v):     # nested jits, jnp functions: one trace
+            return jnp.sum(inner(v) + jnp.einsum("ij,jk->ik", v, v))
+
+        x = jnp.ones((5, 5))
+        x.block_until_ready()
+        t0 = time.time()
+        with tracing.span("test.phases") as sp:
+            phases_probe(x).block_until_ready()
+            mine = _spans(t0, XLA_SPANS)
+            phases_probe(x).block_until_ready()   # cached: nothing
+            assert _spans(t0, XLA_SPANS) == mine
+        assert [e["name"] for e in mine] == list(XLA_SPANS)
+        assert [e["args"]["fun_name"] for e in mine] == \
+            ["phases_probe", "jit(phases_probe)", "jit(phases_probe)"]
+        outer, = _spans(t0, ("test.phases",))
+        end = 0.0
+        for e in mine:
+            assert e["tid"] == "xla-compile"
+            assert e["args"]["trace_id"] == sp.trace_id
+            assert e["args"]["parent_span_id"] == sp.span_id
+            assert e["args"]["duration_s"] == pytest.approx(
+                e["dur"] * 1e-6, abs=1e-4)
+            # jax's own start and end: inside the ambient span, one
+            # phase after the other (1 ms: time.time() beside the ring's
+            # perf_counter clock)
+            assert e["ts"] >= max(end, outer["ts"]) - 1e3
+            end = e["ts"] + e["dur"]
+        assert end <= outer["ts"] + outer["dur"] + 1e3
+        assert mine[2]["args"]["cache_hit"] is False
+        assert mine[2]["args"]["cache_fetch_s"] == 0.0
+
+    def test_one_compile_span_a_compilation_and_the_sums_are_the_backends(
+            self):
+        import jax
+        import jax.numpy as jnp
+
+        device_mod.install_compile_listener()
+        x = jnp.ones(29)
+        x.block_until_ready()
+
+        def read():
+            s = metrics_mod.metrics_summary()
+            return (s.get("ray_tpu_xla_compiles_total", {}).get(
+                        "backend_compile", 0.0),
+                    sum(s.get("ray_tpu_xla_compile_seconds", {}).values()),
+                    dict(s.get("ray_tpu_xla_phase_seconds", {})))
+
+        count0, seconds0, phases0 = read()
+        t0 = time.time()
+        for i in range(3):
+            jax.jit(lambda v, i=i: v * (i + 7.5))(x).block_until_ready()
+        count1, seconds1, phases1 = read()
+        spans = _spans(t0, XLA_SPANS)
+        by_name = {n: [e for e in spans if e["name"] == n]
+                   for n in XLA_SPANS}
+        assert [len(by_name[n]) for n in XLA_SPANS] == [3, 3, 3]
+        assert count1 - count0 == 3
+        # the histogram sums the backend's seconds, as it always has:
+        # neither trace nor lowering nor the cache's fetch is added
+        assert seconds1 - seconds0 == pytest.approx(
+            sum(e["dur"] for e in by_name["xla_compile"]) * 1e-6,
+            abs=1e-3)
+        for phase in ("trace", "lower"):
+            assert phases1[phase] - phases0.get(phase, 0.0) == \
+                pytest.approx(sum(e["dur"] for e in by_name[
+                    "xla_" + phase]) * 1e-6, abs=1e-3)
+        assert set(phases1) <= {"trace", "lower", "cache_fetch"}
+
+    def test_a_cache_fetch_is_kept_for_the_compile_span(self):
+        """The cache's events fire inside the backend's phase, on its
+        thread: the span that closes it says what they said."""
+        device_mod.install_compile_listener()
+        t0 = time.time() - 60.0     # (spans of the past: none leaks on)
+        before = metrics_mod.metrics_summary().get(
+            "ray_tpu_xla_phase_seconds", {}).get("cache_fetch", 0.0)
+        device_mod._on_xla_event(device_mod._CACHE_FETCH_EVENT, 0.125)
+        device_mod._on_xla_event(device_mod._COMPILE_EVENT, 0.25)
+        device_mod._on_xla_span(device_mod._COMPILE_EVENT, t0, t0 + 0.25,
+                                fun_name="jit(fetched)")
+        device_mod._on_xla_span(device_mod._COMPILE_EVENT, t0 + 0.5,
+                                t0 + 0.75, fun_name="jit(compiled)")
+        fetched, compiled = [
+            e for e in _spans(t0, ("xla_compile",))
+            if e["args"]["fun_name"] in ("jit(fetched)", "jit(compiled)")]
+        assert (fetched["args"]["cache_hit"],
+                fetched["args"]["cache_fetch_s"]) == (True, 0.125)
+        assert (compiled["args"]["cache_hit"],
+                compiled["args"]["cache_fetch_s"]) == (False, 0.0)
+        assert metrics_mod.metrics_summary()[
+            "ray_tpu_xla_phase_seconds"]["cache_fetch"] - before == 0.125
+
+    @pytest.mark.parametrize("off", ["tracing", "telemetry"])
+    def test_gates(self, off, monkeypatch):
+        """Spans under tracing, series under the device plane."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.observability import tracing
+
+        device_mod.install_compile_listener()
+        x = jnp.ones(31)
+        x.block_until_ready()
+        monkeypatch.setattr(
+            *{"tracing": (tracing, "_enabled", False),
+              "telemetry": (device_mod, "_enabled", False)}[off])
+        before = metrics_mod.metrics_summary()
+        t0 = time.time()
+        jax.jit(lambda v: v * 11.25 - 2)(x).block_until_ready()
+        assert _spans(t0, XLA_SPANS) == []
+        after = metrics_mod.metrics_summary()
+        grew = after["ray_tpu_xla_compiles_total"]["backend_compile"] \
+            - before["ray_tpu_xla_compiles_total"]["backend_compile"]
+        assert grew == (1 if off == "tracing" else 0)
+        assert (after["ray_tpu_xla_phase_seconds"]
+                != before["ray_tpu_xla_phase_seconds"]) == (off == "tracing")
+
+
+START_SPANS = ("serve.engine_start", "serve.engine_build", "serve.warmup",
+               "serve.warm_program", "serve.warm_wait")
+START_ENGINE = dict(model_preset="debug", max_slots=4, max_len=64,
+                    prefill_buckets=(16,), decode_chunk=8,
+                    prefill_groups=(2, 4))
+START_PLANES = {"dense": {}, "paged": dict(paged=True, block_size=8)}
+
+
+def _inside(child, parent, slack_us=1e3):
+    return (child["ts"] >= parent["ts"] - slack_us
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + slack_us)
+
+
+class TestEngineStartSpans:
+    @pytest.mark.parametrize("plane", sorted(START_PLANES))
+    def test_a_start_is_a_tree_of_spans(self, plane):
+        from ray_tpu.serve import llm
+
+        t0 = time.time()
+        eng = llm.LLMServer(**START_ENGINE, **START_PLANES[plane])
+        try:
+            spans = _spans(t0, START_SPANS + XLA_SPANS)
+            shapes = llm.prefill_shapes(eng.prefill_groups, eng.buckets,
+                                        eng.max_slots)
+            rungs = sorted({g for g, _ in shapes})
+            if plane == "paged":
+                warmed = [(name, g, b) for g, b in shapes for name in (
+                    "serve.prefill_cold", "serve.prefill_warm")]
+                warmed += [("serve.decode_paged", None, None)
+                           ] * len(eng._nb_buckets)
+            else:
+                warmed = [("serve.prefill", g, b) for g, b in shapes]
+                warmed += [("serve.decode_k", None, None)
+                           ] * len(eng.decode_buckets)
+            warmed += [("serve.seat", None, None)] * len(rungs)
+        finally:
+            eng.shutdown()
+        one = {n: [e for e in spans if e["name"] == n] for n in START_SPANS}
+        start, = one["serve.engine_start"]
+        build, = one["serve.engine_build"]
+        warmup, = one["serve.warmup"]
+        wait, = one["serve.warm_wait"]
+        programs = one["serve.warm_program"]
+        assert [(e["args"]["program"], e["args"].get("rows"),
+                 e["args"].get("bucket")) for e in programs] == warmed
+        decode = [e["args"] for e in programs
+                  if e["args"]["program"].startswith("serve.decode")]
+        assert all(a["k"] == 8 for a in decode)
+        if plane == "dense":
+            assert [a["s_active"] for a in decode] == \
+                list(eng.decode_buckets)
+        # one trace, children name their parents, inside them on the clock
+        assert {e["args"]["trace_id"] for e in spans} == \
+            {start["args"]["trace_id"]}
+        assert "parent_span_id" not in start["args"]
+        for child, parent in [(build, start), (warmup, start),
+                              (wait, warmup)] + [(p, warmup)
+                                                 for p in programs]:
+            assert child["args"]["parent_span_id"] == \
+                parent["args"]["span_id"]
+            assert _inside(child, parent)
+        assert build["ts"] + build["dur"] <= warmup["ts"] + 1e3
+        by_id = {e["args"]["span_id"]: e for e in spans
+                 if e["name"] in START_SPANS}
+        phases = [e for e in spans if e["name"] in XLA_SPANS]
+        for e in phases:    # every compilation of the start has a home
+            assert _inside(e, by_id[e["args"]["parent_span_id"]])
+        for p in programs:  # each warmed call: its three phases, once
+            mine = [e["name"] for e in phases
+                    if e["args"]["parent_span_id"] == p["args"]["span_id"]]
+            assert mine == list(XLA_SPANS), (p["args"], mine)
+            assert p["dur"] >= sum(
+                e["dur"] for e in phases
+                if e["args"]["parent_span_id"] == p["args"]["span_id"])
+
+    @pytest.mark.parametrize("plane", sorted(START_PLANES))
+    def test_tracing_off_is_the_same_start_with_no_span(self, plane,
+                                                       monkeypatch):
+        """Warm-up calls the same programs with the same shapes in the
+        same order, and waits once, whether tracing is on or off; off,
+        nothing is written and nothing registered."""
+        import jax
+
+        from ray_tpu.observability import tracing
+        from ray_tpu.serve import llm
+
+        calls, waits = [], []
+        real_wait = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready",
+            lambda x: (waits.append(len(calls)), real_wait(x))[1])
+
+        def counted(name, program):
+            def call(*args, **static):
+                calls.append((name, tuple(
+                    getattr(a, "shape", None) for a in args[2:]),
+                    tuple(sorted(static.items()))))
+                return program(*args, **static)
+            return call
+
+        def start(traced):
+            monkeypatch.setattr(tracing, "_enabled", traced)
+            device_mod.clear_programs()
+            eng = llm.LLMServer(**{**START_ENGINE, "max_slots": 2,
+                                   "prefill_groups": (2,)},
+                                **START_PLANES[plane], warmup=False)
+            for attr in ("_prefill", "_decode_k", "_prefill_cold",
+                         "_prefill_warm", "_decode_paged", "_inject",
+                         "_seat"):
+                if getattr(eng, attr, None) is not None:
+                    setattr(eng, attr, counted(attr, getattr(eng, attr)))
+            del calls[:], waits[:]
+            t0 = time.time()
+            try:
+                eng._warmup()
+                return (list(calls), list(waits),
+                        _spans(t0, START_SPANS + XLA_SPANS),
+                        device_mod.registered_programs())
+            finally:
+                eng.shutdown()   # (waits once more)
+
+        on_calls, on_waits, on_spans, on_registered = start(True)
+        off_calls, off_waits, off_spans, off_registered = start(False)
+        assert off_calls == on_calls and len(off_calls) >= 3
+        # the one wait, after the last decode program and before the seats
+        assert off_waits == on_waits and len(off_waits) == 1
+        assert off_spans == [] and off_registered == []
+        assert len([e for e in on_spans
+                    if e["name"] == "serve.warm_program"]) == \
+            len(on_registered) == len(
+                [c for c in on_calls if c[0] != "_inject"])
+
+
+class TestWarmupStackRoom:
+    """Warm-up runs under one frame with room (``llm._with_stack_room``):
+    CPython frees a 16 KB chunk of frames when its first frame returns,
+    so a hot call at a chunk's end maps and unmaps memory every time."""
+
+    def test_it_calls_and_hands_back(self):
+        from ray_tpu.serve import llm
+
+        assert llm._with_stack_room(lambda: 7) == 7
+        with pytest.raises(KeyError):
+            llm._with_stack_room(lambda: {}["x"])
+
+    @pytest.mark.skipif(sys.implementation.name != "cpython",
+                        reason="CPython's data-stack chunks")
+    def test_no_depth_under_it_meets_a_chunks_end(self):
+        from ray_tpu.serve import llm
+
+        class Key:      # a dict lookup calls back into Python, as jax's do
+            def __hash__(self):
+                # a frame larger than at_depth's: one depth a chunk then
+                # has room for lookups' frame and none for this one
+                a = b = c = d = e = f = g = h = 1
+                return a
+
+        key = Key()
+        table = {key: 0}
+
+        def lookups():
+            t = time.perf_counter()
+            for _ in range(2000):
+                table[key]
+            return time.perf_counter() - t
+
+        def at_depth(n):
+            return at_depth(n - 1) if n else min(lookups(), lookups())
+
+        # 300 of at_depth's frames are two chunks and more: without room
+        # one of these depths pays the chunk at every lookup (20-100x)
+        plain = max(at_depth(n) for n in range(300))
+        roomy = max(llm._with_stack_room(lambda n=n: at_depth(n))
+                    for n in range(300))
+        assert roomy < 0.5 * plain
+
+    def test_warm_up_runs_under_it(self, monkeypatch):
+        from ray_tpu.serve import llm
+
+        under = []
+        real = llm._with_stack_room
+        monkeypatch.setattr(
+            llm, "_with_stack_room",
+            lambda fn: (under.append(fn.__name__), real(fn))[1])
+        eng = llm.LLMServer(**START_ENGINE)
+        eng.shutdown()
+        assert under == ["_warmup"]
+
+
+class TestTrainerStartSpan:
+    @pytest.mark.parametrize("takes_config", [True, False])
+    def test_worker_start_ends_where_the_loop_begins(self, shutdown_only,
+                                                     takes_config):
+        from ray_tpu.train import JaxTrainer, ScalingConfig
+
+        entered = []
+        if takes_config:
+            def loop(config):
+                entered.append((time.time(), config["x"]))
+        else:
+            def loop():
+                entered.append((time.time(), 5))
+        t0 = time.time()
+        JaxTrainer(loop, train_loop_config={"x": 5},
+                   scaling_config=ScalingConfig(num_workers=1)).fit()
+        (t_loop, x), = entered
+        assert x == 5
+        span, = _spans(t0, ("train.worker_start",))
+        assert span["args"]["trace_id"] and span["args"]["span_id"]
+        assert t0 * 1e6 - 1e3 <= span["ts"]
+        assert span["ts"] + span["dur"] == pytest.approx(
+            t_loop * 1e6, abs=50e3)
+
+    def test_no_span_with_tracing_off(self, shutdown_only, monkeypatch):
+        from ray_tpu.observability import tracing
+        from ray_tpu.train import JaxTrainer, ScalingConfig
+
+        monkeypatch.setattr(tracing, "_enabled", False)
+        entered = []
+        t0 = time.time()
+        JaxTrainer(lambda: entered.append(1),
+                   scaling_config=ScalingConfig(num_workers=1)).fit()
+        assert entered == [1]
+        assert _spans(t0, ("train.worker_start",)) == []
