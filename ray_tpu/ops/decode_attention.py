@@ -31,7 +31,18 @@ whole stack:
   nothing.
 
 GQA is ``group = Hq // Hkv`` query heads per kv head in ``bias``; MHA is
-``group == 1``.  ``block_k`` follows from the bytes of a position.
+``group == 1``.  ``block_k`` follows from the bytes of a position: the
+power of two NEAREST to ``_BLOCK_BYTES``, not the one below.  What a block
+costs beside its bytes is the latency of its chain -- wait, ``q . K``,
+max, exp, ``P @ V``: 0.7-0.9 us on a v5e, and twice the score tile adds
+0.17 -- so a block whose DMA is shorter than that reads at the chain's
+pace: 10 kv heads' 102 positions rounded DOWN to 64 (0.4 us of DMA) read
+0.55 of the HBM peak, rounded to 128 they read 0.89.  A second score tile
+that multiplies a kv head's rows against its own query heads alone (a
+strided 32-bit view of the packed block) shortens the chain by 0.13 us
+and, at the same 128 positions, reads 0.91: not worth its lines
+(PERF.md section 6, PR 52; ``tools/decode_attention_sweep.py`` times the
+kernel alone at every cell's shapes over several blocks).
 
 A cache of kv heads that do not fill a sublane tile (4 of them) is handed
 over already AS rows, ``(L, B, S * Hkv, D)``: stored with a dimension of
@@ -77,10 +88,12 @@ _HEAD_TILE = 16
 
 
 def block_k(s: int, hkv: int, d: int, itemsize: int) -> int:
-    """Positions per block: the power of two whose K fills
-    ``_BLOCK_BYTES``, at most the cache's length."""
+    """Positions per block: the power of two whose K is nearest to
+    ``_BLOCK_BYTES`` (102 positions are 128, 85 are 64), at most the
+    cache's length."""
     bk = max(8, _BLOCK_BYTES // (hkv * d * itemsize))
-    return min(1 << (bk.bit_length() - 1), s)
+    low = 1 << (bk.bit_length() - 1)
+    return min(2 * low if 2 * bk >= 3 * low else low, s)
 
 
 def _tiles(hkv: int, d: int, as_rows: bool = False) -> bool:

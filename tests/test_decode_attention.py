@@ -15,7 +15,21 @@ _SHAPES = {
     "layer_2_of_a_stack": (3, 8, 128, 4, 2, 16, 2, 128),
     "bucket_shorter_than_cache": (2, 8, 512, 16, 8, 128, 1, 256),
     "block_does_not_divide_cache": (1, 8, 200, 16, 8, 128, 0, 200),
+    # the benchmark's geometries the cases above lack, handed over AS rows
+    # (``_AS_ROWS``): cell 11's paired rows with a cache length its block
+    # does not divide, cell 7's, cell 10's with a learned selection
+    # (``_KEPT``), cell 11's ring with rows longer than the ring; and ten
+    # rows a position under a selection, which no cell runs yet
+    "paired_rows_40_10_x128": (2, 8, 200, 40, 10, 128, 1, 200),
+    "rows_28_4_x128": (1, 8, 512, 28, 4, 128, 0, 512),
+    "rows_32_4_x128_with_keep": (2, 8, 512, 32, 4, 128, 1, 384),
+    "ring_512_of_longer_rows": (2, 8, 512, 40, 10, 128, 1, 16384),
+    "paired_rows_with_keep": (1, 8, 200, 40, 10, 128, 0, 160),
 }
+_AS_ROWS = {"paired_rows_40_10_x128", "rows_28_4_x128",
+            "rows_32_4_x128_with_keep", "ring_512_of_longer_rows",
+            "paired_rows_with_keep"}
+_KEPT = {"rows_32_4_x128_with_keep", "paired_rows_with_keep"}
 _TOL = 2e-2       # bf16: eight bits of mantissa on values of order one
 
 
@@ -23,6 +37,17 @@ def _lengths(bk, s_active):
     """0, 1, around a block's edge, the bucket's last position, past it."""
     return np.asarray([0, 1, bk - 1, bk, bk + 1, s_active - 1,
                        s_active, s_active + 7], np.int32)
+
+
+def _kept(name, B, s_active):
+    """A learned selection for the shapes that take one: four keys in ten,
+    a row's first among them, none of a row's first block but one."""
+    if name not in _KEPT:
+        return None
+    keep = np.random.default_rng(len(name)).random((B, s_active)) < 0.4
+    keep[3, :min(300, s_active - 8)] = False
+    keep[:, 0] = True
+    return keep
 
 
 def _inputs(shape, seed=0):
@@ -36,14 +61,18 @@ def _inputs(shape, seed=0):
             jax.random.normal(kv, (L, B, S, hkv, d), jnp.bfloat16))
 
 
-def _reference(q, ck, cv, layer, lens, active, s_active):
+def _reference(q, ck, cv, layer, lens, active, s_active, keep=None):
     import jax.numpy as jnp
 
     from ray_tpu.models import llama
 
+    s_active = min(s_active, ck.shape[2])
+    selection = () if keep is None else (
+        jnp.broadcast_to(jnp.arange(s_active), keep.shape),
+        jnp.asarray(keep))
     out = llama._cache_attend(
         q[:, None], ck[layer, :, :s_active], cv[layer, :, :s_active],
-        lens[:, None], q.shape[-1] ** -0.5)[:, 0]
+        lens[:, None], q.shape[-1] ** -0.5, *selection)[:, 0]
     return jnp.where(active[:, None, None], out, 0)
 
 
@@ -64,17 +93,23 @@ def test_kernel_agrees_with_cache_attend(name, rows):
     active = jnp.asarray(
         [True, False, True, True, False, True, True, False]
         if rows == "some_inactive" else [True] * B)
-    want = _reference(q, ck, cv, layer, lens, active, s_active)
+    keep = _kept(name, B, s_active)
+    want = _reference(q, ck, cv, layer, lens, active, s_active, keep)
     if rows == "nan_past_the_length":
         # What lies past a row's last key must be masked by selection:
         # a probability of zero times NaN is NaN.
         past = jnp.arange(S)[None, :] > lens[:, None]
         ck, cv = (jnp.where(past[None, :, :, None, None], jnp.nan, c)
                   for c in (ck, cv))
+    as_rows = {}
+    if name in _AS_ROWS:
+        ck, cv = (c.reshape(L, B, S * hkv, d) for c in (ck, cv))
+        as_rows = dict(hkv=hkv)
     got = jax.jit(da.decode_attention,
-                  static_argnames=("s_active", "scale"))(
+                  static_argnames=("s_active", "scale", "hkv"))(
         q, ck, cv, jnp.int32(layer), lens, active, s_active=s_active,
-        scale=d ** -0.5)
+        scale=d ** -0.5, keep=None if keep is None else jnp.asarray(keep),
+        **as_rows)
     assert got.shape == want.shape and got.dtype == cv.dtype
     got, want = (np.asarray(x, np.float32) for x in (got, want))
     assert np.isfinite(got).all()
@@ -126,6 +161,74 @@ def test_block_follows_from_the_bytes_of_a_position():
     assert block_k(1280, 8, 128, 2) == 128
     assert block_k(64, 2, 16, 2) == 64          # a toy: the whole cache
     assert block_k(512, 8, 128, 4) == 64        # a float32 cache
+
+
+def test_the_block_is_the_power_of_two_nearest_to_its_bytes():
+    """Where the bytes of a position give a power of two the block is what
+    it was (cells 3, 4 and 5: PR 29's fit; cells 7 and 10); between two,
+    the nearer and not the lower: cell 11's 102 positions of 10 rows are
+    128, whose DMA outlasts the block's chain, where 64 read at the
+    chain's pace (PERF.md section 6, PR 52)."""
+    from ray_tpu.ops.decode_attention import block_k
+
+    assert block_k(512, 8, 128, 2) == 128         # cell 3
+    assert block_k(1280, 8, 128, 2) == 128        # cell 4
+    assert block_k(512, 16, 128, 2) == 64         # cell 5
+    assert block_k(16384, 4, 128, 2) == 256       # cells 7 and 10
+    assert block_k(4096, 4, 128, 2) == 256        # cell 7's rings
+    assert block_k(16384, 10, 128, 2) == 128      # cell 11's pool: 102
+    assert block_k(512, 10, 128, 2) == 128        # and its rings
+    assert block_k(16384, 12, 128, 2) == 64       # 85: the lower is nearer
+    assert block_k(16384, 6, 128, 2) == 128       # 170
+    assert block_k(16384, 5, 128, 2) == 256       # 204
+    assert block_k(16384, 10, 128, 4) == 64       # 51, a float32 cache
+    assert block_k(100, 10, 128, 2) == 100        # the whole cache
+
+
+def test_the_benchmarks_cells_take_the_block_their_shapes_say():
+    """``block_k`` sees four numbers, none of them a model's name, and the
+    benchmark's cells that run this kernel take the block their shapes
+    say -- read from the configuration files as the benchmark builds its
+    programs."""
+    import inspect
+    import json
+    import os
+
+    import jax.numpy as jnp
+
+    from benchmarks.lib import program
+    from ray_tpu.ops import decode_attention as da
+
+    assert list(inspect.signature(da.block_k).parameters) == \
+        ["s", "hkv", "d", "itemsize"]
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+
+    def load(*path):
+        with open(os.path.join(root, *path)) as f:
+            return json.load(f)
+
+    taken = {}
+    for entry in load("BENCHMARK.json")["workloads"]:
+        cell = load("benchmarks", "workloads", entry["name"] + ".json")
+        if "engine" not in cell:
+            continue                                  # a train cell
+        cfg = program.llama_config(
+            load("benchmarks", "configs", entry["config"] + ".json"))
+        hkv, d = cfg.kv_row_heads, cfg.kv_row_dim
+        if cfg.kv_lora_rank or not da._tiles(hkv, d, as_rows=True):
+            continue            # latent attention's own kernel; XLA's
+        taken[entry["name"]] = (
+            cfg.n_heads, hkv, d,
+            da.block_k(cell["engine"]["max_len"], hkv, d,
+                       jnp.dtype(cfg.dtype).itemsize))
+    assert taken.items() >= {
+        "internlm2-1.8b.serve-batch-decode": (16, 8, 128, 128),
+        "internlm2-1.8b.serve-chat-busy": (16, 8, 128, 128),
+        "olmoe-1b-7b.serve-batch-decode": (16, 16, 128, 64),
+        "smallthinker-21b-a3b.serve-long-prompt": (28, 4, 128, 256),
+        "keye-vl-2.0-30b-a3b.serve-long-prompt": (32, 4, 128, 256),
+        "phi-4-mini-flash-reasoning.serve-long-prompt": (40, 10, 128, 128),
+    }.items()
 
 
 def test_interpret_is_asked_of_flash_attention_at_call_time(monkeypatch):
