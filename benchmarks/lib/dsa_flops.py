@@ -9,14 +9,19 @@ A configuration is the dict of ``benchmarks/configs/<name>.json``
 key of that width a token, ``topk`` keys attended a query); every layer has
 ``num_experts`` experts of ``moe_intermediate_size`` (``intermediate_size``
 is the published width of a dense FFN no layer has, which is why
-``lib/moe_flops.py``, that reads it as an expert's, does not count this
-model).  A multiply-add counts as 2 FLOPs; bytes are ``dtype_bytes`` a value
-(bfloat16).
+``lib/moe_flops.py``'s whole-step counts, that read it as an expert's, are
+not this model's; its counts of the grouped matmuls alone are, and are used
+here).  A multiply-add counts as 2 FLOPs; bytes are ``dtype_bytes`` a value
+(bfloat16).  ``decode_step_least_s`` is the floor the configuration's file
+names under ``roofline``.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable
+from typing import Any, Dict, Iterable, Optional
+
+# the grouped matmuls' own counts are every expert configuration's
+from .moe_flops import expert_matmul_flops, expert_params
 
 
 def attention_matmul_params(c: Dict[str, Any]) -> int:
@@ -32,11 +37,6 @@ def indexer_params(c: Dict[str, Any]) -> int:
     sa = c["sa_config"]
     heads, dim = sa["indexer_num_heads"], sa["indexer_head_dim"]
     return c["hidden_size"] * (heads * dim + dim + heads)
-
-
-def expert_params(c: Dict[str, Any]) -> int:
-    """One expert's three matrices."""
-    return 3 * c["hidden_size"] * c["moe_intermediate_size"]
 
 
 def router_params(c: Dict[str, Any]) -> int:
@@ -132,20 +132,23 @@ def decode_step_flops(c: Dict[str, Any], lengths: Iterable[float],
         + expert_matmul_flops(c, expert_rows)
 
 
-def expert_matmul_bytes(c: Dict[str, Any], experts_touched: float,
-                        expert_rows: float, dtype_bytes: int = 2) -> float:
-    """Least HBM traffic of a step's grouped matmuls alone: the three
-    matrices of each (layer, expert) pair that has a row, once, and each
-    row's activations (in at width h twice, the hidden row of width f out
-    twice and in once, out at width h once: ``lib/moe_flops.py``'s account,
-    at an expert's own width)."""
-    h, f = c["hidden_size"], c["moe_intermediate_size"]
-    return (experts_touched * expert_params(c)
-            + expert_rows * (3 * h + 3 * f)) * dtype_bytes
+def decode_step_least_s(obs) -> Optional[float]:
+    """Least seconds of one decode step (every non-expert matmul weight
+    once, three matrices of each (layer, expert) touched, every index key
+    a live row holds, K and V of the ``min(length, topk)`` rows it
+    attends: HBM bytes or the step's FLOPs at peak, the larger); None
+    where the run says neither."""
+    from . import moe_names, swa_names   # what the run observed
 
-
-def expert_matmul_flops(c: Dict[str, Any], expert_rows: float) -> float:
-    return 2.0 * expert_rows * expert_params(c)
+    lengths = swa_names._traced_lengths(obs)
+    medians = moe_names.chunk_medians(obs)
+    if lengths is None or medians is None:
+        return None
+    rows, touched, _imbalance = medians
+    cfg, peaks = obs["cell"].config, obs["peaks"]
+    return max(
+        decode_step_bytes(cfg, touched, lengths) / peaks["hbm_bytes_per_s"],
+        decode_step_flops(cfg, lengths, rows) / peaks["bf16_flops_per_s"])
 
 
 # --------------------------------------------------------------- prefill

@@ -7,7 +7,7 @@ published ``config.json`` keys).  A multiply-add counts as 2 FLOPs.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 
 def _sizes(c: Dict[str, Any]):
@@ -95,3 +95,24 @@ def flash_train_bytes(c: Dict[str, Any], batch: int, seq: int,
     hq, hkv = c["num_attention_heads"], c["num_key_value_heads"]
     per_layer = (6 * hq + 6 * hkv) * seq * c["head_dim"] * dtype_bytes
     return float(batch * c["num_hidden_layers"] * per_layer)
+
+
+def decode_step_least_s(obs) -> Optional[float]:
+    """Least seconds of one decode step at the batch in flight at the
+    middle of the traced span (weights once + each sequence's keys and
+    values once, against HBM bandwidth; or the FLOPs against the MXU,
+    whichever is larger); None where the run does not say the batch."""
+    from . import readers   # what the run observed
+
+    span = obs.get("trace_span")
+    if not span or span[0] is None:
+        return None
+    sequences, positions = readers.context_in_flight(
+        obs, (span[0] + span[1]) / 2)
+    if not sequences:
+        return None
+    cfg, peaks = obs["cell"].config, obs["peaks"]
+    return max(
+        decode_step_bytes(cfg, positions) / peaks["hbm_bytes_per_s"],
+        decode_step_flops(cfg, sequences, positions)
+        / peaks["bf16_flops_per_s"])

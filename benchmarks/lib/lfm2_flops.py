@@ -8,14 +8,20 @@ A configuration is the dict of ``benchmarks/configs/<name>.json``
 (published key names): ``layer_types`` names each layer ``conv`` or
 ``full_attention``; the first ``num_dense_layers`` carry a dense SwiGLU of
 ``intermediate_size``, the others ``num_experts`` experts of
-``moe_intermediate_size`` (which is why ``lib/moe_flops.py``, that reads
-the first as the second, does not count this model).  A multiply-add
-counts as 2 FLOPs; bytes are ``dtype_bytes`` a value (bfloat16).
+``moe_intermediate_size`` (which is why ``lib/moe_flops.py``'s whole-step
+counts, that read the first as the second, are not this model's; its
+counts of the grouped matmuls alone are, and are used here).  A
+multiply-add counts as 2 FLOPs; bytes are ``dtype_bytes`` a value
+(bfloat16).  ``decode_step_least_s`` is the floor the configuration's file
+names under ``roofline``.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, Sequence
+from typing import Any, Dict, Iterable, Optional, Sequence
+
+# the grouped matmuls' own counts are every expert configuration's
+from .moe_flops import expert_matmul_flops, expert_params
 
 
 def head_dim(c: Dict[str, Any]) -> int:
@@ -47,11 +53,6 @@ def attention_params(c: Dict[str, Any]) -> int:
 
 def mixer_params(c: Dict[str, Any], kind: str) -> int:
     return conv_mixer_params(c) if kind == "conv" else attention_params(c)
-
-
-def expert_params(c: Dict[str, Any]) -> int:
-    """One expert's three matrices."""
-    return 3 * c["hidden_size"] * c["moe_intermediate_size"]
 
 
 def dense_ffn_params(c: Dict[str, Any]) -> int:
@@ -151,17 +152,21 @@ def decode_step_flops(c: Dict[str, Any], lengths: Iterable[float],
         + expert_matmul_flops(c, expert_rows)
 
 
-def expert_matmul_bytes(c: Dict[str, Any], experts_touched: float,
-                        expert_rows: float, dtype_bytes: int = 2) -> float:
-    """Least HBM traffic of the grouped matmuls alone: the three
-    matrices of each (layer, expert) pair that has a row, once, and each
-    row's activations (in at width h twice, the hidden row of width f
-    out twice and in once, out at width h once: ``lib/moe_flops.py``'s
-    account, at an expert's own width)."""
-    h, f = c["hidden_size"], c["moe_intermediate_size"]
-    return (experts_touched * expert_params(c)
-            + expert_rows * (3 * h + 3 * f)) * dtype_bytes
+def decode_step_least_s(obs) -> Optional[float]:
+    """Least seconds of one decode step (every non-expert matmul weight
+    once, three matrices of each (layer, expert) touched, the K/V in
+    flight as far as each row is long, the conv states of the slots
+    advanced read and written once: HBM bytes or the step's FLOPs at
+    peak, the larger); None where the run says neither."""
+    from . import lfm2_names, swa_names   # what the run observed
 
-
-def expert_matmul_flops(c: Dict[str, Any], expert_rows: float) -> float:
-    return 2.0 * expert_rows * expert_params(c)
+    lengths = swa_names._traced_lengths(obs)
+    medians = lfm2_names.chunk_medians(obs)
+    if lengths is None or medians is None:
+        return None
+    rows, touched, advanced = medians
+    cfg, peaks = obs["cell"].config, obs["peaks"]
+    return max(
+        decode_step_bytes(cfg, touched, lengths, advanced)
+        / peaks["hbm_bytes_per_s"],
+        decode_step_flops(cfg, lengths, rows) / peaks["bf16_flops_per_s"])

@@ -7,14 +7,16 @@ A configuration is the dict of ``benchmarks/configs/<name>.json``
 (published key names): ``n_routed_experts`` is the count HELD on this chip
 and ``share.n_routed_experts_published`` the router's width;
 ``intermediate_size`` is a DENSE layer's width, ``moe_intermediate_size``
-an expert's (which is why ``lib/moe_flops.py``, that reads the first as
-the second, does not count this model).  A multiply-add counts as 2
-FLOPs; bytes are ``dtype_bytes`` a value (bfloat16).
+an expert's (which is why ``lib/moe_flops.py``'s whole-step counts, that
+read the first as the second, are not this model's).  A multiply-add
+counts as 2 FLOPs; bytes are ``dtype_bytes`` a value (bfloat16).
+``decode_step_least_s`` is the floor the configuration's file names under
+``roofline``.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable
+from typing import Any, Dict, Iterable, Optional
 
 
 def latent_width(c: Dict[str, Any]) -> int:
@@ -150,3 +152,21 @@ def decode_step_flops(c: Dict[str, Any], lengths: Iterable[float],
     return 2.0 * step_matmul_params(c) * len(lengths) \
         + decode_attention_flops(c, lengths) \
         + 2.0 * expert_rows * expert_params(c)
+
+
+def decode_step_least_s(obs) -> Optional[float]:
+    """Least seconds of one decode step (dense, shared and head weights
+    once, three matrices of each (layer, held expert) touched, the latent
+    rows in flight: HBM bytes or the step's FLOPs at peak, the larger);
+    None where the run says neither."""
+    from . import mla_names, swa_names   # what the run observed
+
+    lengths = swa_names._traced_lengths(obs)
+    medians = mla_names.chunk_medians(obs)
+    if lengths is None or medians is None:
+        return None
+    rows, touched = medians
+    cfg, peaks = obs["cell"].config, obs["peaks"]
+    return max(
+        decode_step_bytes(cfg, touched, lengths) / peaks["hbm_bytes_per_s"],
+        decode_step_flops(cfg, lengths, rows) / peaks["bf16_flops_per_s"])

@@ -1,5 +1,6 @@
 """Tell a latent-attention model's work apart in a device trace, and the
-readers of the eight ``mla_*`` metrics.
+readers of the seven ``mla_*`` metrics (its step's floor is
+``lib/mla_flops.py``'s).
 
 An event's name in a v5e trace is the instruction's whole text
 (``lib/moe_names.py`` is the precedent), and a Pallas kernel's
@@ -124,27 +125,6 @@ def decode_attention_roofline(obs) -> Optional[float]:
     return 100.0 * least / kernel_s
 
 
-def decode_step_roofline(obs) -> Optional[float]:
-    """Least time of one decode step (dense, shared and head weights
-    once, three matrices of each (layer, held expert) touched, the latent
-    rows in flight: HBM bytes or the step's FLOPs at peak, the larger) /
-    the median ``jit_decode_k`` step."""
-    step_ms = readers.decode_step_device_ms(obs)
-    if step_ms is None or not _latent(obs):
-        return None
-    lengths, medians = swa_names._traced_lengths(obs), chunk_medians(obs)
-    if lengths is None or medians is None:
-        return None
-    rows, touched = medians
-    cfg, peaks = obs["cell"].config, obs["peaks"]
-    least = max(
-        mla_flops.decode_step_bytes(cfg, touched, lengths)
-        / peaks["hbm_bytes_per_s"],
-        mla_flops.decode_step_flops(cfg, lengths, rows)
-        / peaks["bf16_flops_per_s"])
-    return 100.0 * least / (step_ms * 1e-3)
-
-
 def prefill_attention_time_share(obs) -> Optional[float]:
     found = _prefill_kernel(obs)
     return None if found is None else 100.0 * found[0] / found[1]
@@ -173,17 +153,10 @@ def prefill_attention_roofline(obs) -> Optional[float]:
 
 
 def scope_time_share(scope: str, which: str = "decode"):
-    """Own device seconds of the ops under ``scope`` / device seconds of
-    the ``which`` programs, in %; None where the program's map knows no
-    such scope."""
-    def read(obs) -> Optional[float]:
-        got = scope_names.split(obs, which) if _latent(obs) else None
-        if not got:
-            return None
-        seconds = sum(s for (name, _phase), s in got.by.items()
-                      if name == scope)
-        return 100.0 * seconds / got.module_s if seconds else None
-    return read
+    """``scope_names.scopes_time_share`` for a configuration with latent
+    attention."""
+    return scope_names.scopes_time_share(scope, which=which,
+                                         applies=_latent)
 
 
 def held_rows_share(obs) -> Optional[float]:

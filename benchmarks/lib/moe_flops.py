@@ -5,12 +5,22 @@ yardstick's own arithmetic: nothing here is read from the program.
 A configuration is the dict of ``benchmarks/configs/<name>.json`` (the
 published ``config.json`` keys: ``num_experts``, ``num_experts_per_tok``,
 ``intermediate_size`` = the width of ONE expert).  A multiply-add counts
-as 2 FLOPs.  Every layer has experts and none is shared (OLMoE).
+as 2 FLOPs.  The whole-model and whole-step counts are of a model whose
+every layer has experts and none is shared (OLMoE).
+
+The grouped matmuls' own counts (``expert_params``,
+``expert_matmul_bytes``, ``expert_matmul_flops``) and the three facts
+about the experts (``expert_width``, ``expert_layers``,
+``experts_held``) are every configuration's with experts: they read the
+keys that say them whatever the family -- ``moe_intermediate_size``
+where a configuration has a dense width beside its experts', and what
+the program is given under ``program_fields`` (``first_dense_layers``,
+``moe_experts``, ``moe_held``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 from .flops import kv_bytes_per_token  # K and V rows of a position
 
@@ -23,10 +33,27 @@ def _sizes(c: Dict[str, Any]):
         c["num_experts"]
 
 
+def expert_width(c: Dict[str, Any]) -> int:
+    return c.get("moe_intermediate_size", c["intermediate_size"])
+
+
+def expert_layers(c: Dict[str, Any]) -> int:
+    """Layers that have experts: all but the leading dense ones."""
+    return c["num_hidden_layers"] \
+        - c.get("program_fields", {}).get("first_dense_layers", 0)
+
+
+def experts_held(c: Dict[str, Any]) -> int:
+    """Experts of a layer this chip computes: the program's ``moe_held``
+    range where it holds a share of them, else all."""
+    fields = c["program_fields"]
+    held = fields.get("moe_held")
+    return held[1] - held[0] if held else fields["moe_experts"]
+
+
 def expert_params(c: Dict[str, Any]) -> int:
     """The three matrices of ONE expert of one layer."""
-    h, _q, _kv, f, _v, _e = _sizes(c)
-    return 3 * h * f
+    return 3 * c["hidden_size"] * expert_width(c)
 
 
 def dense_matmul_params_per_layer(c: Dict[str, Any]) -> int:
@@ -63,7 +90,7 @@ def expert_matmul_bytes(c: Dict[str, Any], experts_touched: float,
     matrices of each (layer, expert) pair that has a row, once, and each
     row's activations (in at width h twice, the hidden row of width f
     out twice and in once, out at width h once)."""
-    h, _q, _kv, f, _v, _e = _sizes(c)
+    h, f = c["hidden_size"], expert_width(c)
     return (experts_touched * expert_params(c)
             + expert_rows * (3 * h + 3 * f)) * dtype_bytes
 
@@ -100,3 +127,27 @@ def decode_step_flops(c: Dict[str, Any], batch: float,
     attn = 2 * 2 * context_tokens * heads * d * c["num_hidden_layers"]
     return 2.0 * dense * batch + attn \
         + expert_matmul_flops(c, expert_rows)
+
+
+def decode_step_least_s(obs) -> Optional[float]:
+    """Least seconds of one decode step at the batch in flight at the
+    middle of the traced span and the experts its steps touched (HBM
+    bytes or FLOPs at peak, whichever is larger); None where the run
+    says neither."""
+    from . import moe_names, readers   # what the run observed
+
+    span = obs.get("trace_span")
+    medians = moe_names.chunk_medians(obs)
+    if medians is None or not span or span[0] is None:
+        return None
+    sequences, positions = readers.context_in_flight(
+        obs, (span[0] + span[1]) / 2)
+    if not sequences:
+        return None
+    rows, touched, _ = medians
+    cfg, peaks = obs["cell"].config, obs["peaks"]
+    return max(
+        decode_step_bytes(cfg, touched, positions)
+        / peaks["hbm_bytes_per_s"],
+        decode_step_flops(cfg, sequences, positions, rows)
+        / peaks["bf16_flops_per_s"])

@@ -9,7 +9,7 @@ import re
 import statistics
 from typing import List, Optional
 
-from . import flops
+from . import flops, spec
 from .runtime import percentile
 
 FAILED_REQUEST_MS = 120_000.0   # a failed request counts as the worst
@@ -47,11 +47,20 @@ def _step_runs(obs):
     return trace.module_runs(TRAIN_STEP_MODULE) if trace else []
 
 
-def train_step_device_ms(obs) -> Optional[float]:
-    runs = _step_runs(obs)
+def _whole_run_ms(obs, module: str) -> Optional[float]:
+    """Median device time of the module's runs that the traced slice
+    holds whole (``Trace.whole_runs``: a run that the slice's edge cut
+    is there with what is left of it, and is no run's time); None where
+    it holds none."""
+    trace = obs.get("trace")
+    runs = trace.whole_runs(module) if trace else []
     if not runs:
         return None
     return 1e3 * statistics.median(e - s for s, e, _ in runs)
+
+
+def train_step_device_ms(obs) -> Optional[float]:
+    return _whole_run_ms(obs, TRAIN_STEP_MODULE)
 
 
 def _share_of_steps(obs, seconds: float) -> Optional[float]:
@@ -69,18 +78,16 @@ def flash_seconds(obs) -> Optional[float]:
     return s or None
 
 
-def flash_attention_time_share(obs) -> Optional[float]:
-    s = flash_seconds(obs)
-    return None if s is None else _share_of_steps(obs, s)
-
-
 def flash_attention_roofline(obs) -> Optional[float]:
     """Least time the chip could take for what the kernels of one step
     must do on ONE chip (the larger of FLOPs / peak and bytes / peak),
-    over the kernels' measured time per step."""
-    s, runs = flash_seconds(obs), _step_runs(obs)
-    if s is None or not runs:
+    over the kernels' measured time per step: their share of the steps'
+    device time x a whole step's, so a step that the trace's edge cut
+    miscounts neither."""
+    s, step_ms = flash_seconds(obs), train_step_device_ms(obs)
+    if s is None or step_ms is None:
         return None
+    kernel_s = _share_of_steps(obs, s) * 1e-2 * step_ms * 1e-3
     cfg, peaks, chips = obs["cell"].config, obs["peaks"], obs["chips"]
     need_flops = flops.flash_train_flops(cfg, obs["batch"],
                                          obs["seq_len"]) / chips
@@ -88,7 +95,7 @@ def flash_attention_roofline(obs) -> Optional[float]:
                                          obs["seq_len"]) / chips
     least = max(need_flops / peaks["bf16_flops_per_s"],
                 need_bytes / peaks["hbm_bytes_per_s"])
-    return 100.0 * least / (s / len(runs))
+    return 100.0 * least / kernel_s
 
 
 def collective_time_share(obs) -> Optional[float]:
@@ -103,13 +110,6 @@ def collective_exposed_share(obs) -> Optional[float]:
     if not trace or obs["chips"] < 2:
         return None
     return _share_of_steps(obs, trace.collective_seconds()[1])
-
-
-def trainer_start_s(obs) -> Optional[float]:
-    if "t_first_step_launch" not in obs:
-        return None
-    return (obs["t_first_step_launch"] - obs["t_fit"]
-            - obs["compile_s_before_first_step"])
 
 
 def train_input_wait_share(obs) -> Optional[float]:
@@ -164,12 +164,11 @@ def tpot_percentile(q):
 
 
 def decode_step_device_ms(obs) -> Optional[float]:
-    trace = obs.get("trace")
-    runs = trace.module_runs(DECODE_MODULE) if trace else []
-    if not runs:
-        return None
-    return 1e3 * statistics.median(e - s for s, e, _ in runs) \
-        / obs["decode_chunk"]
+    """A whole ``jit_decode_k`` launch's device time / its steps, the
+    median over the launches the traced slice holds whole: what every
+    decode roofline here divides by."""
+    launch_ms = _whole_run_ms(obs, DECODE_MODULE)
+    return None if launch_ms is None else launch_ms / obs["decode_chunk"]
 
 
 def prefill_device_share(obs) -> Optional[float]:
@@ -198,23 +197,23 @@ def context_in_flight(obs, t: float):
 
 
 def decode_step_roofline(obs) -> Optional[float]:
-    """Least time for one decode step at the batch in flight at the
-    middle of the traced span (weights once + each sequence's keys and
-    values once, against HBM bandwidth; or the FLOPs against the MXU,
-    whichever is larger), over the measured time of a step."""
+    """Least time of one decode step / the measured time of a step.  The
+    least time is the configuration's: its file names, under
+    ``roofline``, the module of ``benchmarks/lib/`` whose
+    ``decode_step_least_s(obs)`` counts what a step of ITS layers must
+    read and compute at the batch in flight at the middle of the traced
+    span (HBM bytes or FLOPs at peak, the larger), beside its operation
+    and byte counts."""
+    cell = obs["cell"]
     step_ms = decode_step_device_ms(obs)
-    span = obs.get("trace_span")
-    if step_ms is None or not span or span[0] is None:
+    if step_ms is None or not cell.config.get("roofline"):
         return None
-    sequences, positions = context_in_flight(obs, (span[0] + span[1]) / 2)
-    if not sequences:
-        return None
-    cfg, peaks = obs["cell"].config, obs["peaks"]
-    least = max(
-        flops.decode_step_bytes(cfg, positions) / peaks["hbm_bytes_per_s"],
-        flops.decode_step_flops(cfg, sequences, positions)
-        / peaks["bf16_flops_per_s"])
-    return 100.0 * least / (step_ms * 1e-3)
+    floor = spec.load_module("lib", cell.config["roofline"], cell.bench_dir)
+    if floor is None:
+        raise spec.SpecError(
+            f"{cell.name}: no benchmarks/lib/{cell.config['roofline']}.py")
+    least = floor.decode_step_least_s(obs)
+    return None if least is None else 100.0 * least / (step_ms * 1e-3)
 
 
 # ------------------------------------------------------------------- both

@@ -27,7 +27,7 @@ MLP 10,240, 5,120 Mamba channels x 16 states, dt rank 160):
 
 from __future__ import annotations
 
-from typing import Any, Dict, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 _ITEMSIZE = {"bfloat16": 2, "float32": 4}
 
@@ -172,3 +172,20 @@ def prefill_flops(c: Dict[str, Any], prompt: float, skip: bool = True):
     self_decoder = matmul_params(c, ("mamba", "window")) + 2 * h * kv
     rest = matmul_params(c) - self_decoder
     return 2.0 * (self_decoder * prompt + rest * (1 if skip else prompt))
+
+
+def decode_step_least_s(obs) -> Optional[float]:
+    """Least seconds of one WHOLE decode step (every weight once, the
+    shared pool's live rows once a reading layer, the rings' live rows,
+    the states of the rows it advances read and written: HBM bytes or
+    FLOPs at peak, the larger); None where the run does not say the rows
+    in flight."""
+    from . import swa_names   # what the run observed
+
+    lengths = swa_names._traced_lengths(obs)
+    if lengths is None:
+        return None
+    cfg, peaks = obs["cell"].config, obs["peaks"]
+    return max(
+        decode_step_bytes(cfg, lengths) / peaks["hbm_bytes_per_s"],
+        decode_step_flops(cfg, lengths) / peaks["bf16_flops_per_s"])

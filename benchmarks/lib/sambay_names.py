@@ -106,26 +106,6 @@ def window_attention_roofline(obs) -> Optional[float]:
     return _read_roofline(obs, WINDOW_SCOPES, sambay_flops.window_kv_bytes)
 
 
-def decode_step_roofline(obs) -> Optional[float]:
-    """Least time of one WHOLE decode step (every weight once, the shared
-    pool's live rows once a reading layer, the rings' live rows, the
-    states of the rows it advances read and written: HBM bytes or FLOPs at
-    peak, the larger) / the measured time of a step."""
-    step_ms = readers.decode_step_device_ms(obs)
-    if step_ms is None or "mb_per_layer" not in obs["cell"].config:
-        return None
-    lengths = _traced_lengths(obs)
-    if lengths is None:
-        return None
-    cfg, peaks = obs["cell"].config, obs["peaks"]
-    least = max(
-        sambay_flops.decode_step_bytes(cfg, lengths)
-        / peaks["hbm_bytes_per_s"],
-        sambay_flops.decode_step_flops(cfg, lengths)
-        / peaks["bf16_flops_per_s"])
-    return 100.0 * least / (step_ms * 1e-3)
-
-
 def prefill_skipped_share(obs) -> Optional[float]:
     """serve.prefill_group: positions x layers the window's prefills did
     not compute (``positions_skipped``: the layers after the K/V layer run

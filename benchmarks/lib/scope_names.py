@@ -180,6 +180,22 @@ def _write_report(obs) -> None:
         json.dump(report, f, indent=1)
 
 
+def scopes_time_share(*scopes: str, which: str = "decode", applies=None):
+    """Own device seconds of the ops the program traced under ``scopes``
+    / device seconds of the decode (or prefill) programs, in %; None
+    where none of them ran, where the program keeps no map, and where
+    ``applies(obs)`` says that the configuration has no such layer."""
+    def read(obs) -> Optional[float]:
+        got = split(obs, which) if applies is None or applies(obs) \
+            else None
+        if not got or not got.module_s:
+            return None
+        seconds = sum(s for (name, _phase), s in got.by.items()
+                      if name in scopes)
+        return 100.0 * seconds / got.module_s if seconds else None
+    return read
+
+
 def time_share(which: str, family: Optional[str] = None,
                phase: Optional[str] = None):
     """Own device seconds of a family of scopes (every scope where

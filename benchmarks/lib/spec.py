@@ -32,18 +32,32 @@ the CPU):
                                  configuration's mathematics
     workloads/<cell>.json        and traffic/<traffic>.json unless the
                                  cell runs a mix that is here
-    metrics/<reader>.py          for what no reader here measures
+    lib/<family>_flops.py        where its decode step's floor is its own:
+                                 ``decode_step_least_s(obs)`` beside its
+                                 operation and byte counts, named by
+                                 ``"roofline"`` in the configuration's file
+    metrics/<reader>.py          for what no reader here measures: its own
+                                 kernels and layers
     BENCHMARK.json               the configuration, the cell, its own
                                  metrics; the cell's name appended to the
                                  ``workloads`` of the entries it joins
 
+One entry a question, and a cell joins by what its program does.  A served
+configuration joins ``batch.decode_step_roofline`` (one reader,
+``readers.decode_step_roofline``, over the floor its file names) and brings
+no entry for its step.  One with experts joins the four ``moe_*`` entries:
+they read the program's ``router`` / ``expert_dispatch`` / ``expert_ffn``
+scopes, the ``%ragged-dot-none*`` kernels and the ``serve.chunk`` spans, at
+the width, layers and held range its file states (``lib/moe_names.py``).  One
+whose program is given an ``index_topk`` joins the ``dsa_*`` selection
+entries, one with a ``kv_lora_rank`` the ``mla_*`` ones that read scopes and
+spans.  It brings entries only for kernels and layers of its own (``per_layer``
+holds 112 of the 128 it may).
+
 A configuration that is cut says so twice: ``reduced`` in
 ``BENCHMARK.json`` lists the keys, and ``reduced`` in the file has one
 ``{"key", "published", "here", "why"}`` per key, in the same order
-(``[]`` where nothing is cut).  ``lib/flops.py`` counts a dense decoder
-only: a model with experts brings its own operation and byte functions
-inside its own roofline reader, under a metric name of its own, and
-does not join ``batch.decode_step_roofline``.
+(``[]`` where nothing is cut).
 """
 
 from __future__ import annotations

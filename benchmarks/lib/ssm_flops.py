@@ -18,7 +18,7 @@ By hand, granite-4.0-h-micro: a Mamba mixer's in-projection is 2,048 x
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 _ITEMSIZE = {"bfloat16": 2, "float32": 4}
 
@@ -131,3 +131,25 @@ def decode_step_flops(c: Dict[str, Any], batch: float,
             * c["head_dim"] * n_attn)
     return 2.0 * matmul_params(c) * batch + attn \
         + state_update_flops(c, batch)
+
+
+def decode_step_least_s(obs) -> Optional[float]:
+    """Least seconds of one decode step (every matmul weight once, the
+    states of the slots it advances read and written once, the K/V of the
+    batch in flight at the middle of the traced span: HBM bytes or FLOPs
+    at peak, whichever is larger); None where the run says neither."""
+    from . import readers, ssm_names   # what the run observed
+
+    span = obs.get("trace_span")
+    if not span or span[0] is None:
+        return None
+    rows = ssm_names.rows_a_step(obs)
+    sequences, positions = readers.context_in_flight(
+        obs, (span[0] + span[1]) / 2)
+    if rows is None or not sequences:
+        return None
+    cfg, peaks = obs["cell"].config, obs["peaks"]
+    return max(
+        decode_step_bytes(cfg, rows, positions) / peaks["hbm_bytes_per_s"],
+        decode_step_flops(cfg, sequences, positions)
+        / peaks["bf16_flops_per_s"])

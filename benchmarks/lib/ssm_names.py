@@ -1,5 +1,6 @@
 """Tell a state-space layer's work apart in a device trace, and the
-readers of the four ``ssm_*`` metrics.
+readers of the three ``ssm_*`` metrics (its step's floor is
+``lib/ssm_flops.py``'s).
 
 An event's name in a v5e trace is the instruction's whole text, result
 and operands with their shapes (``lib/moe_names.py`` is the precedent):
@@ -129,28 +130,3 @@ def state_update_roofline(obs) -> Optional[float]:
         ssm_flops.state_update_flops(cfg, rows)
         / peaks["bf16_flops_per_s"])
     return 100.0 * least / update_s
-
-
-def decode_step_roofline(obs) -> Optional[float]:
-    """Least time of one decode step (every matmul weight once, the
-    states of the slots it advances read and written once, the K/V of the
-    batch in flight at the middle of the traced span: HBM bytes or FLOPs
-    at peak, whichever is larger) / the measured time of a step."""
-    step_ms = readers.decode_step_device_ms(obs)
-    span = obs.get("trace_span")
-    cfg = obs["cell"].config
-    if (step_ms is None or not span or span[0] is None
-            or "mamba" not in cfg.get("layer_types", ())):
-        return None
-    rows = rows_a_step(obs)
-    sequences, positions = readers.context_in_flight(
-        obs, (span[0] + span[1]) / 2)
-    if rows is None or not sequences:
-        return None
-    peaks = obs["peaks"]
-    least = max(
-        ssm_flops.decode_step_bytes(cfg, rows, positions)
-        / peaks["hbm_bytes_per_s"],
-        ssm_flops.decode_step_flops(cfg, sequences, positions)
-        / peaks["bf16_flops_per_s"])
-    return 100.0 * least / (step_ms * 1e-3)

@@ -1,11 +1,12 @@
 """The program's own account of a start: the spans it wrote to its
 timeline BEFORE the window opened (``ray_tpu/observability/timeline.py``,
-one ring per process), read after the run.  One reader for the five
-``setup_*`` metrics of ``BENCHMARK.json`` (its ``per_layer`` holds 128
-entries and may hold no more: the rest of the account -- the cache's
-fetches, the programs counted, the engine's build, the warm-up's one
-wait, the trainer's ``train.worker_start`` -- is in
-``benchmarks/out/<cell>/start.json``, which this module writes).
+one ring per process), read after the run.  One reader for the seven
+start metrics of ``BENCHMARK.json`` (``setup_trace_s``, ``setup_lower_s``,
+``setup_cache_fetch_s``, ``setup_before_engine_s``, ``setup_warmup_s``,
+``setup_warm_unnamed_s``, ``train_worker_start_s``); the rest of the
+account -- the programs counted, the engine's build, the warm-up's one
+wait -- is in ``benchmarks/out/<cell>/start.json``, which this module
+writes.
 
 What the program writes (``serve/llm.py`` ``__init__`` / ``_warmup``,
 ``train/trainer.py``, the compile listener of ``observability/device.py``):

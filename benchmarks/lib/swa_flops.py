@@ -12,7 +12,7 @@ multiply-add counts as 2 FLOPs.  The layers' weights are
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, Tuple
+from typing import Any, Dict, Iterable, Optional, Tuple
 
 from . import moe_flops
 
@@ -94,3 +94,21 @@ def prefill_attention_flops(c: Dict[str, Any], length: float) -> float:
     pairs = full * band_pairs(length) \
         + window * band_pairs(length, c["sliding_window_size"])
     return 2.0 * 2.0 * pairs * c["num_attention_heads"] * c["head_dim"]
+
+
+def decode_step_least_s(obs) -> Optional[float]:
+    """Least seconds of one decode step (dense weights once, the experts
+    the step touched once, each live row's keys once, a ring layer's at
+    ``min(length, window)``: HBM bytes or FLOPs at peak, the larger);
+    None where the run says neither."""
+    from . import moe_names, swa_names   # what the run observed
+
+    lengths = swa_names._traced_lengths(obs)
+    medians = moe_names.chunk_medians(obs)
+    if lengths is None or medians is None:
+        return None
+    rows, touched, _imbalance = medians
+    cfg, peaks = obs["cell"].config, obs["peaks"]
+    return max(
+        decode_step_bytes(cfg, touched, lengths) / peaks["hbm_bytes_per_s"],
+        decode_step_flops(cfg, lengths, rows) / peaks["bf16_flops_per_s"])

@@ -1,5 +1,6 @@
 """Tell the attention of a model with window layers apart in a device
-trace, and the readers of the six ``swa_*`` metrics.
+trace, and the readers of the five ``swa_*`` metrics (its step's floor is
+``lib/swa_flops.py``'s).
 
 An event's name in a v5e trace is the instruction's whole text
 (``lib/moe_names.py`` is the precedent), and a Pallas kernel's
@@ -27,7 +28,7 @@ import re
 import statistics
 from typing import Dict, List, Optional, Tuple
 
-from . import moe_names, program_spans, readers, ssm_names, swa_flops
+from . import program_spans, readers, ssm_names, swa_flops
 
 DECODE_ATTENTION_KERNEL = re.compile(r"^%decode_attention(\.\w+)* = ")
 PREFILL_ATTENTION_KERNEL = re.compile(
@@ -103,27 +104,6 @@ def decode_attention_roofline(obs) -> Optional[float]:
         swa_flops.decode_attention_flops(cfg, lengths)
         / peaks["bf16_flops_per_s"])
     return 100.0 * least / kernel_s
-
-
-def decode_step_roofline(obs) -> Optional[float]:
-    """Least time of one decode step (dense weights once, the experts
-    the step touched once, each live row's keys once, a ring layer's at
-    ``min(length, window)``: HBM bytes or FLOPs at peak) / the measured
-    time of a step."""
-    step_ms = readers.decode_step_device_ms(obs)
-    if step_ms is None or not _windowed(obs):
-        return None
-    lengths, medians = _traced_lengths(obs), moe_names.chunk_medians(obs)
-    if lengths is None or medians is None:
-        return None
-    rows, touched, _imbalance = medians
-    cfg, peaks = obs["cell"].config, obs["peaks"]
-    least = max(
-        swa_flops.decode_step_bytes(cfg, touched, lengths)
-        / peaks["hbm_bytes_per_s"],
-        swa_flops.decode_step_flops(cfg, lengths, rows)
-        / peaks["bf16_flops_per_s"])
-    return 100.0 * least / (step_ms * 1e-3)
 
 
 def prefill_attention_time_share(obs) -> Optional[float]:

@@ -16,6 +16,10 @@ What a TPU trace holds (looked at by hand on the v5e, PERF.md section 3):
   ``jax.profiler.TraceAnnotation`` is an event on the line of the thread
   that opened it, under the annotation's name.
 - all ``start_ns`` are on one clock.
+- the profiler's start and stop fall where they fall: the program that
+  was running at either edge is there as an event of what is LEFT of it,
+  with the ops that ran inside the trace and no others.  Only a chip's
+  first and last module event can be such a piece (``whole_runs``).
 
 Busy time is the UNION of the op intervals of a chip (nested and
 overlapping events count once); an op's own time is its duration minus
@@ -24,11 +28,13 @@ what its nested children cover.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import functools
 import glob
 import os
 import re
+import statistics
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -37,6 +43,7 @@ ASYNC_LINE = "Async XLA Ops"
 MODULES_LINE = "XLA Modules"
 MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 HOST_PLANE = "/host:CPU"
+WHOLE_RUN_OPS = 0.98   # of a whole run's op events: ``Trace.whole_runs``
 
 Interval = Tuple[float, float]          # (start_s, end_s)
 Event = Tuple[float, float, str]        # (start_s, end_s, name)
@@ -159,8 +166,17 @@ class DeviceTrace:
     def op_seconds(self) -> Dict[str, float]:
         return self_times(self.ops)
 
+    @functools.cached_property
+    def op_starts(self) -> List[float]:
+        return sorted(s for s, _, _ in self.ops)
+
     def busy(self, lo: float, hi: float) -> List[Interval]:
         return clip(self.busy_union, lo, hi)
+
+    def ops_inside(self, run: Event) -> int:
+        """How many op events start inside a module's run."""
+        return bisect.bisect_left(self.op_starts, run[1]) \
+            - bisect.bisect_left(self.op_starts, run[0])
 
 
 @dataclasses.dataclass
@@ -200,6 +216,29 @@ class Trace:
         rx = re.compile(pattern)
         return [m for m in self.devices[device].modules
                 if rx.search(m[2])] if self.devices else []
+
+    def whole_runs(self, pattern: str, device: int = 0) -> List[Event]:
+        """The module's runs that the trace holds WHOLE.  A chip runs one
+        program at a time, so only its first and its last module event
+        can be a piece that the trace's edge cut; such a piece holds
+        fewer ops than a whole run of the same program (the same
+        ``jit_name(<fingerprint>)``) does.  An edge run is kept where it
+        holds as many ops (to 2%: what a piece that short of whole is
+        short by) as the median of that program's runs between the
+        edges, and dropped where it holds fewer or there is none to
+        compare it with."""
+        runs = self.module_runs(pattern, device)
+        if not runs:
+            return []
+        dev = self.devices[device]
+        edges = (min(dev.modules), max(dev.modules))
+        inner: Dict[str, List[int]] = {}
+        for run in runs:
+            if run not in edges:
+                inner.setdefault(run[2], []).append(dev.ops_inside(run))
+        return [run for run in runs if run not in edges or (
+            run[2] in inner and dev.ops_inside(run)
+            >= WHOLE_RUN_OPS * statistics.median(inner[run[2]]))]
 
     def busy_inside(self, spans: Sequence[Event],
                     device: int = 0) -> List[float]:

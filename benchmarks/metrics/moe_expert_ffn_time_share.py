@@ -1,7 +1,8 @@
-"""Device time of the grouped-matmul kernels (by their names) / device
-time of the decode programs.
+"""Own device time of the ops the program traced under ``expert_ffn`` (the
+grouped matmuls and the activation between them) / device time of the decode
+programs.
 """
 
 from benchmarks.lib import moe_names
 
-read = moe_names.time_share("matmul")
+read = moe_names.expert_ffn_time_share

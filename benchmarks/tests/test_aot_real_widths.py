@@ -9,6 +9,7 @@ in the test's own process: the TPU library loads once, in the worker
 that gets the file.
 """
 
+import collections
 import json
 import os
 import re
@@ -24,6 +25,29 @@ MOSAIC = 'custom_call_target="tpu_custom_call"'
 def _json(kind, name):
     with open(os.path.join(spec.BENCH_DIR, kind, name + ".json")) as f:
         return json.load(f)
+
+
+def kernels_by_name_and_scope(compiled_text):
+    """``{(kernel, scope): calls}`` of a compiled module's Mosaic kernels:
+    the instruction's name without its dotted suffix (``decode_attention``,
+    ``ragged-dot-none``: what a reader that goes by name matches in a
+    trace) and the scope the program's own map gives the instruction (what
+    a reader that goes by scope sums).  A cell's test holds the kernels
+    its readers name, each under the scope they expect, and not the
+    module's total: a kernel more is no reader's loss."""
+    from ray_tpu.observability import device
+
+    scopes = device.scopes_of_text(compiled_text)
+    found = collections.Counter()
+    for line in compiled_text.splitlines():
+        if MOSAIC not in line:
+            continue
+        text = line.strip()
+        text = text[len("ROOT "):] if text.startswith("ROOT ") else text
+        kernel = text.split(" = ", 1)[0].lstrip("%").split(".", 1)[0]
+        scope = scopes.get(device.instruction_key(text), ("?", ""))[0]
+        found[kernel, scope] += 1
+    return found
 
 
 @pytest.fixture(scope="module")

@@ -20,9 +20,9 @@ import pytest
 
 from benchmarks import run as bench_run
 from benchmarks.kinds import serve_llm_even
-from benchmarks.lib import (loadgen, mla_flops, mla_names, program,
-                            program_spans, scope_names, spec, swa_names,
-                            trace_reduce)
+from benchmarks.lib import (loadgen, mla_flops, mla_names, moe_flops,
+                            program, program_spans, scope_names, spec,
+                            swa_names, trace_reduce)
 from benchmarks.tools import replay_spread
 from benchmarks.tests import test_rehearsal
 from benchmarks.tests.test_aot_real_widths import (  # noqa: F401
@@ -35,8 +35,11 @@ _READERS = ("mla_decode_attention_roofline",
             "mla_decode_attention_time_share",
             "mla_prefill_attention_roofline",
             "mla_prefill_attention_time_share", "mla_absorb_time_share",
-            "mla_shared_expert_time_share", "mla_decode_step_roofline",
-            "mla_held_rows_share")
+            "mla_shared_expert_time_share", "mla_held_rows_share")
+# what the cell joins for its step and its experts: one reader each for
+# every configuration (``lib/readers.py``, ``lib/moe_names.py``)
+_JOINED = ("decode_step_roofline", "moe_expert_ffn_time_share",
+           "moe_expert_matmul_roofline", "moe_expert_load_imbalance")
 
 
 # ------------------------------------------------- parameters and bytes
@@ -125,29 +128,36 @@ def test_the_file_is_the_catalogs_entry_cut_as_it_says():
                                                         3072)
 
 
-def test_the_readers_names_lead_to_files():
+def test_the_cells_readers_lead_to_files():
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         benchmark = json.load(f)
     mine = [m for m in benchmark["per_layer"]
             if m["name"].startswith("mla_")]
-    assert [m["name"] for m in mine] == list(_READERS)
-    assert benchmark["per_layer"][-len(mine):] == mine      # appended
+    assert sorted(m["name"] for m in mine) == sorted(_READERS)
     for m in mine:
         assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_output_tokens_per_s"
         assert callable(spec.load_module("metrics", m["name"]).read)
-    assert benchmark["workloads"][-1]["name"] == CELL
-    assert benchmark["workloads"][-1]["chips"] == 1
-    assert benchmark["configs"][-1]["name"] == CONFIG
+    entry = next(w for w in benchmark["workloads"] if w["name"] == CELL)
+    assert entry["chips"] == 1 and entry["config"] == CONFIG
     cell = spec.Cell(CELL)
     reported = {e["name"] for e, _ in cell.readers("per_layer")}
     assert set(_READERS) <= reported
-    assert {"batch.decode_kv_read_share", "tpot_p50_ms",
+    assert {"batch.decode_kv_read_share", "batch.slot_wait_p50_ms",
             "batch.prefill_expert_dispatch_time_share",
             "batch.decode_step_device_ms"} <= reported
-    # lib/moe_flops.py reads intermediate_size as an expert's width, and
-    # lib/moe_names.py the published count under another key
-    assert not {m for m in reported if m.startswith(("moe_", "swa_"))}
+    # the step's floor is the file's (lib/mla_flops.py: a latent row, the
+    # experts HELD), and the experts' entries are every expert cell's: an
+    # expert's width is ``moe_intermediate_size``, its layers all but the
+    # leading dense one, its experts the 40 of the program's ``moe_held``
+    assert cell.config["roofline"] == "mla_flops"
+    assert (moe_flops.expert_width(cell.config),
+            moe_flops.expert_layers(cell.config),
+            moe_flops.experts_held(cell.config)) == (1536, 4, 40)
+    assert {"batch.decode_step_roofline", "moe_expert_matmul_roofline",
+            "moe_expert_ffn_time_share", "moe_routing_time_share",
+            "moe_expert_load_imbalance"} <= reported
+    assert not {m for m in reported if m.startswith("swa_")}
     assert {e["name"] for e, _ in cell.readers("end_to_end")} == {
         "serve_output_tokens_per_s", "setup_s"}
 
@@ -338,7 +348,8 @@ _YARN = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
          "original_max_position_embeddings": 16}
 TINY_LATENT = {
     "name": "tiny-latent", "source": "none (test, latent attention)",
-    "reference": "deepseek_v2_decoder", "vocab_size": 256,
+    "reference": "deepseek_v2_decoder", "roofline": "mla_flops",
+    "vocab_size": 256,
     "hidden_size": 64, "num_hidden_layers": 3, "num_attention_heads": 4,
     "num_key_value_heads": 4, "head_dim": 24, "intermediate_size": 128,
     "moe_intermediate_size": 32, "n_routed_experts": 8,
@@ -440,10 +451,11 @@ def test_a_toy_latent_model_runs_end_to_end_on_the_cpu(tree, cpu_peaks,
     assert obs["cell"].reference.__name__.endswith("deepseek_v2_decoder")
     assert len(obs["logit_gaps"]) == 4 and obs["logit_gap_max"] < 1e-2
     metrics = result["metrics"]
-    assert {"batch.ttft_p50_ms", "tpot_p50_ms", "mla_held_rows_share",
+    assert {"batch.slot_wait_p50_ms", "batch.token_burst_gap_p50_ms",
+            "mla_held_rows_share", "moe_expert_load_imbalance",
             "batch.decode_slot_utilization", "batch.decode_kv_read_share",
             "batch.prefill_padding_share", "window_compiles"} <= set(metrics)
-    assert not {"mla_decode_step_roofline", "mla_decode_attention_roofline",
+    assert not {"batch.decode_step_roofline", "mla_decode_attention_roofline",
                 "mla_prefill_attention_roofline",
                 "mla_decode_attention_time_share"} & set(metrics)
     assert 10 < metrics["mla_held_rows_share"]["value"] < 90
@@ -556,7 +568,8 @@ def _synthetic_obs(steps=16, runs=2):
     scopes = {"jit_decode_k": {
         instruction_key(_ABSORB): ("mla_absorb", "forward"),
         instruction_key(_SHARED): ("shared_expert", "forward"),
-        instruction_key(_DECODE_LAYER[0][0]): ("qkv_proj", "forward")}}
+        instruction_key(_DECODE_LAYER[0][0]): ("qkv_proj", "forward"),
+        instruction_key(_DECODE_LAYER[-1][0]): ("expert_ffn", "forward")}}
     return {
         "trace": trace, "cell": cell, "decode_chunk": 16,
         "trace_span": [0.9, 1.1], "scope_map": scopes,
@@ -571,7 +584,7 @@ def test_the_eight_readers_on_a_synthetic_trace(monkeypatch):
     monkeypatch.setattr(scope_names, "_write_report", lambda obs: None)
     obs = _synthetic_obs()
     reads = {name: spec.load_module("metrics", name).read(obs)
-             for name in _READERS}
+             for name in _READERS + _JOINED}
     layer_us = sum(us for _n, us in _DECODE_LAYER)               # 3,000
     assert reads["mla_decode_attention_time_share"] == pytest.approx(
         100 * 1200 / layer_us)
@@ -591,8 +604,19 @@ def test_the_eight_readers_on_a_synthetic_trace(monkeypatch):
     assert mla_names.chunk_medians(obs) == (4 * 45, 4 * 27)
     floor = mla_flops.decode_step_bytes(c, 4 * 27, lengths) / 819e9
     assert floor > mla_flops.decode_step_flops(c, lengths, 4 * 45) / 197e12
-    assert reads["mla_decode_step_roofline"] == pytest.approx(
+    assert reads["decode_step_roofline"] == pytest.approx(
         100 * floor / step_s, rel=1e-3)
+    # the held experts as every expert cell's entries read them: the
+    # grouped matmuls of 1,536-wide experts against 4 x 27 touched pairs
+    # and 4 x 45 rows; the busiest of 4 x 40 held (layer, expert) pairs
+    assert reads["moe_expert_ffn_time_share"] == pytest.approx(
+        100 * 1350 / layer_us)
+    experts = (4 * 27 * 3 * 5120 * 1536
+               + 4 * 45 * (3 * 5120 + 3 * 1536)) * 2 / 819e9
+    assert reads["moe_expert_matmul_roofline"] == pytest.approx(
+        100 * experts / (5 * 1350e-6), rel=1e-3)
+    assert reads["moe_expert_load_imbalance"] == pytest.approx(
+        16 * 4 / (16 * 4 * 45 / (4 * 40)))
     attention = mla_flops.decode_attention_flops(c, lengths) / 197e12
     assert attention > mla_flops.decode_attention_bytes(c, lengths) / 819e9
     assert obs["mla_decode_attention_bound"] == "flops"
@@ -602,7 +626,7 @@ def test_the_eight_readers_on_a_synthetic_trace(monkeypatch):
     whole = 5 * mla_flops.prefill_attention_flops(c, 7000) / 197e12
     assert reads["mla_prefill_attention_roofline"] == pytest.approx(
         100 * whole / (20 * 5000e-6), rel=1e-3)
-    for name in _READERS:
+    for name in _READERS + _JOINED[:3]:
         assert 0 < reads[name] < 100, name
 
 
@@ -636,3 +660,5 @@ def test_a_program_without_a_latent_leaf_reads_nothing(monkeypatch):
         if name != "mla_held_rows_share":           # reads spans alone
             assert read(dict(no_trace)) is None, name
         assert read(dict(parent)) is None, name
+    step = spec.load_module("metrics", "decode_step_roofline").read
+    assert step(dict(parent)) is None and step(dict(no_trace)) is None

@@ -19,11 +19,11 @@ import types
 import pytest
 
 from benchmarks import run as bench_run
-from benchmarks.lib import (dsa_flops, program, program_spans, scope_names,
-                            spec, swa_names, trace_reduce)
+from benchmarks.lib import (dsa_flops, moe_flops, program, program_spans,
+                            scope_names, spec, swa_names, trace_reduce)
 from benchmarks.tests import test_rehearsal
 from benchmarks.tests.test_aot_real_widths import (  # noqa: F401
-    MOSAIC, _json, _on, compiled_kernels, one_chip, topo)
+    _json, _on, compiled_kernels, kernels_by_name_and_scope, one_chip, topo)
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 CONFIG = "keye-vl-2.0-30b-a3b"
@@ -32,9 +32,12 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 _READERS = ("dsa_indexer_time_share", "dsa_select_time_share",
             "dsa_sparse_attention_time_share",
             "dsa_prefill_selection_time_share", "dsa_selected_share",
-            "dsa_expert_ffn_time_share", "dsa_decode_step_roofline",
-            "dsa_sparse_prefill_attention_roofline",
-            "dsa_expert_matmul_roofline", "dsa_routing_time_share")
+            "dsa_sparse_prefill_attention_roofline")
+# what the cell joins for its step and its experts: one reader each for
+# every configuration (``lib/readers.py``, ``lib/moe_names.py``)
+_JOINED = ("decode_step_roofline", "moe_expert_ffn_time_share",
+           "moe_routing_time_share", "moe_expert_matmul_roofline",
+           "moe_expert_load_imbalance")
 LAYER = 625_381_632
 ENDS = 2 * 151_936 * 2048 + 2048
 
@@ -165,17 +168,23 @@ def test_the_readers_names_lead_to_files():
         "keye_sparse_decoder")
     reported = {e["name"] for e, _ in cell.readers("per_layer")}
     assert set(_READERS) <= reported
-    assert {"batch.decode_kv_read_share", "tpot_p50_ms",
+    assert {"batch.decode_kv_read_share", "batch.slot_wait_p50_ms",
             "batch.prefill_expert_dispatch_time_share",
-            "batch.decode_step_device_ms", "setup_compile_s",
+            "batch.decode_step_device_ms", "setup_cache_fetch_s",
             "window_compiles"} <= reported
-    # lib/flops.py counts a dense decoder; lib/moe_flops.py and
-    # lib/moe_names.py read intermediate_size (6,144 here) as an expert's,
-    # but for the load imbalance, which reads the spans' rows alone
-    assert "batch.decode_step_roofline" not in reported
+    # the step's floor is the file's (lib/dsa_flops.py: index keys, the
+    # selected rows), and the experts' entries are every expert cell's: an
+    # expert is ``moe_intermediate_size`` wide (768, not the 6,144 of a
+    # dense FFN no layer has) and all 6 layers have all 128
+    assert cell.config["roofline"] == "dsa_flops"
+    assert (moe_flops.expert_width(cell.config),
+            moe_flops.expert_layers(cell.config),
+            moe_flops.experts_held(cell.config)) == (768, 6, 128)
     assert {m for m in reported if m.startswith(
-        ("moe_", "swa_", "ssm_", "mla_", "lfm2_"))} \
-        == {"moe_expert_load_imbalance"}
+        ("moe_", "swa_", "ssm_", "mla_", "lfm2_"))} == {
+        "moe_expert_load_imbalance", "moe_expert_ffn_time_share",
+        "moe_routing_time_share", "moe_expert_matmul_roofline"}
+    assert "batch.decode_step_roofline" in reported
     assert {e["name"] for e, _ in cell.readers("end_to_end")} == {
         "serve_output_tokens_per_s", "setup_s"}
 
@@ -251,8 +260,14 @@ def test_the_engines_programs_fit_a_v5e_at_16_slots(one_chip):
         assert weights + cache_bytes <= held < weights + cache_bytes + 1e6
         assert memory.alias_size_in_bytes >= cache_bytes   # updated in place
         assert memory.temp_size_in_bytes < scratch
-        # 1 attention + 3 grouped matmuls + their metadata
-        assert compiled.as_text().count(MOSAIC) == 5
+        # the kernels the cell's readers name, under the scope they sum
+        kernels = kernels_by_name_and_scope(compiled.as_text())
+        assert kernels["ragged-dot-none", "expert_ffn"] >= 3
+        if lowered is decode:
+            assert kernels["decode_attention", "decode_attention"] >= 1
+        else:
+            assert kernels["sparse_prefill_attention",
+                           "flash_attention.fwd"] >= 1
         more = held + memory.temp_size_in_bytes \
             + 8 * dsa_flops.slot_bytes(c, max_len)
         if lowered is prefill:
@@ -263,7 +278,8 @@ def test_the_engines_programs_fit_a_v5e_at_16_slots(one_chip):
 # ------------------------------------------------- a rehearsal on the CPU
 TINY = {
     "name": "tiny-indexed", "source": "none (test, an indexer)",
-    "reference": "keye_sparse_decoder", "vocab_size": 256,
+    "reference": "keye_sparse_decoder", "roofline": "dsa_flops",
+    "vocab_size": 256,
     "hidden_size": 64, "num_hidden_layers": 3, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
     "moe_intermediate_size": 32, "num_experts": 8, "num_experts_per_tok": 2,
@@ -358,10 +374,11 @@ def test_a_toy_model_with_an_indexer_runs_end_to_end_on_the_cpu(
     assert obs["cell"].reference.__name__.endswith("keye_sparse_decoder")
     assert len(obs["logit_gaps"]) == 4 and obs["logit_gap_max"] < 1e-2
     metrics = result["metrics"]
-    assert {"batch.ttft_p50_ms", "tpot_p50_ms", "dsa_selected_share",
+    assert {"batch.slot_wait_p50_ms", "batch.token_burst_gap_p50_ms",
+            "dsa_selected_share", "moe_expert_load_imbalance",
             "batch.decode_slot_utilization", "batch.decode_kv_read_share",
             "batch.prefill_padding_share", "window_compiles"} <= set(metrics)
-    assert not {"dsa_decode_step_roofline", "dsa_indexer_time_share",
+    assert not {"batch.decode_step_roofline", "dsa_indexer_time_share",
                 "dsa_sparse_prefill_attention_roofline"} & set(metrics)
     spans = program_spans.collect(obs)
     chunk = next(c for c in spans.chunks if c.get("kv_positions_present"))
@@ -550,7 +567,7 @@ def test_the_readers_on_a_synthetic_trace(monkeypatch):
     monkeypatch.setattr(scope_names, "_write_report", lambda obs: None)
     obs = _synthetic_obs()
     reads = {name: spec.load_module("metrics", name).read(obs)
-             for name in _READERS}
+             for name in _READERS + _JOINED}
     layer_us = sum(us for _n, us, _s in _STEP)                   # 1,700
     assert layer_us == 1700
     assert reads["dsa_indexer_time_share"] == pytest.approx(
@@ -559,9 +576,9 @@ def test_the_readers_on_a_synthetic_trace(monkeypatch):
         100 * 200 / layer_us)
     assert reads["dsa_sparse_attention_time_share"] == pytest.approx(
         100 * (150 + 110) / layer_us)
-    assert reads["dsa_expert_ffn_time_share"] == pytest.approx(
+    assert reads["moe_expert_ffn_time_share"] == pytest.approx(
         100 * 1000 / layer_us)
-    assert reads["dsa_routing_time_share"] == pytest.approx(
+    assert reads["moe_routing_time_share"] == pytest.approx(
         100 * (40 + 60) / layer_us)
     assert reads["dsa_prefill_selection_time_share"] == pytest.approx(
         100 * 5000 / 10_000)
@@ -572,20 +589,22 @@ def test_the_readers_on_a_synthetic_trace(monkeypatch):
     step_s = 6 * layer_us * 1e-6
     floor = dsa_flops.decode_step_bytes(c, 480, lengths) / 819e9
     assert floor > dsa_flops.decode_step_flops(c, lengths, 768) / 197e12
-    assert reads["dsa_decode_step_roofline"] == pytest.approx(
+    assert reads["decode_step_roofline"] == pytest.approx(
         100 * floor / step_s, rel=1e-3)
     # 480 (layer, expert) pairs of 3 x 2,048 x 768 and 768 rows' activations
     # against the six grouped matmuls' 6 ms a step
-    experts = dsa_flops.expert_matmul_bytes(c, 480, 768)
+    experts = moe_flops.expert_matmul_bytes(c, 480, 768)
     assert experts == (480 * 4_718_592 + 768 * (3 * 2048 + 3 * 768)) * 2
     assert experts / 819e9 > dsa_flops.expert_matmul_flops(c, 768) / 197e12
-    assert reads["dsa_expert_matmul_roofline"] == pytest.approx(
+    assert reads["moe_expert_matmul_roofline"] == pytest.approx(
         100 * experts / 819e9 / (6 * 1000e-6), rel=1e-3)
     # 6 traced calls, each a layer's share of a 7,000-token prompt
     inside = dsa_flops.prefill_attention_flops(c, 7000) / 197e12
     assert reads["dsa_sparse_prefill_attention_roofline"] == pytest.approx(
         100 * inside / (6 * 5000e-6), rel=1e-3)
-    for name in _READERS:
+    # the busiest expert's 4 rows a step against 768 over 6 x 128 pairs
+    assert reads["moe_expert_load_imbalance"] == pytest.approx(4.0)
+    for name in _READERS + _JOINED[:4]:
         assert 0 < reads[name] < 100, name
 
 
@@ -610,9 +629,29 @@ def test_a_program_without_an_indexer_reads_nothing(monkeypatch):
     parent["program_spans"] = program_spans.ProgramSpans(
         [], [{"k": 16, "tokens_kept": 1, "token_steps": 2}], [])
     no_trace = dict(obs, trace=None)
-    for name in _READERS:
+    for name in _READERS + _JOINED:
         read = spec.load_module("metrics", name).read
-        assert read(dict(other)) is None, name
-        if name != "dsa_selected_share":            # reads spans alone
+        if name in _READERS:        # the experts' are every expert cell's
+            assert read(dict(other)) is None, name
+        if name not in ("dsa_selected_share",       # read spans alone
+                        "moe_expert_load_imbalance"):
             assert read(dict(no_trace)) is None, name
         assert read(dict(parent)) is None, name
+    # what says that a configuration selects is what its program is given:
+    # an ``index_topk`` under ``program_fields`` or as a key of its own,
+    # whatever the family spells beside it (``sa_config`` here)
+    plain = {k: v for k, v in obs["cell"].config.items()
+             if k != "sa_config"}
+    unspelled = dict(plain, program_fields={
+        k: v for k, v in plain["program_fields"].items()
+        if k != "index_topk"})
+    for config, selects in ((plain, True), (unspelled, False),
+                            (dict(unspelled, index_topk=2048), True)):
+        given = dict(obs, cell=types.SimpleNamespace(
+            **{**vars(obs["cell"]), "config": config}))
+        for name in ("dsa_indexer_time_share", "dsa_select_time_share",
+                     "dsa_prefill_selection_time_share",
+                     "dsa_selected_share"):
+            read = spec.load_module("metrics", name).read
+            assert read(dict(given)) == (read(dict(obs)) if selects
+                                         else None), name

@@ -18,18 +18,22 @@ import types
 import pytest
 
 from benchmarks import run as bench_run
-from benchmarks.lib import (lfm2_flops, lfm2_names, program, program_spans,
-                            scope_names, spec, swa_names, trace_reduce)
+from benchmarks.lib import (lfm2_flops, lfm2_names, moe_flops, program,
+                            program_spans, scope_names, spec, swa_names,
+                            trace_reduce)
 from benchmarks.tests import test_rehearsal
 from benchmarks.tests.test_aot_real_widths import (  # noqa: F401
-    MOSAIC, _json, _on, compiled_kernels, one_chip, topo)
+    _json, _on, compiled_kernels, kernels_by_name_and_scope, one_chip, topo)
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 CONFIG = "lfm2-8b-a1b"
 CELL = "lfm2-8b-a1b.serve-batch-decode-wide"
-_READERS = ("lfm2_decode_step_roofline", "lfm2_expert_ffn_time_share",
-            "lfm2_routing_time_share", "lfm2_conv_mixer_time_share",
-            "lfm2_attention_time_share", "lfm2_expert_matmul_roofline")
+_READERS = ("lfm2_conv_mixer_time_share", "lfm2_attention_time_share")
+# what the cell joins for its step and its experts: one reader each for
+# every configuration (``lib/readers.py``, ``lib/moe_names.py``)
+_JOINED = ("decode_step_roofline", "moe_expert_ffn_time_share",
+           "moe_routing_time_share", "moe_expert_matmul_roofline",
+           "moe_expert_load_imbalance")
 PUBLISHED = ["conv", "conv", "full_attention", "conv", "conv", "conv",
              "full_attention", "conv", "conv", "conv", "full_attention",
              "conv", "conv", "conv", "full_attention", "conv", "conv",
@@ -110,7 +114,7 @@ def test_operations_and_bytes_by_hand():
         == 3 * 400 * 2 * 2 * 32 * 64
     assert lfm2_flops.decode_step_flops(c, lengths, 96) \
         == 2 * every * 2 + 3 * 400 * 8192 + 2 * 96 * 11_010_048
-    assert lfm2_flops.expert_matmul_bytes(c, 384, 960) \
+    assert moe_flops.expert_matmul_bytes(c, 384, 960) \
         == (384 * 11_010_048 + 960 * (3 * 2048 + 3 * 1792)) * 2
     assert lfm2_flops.expert_matmul_flops(c, 960) == 2 * 960 * 11_010_048
     # the cell's step, every expert touched at 240 rows of ~256 positions:
@@ -172,35 +176,35 @@ def test_the_readers_names_lead_to_files():
     mine = [m for m in benchmark["per_layer"]
             if m["name"].startswith("lfm2_")]
     assert [m["name"] for m in mine] == list(_READERS)
-    # appended as one block behind every entry the benchmark had (PR 37's
-    # ``mla_*`` were its last); a later PR appends behind these
-    names = [m["name"] for m in benchmark["per_layer"]]
-    first = names.index(_READERS[0])
-    assert names[first:first + len(mine)] == list(_READERS)
-    assert first > max(i for i, n in enumerate(names) if n.startswith("mla_"))
     for m in mine:
         assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_output_tokens_per_s"
         assert m["unit"] == "%"
-        assert m["layer"] in ("serve device programs", "expert layer")
+        assert m["layer"] == "serve device programs"
         assert callable(spec.load_module("metrics", m["name"]).read)
-    cells = [w["name"] for w in benchmark["workloads"]]
-    assert cells.index(CELL) == 8 and benchmark["workloads"][8]["chips"] == 1
-    assert [c["name"] for c in benchmark["configs"]].index(CONFIG) == 6
+    entry = next(w for w in benchmark["workloads"] if w["name"] == CELL)
+    assert entry["chips"] == 1 and entry["config"] == CONFIG
     assert sum(w["chips"] == 4 for w in benchmark["workloads"]) == 1
     cell = spec.Cell(CELL)
     reported = {e["name"] for e, _ in cell.readers("per_layer")}
     assert set(_READERS) <= reported
-    assert {"batch.decode_kv_read_share", "tpot_p50_ms",
+    assert {"batch.decode_kv_read_share", "batch.slot_wait_p50_ms",
             "batch.prefill_expert_dispatch_time_share",
-            "batch.decode_step_device_ms", "setup_compile_s",
+            "batch.decode_step_device_ms", "setup_cache_fetch_s",
             "window_compiles"} <= reported
-    # lib/flops.py counts a dense decoder; lib/moe_flops.py reads
-    # intermediate_size as an expert's width and lib/moe_names.py counts
-    # num_hidden_layers x num_experts pairs, 14 for this model's 12
-    assert "batch.decode_step_roofline" not in reported
+    # the step's floor is the file's (lib/lfm2_flops.py: conv states, 3
+    # attention layers), and the experts' entries are every expert cell's:
+    # an expert is ``moe_intermediate_size`` wide and 12 of the 14 layers
+    # have 32 of them, so the busiest expert is held against 12 x 32 pairs
+    assert cell.config["roofline"] == "lfm2_flops"
+    assert (moe_flops.expert_width(cell.config),
+            moe_flops.expert_layers(cell.config),
+            moe_flops.experts_held(cell.config)) == (1792, 12, 32)
+    assert {"batch.decode_step_roofline", "moe_expert_matmul_roofline",
+            "moe_expert_ffn_time_share", "moe_routing_time_share",
+            "moe_expert_load_imbalance"} <= reported
     assert not {m for m in reported if m.startswith(
-        ("moe_", "swa_", "ssm_", "mla_"))}
+        ("swa_", "ssm_", "mla_"))}
     assert {e["name"] for e, _ in cell.readers("end_to_end")} == {
         "serve_output_tokens_per_s", "setup_s"}
 
@@ -268,16 +272,17 @@ def test_the_engines_programs_fit_a_v5e_at_240_slots(one_chip):
         assert memory.alias_size_in_bytes >= cache_bytes   # updated in place
         assert memory.temp_size_in_bytes < scratch
         assert held + memory.temp_size_in_bytes < 15.75e9
-        text = compiled.as_text()
-        kernels = [line.split("=")[0].strip() for line in text.splitlines()
-                   if MOSAIC in line]
-        assert kernels and all("ragged-dot" in name for name in kernels)
+        # the kernels the cell's readers name, under the scope they sum:
+        # the grouped matmuls (attention at head 64 is XLA's)
+        kernels = kernels_by_name_and_scope(compiled.as_text())
+        assert kernels["ragged-dot-none", "expert_ffn"] >= 3
 
 
 # ------------------------------------------------- a rehearsal on the CPU
 TINY = {
     "name": "tiny-lfm2", "source": "none (test, short convolution)",
-    "reference": "lfm2_moe_decoder", "vocab_size": 256, "hidden_size": 64,
+    "reference": "lfm2_moe_decoder", "roofline": "lfm2_flops",
+    "vocab_size": 256, "hidden_size": 64,
     "num_hidden_layers": 10, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
     "moe_intermediate_size": 32, "num_experts": 8, "num_experts_per_tok": 2,
@@ -362,10 +367,12 @@ def test_a_toy_lfm2_runs_end_to_end_on_the_cpu(tree, cpu_peaks):
     assert obs["cell"].reference.__name__.endswith("lfm2_moe_decoder")
     assert len(obs["logit_gaps"]) == 4 and obs["logit_gap_max"] < 1e-2
     metrics = result["metrics"]
-    assert {"batch.ttft_p50_ms", "tpot_p50_ms",
+    assert {"batch.slot_wait_p50_ms", "batch.token_burst_gap_p50_ms",
             "batch.decode_slot_utilization", "batch.decode_kv_read_share",
+            "moe_expert_load_imbalance",
             "batch.prefill_padding_share", "window_compiles"} <= set(metrics)
-    assert not set(_READERS) & set(metrics)
+    assert not {*_READERS, "batch.decode_step_roofline",
+                "moe_expert_matmul_roofline"} & set(metrics)
     spans = program_spans.collect(obs)
     chunk = next(c for c in spans.chunks if c.get("state_rows_updated"))
     assert chunk["state_rows_updated"] == chunk["active"] * chunk["k"]
@@ -453,11 +460,11 @@ _SCOPE_OF = {_CONV_IN: "conv_proj", _SHORT_CONV: "short_conv",
              _SORT: "expert_dispatch", _ACT: "expert_ffn"}
 
 
-def _synthetic_obs(steps=16, runs=2):
+def _synthetic_obs(steps=16, runs=3):
     from ray_tpu.observability.device import instruction_key
 
     ops, modules, t = [], [], 0.0
-    for run in range(runs):
+    for _run in range(runs):
         start, body = t, []
         for _ in range(steps):
             for name, us in _STEP:
@@ -466,7 +473,7 @@ def _synthetic_obs(steps=16, runs=2):
         ops.append((start, t, "%while.7 = (s32[]) while((s32[]) %t), "
                     "body=%step"))
         ops.extend(body)
-        modules.append((start, t, f"jit_decode_k({run})"))
+        modules.append((start, t, "jit_decode_k(7)"))
         t += 1e-4
     trace = trace_reduce.Trace(
         [trace_reduce.DeviceTrace(0, ops, modules)], [], 0.0, t)
@@ -500,16 +507,16 @@ def test_the_six_readers_on_a_synthetic_trace(monkeypatch):
     monkeypatch.setattr(scope_names, "_write_report", lambda obs: None)
     obs = _synthetic_obs()
     reads = {name: spec.load_module("metrics", name).read(obs)
-             for name in _READERS}
+             for name in _READERS + _JOINED}
     step_us = sum(us for _n, us in _STEP)                        # 2,000
     assert step_us == 2000
     assert reads["lfm2_conv_mixer_time_share"] == pytest.approx(
         100 * 100 / step_us)
     assert reads["lfm2_attention_time_share"] == pytest.approx(
         100 * 300 / step_us)
-    assert reads["lfm2_routing_time_share"] == pytest.approx(
+    assert reads["moe_routing_time_share"] == pytest.approx(
         100 * 2 * 50 / step_us)
-    assert reads["lfm2_expert_ffn_time_share"] == pytest.approx(
+    assert reads["moe_expert_ffn_time_share"] == pytest.approx(
         100 * 2 * 750 / step_us)
     c = obs["cell"].config
     lengths = [250.0] * 240
@@ -517,11 +524,14 @@ def test_the_six_readers_on_a_synthetic_trace(monkeypatch):
     assert lfm2_names.chunk_medians(obs) == (12 * 960, 384, 240)
     floor = lfm2_flops.decode_step_bytes(c, 384, lengths, 240) / 819e9
     assert floor > lfm2_flops.decode_step_flops(c, lengths, 12 * 960) / 197e12
-    assert reads["lfm2_decode_step_roofline"] == pytest.approx(
+    assert reads["decode_step_roofline"] == pytest.approx(
         100 * floor / (step_us * 1e-6), rel=1e-3)
-    grouped = lfm2_flops.expert_matmul_bytes(c, 384, 12 * 960) / 819e9
-    assert reads["lfm2_expert_matmul_roofline"] == pytest.approx(
+    grouped = moe_flops.expert_matmul_bytes(c, 384, 12 * 960) / 819e9
+    assert reads["moe_expert_matmul_roofline"] == pytest.approx(
         100 * grouped / (2 * 700e-6), rel=1e-3)
+    # the busiest expert's 40 rows a step against 12 x 960 over the 12 x 32
+    # pairs that HAVE experts (14 x 32 would read 1.56)
+    assert reads["moe_expert_load_imbalance"] == pytest.approx(40 / 30)
 
 
 def test_a_program_without_a_conv_layer_reads_nothing(monkeypatch):
@@ -539,8 +549,10 @@ def test_a_program_without_a_conv_layer_reads_nothing(monkeypatch):
     parent["program_spans"] = program_spans.ProgramSpans(
         [], [{"k": 16, "tokens_kept": 1, "token_steps": 2}], [])
     no_trace = dict(obs, trace=None)
-    for name in _READERS:
+    for name in _READERS + _JOINED:
         read = spec.load_module("metrics", name).read
-        assert read(dict(other)) is None, name
-        assert read(dict(no_trace)) is None, name
+        if name in _READERS:        # the experts' are every expert cell's
+            assert read(dict(other)) is None, name
+        if name != "moe_expert_load_imbalance":     # reads spans alone
+            assert read(dict(no_trace)) is None, name
         assert read(dict(parent)) is None, name

@@ -18,8 +18,8 @@ import types
 import pytest
 
 from benchmarks import run as bench_run
-from benchmarks.lib import (moe_flops, moe_names, program_spans, spec,
-                            trace_reduce)
+from benchmarks.lib import (moe_flops, moe_names, program_spans, scope_names,
+                            spec, trace_reduce)
 from benchmarks.tests import test_rehearsal
 # ``topo`` is described inside that file's fixture (never at import);
 # ``compiled_kernels`` keeps these compiles out of the persistent cache.
@@ -157,7 +157,8 @@ def test_weights_are_made_in_their_serving_type(one_chip):
 # ------------------------------------------------- a rehearsal on the CPU
 TINY_OLMOE = {
     "name": "tiny-olmoe", "source": "none (test, experts)",
-    "reference": "olmoe_decoder", "vocab_size": 256, "hidden_size": 64,
+    "reference": "olmoe_decoder", "roofline": "moe_flops",
+    "vocab_size": 256, "hidden_size": 64,
     "num_hidden_layers": 1, "num_attention_heads": 4,
     "num_key_value_heads": 4, "head_dim": 16, "intermediate_size": 32,
     "num_experts": 8, "num_experts_per_tok": 3, "norm_topk_prob": False,
@@ -243,13 +244,12 @@ def test_traced_run_reports_the_joined_metrics_and_the_programs_own(
     left out on a CPU, not invented."""
     result, obs = _measure(tree, trace=1)
     metrics = result["metrics"]
-    assert {"batch.ttft_p50_ms", "tpot_p50_ms",
+    assert {"batch.slot_wait_p50_ms", "batch.token_burst_gap_p50_ms",
             "batch.decode_slot_utilization",
             "batch.prefill_padding_share", "window_compiles",
             "moe_expert_load_imbalance"} <= set(metrics)
-    assert "batch.decode_step_roofline" not in metrics     # dense only
     assert 1.0 <= metrics["moe_expert_load_imbalance"]["value"] <= 8.0
-    assert not {"moe_decode_step_roofline", "moe_expert_matmul_roofline",
+    assert not {"batch.decode_step_roofline", "moe_expert_matmul_roofline",
                 "moe_expert_ffn_time_share",
                 "moe_routing_time_share"} & set(metrics)
     rows, touched, _ = moe_names.chunk_medians(obs)
@@ -262,56 +262,59 @@ cpu_peaks = test_rehearsal.cpu_peaks
 # --------------------------------------- the readers on a synthetic trace
 # One layer of one decode step as a v5e trace of the cell names it (my chip
 # run, PR 26; instruction texts cut to what the readers look at), with
-# durations in microseconds.
+# durations in microseconds and the scope the program's map gives each.
 _LAYER = [
     ("%fusion.189 = f32[4,512,16]{2,1,0} fusion(f32[4,16,128] %q, "
-     "bf16[8,120,512,16,128] %k)", 400.0),                    # attention
+     "bf16[8,120,512,16,128] %k)", 400.0, "attention"),
     ("%fusion.169 = (f32[120], bf16[120,1,2048]) fusion(bf16[120,1,2048] "
-     "%x, bf16[8,2048,2048] %wo)", 16.0),                     # wo
+     "%x, bf16[8,2048,2048] %wo)", 16.0, "attn_out"),
     ("%fusion.171 = (f32[120]{0}, f32[120,64]{0,1}) fusion(bf16[120,2048] "
-     "%h, bf16[8,2048,64] %router)", 1.5),
+     "%h, bf16[8,2048,64] %router)", 1.5, "router"),
     ("%sort.31 = (f32[120,64]{0,1}, s32[120,64]{0,1}) sort(f32[120,64] "
-     "%probs, s32[120,64] %iota)", 1.0),
+     "%probs, s32[120,64] %iota)", 1.0, "router"),
     ("%sort.32 = (s32[960]{0}, s32[960]{0}) sort(s32[960] %experts, "
-     "s32[960] %iota)", 4.5),
+     "s32[960] %iota)", 4.5, "expert_dispatch"),
     ("%fusion.174 = bf16[960,2048]{1,0} fusion(bf16[120,2048] %h, "
-     "s32[1024] %order)", 13.0),                              # gather
+     "s32[1024] %order)", 13.0, "expert_dispatch"),           # gather
     ("%ragged-dot-metadata = (s32[513]{0}, s32[526]{0}, s32[526]{0}, "
      "s32[1]{0}) custom-call(s32[512]{0} %sizes), "
-     "custom_call_target=\"tpu_custom_call\"", 22.0),
+     "custom_call_target=\"tpu_custom_call\"", 22.0, "expert_dispatch"),
     ("%ragged-dot-none.1 = f32[960,1024]{1,0} custom-call(s32[1] %n, "
      "bf16[960,2048] %rows, bf16[512,2048,1024] %bitcast.213), "
-     "custom_call_target=\"tpu_custom_call\"", 540.0),
+     "custom_call_target=\"tpu_custom_call\"", 540.0, "expert_ffn"),
     ("%ragged-dot-none = f32[960,1024]{1,0} custom-call(s32[1] %n, "
      "bf16[960,2048] %rows, bf16[512,2048,1024] %bitcast.212), "
-     "custom_call_target=\"tpu_custom_call\"", 540.0),
+     "custom_call_target=\"tpu_custom_call\"", 540.0, "expert_ffn"),
     ("%convert_multiply_fusion.10 = bf16[960,1024]{1,0} fusion("
-     "f32[960,1024] %gate, f32[960,1024] %up)", 1.5),         # activation
+     "f32[960,1024] %gate, f32[960,1024] %up)", 1.5, "expert_ffn"),
     ("%ragged-dot-none.2 = f32[960,2048]{1,0} custom-call(s32[1] %n, "
      "bf16[960,1024] %act, bf16[512,1024,2048] %bitcast.214), "
-     "custom_call_target=\"tpu_custom_call\"", 520.0),
+     "custom_call_target=\"tpu_custom_call\"", 520.0, "expert_ffn"),
     ("%fusion.176 = f32[960,2048]{1,0} fusion(f32[960,2048] "
-     "%ragged-dot-none.2, s32[1024] %inverse)", 8.0),         # un-sort
+     "%ragged-dot-none.2, s32[1024] %inverse)", 8.0,
+     "expert_dispatch"),                                      # un-sort
     ("%fusion.177 = bf16[120,2048]{1,0} fusion(f32[960,2048] %fusion.176, "
-     "f32[120,8] %gates)", 10.0),                             # combine
+     "f32[120,8] %gates)", 10.0, "expert_dispatch"),          # combine
     ("%fusion.178 = bf16[120,1,2048]{2,0,1} fusion(bf16[120,1,2048] %x, "
-     "bf16[120,2048] %fusion.177)", 1.0),                     # residual
+     "bf16[120,2048] %fusion.177)", 1.0, "ffn"),              # residual
 ]
 
 
 def _synthetic_obs(layers=8, steps=16, runs=2):
+    from ray_tpu.observability.device import instruction_key
+
     ops, modules, t = [], [], 0.0
     for run in range(runs):
         start = t
         body = []
         for _ in range(steps * layers):
-            for name, us in _LAYER:
+            for name, us, _scope in _LAYER:
                 body.append((t, t + us * 1e-6, name))
                 t += us * 1e-6
         ops.append((start, t, "%while.7 = (s32[]) while((s32[]) %t), "
                     "body=%step"))
         ops.extend(body)
-        modules.append((start, t, f"jit_decode_k({run})"))
+        modules.append((start, t, "jit_decode_k(7)"))
         t += 1e-4
     # a prefill program's own grouped matmuls do not count
     ops.append((t, t + 0.05, "%ragged-dot-none.2 = f32[65536,2048]{1,0} "
@@ -322,7 +325,10 @@ def _synthetic_obs(layers=8, steps=16, runs=2):
         [trace_reduce.DeviceTrace(0, ops, modules)], [], 0.0, t + 0.05)
     cell = types.SimpleNamespace(
         config=_json("configs", "olmoe-1b-7b"),
-        workload=_json("workloads", CELL))
+        workload=_json("workloads", CELL), bench_dir=spec.BENCH_DIR,
+        name=CELL)
+    scopes = {"jit_decode_k": {instruction_key(name): (scope, "forward")
+                               for name, _us, scope in _LAYER}}
     # 100 sequences in flight, each 150 positions at the span's middle
     records = [types.SimpleNamespace(
         ok=True, got_tokens=101, sent=0.0, ttft_ms=0.0, done=2.0,
@@ -331,26 +337,30 @@ def _synthetic_obs(layers=8, steps=16, runs=2):
              "experts_touched": 16 * 8 * 60, "expert_rows_max": 300}
     return {
         "trace": trace, "cell": cell, "decode_chunk": 16,
-        "trace_span": [0.9, 1.1],
+        "trace_span": [0.9, 1.1], "scope_map": scopes,
         "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
         "log": types.SimpleNamespace(records=records),
         "program_spans": program_spans.ProgramSpans([], [chunk, chunk], []),
     }
 
 
-def test_the_five_readers_on_a_synthetic_trace():
+_READERS = ("decode_step_roofline", "moe_expert_matmul_roofline",
+            "moe_expert_ffn_time_share", "moe_routing_time_share",
+            "moe_expert_load_imbalance")
+
+
+def test_the_five_readers_on_a_synthetic_trace(monkeypatch):
+    monkeypatch.setattr(scope_names, "_write_report", lambda obs: None)
     obs = _synthetic_obs()
     reads = {name: spec.load_module("metrics", name).read(obs)
-             for name in ("moe_decode_step_roofline",
-                          "moe_expert_matmul_roofline",
-                          "moe_expert_ffn_time_share",
-                          "moe_routing_time_share",
-                          "moe_expert_load_imbalance")}
-    layer_us = sum(us for _n, us in _LAYER)                     # 2,079
+             for name in _READERS}
+    layer_us = sum(us for _n, us, _s in _LAYER)                 # 2,079
+    # what the program traced under ``expert_ffn``: the three grouped
+    # matmuls and the activation between them
     assert reads["moe_expert_ffn_time_share"] == pytest.approx(
-        100 * 1600 / layer_us)
-    # router, sorts, gather, metadata, un-sort, combine: not attention,
-    # wo, the activation or the residual
+        100 * (1600 + 1.5) / layer_us)
+    # ``router`` and ``expert_dispatch``: router, sorts, gather, the
+    # kernels' metadata, un-sort, combine; not attention, wo, the residual
     assert reads["moe_routing_time_share"] == pytest.approx(
         100 * 60 / layer_us)
     # busiest (layer, expert) 300 rows a chunk; mean 102,400 / 512 = 200
@@ -360,30 +370,41 @@ def test_the_five_readers_on_a_synthetic_trace():
     floor = moe_flops.decode_step_bytes(c, touched, context) / 819e9
     assert floor > moe_flops.decode_step_flops(c, 100, context, rows) \
         / 197e12
-    assert reads["moe_decode_step_roofline"] == pytest.approx(
+    assert reads["decode_step_roofline"] == pytest.approx(
         100 * floor / (8 * layer_us * 1e-6), rel=1e-3)
     matmul_floor = moe_flops.expert_matmul_bytes(c, touched, rows) / 819e9
     assert reads["moe_expert_matmul_roofline"] == pytest.approx(
         100 * matmul_floor / (8 * 1600e-6), rel=1e-3)
     assert reads["moe_expert_matmul_roofline"] < 100 > \
-        reads["moe_decode_step_roofline"]
+        reads["decode_step_roofline"]
 
 
-def test_a_program_without_experts_reads_nothing():
-    """A dense cell's observations (and the parent commit's, whose spans
-    carry no expert load): every reader returns None, none raises."""
+def test_a_program_without_experts_reads_nothing(monkeypatch):
+    """A dense program's observations (its map knows no expert scope, its
+    spans carry no expert load: the parent commit's too) and an untraced
+    run: every reader returns None, none raises.  What says that a cell
+    has experts is what its program traced, not a key of its file."""
+    monkeypatch.setattr(scope_names, "_write_report", lambda obs: None)
     obs = _synthetic_obs()
-    dense = dict(obs, cell=types.SimpleNamespace(
-        config=_json("configs", "internlm2-1.8b"),
-        workload=obs["cell"].workload))
     no_spans = dict(obs, program_spans=program_spans.ProgramSpans(
         [], [{"k": 16, "tokens_kept": 1, "token_steps": 2}], []))
+    dense = dict(no_spans, scope_map={
+        module: {key: ("ffn", "forward") for key in rows}
+        for module, rows in obs["scope_map"].items()})
     no_trace = dict(obs, trace=None)
-    for name in ("moe_decode_step_roofline", "moe_expert_matmul_roofline",
-                 "moe_expert_ffn_time_share", "moe_routing_time_share"):
+    for name in _READERS:
         read = spec.load_module("metrics", name).read
-        assert read(dict(dense)) is None
-        assert read(dict(no_trace)) is None
-    for name in ("moe_decode_step_roofline", "moe_expert_matmul_roofline",
+        assert read(dict(dense)) is None, name
+        if name != "moe_expert_load_imbalance":         # reads spans alone
+            assert read(dict(no_trace)) is None, name
+    for name in ("decode_step_roofline", "moe_expert_matmul_roofline",
                  "moe_expert_load_imbalance"):
         assert spec.load_module("metrics", name).read(dict(no_spans)) is None
+    # the same trace under another configuration's file: the shares are
+    # the program's scopes' whatever the file says of its experts
+    other = dict(obs, cell=types.SimpleNamespace(
+        **{**vars(obs["cell"]),
+           "config": _json("configs", "internlm2-1.8b")}))
+    for name in ("moe_expert_ffn_time_share", "moe_routing_time_share"):
+        read = spec.load_module("metrics", name).read
+        assert read(dict(other)) == read(dict(obs)) is not None

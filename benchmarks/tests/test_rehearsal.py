@@ -11,6 +11,7 @@ import json
 import os
 import shutil
 import time
+import types
 
 import pytest
 
@@ -19,6 +20,7 @@ from benchmarks.lib import spec
 
 TINY = {
     "name": "tiny", "source": "none (test)", "reference": "dense_decoder",
+    "roofline": "flops",
     "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "intermediate_size": 128, "max_position_embeddings": 256,
@@ -91,6 +93,55 @@ CELLS = {   # name -> (cell file, the real cell whose metrics it reports)
     "tiny.tiny-open": (SERVE, "internlm2-1.8b.serve-chat-busy"),
     "tiny-cut.tiny-closed": (SERVE, "internlm2-1.8b.serve-batch-decode"),
 }
+# What the ``model_config`` PR after PR 53 brings for its step and its
+# layers, at toy size: a configuration of a family the benchmark has not
+# (experts of which the chip HOLDS a range, behind a leading dense layer; a
+# latent row; a learned selection), the floor of ITS decode step as a module
+# its file names, and its cell's name in the lists of the entries that ask
+# what its program does -- no reader, and no entry of its own for any of
+# them.  The program cannot run such a model yet (ROADMAP.md Queue 2 item
+# 2), so the readers are rehearsed on what a traced run of it would hand
+# them: ``test_a_new_family_joins_by_files_and_list_entries``.
+TINY_FAMILY = dict(
+    TINY, name="tiny-latent-select", source="none (test, a new family)",
+    roofline="tiny_latent_select_flops", num_hidden_layers=3,
+    first_k_dense_replace=1, moe_intermediate_size=32, n_routed_experts=16,
+    num_experts_per_tok=2, kv_lora_rank=16, qk_rope_head_dim=8,
+    program_fields={
+        "first_dense_layers": 1, "moe_experts": 16, "moe_held": [4, 8],
+        "moe_top_k": 2, "moe_intermediate_size": 32, "kv_lora_rank": 16,
+        "index_heads": 2, "index_head_dim": 8, "index_topk": 16})
+TINY_FAMILY_CELL = "tiny-latent-select.tiny-closed"
+TINY_FAMILY_FLOOR = '''"""What a decode step of the toy family must read: the touched experts'
+matrices at an expert's own width, and of every live row the latent rows
+its selection keeps, a layer."""
+
+from benchmarks.lib import moe_flops, moe_names, swa_names
+
+
+def step_bytes(c, touched, lengths):
+    kept = sum(min(n, c["program_fields"]["index_topk"]) for n in lengths)
+    row = 2 * (c["kv_lora_rank"] + c["qk_rope_head_dim"])
+    return 2 * touched * moe_flops.expert_params(c) \\
+        + c["num_hidden_layers"] * kept * row
+
+
+def decode_step_least_s(obs):
+    lengths = swa_names._traced_lengths(obs)
+    medians = moe_names.chunk_medians(obs)
+    if lengths is None or medians is None:
+        return None
+    return step_bytes(obs["cell"].config, medians[1], lengths) \\
+        / obs["peaks"]["hbm_bytes_per_s"]
+'''
+TINY_FAMILY_JOINS = (
+    "batch.decode_step_roofline", "moe_expert_ffn_time_share",
+    "moe_routing_time_share", "moe_expert_matmul_roofline",
+    "moe_expert_load_imbalance", "dsa_indexer_time_share",
+    "dsa_select_time_share", "dsa_prefill_selection_time_share",
+    "dsa_selected_share", "mla_absorb_time_share",
+    "mla_shared_expert_time_share", "mla_held_rows_share",
+    "serve_output_tokens_per_s")
 NEW_METRIC = '''"""Requests the generator measured (a count, from its log)."""
 
 
@@ -121,9 +172,11 @@ def tree(tmp_path_factory):
     for name, traffic in TRAFFIC.items():
         drop(f"traffic/{name}.json", json.dumps(traffic))
     drop("metrics/tiny_requests_measured.py", NEW_METRIC)
+    drop("configs/tiny-latent-select.json", json.dumps(TINY_FAMILY))
+    drop("lib/tiny_latent_select_flops.py", TINY_FAMILY_FLOOR)
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         benchmark = json.load(f)
-    for config in (TINY, TINY_CUT):
+    for config in (TINY, TINY_CUT, TINY_FAMILY):
         benchmark["configs"].append(
             {"name": config["name"], "source": config["source"],
              "reduced": [cut["key"] for cut in config["reduced"]],
@@ -141,6 +194,16 @@ def tree(tmp_path_factory):
             for metric in benchmark[group]:
                 if like in metric.get("workloads", []):
                     metric["workloads"].append(name)
+    drop(f"workloads/{TINY_FAMILY_CELL}.json", json.dumps(dict(
+        SERVE, name=TINY_FAMILY_CELL, config="tiny-latent-select",
+        traffic="tiny-closed", why="test")))
+    benchmark["workloads"].append(
+        {"name": TINY_FAMILY_CELL, "config": "tiny-latent-select",
+         "traffic": "tiny-closed", "chips": 1, "why": "test"})
+    for group in ("end_to_end", "per_layer"):
+        for metric in benchmark[group]:
+            if metric["name"] in TINY_FAMILY_JOINS:
+                metric["workloads"].append(TINY_FAMILY_CELL)
     benchmark["per_layer"].append(
         {"name": "tiny_requests_measured", "unit": "count",
          "better": "higher", "source": "program_counter",
@@ -239,13 +302,141 @@ def test_traced_run_reports_per_layer_metrics_and_a_new_one(tree,
     assert metrics["tiny_requests_measured"]["value"] == \
         result["attempted"]
     assert {"chat.ttft_p50_ms", "ttft_p90_ms", "loadgen_lag_p99_ms",
-            "setup_compile_s", "window_compiles"} <= set(metrics)
+            "setup_cache_fetch_s", "window_compiles"} <= set(metrics)
     assert metrics["window_compiles"]["value"] == 0.0
     # no end-to-end metric in a traced run, and nothing a CPU cannot know
     assert "serve_tpot_p50_ms" not in metrics
     assert not any("roofline" in m or "device" in m for m in metrics)
     assert "breakdown" in result
     assert {"busy_s", "window_s"} <= set(result["device"])
+
+
+# One layer of one decode step and of a prefill of the toy family, in
+# instruction texts of the kind a v5e trace holds (``lib/moe_names.py``),
+# microseconds, and the scope the program's map gives each.
+_FAMILY_STEP = [
+    ("%fusion.1 = bf16[4,64]{1,0} fusion(bf16[4,16] %latent)", 100.0,
+     "mla_absorb"),
+    ("%fusion.2 = f32[4,2,128]{2,1,0} fusion(bf16[4,2,8] %qi)", 150.0,
+     "indexer"),
+    ("%fusion.3 = u32[4]{0} fusion(u32[4,128] %scores)", 50.0,
+     "index_select"),
+    ("%mla_decode_attention.3 = bf16[4,4,16]{2,1,0} custom-call(bf16[4,4,24]"
+     " %q), custom_call_target=\"tpu_custom_call\"", 200.0, "decode_attention"),
+    ("%fusion.4 = f32[4,16]{1,0} fusion(bf16[4,64] %h)", 40.0, "router"),
+    ("%fusion.5 = bf16[8,64]{1,0} fusion(bf16[4,64] %h, s32[8] %order)",
+     60.0, "expert_dispatch"),
+    ("%ragged-dot-none.2 = f32[8,32]{1,0} custom-call(bf16[8,64] %rows, "
+     "bf16[4,64,32] %w), custom_call_target=\"tpu_custom_call\"", 300.0,
+     "expert_ffn"),
+    ("%fusion.6 = bf16[4,64]{1,0} fusion(bf16[4,64] %h)", 100.0,
+     "shared_expert"),
+]
+_FAMILY_PREFILL = [
+    ("%fusion.7 = f32[1,64,2,64]{3,2,1,0} fusion(bf16[1,64,2,8] %qi)",
+     300.0, "indexer"),
+    ("%fusion.8 = s32[1,64]{1,0} fusion(u32[1,64,64] %scores)", 200.0,
+     "index_select"),
+    ("%fusion.9 = bf16[1,64,64]{2,1,0} fusion(bf16[1,64,64] %x)", 500.0,
+     "ffn"),
+]
+
+
+def _traced_run_of_the_family(cell):
+    """What ``run.measure`` would hand the readers after a traced run of
+    the cell: five whole decode launches of 16 steps x 2 expert layers
+    and a prefill on a chip's trace, the program's scope map, its
+    ``serve.chunk`` spans and the generator's log."""
+    from benchmarks.lib import program_spans, trace_reduce
+    from ray_tpu.observability.device import instruction_key
+
+    ops, modules, t = [], [], 0.0
+    for _launch in range(5):
+        start = t
+        for _ in range(16 * 2):
+            for name, us, _scope in _FAMILY_STEP:
+                ops.append((t, t + us * 1e-6, name))
+                t += us * 1e-6
+        modules.append((start, t, "jit_decode_k(7)"))
+        t += 1e-4
+    start = t
+    for name, us, _scope in _FAMILY_PREFILL:
+        ops.append((t, t + us * 1e-6, name))
+        t += us * 1e-6
+    modules.append((start, t, "jit_prefill(9)"))
+    scopes = {module: {instruction_key(name): (scope, "forward")
+                       for name, _us, scope in rows}
+              for module, rows in (("jit_decode_k", _FAMILY_STEP),
+                                   ("jit_prefill", _FAMILY_PREFILL))}
+    # 4 rows decoding, 40 positions each at the span's middle: 16 kept
+    records = [types.SimpleNamespace(
+        ok=True, got_tokens=21, sent=0.0, ttft_ms=0.0, done=2.0,
+        prompt_tokens=29) for _ in range(4)]
+    chunk = {"k": 16, "active": 4, "expert_rows": 16 * 2 * 2,
+             "expert_rows_elsewhere": 16 * 2 * 6,
+             "experts_touched": 16 * 3, "expert_rows_max": 16 * 1,
+             "kv_positions_present": 16 * 4 * 40,
+             "kv_positions_attended": 16 * 4 * 16}
+    return {
+        "trace": trace_reduce.Trace(
+            [trace_reduce.DeviceTrace(0, ops, modules)], [], 0.0, t),
+        "cell": cell, "decode_chunk": 16, "trace_span": [0.9, 1.1],
+        "scope_map": scopes, "chips": 1, "window_compiles": 0,
+        "program_window_compiles": 0,
+        "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
+        "log": types.SimpleNamespace(records=records),
+        "program_spans": program_spans.ProgramSpans([], [chunk] * 3, [])}
+
+
+def test_a_new_family_joins_by_files_and_list_entries(tree):
+    """A configuration with experts behind a leading dense layer, a held
+    range of them, a latent row and an ``index_topk`` among its program's
+    fields, dropped into the tree as a configuration file, a cell file
+    and ``lib/tiny_latent_select_flops.py``, its cell's name appended to
+    twelve entries' lists (``tree`` asserts that no file that was there
+    changed): the step roofline's one reader divides ITS floor, the
+    experts' entries read its scopes and kernels at its own width over
+    the experts it HOLDS, the selection's and the latent path's read its
+    scopes and spans -- each a number, from the readers the real cells
+    use."""
+    bench, benchmark_json = tree
+    cell = spec.Cell(TINY_FAMILY_CELL, bench, benchmark_json)
+    # (and the one entry every cell reports, which has no list)
+    assert {e["name"] for e, _ in cell.readers("per_layer")} \
+        == {*TINY_FAMILY_JOINS[:-1], "window_compiles"}
+    obs = _traced_run_of_the_family(cell)
+    reads = {entry["name"]: read(obs)
+             for entry, read in cell.readers("per_layer")}
+    assert all(isinstance(v, float) for v in reads.values()), reads
+    step_us = 2 * sum(us for _n, us, _s in _FAMILY_STEP)         # 2,000
+    layer_us = step_us / 2
+    # its own floor: 3 touched experts of 3 x 64 x 32, 4 rows x 16 kept
+    # latent rows of 48 bytes a layer -- over a whole launch's step
+    floor = (2 * 3 * 3 * 64 * 32 + 3 * 4 * 16 * 48) / 819e9
+    assert reads["batch.decode_step_roofline"] == pytest.approx(
+        100 * floor / (step_us * 1e-6))
+    assert reads["moe_expert_ffn_time_share"] == pytest.approx(
+        100 * 300 / layer_us)
+    assert reads["moe_routing_time_share"] == pytest.approx(
+        100 * (40 + 60) / layer_us)
+    experts = 2 * (3 * 3 * 64 * 32 + 4 * (3 * 64 + 3 * 32)) / 819e9
+    assert reads["moe_expert_matmul_roofline"] == pytest.approx(
+        100 * experts / (2 * 300e-6))
+    # the busiest of the 2 layers x 4 HELD experts against their mean
+    assert reads["moe_expert_load_imbalance"] == pytest.approx(
+        1 / (4 / (2 * 4)))
+    assert reads["dsa_indexer_time_share"] == pytest.approx(
+        100 * 150 / layer_us)
+    assert reads["dsa_select_time_share"] == pytest.approx(
+        100 * 50 / layer_us)
+    assert reads["dsa_prefill_selection_time_share"] == pytest.approx(
+        100 * 500 / 1000)
+    assert reads["dsa_selected_share"] == pytest.approx(100 * 16 / 40)
+    assert reads["mla_absorb_time_share"] == pytest.approx(
+        100 * 100 / layer_us)
+    assert reads["mla_shared_expert_time_share"] == pytest.approx(
+        100 * 100 / layer_us)
+    assert reads["mla_held_rows_share"] == pytest.approx(100 * 2 / 8)
 
 
 def test_command_refuses_without_a_chip(tree):

@@ -23,12 +23,12 @@ from benchmarks.lib import (moe_flops, program, program_spans, spec,
                             swa_flops, swa_names, trace_reduce)
 from benchmarks.tests import test_rehearsal
 from benchmarks.tests.test_aot_real_widths import (  # noqa: F401
-    MOSAIC, _json, _on, compiled_kernels, one_chip, topo)
+    _json, _on, compiled_kernels, kernels_by_name_and_scope, one_chip, topo)
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 CONFIG = "smallthinker-21b-a3b"
 CELL = "smallthinker-21b-a3b.serve-long-prompt"
-_READERS = ("swa_decode_step_roofline", "swa_decode_attention_time_share",
+_READERS = ("swa_decode_attention_time_share",
             "swa_decode_attention_roofline",
             "swa_prefill_attention_time_share",
             "swa_prefill_attention_roofline", "swa_window_kv_read_share")
@@ -87,7 +87,11 @@ def test_the_readers_names_lead_to_files():
     cell = spec.Cell(CELL)
     reported = {e["name"] for e, _ in cell.readers("per_layer")}
     assert set(_READERS) <= reported
-    assert "moe_decode_step_roofline" not in reported   # counts full keys
+    # the step's floor is the file's: lib/swa_flops.py counts a ring's keys
+    assert cell.config["roofline"] == "swa_flops"
+    assert {"batch.decode_step_roofline", "moe_expert_matmul_roofline",
+            "moe_expert_ffn_time_share", "moe_routing_time_share",
+            "moe_expert_load_imbalance"} <= reported
     assert {e["name"] for e, _ in cell.readers("end_to_end")} == {
         "serve_output_tokens_per_s", "setup_s"}
 
@@ -161,7 +165,14 @@ def test_the_engines_programs_fit_and_the_pools_occupy_their_own_bytes(
         assert memory.alias_size_in_bytes >= cache_bytes   # updated in place
         assert memory.temp_size_in_bytes < scratch
         text = compiled.as_text()
-        assert text.count(MOSAIC) == 20    # 4 attention + 16 grouped matmul
+        # the kernels the cell's readers name, under the scope they sum
+        # (AOT, PR 32: 4 attention + 16 grouped matmul and metadata)
+        kernels = kernels_by_name_and_scope(text)
+        assert kernels["ragged-dot-none", "expert_ffn"] >= 3
+        attention = ("decode_attention", "decode_attention") \
+            if lowered is decode \
+            else ("flash_prefill_attention", "flash_attention.fwd")
+        assert kernels[attention] >= 1
         assert not [line for line in text.splitlines()
                     if "dynamic-slice(" in line
                     and ",4,128]" in line.split(" dynamic-slice(")[0]]
@@ -170,7 +181,8 @@ def test_the_engines_programs_fit_and_the_pools_occupy_their_own_bytes(
 # ------------------------------------------------- a rehearsal on the CPU
 TINY_WINDOWED = {
     "name": "tiny-windowed", "source": "none (test, window layers)",
-    "reference": "smallthinker_decoder", "vocab_size": 256,
+    "reference": "smallthinker_decoder", "roofline": "swa_flops",
+    "vocab_size": 256,
     "hidden_size": 64, "num_hidden_layers": 8, "num_attention_heads": 8,
     "num_key_value_heads": 4, "head_dim": 16, "intermediate_size": 32,
     "moe_ffn_hidden_size": 32, "moe_num_primary_experts": 8,
@@ -264,12 +276,13 @@ def test_a_toy_windowed_model_runs_end_to_end_on_the_cpu(tree, cpu_peaks,
     assert obs["cell"].reference.__name__.endswith("smallthinker_decoder")
     assert len(obs["logit_gaps"]) == 4 and obs["logit_gap_max"] < 1e-2
     metrics = result["metrics"]
-    assert {"batch.ttft_p50_ms", "tpot_p50_ms", "swa_window_kv_read_share",
+    assert {"batch.slot_wait_p50_ms", "batch.token_burst_gap_p50_ms",
+            "swa_window_kv_read_share",
             "batch.decode_slot_utilization", "moe_expert_load_imbalance",
             "batch.prefill_padding_share", "window_compiles"} <= set(metrics)
-    assert not {"swa_decode_step_roofline", "swa_decode_attention_roofline",
+    assert not {"batch.decode_step_roofline", "swa_decode_attention_roofline",
                 "swa_prefill_attention_roofline",
-                "moe_decode_step_roofline"} & set(metrics)
+                "moe_expert_matmul_roofline"} & set(metrics)
     spans = program_spans.collect(obs)
     chunk = next(c for c in spans.chunks
                  if c.get("kv_full_positions_attended"))
@@ -372,7 +385,8 @@ def _synthetic_obs(steps=16, runs=2):
     trace = trace_reduce.Trace(
         [trace_reduce.DeviceTrace(0, ops, modules)], [], 0.0, t)
     cell = types.SimpleNamespace(config=_json("configs", CONFIG),
-                                 workload=_json("workloads", CELL))
+                                 workload=_json("workloads", CELL),
+                                 bench_dir=spec.BENCH_DIR, name=CELL)
     # 30 sequences in flight, each 6,000 positions at the span's middle
     records = [types.SimpleNamespace(
         ok=True, got_tokens=201, sent=0.0, ttft_ms=0.0, done=2.0,
@@ -395,7 +409,7 @@ def _synthetic_obs(steps=16, runs=2):
 def test_the_six_readers_on_a_synthetic_trace():
     obs = _synthetic_obs()
     reads = {name: spec.load_module("metrics", name).read(obs)
-             for name in _READERS}
+             for name in (*_READERS, "decode_step_roofline")}
     period_us = sum(us for _n, us in _DECODE_LAYERS)             # 3,000
     assert reads["swa_decode_attention_time_share"] == pytest.approx(
         100 * 900 / period_us)
@@ -409,7 +423,7 @@ def test_the_six_readers_on_a_synthetic_trace():
     assert swa_names.lengths_in_flight(obs, 1.0) == pytest.approx(lengths)
     step_s = 4 * period_us * 1e-6
     floor = swa_flops.decode_step_bytes(c, 8 * 60, lengths) / 819e9
-    assert reads["swa_decode_step_roofline"] == pytest.approx(
+    assert reads["decode_step_roofline"] == pytest.approx(
         100 * floor / step_s, rel=1e-3)
     attention = swa_flops.decode_attention_bytes(c, lengths) / 819e9
     assert reads["swa_decode_attention_roofline"] == pytest.approx(
@@ -418,7 +432,7 @@ def test_the_six_readers_on_a_synthetic_trace():
     band = swa_flops.prefill_attention_flops(c, 7000) / 197e12
     assert reads["swa_prefill_attention_roofline"] == pytest.approx(
         100 * band / (8 * 3000e-6), rel=1e-3)
-    for name in _READERS:
+    for name in reads:
         assert 0 < reads[name] < 100, name
 
 
@@ -446,3 +460,5 @@ def test_a_program_without_window_layers_reads_nothing():
             assert read(dict(dense)) is None, name
             assert read(dict(no_trace)) is None, name
         assert read(dict(parent)) is None, name
+    step = spec.load_module("metrics", "decode_step_roofline").read
+    assert step(dict(parent)) is None and step(dict(no_trace)) is None

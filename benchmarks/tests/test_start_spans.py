@@ -12,9 +12,11 @@ from benchmarks.lib import start_spans
 from benchmarks.tests.test_rehearsal import (  # noqa: F401 - fixtures
     _measure, cpu_peaks, tree)
 
-SERVE_METRICS = ("setup_trace_s", "setup_lower_s", "setup_before_engine_s",
-                 "setup_warmup_s", "setup_warm_unnamed_s")
-TRAIN_METRICS = ("setup_trace_s", "setup_lower_s")
+SERVE_METRICS = ("setup_trace_s", "setup_lower_s", "setup_cache_fetch_s",
+                 "setup_before_engine_s", "setup_warmup_s",
+                 "setup_warm_unnamed_s")
+TRAIN_METRICS = ("setup_trace_s", "setup_lower_s", "setup_cache_fetch_s",
+                 "train_worker_start_s")
 
 
 @pytest.fixture(scope="module")
@@ -45,6 +47,8 @@ def test_metrics_on_a_traced_tiny_cell(traced, cpu_peaks, cell, names):
     assert metrics["setup_trace_s"] > 0.0 < metrics["setup_lower_s"]
     if "train" in cell:     # no engine: the engine's metrics are left out
         assert not (set(SERVE_METRICS) - set(names)) & set(metrics)
+    else:                   # and no trainer: nor is its start
+        assert "train_worker_start_s" not in metrics
 
 
 def test_the_three_parts_of_a_serve_start_are_setup_s(traced, cpu_peaks):
@@ -94,10 +98,17 @@ def test_warm_up_is_named_to_the_loop_itself(traced, cpu_peaks):
 
 
 def test_a_train_start_names_the_worker_and_the_step(traced, cpu_peaks):
-    _result, obs, report = traced("tiny.tiny-train")
+    result, obs, report = traced("tiny.tiny-train")
     worker, = [n for n in report["tree"]
                if n["name"] == "train.worker_start"]
     assert 0.0 < worker["dur_s"] < obs["setup_s"]
+    assert result["metrics"]["train_worker_start_s"]["value"] \
+        == pytest.approx(worker["dur_s"], abs=1e-4)
+    # the seconds read back from the persistent cache are part of what
+    # the listener timed as compiles (none is kept here: 0, not absent)
+    assert 0.0 <= result["metrics"]["setup_cache_fetch_s"]["value"] \
+        == report["cache_fetch_s"] <= report["compile_phases_s"][
+            "xla_compile"]
     assert "parts_s" not in report and "warmup_s" not in report
     step, = [r for r in report["by_function"] if r["fun_name"] == "step"]
     assert step["calls"] == 1

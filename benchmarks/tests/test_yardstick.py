@@ -7,11 +7,13 @@ import json
 import os
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
 
-from benchmarks.lib import flops, loadgen, spec, trace_reduce
+from benchmarks.lib import (flops, loadgen, program_spans, readers, spec,
+                            trace_reduce)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FIXTURE = os.path.join(HERE, "fixtures", "tiny_tpu.xplane.pb")
@@ -235,6 +237,175 @@ def test_reduction_of_a_recorded_tpu_trace():
     assert all(len(n) <= 120 for n in names)
 
 
+# ------------------------------------------------- a whole launch's step
+PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
+_OP = "%fusion.1 = bf16[8] fusion(bf16[8] %x), kind=kLoop"
+
+
+def _launches(*runs, per_ms=10.0):
+    """A chip's trace of ``(module name, steps held)`` runs back to back:
+    a step is ``per_ms`` long and four ops; a run that holds fewer than
+    its program's 16 steps is the piece an edge of the trace left."""
+    ops, modules, t = [], [], 0.0
+    for name, steps in runs:
+        start = t
+        for _ in range(steps * 4):
+            ops.append((t, t + per_ms * 0.25e-3, _OP))
+            t += per_ms * 0.25e-3
+        modules.append((start, t, name))
+        t += 1e-4
+    return trace_reduce.Trace(
+        [trace_reduce.DeviceTrace(0, ops, modules)], [], 0.0, t)
+
+
+DECODE, OTHER, PREFILL = ("jit_decode_k(7)", "jit_decode_k(8)",
+                          "jit_prefill(9)")
+
+
+@pytest.mark.parametrize("runs,whole,step_ms", [
+    # both edges cut: the three launches between them are the step
+    ([(DECODE, 5), (DECODE, 16), (DECODE, 16), (DECODE, 16), (DECODE, 3)],
+     3, 10.0),
+    # PR 52's seed 2147481012: one whole launch and what an edge left of
+    # another, whichever edge (the median of the two read 182% of a floor)
+    ([(PREFILL, 9), (DECODE, 16), (DECODE, 5)], 1, 10.0),
+    ([(DECODE, 5), (DECODE, 16), (PREFILL, 9)], 1, 10.0),
+    # an edge launch that is whole (as many ops as its program's others)
+    # counts; so does every launch between a prefill at either edge
+    ([(DECODE, 16), (DECODE, 16), (DECODE, 16)], 3, 10.0),
+    ([(PREFILL, 9), (DECODE, 16), (DECODE, 16), (PREFILL, 9)], 2, 10.0),
+    # nothing whole to hold an edge launch against: not a step's time --
+    # two pieces, a lone launch, another program's launches
+    ([(DECODE, 9), (DECODE, 9)], 0, None),
+    ([(DECODE, 16)], 0, None),
+    ([(OTHER, 16), (DECODE, 16), (DECODE, 16), (OTHER, 7)], 2, 10.0),
+    ([(OTHER, 16), (DECODE, 16), (OTHER, 16)], 1, 10.0),
+])
+def test_a_step_is_a_whole_launchs(runs, whole, step_ms):
+    """``decode_step_device_ms`` and ``train_step_device_ms`` take the
+    median of the launches the traced slice holds WHOLE, and read None
+    where it holds none."""
+    trace = _launches(*runs)
+    assert len(trace.whole_runs(readers.DECODE_MODULE)) == whole
+    got = readers.decode_step_device_ms({"trace": trace, "decode_chunk": 16})
+    assert got == (None if step_ms is None else pytest.approx(step_ms))
+    # the same trace as a trainer's: a step a launch
+    train = trace_reduce.Trace(
+        [trace_reduce.DeviceTrace(
+            0, trace.devices[0].ops,
+            [(s, e, n.replace("jit_decode_k", "jit_step"))
+             for s, e, n in trace.devices[0].modules])], [], 0.0, trace.hi)
+    got = readers.train_step_device_ms({"trace": train})
+    assert got == (None if step_ms is None
+                   else pytest.approx(16 * step_ms))
+    assert readers.decode_step_device_ms({"trace": None}) is None
+
+
+@pytest.mark.parametrize("steps", [
+    [16, 16, 16, 16],           # whole steps alone
+    [7, 16, 16, 16, 5],         # the slice's edges cut the first and last
+])
+def test_the_flash_roofline_is_a_whole_steps_too(steps):
+    """The kernels' seconds are held against their share of the steps'
+    time x a WHOLE step, not divided by the count of runs: a step that the
+    trace's edge cut holds its part of the kernels and of the time."""
+    kernel = ("%flash_attention_fwd.3 = bf16[8,15,2048,64]{3,2,1,0} "
+              "custom-call(bf16[8,15,2048,64] %q), "
+              "custom_call_target=\"tpu_custom_call\"")
+    ops, modules, t = [], [], 0.0
+    for held in steps:                     # 16 x (1 ms kernel + 3 ms rest)
+        start = t
+        for _ in range(held):
+            ops += [(t, t + 1e-3, kernel), (t + 1e-3, t + 4e-3, _OP)]
+            t += 4e-3
+        modules.append((start, t, "jit_step(3)"))
+    trace = trace_reduce.Trace(
+        [trace_reduce.DeviceTrace(0, ops, modules)], [], 0.0, t)
+    c = _config("smollm2-360m")
+    obs = {"trace": trace, "peaks": PEAKS, "chips": 1, "batch": 8,
+           "seq_len": 2048, "cell": types.SimpleNamespace(config=c)}
+    least = max(flops.flash_train_flops(c, 8, 2048) / 197e12,
+                flops.flash_train_bytes(c, 8, 2048) / 819e9)
+    assert readers.train_step_device_ms(obs) == pytest.approx(64.0)
+    assert readers.flash_attention_roofline(obs) == pytest.approx(
+        100 * least / 16e-3)
+
+
+# ------------------------------------------- a configuration's own floor
+def fixed_observations(config, cell_name):
+    """What a decode step's floor is a function of, fixed: twelve
+    requests decoding at the traced span's middle with prompts of 300 to
+    3,600, five chunks' own counts of their experts and states, four
+    whole launches of 160 ms."""
+    with open(os.path.join(spec.BENCH_DIR, "workloads",
+                           cell_name + ".json")) as f:
+        workload = json.load(f)
+    cell = types.SimpleNamespace(config=config, workload=workload,
+                                 bench_dir=spec.BENCH_DIR, name=cell_name)
+    records = [types.SimpleNamespace(
+        ok=True, got_tokens=101 + 10 * i, sent=0.0, ttft_ms=100.0 + 10 * i,
+        done=2.0 + 0.1 * i, prompt_tokens=300 * (i + 1)) for i in range(12)]
+    chunks = [{"k": 16, "active": 12, "expert_rows": 16 * (500 + 8 * j),
+               "experts_touched": 16 * (150 + j),
+               "expert_rows_max": 16 * (9 + j),
+               "expert_rows_elsewhere": 16 * 700,
+               "state_rows_updated": 16 * (11 + j % 2)} for j in range(5)]
+    return {"cell": cell, "peaks": PEAKS, "decode_chunk": 16,
+            "trace": _launches(*[(DECODE, 16)] * 4),
+            "trace_span": [0.9, 1.1],
+            "log": types.SimpleNamespace(records=records),
+            "program_spans": program_spans.ProgramSpans([], chunks, [])}
+
+
+@pytest.mark.parametrize("config,cell,module,parent_s", [
+    # the seconds the PARENT's family reader (085977a: ``readers``,
+    # ``moe_names``, ``ssm_names``, ``swa_names``, ``mla_names``,
+    # ``lfm2_names``, ``dsa_names``, ``sambay_names``
+    # ``.decode_step_roofline``) priced these observations at, from its
+    # share of a 10 ms step, before it was deleted
+    ("internlm2-1.8b", "internlm2-1.8b.serve-batch-decode", "flops",
+     0.007037739286253819),
+    ("olmoe-1b-7b", "olmoe-1b-7b.serve-batch-decode", "moe_flops",
+     0.004842262918552607),
+    ("granite-4.0-h-micro", "granite-4.0-h-micro.serve-batch-decode",
+     "ssm_flops", 0.01008460215175436),
+    ("smallthinker-21b-a3b", "smallthinker-21b-a3b.serve-long-prompt",
+     "swa_flops", 0.0040333348822633045),
+    ("deepseek-v2", "deepseek-v2.serve-long-prompt", "mla_flops",
+     0.011998633714570113),
+    ("lfm2-8b-a1b", "lfm2-8b-a1b.serve-batch-decode-wide", "lfm2_flops",
+     0.005342080536892694),
+    ("keye-vl-2.0-30b-a3b", "keye-vl-2.0-30b-a3b.serve-long-prompt",
+     "dsa_flops", 0.003130993063897268),
+    ("phi-4-mini-flash-reasoning",
+     "phi-4-mini-flash-reasoning.serve-long-prompt", "sambay_flops",
+     0.01100107968493337),
+])
+def test_a_configurations_floor_is_its_familys_of_the_parent(
+        config, cell, module, parent_s):
+    """The module a served configuration names under ``roofline`` prices
+    fixed observations as the family's own reader did, to 1e-9, and
+    ``batch.decode_step_roofline``'s one reader divides it by a whole
+    launch's step."""
+    c = _config(config)
+    assert c["roofline"] == module
+    obs = fixed_observations(c, cell)
+    floor = spec.load_module("lib", module)
+    assert floor.decode_step_least_s(obs) == pytest.approx(
+        parent_s, rel=1e-9)
+    read = spec.load_module("metrics", "decode_step_roofline").read
+    assert read(obs) == pytest.approx(100 * parent_s / 10e-3, rel=1e-9)
+    assert read(dict(obs, trace=None)) is None
+    assert read(dict(obs, trace_span=[None, None])) is None
+    unnamed = dict(c)
+    del unnamed["roofline"]
+    assert read(dict(obs, cell=types.SimpleNamespace(
+        **{**vars(obs["cell"]), "config": unnamed}))) is None
+    with pytest.raises(spec.SpecError):
+        read(dict(obs, cell=types.SimpleNamespace(
+            **{**vars(obs["cell"]), "config": dict(c, roofline="none")})))
+
+
 # ------------------------------------------------------------------- spec
 def names_lead_to_files(root):
     """Every name in ``<root>/BENCHMARK.json`` against the files under
@@ -265,9 +436,23 @@ def names_lead_to_files(root):
                              cell.readers("end_to_end")}
     for m in bench["per_layer"]:
         assert m["moves"] in end_to_end
+    # one entry a question: the table has room, and no two entries are one
+    # reader of one end-to-end metric in the same cells
+    assert len(bench["per_layer"]) <= 128
+    asked = [(m["name"].rsplit(".", 1)[-1], m["moves"],
+              tuple(m.get("workloads", ()))) for m in bench["per_layer"]]
+    assert len(set(asked)) == len(asked)
+    served = {w["config"] for w in bench["workloads"]
+              if any(w["name"] in m.get("workloads", ())
+                     and m["name"].endswith("decode_step_roofline")
+                     for m in bench["per_layer"])}
     for c in bench["configs"]:
         with open(os.path.join(root, c["file"])) as f:
             cfg = json.load(f)
+        # a served configuration names the module that counts ITS step
+        if c["name"] in served:
+            floor = spec.load_module("lib", cfg["roofline"], bench_dir)
+            assert callable(floor.decode_step_least_s), c["name"]
         assert cfg["source"] == c["source"] and cfg["assumed"]
         # a cut is written out: BENCHMARK.json names the keys, the file
         # says from what to what and why (lib/spec.py's header)
