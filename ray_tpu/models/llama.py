@@ -40,7 +40,9 @@ from ray_tpu.parallel.sharding import (axes_entry, current_rules,
 
 PyTree = Any
 LAYER_KINDS = ("attention", "mamba", "window", "conv", "mamba1", "gmu",
-               "cross")
+               "cross", "kda")
+# The kinds that keep a state a slot (``state_mixer``): a model has one.
+STATE_KINDS = ("mamba", "mamba1", "conv", "kda")
 # Leaves that stay float32 whatever type the weights are served in.
 FLOAT32_LEAVES = ("router_bias",)
 # The deviation a router's selection bias is drawn with: of the order of
@@ -280,6 +282,22 @@ class LlamaConfig:
     # alone (``layer_walk``).
     ssm_inner: int = 0
     ssm_dt_rank: int = 0
+    # KDA layers (kind "kda", models/kda.py; served only, dense plane): a
+    # gated delta-rule linear attention of kda_heads heads of kda_head_dim,
+    # whose state is a (kda_head_dim, kda_head_dim) matrix a head (stored
+    # as ssm_state_dtype), behind a causal conv of kda_conv taps; its decay
+    # and output gates are low-rank, of rank kda_gate_rank; the prefill
+    # runs the rule kda_chunk positions at a time.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_gate_rank: int = 128
+    kda_chunk: int = 64
+    # An output GATE on every attending layer: what the output projection
+    # reads is multiplied by ``sigmoid(W_gate h)``, h the layer's normed
+    # input, a value an attention output (leaf ``w_attn_gate``; served
+    # only).
+    attn_gate: bool = False
     # DIFFERENTIAL attention (arXiv:2410.05258) in every attending layer:
     # query and key heads come in pairs, a pair of value heads is one value
     # of 2 x head_dim, two softmaxes are subtracted and the difference is
@@ -337,17 +355,23 @@ class LlamaConfig:
         if "mamba1" in kinds and min(self.ssm_inner, self.ssm_dt_rank) < 1:
             raise ValueError("a mamba1 layer needs ssm_inner and "
                              "ssm_dt_rank")
-        if len(kinds & {"mamba", "mamba1", "conv"}) > 1:
+        if "kda" in kinds and (
+                min(self.kda_heads, self.kda_gate_rank) < 1
+                or self.kda_conv < 2 or self.kda_chunk % 8):
+            raise ValueError("a kda layer needs kda_heads, kda_gate_rank, "
+                             "kda_conv of 2 or more and a kda_chunk of "
+                             "whole sublane tiles")
+        if len(kinds & set(STATE_KINDS)) > 1:
             raise ValueError(
-                "mamba, mamba1 and conv layers keep their states under "
+                "mamba, mamba1, conv and kda layers keep their states under "
                 "the same leaves of the serving cache (ssm, conv): they "
                 "do not mix, one kind of state-keeping layer a model")
-        if "window" in kinds and kinds & {"mamba", "conv"}:
+        if "window" in kinds and kinds & {"mamba", "conv", "kda"}:
             raise ValueError(
                 "window rings beside a recurrent or conv state are built "
                 "and held to a reference for Mamba-1 layers alone (a "
-                "decoder-hybrid-decoder): window and mamba or conv layers "
-                "do not mix")
+                "decoder-hybrid-decoder): window and mamba, conv or kda "
+                "layers do not mix")
         self._check_cross_decoder(kinds)
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm is over the whole projection, "
@@ -617,7 +641,7 @@ class LlamaConfig:
             or self.rope_scaling is not None or self.first_dense_layers
             or self.moe_held or self.qk_head_norm
             or self.moe_router_score != "softmax" or self.moe_router_bias
-            or self.layer_norm or self.attn_bias)
+            or self.layer_norm or self.attn_bias or self.attn_gate)
 
     @property
     def attn_scale(self) -> float:
@@ -800,6 +824,12 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         from ray_tpu.models import mamba1
 
         axes["layers"].update(mamba1.param_axes(config))
+    if config.layers_of("kda"):
+        from ray_tpu.models import kda
+
+        axes["layers"].update(kda.param_axes(config))
+    if config.attn_gate:
+        axes["layers"]["w_attn_gate"] = ("layers", "embed", "heads")
     if config.layers_of("gmu"):
         axes["layers"].update(gmu_in=("layers", "embed", "mlp"),
                               gmu_out=("layers", "mlp", "embed"))
@@ -957,6 +987,16 @@ def init_params(rng: jax.Array, config: LlamaConfig,
         params["layers"].update(mamba1.init_params(
             jax.random.fold_in(rng, 91), c, c.layers_of("mamba1"), dtype,
             dense))
+    if c.layers_of("kda"):
+        from ray_tpu.models import kda
+
+        params["layers"].update(kda.init_params(
+            jax.random.fold_in(rng, 78), c, c.layers_of("kda"), dtype,
+            dense))
+    if c.attn_gate:
+        params["layers"]["w_attn_gate"] = dense(
+            jax.random.fold_in(rng, 77), (La, c.hidden_size, c.o_dim),
+            c.hidden_size)
     if c.layers_of("gmu"):
         Lg, kg = c.layers_of("gmu"), jax.random.split(
             jax.random.fold_in(rng, 90), 2)
@@ -1569,6 +1609,21 @@ def split_expert_stacks(layers: Dict[str, jax.Array],
             {k: layers[k] for k in EXPERT_STACKS})
 
 
+def gate_attention(x: jax.Array, attn: jax.Array,
+                   layer: Dict[str, jax.Array], config: LlamaConfig):
+    """An attending layer's output gate (``attn_gate``): ``attn * sigmoid(
+    W_gate h)``, h the layer's normed input (``_qkv_rope``'s, which XLA
+    computes once), elementwise over what the output projection reads.
+    x (B, S, D) the layer's input; attn (B, S, heads, head_dim)."""
+    c = config
+    with jax.named_scope("qkv_proj"):
+        h = norm(x, layer, "attn_norm", c).astype(c.dtype)
+        gate = matmul(h, layer["w_attn_gate"].astype(c.dtype), jnp.float32)
+    with jax.named_scope("attn_out"):
+        return (attn.astype(jnp.float32)
+                * jax.nn.sigmoid(gate).reshape(attn.shape)).astype(attn.dtype)
+
+
 def residual_add(x: jax.Array, branch: jax.Array,
                  config: LlamaConfig) -> jax.Array:
     """``x + residual_multiplier * branch``: both branches of a layer."""
@@ -1749,7 +1804,7 @@ def embed(params: PyTree, tokens: jax.Array,
 # each) and the weight of the norm over a pair's 2 x head_dim value.
 DIFF_LEAVES = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2", "sub_norm")
 ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm",
-                    "bq", "bk", "bv", "bo") + DIFF_LEAVES
+                    "bq", "bk", "bv", "bo", "w_attn_gate") + DIFF_LEAVES
 
 
 def _leaf_kind(name: str) -> Optional[str]:
@@ -1761,6 +1816,8 @@ def _leaf_kind(name: str) -> Optional[str]:
         return "conv"
     if name.startswith("gmu_"):
         return "gmu"
+    if name.startswith("kda_"):
+        return "kda"
     return "mamba" if name.startswith("ssm_") else None
 
 
@@ -1863,6 +1920,10 @@ def state_mixer(kind: str):
         from ray_tpu.models import mamba1
 
         return mamba1, "ssm_proj", "ssm_out"
+    if kind == "kda":
+        from ray_tpu.models import kda
+
+        return kda, "ssm_proj", "ssm_out"
     from ray_tpu.models import mamba2
 
     return mamba2, "ssm_proj", "ssm_out"
@@ -1906,6 +1967,8 @@ def layer_block(x, layer, kind: str, config: LlamaConfig, sin, cos,
         if c.diff_attention:
             attn = diff_combine(attn, layer,
                                 c.layer_offset + layer_index(*at), c)
+        if c.attn_gate:
+            attn = gate_attention(x, attn, layer, c)
         return attn_out_ffn(x, attn, layer, c, **rows_and_place()) + (ys,)
     if kind == "gmu":
         with jax.named_scope("gmu"):
@@ -2013,7 +2076,8 @@ def walk_layers(carry, params: PyTree, config: LlamaConfig, block: Callable,
             return carry, (stack_period(ys["attention"], c),
                            stack_period(rows, c),
                            stack_period(
-                               ys["mamba"] + ys["mamba1"] + ys["conv"], c),
+                               sum((ys[kind] for kind in STATE_KINDS), []),
+                               c),
                            stack_period(ys["window"], c))
 
         # ``layer_scan``: the loop's own slicing of a layer's weights and

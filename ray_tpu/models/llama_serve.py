@@ -78,7 +78,8 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     recurrent state ``ssm (Lm, B, N, nh x hd)`` (stored as
     ``cfg.ssm_state_dtype``) and conv window ``conv (Lm, K - 1, B,
     conv_dim)``; for its short-convolution layers ``conv (Lc, taps - 1,
-    B, D)`` alone.  A state is not positional: ``build_prefill`` replaces a
+    B, D)`` alone; for its KDA layers ``ssm (Lk, B, H, d, d)``, a matrix a
+    head, and ``conv (Lk, K - 1, B, 3 H d)``.  A state is not positional: ``build_prefill`` replaces a
     slot's whole state, ``decode_step`` advances it in place."""
     if cfg.layers_of("window") or cfg.kv_layer is not None:
         # (a decoder-hybrid-decoder: the pools its kinds of layer ask for
@@ -109,6 +110,10 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
 
         cache.update(shortconv.init_state(cfg, cfg.layers_of("conv"),
                                           slots))
+    if cfg.layers_of("kda"):
+        from ray_tpu.models import kda
+
+        cache.update(kda.init_state(cfg, cfg.layers_of("kda"), slots))
     return cache
 
 
@@ -202,7 +207,8 @@ def insert_states(cache, states, slots):
         for name, new in zip(names, states)}}
 
 
-def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
+def decode_step(cfg: LlamaConfig, params, s_active: int, active,
+                keep_logits: bool = False) -> Callable:
     """The shared per-token decode step (scan body): a row write of
     each slot's new K/V at its current position, cache attention
     over the row's keys among the first ``s_active`` positions
@@ -221,7 +227,9 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     two planes' tokens bit-identical.  The speculative DRAFT model
     runs this step with its own ``cfg`` on its own dense cache.  The
     step's ys are ``(tokens, expert rows)``: the (L, E) rows each
-    layer's experts computed, None for a dense model.  Experts compute
+    layer's experts computed, None for a dense model (and, with
+    ``keep_logits``, the step's (B, V) logits: what a check against a
+    reference reads; no serving program asks for them).  Experts compute
     ``active`` slots only, and read their ``[L, E, ...]`` matrices in
     place (the stacks are closed over, not sliced by the layer scan).
 
@@ -232,7 +240,9 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
     tok, lens, ssm, conv)`` -- through both loops and are updated in
     place (``mamba2.decode``, ``ops/ssm_state_update.py``); an inactive
     slot's states are left as they are.  A short-convolution layer does
-    the same with the one state it keeps, ``(ck, cv, tok, lens, conv)``.
+    the same with the one state it keeps, ``(ck, cv, tok, lens, conv)``, a
+    KDA layer with its matrix states and conv tails
+    (``ops/kda_state_update.py``).
     A model with window layers carries its two pools as rows (``init_
     cache``): ``ck`` / ``cv`` the full layers' and, after the lengths,
     the window layers' rings; a window layer writes its new row at
@@ -377,8 +387,8 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
 
                 def state_step(mixer, h):
                     # A state-keeping layer (Mamba-2, Mamba-1, short
-                    # convolution): its layer of the stacked states, in
-                    # place.
+                    # convolution, KDA): its layer of the stacked states,
+                    # in place.
                     nonlocal memory
                     m = llama.layer_index(p, n_of(kind), i)
                     if l0:
@@ -443,7 +453,8 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active) -> Callable:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt = jnp.where(active, nxt, tok)
             lens = lens + active.astype(jnp.int32)
-        return (ck, cv, nxt, lens, *state), (nxt, expert_rows)
+        return (ck, cv, nxt, lens, *state), (nxt, expert_rows) + (
+            (logits,) if keep_logits else ())
 
     return step
 

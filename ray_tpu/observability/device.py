@@ -604,6 +604,7 @@ SCOPES = (
     "indexer", "index_select", "sparse_attention",
     "mamba1_scan", "mamba1_state_update", "gmu", "cross_attention",
     "diff_combine",
+    "kda_chunk", "kda_state_update", "kda_gates",
     "head", "sample", "head_loss", "optimizer",
 )
 PHASES = ("forward", "backward", "remat")

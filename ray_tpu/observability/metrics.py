@@ -472,6 +472,13 @@ def serve_engine_counters():
             "advanced a state, one read and one write of the slot's "
             "states over all state-space layers",
             tag_keys=("deployment", "kind")),
+        # A model with KDA layers only.
+        "kda_slots_advanced": Counter(
+            "ray_tpu_serve_kda_slots_advanced_total",
+            "(slot, step) pairs whose decode step advanced the slot's "
+            "delta-rule states: each is one read and one write of a "
+            "(heads, d, d) matrix state a KDA layer",
+            tag_keys=("deployment",)),
         # A model with experts only (a dense one never touches these).
         "moe_expert_rows": Counter(
             "ray_tpu_serve_moe_expert_rows_total",

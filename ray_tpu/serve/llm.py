@@ -387,7 +387,7 @@ class LLMServer:
         ) if on]
         stateful = [name for kind, name in (
             ("mamba", "state-space"), ("mamba1", "state-space"),
-            ("conv", "short-convolution"))
+            ("conv", "short-convolution"), ("kda", "linear-attention"))
             if self.cfg.layers_of(kind)]
         if asked and stateful:
             raise ValueError(
@@ -1988,7 +1988,15 @@ class LLMServer:
             total += moved
             self._engine_metrics["state_bytes"].inc(
                 moved, tags=self._state_tags[kind])
-        return {"state_rows_updated": rows, "state_bytes": total}
+        kda = {}
+        if self.cfg.layers_of("kda"):
+            # the matrix states alone, which ``ops/kda_state_update.py``
+            # moves: what its roofline is counted from
+            kda = {"kda_slots_advanced": rows,
+                   "kda_state_bytes": 2 * rows * self._state_bytes["ssm"]}
+            self._engine_metrics["kda_slots_advanced"].inc(
+                rows, tags=self._tags)
+        return {"state_rows_updated": rows, "state_bytes": total, **kda}
 
     def _record_prefill_group(self, t0: float, t1: float, bucket: int,
                               lens: np.ndarray, real: int,
@@ -2008,10 +2016,11 @@ class LLMServer:
         m["prefill_padded_tokens"].inc(computed, tags=self._tags)
         scan = {}
         if "ssm" in self._state_bytes:
-            # chunks of the state-space scan the padded group computed,
-            # a Mamba layer
-            scan["scan_chunks"] = rows * -(-bucket // min(
-                bucket, self.cfg.ssm_chunk))
+            # chunks of the state-space scan (of the delta rule) the
+            # padded group computed, a Mamba (a KDA) layer
+            chunk = self.cfg.kda_chunk if self.cfg.layers_of("kda") \
+                else self.cfg.ssm_chunk
+            scan["scan_chunks"] = rows * -(-bucket // min(bucket, chunk))
         if self._ring:
             # of the bucket's score square, the share inside a window
             # layer's band (what its attention has to compute)

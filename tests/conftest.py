@@ -175,44 +175,3 @@ def shutdown_only():
     ray_tpu.shutdown()
 
 
-@pytest.fixture(autouse=True)
-def _benchmark_as_pr_37_left_it(request, monkeypatch, tmp_path):
-    """``benchmarks/tests/test_deepseek_v2_cell.py::
-    test_the_readers_names_lead_to_files`` asserts that ITS configuration,
-    cell and metrics are the LAST entries of ``BENCHMARK.json``: true of
-    the file PR 37 wrote, of no file a later PR appends to -- and a later
-    PR may neither edit a benchmark file nor put its entries anywhere but
-    the end.  That one test reads ``BENCHMARK.json`` as far as its own
-    entries go (what later cells appended is cut off, from the lists and
-    from the metrics' ``workloads``); everything else it checks is the
-    real tree.  A ``benchmark`` PR can drop the three assertions, and this
-    with them."""
-    if (request.node.name != "test_the_readers_names_lead_to_files"
-            or "deepseek_v2" not in request.node.nodeid):
-        return
-    import json
-
-    from benchmarks.lib import spec
-
-    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
-        benchmark = json.load(f)
-
-    def upto(entries, last):
-        at = max(i for i, e in enumerate(entries) if last(e["name"]))
-        return entries[:at + 1]
-
-    benchmark["configs"] = upto(benchmark["configs"],
-                                lambda n: n == "deepseek-v2")
-    benchmark["workloads"] = upto(
-        benchmark["workloads"], lambda n: n == "deepseek-v2.serve-long-prompt")
-    benchmark["per_layer"] = upto(benchmark["per_layer"],
-                                  lambda n: n.startswith("mla_"))
-    cells = {w["name"] for w in benchmark["workloads"]}
-    for group in ("end_to_end", "per_layer"):
-        for metric in benchmark[group]:
-            if "workloads" in metric:
-                metric["workloads"] = [w for w in metric["workloads"]
-                                       if w in cells]
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(benchmark))
-    monkeypatch.setattr(spec, "ROOT", str(tmp_path))
-

@@ -333,13 +333,51 @@ def test_yarn_table_by_hand():
 
 
 # ------------------------------------------------------------- the share
-def test_the_four_shares_of_an_expert_layer_sum_to_the_uncut_layer():
-    """16 experts over 4 ranks of 4 (a routing group each): every rank
-    routes over all 16 and adds its own experts' part; the four parts,
-    the shared expert and the stream counted once, are the uncut
+def _solar_cfg(**kw):
+    """Solar-Open2's expert layer at toy widths: a sigmoid router with a
+    selection bias, normalised gates, one shared expert."""
+    return LlamaConfig(**{**dict(
+        vocab_size=VOCAB, hidden_size=64, n_layers=4, n_heads=8,
+        n_kv_heads=2, head_dim=8, intermediate_size=128,
+        max_seq_len=MAX_LEN, norm_eps=1e-5, tie_embeddings=False,
+        remat=False, dtype=jnp.float32,
+        layer_pattern=("attention", "kda", "kda", "kda"), rope=False,
+        attn_gate=True, kda_heads=4, kda_head_dim=16, kda_gate_rank=8,
+        kda_chunk=8, moe_experts=16, moe_top_k=4, moe_norm_topk=True,
+        moe_intermediate_size=32, moe_shared_size=32,
+        moe_router_score="sigmoid", moe_router_bias=True), **kw})
+
+
+def _uncut_deepseek(x, params, K):
+    return reference._ffn(
+        x, {k: params["layers"][k] for k in (
+            "mlp_norm", "router", "w_gate", "w_up", "w_down", "ws_gate",
+            "ws_up", "ws_down")},
+        jnp.int32(0), 1e-6, False, 0, 4, 2, K, 16.0, False, 32)
+
+
+def _uncut_solar(x, params, K):
+    from benchmarks.references import solar_open2_decoder
+
+    return solar_open2_decoder._ffn(
+        x, {k: v[0] for k, v in params["layers"].items() if k in (
+            "mlp_norm", "router", "router_bias", "w_gate", "w_up", "w_down",
+            "ws_gate", "ws_up", "ws_down")}, 1e-5, 0, K, True, 1.0)[0]
+
+
+@pytest.mark.parametrize("make,ranks,uncut_layer", [
+    (_cfg, 4, _uncut_deepseek), (_solar_cfg, 8, _uncut_solar)],
+    ids=["deepseek-v2", "solar-open2-250b"])
+def test_the_four_shares_of_an_expert_layer_sum_to_the_uncut_layer(
+        make, ranks, uncut_layer):
+    """16 experts over 4 ranks of 4 (a routing group each; DeepSeek-V2) or
+    8 ranks of 2 (Solar-Open2: sigmoid scores, a selection bias, normalised
+    gates): every rank routes over all 16 and adds its own experts' part;
+    the parts, the shared expert and the stream counted once, are the uncut
     reference's layer.  And the rows: held + elsewhere = tokens x top-k on
     every rank, the held ones summing to it over the ranks."""
-    whole = _cfg(moe_held=())
+    whole = make(moe_held=())
+    held = 16 // ranks
     params = llama.init_params(jax.random.key(5), whole)
     full = {k: v[0] for k, v in params["layers"].items()}
     rng = np.random.default_rng(9)
@@ -349,22 +387,18 @@ def test_the_four_shares_of_an_expert_layer_sum_to_the_uncut_layer():
     shared = (jax.nn.silu(h @ full["ws_gate"]) * (h @ full["ws_up"])) \
         @ full["ws_down"]
     routed, held_rows = 0.0, 0
-    for rank in range(4):
-        cfg = _cfg(moe_held=(4 * rank, 4))
-        layer = {k: (v[4 * rank:4 * rank + 4] if k in llama.EXPERT_STACKS
-                     else v) for k, v in full.items()}
+    for rank in range(ranks):
+        cfg = make(moe_held=(held * rank, held))
+        layer = {k: (v[held * rank:held * (rank + 1)]
+                     if k in llama.EXPERT_STACKS else v)
+                 for k, v in full.items()}
         out, _aux, rows = llama.ffn_half(x, layer, cfg)
-        assert rows.shape == (5,)
+        assert rows.shape == (held + 1,)
         assert int(rows.sum()) == T * K          # held + elsewhere
-        held_rows += int(rows[:4].sum())
+        held_rows += int(rows[:held].sum())
         routed = routed + (out - x - shared)
     assert held_rows == T * K
-    uncut = reference._ffn(
-        x.reshape(T, 64),
-        {k: params["layers"][k] for k in (
-            "mlp_norm", "router", "w_gate", "w_up", "w_down", "ws_gate",
-            "ws_up", "ws_down")},
-        jnp.int32(0), 1e-6, False, 0, 4, 2, K, 16.0, False, 32)
+    uncut = uncut_layer(x.reshape(T, 64), params, K)
     ours = (x + shared + routed).reshape(T, 64)
     assert float(jnp.abs(ours - uncut).max()) < 1e-4
     # the uncut program is that layer too
