@@ -40,8 +40,12 @@ sub-blocks of ``_SUB`` rows, a sub-block against the ones before it through
 its own first row (two factors, both <= 1, a matmul), against itself by the
 ``(_SUB, _SUB, d)`` broadcast (``_decayed_products``).  Everything that
 does not read the state is computed for ``_BLOCK`` positions at once; the
-state walks the chunks in a scan.  XLA's, all of it: no kernel yet (PERF.md
-section 7).  A padded position takes ``g = 0`` and ``b = 0``: it neither
+state walks the chunks in order.  At a state Mosaic tiles (``d`` whole
+128-lane tiles: the published widths) a block's chunks are ONE kernel
+(``ops/kda_chunk.py``: the same form with everything between q, k, v, g and
+``o`` in VMEM); ``chunk_rule`` here is XLA's form, which a toy preset's
+shape keeps and the tests hold the kernel against.  A padded position takes
+``g = 0`` and ``b = 0``: it neither
 decays the state nor writes it, so a row's state is that of ITS OWN last
 real position, and its conv state its last ``K - 1`` real pre-conv inputs.
 
@@ -288,6 +292,13 @@ def _block_len(P: int, chunk: int) -> int:
     return -(-chunks // blocks) * chunk
 
 
+def padded_len(P: int, chunk: int) -> int:
+    """Positions a row of ``P`` goes through the chunked rule: whole
+    blocks of ``_block_len``."""
+    T = _block_len(P, chunk)
+    return -(-P // T) * T
+
+
 def prefill(h: jax.Array, layer, c, lengths: Optional[jax.Array]):
     """The mixer over right-padded prompts from empty states.
 
@@ -295,6 +306,8 @@ def prefill(h: jax.Array, layer, c, lengths: Optional[jax.Array]):
     every position is real).  Returns (out (G, P, D), (state (G, H, d, d)
     in the state's storage type, conv state (K - 1, G, 3 H d))), both as
     of each row's last real position."""
+    from ray_tpu.ops.kda_chunk import kda_chunk
+
     G, P, _ = h.shape
     H, d, K = c.kda_heads, c.kda_head_dim, c.kda_conv
     hd = dims(c)[0]
@@ -326,7 +339,7 @@ def prefill(h: jax.Array, layer, c, lengths: Optional[jax.Array]):
                 qkv, jax.lax.dynamic_slice_in_dim(low, at, T, 1), layer, c,
                 live)
         with jax.named_scope("kda_chunk"):
-            o, S = chunk_rule(q, k, v, g, b, S, c.kda_chunk)
+            o, S = kda_chunk(q, k, v, g, b, S, c.kda_chunk)
         with jax.named_scope("kda_gates"):
             return S, _gated_norm(o, z, layer, c)
 

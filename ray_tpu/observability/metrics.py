@@ -479,6 +479,12 @@ def serve_engine_counters():
             "delta-rule states: each is one read and one write of a "
             "(heads, d, d) matrix state a KDA layer",
             tag_keys=("deployment",)),
+        "kda_chunk_positions": Counter(
+            "ray_tpu_serve_kda_chunk_positions_total",
+            "padded prompt positions x KDA layers the prefill launches "
+            "sent through the chunked delta rule's kernel "
+            "(ops/kda_chunk.py); a shape that keeps XLA's form adds none",
+            tag_keys=("deployment",)),
         # A model with experts only (a dense one never touches these).
         "moe_expert_rows": Counter(
             "ray_tpu_serve_moe_expert_rows_total",

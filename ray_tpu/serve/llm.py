@@ -2018,9 +2018,21 @@ class LLMServer:
         if "ssm" in self._state_bytes:
             # chunks of the state-space scan (of the delta rule) the
             # padded group computed, a Mamba (a KDA) layer
-            chunk = self.cfg.kda_chunk if self.cfg.layers_of("kda") \
-                else self.cfg.ssm_chunk
+            kda_layers = self.cfg.layers_of("kda")
+            chunk = self.cfg.kda_chunk if kda_layers else self.cfg.ssm_chunk
             scan["scan_chunks"] = rows * -(-bucket // min(bucket, chunk))
+            if kda_layers:
+                # positions x KDA layers that went through
+                # ``ops/kda_chunk.py`` (0 where the shape kept XLA's form):
+                # what its roofline is counted from
+                from ray_tpu.models.kda import padded_len
+                from ray_tpu.ops.kda_chunk import engages
+
+                scan["kda_chunk_positions"] = (
+                    rows * padded_len(bucket, chunk) * kda_layers
+                    if engages(self.cfg.kda_head_dim, chunk) else 0)
+                m["kda_chunk_positions"].inc(
+                    scan["kda_chunk_positions"], tags=self._tags)
         if self._ring:
             # of the bucket's score square, the share inside a window
             # layer's band (what its attention has to compute)

@@ -1,4 +1,5 @@
-"""The KDA mixer (``ray_tpu/models/kda.py``) and its decode kernel
+"""The KDA mixer (``ray_tpu/models/kda.py``), its prefill kernel
+(``ray_tpu/ops/kda_chunk.py``) and its decode kernel
 (``ray_tpu/ops/kda_state_update.py``) against the recurrence as it is
 written, token by token, in float32."""
 
@@ -9,6 +10,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import kda, llama
+from ray_tpu.ops import kda_chunk as chunk_op
 from ray_tpu.ops import kda_state_update as op
 
 
@@ -70,6 +72,77 @@ def test_a_padded_position_neither_decays_nor_writes():
     np.testing.assert_allclose(got[:1], want, atol=2e-5, rtol=2e-5)
 
 
+def _chunk_kernel(*args, chunk=64):
+    # a jit of its own a call: the heads a step are read at trace time
+    return jax.jit(lambda *a: chunk_op.kda_chunk(*a, chunk))(*args)
+
+
+@pytest.mark.parametrize("T,heads_a_step", [(64, 2), (128, 2), (1024, 1)])
+def test_the_chunk_kernel_is_the_rule_is_the_recurrence(T, heads_a_step,
+                                                        monkeypatch):
+    """Interpret mode, at the smallest state Mosaic tiles (d = 128) in the
+    cell's chunks of 64, two blocks of heads, from a non-zero state, over
+    1, 2 and 16 chunks: ``o`` and the state are the recurrence's and
+    ``chunk_rule``'s, with decays that fall by > 100 a chunk (where
+    ``e^-G`` overflows) and ``b`` past 1.5."""
+    H = 2 * heads_a_step
+    q, k, v, g, b, S = _inputs(T, 1, T, H, 128)
+    assert float(b.max()) > 1.5
+    assert float(jnp.cumsum(g[:, :64], 1).min()) < -100
+    assert chunk_op.engages(128, 64)
+    monkeypatch.setattr(chunk_op, "_HEADS", heads_a_step)
+    got_o, got_S = _chunk_kernel(q, k, v, g, b, S)
+    want_o, want_S = jax.jit(_recurrence)(q, k, v, g, b, S)
+    xla_o, xla_S = jax.jit(kda.chunk_rule, static_argnums=6)(
+        q, k, v, g, b, S, 64)
+    for want in ((want_o, want_S), (xla_o, xla_S)):
+        np.testing.assert_allclose(got_o, want[0], atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(got_S, want[1], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("lengths", [(37, 128), (64, 100), (1, 65)])
+def test_the_chunk_kernel_neither_decays_nor_writes_at_a_padded_position(
+        lengths, monkeypatch):
+    """Rows padded from different lengths: a row's state is that of ITS
+    last real position -- the recurrence's over the real positions, and for
+    a row that ends inside the first chunk bit for bit what a call over
+    that chunk alone returns."""
+    monkeypatch.setattr(chunk_op, "_HEADS", 1)
+    q, k, v, g, b, S = _inputs(11, 2, 128, 1, 128)
+    at = jnp.asarray(lengths)
+    live = jnp.arange(128)[None, :] < at[:, None]
+    g = jnp.where(live[..., None, None], g, 0.0)
+    b = jnp.where(live[..., None], b, 0.0)
+    got_o, got_S = _chunk_kernel(q, k, v, g, b, S)
+    for row, n in enumerate(lengths):
+        want_o, want_S = jax.jit(_recurrence)(
+            *(x[row:row + 1, :n] for x in (q, k, v, g, b)), S[row:row + 1])
+        np.testing.assert_allclose(got_S[row:row + 1], want_S, atol=2e-5,
+                                   rtol=2e-5)
+        np.testing.assert_allclose(got_o[row:row + 1, :n], want_o,
+                                   atol=2e-5, rtol=2e-5)
+    # the first chunk alone, both rows: the second chunk of a row that
+    # ended before it changes nothing
+    _, short = _chunk_kernel(*(x[:, :64] for x in (q, k, v, g, b)), S)
+    ended = np.asarray(lengths) <= 64
+    assert ended.any()
+    np.testing.assert_array_equal(got_S[ended], short[ended])
+
+
+@pytest.mark.parametrize("d,chunk", [(16, 16), (32, 64), (128, 24),
+                                     (128, 128)])
+def test_a_chunk_mosaic_cannot_tile_keeps_the_xla_form(d, chunk):
+    """By shape: a state that is not whole 128-lane tiles, a chunk that is
+    not whole sub-blocks or leaves the kernel no room: ``chunk_rule``'s own
+    arrays, exactly."""
+    assert not chunk_op.engages(d, chunk)
+    q, k, v, g, b, S = _inputs(9, 1, 2 * chunk, 2, d)
+    got = _chunk_kernel(q, k, v, g, b, S, chunk=chunk)
+    want = jax.jit(kda.chunk_rule, static_argnums=6)(q, k, v, g, b, S, chunk)
+    for a, w in zip(got, want):
+        np.testing.assert_array_equal(a, w)
+
+
 def _config(**kw):
     return llama.LlamaConfig(**{**dict(
         vocab_size=64, hidden_size=32, n_layers=4, n_heads=4, n_kv_heads=2,
@@ -105,6 +178,35 @@ def test_prefill_then_decode_is_one_recurrence(P, lengths):
     ssm, conv = state[None], conv[None]
     active = jnp.array([True, True])
     for step in range(6):
+        at = lengths + step
+        tok = jnp.take_along_axis(h, at[:, None, None], axis=1)
+        out, ssm, conv = kda.decode(tok, layer, c, ssm, conv,
+                                    jnp.int32(0), active)
+        for row in range(2):
+            np.testing.assert_allclose(out[row, 0], want[row, at[row]],
+                                       atol=1e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("P,lengths", [(64, (64, 23)), (70, (64, 3))])
+def test_prefill_then_decode_is_one_recurrence_through_the_kernels(
+        P, lengths):
+    """The same hand-over at a state Mosaic tiles (d = 128, chunks of 64):
+    ``prefill`` through ``ops/kda_chunk.py``, ``decode`` through
+    ``ops/kda_state_update.py``, both interpreted."""
+    c = _config(kda_head_dim=128, kda_chunk=64)
+    assert chunk_op.engages(c.kda_head_dim, c.kda_chunk)
+    layer = _layer(c)
+    total = P + 3
+    h = jax.random.normal(jax.random.key(1), (2, total, c.hidden_size))
+    want, _ = kda.prefill(h, layer, c, None)
+    lengths = jnp.array(lengths)
+    out, (state, conv) = kda.prefill(h[:, :P], layer, c, lengths)
+    for row, n in enumerate(lengths):
+        np.testing.assert_allclose(out[row, :n], want[row, :n], atol=1e-5,
+                                   rtol=1e-4)
+    ssm, conv = state[None], conv[None]
+    active = jnp.array([True, True])
+    for step in range(3):
         at = lengths + step
         tok = jnp.take_along_axis(h, at[:, None, None], axis=1)
         out, ssm, conv = kda.decode(tok, layer, c, ssm, conv,
@@ -182,18 +284,22 @@ def test_a_state_mosaic_cannot_tile_is_updated_by_xla():
 
 
 def test_kda_is_imported_where_a_layer_asks_for_it():
-    """Another model's start does not pay for this one: neither module is
-    loaded by the modules every engine imports."""
+    """Another model's start does not pay for this one: none of the three
+    modules is loaded by the modules every engine imports, and the two
+    kernels' only where ``kda.prefill`` / ``kda.decode`` ask for them."""
     import subprocess
     import sys
 
     out = subprocess.run(
         [sys.executable, "-c",
          "import sys, ray_tpu.serve.llm, ray_tpu.models.llama_serve;"
+         "print([m for m in sys.modules if 'kda' in m]);"
+         "import ray_tpu.models.kda;"
          "print([m for m in sys.modules if 'kda' in m])"],
         capture_output=True, text=True, check=True,
         env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"})
-    assert out.stdout.strip() == "[]", out.stdout
+    assert out.stdout.split("\n")[:2] == ["[]", "['ray_tpu.models.kda']"], \
+        out.stdout
 
 
 def test_a_config_is_refused_what_it_cannot_serve():

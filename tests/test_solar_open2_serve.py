@@ -203,7 +203,7 @@ def test_a_broken_variant_fails_the_reference(model, variant):
 
 # ------------------------------------------------------------- the engine
 def test_llm_server_serves_the_model_and_counts_the_state_it_moves(
-        model, traced):
+        model, traced, monkeypatch):
     from ray_tpu.serve import llm
 
     cfg, params, published = model
@@ -241,6 +241,19 @@ def test_llm_server_serves_the_model_and_counts_the_state_it_moves(
         assert c["kda_state_bytes"] == 2 * c["kda_slots_advanced"] * state
         assert c["expert_rows"] > 0
     assert pools["state_pool"]["bytes_per_slot"]["ssm"] == state
+    # a toy state (d = 16) keeps XLA's chunked rule: no position went
+    # through ``ops/kda_chunk.py``; at a state the kernel takes, a launch's
+    # padded positions (whole blocks of chunks) x the three KDA layers
+    groups = [e["args"] for e in events if e["name"] == "serve.prefill_group"]
+    assert groups and all(g["kda_chunk_positions"] == 0 for g in groups)
+    from ray_tpu.ops import kda_chunk
+
+    monkeypatch.setattr(kda_chunk, "engages", lambda d, chunk: True)
+    server._record_prefill_group(0.0, 1.0, 20, np.array([9, 20]), 2)
+    newest = [e["args"] for e in traced.export_timeline()
+              if e.get("name") == "serve.prefill_group"][-1]
+    assert newest["kda_chunk_positions"] == 2 * 24 * 3
+    assert newest["token_positions"] == 2 * 20
     with pytest.raises(ValueError, match="linear-attention"):
         llm.LLMServer(model_preset=name, params=params, max_slots=2,
                       max_len=MAX_LEN, paged=True, warmup=False)
