@@ -61,6 +61,20 @@ ATTENDING_KINDS = ("attention", "window", "cross")
 # the reference.  And that of a differential attention's lambda vectors.
 BIAS_STD = 0.02
 LAMBDA_STD = 0.1
+# What a sandwich norm's weight starts at (``sandwich_norm``).  The norm
+# erases the scale of the branch's output projection, so the (2 L)^-1/2 a
+# residual-scaled initialiser gives that projection (0.32 at 5 layers, 0.125
+# at 32) has to sit in the norm's own weight; and the embedding is then NOT
+# drawn down by its multiplier, so that tokens enter at unit rms beside the
+# branches.  At 1 / 1 every branch enters at 45 x the embedding at hidden
+# 2,048, every position's stream is nearly the same vector and a sigmoid
+# router sends most tokens to the same few experts: one chip's share of the
+# rows then swings by +-45% from seed to seed at the first step (at toy
+# width 545-1,431 rows of an expected 1,024, with this 760-1,109; on the
+# v5e 30.1k-36.9k of 32.8k with it).  What the share does AFTER the first
+# step is the learning rate's: ``lr_warmup_steps`` (PERF.md section 6, PR
+# 57).
+POST_NORM_INIT = 0.3
 
 
 def _runs(kinds, cuts=()):
@@ -161,6 +175,23 @@ class LlamaConfig:
     # chosen SCORES, without it (LFM2).
     moe_router_score: str = "softmax"
     moe_router_bias: bool = False
+    # Training balances that bias without an auxiliary loss (DeepSeek-V3,
+    # arXiv:2412.19437 section 2.1.2): after each step and an expert layer,
+    # ``b += d - mean(d)``, ``d = moe_balance_rate x sign(mean(c) - c)``, c
+    # the step's tokens an expert was chosen by (``balance_router_bias``).
+    # The bias takes no gradient, no weight decay and no Adam moments.
+    moe_balance_rate: float = 0.001
+    # The train step's learning rate rises linearly over this many steps
+    # (step n runs at ``learning_rate x min(1, n / lr_warmup_steps)``, n
+    # from 1); 0: the rate from the first step, as every plain decoder is
+    # stepped.  That balance presumes it: at the whole rate Adam's first
+    # updates are the gradient's SIGN, every row of a router moves by the
+    # rate along what the tokens' streams have in common, a step shifts an
+    # expert's logit for all tokens alike by ~0.5 at hidden 2,048 against
+    # the bias's 0.001, and every token chooses the same experts within
+    # twenty steps (seen on the v5e: a held share's rows swing between 0.3
+    # and 2.8 x their expectation, PERF.md section 6, PR 57).
+    lr_warmup_steps: int = 0
     # RMSNorm on q and on k, each over its WHOLE projection, before the
     # split into heads and before RoPE (OLMoE); or over each HEAD's
     # head_dim, one weight of head_dim shared by the heads, before RoPE
@@ -310,6 +341,11 @@ class LlamaConfig:
     # of RMSNorm, and biases on the attention projections.
     layer_norm: bool = False
     attn_bias: bool = False
+    # SANDWICH norms (Trinity / AFMoE, Gemma 2): an RMSNorm on each
+    # branch's OUTPUT as well, before it is added to the stream -- ``x +
+    # post_attn_norm(attn W_o)``, ``x + post_mlp_norm(ffn)`` (leaves beside
+    # ``attn_norm`` / ``mlp_norm``, a weight of hidden_size a layer each).
+    sandwich_norm: bool = False
     # A part's first layer's index among all (``parts`` sets it): what a
     # differential layer's lambda_init is a function of.
     layer_offset: int = 0
@@ -376,6 +412,10 @@ class LlamaConfig:
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm is over the whole projection, "
                              "qk_head_norm over a head: choose one")
+        if self.sandwich_norm and (
+                self.layer_norm or kinds - {"attention", "window"}):
+            raise ValueError("sandwich_norm is built as RMSNorm on the "
+                             "branches of attention and window layers")
         if self.moe_router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_router_score {self.moe_router_score!r}")
         if self.moe_groups and (self.moe_router_score != "softmax"
@@ -629,19 +669,33 @@ class LlamaConfig:
 
     @property
     def plain_decoder(self) -> bool:
-        """What ``llama.forward`` TRAINS and a pipeline stage runs; any
-        other config is served only.  ``forward`` goes through the walk that
-        serves them (``walk_layers``), which computes most of these terms:
+        """What ``llama.forward`` TRAINS; any other config is served only.
+        ``forward`` goes through the walk that serves them
+        (``walk_layers``), which computes most of the refused terms too:
         they are refused because no test and no cell holds their backward,
-        not for want of a code path (ROADMAP Queue 2)."""
-        return self.one_kv_stack and not (
-            self.attention_multiplier is not None
-            or self.embedding_multiplier != 1.0 or self.logits_scaling != 1.0
-            or self.nope_kinds or self.moe_router_input != "ffn"
-            or self.rope_scaling is not None or self.first_dense_layers
-            or self.moe_held or self.qk_head_norm
-            or self.moe_router_score != "softmax" or self.moe_router_bias
-            or self.layer_norm or self.attn_bias or self.attn_gate)
+        not for want of a code path (ROADMAP Queue 2).  Trained: layers of
+        softmax attention, full or over a window, in one pattern or a
+        ``layer_types`` list with leading dense layers; RoPE, NoPE or one
+        a kind; q/k norms; an output gate; sandwich norms; an embedding
+        multiplier; SwiGLU or routed experts (softmax or sigmoid scores, a
+        selection bias, a shared expert, a held share)."""
+        return not (
+            self.kv_lora_rank or self.index_topk or self.diff_attention
+            or any(self.layers_of(kind) for kind in LAYER_KINDS
+                   if kind not in ("attention", "window"))
+            or self.attention_multiplier is not None
+            or self.logits_scaling != 1.0
+            or self.moe_router_input != "ffn"
+            or self.rope_scaling is not None
+            or self.layer_norm or self.attn_bias)
+
+    @property
+    def one_stage_stack(self) -> bool:
+        """What a PIPELINE stage runs (``llama_pipeline``): a trained
+        config whose layers are one stack of one kind, which a stage
+        slices by layer."""
+        return self.plain_decoder and self.one_kv_stack \
+            and not self.first_dense_layers
 
     @property
     def attn_scale(self) -> float:
@@ -792,6 +846,9 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     if config.qk_norm or config.qk_head_norm:
         axes["layers"]["q_norm"] = ("layers", None)
         axes["layers"]["k_norm"] = ("layers", None)
+    if config.sandwich_norm:
+        axes["layers"]["post_attn_norm"] = ("layers", None)
+        axes["layers"]["post_mlp_norm"] = ("layers", None)
     if config.moe_router_bias:
         axes["layers"]["router_bias"] = ("layers", "expert")
     if config.moe_shared_size:
@@ -926,7 +983,8 @@ def init_params(rng: jax.Array, config: LlamaConfig,
         }
     embed_tokens = dense(keys[0], (c.vocab_size, c.hidden_size),
                          c.hidden_size)
-    if c.embedding_multiplier != 1.0:
+    if c.embedding_multiplier != 1.0 and not c.sandwich_norm:
+        # (sandwich norms: ``POST_NORM_INIT``.)
         # Drawn so that the embedding the LAYERS see (x the multiplier)
         # is the one every other configuration starts from.  Drawn at
         # that scale itself and tied to the head, a row's own logit is
@@ -957,6 +1015,11 @@ def init_params(rng: jax.Array, config: LlamaConfig,
     if c.qk_head_norm:
         params["layers"]["q_norm"] = jnp.ones((La, c.head_dim), dtype)
         params["layers"]["k_norm"] = jnp.ones((La, c.head_dim), dtype)
+    if c.sandwich_norm:
+        params["layers"]["post_attn_norm"] = jnp.full(
+            (L, c.hidden_size), POST_NORM_INIT, dtype)
+        params["layers"]["post_mlp_norm"] = jnp.full(
+            (L, c.hidden_size), POST_NORM_INIT, dtype)
     if c.moe_experts > 0 and c.moe_router_bias:
         params["layers"]["router_bias"] = ROUTER_BIAS_STD * jax.random.normal(
             jax.random.fold_in(rng, 94), (L, c.moe_experts), jnp.float32)
@@ -1625,8 +1688,13 @@ def gate_attention(x: jax.Array, attn: jax.Array,
 
 
 def residual_add(x: jax.Array, branch: jax.Array,
-                 config: LlamaConfig) -> jax.Array:
-    """``x + residual_multiplier * branch``: both branches of a layer."""
+                 config: LlamaConfig,
+                 post_norm: Optional[jax.Array] = None) -> jax.Array:
+    """``x + residual_multiplier * branch``: both branches of a layer.
+    ``post_norm``: the weight of the branch's own output norm
+    (``sandwich_norm``), applied first."""
+    if post_norm is not None:
+        branch = rms_norm(branch, post_norm, config.norm_eps)
     if config.residual_multiplier != 1.0:
         branch = branch * config.residual_multiplier
     return x + branch.astype(x.dtype)
@@ -1635,7 +1703,8 @@ def residual_add(x: jax.Array, branch: jax.Array,
 def attn_out_ffn(x: jax.Array, attn: jax.Array,
                  layer: Dict[str, jax.Array], config: LlamaConfig,
                  valid: Optional[jax.Array] = None,
-                 layer_index: Optional[jax.Array] = None):
+                 layer_index: Optional[jax.Array] = None,
+                 training: bool = False):
     """Output projection + FFN half of an attending layer
     (``layer_block``'s; the conventions shared with ``_qkv_rope`` live
     here).  Returns what ``ffn_half`` does.  ``x`` is the layer's input:
@@ -1648,15 +1717,18 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
                                     ("heads", "embed"))
         if config.attn_bias:
             out = out + layer["bo"].astype(config.dtype)
-        x = residual_add(x, out, config)
-    return ffn_half(x, layer, config, valid, layer_index, route_x)
+        x = residual_add(x, out, config, layer["post_attn_norm"]
+                         if config.sandwich_norm else None)
+    return ffn_half(x, layer, config, valid, layer_index, route_x,
+                    training)
 
 
 @jax.named_scope("ffn")
 def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
              config: LlamaConfig, valid: Optional[jax.Array] = None,
              layer_index: Optional[jax.Array] = None,
-             route_x: Optional[jax.Array] = None):
+             route_x: Optional[jax.Array] = None,
+             training: bool = False):
     """The FFN half of a layer, after whichever mixer (attention's output
     projection, a Mamba-2 mixer) has been added to ``x``.  ``route_x``:
     what the router reads where that is not the normed ``x``
@@ -1667,7 +1739,10 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     for a dense config.  ``valid`` (broadcastable to (B, S)) marks the
     rows that are real; experts compute no others.  With ``layer_index``
     the expert matrices in ``layer`` are the whole ``[L, E, ...]`` stacks
-    (``split_expert_stacks``), read in place.
+    (``split_expert_stacks``), read in place.  ``training``:
+    ``moe.moe_ffn_dropless``'s -- the expert rows are then the router's
+    CHOICES counted over all ``moe_experts``, held here or not: what the
+    balance update of a selection bias reads (``balance_router_bias``).
 
     All of it is scope ``ffn``; an expert layer's ``router``,
     ``expert_dispatch`` and ``expert_ffn`` (``models/moe.py``) lie inside
@@ -1676,6 +1751,7 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     dt = c.dtype
     x = with_logical_constraint(x, "batch", "seq", None)
     h = norm(x, layer, "mlp_norm", c).astype(dt)
+    post_norm = layer["post_mlp_norm"] if c.sandwich_norm else None
     if c.moe_experts == 0:
         gate = scattered_grad_matmul(h, layer["w_gate"].astype(dt),
                                      ("embed", "mlp"))
@@ -1688,7 +1764,8 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
         ff = checkpoint_name(jax.nn.silu(gate) * up, "ffn_act")
         ff = with_logical_constraint(ff, "batch", "seq", "mlp")
         x = residual_add(x, scattered_grad_matmul(
-            ff, layer["w_down"].astype(dt), ("mlp", "embed")), c)
+            ff, layer["w_down"].astype(dt), ("mlp", "embed")), c,
+            post_norm)
         return (with_logical_constraint(x, "batch", "seq", None),
                 jnp.zeros((), jnp.float32), None)
     from ray_tpu.models import moe
@@ -1714,11 +1791,11 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     elif c.moe_dispatch_chunk and h.shape[1] > c.moe_dispatch_chunk:
         ff, aux, expert_rows = _dispatch_in_chunks(
             h, moe_params, mcfg, valid, layer_index, route_x,
-            c.moe_dispatch_chunk)
+            c.moe_dispatch_chunk, training)
     else:
         ff, aux, expert_rows = moe.moe_ffn_dropless(
             h, moe_params, mcfg, valid=valid, layer_index=layer_index,
-            route_x=route_x)
+            route_x=route_x, training=training)
     if c.moe_shared_size:
         # the shared expert: a plain SwiGLU every token passes, added to
         # what its routed experts gave
@@ -1726,13 +1803,13 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
             shared = jax.nn.silu(matmul(h, layer["ws_gate"].astype(dt))) \
                 * matmul(h, layer["ws_up"].astype(dt))
             ff = ff + matmul(shared, layer["ws_down"].astype(dt))
-    x = residual_add(x, ff, c)
+    x = residual_add(x, ff, c, post_norm)
     return with_logical_constraint(x, "batch", "seq", None), aux, \
         expert_rows
 
 
 def _dispatch_in_chunks(h, moe_params, mcfg, valid, layer_index, route_x,
-                        chunk: int):
+                        chunk: int, training: bool = False):
     """``moe.moe_ffn_dropless`` over h (B, S, D), at most ``chunk``
     positions at a time and one chunk after another (a dispatch mixes no
     positions): ``(ff, aux averaged, expert rows summed)``.  The chunks
@@ -1760,7 +1837,8 @@ def _dispatch_in_chunks(h, moe_params, mcfg, valid, layer_index, route_x,
         return moe.moe_ffn_dropless(
             args[0], moe_params, mcfg, valid=args[1],
             layer_index=layer_index,
-            route_x=args[2] if len(args) > 2 else None)
+            route_x=args[2] if len(args) > 2 else None,
+            training=training)
 
     ff, aux, rows = jax.lax.map(one, xs)
     return (jnp.moveaxis(ff, 0, 1).reshape(B, S, D), aux.mean(),
@@ -1958,7 +2036,9 @@ def layer_block(x, layer, kind: str, config: LlamaConfig, sin, cos,
         return dict(
             valid=valid[:, None] if getattr(valid, "ndim", 0) == 1
             else valid,
-            layer_index=None if at is None else layer_index(*at))
+            layer_index=None if at is None else layer_index(*at),
+            # the training walk scans the experts' stacks (``at`` None)
+            training=at is None)
 
     if c.kv_lora_rank or kind in ATTENDING_KINDS:
         fresh = (latent_down(x, layer, sin, cos, c) if c.kv_lora_rank
@@ -2119,26 +2199,34 @@ def embed_sharded(params: PyTree, tokens, config: LlamaConfig):
     with jax.named_scope("embed"):
         emb = with_logical_constraint(
             params["embed_tokens"].astype(config.dtype), "vocab", None)
-        return with_logical_constraint(emb[tokens], "batch", "seq", None)
+        x = emb[tokens]
+        if config.embedding_multiplier != 1.0:      # as ``embed``
+            x = x * config.embedding_multiplier
+        return with_logical_constraint(x, "batch", "seq", None)
 
 
 def head_loss_logits(x, params: PyTree, config: LlamaConfig):
     """Training's final norm and head, in the scope ``loss_fn`` goes on in."""
     with jax.named_scope("head_loss"):
         x = norm(x, params, "final_norm", config)
-        return with_logical_constraint(
-            scattered_grad_matmul(x, lm_head(params, config),
-                                  ("embed", "vocab")),
-            "batch", "seq", "vocab")
+        logits = scattered_grad_matmul(x, lm_head(params, config),
+                                       ("embed", "vocab"))
+        if config.logits_scaling != 1.0:            # as ``head_logits``
+            logits = logits / config.logits_scaling
+        return with_logical_constraint(logits, "batch", "seq", "vocab")
 
 
 def train_block(config: LlamaConfig, sin, cos, positions) -> Callable:
-    """``walk_block`` as training runs it: the rows attended as they are,
-    nothing kept, under the config's remat."""
+    """``walk_block`` as training runs it: the rows attended as they are
+    (a window layer's through the same function, told its band), nothing
+    kept, under the config's remat."""
     attention_fn = _get_attention_fn(config)
     block = walk_block(
         sin, cos, positions, lambda q, k, v, positions, _cache: (
-            attention_fn(q, k, v, positions), None))
+            attention_fn(q, k, v, positions), None),
+        lambda q, k, v, positions: (
+            attention_fn(q, k, v, positions, window=config.window_size),
+            None))
     if config.remat:
         block = jax.checkpoint(block, policy=_remat_policy(config),
                                static_argnums=(4, 5))
@@ -2146,33 +2234,44 @@ def train_block(config: LlamaConfig, sin, cos, positions) -> Callable:
 
 
 def train_layers(x, params: PyTree, config: LlamaConfig, positions):
-    """``walk_layers`` as training runs it: x (B, S, D) -> ``(x, aux sum)``."""
+    """``walk_layers`` as training runs it: x (B, S, D) -> ``(x, aux sum,
+    the router's choices an expert layer and expert, (L, E) int32; None for
+    a dense config)``."""
     block = train_block(config, *rope_for(positions, config), positions)
 
     def summing(carry, *layer_args):
         x, aux, rows, ys, _memory = block(carry[0], *layer_args)
         return (x, carry[1] + aux), rows, ys
 
-    return walk_layers((x, jnp.zeros((), jnp.float32)), params, config,
-                       summing, scan_experts=True)[0]
+    (x, aux), (_kv, rows, _states, _windows) = walk_layers(
+        (x, jnp.zeros((), jnp.float32)), params, config, summing,
+        scan_experts=True)
+    return x, aux, rows
 
 
 def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             positions: Optional[jax.Array] = None,
-            return_aux: bool = False):
+            return_aux: bool = False, return_expert_rows: bool = False):
     """Logits for next-token prediction.  tokens: (B, S) int32.
 
     With ``return_aux=True`` returns (logits, aux) where aux is the
-    summed MoE load-balancing loss over layers (0.0 for dense)."""
+    summed MoE load-balancing loss over layers (0.0 for dense); with
+    ``return_expert_rows`` also, last, the router's choices an expert
+    layer and expert ((L, E) int32; None for a dense config)."""
     c = config
     if not c.plain_decoder:
         raise NotImplementedError(
-            "llama.forward (training) computes a stack of one kind of "
-            "attention layer with the default scale, embedding, logits and "
-            "router (LlamaConfig.plain_decoder): a config with state-space, "
-            "short-convolution or window layers, latent attention, a list "
-            "of layer_types or any other term of that property is served "
-            "only (llama_serve.build_*)")
+            "llama.forward (training) computes layers of softmax attention, "
+            "full or over a window, with the default scale and logits and a "
+            "router on the FFN's input (LlamaConfig.plain_decoder): a "
+            "config with state-space, short-convolution, cross or gmu "
+            "layers, latent or differential attention, an indexer or any "
+            "other term of that property is served only "
+            "(llama_serve.build_*)")
+    if c.layers_of("window") and c.attention_impl == "ring":
+        raise NotImplementedError(
+            "attention_impl='ring' has no band: window layers train "
+            "through 'flash' or 'dot'")
     if positions is not None and c.attention_impl != "dot":
         # flash/ring mask on raw row index, not positions — packed or
         # offset sequences would silently attend across boundaries.
@@ -2188,8 +2287,13 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
     from ray_tpu.parallel.sharding import current_mesh
 
     mesh = current_mesh()
+    expert_rows = None
     if (c.pipeline_microbatches > 0 and mesh is not None
             and mesh.shape.get("pipe", 1) > 1):
+        if not c.one_stage_stack:
+            raise NotImplementedError(
+                "pipeline stages slice one stack of one kind of layer "
+                "(LlamaConfig.one_stage_stack)")
         if c.moe_experts > 0:
             raise NotImplementedError(
                 "MoE layers inside pipeline stages are not supported "
@@ -2220,16 +2324,25 @@ def forward(params: PyTree, tokens: jax.Array, config: LlamaConfig,
             batch_axes=batch_axes)
         aux_total = jnp.zeros((), jnp.float32)
     else:
-        x, aux_total = train_layers(x, params, c, positions)
+        x, aux_total, expert_rows = train_layers(x, params, c, positions)
 
     logits = head_loss_logits(x, params, c)
-    return (logits, aux_total) if return_aux else logits
+    out = (logits,) + ((aux_total,) if return_aux else ()) \
+        + ((expert_rows,) if return_expert_rows else ())
+    return out if len(out) > 1 else logits
 
 
 def loss_fn(params: PyTree, batch: Dict[str, jax.Array],
             config: LlamaConfig) -> jax.Array:
     """Mean next-token cross-entropy.  batch: tokens (B,S) int32,
     optional loss_mask (B,S)."""
+    return loss_and_expert_rows(params, batch, config)[0]
+
+
+def loss_and_expert_rows(params: PyTree, batch: Dict[str, jax.Array],
+                         config: LlamaConfig):
+    """``(loss_fn's loss, forward's expert rows)``: what the train step
+    differentiates, the rows its auxiliary output."""
     tokens = batch["tokens"]
     positions = batch.get("positions")
     if positions is None:
@@ -2237,17 +2350,18 @@ def loss_fn(params: PyTree, batch: Dict[str, jax.Array],
         # position's logits, instead of slicing tokens to S-1: a
         # 2047-long sequence does not tile the flash kernel's blocks
         # (it would take the kernel's pad-and-slice path every step).
-        logits, aux = forward(params, tokens, config, return_aux=True)
+        logits, aux, rows = forward(params, tokens, config, return_aux=True,
+                                    return_expert_rows=True)
         logits = logits[:, :-1]
     else:
         # Packed/offset positions (dot-attention path): keep the old
         # S-1 slice so the last raw token never becomes a key — at full
         # length a small positions[S-1] (new-document start) would be
         # attended by every later-positioned query.
-        logits, aux = forward(params, tokens[:, :-1], config,
-                              positions=positions[:, :-1],
-                              return_aux=True)
-    return next_token_loss(logits, batch, config, aux)
+        logits, aux, rows = forward(params, tokens[:, :-1], config,
+                                    positions=positions[:, :-1],
+                                    return_aux=True, return_expert_rows=True)
+    return next_token_loss(logits, batch, config, aux), rows
 
 
 def next_token_loss(logits: jax.Array, batch: Dict[str, jax.Array],
@@ -2277,13 +2391,76 @@ def next_token_loss(logits: jax.Array, batch: Dict[str, jax.Array],
 # Train step
 # ---------------------------------------------------------------------------
 
-def default_optimizer(learning_rate: float = 3e-4):
+def warmup_rate(learning_rate: float, warmup_steps: int, step):
+    """The rate of update ``step`` (from 1) under a linear warm-up over
+    ``warmup_steps``; the rate itself, untouched, without one."""
+    if not warmup_steps:
+        return learning_rate
+    return learning_rate * jnp.minimum(
+        1.0, step.astype(jnp.float32) / warmup_steps)
+
+
+def default_optimizer(learning_rate: float = 3e-4, warmup_steps: int = 0):
     import optax
 
     return optax.chain(
         optax.clip_by_global_norm(1.0),
-        optax.adamw(learning_rate, weight_decay=0.1),
+        # (optax counts the updates already made: from 0)
+        optax.adamw((lambda count: warmup_rate(
+            learning_rate, warmup_steps, count + 1))
+            if warmup_steps else learning_rate, weight_decay=0.1),
     )
+
+
+# Leaves of ``params`` that no gradient trains: the optimizer state has no
+# moments for them, the clipped norm no part of them.
+UNTRAINED_LEAVES = ("router_bias",)
+
+
+def split_untrained(params: PyTree):
+    """``(params without UNTRAINED_LEAVES, {stack: {leaf: value}})``: the
+    tree the optimizer sees and what the step updates by rule.  A tree
+    with no such leaf comes back as it is, beside {}."""
+    fixed = {key: {k: sub[k] for k in UNTRAINED_LEAVES if k in sub}
+             for key, sub in params.items() if isinstance(sub, dict)}
+    fixed = {key: sub for key, sub in fixed.items() if sub}
+    if not fixed:
+        return params, fixed
+    return {key: ({k: v for k, v in sub.items() if k not in fixed[key]}
+                  if key in fixed else sub)
+            for key, sub in params.items()}, fixed
+
+
+def merge_untrained(trained: PyTree, fixed) -> PyTree:
+    """``split_untrained``'s inverse."""
+    if not fixed:
+        return trained
+    return {key: ({**sub, **fixed[key]} if key in fixed else sub)
+            for key, sub in trained.items()}
+
+
+def balance_router_bias(fixed, expert_rows: jax.Array,
+                        config: LlamaConfig):
+    """The selection biases after a step (``moe_balance_rate``): an expert
+    chosen by fewer tokens than the layer's mean goes up by the rate, one
+    chosen by more down, and the step is centred so that the biases keep
+    their mean.  ``fixed``: ``split_untrained``'s, a ``router_bias`` (L,
+    E) float32 a stack of expert layers; ``expert_rows`` (all expert
+    layers, E) int32 in the layers' order, every expert counted whether
+    held here or not (``forward``'s).  Scope ``router_balance``."""
+    out, at = {}, 0
+    with jax.named_scope("router_balance"):
+        for _part, key, _ in config.parts():
+            if key not in fixed:
+                continue
+            bias = fixed[key]["router_bias"]
+            chosen = expert_rows[at:at + bias.shape[0]].astype(jnp.float32)
+            at += bias.shape[0]
+            d = config.moe_balance_rate * jnp.sign(
+                chosen.mean(-1, keepdims=True) - chosen)
+            out[key] = {**fixed[key],
+                        "router_bias": bias + d - d.mean(-1, keepdims=True)}
+    return out
 
 
 def init_train_state(rng: jax.Array, config: LlamaConfig,
@@ -2316,13 +2493,14 @@ def _train_state_builder(config: LlamaConfig, optimizer, fused: bool,
     if fused:
         from ray_tpu.train.optim import fused_adamw_init as opt_init
     else:
-        opt_init = (optimizer or default_optimizer()).init
+        opt_init = (optimizer or default_optimizer(
+            warmup_steps=config.lr_warmup_steps)).init
 
     def build(rng):
         params = init_params(rng, config)
         return {
             "params": params,
-            "opt_state": opt_init(params),
+            "opt_state": opt_init(split_untrained(params)[0]),
             "step": jnp.zeros((), jnp.int32),
         }
 
@@ -2334,14 +2512,14 @@ def _train_state_builder(config: LlamaConfig, optimizer, fused: bool,
         lambda axes: logical_sharding(axes, mesh, rules),
         param_logical_axes(config),
         is_leaf=lambda v: isinstance(v, tuple))
-    params_def = jax.tree.structure(param_shardings)
+    # (the optimizer's trees are shaped like the params it trains)
+    by_def = {jax.tree.structure(tree): tree for tree in (
+        split_untrained(param_shardings)[0], param_shardings)}
     replicated = logical_sharding((), mesh, rules)
     shardings = jax.tree.map(
-        lambda sub: (param_shardings
-                     if jax.tree.structure(sub) == params_def
-                     else replicated),
+        lambda sub: by_def.get(jax.tree.structure(sub), replicated),
         jax.eval_shape(build, jax.random.key(0)),
-        is_leaf=lambda sub: jax.tree.structure(sub) == params_def)
+        is_leaf=lambda sub: jax.tree.structure(sub) in by_def)
     return jax.jit(build, out_shardings=shardings)
 
 
@@ -2370,36 +2548,49 @@ def make_train_step(config: LlamaConfig, optimizer=None,
 
         hp = fused_hyperparams(learning_rate)
 
-        def step(state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(
-                state["params"], batch, config)
-            with jax.named_scope("optimizer"):
-                params, opt_state, gnorm = fused_adamw_update(
-                    grads, state["opt_state"], state["params"], **hp)
-            new_state = {"params": params, "opt_state": opt_state,
-                         "step": state["step"] + 1}
-            return new_state, {"loss": loss, "grad_norm": gnorm,
-                               "step": new_state["step"]}
+        def update(grads, opt_state, trained):
+            rate = warmup_rate(learning_rate, config.lr_warmup_steps,
+                               opt_state.count + 1)
+            return fused_adamw_update(grads, opt_state, trained,
+                                      **{**hp, "learning_rate": rate})
+    else:
+        if optimizer is None:
+            optimizer = default_optimizer(learning_rate,
+                                          config.lr_warmup_steps)
 
-        return _annotate_step(
-            jax.jit(step, donate_argnums=(0,) if donate else ()))
-
-    if optimizer is None:
-        optimizer = default_optimizer(learning_rate)
+        def update(grads, opt_state, trained):
+            updates, opt_state = optimizer.update(grads, opt_state, trained)
+            return (optax.apply_updates(trained, updates), opt_state,
+                    optax.global_norm(grads))
 
     def step(state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch,
-                                                  config)
+        # What no gradient trains (a router's selection bias) stays out of
+        # the differentiated tree, the optimizer and the clipped norm, and
+        # is updated by its own rule from the step's expert rows.
+        trained, fixed = split_untrained(state["params"])
+        (loss, expert_rows), grads = jax.value_and_grad(
+            lambda trained: loss_and_expert_rows(
+                merge_untrained(trained, fixed), batch, config),
+            has_aux=True)(trained)
         with jax.named_scope("optimizer"):
-            updates, opt_state = optimizer.update(
-                grads, state["opt_state"], state["params"])
-            params = optax.apply_updates(state["params"], updates)
-        new_state = {"params": params, "opt_state": opt_state,
-                     "step": state["step"] + 1}
-        with jax.named_scope("optimizer"):
-            gnorm = optax.global_norm(grads)
-        return new_state, {"loss": loss, "grad_norm": gnorm,
-                           "step": new_state["step"]}
+            trained, opt_state, gnorm = update(grads, state["opt_state"],
+                                               trained)
+        metrics = {"loss": loss, "grad_norm": gnorm,
+                   "step": state["step"] + 1}
+        if expert_rows is not None:
+            metrics["expert_rows"] = expert_rows
+        if fixed:
+            if expert_rows is None:
+                raise NotImplementedError(
+                    "a router's selection bias is balanced by the rows the "
+                    "dropless dispatch counts: not under an expert mesh axis")
+            fixed = balance_router_bias(fixed, expert_rows, config)
+            metrics["router_bias_max"] = jnp.max(jnp.stack([
+                jnp.max(jnp.abs(sub["router_bias"]))
+                for sub in fixed.values()]))
+        new_state = {"params": merge_untrained(trained, fixed),
+                     "opt_state": opt_state, "step": metrics["step"]}
+        return new_state, metrics
 
     return _annotate_step(
         jax.jit(step, donate_argnums=(0,) if donate else ()))

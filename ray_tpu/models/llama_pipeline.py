@@ -37,10 +37,11 @@ PyTree = Any
 def check_pipeline_config(config: LlamaConfig, n_stages: int):
     if n_stages < 2:
         raise ValueError("cross-process pipeline needs >= 2 stages")
-    if not config.plain_decoder:
+    if not config.one_stage_stack:
         raise NotImplementedError(
-            "a pipeline stage runs what llama.forward trains "
-            "(LlamaConfig.plain_decoder): this config is served only")
+            "a pipeline stage runs one stack of one kind of the layers "
+            "llama.forward trains (LlamaConfig.one_stage_stack): this "
+            "config is served only, or trained whole")
     if config.n_layers % n_stages:
         raise ValueError(
             f"{config.n_layers} layers not divisible by {n_stages} stages")

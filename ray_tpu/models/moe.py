@@ -163,10 +163,43 @@ def _aux_loss(probs: jax.Array, expert_idx: jax.Array) -> jax.Array:
     return E * jnp.sum(top1.mean(0) * probs.mean(0))
 
 
+@jax.custom_vjp
+def _computed_rows(rows: jax.Array, computed: jax.Array) -> jax.Array:
+    """``rows`` as they are; their COTANGENT zeroed past the first
+    ``computed`` rows.  A grouped matmul computes the rows of its groups
+    alone, in the backward pass too: what it hands back for a row behind
+    every group (an assignment elsewhere, a row that is not real) is
+    whatever the buffer held -- seen as NaN on a v5e, PR 57 -- and must not
+    be scattered into the stream's gradient.  Nothing in the forward."""
+    return rows
+
+
+def _computed_rows_fwd(rows, computed):
+    return rows, computed
+
+
+def _computed_rows_bwd(computed, g):
+    mine = jnp.arange(g.shape[0], dtype=jnp.int32)[:, None] < computed
+    return jnp.where(mine, g, jnp.zeros_like(g)), None
+
+
+_computed_rows.defvjp(_computed_rows_fwd, _computed_rows_bwd)
+
+
+def _rows_per_group(group_of_row: jax.Array, groups: int) -> jax.Array:
+    """(groups,) int32: how many rows name each group; a row that names
+    ``groups`` or more counts for none."""
+    return jnp.sum(
+        group_of_row[:, None] == jnp.arange(
+            groups, dtype=group_of_row.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+
+
 def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
                      valid: Optional[jax.Array] = None,
                      layer_index: Optional[jax.Array] = None,
-                     route_x: Optional[jax.Array] = None
+                     route_x: Optional[jax.Array] = None,
+                     training: bool = False
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x: (B, S, D) → (out (B, S, D), aux_loss scalar, expert_rows (E,)
     int32: the rows each expert computed).
@@ -193,7 +226,13 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     router in ``params`` is the layer's own either way.
 
     ``route_x`` (B, S, D): what the router reads where that is not the
-    rows the experts multiply (a router placed before attention)."""
+    rows the experts multiply (a router placed before attention).
+
+    ``training``: what a backward pass and the balance update of a
+    selection bias need, beside a forward that serving lowers as it always
+    did.  ``expert_rows`` is (n_experts,), the real rows' choices counted
+    over EVERY expert the router scores, held here or not; and the rows
+    that no group computes hand back no cotangent (``_computed_rows``)."""
     c = config
     B, S, D = x.shape
     T = B * S
@@ -224,9 +263,7 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
             # behind every group: sorted last, counted by no expert
             flat = jnp.where(jnp.repeat(valid, K), flat, E)
         order = jnp.argsort(flat)        # stable: a token's K stay in order
-        expert_rows = jnp.sum(
-            flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
-            axis=0, dtype=jnp.int32)
+        expert_rows = _rows_per_group(flat, E)
 
     with jax.named_scope("expert_ffn"):
         w_gate, w_up, w_down = (params[k].astype(dt)
@@ -245,11 +282,15 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
         return jax.lax.ragged_dot(rows, w, group_sizes,
                                   preferred_element_type=out_dtype)
 
+    def computed(rows):     # sorted first: the groups' rows
+        return _computed_rows(rows, jnp.sum(expert_rows)) if training \
+            else rows
+
     with jax.named_scope("expert_dispatch"):
-        rows = xt[order // K]                              # (T*K, D)
+        rows = computed(xt[order // K])                    # (T*K, D)
     with jax.named_scope("expert_ffn"):
-        act = c.act(grouped(rows, w_gate).astype(dt)) \
-            * grouped(rows, w_up).astype(dt)
+        act = computed(c.act(grouped(rows, w_gate).astype(dt))
+                       * grouped(rows, w_up).astype(dt))
         # (T*K, D) float32; a share's in ``dt``: three in four of its rows
         # are not computed, and at a 12,288-token prompt's 73,728 rows of
         # 5,120 the float32 result and its un-sorted copy are 2.8 GB
@@ -257,6 +298,11 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     # Un-sort (order is a permutation) and sum under the gates.  Rows
     # past the last group were not computed: select, do not multiply.
     with jax.named_scope("expert_dispatch"):
+        if training and c.held:
+            chosen = expert_idx.reshape(T * K)
+            if valid is not None:
+                chosen = jnp.where(jnp.repeat(valid, K), chosen, c.n_experts)
+            choices = _rows_per_group(chosen, c.n_experts)
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(T * K, dtype=order.dtype), unique_indices=True)
         if c.held:
@@ -269,7 +315,8 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
                 jnp.where(computed[:, k, None], out[:, k * D:(k + 1) * D],
                           0).astype(jnp.float32) * gate_vals[:, k, None]
                 for k in range(K))
-            expert_rows = jnp.concatenate([expert_rows, elsewhere[None]])
+            expert_rows = choices if training \
+                else jnp.concatenate([expert_rows, elsewhere[None]])
         else:
             out = out[inverse].reshape(T, K, D)
             out = jnp.sum(out * gate_vals[..., None], axis=1)

@@ -196,6 +196,14 @@ def model_plane_metrics():
         "train_step_seconds": _metrics.Gauge(
             "ray_tpu_train_step_seconds",
             "last training step wall time"),
+        "train_expert_load_imbalance": _metrics.Gauge(
+            "ray_tpu_train_expert_load_imbalance",
+            "last read training step's busiest expert's tokens / the mean "
+            "an expert, the worst expert layer (1.0: even routing)"),
+        "train_router_bias_max": _metrics.Gauge(
+            "ray_tpu_train_router_bias_max",
+            "largest magnitude of the routers' selection biases after the "
+            "last read training step's balance update"),
         "program_ema": _metrics.Gauge(
             "ray_tpu_serve_program_seconds",
             "serve engine per-program execution-time EMA (prefill / "
@@ -598,6 +606,7 @@ SCOPES = (
     "flash_attention.dkdv", "decode_attention",
     "kv_write", "attn_out", "ffn",
     "router", "expert_dispatch", "expert_ffn", "shared_expert",
+    "router_balance",
     "mla_absorb", "mla_expand", "mla_decode_attention",
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_state_update", "ssm_out",
     "conv_proj", "short_conv", "conv_out",
@@ -907,6 +916,28 @@ def record_train_step(tokens: int, step_s: float,
             if peak:
                 m["train_mfu"].set(
                     tps * 6 * n_params / (peak * max(1, n_devices)))
+    except Exception:
+        pass
+
+
+def record_expert_balance(expert_rows, router_bias_max=None) -> None:
+    """Publish a training step's expert load: ``expert_rows`` (expert
+    layers, experts), the step metric of that name
+    (``llama.make_train_step``), and the selection biases' largest
+    magnitude where the model has them.  Reading the metrics waits for the
+    step, so a train loop calls this where it reads the loss.  Must never
+    raise."""
+    if not _enabled:
+        return
+    try:
+        import numpy as np
+
+        rows = np.asarray(expert_rows, np.float64)
+        m = model_plane_metrics()
+        m["train_expert_load_imbalance"].set(float(
+            (rows.max(-1) / np.maximum(rows.mean(-1), 1e-9)).max()))
+        if router_bias_max is not None:
+            m["train_router_bias_max"].set(float(router_bias_max))
     except Exception:
         pass
 

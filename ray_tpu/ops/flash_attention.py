@@ -266,12 +266,41 @@ def _causal_mask(s, row0, col0, query_axis=0):
     return jnp.where(rows >= cols, s, NEG_INF)
 
 
-def _band_mask(s, row0, col0, window):
+def _band_mask(s, row0, col0, window, query_axis=0):
     """``_causal_mask`` and, beside it, keys ``window`` or more before
     their query -> NEG_INF."""
-    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, query_axis)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                           1 - query_axis)
     return jnp.where((rows >= cols) & (rows - cols < window), s, NEG_INF)
+
+
+def _mask(s, mask, window=None, query_axis=0):
+    """A tile's scores under ``_causal_dispatch``'s ``mask`` (None: the
+    tile is clear of the diagonal and of the band's older edge)."""
+    if mask is None:
+        return s
+    if window is None:
+        return _causal_mask(s, *mask, query_axis=query_axis)
+    return _band_mask(s, *mask, window, query_axis=query_axis)
+
+
+# Which tiles a band of ``window`` keys leaves to compute, beside the
+# causal rule.
+
+def _in_band(qi, ki, window, block_q, block_k):
+    """Tile (qi, ki) holds a key that some row of it still sees."""
+    return ki * block_k + block_k - 1 > qi * block_q - window
+
+
+def _band_first_k(qi, window, block_q, block_k):
+    """The first k block that q block ``qi``'s band reaches back to."""
+    return jnp.maximum((block_q * qi - window + 1) // block_k, 0)
+
+
+def _band_last_q(ki, window, block_q, block_k):
+    """The last q block that still sees k block ``ki``."""
+    return (block_k * ki + block_k - 2 + window) // block_q
 
 
 def _causal_dispatch(compute, causal, should_run, qi, ki,
@@ -279,29 +308,39 @@ def _causal_dispatch(compute, causal, should_run, qi, ki,
     """Run ``compute(rows, cols, mask)`` under pl.when over what a tile
     has to compute.  ``rows`` / ``cols`` are static ``(start, stop)``
     within the tile; ``mask`` is None or the ``(row0, col0)`` that
-    ``_causal_mask`` takes.  Tiles below the diagonal and non-causal
+    ``_mask`` takes.  Tiles below the diagonal and non-causal
     tiles are one unmasked call.  A tile the diagonal crosses is computed
     whole and masked, unless the kernel asks for ``strips`` (``"rows"`` or
     ``"cols"``) and the tile is square: then it is walked in
     ``_diag_strips``, nothing above them is computed, and a masked score
     adds an exact zero either way, so the sums are the same.  With
-    ``window`` (the forward alone) a tile the band's older edge crosses
-    is masked whole as well."""
+    ``window`` a tile the band's older edge crosses is masked whole as
+    well (a strip's mask is the band's too: in the diagonal's own tile a
+    strip's offsets are its distances)."""
     whole = (0, block_q), (0, block_k)
     if causal:
         on_diag = ki * block_k + block_k - 1 > qi * block_q
+        crossed = on_diag
         if window is not None:
             # the tile's last row minus its first column: the farthest
             # any of its keys lies behind its query
-            on_diag |= qi * block_q + block_q - 1 - ki * block_k >= window
+            crossed |= qi * block_q + block_q - 1 - ki * block_k >= window
 
-        @pl.when(should_run & jnp.logical_not(on_diag))
+        @pl.when(should_run & jnp.logical_not(crossed))
         def _below():
             compute(*whole, None)
 
-        @pl.when(should_run & on_diag)
+        strip = strips and _diag_strip(block_q, block_k)
+        if strip and window is not None:
+            # the band's older edge, away from the diagonal: whole, masked
+            @pl.when(should_run & crossed & jnp.logical_not(on_diag))
+            def _edge():
+                compute(*whole, (qi * block_q, ki * block_k))
+
+            crossed = on_diag
+
+        @pl.when(should_run & crossed)
         def _diag():
-            strip = strips and _diag_strip(block_q, block_k)
             if not strip:
                 compute(*whole, (qi * block_q, ki * block_k))
                 return
@@ -339,7 +378,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # row.  A row that a crossed tile masks whole counts NEG_INF -
         # NEG_INF = 0 there; the tile of its own key, which always comes
         # after, scales that away (alpha = 0).
-        should_run &= ki * block_k + block_k - 1 > qi * block_q - window
+        should_run &= _in_band(qi, ki, window, block_q, block_k)
     if lengths_ref is not None:
         # Nor any tile of a q block that starts at or past its row's
         # length: padding, whose output nothing reads.  ``_init`` and
@@ -354,10 +393,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         v = v_ref[0, 0, c, :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        if mask is not None and window is None:
-            s = _causal_mask(s, *mask)
-        elif mask is not None:
-            s = _band_mask(s, *mask, window)
+        s = _mask(s, mask, window)
         if keep_ref is not None:
             # A mask that is data, below the diagonal too.  A row whose
             # keys of a tile are all masked counts NEG_INF - NEG_INF = 0
@@ -447,8 +483,8 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
             # Skipped blocks fetch nothing new: those behind the band
             # wait on its first block, those above the diagonal stay on
             # the diagonal's.
-            first = jnp.maximum((bq * qi - window + 1) // bk, 0)
-            ki = jnp.clip(ki, first, (bq * qi + bq - 1) // bk)
+            ki = jnp.clip(ki, _band_first_k(qi, window, bq, bk),
+                          (bq * qi + bq - 1) // bk)
         return (b, h // group, ki, 0)
 
     def o_map(b, h, qi, ki, *lens):
@@ -527,7 +563,8 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None,
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, lse_scr, delta_scr, *, block_q, block_k, nk, causal):
+               dq_scr, lse_scr, delta_scr, *, block_q, block_k, nk, causal,
+               window=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -544,6 +581,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     else:
         should_run = True
         last_k = nk - 1
+    if window is not None:
+        # nor tiles wholly behind the band, as in the forward: the walk
+        # still ends on the diagonal's tile, which always runs
+        should_run &= _in_band(qi, ki, window, block_q, block_k)
 
     def _compute(rows, cols, mask):
         r, c = slice(*rows), slice(*cols)
@@ -553,10 +594,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         do = do_ref[0, 0, r, :]
         lse = lse_scr[r, :1]
         delta = delta_scr[r, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if mask is not None:
-            s = _causal_mask(s, *mask)
+        s = _mask(jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32),
+                  mask, window)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -566,7 +606,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             preferred_element_type=jnp.float32)
 
     _causal_dispatch(_compute, causal, should_run, qi, ki,
-                     block_q, block_k, strips="rows")
+                     block_q, block_k, strips="rows", window=window)
 
     @pl.when(ki == last_k)
     def _finalize():
@@ -575,7 +615,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr,
-                 *, block_q, block_k, nq, group, causal):
+                 *, block_q, block_k, nq, group, causal, window=None):
     ki = pl.program_id(2)
     # The reduction walks the kv head's ``group`` q heads, ``nq`` q blocks
     # of each (``_bwd_impl``'s ``q_map_kv`` picks the head): ``step`` is
@@ -596,6 +636,9 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         should_run = qi * block_q + block_q - 1 >= ki * block_k
     else:
         should_run = True
+    if window is not None:
+        # nor q blocks whose every row left this kv block behind
+        should_run &= _in_band(qi, ki, window, block_q, block_k)
 
     def _compute(rows, cols, mask):
         # The tile keys x queries, ``K Q^T``: a query's statistic is then
@@ -611,10 +654,9 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0, 0, r, :]
         lse = lse_ref[0, 0, :, r]
         delta = delta_ref[0, 0, :, r]
-        st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if mask is not None:
-            st = _causal_mask(st, *mask, query_axis=1)
+        st = _mask(jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                       preferred_element_type=jnp.float32),
+                   mask, window, query_axis=1)
         pt = jnp.exp(st - lse)
         dv_scr[c, :] = dv_scr[c, :] + jax.lax.dot_general(
             pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
@@ -627,7 +669,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
 
     _causal_dispatch(_compute, causal, should_run, qi, ki,
-                     block_q, block_k, strips="cols")
+                     block_q, block_k, strips="cols", window=window)
 
     @pl.when(step == group * nq - 1)
     def _finalize():
@@ -636,14 +678,17 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
-              interpret, out_dtype):
+              interpret, out_dtype, window=None):
     """q, o, do (B, Hq, Sq, D); k, v (B, Hkv, Sk, D), ``Hq // Hkv`` q
     heads to a kv head (1: the same program, a one-head walk); lse as
     ``_fwd`` returns it for these blocks.  Returns dq (B, Hq, Sq, D) and
     dk, dv (B, Hkv, Sk, D), un-scaled, in ``out_dtype``: each a float32
     accumulator rounded once as it leaves VMEM.  ``delta`` is made in
     ``lse``'s layout: no (B, Hq, S, 1) value exists where the q block is
-    whole lanes."""
+    whole lanes.  ``window`` (causal only): the forward's band; tiles
+    wholly outside it are neither fetched nor computed by dq's walk over k
+    blocks or dk/dv's over q blocks.  Without it both kernels are built as
+    they always were."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
     group = Hq // Hkv
@@ -652,18 +697,24 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(lse.shape)
+    banded = {} if window is None else {"window": window}
 
     def q_map(b, h, qi, ki):
         return (b, h, qi, 0)
 
     def k_map_q(b, h, qi, ki):
-        if causal:
+        if causal and not banded:
             ki = jax.lax.select(bk * ki <= bq * qi + bq - 1, ki, 0)
+        elif causal:
+            # as the forward: blocks behind the band wait on its first,
+            # those above the diagonal stay on the diagonal's
+            ki = jnp.clip(ki, _band_first_k(qi, window, bq, bk),
+                          (bq * qi + bq - 1) // bk)
         return (b, h // group, ki, 0)
 
     dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=bq, block_k=bk, nk=nk,
-                          causal=causal),
+                          causal=causal, **banded),
         name="flash_attention_dq",
         grid=(B, Hq, nq, nk),
         in_specs=[
@@ -696,13 +747,16 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, block_q, block_k,
         if causal:
             # Above-diagonal (skipped) blocks: redirect the prefetch to
             # the same q head's first block that runs, the next one wanted.
-            qi = jnp.clip(qi, bk * ki // bq, nq - 1)
+            # (past the band: stay on its last block that runs)
+            qi = jnp.clip(qi, bk * ki // bq,
+                          jnp.minimum(_band_last_q(ki, window, bq, bk),
+                                      nq - 1) if banded else nq - 1)
         return (b, h * group + r // nq, qi, 0)
 
     lse_rows, delta_rows = _stats_rows(lse, bq), _stats_rows(delta, bq)
     dkdv_call = pl.pallas_call(
         functools.partial(_dkdv_kernel, block_q=bq, block_k=bk, nq=nq,
-                          group=group, causal=causal),
+                          group=group, causal=causal, **banded),
         name="flash_attention_dkdv",
         grid=(B, Hkv, nk, group * nq),
         in_specs=[
@@ -753,16 +807,16 @@ FLASH_RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_o",
                         "flash_lse")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_core(qt, kt, vt, o, lse, causal, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_core(qt, kt, vt, o, lse, causal, block_q, block_k, window):
     return o
 
 
-def _flash_core_fwd(qt, kt, vt, o, lse, causal, block_q, block_k):
+def _flash_core_fwd(qt, kt, vt, o, lse, causal, block_q, block_k, window):
     return o, (qt, kt, vt, o, lse)
 
 
-def _flash_core_bwd(causal, block_q, block_k, res, g):
+def _flash_core_bwd(causal, block_q, block_k, window, res, g):
     qt, kt, vt, o, lse = res
     # ``g`` is already (B, Hq, Sq, D).  dq is returned w.r.t. the
     # PRE-SCALED qt: the outer qt = q * scale chain applies the scale
@@ -770,7 +824,8 @@ def _flash_core_bwd(causal, block_q, block_k, res, g):
     # to undo it by hand).
     dq, dk, dv = _bwd_impl(qt, kt, vt, o, lse, g, causal=causal,
                            block_q=block_q, block_k=block_k,
-                           interpret=_use_interpret(), out_dtype=qt.dtype)
+                           interpret=_use_interpret(), out_dtype=qt.dtype,
+                           window=window)
     # o and lse are functions of q/k/v computed under stop_gradient in
     # the primal; their cotangents are structurally zero.
     return dq, dk, dv, jnp.zeros_like(o), jnp.zeros_like(lse)
@@ -795,7 +850,7 @@ def _named_packed(x, name):
     return checkpoint_name(x, name)
 
 
-def _flash(q, k, v, causal, block_q, block_k):
+def _flash(q, k, v, causal, block_q, block_k, window=None):
     B, S, Hq, D = q.shape
     scale = D ** -0.5
     qt = jnp.transpose(q, (0, 2, 1, 3)) * jnp.asarray(scale, q.dtype)
@@ -804,7 +859,7 @@ def _flash(q, k, v, causal, block_q, block_k):
     o, lse = _fwd(jax.lax.stop_gradient(qt), jax.lax.stop_gradient(kt),
                   jax.lax.stop_gradient(vt), causal=causal,
                   block_q=block_q, block_k=block_k,
-                  interpret=_use_interpret())
+                  interpret=_use_interpret(), window=window)
     qt = _named_packed(qt, "flash_q")
     kt = _named_packed(kt, "flash_k")
     vt = _named_packed(vt, "flash_v")
@@ -812,16 +867,20 @@ def _flash(q, k, v, causal, block_q, block_k):
     # lane-dense, lse has nothing to pack: the residual the "attn" remat
     # policy saves IS the kernels' operand (a width-1 column still packs)
     lse = _named_packed(lse, "flash_lse")
-    out = _flash_core(qt, kt, vt, o, lse, causal, block_q, block_k)
+    out = _flash_core(qt, kt, vt, o, lse, causal, block_q, block_k, window)
     return jnp.transpose(out, (0, 2, 1, 3))
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None) -> jax.Array:
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention.  q: (B, S, Hq, D); k/v: (B, S, Hkv, D) with
     Hq % Hkv == 0 (GQA).  Softmax scale is D**-0.5 (applied inside).
+    ``window`` (causal only): a query sees its last ``window`` keys, its
+    own among them, forward and backward; tiles wholly outside the band
+    are neither fetched nor computed.
 
     On TPU a shape the kernel cannot tile (after the causal pad below)
     raises: silently running another implementation would hide that
@@ -831,13 +890,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Sk = k.shape[1]
     if Hq % k.shape[2]:
         raise ValueError(f"Hq={Hq} not a multiple of Hkv={k.shape[2]}")
+    if window is not None and not (causal and window > 0):
+        raise ValueError("a window is a band under the causal diagonal")
     # Under a mesh the kernel runs per shard of the batch and head axes
     # (attention is independent across both); the sequence stays whole.
     q_axes = ("batch", None, "heads", "head_dim")
     kv_axes = ("batch", None, "kv_heads", "head_dim")
     flash = shard_over_mesh(
         functools.partial(_flash, causal=causal, block_q=block_q,
-                          block_k=block_k),
+                          block_k=block_k, window=window),
         in_axes=(q_axes, kv_axes, kv_axes), out_axes=q_axes)
     if not _supported(Sq, Sk, D):
         if causal and Sq == Sk:
@@ -906,14 +967,15 @@ def flash_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def flash_attention_causal(q, k, v, positions=None,
                            block_q: Optional[int] = None,
-                           block_k: Optional[int] = None):
+                           block_k: Optional[int] = None,
+                           window: Optional[int] = None):
     """Drop-in for models.llama.dot_attention (standard causal layout;
     packed/offset positions must use the dot path).  ``block_q``/
     ``block_k`` override the kernel tile sizes (None: the default,
-    ``DEFAULT_BLOCK``)."""
+    ``DEFAULT_BLOCK``); ``window``: ``flash_attention``'s."""
     _check_default_positions(positions, q.shape[1], "flash_attention_causal")
     return flash_attention(q, k, v, causal=True, block_q=block_q,
-                           block_k=block_k)
+                           block_k=block_k, window=window)
 
 
 def _check_default_positions(positions, seq_len, name):

@@ -447,12 +447,14 @@ def test_training_and_the_single_stack_cache_refuse_the_config(model):
         llama.forward_with_cache(
             params, jnp.zeros((1, 1), jnp.int32),
             jnp.zeros((1, 1), jnp.int32), {}, cfg)
-    # each mechanism alone is served only, too
-    for kw in (dict(rope_scaling=YARN), dict(moe_experts=4, moe_held=(0, 2)),
+    # YaRN alone is served only, too; a held share and a leading dense
+    # layer alone are trained since PR 57 (tests/test_trinity_train.py)
+    with pytest.raises(NotImplementedError, match="served only"):
+        llama.forward(None, jnp.zeros((1, 8), jnp.int32),
+                      LlamaConfig.debug(rope_scaling=YARN))
+    for kw in (dict(moe_experts=4, moe_held=(0, 2)),
                dict(moe_experts=4, first_dense_layers=1)):
-        plain = LlamaConfig.debug(**kw)
-        with pytest.raises(NotImplementedError, match="served only"):
-            llama.forward(None, jnp.zeros((1, 8), jnp.int32), plain)
+        assert LlamaConfig.debug(**kw).plain_decoder
 
 
 def test_parts_and_parameter_trees(model):

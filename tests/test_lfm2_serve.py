@@ -390,14 +390,13 @@ def test_training_and_the_one_stack_cache_refuse_the_config(cut):
     with pytest.raises(NotImplementedError, match="short-convolution"):
         llama.forward_with_cache(params, jnp.zeros((1, 1), jnp.int32),
                                  jnp.zeros((1, 1), jnp.int32), {}, cfg)
-    # each mechanism alone is served only, too
+    # the conv layers alone refuse it: each other mechanism alone is
+    # trained since PR 57 (tests/test_trinity_train.py)
     for kw in (dict(qk_head_norm=True),
                dict(moe_experts=4, moe_router_score="sigmoid"),
                dict(moe_experts=4, moe_router_bias=True),
                dict(layer_types=("attention", "attention"))):
-        with pytest.raises(NotImplementedError, match="served only"):
-            llama.forward(None, jnp.zeros((1, 8), jnp.int32),
-                          LlamaConfig.debug(**kw))
+        assert LlamaConfig.debug(**kw).plain_decoder
 
 
 # ------------------------------------------------- through the scheduler

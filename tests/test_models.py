@@ -304,16 +304,18 @@ def test_moe_sharded_step_matches_single_device(moe_cfg, spec):
 # ------------------------------------------- "served only" in one place
 def _forward_refused_before(c):
     """``llama.forward``'s condition as it stood before ``plain_decoder``
-    (PR 42), kept as the reference the property is held to."""
+    (PR 42), kept as the reference the property is held to -- less the ten
+    terms whose backward tests/test_trinity_train.py holds since PR 57
+    (an embedding multiplier, window layers, NoPE kinds, leading dense
+    layers, a held share, a ``layer_types`` list of attention and window
+    layers, q/k norm a head, a sigmoid router, a selection bias; an output
+    gate was refused by the property alone)."""
     return bool(
         c.layers_of("mamba") or c.attention_multiplier is not None
-        or c.embedding_multiplier != 1.0 or c.logits_scaling != 1.0
-        or c.layers_of("window") or c.nope_kinds
+        or c.logits_scaling != 1.0
         or c.moe_router_input != "ffn" or c.kv_lora_rank
-        or c.rope_scaling is not None or c.first_dense_layers
-        or c.moe_held or c.layer_types or c.layers_of("conv")
-        or c.qk_head_norm or c.moe_router_score != "softmax"
-        or c.moe_router_bias or c.index_topk)       # (an indexer: PR 45)
+        or c.rope_scaling is not None or c.layers_of("conv")
+        or c.index_topk)                            # (an indexer: PR 45)
 
 
 def _cache_refused_before(c):
@@ -327,7 +329,8 @@ _PRESETS = ("debug", "moe_debug", "hybrid_debug", "llama_moe_1b",
             "llama_125m", "llama_440m", "llama2_7b", "llama3_8b")
 _BENCH_CONFIGS = ("internlm2-1.8b", "smollm2-360m", "olmoe-1b-7b",
                   "granite-4.0-h-micro", "smallthinker-21b-a3b",
-                  "deepseek-v2", "lfm2-8b-a1b", "keye-vl-2.0-30b-a3b")
+                  "deepseek-v2", "lfm2-8b-a1b", "keye-vl-2.0-30b-a3b",
+                  "trinity-mini")
 _SSM = dict(ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_chunk=8)
 # one config a term of the two old conditions, and four that neither held
 _TERMS = {
@@ -358,6 +361,8 @@ _TERMS = {
     "no_rope": dict(rope=False),
     "qk_norm": dict(qk_norm=True),
     "stream_dtype": dict(stream_dtype="float32"),
+    "attn_gate": dict(attn_gate=True),
+    "sandwich_norm": dict(sandwich_norm=True),
 }
 
 
@@ -390,15 +395,20 @@ def test_served_only_is_one_derived_property(source, name):
     c = _config(source, name)
     assert (not c.plain_decoder) == _forward_refused_before(c)
     assert (not c.one_kv_stack) == _cache_refused_before(c)
-    assert c.plain_decoder <= c.one_kv_stack
+    # a pipeline stage slices ONE stack of one kind of the layers forward
+    # trains
+    assert c.one_stage_stack == (c.plain_decoder and c.one_kv_stack
+                                 and not c.first_dense_layers)
     if source == "term":
-        assert _forward_refused_before(c) == (
-            name not in ("residual_multiplier", "no_rope", "qk_norm",
-                         "stream_dtype"))
+        assert _forward_refused_before(c) == (name in (
+            "mamba", "attention_multiplier", "logits_scaling",
+            "moe_router_input", "kv_lora_rank", "rope_scaling", "conv",
+            "index_topk"))
     toks = jnp.zeros((1, 8), jnp.int32)
     if not c.plain_decoder:
         with pytest.raises(NotImplementedError, match="served only"):
             forward(None, toks, c)
+    if not c.one_stage_stack:
         # (a pipeline stage computed a plain decoder over whatever
         # leaves it found, or raised a KeyError, before PR 43)
         with pytest.raises(NotImplementedError, match="served only"):
