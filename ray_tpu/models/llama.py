@@ -1771,15 +1771,7 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     from ray_tpu.models import moe
     from ray_tpu.parallel.sharding import current_mesh
 
-    mcfg = moe.MoEConfig(hidden_size=c.hidden_size,
-                         intermediate_size=c.expert_width,
-                         n_experts=c.moe_experts, top_k=c.moe_top_k,
-                         capacity_factor=c.moe_capacity_factor,
-                         norm_topk=c.moe_norm_topk,
-                         activation=c.moe_activation, dtype=dt,
-                         groups=c.moe_groups, top_groups=c.moe_top_groups,
-                         routed_scale=c.moe_routed_scale, held=c.moe_held,
-                         score=c.moe_router_score)
+    mcfg = expert_config(c)
     moe_params = {k: layer[k] for k in ("router",) + EXPERT_STACKS
                   + (("router_bias",) if c.moe_router_bias else ())}
     mesh = current_mesh()
@@ -1806,6 +1798,22 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
     x = residual_add(x, ff, c, post_norm)
     return with_logical_constraint(x, "batch", "seq", None), aux, \
         expert_rows
+
+
+def expert_config(config: LlamaConfig):
+    """The ``moe.MoEConfig`` of the config's expert layers."""
+    from ray_tpu.models import moe
+
+    c = config
+    return moe.MoEConfig(hidden_size=c.hidden_size,
+                         intermediate_size=c.expert_width,
+                         n_experts=c.moe_experts, top_k=c.moe_top_k,
+                         capacity_factor=c.moe_capacity_factor,
+                         norm_topk=c.moe_norm_topk,
+                         activation=c.moe_activation, dtype=c.dtype,
+                         groups=c.moe_groups, top_groups=c.moe_top_groups,
+                         routed_scale=c.moe_routed_scale, held=c.moe_held,
+                         score=c.moe_router_score)
 
 
 def _dispatch_in_chunks(h, moe_params, mcfg, valid, layer_index, route_x,
@@ -2463,6 +2471,28 @@ def balance_router_bias(fixed, expert_rows: jax.Array,
     return out
 
 
+def dispatch_compact_share(expert_rows: jax.Array, batch,
+                           config: LlamaConfig):
+    """The share of a step's expert layers whose dispatch was ONE block of
+    ``moe.compact_rows`` sorted rows (the held experts' rows fitted it; a
+    layer under 1.0 went through two blocks or more that step), float32 --
+    from ``forward``'s ``expert_rows`` (expert layers, E) and the batch's
+    shape, which decide it.  None where no layer is dispatched in blocks:
+    every expert held, or half and more, or a dispatch in chunks (whose
+    rows arrive summed)."""
+    from ray_tpu.models import moe
+
+    B, S = batch["tokens"].shape
+    S -= "positions" in batch       # ``loss_and_expert_rows``
+    bound = moe.compact_rows(B * S, expert_config(config))
+    if bound == B * S * config.moe_top_k or (
+            config.moe_dispatch_chunk and S > config.moe_dispatch_chunk):
+        return None
+    first, count = config.moe_held
+    held = expert_rows[:, first:first + count].sum(-1)
+    return jnp.mean((held <= bound).astype(jnp.float32))
+
+
 def init_train_state(rng: jax.Array, config: LlamaConfig,
                      optimizer=None,
                      fused: bool = False) -> Dict[str, Any]:
@@ -2579,6 +2609,9 @@ def make_train_step(config: LlamaConfig, optimizer=None,
                    "step": state["step"] + 1}
         if expert_rows is not None:
             metrics["expert_rows"] = expert_rows
+            compact = dispatch_compact_share(expert_rows, batch, config)
+            if compact is not None:
+                metrics["dispatch_compact_share"] = compact
         if fixed:
             if expert_rows is None:
                 raise NotImplementedError(

@@ -27,6 +27,7 @@ natively.  Two formulations share one router (``_route``):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -195,6 +196,215 @@ def _rows_per_group(group_of_row: jax.Array, groups: int) -> jax.Array:
         axis=0, dtype=jnp.int32)
 
 
+def compact_rows(tokens: int, config: MoEConfig) -> int:
+    """``C``: how many of a dispatch's sorted ``tokens x top_k`` rows a
+    TRAINING share moves -- twice the even share of its held experts, in
+    whole sublane tiles, at most all of them (which a configuration that
+    holds half or more of the experts, or all, gets: nothing to compact)."""
+    assignments = tokens * config.top_k
+    if not config.held:
+        return assignments
+    bound = -(-2 * assignments * config.held[1] // config.n_experts)
+    return min(-(-bound // 8) * 8, assignments)
+
+
+def _grouped(rows, w, group_sizes, out_dtype=jnp.float32):
+    # f32 accumulation, as llama.matmul
+    return jax.lax.ragged_dot(rows, w, group_sizes,
+                              preferred_element_type=out_dtype)
+
+
+def _sorted_ffn(c: MoEConfig, training: bool, xt, w_gate, w_up, w_down,
+                gate_vals, order, group_of_row, group_sizes) -> jax.Array:
+    """The experts' part of xt (T, D) -> (T, D) float32 over ALL ``T x K``
+    assignments in their sorted ``order``: gathered, through the three
+    grouped matmuls (which compute their groups' rows alone), un-sorted
+    and summed under the gates.  ``group_of_row`` (T*K,): what ``order``
+    sorts, a share's held count where no group computes the assignment."""
+    T, D = xt.shape
+    K = c.top_k
+    dt = c.dtype
+
+    def guarded(rows):     # sorted first: the groups' rows
+        return _computed_rows(rows, jnp.sum(group_sizes)) if training \
+            else rows
+
+    with jax.named_scope("expert_dispatch"):
+        rows = guarded(xt[order // K])                     # (T*K, D)
+    with jax.named_scope("expert_ffn"):
+        act = guarded(
+            c.act(_grouped(rows, w_gate, group_sizes).astype(dt))
+            * _grouped(rows, w_up, group_sizes).astype(dt))
+        # (T*K, D) float32; a share's in ``dt``: three in four of its rows
+        # are not computed, and at a 12,288-token prompt's 73,728 rows of
+        # 5,120 the float32 result and its un-sorted copy are 2.8 GB
+        out = _grouped(act, w_down, group_sizes,
+                       dt if c.held else jnp.float32)
+    # Un-sort (order is a permutation) and sum under the gates.  Rows
+    # past the last group were not computed: select, do not multiply.
+    with jax.named_scope("expert_dispatch"):
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * K, dtype=order.dtype), unique_indices=True)
+        if not c.held:
+            out = out[inverse].reshape(T, K, D)
+            return jnp.sum(out * gate_vals[..., None], axis=1)
+        # A token's K results side by side, (T, K x D): as (T, K, D)
+        # the chip pads K = 6 to a sublane tile.  An assignment
+        # elsewhere was not computed: select.
+        out = out[inverse].reshape(T, K * D)
+        computed = (group_of_row < c.held[1]).reshape(T, K)
+        return sum(
+            jnp.where(computed[:, k, None], out[:, k * D:(k + 1) * D],
+                      0).astype(jnp.float32) * gate_vals[:, k, None]
+            for k in range(K))
+
+
+def _block(c: MoEConfig, b, xt, gate_vals, order, group_sizes):
+    """Block ``b`` of ``C`` sorted assignments (``compact_rows``; ``order``
+    padded to whole blocks): ``(the assignments (C,), their tokens (C,),
+    their rows of xt (C, D), their gates (C,), mine (C, 1) bool: the rows
+    some group computes, the groups' sizes INSIDE the block)``."""
+    C = compact_rows(xt.shape[0], c)
+    first = jax.lax.dynamic_slice(order, (b * C,), (C,))
+    token = first // c.top_k
+    # (a row of the padding is past every group: its index 0 is never read
+    # as an assignment)
+    mine = (b * C + jnp.arange(C, dtype=jnp.int32)
+            < jnp.sum(group_sizes))[:, None]
+    ends = jnp.cumsum(group_sizes)
+    inside = jnp.clip(ends, b * C, (b + 1) * C) \
+        - jnp.clip(ends - group_sizes, b * C, (b + 1) * C)
+    return (first, token, xt.at[token].get(mode="promise_in_bounds"),
+            gate_vals.reshape(-1)[first], mine, inside)
+
+
+def _blocks(c: MoEConfig, xt, order, group_sizes):
+    """``(order padded to whole blocks of C, how many blocks hold a row of
+    some group: 1 wherever the held experts' rows fit C)``."""
+    C = compact_rows(xt.shape[0], c)
+    return (jnp.pad(order, (0, -order.shape[0] % C)),
+            (jnp.sum(group_sizes) + C - 1) // C)
+
+
+def _held_rows_forward(c: MoEConfig, keep: bool, xt, w_gate, w_up, w_down,
+                       gate_vals, order, group_sizes):
+    """``_held_rows_ffn``; with ``keep`` also, over every block's rows,
+    the two products between the matmuls: what ``_held_rows_ffn_bwd`` reads
+    again.  The gathered rows and the third matmul's result it computes
+    anew (~1 ms a layer of cell 13's step): kept as well they are two more
+    full-size buffers, 0.5 GB of the step's scratch in cell 13, filled
+    with zeros a pass (PERF.md section 6, PR 58)."""
+    dt = c.dtype
+    order, blocks = _blocks(c, xt, order, group_sizes)
+
+    def block(carry):
+        b, total, kept = carry
+        with jax.named_scope("expert_dispatch"):
+            _, token, rows, gates, mine, inside = _block(
+                c, b, xt, gate_vals, order, group_sizes)
+        with jax.named_scope("expert_ffn"):
+            gate = _grouped(rows, w_gate, inside).astype(dt)
+            up = _grouped(rows, w_up, inside).astype(dt)
+            out = _grouped(c.act(gate) * up, w_down, inside, dt)
+            kept = tuple(
+                jax.lax.dynamic_update_slice(k, new, (b * new.shape[0], 0))
+                for k, new in zip(kept, (gate, up)))
+        with jax.named_scope("expert_dispatch"):
+            # (a row no group computes holds what the buffer held)
+            total = total.at[token].add(
+                jnp.where(mine, out, 0).astype(jnp.float32)
+                * gates[:, None], mode="promise_in_bounds")
+        return b + 1, total, kept
+
+    nothing = jnp.zeros((order.shape[0], w_gate.shape[-1]), dt)
+    _, total, kept = jax.lax.while_loop(
+        lambda carry: carry[0] < blocks, block,
+        (jnp.int32(0), jnp.zeros(xt.shape, jnp.float32),
+         (nothing, nothing) if keep else ()))
+    return total, kept
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_rows_ffn(c: MoEConfig, xt, w_gate, w_up, w_down, gate_vals,
+                   order, group_sizes) -> jax.Array:
+    """``_sorted_ffn`` of a training share that holds under half the
+    experts, exact for every routing (dropless): the sorted rows go through
+    in blocks of ``C`` (``compact_rows``) -- gathered, multiplied and added
+    back to their tokens under their gates (float32) -- and the loop ends
+    with the last block that holds a row of some group: ONE block wherever
+    the held experts' rows fit ``C``, so the dispatch moves ``C`` rows and
+    not ``T x K``.  The loop's length is known at run time alone, so the
+    backward pass is written out (``_held_rows_ffn_bwd``)."""
+    return _held_rows_forward(c, False, xt, w_gate, w_up, w_down, gate_vals,
+                              order, group_sizes)[0]
+
+
+def _held_rows_ffn_fwd(c, xt, w_gate, w_up, w_down, gate_vals, order,
+                       group_sizes):
+    total, kept = _held_rows_forward(c, True, xt, w_gate, w_up, w_down,
+                                     gate_vals, order, group_sizes)
+    return total, (kept, xt, w_gate, w_up, w_down, gate_vals, order,
+                   group_sizes)
+
+
+def _held_rows_ffn_bwd(c, saved, g):
+    """The cotangents of (xt, the three stacks, gate_vals) for ``g`` (T, D)
+    float32, block by block: op for op what differentiating ``_sorted_ffn``
+    gives on a block's rows, and no row past the groups' count hands
+    anything back (``_computed_rows``)."""
+    kept, xt, w_gate, w_up, w_down, gate_vals, order, group_sizes = saved
+    dt = c.dtype
+    f32 = jnp.float32
+    order, blocks = _blocks(c, xt, order, group_sizes)
+
+    def block(carry):
+        b, d_xt, d_w_gate, d_w_up, d_w_down, d_gate_vals = carry
+
+        def grouped_vjp(rows, w, ct, out_dtype=f32):
+            # (the vjp's own product is dead code)
+            return jax.vjp(lambda rows, w: _grouped(
+                rows, w, inside, out_dtype), rows, w)[1](ct)
+
+        with jax.named_scope("expert_dispatch"):
+            first, token, rows, gates, mine, inside = _block(
+                c, b, xt, gate_vals, order, group_sizes)
+            g_rows = g.at[token].get(mode="promise_in_bounds")  # (C, D)
+            d_out = jnp.where(mine, g_rows * gates[:, None], 0).astype(dt)
+        with jax.named_scope("expert_ffn"):
+            gate, up = (jax.lax.dynamic_slice(
+                k, (b * rows.shape[0], 0), (rows.shape[0], k.shape[1]))
+                for k in kept)
+            act, act_vjp = jax.vjp(
+                lambda gate, up: c.act(gate) * up, gate, up)
+            out = _grouped(act, w_down, inside, dt)
+            d_act, d_down = grouped_vjp(act, w_down, d_out, dt)
+            d_gate, d_up = (jnp.where(mine, d, 0).astype(f32)
+                            for d in act_vjp(d_act))
+            d_rows, d_gate_w = grouped_vjp(rows, w_gate, d_gate)
+            d_rows_up, d_up_w = grouped_vjp(rows, w_up, d_up)
+        with jax.named_scope("expert_dispatch"):
+            d_gates = jnp.sum(
+                g_rows * jnp.where(mine, out, 0).astype(f32), axis=-1)
+            return (b + 1,
+                    d_xt.at[token].add(
+                        jnp.where(mine, d_rows + d_rows_up, 0),
+                        mode="promise_in_bounds"),
+                    d_w_gate + d_gate_w, d_w_up + d_up_w,
+                    d_w_down + d_down,
+                    d_gate_vals.at[first].add(d_gates))
+
+    _, d_xt, d_w_gate, d_w_up, d_w_down, d_gate_vals = jax.lax.while_loop(
+        lambda carry: carry[0] < blocks, block,
+        (jnp.int32(0), *(jnp.zeros_like(a)
+                         for a in (xt, w_gate, w_up, w_down)),
+         jnp.zeros((gate_vals.size,), f32)))
+    return (d_xt, d_w_gate, d_w_up, d_w_down,
+            d_gate_vals.reshape(gate_vals.shape), None, None)
+
+
+_held_rows_ffn.defvjp(_held_rows_ffn_fwd, _held_rows_ffn_bwd)
+
+
 def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
                      valid: Optional[jax.Array] = None,
                      layer_index: Optional[jax.Array] = None,
@@ -231,8 +441,11 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     ``training``: what a backward pass and the balance update of a
     selection bias need, beside a forward that serving lowers as it always
     did.  ``expert_rows`` is (n_experts,), the real rows' choices counted
-    over EVERY expert the router scores, held here or not; and the rows
-    that no group computes hand back no cotangent (``_computed_rows``)."""
+    over EVERY expert the router scores, held here or not; the rows that
+    no group computes hand back no cotangent (``_computed_rows``); and a
+    share of under half the experts gathers, multiplies and adds back its
+    ``T x K`` sorted rows in blocks of ``compact_rows``, as many as hold a
+    held expert's row: one wherever they fit it (``_held_rows_ffn``)."""
     c = config
     B, S, D = x.shape
     T = B * S
@@ -277,49 +490,22 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
             w_gate, w_up, w_down = (w.reshape((L * E,) + w.shape[2:])
                                     for w in (w_gate, w_up, w_down))
 
-    def grouped(rows, w, out_dtype=jnp.float32):
-        # f32 accumulation, as llama.matmul
-        return jax.lax.ragged_dot(rows, w, group_sizes,
-                                  preferred_element_type=out_dtype)
-
-    def computed(rows):     # sorted first: the groups' rows
-        return _computed_rows(rows, jnp.sum(expert_rows)) if training \
-            else rows
-
-    with jax.named_scope("expert_dispatch"):
-        rows = computed(xt[order // K])                    # (T*K, D)
-    with jax.named_scope("expert_ffn"):
-        act = computed(c.act(grouped(rows, w_gate).astype(dt))
-                       * grouped(rows, w_up).astype(dt))
-        # (T*K, D) float32; a share's in ``dt``: three in four of its rows
-        # are not computed, and at a 12,288-token prompt's 73,728 rows of
-        # 5,120 the float32 result and its un-sorted copy are 2.8 GB
-        out = grouped(act, w_down, dt if c.held else jnp.float32)
-    # Un-sort (order is a permutation) and sum under the gates.  Rows
-    # past the last group were not computed: select, do not multiply.
     with jax.named_scope("expert_dispatch"):
         if training and c.held:
             chosen = expert_idx.reshape(T * K)
             if valid is not None:
                 chosen = jnp.where(jnp.repeat(valid, K), chosen, c.n_experts)
             choices = _rows_per_group(chosen, c.n_experts)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(T * K, dtype=order.dtype), unique_indices=True)
+    stacks = (w_gate, w_up, w_down)
+    if training and compact_rows(T, c) < T * K:
+        out = _held_rows_ffn(c, xt, *stacks, gate_vals, order, group_sizes)
+    else:
+        out = _sorted_ffn(c, training, xt, *stacks, gate_vals, order, flat,
+                          group_sizes)
+    with jax.named_scope("expert_dispatch"):
         if c.held:
-            # A token's K results side by side, (T, K x D): as (T, K, D)
-            # the chip pads K = 6 to a sublane tile.  An assignment
-            # elsewhere was not computed: select.
-            out = out[inverse].reshape(T, K * D)
-            computed = (flat < E).reshape(T, K)
-            out = sum(
-                jnp.where(computed[:, k, None], out[:, k * D:(k + 1) * D],
-                          0).astype(jnp.float32) * gate_vals[:, k, None]
-                for k in range(K))
             expert_rows = choices if training \
                 else jnp.concatenate([expert_rows, elsewhere[None]])
-        else:
-            out = out[inverse].reshape(T, K, D)
-            out = jnp.sum(out * gate_vals[..., None], axis=1)
         if valid is not None:
             out = jnp.where(valid[:, None], out, 0.0)
         out = out.reshape(B, S, D).astype(x.dtype)

@@ -204,6 +204,11 @@ def model_plane_metrics():
             "ray_tpu_train_router_bias_max",
             "largest magnitude of the routers' selection biases after the "
             "last read training step's balance update"),
+        "train_dispatch_compact_share": _metrics.Gauge(
+            "ray_tpu_train_dispatch_compact_share",
+            "share of the last read training step's expert layers whose "
+            "dispatch was one block of moe.compact_rows sorted rows (1.0: "
+            "every layer's held experts' rows fitted it)"),
         "program_ema": _metrics.Gauge(
             "ray_tpu_serve_program_seconds",
             "serve engine per-program execution-time EMA (prefill / "
@@ -920,13 +925,15 @@ def record_train_step(tokens: int, step_s: float,
         pass
 
 
-def record_expert_balance(expert_rows, router_bias_max=None) -> None:
+def record_expert_balance(expert_rows, router_bias_max=None,
+                          dispatch_compact_share=None) -> None:
     """Publish a training step's expert load: ``expert_rows`` (expert
     layers, experts), the step metric of that name
-    (``llama.make_train_step``), and the selection biases' largest
-    magnitude where the model has them.  Reading the metrics waits for the
-    step, so a train loop calls this where it reads the loss.  Must never
-    raise."""
+    (``llama.make_train_step``), and, where the step reports them, the
+    selection biases' largest magnitude and the share of expert layers
+    dispatched in one block (the step metrics of those names).
+    Reading the metrics waits for the step, so a train loop calls this
+    where it reads the loss.  Must never raise."""
     if not _enabled:
         return
     try:
@@ -938,6 +945,9 @@ def record_expert_balance(expert_rows, router_bias_max=None) -> None:
             (rows.max(-1) / np.maximum(rows.mean(-1), 1e-9)).max()))
         if router_bias_max is not None:
             m["train_router_bias_max"].set(float(router_bias_max))
+        if dispatch_compact_share is not None:
+            m["train_dispatch_compact_share"].set(
+                float(dispatch_compact_share))
     except Exception:
         pass
 

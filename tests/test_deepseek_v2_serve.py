@@ -407,6 +407,56 @@ def test_the_four_shares_of_an_expert_layer_sum_to_the_uncut_layer(
     assert rows.shape == (16,) and int(rows.sum()) == T * K
 
 
+@pytest.mark.parametrize("program,ragged_dots,conds,sha", [
+    ("prefill", 3, 0, "15c0464d743f9330"),
+    ("decode_k", 3, 10, "3f1556571f49f94a")], ids=["prefill", "decode_k"])
+def test_serving_a_quarter_share_lowers_no_compact_dispatch(
+        program, ragged_dots, conds, sha):
+    """A share that TRAINING dispatches compactly (4 of 16 experts: twice
+    their even share is half the assignments, ``moe.compact_rows``) is
+    served by the programs the parent commit (47a1d40, PR 57) lowered: as
+    many grouped matmuls, no conditional more, the same text by sha256.
+    The loop over blocks exists where ``training`` asks for it alone."""
+    import hashlib
+    import re
+
+    cfg = _cfg(moe_held=(4, 4))
+    shapes = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+    params = shapes(jax.eval_shape(
+        lambda k: llama.init_params(k, cfg, cfg.dtype), jax.random.key(0)))
+    cache = shapes(jax.eval_shape(
+        lambda: llama_serve.init_cache(cfg, 4, 64)))
+    group = jax.ShapeDtypeStruct((2,), jnp.int32)
+    ints = jax.ShapeDtypeStruct((4,), jnp.int32)
+    bools = jax.ShapeDtypeStruct((4,), jnp.bool_)
+    traced = llama_serve.build_prefill(cfg).trace(
+        params, cache, jax.ShapeDtypeStruct((2, 16), jnp.int32), group,
+        group) if program == "prefill" else \
+        llama_serve.build_decode_k(cfg).trace(
+            params, cache, ints, ints, ints, ints, bools, bools, k=4,
+            s_active=32)
+
+    def count(jaxpr):
+        text = str(jaxpr)
+        return tuple(len(re.findall(rf"\b{op}\[", text))
+                     for op in ("ragged_dot_general", "cond", "while"))
+
+    assert count(traced.jaxpr)[:2] == (ragged_dots, conds)
+    assert hashlib.sha256(traced.lower().as_text().encode()
+                          ).hexdigest()[:16] == sha
+    # the same layer as the train step walks it: the three matmuls inside
+    # the loop over blocks of 48 of the 96 sorted rows, which serving has not
+    layer = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                         params["layers"])
+    x = jax.ShapeDtypeStruct((2, 16, 64), cfg.dtype)
+    assert moe.compact_rows(32, llama.expert_config(cfg)) == 48
+    trained, served = (count(jax.make_jaxpr(lambda x, layer: llama.ffn_half(
+        x, layer, cfg, training=training))(x, layer))
+        for training in (True, False))
+    assert trained == (3, 0, 1) and served == (3, 0, 0)
+
+
 def test_the_dense_dispatch_refuses_a_share():
     cfg = moe.MoEConfig(64, 32, n_experts=8, held=(0, 4))
     with pytest.raises(NotImplementedError, match="share of the experts"):

@@ -282,13 +282,19 @@ def test_eight_shares_add_up_to_the_uncut_layer():
         assert float(jnp.abs(d_rank[name][:6]).max()) == 0.0
 
 
-def test_rows_no_group_computes_never_reach_the_gradient(monkeypatch):
+@pytest.mark.parametrize("path", ["compact", "whole"])
+def test_rows_no_group_computes_never_reach_the_gradient(monkeypatch, path):
     """On the chip a grouped matmul leaves the rows behind its last group
     as the buffer held them, forward and backward (NaN in the first run of
     the cell, PR 57); the CPU's computes zeros there.  With such rows
     poisoned as the chip leaves them, a share's gradient is what it is
-    without: finite, and the same."""
+    without: finite, and the same -- in a block of ``moe.compact_rows``
+    sorted rows, where they are the rows between the held experts' count
+    and the block's end, and on the whole path (the bound at every
+    assignment), where they are every assignment elsewhere."""
     real = jax.lax.ragged_dot
+    if path == "whole":
+        monkeypatch.setattr(moe, "compact_rows", lambda T, c: T * c.top_k)
 
     def poisoned(lhs, rhs, group_sizes, **kw):
         mine = jnp.arange(lhs.shape[0])[:, None] < jnp.sum(group_sizes)
@@ -316,6 +322,11 @@ def test_rows_no_group_computes_never_reach_the_gradient(monkeypatch):
         cfg, held=()))
     params = {k: (v[2:4] if k != "router" else v) for k, v in params.items()}
     x = jax.random.normal(jax.random.key(1), (1, 24, 32))
+    held = int(jax.jit(lambda x: moe.moe_ffn_dropless(
+        x, params, cfg, training=True)[2])(x)[2:4].sum())
+    # some rows are the held experts', and rows lie between them and C
+    assert 0 < held < moe.compact_rows(24, cfg) == (24 if path == "compact"
+                                                    else 48)
 
     def grads():        # (traced anew a call: the patch is seen)
         return jax.jit(jax.grad(lambda x, p: jnp.sum(jnp.square(
@@ -328,6 +339,80 @@ def test_rows_no_group_computes_never_reach_the_gradient(monkeypatch):
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert bool(jnp.isfinite(g).all())
         np.testing.assert_allclose(g, w, atol=1e-6)
+
+
+# --------------------------- the dispatch in blocks is the whole dispatch
+SHARE = moe.MoEConfig(hidden_size=32, intermediate_size=16, n_experts=16,
+                      top_k=4, dtype=jnp.float32, held=(4, 4),
+                      score="sigmoid")
+
+
+@functools.lru_cache(maxsize=None)
+def _share_layer(compact: bool, held: int):
+    """``(valid, bias) -> (out, expert rows, the gradient of a weighted
+    sum by x and every leaf)`` of a share of ``held`` experts from the
+    fourth over 2 x 32 tokens: through ``moe_ffn_dropless(training=True)``
+    as it is, or with the bound at all 256 assignments, which is the
+    program before it had one."""
+    cfg = dataclass_replace(SHARE, held=(4, held))
+    params = moe.init_moe_params(jax.random.key(0), dataclass_replace(
+        SHARE, held=()))
+    params = {k: (v if k == "router" else v[4:4 + held])
+              for k, v in params.items()}
+    x, w = jax.random.normal(jax.random.key(1), (2, 2, 32, 32))
+
+    def run(valid, bias):
+        def weighted(x, params):
+            out, _aux, rows = moe.moe_ffn_dropless(
+                x, {**params, "router_bias": bias}, cfg, valid=valid,
+                training=True)
+            return jnp.sum(w * out), (out, rows)
+
+        (_, (out, rows)), grads = jax.value_and_grad(
+            weighted, (0, 1), has_aux=True)(x, params)
+        return out, rows, grads
+
+    run = jax.jit(run)
+    if not compact:
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(moe, "compact_rows", lambda T, c: T * c.top_k)
+            run(jnp.ones((2, 32), bool), jnp.zeros(16))      # traced here
+    return run
+
+
+@pytest.mark.parametrize("held,bias,padded,held_rows", [
+    (4, 0.0, False, (1, 128)), (4, 0.0, True, (1, 128)),
+    (4, 0.35, False, (129, 255)), (4, 10.0, False, (256, 256)),
+    (4, -10.0, False, (0, 0)), (5, 10.0, False, (256, 256))],
+    ids=["under the bound", "under it beside padding", "over the bound",
+         "every assignment held", "none held",
+         "every one held and the last block past the end"])
+def test_the_compact_dispatch_is_the_whole_one(held, bias, padded,
+                                               held_rows):
+    """A training share gathers, multiplies and adds back its sorted rows
+    in blocks of ``compact_rows`` (128 of 256 for 4 held of 16, 160 for 5:
+    the second block then ends past the last assignment), as many as hold
+    a held expert's row: one where they fit it, none where nothing is
+    held, all where the drawn selection bias sends every assignment here
+    (dropless: no routing loses a row).  The output, the router's choices
+    and the gradient of every leaf and of x are the program's without a
+    bound, however many blocks the step goes through."""
+    assert [moe.compact_rows(64, dataclass_replace(SHARE, held=h))
+            for h in ((4, 4), (4, 5), (0, 8), ())] == [128, 160, 256, 256]
+    valid = jnp.ones((2, 32), bool)
+    if padded:
+        valid = valid.at[1, 20:].set(False)
+    bias = jnp.zeros(16).at[4:4 + held].set(bias)
+    got = _share_layer(True, held)(valid, bias)
+    want = _share_layer(False, held)(valid, bias)
+    np.testing.assert_array_equal(got[1], want[1])
+    low, high = held_rows
+    assert low <= int(got[1][4:4 + held].sum()) <= high
+    assert int(got[1].sum()) == 4 * int(valid.sum())
+    for g, w in zip(jax.tree.leaves((got[0], got[2])),
+                    jax.tree.leaves((want[0], want[2]))):
+        assert bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, w, atol=2e-6)
 
 
 # --------------------------------------------------- the balance update
@@ -369,6 +454,7 @@ def _one_step(fused: bool):
         "router_moved": float(jnp.abs(new["params"]["layers"]["router"]
                                       - old["router"]).max()),
         "rows": np.asarray(m["expert_rows"]).tolist(),
+        "compact": float(m["dispatch_compact_share"]),
         "grad_norm": float(m["grad_norm"]),
         "bias_max": float(m["router_bias_max"]), "tokens": tokens.size,
         "rate": cfg.moe_balance_rate, "top_k": cfg.moe_top_k}
@@ -381,6 +467,9 @@ def _check_the_balance(got):
     # every expert counted, held here or not: tokens x top-k a layer
     assert rows.shape == (2, 8)
     assert (rows.sum(-1) == got["tokens"] * got["top_k"]).all()
+    # the layers whose held experts' (2 and 3) rows fitted twice their even
+    # share, 32 of the 64 assignments: the dispatch moved those rows alone
+    assert got["compact"] == (rows[:, 2:4].sum(-1) <= 32).mean()
     old, new = np.asarray(got["bias_old"]), np.asarray(got["bias_new"])
     d = got["rate"] * np.sign(rows.mean(-1, keepdims=True) - rows)
     # the rule alone moved it: sign, centring, no AdamW step, no decay (a
@@ -400,9 +489,10 @@ def test_the_optax_step_balances_the_bias_and_adamw_never_sees_it():
 
 def test_the_fused_step_does_through_the_trainer_and_reports_the_rows():
     """Through ``JaxTrainer`` -> ``init_train_state`` / ``make_train_step``,
-    the entry points the train cells use: the step's expert rows and the
-    bias's largest magnitude go back through ``train.report`` and into the
-    gauges beside ``ray_tpu_train_step_seconds``."""
+    the entry points the train cells use: the step's expert rows, the
+    bias's largest magnitude and the share of layers dispatched compactly
+    go back through ``train.report`` and into the gauges beside
+    ``ray_tpu_train_step_seconds``."""
     import ray_tpu
     from ray_tpu import train
     from ray_tpu.observability import device, metrics as obs_metrics
@@ -418,8 +508,9 @@ def test_the_fused_step_does_through_the_trainer_and_reports_the_rows():
     finally:
         ray_tpu.shutdown()
     _check_the_balance(result.metrics)
-    device.record_expert_balance(np.asarray([[3, 1], [2, 2]]), 0.25)
+    device.record_expert_balance(np.asarray([[3, 1], [2, 2]]), 0.25, 0.5)
     summary = obs_metrics.metrics_summary()
+    assert summary["ray_tpu_train_dispatch_compact_share"][""] == 0.5
     assert summary["ray_tpu_train_router_bias_max"][""] == 0.25
     assert summary["ray_tpu_train_expert_load_imbalance"][""] == 1.5
 
