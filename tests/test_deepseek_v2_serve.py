@@ -17,7 +17,6 @@ form only, no cache, every held expert on every token):
   and absent for the others.
 """
 
-import asyncio
 import dataclasses
 import functools
 import importlib
@@ -28,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.references import deepseek_v2_decoder as reference
 from benchmarks.tools import mla_check
 from ray_tpu.models import llama, llama_serve, moe
@@ -37,7 +37,6 @@ from ray_tpu.ops import mla_decode_attention as kernel_module
 
 VOCAB, SLOTS, MAX_LEN = 256, 4, 64
 TOL = 1e-3          # float32 both sides: the order of sums alone
-MARGIN = 0.25       # kinds/serve_llm.py's LOGIT_MARGIN
 YARN = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
         "mscale": 0.707, "mscale_all_dim": 0.707,
         "original_max_position_embeddings": 16}
@@ -86,37 +85,7 @@ def _published(cfg, first=None):
 @pytest.fixture(scope="module")
 def model():
     cfg = _cfg()
-    return cfg, llama.init_params(jax.random.key(7), cfg)
-
-
-@functools.lru_cache(maxsize=None)
-def _programs(cfg, flash_from):
-    return llama_serve.build_prefill(cfg), llama_serve.build_decode_k(cfg)
-
-
-def _serve(cfg, params, prompt, new_tokens, cache=None, slot=2):
-    """One request through the two programs: its tokens and the cache."""
-    prefill, decode_k = _programs(cfg, llama.FLASH_PREFILL_FROM)
-    if cache is None:
-        cache = llama_serve.init_cache(cfg, SLOTS, MAX_LEN)
-    n = len(prompt)
-    bucket = next(b for b in (8, 16, 32, 64) if b >= n)
-    toks = np.zeros((1, bucket), np.int32)
-    toks[0, :n] = prompt
-    cache, first, _ = prefill(params, cache, jnp.asarray(toks),
-                              jnp.asarray([n], jnp.int32),
-                              jnp.asarray([slot], jnp.int32))
-    emitted = [int(first[0])]
-    tok = jnp.zeros(SLOTS, jnp.int32).at[slot].set(first[0])
-    lens = jnp.zeros(SLOTS, jnp.int32).at[slot].set(n)
-    active = jnp.zeros(SLOTS, bool).at[slot].set(True)
-    zeros, off = jnp.zeros(SLOTS, jnp.int32), jnp.zeros(SLOTS, bool)
-    while len(emitted) < new_tokens:
-        cache, out, tok, lens, _ = decode_k(
-            params, cache, tok, lens, zeros, zeros, off, active, k=4,
-            s_active=MAX_LEN)
-        emitted += [int(t) for t in np.asarray(out)[:, slot]]
-    return emitted[:new_tokens], cache
+    return cfg, family.init_params(jax.random.key(7), cfg)
 
 
 def _gap(cfg, params, prompt, emitted):
@@ -139,7 +108,12 @@ def test_prefill_then_decode_through_the_latent_cache(
         monkeypatch.setattr(llama, "FLASH_PREFILL_FROM", 0)
     prompt = np.random.default_rng(prompt_len).integers(
         0, VOCAB, prompt_len).astype(np.int32)
-    emitted, _ = _serve(cfg, params, prompt, new_tokens)
+    # one case keeps its own bucket: 16 rows in a bucket of 16, the row
+    # that fills its bucket exactly (no padding position behind the last
+    # token); every other length is data in the file's one bucket
+    bucket = 16 if prompt_len == 16 else None
+    emitted, _ = family.serve_one(cfg, params, prompt, new_tokens,
+                                  bucket=bucket)
     assert _gap(cfg, params, prompt, emitted) <= TOL
 
 
@@ -148,7 +122,7 @@ def test_the_head_groups_of_a_long_prefill_are_the_heads(monkeypatch):
     of heads at a time, and dispatches to its experts a chunk of positions
     at a time: the same logits and rows as all at once."""
     cfg = _cfg(n_layers=2)
-    params = llama.init_params(jax.random.key(3), cfg)
+    params = family.init_params(jax.random.key(3), cfg)
     prompt = np.random.default_rng(0).integers(0, VOCAB, (1, 48))
     lengths = jnp.asarray([41], jnp.int32)
     whole = llama.prefill_with_states(params, jnp.asarray(prompt), lengths,
@@ -196,14 +170,9 @@ def test_absorbed_is_expanded(model):
 
 def test_a_reused_slot_inherits_nothing(model):
     cfg, params = model
-    rng = np.random.default_rng(5)
-    long, short = (rng.integers(0, VOCAB, n).astype(np.int32)
-                   for n in (30, 4))
-    _, cache = _serve(cfg, params, long, 20)
-    reused, _ = _serve(cfg, params, short, 14, cache=cache)
-    fresh, _ = _serve(cfg, params, short, 14)
-    assert reused == fresh
-    assert _gap(cfg, params, short, reused) <= TOL
+    family.reused_slot_inherits_nothing(
+        functools.partial(family.serve_one, cfg, params),
+        functools.partial(_gap, cfg, params), TOL)
 
 
 @pytest.mark.parametrize("variant", mla_check.VARIANTS)
@@ -218,11 +187,7 @@ def test_a_broken_variant_fails_the_reference(model, variant):
     with patched:
         emitted = mla_check.serve_one(vcfg, params, prompt, 24, 32, MAX_LEN,
                                       k=4, slots=SLOTS, slot=2)
-    gap = _gap(cfg, params, prompt, emitted)
-    if variant == "intact":
-        assert gap <= TOL
-    else:
-        assert gap > MARGIN
+    family.reads_as(_gap(cfg, params, prompt, emitted), variant, TOL)
 
 
 # ------------------------------------------------------------- the kernel
@@ -378,7 +343,7 @@ def test_the_four_shares_of_an_expert_layer_sum_to_the_uncut_layer(
     every rank, the held ones summing to it over the ranks."""
     whole = make(moe_held=())
     held = 16 // ranks
-    params = llama.init_params(jax.random.key(5), whole)
+    params = family.init_params(jax.random.key(5), whole)
     full = {k: v[0] for k, v in params["layers"].items()}
     rng = np.random.default_rng(9)
     x = jnp.asarray(rng.normal(size=(2, 11, 64)), jnp.float32)
@@ -529,38 +494,8 @@ def test_parts_and_parameter_trees(model):
 
 
 # -------------------------------------------------------------- the engine
-@pytest.fixture(autouse=True)
-def _preset(monkeypatch):
-    monkeypatch.setattr(
-        LlamaConfig, "latent_debug_f32",
-        classmethod(lambda cls, **kw: _cfg(**kw)), raising=False)
-
-
-@pytest.fixture
-def engine():
-    from ray_tpu.serve import llm
-
-    servers = []
-
-    def build(preset="latent_debug_f32", **kw):
-        args = dict(model_preset=preset, max_slots=4, max_len=64,
-                    prefill_buckets=(16, 32), decode_chunk=4,
-                    prefill_groups=(2, 4), warmup=False)
-        args.update(kw)
-        servers.append(llm.LLMServer(**args))
-        return servers[-1]
-
-    yield build
-    for server in servers:
-        server.shutdown()
-
-
-def _generate(server, requests):
-    async def run():
-        return await asyncio.gather(*[server.generate(r)
-                                      for r in requests])
-
-    return asyncio.run(run())
+_presets = family.presets({"latent_debug_f32": _cfg})
+engine = family.engines("latent_debug_f32", max_len=MAX_LEN)
 
 
 def test_llm_server_serves_the_model_through_generate(model, engine):
@@ -568,29 +503,16 @@ def test_llm_server_serves_the_model_through_generate(model, engine):
     prefill waves of several rows, chunks, slots reused by later requests
     (8 requests on 4 slots) -- every reply within TOL of the reference."""
     cfg, params = model
-    server = engine(params=params)
-    rng = np.random.default_rng(2)
-    requests = [{"prompt": rng.integers(0, VOCAB, n).tolist(),
-                 "max_new_tokens": m}
-                for n, m in ((5, 19), (16, 12), (23, 17), (1, 24), (30, 6),
-                             (8, 10), (9, 25), (17, 11))]
-    for request, reply in zip(requests, _generate(server, requests)):
-        assert len(reply["tokens"]) == request["max_new_tokens"]
-        assert _gap(cfg, params, request["prompt"], reply["tokens"]) <= TOL
+    family.serves_through_generate(
+        engine(params=params),
+        ((5, 19), (16, 12), (23, 17), (1, 24), (30, 6), (8, 10), (9, 25),
+         (17, 11)), functools.partial(_gap, cfg, params), TOL)
 
 
 @pytest.mark.parametrize("plane,args", [
-    ("paged", dict(paged=True)),
-    ("speculative", dict(paged=True, spec_k=2)),
-    ("disaggregat", dict(paged=True, role="prefill")),
-    ("kv_quant", dict(paged=True, kv_quant="int8")),
-])
+    case for case in family.PLANES if case[0] != "prefix sharing"])
 def test_planes_built_on_kv_rows_refuse_the_config(plane, args):
-    from ray_tpu.serve import llm
-
-    with pytest.raises(ValueError, match="latent attention") as refusal:
-        llm.LLMServer(model_preset="latent_debug_f32", warmup=False, **args)
-    assert plane in str(refusal.value)
+    family.refuses_plane("latent_debug_f32", plane, args, "latent attention")
 
 
 def test_spans_counters_and_the_latent_pool_for_this_model_and_only_for_it(
@@ -611,11 +533,13 @@ def test_spans_counters_and_the_latent_pool_for_this_model_and_only_for_it(
     before = {name: series(name) for name in
               ("moe_expert_rows", "moe_expert_rows_elsewhere")}
     timeline.clear()
-    server = engine()
+    # servers of its own, this one and the two plain ones below: every
+    # span on the timeline and every counted row is this test's
+    server = engine(fresh=True)
     cfg = server.cfg
-    _generate(server, [{"prompt": list(range(1, 1 + n)),
-                        "max_new_tokens": 9} for n in (5, 12, 20)])
-    _generate(server, [{"prompt": [1], "max_new_tokens": 1}])   # settle
+    family.generate(server, [{"prompt": list(range(1, 1 + n)),
+                              "max_new_tokens": 9} for n in (5, 12, 20)])
+    family.settle(server)
     stats = server.kv_stats()
     server.shutdown()
     row = 3 * 128 * 4                   # 3 layers x 128 values x float32
@@ -627,9 +551,9 @@ def test_spans_counters_and_the_latent_pool_for_this_model_and_only_for_it(
         "bytes_per_slot": 64 * row, "bytes_per_position": row}}
     assert pools["pool_bytes"].snapshot()[("llm.latent", "float32")] \
         == 4 * 64 * row
-    spans = [e for e in timeline.export_timeline() if e.get("ph") == "X"]
-    groups = [e["args"] for e in spans if e["name"] == "serve.prefill_group"]
-    chunks = [e["args"] for e in spans if e["name"] == "serve.chunk"]
+    spans = timeline.export_timeline()
+    groups = family.span_args(spans, "serve.prefill_group")
+    chunks = family.span_args(spans, "serve.chunk")
     assert groups and chunks
     per_token = 2 * 3                   # 2 expert layers x top-3
     for g in groups:
@@ -656,18 +580,17 @@ def test_spans_counters_and_the_latent_pool_for_this_model_and_only_for_it(
     timeline.clear()
     elsewhere = series("moe_expert_rows_elsewhere")
     for preset in ("debug", "moe_debug"):
-        plain = engine(preset=preset)
-        _generate(plain, [{"prompt": [1, 2, 3], "max_new_tokens": 5}])
-        _generate(plain, [{"prompt": [1], "max_new_tokens": 1}])
+        plain = engine(model_preset=preset, fresh=True)
+        family.generate(plain, [{"prompt": [1, 2, 3], "max_new_tokens": 5}])
+        family.settle(plain)
         assert "kv_pools" not in plain.kv_stats()
         assert "latent" not in llama_serve.cache_pools(plain.cfg, 4, 64)
-    spans = [e for e in timeline.export_timeline() if e.get("ph") == "X"]
-    seen = [e for e in spans
-            if e["name"] in ("serve.chunk", "serve.prefill_group")]
+    spans = timeline.export_timeline()
+    seen = family.span_args(spans, "serve.chunk") \
+        + family.span_args(spans, "serve.prefill_group")
     assert seen
-    for e in seen:
-        assert not [k for k in e["args"]
-                    if "latent" in k or "elsewhere" in k]
+    for args in seen:
+        assert not [k for k in args if "latent" in k or "elsewhere" in k]
     assert series("moe_expert_rows_elsewhere") == elsewhere
 
 

@@ -581,6 +581,7 @@ def _spans(since=0.0, names=None):
 XLA_SPANS = ("xla_trace", "xla_lower", "xla_compile")
 
 
+@pytest.mark.usefixtures("own_compile_cache")
 class TestCompilePhases:
     def test_first_call_writes_one_span_a_phase_under_the_ambient_span(
             self):

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from ray_tpu.models import indexer, llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.observability import timeline
@@ -160,7 +161,7 @@ def test_prefill_with_states_returns_what_it_returned(
     monkeypatch.setattr(llama, "LATENT_HEAD_GROUP", 2)
     monkeypatch.setattr(indexer, "QUERY_TILE", 8)
     cfg = toy()
-    params = llama.init_params(jax.random.key(5), cfg)
+    params = family.init_params(jax.random.key(5), cfg)
     lens = (21, 48, 0)
     tokens = np.zeros((3, S), np.int32)
     rng = np.random.default_rng(4)

@@ -16,13 +16,12 @@ recurrence a sequential scan over positions, no cache, no chunks.
 - spans, counters and pool sizes exist for a hybrid and only for one.
 """
 
-import asyncio
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.references import granite_hybrid_decoder as reference
 from ray_tpu.models import llama, llama_serve, mamba2
 from ray_tpu.models.llama import LlamaConfig
@@ -68,7 +67,7 @@ def _published(cfg):
 @pytest.fixture(scope="module")
 def model():
     cfg = _cfg()
-    params = llama.init_params(jax.random.key(0), cfg)
+    params = family.init_params(jax.random.key(0), cfg)
     # norms, the conv bias and D away from their initial constants, so
     # that one left out or misplaced shows
     keys = iter(jax.random.split(jax.random.key(100), 8))
@@ -209,8 +208,7 @@ def _serve(cfg, params, prompts, slots, steps, tamper=None, engine_params=None,
     that are not active in the FIRST chunk (and run one chunk more
     instead).  Returns ({slot: emitted tokens}, cache)."""
     run = engine_params if engine_params is not None else params
-    prefill = llama_serve.build_prefill(cfg)
-    decode_k = llama_serve.build_decode_k(cfg)
+    prefill, decode_k = family.programs(cfg)
     cache = llama_serve.init_cache(cfg, SLOTS, MAX_LEN)
     toks, lengths = _padded(prompts, 24)
     cache, first, _ = prefill(run, cache, toks, lengths,
@@ -288,7 +286,7 @@ def test_a_slot_that_sits_out_a_chunk_keeps_its_state(model):
     together, _ = _serve(cfg, params, prompts, slots, steps=4)
     assert apart == together
     # after ONE chunk in which slot 1 sat out: run it alone to look
-    decode_k = llama_serve.build_decode_k(cfg)
+    decode_k = family.programs(cfg)[1]
     zeros, no = jnp.zeros(SLOTS, jnp.int32), jnp.zeros(SLOTS, bool)
     cache, *_ = decode_k(params, jax.tree.map(jnp.copy, before),
                          jnp.asarray([apart[0][0], 0, 0, apart[3][0]],
@@ -311,7 +309,7 @@ def test_a_reused_slot_inherits_nothing(model):
     cfg, params = model
     long, short = _prompts(7, (20,)), _prompts(8, (1,))
     _, used = _serve(cfg, params, long, (2,), steps=8)
-    prefill = llama_serve.build_prefill(cfg)
+    prefill = family.programs(cfg)[0]
     toks, lengths = _padded(short, 24)
     slot = jnp.asarray([2], jnp.int32)
     again, first_a, _ = prefill(params, used, toks, lengths, slot)
@@ -365,7 +363,7 @@ def test_a_broken_variant_fails_the_reference(model, variant):
         # step that advanced its state all the same is this: the chunk run
         # with the slot active on whatever token its carry holds
         emitted, _ = _serve(cfg, params, prompts, slots, steps=12)
-        decode_k = llama_serve.build_decode_k(cfg)
+        decode_k = family.programs(cfg)[1]
         _, cache = _serve(cfg, params, prompts, slots, steps=0)
         zeros, no = jnp.zeros(SLOTS, jnp.int32), jnp.zeros(SLOTS, bool)
         lens = np.asarray([20, 9, 0, 15], np.int32)
@@ -386,31 +384,8 @@ def test_a_broken_variant_fails_the_reference(model, variant):
 
 
 # ------------------------------------------------- through the scheduler
-@pytest.fixture
-def engine():
-    from ray_tpu.serve import llm
-
-    servers = []
-
-    def build(preset="hybrid_debug", **kw):
-        args = dict(model_preset=preset, max_slots=4, max_len=128,
-                    prefill_buckets=(16, 32), decode_chunk=4,
-                    prefill_groups=(2, 4), warmup=False)
-        args.update(kw)
-        servers.append(llm.LLMServer(**args))
-        return servers[-1]
-
-    yield build
-    for server in servers:
-        server.shutdown()
-
-
-def _generate(server, requests):
-    async def run():
-        return await asyncio.gather(*[server.generate(r)
-                                      for r in requests])
-
-    return asyncio.run(run())
+_presets = family.presets({"hybrid_debug_f32": _cfg})
+engine = family.engines("hybrid_debug", max_len=MAX_LEN)
 
 
 def test_llm_server_serves_the_hybrid_through_generate(model, engine):
@@ -418,46 +393,22 @@ def test_llm_server_serves_the_hybrid_through_generate(model, engine):
     prefill waves, chunks, slots reused by later requests (8 requests on
     4 slots) -- every reply within TOL of the reference."""
     cfg, params = model
-    server = engine(params=params,
-                    model_preset="hybrid_debug_f32")
-    rng = np.random.default_rng(2)
-    requests = [{"prompt": rng.integers(0, VOCAB, n).tolist(),
-                 "max_new_tokens": m}
-                for n, m in ((5, 9), (16, 12), (23, 7), (1, 14), (30, 6),
-                             (8, 10), (9, 5), (17, 11))]
-    for request, reply in zip(requests, _generate(server, requests)):
-        assert len(reply["tokens"]) == request["max_new_tokens"]
-        gap = reference.teacher_forced_gap(
-            params, request["prompt"], reply["tokens"], _published(cfg),
-            pad_to=64)
-        assert gap.max() <= TOL, (request, gap)
+    family.serves_through_generate(
+        engine(params=params, model_preset="hybrid_debug_f32"),
+        ((5, 9), (16, 12), (23, 7), (1, 14), (30, 6), (8, 10), (9, 5),
+         (17, 11)),
+        lambda prompt, tokens: reference.teacher_forced_gap(
+            params, prompt, tokens, _published(cfg), pad_to=64).max(), TOL)
 
 
-@pytest.fixture(autouse=True)
-def _f32_preset(monkeypatch):
-    monkeypatch.setattr(
-        LlamaConfig, "hybrid_debug_f32",
-        classmethod(lambda cls, **kw: _cfg(**kw)), raising=False)
-
-
-@pytest.mark.parametrize("plane,args", [
-    ("paged", dict(paged=True)),
-    ("prefix sharing", dict(paged=True, block_size=8, num_blocks=64)),
-    ("speculative", dict(paged=True, spec_k=2)),
-    ("disaggregat", dict(paged=True, role="prefill")),
-    ("kv_quant", dict(paged=True, kv_quant="int8")),
-])
+@pytest.mark.parametrize("plane,args", family.PLANES)
 def test_planes_that_cannot_hold_a_state_refuse_the_config(plane, args):
     """Blocks, shared prefixes, a rejected draft's rewind, a K/V hand-off
     and K/V quantization all rest on a cache of rows by position; a
     recurrent state is not one.  Each refuses at construction, naming the
     reason and what was asked."""
-    from ray_tpu.serve import llm
-
-    with pytest.raises(ValueError, match="state-space") as refusal:
-        llm.LLMServer(model_preset="hybrid_debug", warmup=False, **args)
-    assert plane in str(refusal.value)
-    assert "not rows by position" in str(refusal.value)
+    family.refuses_plane("hybrid_debug", plane, args, "state-space",
+                         words=("not rows by position",))
 
 
 def test_training_refuses_the_config(model):
@@ -482,20 +433,23 @@ def test_spans_counters_and_pools_for_a_hybrid_and_only_for_one(engine):
 
     timeline.clear()
     before = series()
-    server = engine()
+    # servers of its own, this one and the plain one below: every span on
+    # the timeline is counted, and the last chunk's is written by the time
+    # the scheduler's thread has been joined (``shutdown``)
+    server = engine(fresh=True)
     cfg = server.cfg
     requests = [{"prompt": list(range(1, 1 + n)), "max_new_tokens": 6}
                 for n in (5, 9, 20)]
-    _generate(server, requests)
+    family.generate(server, requests)
     stats = server.kv_stats()
     server.shutdown()
     per_slot = llama_serve.state_bytes_per_slot(cfg)
     # 4 Mamba layers x (16 x (4 heads x 16) float32 | 3 taps x 96 bfloat16)
     assert per_slot == {"ssm": 4 * 4 * 16 * 16 * 4,
                         "conv": 4 * 3 * (64 + 32) * 2}
-    spans = [e for e in timeline.export_timeline() if e.get("ph") == "X"]
-    groups = [e["args"] for e in spans if e["name"] == "serve.prefill_group"]
-    chunks = [e["args"] for e in spans if e["name"] == "serve.chunk"]
+    spans = timeline.export_timeline()
+    groups = family.span_args(spans, "serve.prefill_group")
+    chunks = family.span_args(spans, "serve.chunk")
     assert groups and chunks
     for g in groups:        # buckets 16 and 32 in chunks of 8
         assert g["scan_chunks"] == g["rows_padded"] * g["bucket"] // 8
@@ -520,8 +474,8 @@ def test_spans_counters_and_pools_for_a_hybrid_and_only_for_one(engine):
     # a plain decoder of the dense cells' shape: none of it
     timeline.clear()
     after = series()
-    dense = engine(preset="debug")
-    _generate(dense, requests[:2])
+    dense = engine(model_preset="debug", fresh=True)
+    family.generate(dense, requests[:2])
     assert "state_pool" not in dense.kv_stats()
     dense.shutdown()
     for e in timeline.export_timeline():

@@ -3,6 +3,7 @@
 (``ray_tpu/ops/kda_state_update.py``) against the recurrence as it is
 written, token by token, in float32."""
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,9 @@ def _recurrence(q, k, v, g, b, state):
     return jnp.moveaxis(o, 0, 1), state
 
 
+# one program a shape (as ``_kernel_inputs`` below): op by op the draws
+# are a dozen small compiles a case
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
 def _inputs(seed, N, T, H, d, strong_decay=1.0):
     ks = jax.random.split(jax.random.key(seed), 6)
     q = kda._l2norm(jax.random.normal(ks[0], (N, T, H, d))) * d ** -0.5
@@ -229,6 +233,7 @@ def test_b_reaches_past_one_on_the_drawn_weights():
     assert float(b.max()) > 1.4 and float(b.min()) > 0.0
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
 def _kernel_inputs(seed, slots, H, d, layers=2):
     ks = jax.random.split(jax.random.key(seed), 7)
     ssm = jax.random.normal(ks[0], (layers, slots, H, d, d))

@@ -21,10 +21,8 @@ row):
 - ``LLMServer.generate`` end to end, spans / counters / the index-key pool.
 """
 
-import asyncio
 import contextlib
 import dataclasses
-import functools
 import importlib
 
 import jax
@@ -32,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.references import keye_sparse_decoder as reference
 from benchmarks.tools import dsa_check
 from ray_tpu.models import indexer, llama, llama_serve
@@ -40,7 +39,6 @@ from ray_tpu.observability import metrics, timeline, tracing
 
 VOCAB, SLOTS, MAX_LEN, TOPK = 256, 4, 64, 8
 TOL = 1e-3          # float32 both sides: the order of sums alone
-MARGIN = 0.25       # kinds/serve_llm.py's LOGIT_MARGIN
 
 
 def _cfg(**kw):
@@ -104,40 +102,6 @@ def model():
     return cfg, _init(cfg), _published(cfg)
 
 
-@functools.lru_cache(maxsize=None)
-def _programs(cfg):
-    return llama_serve.build_prefill(cfg), llama_serve.build_decode_k(cfg)
-
-
-def _prefill(cfg, params, cache, prompts, slots, bucket=32):
-    """One padded group: the prompts, right-padded to the bucket, and one
-    padding row (length 0, slot -1) behind them."""
-    rows = len(prompts) + 1
-    toks = np.zeros((rows, bucket), np.int32)
-    for g, prompt in enumerate(prompts):
-        toks[g, :len(prompt)] = prompt
-    lengths = [len(p) for p in prompts] + [0]
-    cache, first, load = _programs(cfg)[0](
-        params, cache, jnp.asarray(toks), jnp.asarray(lengths, jnp.int32),
-        jnp.asarray(list(slots) + [-1], jnp.int32))
-    return cache, np.asarray(first)[:len(prompts)], load
-
-
-def _decode(cfg, params, cache, tok, lens, who, k=4, s_active=MAX_LEN):
-    active = jnp.zeros(SLOTS, bool).at[jnp.asarray(who)].set(True)
-    zeros, no = jnp.zeros(SLOTS, jnp.int32), jnp.zeros(SLOTS, bool)
-    cache, out, tok, lens, load = _programs(cfg)[1](
-        params, cache, tok, lens, zeros, zeros, no, active, k=k,
-        s_active=s_active)
-    return cache, np.asarray(out), tok, lens, load
-
-
-def _seat(first, lengths, slots):
-    at = jnp.asarray(slots)
-    return (jnp.zeros(SLOTS, jnp.int32).at[at].set(jnp.asarray(first)),
-            jnp.zeros(SLOTS, jnp.int32).at[at].set(jnp.asarray(lengths)))
-
-
 def _gap(params, prompt, emitted, published, pad_to=0):
     """The RAW gaps' largest: in float32 no tie breaks the other way, and
     nothing is taken out by count (``take_out_undecided`` is the chip's)."""
@@ -158,23 +122,23 @@ def test_prefill_then_decode_through_kv_and_index_keys(model):
     prompts = [rng.integers(0, VOCAB, n).astype(np.int32) for n in lengths]
     slots = (2, 0, 3)
     cache = llama_serve.init_cache(cfg, SLOTS, MAX_LEN)
-    cache, first, load = _prefill(cfg, params, cache, prompts, slots)
+    cache, first, load = family.prefill(cfg, params, cache, prompts, slots)
     assert (np.asarray(load[0]).sum(1) == 40 * cfg.moe_top_k).all()
-    tok, lens = _seat(first, lengths, slots)
+    tok, lens = family.seat(first, lengths, slots)
     emitted = {s: [int(t)] for s, t in zip(slots, first)}
     chosen = {s: [] for s in slots}
     with contextlib.ExitStack() as recorders:
         for s in slots:
             recorders.enter_context(
                 dsa_check.recorded_selection(s, chosen[s]))
-        _programs.cache_clear()         # traced under the recorders
+        family.forget_programs()        # traced under the recorders
         for who in (slots, slots, (2, 3), slots, slots):
-            cache, out, tok, lens, load = _decode(cfg, params, cache, tok,
-                                                  lens, who)
+            cache, out, tok, lens, load = family.decode(
+                cfg, params, cache, tok, lens, who)
             for s in who:
                 emitted[s] += [int(t) for t in out[:, s]]
         jax.effects_barrier()
-    _programs.cache_clear()
+    family.forget_programs()
     # (a slot that sat out a chunk recorded empty sets there)
     chosen = {s: [c for c in sets if len(c)] for s, sets in chosen.items()}
     assert [len(emitted[s]) for s in slots] == [21, 17, 21]
@@ -253,9 +217,11 @@ def test_a_row_no_longer_than_topk_is_the_model_without_an_indexer(model):
     emitted = []
     for c, p in ((cfg, params), (plain, plain_params)):
         cache = llama_serve.init_cache(c, SLOTS, MAX_LEN)
-        cache, first, _ = _prefill(c, p, cache, prompts, (1, 3), bucket=16)
-        tok, lens = _seat(first, (2, 4), (1, 3))
-        cache, out, tok, lens, _ = _decode(c, p, cache, tok, lens, (1, 3))
+        cache, first, _ = family.prefill(c, p, cache, prompts, (1, 3),
+                                         bucket=16)
+        tok, lens = family.seat(first, (2, 4), (1, 3))
+        cache, out, tok, lens, _ = family.decode(c, p, cache, tok, lens,
+                                                 (1, 3))
         emitted.append(np.concatenate([first[None], out[:, [1, 3]]]))
     assert (emitted[0] == emitted[1]).all()
 
@@ -410,11 +376,7 @@ def test_a_broken_variant_fails_the_reference(model, variant):
     with patched():
         emitted = dsa_check.serve_one(vcfg, weights(params), before, prompt,
                                       24, (16, 32), MAX_LEN, k=4, slots=3)
-    gap = _gap(params, prompt, emitted, published)
-    if variant == "intact":
-        assert gap <= TOL
-    else:
-        assert gap > MARGIN, gap
+    family.reads_as(_gap(params, prompt, emitted, published), variant, TOL)
 
 
 # ----------------------------------------------------------------- slots
@@ -428,13 +390,13 @@ def test_no_slot_writes_or_reads_a_foreign_index_key(model):
     long, short = (rng.integers(0, VOCAB, n).astype(np.int32)
                    for n in (40, 12))
     cache = llama_serve.init_cache(cfg, SLOTS, MAX_LEN)
-    cache, first, _ = _prefill(cfg, params, cache, [long, short], (1, 2),
-                               bucket=64)
-    tok, lens = _seat(first, (40, 12), (1, 2))
+    cache, first, _ = family.prefill(cfg, params, cache, [long, short],
+                                     (1, 2), bucket=64)
+    tok, lens = family.seat(first, (40, 12), (1, 2))
     held = jax.tree.map(np.asarray, cache)
     # slot 1 sits out; slot 2 decodes under an attended prefix of 32
-    cache, _out, tok, lens, _ = _decode(cfg, params, cache, tok, lens, (2,),
-                                        s_active=32)
+    cache, _out, tok, lens, _ = family.decode(cfg, params, cache, tok, lens,
+                                              (2,), s_active=32)
     for name in ("k", "v", "ik"):
         assert (np.asarray(cache[name])[:, 1] == held[name][:, 1]).all()
         assert (np.asarray(cache[name])[:, 0] == 0).all()
@@ -442,19 +404,19 @@ def test_no_slot_writes_or_reads_a_foreign_index_key(model):
     # slot 1 at 40 positions is past a prefix of 32: in the launch, it
     # writes nothing
     now = jax.tree.map(np.asarray, cache)
-    cache, _out, tok, lens, _ = _decode(cfg, params, cache, tok, lens,
-                                        (1, 2), s_active=32)
+    cache, _out, tok, lens, _ = family.decode(cfg, params, cache, tok, lens,
+                                              (1, 2), s_active=32)
     for name in ("k", "v", "ik"):
         assert (np.asarray(cache[name])[:, 1] == now[name][:, 1]).all()
 
     def reply(cache, slot):
-        cache, first, _ = _prefill(cfg, params, cache, [short], (slot,),
-                                   bucket=16)
-        tok, lens = _seat(first, (12,), (slot,))
+        cache, first, _ = family.prefill(cfg, params, cache, [short],
+                                         (slot,), bucket=16)
+        tok, lens = family.seat(first, (12,), (slot,))
         out = [first]
         for _ in range(3):
-            cache, toks, tok, lens, _ = _decode(cfg, params, cache, tok,
-                                                lens, (slot,))
+            cache, toks, tok, lens, _ = family.decode(
+                cfg, params, cache, tok, lens, (slot,))
             out.append(toks[:, slot])
         return np.concatenate(out)
 
@@ -483,7 +445,7 @@ def test_the_parameter_and_cache_trees():
         "kv": (2 * 2 * 4 * 64 * 2 * 16 * 4, "float32"),
         "index_keys": (2 * 4 * 8 * 64 * 4, "float32")}
     assert llama_serve.state_bytes_per_slot(cfg) == {}
-    without = llama.init_params(jax.random.key(0), _cfg(
+    without = family.init_params(jax.random.key(0), _cfg(
         index_heads=0, index_head_dim=0, index_topk=0))
     assert not set(indexer.LEAVES) & set(without["layers"])
 
@@ -512,59 +474,18 @@ def test_training_and_the_one_stack_cache_refuse_the_config(model):
         llama.forward_with_cache(params, toks[:, :1], toks[:, :1], {}, cfg)
 
 
-@pytest.fixture(autouse=True)
-def _presets(monkeypatch):
-    monkeypatch.setattr(LlamaConfig, "keye_debug_f32", classmethod(
-        lambda cls, **kw: _cfg(**kw)), raising=False)
-    monkeypatch.setattr(LlamaConfig, "keye_debug", classmethod(
-        lambda cls, **kw: _cfg(**{"dtype": jnp.bfloat16, **kw})),
-        raising=False)
+_presets = family.presets({
+    "keye_debug_f32": _cfg,
+    "keye_debug": lambda **kw: _cfg(**{"dtype": jnp.bfloat16, **kw})})
+engine = family.engines("keye_debug", max_len=128)
 
 
-@pytest.fixture
-def engine():
-    from ray_tpu.serve import llm
-
-    servers = []
-
-    def build(preset="keye_debug", **kw):
-        args = dict(model_preset=preset, max_slots=4, max_len=128,
-                    prefill_buckets=(16, 32), decode_chunk=4,
-                    prefill_groups=(2, 4), warmup=False)
-        args.update(kw)
-        servers.append(llm.LLMServer(**args))
-        return servers[-1]
-
-    yield build
-    for server in servers:
-        server.shutdown()
-
-
-def _generate(server, requests):
-    async def run():
-        return await asyncio.gather(*[server.generate(r)
-                                      for r in requests])
-
-    return asyncio.run(run())
-
-
-@pytest.mark.parametrize("plane,args", [
-    ("paged", dict(paged=True)),
-    ("prefix sharing", dict(paged=True, block_size=8, num_blocks=64)),
-    ("speculative", dict(paged=True, spec_k=2)),
-    ("disaggregat", dict(paged=True, role="prefill")),
-    ("kv_quant", dict(paged=True, kv_quant="int8")),
-])
+@pytest.mark.parametrize("plane,args", family.PLANES)
 def test_planes_that_hold_no_index_keys_refuse_the_config(plane, args):
     """Blocks, shared prefixes, a rejected draft's rewind, a K/V hand-off
     and K/V quantization hold K and V rows alone."""
-    from ray_tpu.serve import llm
-
-    with pytest.raises(ValueError, match="has an indexer") as refusal:
-        llm.LLMServer(model_preset="keye_debug", warmup=False, **args)
-    assert plane in str(refusal.value)
-    assert "no index-key pool" in str(refusal.value)
-    assert "window" not in str(refusal.value)
+    family.refuses_plane("keye_debug", plane, args, "has an indexer",
+                         words=("no index-key pool",), absent=("window",))
 
 
 def test_llm_server_serves_the_model_through_generate(model, engine):
@@ -583,23 +504,20 @@ def test_llm_server_serves_the_model_through_generate(model, engine):
 
     timeline.clear()
     before = series()
-    server = engine(params=params, preset="keye_debug_f32")
+    # a server of its own: every chunk on the timeline is counted, and it
+    # is shut down (the scheduler's thread joined) before they are
+    server = engine(params=params, model_preset="keye_debug_f32",
+                    fresh=True)
     assert set(server.cache) == {"k", "v", "ik"}
-    rng = np.random.default_rng(2)
-    requests = [{"prompt": rng.integers(0, VOCAB, n).tolist(),
-                 "max_new_tokens": m}
-                for n, m in ((5, 9), (16, 12), (23, 7), (1, 14), (30, 6),
-                             (2, 4), (9, 5), (17, 11))]
-    for request, reply in zip(requests, _generate(server, requests)):
-        assert len(reply["tokens"]) == request["max_new_tokens"]
-        gap = _gap(params, request["prompt"], reply["tokens"], published,
-                   pad_to=64)
-        assert gap <= TOL, (request, gap)
-    _generate(server, [{"prompt": [1], "max_new_tokens": 1}])   # settle
+    family.serves_through_generate(
+        server, ((5, 9), (16, 12), (23, 7), (1, 14), (30, 6), (2, 4), (9, 5),
+                 (17, 11)),
+        lambda prompt, tokens: _gap(params, prompt, tokens, published,
+                                    pad_to=64), TOL)
+    family.settle(server)
     stats = server.kv_stats()
     server.shutdown()
-    chunks = [e["args"] for e in timeline.export_timeline()
-              if e.get("ph") == "X" and e["name"] == "serve.chunk"]
+    chunks = family.span_args(timeline.export_timeline(), "serve.chunk")
     assert chunks
     for c in chunks:
         assert "index_keys_scored" not in c     # one a position present

@@ -20,8 +20,6 @@ conv state, a loop over experts):
   parent commit lowered.
 """
 
-import asyncio
-import functools
 import hashlib
 
 import jax
@@ -29,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.references import lfm2_moe_decoder as reference
 from benchmarks.tools import lfm2_check
 from ray_tpu.models import llama, llama_serve, moe, shortconv
@@ -37,7 +36,6 @@ from ray_tpu.observability import metrics, timeline, tracing
 
 VOCAB, SLOTS, MAX_LEN = 256, 4, 64
 TOL = 1e-3          # float32 both sides: the order of sums alone
-MARGIN = 0.25       # kinds/serve_llm.py's LOGIT_MARGIN
 PUBLISHED = ("conv", "conv", "full_attention", "conv", "conv", "conv",
              "full_attention", "conv", "conv", "conv", "full_attention",
              "conv", "conv", "conv", "full_attention", "conv", "conv",
@@ -82,16 +80,20 @@ def _init(cfg, seed=7):
     where a norm sits and which weight it takes is seen, and the selection
     bias ten times as wide (0.2: at 8 experts the scores lie further
     apart, and a bias that reached the gates has to show)."""
-    params = llama.init_params(jax.random.key(seed), cfg)
-    keys = iter(jax.random.split(jax.random.key(seed + 1), 64))
+    def init(key, moving):
+        keys = iter(jax.random.split(moving, 64))
 
-    def moved(path, x):
-        name = path[-1].key
-        if name.endswith("norm"):
-            return x * (1 + 0.2 * jax.random.normal(next(keys), x.shape))
-        return 10 * x if name == "router_bias" else x
+        def moved(path, x):
+            name = path[-1].key
+            if name.endswith("norm"):
+                return x * (1 + 0.2 * jax.random.normal(next(keys), x.shape))
+            return 10 * x if name == "router_bias" else x
 
-    return jax.tree_util.tree_map_with_path(moved, params)
+        return jax.tree_util.tree_map_with_path(
+            moved, llama.init_params(key, cfg))
+
+    # one program: op by op the initialiser is a hundred small compiles
+    return jax.jit(init)(jax.random.key(seed), jax.random.key(seed + 1))
 
 
 @pytest.fixture(scope="module", params=sorted(PATTERNS))
@@ -105,34 +107,6 @@ def model(request):
 def cut():
     cfg = _cfg()
     return cfg, _init(cfg), _published(cfg, PUBLISHED[:14])
-
-
-@functools.lru_cache(maxsize=None)
-def _programs(cfg):
-    return llama_serve.build_prefill(cfg), llama_serve.build_decode_k(cfg)
-
-
-def _prefill(cfg, params, cache, prompts, slots, bucket=32):
-    """One padded group: the prompts, right-padded to the bucket, and one
-    padding row (length 0, slot -1) behind them."""
-    rows = len(prompts) + 1
-    toks = np.zeros((rows, bucket), np.int32)
-    for g, prompt in enumerate(prompts):
-        toks[g, :len(prompt)] = prompt
-    lengths = [len(p) for p in prompts] + [0]
-    cache, first, load = _programs(cfg)[0](
-        params, cache, jnp.asarray(toks), jnp.asarray(lengths, jnp.int32),
-        jnp.asarray(list(slots) + [-1], jnp.int32))
-    return cache, np.asarray(first)[:len(prompts)], load
-
-
-def _decode(cfg, params, cache, tok, lens, who, k=4):
-    active = jnp.zeros(SLOTS, bool).at[jnp.asarray(who)].set(True)
-    zeros, no = jnp.zeros(SLOTS, jnp.int32), jnp.zeros(SLOTS, bool)
-    cache, out, tok, lens, load = _programs(cfg)[1](
-        params, cache, tok, lens, zeros, zeros, no, active, k=k,
-        s_active=MAX_LEN)
-    return cache, np.asarray(out), tok, lens, load
 
 
 def _gap(params, prompt, emitted, published):
@@ -151,19 +125,16 @@ def test_prefill_then_decode_through_kv_and_conv_state(model):
                for n in (1, 9, 30)]
     slots = (2, 0, 3)
     cache = llama_serve.init_cache(cfg, SLOTS, MAX_LEN)
-    cache, first, load = _prefill(cfg, params, cache, prompts, slots)
+    cache, first, load = family.prefill(cfg, params, cache, prompts, slots)
     expert_layers = cfg.n_layers - cfg.first_dense_layers
     assert load[0].shape == (expert_layers, cfg.moe_experts)
     # the real positions alone, top-k experts each, in every expert layer
     assert (np.asarray(load[0]).sum(1) == 40 * cfg.moe_top_k).all()
-    tok = jnp.zeros(SLOTS, jnp.int32).at[jnp.asarray(slots)].set(
-        jnp.asarray(first))
-    lens = jnp.zeros(SLOTS, jnp.int32).at[jnp.asarray(slots)].set(
-        jnp.asarray([1, 9, 30]))
+    tok, lens = family.seat(first, (1, 9, 30), slots)
     emitted = {s: [int(t)] for s, t in zip(slots, first)}
     for who in (slots, slots, (2, 3), slots, slots):
-        cache, out, tok, lens, load = _decode(cfg, params, cache, tok, lens,
-                                              who)
+        cache, out, tok, lens, load = family.decode(cfg, params, cache, tok,
+                                                    lens, who)
         assert int(np.asarray(load[0]).sum()) \
             == 4 * len(who) * cfg.moe_top_k * expert_layers
         for s in who:
@@ -297,11 +268,7 @@ def test_a_broken_variant_fails_the_reference(cut, variant):
     with patched():
         emitted = lfm2_check.serve_one(vcfg, weights(params), before, prompt,
                                        24, 32, MAX_LEN, k=4, slots=3)
-    gap = _gap(params, prompt, emitted, published)
-    if variant == "intact":
-        assert gap <= TOL
-    else:
-        assert gap > MARGIN, gap
+    family.reads_as(_gap(params, prompt, emitted, published), variant, TOL)
 
 
 # ------------------------------------------- parts, trees and refusals
@@ -400,40 +367,10 @@ def test_training_and_the_one_stack_cache_refuse_the_config(cut):
 
 
 # ------------------------------------------------- through the scheduler
-@pytest.fixture(autouse=True)
-def _presets(monkeypatch):
-    monkeypatch.setattr(LlamaConfig, "lfm2_debug_f32", classmethod(
-        lambda cls, **kw: _cfg(**kw)), raising=False)
-    monkeypatch.setattr(LlamaConfig, "lfm2_debug", classmethod(
-        lambda cls, **kw: _cfg(**{"dtype": jnp.bfloat16, **kw})),
-        raising=False)
-
-
-@pytest.fixture
-def engine():
-    from ray_tpu.serve import llm
-
-    servers = []
-
-    def build(preset="lfm2_debug", **kw):
-        args = dict(model_preset=preset, max_slots=4, max_len=128,
-                    prefill_buckets=(16, 32), decode_chunk=4,
-                    prefill_groups=(2, 4), warmup=False)
-        args.update(kw)
-        servers.append(llm.LLMServer(**args))
-        return servers[-1]
-
-    yield build
-    for server in servers:
-        server.shutdown()
-
-
-def _generate(server, requests):
-    async def run():
-        return await asyncio.gather(*[server.generate(r)
-                                      for r in requests])
-
-    return asyncio.run(run())
+_presets = family.presets({
+    "lfm2_debug_f32": _cfg,
+    "lfm2_debug": lambda **kw: _cfg(**{"dtype": jnp.bfloat16, **kw})})
+engine = family.engines("lfm2_debug", max_len=128)
 
 
 def test_llm_server_serves_the_model_through_generate(cut, engine):
@@ -441,19 +378,14 @@ def test_llm_server_serves_the_model_through_generate(cut, engine):
     prefill waves, chunks, slots reused by later requests (8 requests on
     4 slots) -- every reply within TOL of the reference."""
     cfg, params, published = cut
-    server = engine(params=params, preset="lfm2_debug_f32")
+    server = engine(params=params, model_preset="lfm2_debug_f32")
     bias = server.params["layers"]["router_bias"]
     assert bias.dtype == jnp.float32
-    rng = np.random.default_rng(2)
-    requests = [{"prompt": rng.integers(0, VOCAB, n).tolist(),
-                 "max_new_tokens": m}
-                for n, m in ((5, 9), (16, 12), (23, 7), (1, 14), (30, 6),
-                             (8, 10), (9, 5), (17, 11))]
-    for request, reply in zip(requests, _generate(server, requests)):
-        assert len(reply["tokens"]) == request["max_new_tokens"]
-        gap = reference.teacher_forced_gap(
-            params, request["prompt"], reply["tokens"], published, pad_to=64)
-        assert gap.max() <= TOL, (request, gap)
+    family.serves_through_generate(
+        server, ((5, 9), (16, 12), (23, 7), (1, 14), (30, 6), (8, 10),
+                 (9, 5), (17, 11)),
+        lambda prompt, tokens: reference.teacher_forced_gap(
+            params, prompt, tokens, published, pad_to=64).max(), TOL)
 
 
 def test_a_bfloat16_engine_keeps_the_bias_float32(engine):
@@ -463,24 +395,14 @@ def test_a_bfloat16_engine_keeps_the_bias_float32(engine):
     assert server.cache["conv"].dtype == jnp.bfloat16
 
 
-@pytest.mark.parametrize("plane,args", [
-    ("paged", dict(paged=True)),
-    ("prefix sharing", dict(paged=True, block_size=8, num_blocks=64)),
-    ("speculative", dict(paged=True, spec_k=2)),
-    ("disaggregat", dict(paged=True, role="prefill")),
-    ("kv_quant", dict(paged=True, kv_quant="int8")),
-])
+@pytest.mark.parametrize("plane,args", family.PLANES)
 def test_planes_that_cannot_hold_a_state_refuse_the_config(plane, args):
     """Blocks, shared prefixes, a rejected draft's rewind, a K/V hand-off
     and K/V quantization all rest on a cache of rows by position; a conv
     state is not one, as a recurrent state is not."""
-    from ray_tpu.serve import llm
-
-    with pytest.raises(ValueError, match="short-convolution") as refusal:
-        llm.LLMServer(model_preset="lfm2_debug", warmup=False, **args)
-    assert plane in str(refusal.value)
-    assert "not rows by position" in str(refusal.value)
-    assert "state-space" not in str(refusal.value)
+    family.refuses_plane("lfm2_debug", plane, args, "short-convolution",
+                         words=("not rows by position",),
+                         absent=("state-space",))
 
 
 def test_spans_counters_and_the_conv_pool(engine):
@@ -500,18 +422,21 @@ def test_spans_counters_and_the_conv_pool(engine):
 
     timeline.clear()
     before = series()
-    server = engine()
+    # a server of its own, though its arguments are the bfloat16 test's:
+    # every span on the timeline is counted, and the last chunk's is written
+    # by the time the scheduler's thread has been joined (``shutdown``)
+    server = engine(fresh=True)
     cfg = server.cfg
     requests = [{"prompt": list(range(1, 1 + n)), "max_new_tokens": 6}
                 for n in (5, 9, 20)]
-    _generate(server, requests)
+    family.generate(server, requests)
     stats = server.kv_stats()
     server.shutdown()
     per_slot = llama_serve.state_bytes_per_slot(cfg)
     assert per_slot == {"conv": 11 * 2 * 64 * 2}    # 11 layers, bfloat16
-    spans = [e for e in timeline.export_timeline() if e.get("ph") == "X"]
-    groups = [e["args"] for e in spans if e["name"] == "serve.prefill_group"]
-    chunks = [e["args"] for e in spans if e["name"] == "serve.chunk"]
+    spans = timeline.export_timeline()
+    groups = family.span_args(spans, "serve.prefill_group")
+    chunks = family.span_args(spans, "serve.chunk")
     assert groups and chunks
     for g in groups:
         assert "scan_chunks" not in g

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from ray_tpu.models import llama, llama_serve
 from ray_tpu.models.llama import LlamaConfig
 
@@ -226,7 +227,7 @@ T = 12
 def model(request):
     """float32 toy weights, the tokens, and what ``forward`` says."""
     cfg = _cfg(request.param, dtype=jnp.float32, tie_embeddings=False)
-    params = llama.init_params(jax.random.key(1), cfg)
+    params = family.init_params(jax.random.key(1), cfg)
     tokens = jax.random.randint(jax.random.key(2), (SLOTS, T), 0,
                                 cfg.vocab_size, dtype=jnp.int32)
     want = np.asarray(llama.forward(params, tokens, cfg))

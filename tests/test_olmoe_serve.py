@@ -12,13 +12,14 @@ precision does: the same reference run in bfloat16 is off by ~1e-2
 (asserted below, so the tolerance cannot be met by lower precision).
 """
 
-import asyncio
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.references import olmoe_decoder
 from ray_tpu.models import llama, moe
 from ray_tpu.models.llama import LlamaConfig
@@ -49,9 +50,12 @@ def _program_fields(top_k):
                 qk_norm=True)
 
 
+@functools.lru_cache(maxsize=None)
 def _model(top_k, seed=0, zero_router=False):
+    """(config, weights), the SAME objects at every call: ``engine`` tells
+    weights apart by identity."""
     cfg = LlamaConfig(**_program_fields(top_k))
-    params = llama.init_params(jax.random.key(seed), cfg)
+    params = family.init_params(jax.random.key(seed), cfg)
     # norms away from 1, so that a norm left out or misplaced shows
     keys = iter(jax.random.split(jax.random.key(seed + 100), 8))
     for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
@@ -88,39 +92,21 @@ def _reference_logits(params, tokens, top_k, dtype=None, monkeypatch=None):
             low, tokens, _published(top_k)).astype(jnp.float32))
 
 
-@pytest.fixture
-def engine(monkeypatch):
-    """``build(top_k, **engine_args)`` -> a real ``LLMServer`` on the toy
-    model (the preset installed by name, as the benchmark does)."""
-    from ray_tpu.serve import llm
-
-    servers = []
-
-    def build(top_k, params, **kw):
-        name = f"olmoe_toy_k{top_k}"
-        fields = _program_fields(top_k)
-        monkeypatch.setattr(
-            LlamaConfig, name,
-            classmethod(lambda cls, **over: cls(**{**fields, **over})),
-            raising=False)
-        args = dict(model_preset=name, params=params, max_slots=4,
-                    max_len=128, prefill_buckets=(16, 32), decode_chunk=4,
-                    prefill_groups=(2, 4), warmup=False)
-        args.update(kw)
-        servers.append(llm.LLMServer(**args))
-        return servers[-1]
-
-    yield build
-    for server in servers:
-        server.shutdown()
+def _preset(top_k):
+    return lambda **over: LlamaConfig(**{**_program_fields(top_k), **over})
 
 
-def _generate(server, requests):
-    async def run():
-        return await asyncio.gather(*[server.generate(r)
-                                      for r in requests])
+# the presets by name, as the benchmark names its own
+_presets = family.presets({f"olmoe_toy_k{top_k}": _preset(top_k)
+                           for top_k in (2, 3)})
+engine = family.engines(max_len=128)
 
-    return asyncio.run(run())
+
+def _engine(engine, top_k, **kw):
+    """The file's server of the ``top_k`` toy: one a ``top_k`` for the
+    tests that ask for no more."""
+    return engine(model_preset=f"olmoe_toy_k{top_k}",
+                  params=_model(top_k)[1], **kw)
 
 
 # ---------------------------------------------------------------- forward
@@ -165,13 +151,13 @@ def test_llm_server_prefill_then_decode_against_the_full_forward_pass(
     position its logit of the emitted token must lie within TOL of its
     top logit (logits, not token equality: a near-tie may flip)."""
     cfg, params = _model(top_k)
-    server = engine(top_k, params)
+    server = _engine(engine, top_k)
     rng = np.random.default_rng(top_k)
     requests = [{"prompt": rng.integers(0, VOCAB, n).tolist(),
                  "max_new_tokens": m}
                 for n, m in ((5, 9), (16, 12), (23, 7), (11, 14), (30, 6),
                              (8, 10))]
-    replies = _generate(server, requests)
+    replies = family.generate(server, requests)
     for request, reply in zip(requests, replies):
         assert len(reply["tokens"]) == request["max_new_tokens"]
         gap = olmoe_decoder.teacher_forced_gap(
@@ -185,14 +171,14 @@ def test_paged_plane_and_draft_inherit_the_expert_step(engine):
     """``decode_paged`` and the speculative draft reuse
     ``_make_decode_step``: the paged plane's tokens equal the dense
     plane's, bit for bit, with and without speculation."""
-    cfg, params = _model(2)
     requests = [{"prompt": list(range(3, 3 + n)), "max_new_tokens": m}
                 for n, m in ((7, 10), (18, 6), (12, 13))]
-    dense = _generate(engine(2, params), requests)
-    paged = _generate(engine(2, params, paged=True, block_size=16),
-                      requests)
-    spec = _generate(engine(2, params, paged=True, block_size=16, spec_k=3,
-                            draft_layers=1), requests)
+    dense = family.generate(_engine(engine, 2), requests)
+    paged = family.generate(_engine(engine, 2, paged=True, block_size=16),
+                            requests)
+    spec = family.generate(
+        _engine(engine, 2, paged=True, block_size=16, spec_k=3,
+                draft_layers=1), requests)
     for d, p, s in zip(dense, paged, spec):
         assert d["tokens"] == p["tokens"] == s["tokens"]
 
@@ -278,7 +264,7 @@ def test_prefill_padding_changes_nothing_and_takes_no_expert_rows(top_k):
 def test_inactive_slots_change_nothing_and_take_no_expert_rows(top_k,
                                                                engine):
     cfg, params = _model(top_k)
-    server = engine(top_k, params)
+    server = _engine(engine, top_k)
     shape = llama.init_kv_cache(cfg, 4, 128)["k"].shape
     kk, kv = jax.random.split(jax.random.key(9))
     cache = {"k": jax.random.normal(kk, shape), "v": jax.random.normal(
@@ -323,14 +309,17 @@ def test_spans_and_counters_say_what_the_experts_computed(engine):
 
     timeline.clear()
     before = series()
-    server = engine(top_k, params)
+    # a server of its own, and the dense one below: every span on the
+    # timeline is counted, and the last chunk's is written by the time the
+    # scheduler's thread has been joined (``shutdown``)
+    server = _engine(engine, top_k, fresh=True)
     requests = [{"prompt": list(range(1, 1 + n)), "max_new_tokens": 6}
                 for n in (5, 9, 20)]
-    _generate(server, requests)
+    family.generate(server, requests)
     server.shutdown()
-    spans = [e for e in timeline.export_timeline() if e.get("ph") == "X"]
-    groups = [e["args"] for e in spans if e["name"] == "serve.prefill_group"]
-    chunks = [e["args"] for e in spans if e["name"] == "serve.chunk"]
+    spans = timeline.export_timeline()
+    groups = family.span_args(spans, "serve.prefill_group")
+    chunks = family.span_args(spans, "serve.chunk")
     assert groups and chunks
     per_token = top_k * LAYERS
     for g in groups:
@@ -354,15 +343,9 @@ def test_spans_and_counters_say_what_the_experts_computed(engine):
 
     # a dense engine: none of the attributes, none of the series
     timeline.clear()
-    from ray_tpu.serve import llm
-
-    dense = llm.LLMServer(model_preset="debug", max_slots=4, max_len=128,
-                          prefill_buckets=(16, 32), decode_chunk=4,
-                          prefill_groups=(2, 4), warmup=False)
-    try:
-        _generate(dense, requests[:1])
-    finally:
-        dense.shutdown()
+    dense = engine(model_preset="debug", fresh=True)
+    family.generate(dense, requests[:1])
+    dense.shutdown()
     for e in timeline.export_timeline():
         if e.get("name") in ("serve.chunk", "serve.prefill_group"):
             assert not {"expert_rows", "expert_rows_max",

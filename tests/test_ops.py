@@ -14,16 +14,32 @@ from ray_tpu.ops import flash_attention, ring_attention
 from ray_tpu.parallel import MeshSpec, use_mesh
 
 
-def _rand_qkv(key, B, S, Hq, Hkv, D, dtype=jnp.float32):
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (B, S, Hq, D), dtype)
-    k = jax.random.normal(kk, (B, S, Hkv, D), dtype)
-    v = jax.random.normal(kv, (B, S, Hkv, D), dtype)
-    return q, k, v
+def _jit(fn, **static):
+    """``fn`` with its keywords bound, as ONE program: op by op a
+    reference and its gradient are a hundred small compiles a case."""
+    return jax.jit(functools.partial(fn, **static))
+
+
+def _normal(seed, shape, dtype=jnp.float32):
+    """Made on the host: ``jax.random.normal`` is a program a shape, and
+    what the values are is nothing a test here reads."""
+    return jnp.asarray(np.random.default_rng(seed).standard_normal(
+        shape, np.float32).astype(dtype))
+
+
+def _rand_qkv(seed, B, S, Hq, Hkv, D, dtype=jnp.float32):
+    return (_normal(seed, (B, S, Hq, D), dtype),
+            _normal(seed + 100, (B, S, Hkv, D), dtype),
+            _normal(seed + 200, (B, S, Hkv, D), dtype))
 
 
 def _positions(B, S):
     return jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+
+
+def _dot_attention(q, k, v):
+    B, S = q.shape[:2]
+    return dot_attention(q, k, v, _positions(B, S))
 
 
 def _gqa_reference(q, k, v, *, causal):
@@ -39,34 +55,42 @@ def _gqa_reference(q, k, v, *, causal):
             jax.nn.logsumexp(s, axis=-1))
 
 
+@functools.partial(jax.jit, static_argnames="causal")
+def _reference_and_vjp(q, k, v, do, *, causal):
+    """``_gqa_reference``'s (o, lse) and its gradients under ``do``."""
+    (o, lse), vjp = jax.vjp(
+        functools.partial(_gqa_reference, causal=causal), q, k, v)
+    return o, lse, vjp((do, jnp.zeros_like(lse)))
+
+
 @pytest.mark.parametrize("B,S,Hq,Hkv,D", [
     (2, 128, 4, 4, 64),     # MHA
     (2, 256, 8, 2, 32),     # GQA 4:1
     (1, 128, 4, 1, 64),     # MQA
 ])
 def test_flash_forward_matches_reference(B, S, Hq, Hkv, D):
-    q, k, v = _rand_qkv(jax.random.key(0), B, S, Hq, Hkv, D)
-    ref = dot_attention(q, k, v, _positions(B, S))
-    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=128)
+    q, k, v = _rand_qkv(0, B, S, Hq, Hkv, D)
+    ref = jax.jit(_dot_attention)(q, k, v)
+    out = _jit(flash_attention, causal=True, block_q=64, block_k=128)(
+        q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
 def test_flash_backward_matches_reference():
     B, S, Hq, Hkv, D = 2, 128, 4, 2, 32
-    q, k, v = _rand_qkv(jax.random.key(1), B, S, Hq, Hkv, D)
-    pos = _positions(B, S)
+    q, k, v = _rand_qkv(1, B, S, Hq, Hkv, D)
 
     def loss_ref(q, k, v):
-        return jnp.sum(dot_attention(q, k, v, pos) ** 2)
+        return jnp.sum(_dot_attention(q, k, v) ** 2)
 
     def loss_flash(q, k, v):
         return jnp.sum(
             flash_attention(q, k, v, causal=True, block_q=64,
                             block_k=128) ** 2)
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g_fl, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4, err_msg=name)
@@ -74,21 +98,28 @@ def test_flash_backward_matches_reference():
 
 def test_flash_noncausal_matches_softmax():
     B, S, H, D = 1, 128, 2, 32
-    q, k, v = _rand_qkv(jax.random.key(2), B, S, H, H, D)
-    out = flash_attention(q, k, v, causal=False, block_q=64, block_k=128)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D ** -0.5)
-    p = jax.nn.softmax(s, axis=-1)
-    ref = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    q, k, v = _rand_qkv(2, B, S, H, H, D)
+    out = _jit(flash_attention, causal=False, block_q=64, block_k=128)(
+        q, k, v)
+
+    @jax.jit
+    def softmax_attention(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D ** -0.5)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    ref = softmax_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
 def test_flash_bf16_close():
     B, S, Hq, Hkv, D = 2, 128, 4, 2, 64
-    q, k, v = _rand_qkv(jax.random.key(3), B, S, Hq, Hkv, D,
+    q, k, v = _rand_qkv(3, B, S, Hq, Hkv, D,
                         dtype=jnp.bfloat16)
-    ref = dot_attention(q, k, v, _positions(B, S))
-    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=128)
+    ref = jax.jit(_dot_attention)(q, k, v)
+    out = _jit(flash_attention, causal=True, block_q=64, block_k=128)(
+        q, k, v)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=3e-2, rtol=3e-2)
@@ -109,24 +140,21 @@ def test_flash_strips_match_reference(S, Hq, Hkv, D, block_q, block_k,
     bq, bk = fa._block_sizes(padded, padded, block_q, block_k)
     strip = fa._diag_strip(bq, bk)
     assert (strip and bq // strip) == strips
-    q, k, v = _rand_qkv(jax.random.key(7), 1, S, Hq, Hkv, D)
-    w = jax.random.normal(jax.random.key(8), q.shape)
-    pos = _positions(1, S)
-
-    def ref(q, k, v):
-        return dot_attention(q, k, v, pos)
+    q, k, v = _rand_qkv(7, 1, S, Hq, Hkv, D)
+    w = _normal(8, q.shape)
+    ref = _dot_attention
 
     def flash(q, k, v):
         return flash_attention(q, k, v, causal=True, block_q=block_q,
                                block_k=block_k)
 
-    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
-                               np.asarray(ref(q, k, v)),
+    np.testing.assert_allclose(np.asarray(jax.jit(flash)(q, k, v)),
+                               np.asarray(jax.jit(ref)(q, k, v)),
                                atol=2e-5, rtol=2e-5)
-    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
-    g_fl = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
+    g_ref = jax.jit(jax.grad(lambda *a: jnp.sum(ref(*a) * w),
+                             argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(lambda *a: jnp.sum(flash(*a) * w),
+                            argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g_fl, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4, err_msg=name)
@@ -142,20 +170,20 @@ def test_flash_statistics_are_lane_dense_and_round_trip(S, Hq, Hkv):
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
     B, D = 1, 32
     q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in _rand_qkv(
-        jax.random.key(S + Hq), B, S, Hq, Hkv, D))
-    do = jax.random.normal(jax.random.key(11), q.shape)
+        S + Hq, B, S, Hq, Hkv, D))
+    do = _normal(11, q.shape)
     kw = dict(causal=True, block_q=None, block_k=None, interpret=True)
     assert fa._stats_dense(fa._block_sizes(S, S, None, None)[0])
-    (o_ref, lse_ref), vjp = jax.vjp(
-        functools.partial(_gqa_reference, causal=True), q, k, v)
-    o, lse = fa._fwd(q, k, v, **kw)
+    o_ref, lse_ref, g_ref = _reference_and_vjp(q, k, v, do, causal=True)
+    o, lse = _jit(fa._fwd, **kw)(q, k, v)
     assert lse.shape == (B, Hq, 1, S) and lse.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(lse[:, :, 0, :]),
                                np.asarray(lse_ref), atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                atol=2e-5, rtol=2e-5)
-    grads = fa._bwd_impl(q, k, v, o, lse, do, out_dtype=jnp.float32, **kw)
-    for a, b, name in zip(grads, vjp((do, jnp.zeros_like(lse_ref))), "qkv"):
+    grads = _jit(fa._bwd_impl, out_dtype=jnp.float32, **kw)(
+        q, k, v, o, lse, do)
+    for a, b, name in zip(grads, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4, err_msg=name)
 
@@ -175,17 +203,18 @@ def test_flash_backward_does_its_own_gqa(Hq, Hkv, causal, out_dtype):
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
     B, S, D, block = 1, 256, 32, 128
     q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in _rand_qkv(
-        jax.random.key(Hq), B, S, Hq, Hkv, D))
-    do = jax.random.normal(jax.random.key(12), q.shape)
+        Hq, B, S, Hq, Hkv, D))
+    do = _normal(12, q.shape)
     kw = dict(causal=causal, block_q=block, block_k=block, interpret=True)
-    o, lse = fa._fwd(q, k, v, **kw)
-    exact = fa._bwd_impl(q, k, v, o, lse, do, out_dtype=jnp.float32, **kw)
+    o, lse = _jit(fa._fwd, **kw)(q, k, v)
+    exact = _jit(fa._bwd_impl, out_dtype=jnp.float32, **kw)(
+        q, k, v, o, lse, do)
     if out_dtype == jnp.float32:
-        (_, lse_ref), vjp = jax.vjp(
-            functools.partial(_gqa_reference, causal=causal), q, k, v)
-        grads, want, close = exact, vjp((do, jnp.zeros_like(lse_ref))), 1e-4
+        want = _reference_and_vjp(q, k, v, do, causal=causal)[2]
+        grads, close = exact, 1e-4
     else:
-        grads = fa._bwd_impl(q, k, v, o, lse, do, out_dtype=out_dtype, **kw)
+        grads = _jit(fa._bwd_impl, out_dtype=out_dtype, **kw)(
+            q, k, v, o, lse, do)
         want, close = [g.astype(out_dtype) for g in exact], 0
     assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
     for a, b, name in zip(grads, want, "qkv"):
@@ -203,9 +232,9 @@ def test_flash_gqa_gradients_in_the_models_type(Hq, Hkv, causal):
     the model's granularity, (B, S, Hq | Hkv, D), and are the float32
     reference's on the same (rounded) inputs to bf16's precision."""
     B, S, D = 1, 256, 32
-    q, k, v = _rand_qkv(jax.random.key(Hkv), B, S, Hq, Hkv, D,
+    q, k, v = _rand_qkv(Hkv, B, S, Hq, Hkv, D,
                         dtype=jnp.bfloat16)
-    w = jax.random.normal(jax.random.key(13), q.shape)
+    w = _normal(13, q.shape)
 
     def ref(q, k, v):
         out, _ = _gqa_reference(*(jnp.transpose(x, (0, 2, 1, 3)) for x in (
@@ -216,10 +245,11 @@ def test_flash_gqa_gradients_in_the_models_type(Hq, Hkv, causal):
         return flash_attention(q, k, v, causal=causal, block_q=128,
                                block_k=128)
 
-    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2))(
+    g_ref = jax.jit(jax.grad(lambda *a: jnp.sum(ref(*a) * w),
+                             argnums=(0, 1, 2)))(
         *(x.astype(jnp.float32) for x in (q, k, v)))
-    g_fl = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
+    g_fl = jax.jit(jax.grad(lambda *a: jnp.sum(flash(*a) * w),
+                            argnums=(0, 1, 2)))(q, k, v)
     for a, b, x, name in zip(g_fl, g_ref, (q, k, v), "qkv"):
         assert a.dtype == jnp.bfloat16 and a.shape == x.shape
         np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
@@ -233,12 +263,12 @@ def test_flash_statistics_keep_the_column_under_a_lane_of_rows(Sq, block_q):
     the ring's merge leans on)."""
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
     q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in _rand_qkv(
-        jax.random.key(Sq), 1, Sq, 2, 2, 32))
+        Sq, 1, Sq, 2, 2, 32))
     kw = dict(causal=True, block_k=None, interpret=True)
-    o, lse = fa._fwd(q, k, v, block_q=block_q, **kw)
+    o, lse = _jit(fa._fwd, block_q=block_q, **kw)(q, k, v)
     assert lse.shape == (1, 2, Sq, 1)
     if Sq % 128 == 0:
-        _, dense = fa._fwd(q, k, v, block_q=128, **kw)
+        _, dense = _jit(fa._fwd, block_q=128, **kw)(q, k, v)
         assert dense.shape == (1, 2, 1, Sq)
         np.testing.assert_allclose(np.asarray(dense).reshape(lse.shape),
                                    np.asarray(lse), atol=1e-6, rtol=1e-6)
@@ -281,8 +311,8 @@ def test_diag_strips_cover_the_causal_half_once(by):
 @pytest.mark.parametrize("seq_shards", [2, 4])
 def test_ring_forward_matches_reference(seq_shards):
     B, S, Hq, Hkv, D = 2, 256, 4, 2, 32
-    q, k, v = _rand_qkv(jax.random.key(4), B, S, Hq, Hkv, D)
-    ref = dot_attention(q, k, v, _positions(B, S))
+    q, k, v = _rand_qkv(4, B, S, Hq, Hkv, D)
+    ref = jax.jit(_dot_attention)(q, k, v)
     mesh = MeshSpec(seq=seq_shards).build(jax.devices()[:seq_shards])
     with use_mesh(mesh):
         out = jax.jit(functools.partial(ring_attention, mesh=mesh))(q, k, v)
@@ -292,13 +322,12 @@ def test_ring_forward_matches_reference(seq_shards):
 
 def test_ring_backward_matches_reference():
     B, S, Hq, Hkv, D = 1, 256, 4, 2, 32
-    q, k, v = _rand_qkv(jax.random.key(5), B, S, Hq, Hkv, D)
-    pos = _positions(B, S)
+    q, k, v = _rand_qkv(5, B, S, Hq, Hkv, D)
 
     def loss_ref(q, k, v):
-        return jnp.sum(dot_attention(q, k, v, pos) ** 2)
+        return jnp.sum(_dot_attention(q, k, v) ** 2)
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
 
     mesh = MeshSpec(seq=4).build(jax.devices()[:4])
     with use_mesh(mesh):
@@ -315,9 +344,9 @@ def test_flash_fallback_small_shapes():
     # Debug-model shapes (S=32, D=16) take the einsum fallback on TPU
     # and interpret mode on CPU; either way numerics match.
     B, S, H, D = 2, 32, 4, 16
-    q, k, v = _rand_qkv(jax.random.key(6), B, S, H, H, D)
-    ref = dot_attention(q, k, v, _positions(B, S))
-    out = flash_attention(q, k, v, causal=True)
+    q, k, v = _rand_qkv(6, B, S, H, H, D)
+    ref = jax.jit(_dot_attention)(q, k, v)
+    out = _jit(flash_attention, causal=True)(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 

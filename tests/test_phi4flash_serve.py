@@ -9,7 +9,6 @@ a sequential recurrence, two explicit softmaxes a differential head, every
 layer at every position.  Kernels interpreted.
 """
 
-import asyncio
 import dataclasses
 import importlib
 
@@ -18,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.lib import sambay_flops
 from benchmarks.references import phi4flash_decoder as reference
 from ray_tpu.models import llama, llama_serve, mamba1
@@ -51,7 +51,7 @@ def toy(**fields) -> LlamaConfig:
 @pytest.fixture(scope="module")
 def model():
     cfg = toy()
-    return cfg, llama.init_params(jax.random.key(0), cfg, jnp.float32)
+    return cfg, family.init_params(jax.random.key(0), cfg, jnp.float32)
 
 
 @pytest.fixture(scope="module")
@@ -261,28 +261,15 @@ def test_the_kernels_compute_the_two_explicit_softmaxes(model, monkeypatch):
 
 
 # ------------------------------------------------------------ the engine
-def _serve(cfg, params, requests, **engine):
-    from ray_tpu.serve import llm
-
-    name = f"phi4flash_toy_{abs(hash(cfg)) % 10 ** 8}"
-    setattr(LlamaConfig, name, classmethod(
-        lambda cls, **kw: dataclasses.replace(cfg, **kw)))
-    server = llm.LLMServer(**{**dict(
-        model_preset=name, params=params, max_slots=2, max_len=64,
-        prefill_buckets=(16, 32), decode_chunk=4, prefill_groups=(1, 2),
-        warmup=False), **engine})
-
-    async def run(wave):
-        return await asyncio.gather(*[server.generate(r) for r in wave])
-
-    try:
-        return [asyncio.run(run(wave)) for wave in requests], server
-    finally:
-        server.shutdown()
+# two slots and groups of one and two rows: three requests put the third
+# in a REUSED slot
+_presets = family.presets({"phi4flash_toy": toy})
+engine = family.engines("phi4flash_toy", max_slots=2, max_len=64,
+                        prefill_groups=(1, 2))
 
 
 def test_prefill_then_decode_through_the_cache_is_the_reference(
-        model, tokens, traced, monkeypatch):
+        model, tokens, traced, monkeypatch, engine):
     """Through ``LLMServer``: three prompts on two slots (9 tokens: one
     ring lap; 20: past the window and a scan chunk's edge; 31), 12 tokens
     each, so the third request is served in a REUSED slot and inherits no
@@ -295,15 +282,19 @@ def test_prefill_then_decode_through_the_cache_is_the_reference(
     monkeypatch.setattr(flash, "DEFAULT_BLOCK", 16)
     prompts = [tokens[0, :9].tolist(), tokens[1, :20].tolist(),
                tokens[0, 5:36].tolist()]
-    (replies, _settle), server = _serve(cfg, params, [
-        [{"prompt": p, "max_new_tokens": 12} for p in prompts],
-        [{"prompt": [7], "max_new_tokens": 1}]])
+    # a server of its own: its programs are traced under this test's
+    # patches, and every span on the timeline is counted
+    server = engine(params=params, fresh=True)
+    replies = family.generate(
+        server, [{"prompt": p, "max_new_tokens": 12} for p in prompts])
+    family.settle(server)
+    server.shutdown()
     for prompt, reply in zip(prompts, replies):
         assert len(reply["tokens"]) == 12
         assert float(np.max(_gaps(params, prompt, reply["tokens"]))) < 1e-3
-    events = [e for e in traced.export_timeline() if e.get("ph") == "X"]
-    groups = [e["args"] for e in events if e["name"] == "serve.prefill_group"]
-    chunks = [e["args"] for e in events if e["name"] == "serve.chunk"]
+    events = traced.export_timeline()
+    groups = family.span_args(events, "serve.prefill_group")
+    chunks = family.span_args(events, "serve.chunk")
     assert groups and chunks
     for g in groups:
         # layers 6 and 7 at one position a row
@@ -329,7 +320,8 @@ def _walk_distance(cfg, params, tokens):
     return float(jnp.max(jnp.abs(mine - theirs)) / jnp.std(theirs))
 
 
-def test_faults_read_far_over_the_sound_engine(model, tokens, monkeypatch):
+def test_faults_read_far_over_the_sound_engine(model, tokens, monkeypatch,
+                                               engine):
     """How tight the comparison is at THIS size.  The sound programs sit
     1e-5 deviations from the reference.  Lambda applied to the pair's first
     softmax makes the emitted tokens arbitrary ones: the harness's own
@@ -352,8 +344,10 @@ def test_faults_read_far_over_the_sound_engine(model, tokens, monkeypatch):
     with monkeypatch.context() as patch:
         patch.setattr(llama, "diff_combine",
                       sambay_check.faulty_combine("lambda_wrong_half"))
-        (replies,), _ = _serve(cfg, params, [[{"prompt": prompt,
-                                               "max_new_tokens": 12}]])
+        # a server of its own: its programs are traced under the fault
+        replies = family.generate(
+            engine(params=params, fresh=True),
+            [{"prompt": prompt, "max_new_tokens": 12}])
         wrong_half = _walk_distance(cfg, params, tokens)
     assert float(np.max(_gaps(params, prompt, replies[0]["tokens"]))) > 0.25
     assert wrong_half > 0.25
@@ -389,7 +383,6 @@ def test_faults_read_far_over_the_sound_engine(model, tokens, monkeypatch):
     assert float(jnp.max(jnp.abs(stored - exact)) / jnp.std(exact)) > 3e-4
 
 
-def test_the_planes_that_hold_kv_rows_alone_refuse_the_model(model):
-    cfg, params = model
-    with pytest.raises(ValueError, match="keep a state a slot"):
-        _serve(cfg, params, [], paged=True)
+def test_the_planes_that_hold_kv_rows_alone_refuse_the_model():
+    family.refuses_plane("phi4flash_toy", "paged", dict(paged=True),
+                         "keep a state a slot")

@@ -6,13 +6,13 @@ every plane that prefills — toy sizes, on the CPU.  Also: what the
 ``serve.prefill_group`` spans say of the groups, and that warm-up holds
 every program a wave can ask for."""
 
-import asyncio
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.observability import device as device_plane
@@ -59,11 +59,7 @@ def _requests(seed, count=14):
 
 
 def _generate(server, requests):
-    async def run():
-        return await asyncio.gather(*[server.generate(r)
-                                      for r in requests])
-
-    return [r["tokens"] for r in asyncio.run(run())]
+    return [r["tokens"] for r in family.generate(server, requests)]
 
 
 def _groups():

@@ -12,12 +12,13 @@ widths and their scratch and their optimised HLO are looked at (what a
 launch costs is ``PERF.md``'s business, not a test's).
 """
 
-import asyncio
 import os
 import re
 
 import numpy as np
 import pytest
+
+import family
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -38,6 +39,15 @@ def _onehot_insert(pool, new, slots):
     cur = jax.lax.slice_in_dim(pool, 0, P, axis=2)
     return jax.lax.dynamic_update_slice_in_dim(
         pool, jnp.where(written, spread, cur), 0, axis=2)
+
+
+def _normal(seed, shape, dtype):
+    """Something in every row, made on the host: ``jax.random.normal``
+    is a program a shape, and a filler's shape is nothing a test reads."""
+    import jax.numpy as jnp
+
+    return jnp.asarray(np.random.default_rng(seed).standard_normal(
+        shape, np.float32).astype(dtype))
 
 
 def _bits(x):
@@ -80,12 +90,11 @@ def test_rows_land_where_the_one_hot_insert_put_them(group, bucket,
     from ray_tpu.models import llama_serve
 
     layers, d = 3, 16
-    kp, kn = jax.random.split(jax.random.key(group * 100 + bucket))
+    seed = group * 100 + bucket
     # something in every row, so that a stray write shows
-    pool = jax.random.normal(kp, (layers, SLOTS, MAX_LEN, kv_heads, d),
-                             jnp.bfloat16)
-    new = jax.random.normal(kn, (layers, group, bucket, kv_heads, d),
-                            jnp.bfloat16)
+    pool = _normal(seed, (layers, SLOTS, MAX_LEN, kv_heads, d), jnp.bfloat16)
+    new = _normal(seed + 1, (layers, group, bucket, kv_heads, d),
+                  jnp.bfloat16)
     slots = _slots(group, padding)
     got = jax.jit(llama_serve._insert_rows)(
         pool, new, jnp.asarray(slots, jnp.int32))
@@ -119,11 +128,10 @@ def test_a_pool_stored_as_rows_takes_the_same_rows(group, padding):
     from ray_tpu.models import llama_serve
 
     layers, kv_heads, d, bucket = 3, 4, 16, 16
-    kp, kn = jax.random.split(jax.random.key(group))
     by_position = (layers, SLOTS, MAX_LEN, kv_heads, d)
-    pool = jax.random.normal(kp, by_position, jnp.float32)
-    new = jax.random.normal(kn, (layers, group, bucket, kv_heads, d),
-                            jnp.float32)
+    pool = _normal(group, by_position, jnp.float32)
+    new = _normal(group + 10, (layers, group, bucket, kv_heads, d),
+                  jnp.float32)
     slots = jnp.asarray(_slots(group, padding), jnp.int32)
     got = jax.jit(llama_serve._insert_rows)(
         pool.reshape(layers, SLOTS, MAX_LEN * kv_heads, d), new, slots)
@@ -138,9 +146,8 @@ def _random_like(tree, seed):
     import jax
 
     leaves, treedef = jax.tree.flatten(tree)
-    keys = jax.random.split(jax.random.key(seed), len(leaves))
-    return treedef.unflatten([
-        jax.random.normal(k, x.shape, x.dtype) for k, x in zip(keys, leaves)])
+    return treedef.unflatten([_normal(seed + i, x.shape, x.dtype)
+                              for i, x in enumerate(leaves)])
 
 
 @pytest.mark.parametrize("group,padding", [(1, "none"), (2, "first"),
@@ -160,14 +167,14 @@ def test_prefill_programs_leave_the_cache_the_one_hot_insert_left(
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import llama, llama_serve
+    from ray_tpu.models import llama_serve
     from ray_tpu.models.llama import LlamaConfig
 
     draft = preset == "draft"
     cfg = getattr(LlamaConfig, "debug" if draft else preset)(**kw)
     build = (llama_serve.build_draft_prefill if draft
              else llama_serve.build_prefill)
-    params = llama.init_params(jax.random.key(1), cfg, cfg.dtype)
+    params = family.init_params(jax.random.key(1), cfg, cfg.dtype)
     before = _random_like(llama_serve.init_cache(cfg, SLOTS, MAX_LEN), 2)
     bucket = 16
     rng = np.random.default_rng(group)
@@ -220,11 +227,7 @@ def _requests(seed, count=14):
 
 
 def _generate(server, requests):
-    async def run():
-        return await asyncio.gather(*[server.generate(r)
-                                      for r in requests])
-
-    return [r["tokens"] for r in asyncio.run(run())]
+    return [r["tokens"] for r in family.generate(server, requests)]
 
 
 @pytest.mark.parametrize("plane", sorted(_PLANES))

@@ -22,7 +22,6 @@ every key, every expert on every token, no cache and no ring.
   lower the programs they did at the parent commit.
 """
 
-import asyncio
 import functools
 import hashlib
 import importlib
@@ -32,18 +31,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.references import smallthinker_decoder as reference
 from ray_tpu.models import llama, llama_serve, moe
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.observability import metrics, timeline, tracing
 from ray_tpu.ops import decode_attention as decode_attention_module
 
-VOCAB, SLOTS, MAX_LEN, WINDOW = 256, 4, 64, 8
+VOCAB, MAX_LEN, WINDOW = 256, 64, 8
 # Float32 throughout, as the reference: the two differ by the ORDER of
 # float32 sums alone, a gap is a near-tie of ~1e-5 deviations.  A broken
 # variant emits arbitrary tokens: gaps of whole deviations.
 TOL = 1e-3
-MARGIN = 0.25       # kinds/serve_llm.py's LOGIT_MARGIN
 
 
 def _cfg(**kw):
@@ -79,39 +78,7 @@ def _published(cfg):
 @pytest.fixture(scope="module")
 def model():
     cfg = _cfg()
-    return cfg, llama.init_params(jax.random.key(7), cfg)
-
-
-@functools.lru_cache(maxsize=None)
-def _programs(cfg, flash_from):
-    """(prefill, decode_k) of a config, compiled once a shape for the
-    whole file; ``flash_from`` is what the prefill was traced under."""
-    return llama_serve.build_prefill(cfg), llama_serve.build_decode_k(cfg)
-
-
-def _serve(cfg, params, prompt, new_tokens, cache=None, slot=2, bucket=None):
-    """One request through the two programs: its tokens and the cache."""
-    prefill, decode_k = _programs(cfg, llama.FLASH_PREFILL_FROM)
-    if cache is None:
-        cache = llama_serve.init_cache(cfg, SLOTS, MAX_LEN)
-    n = len(prompt)
-    bucket = bucket or next(b for b in (8, 16, 32, 64) if b >= n)
-    toks = np.zeros((1, bucket), np.int32)
-    toks[0, :n] = prompt
-    cache, first, _ = prefill(params, cache, jnp.asarray(toks),
-                              jnp.asarray([n], jnp.int32),
-                              jnp.asarray([slot], jnp.int32))
-    emitted = [int(first[0])]
-    tok = jnp.zeros(SLOTS, jnp.int32).at[slot].set(first[0])
-    lens = jnp.zeros(SLOTS, jnp.int32).at[slot].set(n)
-    active = jnp.zeros(SLOTS, bool).at[slot].set(True)
-    zeros, off = jnp.zeros(SLOTS, jnp.int32), jnp.zeros(SLOTS, bool)
-    while len(emitted) < new_tokens:
-        cache, out, tok, lens, _ = decode_k(
-            params, cache, tok, lens, zeros, zeros, off, active, k=4,
-            s_active=MAX_LEN)
-        emitted += [int(t) for t in np.asarray(out)[:, slot]]
-    return emitted[:new_tokens], cache
+    return cfg, family.init_params(jax.random.key(7), cfg)
 
 
 def _gap(cfg, params, prompt, emitted):
@@ -138,7 +105,13 @@ def test_prefill_then_decode_against_the_full_forward_pass(
         monkeypatch.setattr(llama, "FLASH_PREFILL_FROM", 0)
     prompt = np.random.default_rng(prompt_len).integers(
         0, VOCAB, prompt_len).astype(np.int32)
-    emitted, _ = _serve(cfg, params, prompt, new_tokens)
+    # one case keeps its own bucket: 8 rows in a bucket of 8, which is the
+    # window too -- the row that fills its bucket exactly and the bucket
+    # that equals the window; every other length is data in the file's one
+    # bucket (the cache's whole length)
+    bucket = 8 if prompt_len == 8 else None
+    emitted, _ = family.serve_one(cfg, params, prompt, new_tokens,
+                                  bucket=bucket)
     assert _gap(cfg, params, prompt, emitted) <= TOL
 
 
@@ -148,7 +121,7 @@ def test_a_long_prompt_crosses_tiles_of_the_banded_flash_forward(
     behind the band, tiles both of its edges cross, and a ring that the
     prompt laps three times."""
     cfg = _cfg(window_size=700, max_seq_len=4096, n_layers=4)
-    params = llama.init_params(jax.random.key(3), cfg)
+    params = family.init_params(jax.random.key(3), cfg)
     flash = importlib.import_module("ray_tpu.ops.flash_attention")
     monkeypatch.setattr(flash, "DEFAULT_BLOCK", 512)
     monkeypatch.setattr(llama, "FLASH_PREFILL_FROM", 2048)
@@ -176,14 +149,9 @@ def test_a_reused_slot_inherits_nothing(model):
     tokens are those it gets in a fresh cache, though the rings and the
     full pool still hold the first one's rows past its length."""
     cfg, params = model
-    rng = np.random.default_rng(5)
-    long, short = (rng.integers(0, VOCAB, n).astype(np.int32)
-                   for n in (30, 4))
-    _, cache = _serve(cfg, params, long, 20)
-    reused, _ = _serve(cfg, params, short, 14, cache=cache)
-    fresh, _ = _serve(cfg, params, short, 14)
-    assert reused == fresh
-    assert _gap(cfg, params, short, reused) <= TOL
+    family.reused_slot_inherits_nothing(
+        functools.partial(family.serve_one, cfg, params),
+        functools.partial(_gap, cfg, params), TOL)
 
 
 @pytest.mark.parametrize("variant", ["window_off_by_one", "unroped_window",
@@ -201,8 +169,8 @@ def test_a_broken_variant_fails_the_reference(model, variant):
     }[variant]
     prompt = np.random.default_rng(11).integers(0, VOCAB, 21).astype(
         np.int32)
-    emitted, _ = _serve(broken, params, prompt, 24)
-    assert _gap(cfg, params, prompt, emitted) > MARGIN
+    emitted, _ = family.serve_one(broken, params, prompt, 24)
+    family.reads_as(_gap(cfg, params, prompt, emitted), variant, TOL)
 
 
 # ------------------------------------------------------------ the kernels
@@ -287,7 +255,7 @@ def test_router_on_the_layers_input_and_relu_gate(model):
 def test_the_report_gives_each_layers_choice_of_experts(model):
     cfg, params = model
     prompt = np.arange(1, 12, dtype=np.int32)
-    emitted, _ = _serve(cfg, params, prompt, 6)
+    emitted, _ = family.serve_one(cfg, params, prompt, 6)
     report = reference.teacher_forced_report(params, prompt, emitted,
                                              _published(cfg))
     L, k = cfg.n_layers, cfg.moe_top_k
@@ -344,38 +312,8 @@ def test_training_refuses_the_config(model):
 
 
 # -------------------------------------------------------------- the engine
-@pytest.fixture(autouse=True)
-def _preset(monkeypatch):
-    monkeypatch.setattr(
-        LlamaConfig, "windowed_debug_f32",
-        classmethod(lambda cls, **kw: _cfg(**kw)), raising=False)
-
-
-@pytest.fixture
-def engine():
-    from ray_tpu.serve import llm
-
-    servers = []
-
-    def build(preset="windowed_debug_f32", **kw):
-        args = dict(model_preset=preset, max_slots=4, max_len=64,
-                    prefill_buckets=(16, 32), decode_chunk=4,
-                    prefill_groups=(2, 4), warmup=False)
-        args.update(kw)
-        servers.append(llm.LLMServer(**args))
-        return servers[-1]
-
-    yield build
-    for server in servers:
-        server.shutdown()
-
-
-def _generate(server, requests):
-    async def run():
-        return await asyncio.gather(*[server.generate(r)
-                                      for r in requests])
-
-    return asyncio.run(run())
+_presets = family.presets({"windowed_debug_f32": _cfg})
+engine = family.engines("windowed_debug_f32", max_len=MAX_LEN)
 
 
 def test_llm_server_serves_the_windowed_model_through_generate(model,
@@ -384,32 +322,17 @@ def test_llm_server_serves_the_windowed_model_through_generate(model,
     prefill waves of several rows, chunks, slots reused by later requests
     (8 requests on 4 slots) -- every reply within TOL of the reference."""
     cfg, params = model
-    server = engine(params=params)
-    rng = np.random.default_rng(2)
-    requests = [{"prompt": rng.integers(0, VOCAB, n).tolist(),
-                 "max_new_tokens": m}
-                for n, m in ((5, 19), (16, 12), (23, 17), (1, 24), (30, 6),
-                             (8, 10), (9, 25), (17, 11))]
-    for request, reply in zip(requests, _generate(server, requests)):
-        assert len(reply["tokens"]) == request["max_new_tokens"]
-        assert _gap(cfg, params, request["prompt"], reply["tokens"]) <= TOL
+    family.serves_through_generate(
+        engine(params=params),
+        ((5, 19), (16, 12), (23, 17), (1, 24), (30, 6), (8, 10), (9, 25),
+         (17, 11)), functools.partial(_gap, cfg, params), TOL)
 
 
-@pytest.mark.parametrize("plane,args", [
-    ("paged", dict(paged=True)),
-    ("prefix sharing", dict(paged=True, block_size=8, num_blocks=64)),
-    ("speculative", dict(paged=True, spec_k=2)),
-    ("disaggregat", dict(paged=True, role="prefill")),
-    ("kv_quant", dict(paged=True, kv_quant="int8")),
-])
+@pytest.mark.parametrize("plane,args", family.PLANES)
 def test_planes_built_on_rows_by_position_refuse_the_config(plane, args):
-    from ray_tpu.serve import llm
-
-    with pytest.raises(ValueError, match="window layers") as refusal:
-        llm.LLMServer(model_preset="windowed_debug_f32", warmup=False,
-                      **args)
-    assert plane in str(refusal.value)
-    assert f"ring of the last {WINDOW} positions" in str(refusal.value)
+    family.refuses_plane(
+        "windowed_debug_f32", plane, args, "window layers",
+        words=(f"ring of the last {WINDOW} positions",))
 
 
 def test_spans_counters_and_pools_for_a_windowed_model_and_only_for_one(
@@ -421,10 +344,13 @@ def test_spans_counters_and_pools_for_a_windowed_model_and_only_for_one(
     assert tracing.enabled()
     pools = metrics.kv_cache_counters()
     timeline.clear()
-    server = engine()
+    # servers of its own, this one and the plain one below: every span on
+    # the timeline is counted, and the last chunk's is written by the time
+    # the scheduler's thread has been joined (``shutdown``)
+    server = engine(fresh=True)
     cfg = server.cfg
-    _generate(server, [{"prompt": list(range(1, 1 + n)),
-                        "max_new_tokens": 9} for n in (5, 12, 20)])
+    family.generate(server, [{"prompt": list(range(1, 1 + n)),
+                              "max_new_tokens": 9} for n in (5, 12, 20)])
     stats = server.kv_stats()
     server.shutdown()
     # K and V of 2 full layers x 64 positions and 6 rings x 8, 4 x 16 wide
@@ -438,9 +364,9 @@ def test_spans_counters_and_pools_for_a_windowed_model_and_only_for_one(
     snapshot = pools["pool_bytes"].snapshot()
     assert snapshot[("llm.kv_full", "float32")] == 4 * full
     assert snapshot[("llm.kv_window", "float32")] == 4 * ring
-    spans = [e for e in timeline.export_timeline() if e.get("ph") == "X"]
-    groups = [e["args"] for e in spans if e["name"] == "serve.prefill_group"]
-    chunks = [e["args"] for e in spans if e["name"] == "serve.chunk"]
+    spans = timeline.export_timeline()
+    groups = family.span_args(spans, "serve.prefill_group")
+    chunks = family.span_args(spans, "serve.chunk")
     assert groups and chunks
     for g in groups:
         b = g["bucket"]
@@ -456,13 +382,14 @@ def test_spans_counters_and_pools_for_a_windowed_model_and_only_for_one(
                < c["kv_full_positions_attended"] for c in chunks)
 
     timeline.clear()
-    plain = engine(preset="debug")
-    _generate(plain, [{"prompt": [1, 2, 3], "max_new_tokens": 5}])
+    plain = engine(model_preset="debug", fresh=True)
+    family.generate(plain, [{"prompt": [1, 2, 3], "max_new_tokens": 5}])
     assert "kv_pools" not in plain.kv_stats()
-    spans = [e for e in timeline.export_timeline() if e.get("ph") == "X"]
-    for e in spans:
-        if e["name"] in ("serve.chunk", "serve.prefill_group"):
-            assert not [k for k in e["args"] if "window" in k
+    plain.shutdown()
+    spans = timeline.export_timeline()
+    for name in ("serve.chunk", "serve.prefill_group"):
+        for args in family.span_args(spans, name):
+            assert not [k for k in args if "window" in k
                         or k.startswith("kv_full")]
 
 

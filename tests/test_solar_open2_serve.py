@@ -6,15 +6,12 @@ one period ``A K K K`` -- a gated NoPE attention layer and three KDA layers
 with a selection bias.
 """
 
-import asyncio
-import dataclasses
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.lib import kda_flops
 from benchmarks.references import solar_open2_decoder as reference
 from benchmarks.tools import kda_check
@@ -23,7 +20,6 @@ from ray_tpu.models.llama import LlamaConfig
 
 VOCAB, SLOTS, MAX_LEN = 256, 4, 64
 TOL = 1e-3          # float32 both sides: the order of sums alone
-MARGIN = 0.25       # kinds/serve_llm.py's LOGIT_MARGIN
 
 
 def _cfg(**kw):
@@ -69,13 +65,8 @@ def _published(cfg):
 @pytest.fixture(scope="module")
 def model():
     cfg = _cfg()
-    return (cfg, llama.init_params(jax.random.key(7), cfg, jnp.float32),
+    return (cfg, family.init_params(jax.random.key(7), cfg, jnp.float32),
             _published(cfg))
-
-
-@functools.lru_cache(maxsize=None)
-def _programs(cfg):
-    return llama_serve.build_prefill(cfg), llama_serve.build_decode_k(cfg)
 
 
 def _gap(params, prompt, emitted, published):
@@ -155,26 +146,18 @@ def test_prefill_then_decode_through_the_cache_is_the_reference(model):
     assert state.shape == (3, 4, 4, 16, 16) and conv.shape == (3, 3, 4, 192)
     assert float(jnp.abs(state[:, 3]).max()) == 0.0      # the padding row
 
-    prefill, decode = _programs(cfg)
     cache = llama_serve.init_cache(cfg, SLOTS, MAX_LEN)
-    cache, first, load = prefill(
-        params, cache, jnp.asarray(toks), lengths,
-        jnp.asarray(list(slots) + [-1], jnp.int32))
+    cache, first, load = family.prefill(cfg, params, cache, prompts, slots)
     # held + elsewhere = the real positions' picks, in every layer
     assert int(np.asarray(load[0]).sum() + np.asarray(load[2])) \
         == 44 * cfg.moe_top_k * cfg.n_layers
-    at = jnp.asarray(slots)
-    tok = jnp.zeros(SLOTS, jnp.int32).at[at].set(first[:3])
-    lens = jnp.zeros(SLOTS, jnp.int32).at[at].set(lengths[:3])
+    tok, lens = family.seat(first, (1, 13, 30), slots)
     emitted = {s: [int(t)] for s, t in zip(slots, first)}
-    zeros, no = jnp.zeros(SLOTS, jnp.int32), jnp.zeros(SLOTS, bool)
     for who in (slots, (2, 3), slots, slots):
-        active = jnp.zeros(SLOTS, bool).at[jnp.asarray(who)].set(True)
-        cache, out, tok, lens, _load = decode(
-            params, cache, tok, lens, zeros, zeros, no, active, k=4,
-            s_active=MAX_LEN)
+        cache, out, tok, lens, _load = family.decode(cfg, params, cache, tok,
+                                                     lens, who)
         for s in who:
-            emitted[s] += [int(t) for t in np.asarray(out)[:, s]]
+            emitted[s] += [int(t) for t in out[:, s]]
     assert [len(emitted[s]) for s in slots] == [17, 13, 17]
     for prompt, s in zip(prompts, slots):
         assert _gap(params, prompt, emitted[s], published) <= TOL
@@ -202,37 +185,29 @@ def test_a_broken_variant_fails_the_reference(model, variant):
 
 
 # ------------------------------------------------------------- the engine
-def test_llm_server_serves_the_model_and_counts_the_state_it_moves(
-        model, traced, monkeypatch):
-    from ray_tpu.serve import llm
+_presets = family.presets({"solar_open2_toy": _cfg})
+engine = family.engines("solar_open2_toy", max_slots=2, max_len=MAX_LEN,
+                        prefill_groups=(1, 2))
 
+
+def test_llm_server_serves_the_model_and_counts_the_state_it_moves(
+        model, traced, monkeypatch, engine):
     cfg, params, published = model
-    name = "solar_open2_toy"
-    setattr(LlamaConfig, name, classmethod(
-        lambda cls, **kw: dataclasses.replace(cfg, **kw)))
-    server = llm.LLMServer(
-        model_preset=name, params=params, max_slots=2, max_len=MAX_LEN,
-        prefill_buckets=(16, 32), decode_chunk=4, prefill_groups=(1, 2),
-        warmup=False)
+    # two slots and groups of one and two rows: of three requests the third
+    # is served in a REUSED slot.  A server of its own: every chunk on the
+    # timeline is counted, and it is shut down before they are.
+    server = engine(params=params, fresh=True)
     rng = np.random.default_rng(5)
     prompts = [rng.integers(1, VOCAB, n).tolist() for n in (9, 20, 31)]
-
-    async def run():
-        return await asyncio.gather(*[
-            server.generate({"prompt": p, "max_new_tokens": 10})
-            for p in prompts])
-
-    try:
-        replies = asyncio.run(run())
-        pools = server.kv_stats()
-    finally:
-        server.shutdown()
-    # three requests on two slots: the third is served in a REUSED slot
+    replies = family.generate(
+        server, [{"prompt": p, "max_new_tokens": 10} for p in prompts])
+    pools = server.kv_stats()
+    server.shutdown()
     for prompt, reply in zip(prompts, replies):
         assert len(reply["tokens"]) == 10
         assert _gap(params, prompt, reply["tokens"], published) <= TOL
-    events = [e for e in traced.export_timeline() if e.get("ph") == "X"]
-    chunks = [e["args"] for e in events if e["name"] == "serve.chunk"]
+    events = traced.export_timeline()
+    chunks = family.span_args(events, "serve.chunk")
     assert chunks
     state = 3 * 4 * 16 * 16 * 4
     for c in chunks:
@@ -244,16 +219,15 @@ def test_llm_server_serves_the_model_and_counts_the_state_it_moves(
     # a toy state (d = 16) keeps XLA's chunked rule: no position went
     # through ``ops/kda_chunk.py``; at a state the kernel takes, a launch's
     # padded positions (whole blocks of chunks) x the three KDA layers
-    groups = [e["args"] for e in events if e["name"] == "serve.prefill_group"]
+    groups = family.span_args(events, "serve.prefill_group")
     assert groups and all(g["kda_chunk_positions"] == 0 for g in groups)
     from ray_tpu.ops import kda_chunk
 
     monkeypatch.setattr(kda_chunk, "engages", lambda d, chunk: True)
     server._record_prefill_group(0.0, 1.0, 20, np.array([9, 20]), 2)
-    newest = [e["args"] for e in traced.export_timeline()
-              if e.get("name") == "serve.prefill_group"][-1]
+    newest = family.span_args(traced.export_timeline(),
+                              "serve.prefill_group")[-1]
     assert newest["kda_chunk_positions"] == 2 * 24 * 3
     assert newest["token_positions"] == 2 * 20
-    with pytest.raises(ValueError, match="linear-attention"):
-        llm.LLMServer(model_preset=name, params=params, max_slots=2,
-                      max_len=MAX_LEN, paged=True, warmup=False)
+    family.refuses_plane("solar_open2_toy", "paged", dict(paged=True),
+                         "linear-attention")

@@ -20,10 +20,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family
 from benchmarks.lib import program
 from benchmarks.references import afmoe_decoder as reference
 from benchmarks.tools import train_check
-from ray_tpu.models import llama, llama_serve, moe
+from ray_tpu.models import llama, moe
 from ray_tpu.models.llama import LlamaConfig
 
 VOCAB, SEQ, WINDOW = 256, 32, 8       # a sequence of four windows
@@ -591,11 +592,6 @@ def test_a_pipeline_stage_still_runs_one_stack_alone():
 
 
 # ------------------------------------------------------------- and served
-@functools.lru_cache(maxsize=None)
-def _programs(cfg):
-    return llama_serve.build_prefill(cfg), llama_serve.build_decode_k(cfg)
-
-
 def test_prefill_then_decode_through_rings_and_pool_is_the_reference(model):
     """``layer_block`` is one function for training and serving: the two new
     norms land in the prefill and in the decode step too.  Every position's
@@ -613,23 +609,9 @@ def test_prefill_then_decode_through_rings_and_pool_is_the_reference(model):
     assert float(jnp.std(theirs)) > 0.3
     np.testing.assert_allclose(mine, theirs, atol=1e-3)
 
-    slots, max_len, n = 2, 64, 20
-    prefill, decode = _programs(cfg)
-    cache = llama_serve.init_cache(cfg, slots, max_len)
-    toks = np.zeros((1, 32), np.int32)
-    toks[0, :n] = tokens[0, :n]
-    cache, first, _ = prefill(params, cache, jnp.asarray(toks),
-                              jnp.asarray([n], jnp.int32),
-                              jnp.asarray([1], jnp.int32))
-    tok = jnp.zeros(slots, jnp.int32).at[1].set(first[0])
-    lens = jnp.zeros(slots, jnp.int32).at[1].set(n)
-    active = jnp.zeros(slots, bool).at[1].set(True)
-    zeros, no = jnp.zeros(slots, jnp.int32), jnp.zeros(slots, bool)
-    emitted = [int(first[0])]
-    for _ in range(3):
-        cache, out, tok, lens, _load = decode(
-            params, cache, tok, lens, zeros, zeros, no, active, k=4,
-            s_active=max_len)
-        emitted += [int(t) for t in np.asarray(out)[:, 1]]
+    n = 20
+    assert cfg.max_seq_len == 64
+    emitted, _cache = family.serve_one(cfg, params, tokens[0, :n], 13,
+                                       slot=1, slots=2, bucket=32)
     gap = reference.teacher_forced_gap(params, tokens[0, :n], emitted, TOY)
     assert len(emitted) == 13 and float(gap.max()) <= 1e-3
