@@ -609,16 +609,25 @@ class LlamaConfig:
                      if before[i] == "attention"), None)
 
     @property
+    def kv_heads_a_row(self) -> int:
+        """Kv heads a stored row holds: 2 under differential attention
+        (a pair's keys side by side, throughout) and for an even number of
+        heads of HALF a 128-lane row (64), which a serving pool keeps two
+        a row (``llama_serve.init_cache``: the same bytes in the same
+        order as by position, in rows the decode kernel reads); 1 else."""
+        return 2 if self.diff_attention or (
+            self.head_dim == 64 and self.n_kv_heads % 2 == 0) else 1
+
+    @property
     def kv_row_heads(self) -> int:
         """K (or V) rows a position keeps a layer, as stored and attended:
-        the kv heads, or under differential attention the PAIRS."""
-        return self.n_kv_heads // 2 if self.diff_attention \
-            else self.n_kv_heads
+        the kv heads, or the PAIRS of them (``kv_heads_a_row``)."""
+        return self.n_kv_heads // self.kv_heads_a_row
 
     @property
     def kv_row_dim(self) -> int:
         """Their width: the head, or a pair's two heads side by side."""
-        return 2 * self.head_dim if self.diff_attention else self.head_dim
+        return self.kv_heads_a_row * self.head_dim
 
     @property
     def expert_width(self) -> int:

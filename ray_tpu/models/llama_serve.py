@@ -6,7 +6,8 @@ plane's from a ``BlockPool``: config, block size, block format): no
 engine, thread or weights are needed to lower one.  By the cache kept:
 
 - a per-slot cache (``init_cache``: K and V ``(La, B, S, Hkv, D)`` over
-  the attention layers and, for a model with Mamba-2 layers, each slot's
+  the attention layers -- heads of 64 two a 128-lane row, as rows -- and,
+  for a model with Mamba-2 layers, each slot's
   recurrent and conv states beside them, for one with short-convolution
   layers its conv states alone; for a model with window layers
   a pool of every position for its full layers and a ring of the last
@@ -40,10 +41,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models import indexer, llama
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.ops.decode_attention import decode_attention
+from ray_tpu.ops.decode_attention import decode_attention, path_taken
 from ray_tpu.ops.mla_decode_attention import mla_decode_attention
 
 
@@ -74,7 +76,9 @@ def _expert_load(expert_rows, held: bool = False):
 # it back to the programs; it never names a leaf.
 def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     """The per-slot cache of a config: K and V ``(La, B, S, Hkv, D)``
-    over its attention layers and, for its Mamba layers, each slot's
+    over its attention layers (heads of 64, an even number of them: two a
+    128-lane row, as rows ``(La, B, S * Hkv / 2, 128)``, the same bytes)
+    and, for its Mamba layers, each slot's
     recurrent state ``ssm (Lm, B, N, nh x hd)`` (stored as
     ``cfg.ssm_state_dtype``) and conv window ``conv (Lm, K - 1, B,
     conv_dim)``; for its short-convolution layers ``conv (Lc, taps - 1,
@@ -100,7 +104,14 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
             (cfg.n_layers, slots, max_len, cfg.latent_row), cfg.dtype)}
     if cfg.index_topk:
         return _init_indexed_cache(cfg, slots, max_len)
-    cache = llama.init_kv_cache(cfg, slots, max_len)
+    if cfg.kv_heads_a_row > 1:
+        # Heads of 64: two a 128-lane row, as rows -- the bytes of ``(La,
+        # B, S, Hkv, 64)`` in their order, in the one shape of them that
+        # the decode kernel reads where they lie (``_attend_rows``).
+        cache = {name: _row_pool(cfg, cfg.layers_of("attention"), slots,
+                                 max_len) for name in ("k", "v")}
+    else:
+        cache = llama.init_kv_cache(cfg, slots, max_len)
     if cfg.layers_of("mamba"):
         from ray_tpu.models import mamba2
 
@@ -180,6 +191,23 @@ def cache_pools(cfg: LlamaConfig, slots: int, max_len: int):
     return pools
 
 
+def kv_rows(cfg: LlamaConfig, cache=None):
+    """What ``serve.engine_build`` says of an engine's K/V
+    (docs/observability.md): ``kv_row_heads`` rows of ``kv_row_dim`` lanes
+    a position and layer as ``cache`` holds them (``init_cache``'s tree;
+    None: by position and head, as the paged planes gather their blocks),
+    and ``decode_attention``, what attends them on this backend: the Mosaic
+    ``"kernel"`` or ``"xla"``.  {} for a latent cache, which keeps neither
+    K nor V."""
+    if cfg.kv_lora_rank:
+        return {}
+    as_rows = cache is not None and cache["k"].ndim == 4
+    hkv, d = (cfg.kv_row_heads, cfg.kv_row_dim) if as_rows \
+        else (cfg.n_kv_heads, cfg.head_dim)
+    return {"kv_row_heads": hkv, "kv_row_dim": d,
+            "decode_attention": path_taken(hkv, d, as_rows)}
+
+
 def state_bytes_per_slot(cfg: LlamaConfig):
     """``{"ssm": ..., "conv": ...}`` bytes one slot's states hold over
     all the layers that keep one (Mamba layers: both; short-convolution
@@ -213,11 +241,12 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active,
     each slot's new K/V at its current position, cache attention
     over the row's keys among the first ``s_active`` positions
     (``ops/decode_attention.py``: one Mosaic call a layer whose operand
-    is the whole cache), greedy argmax fed back in-graph.  The carry
-    holds the WHOLE stacked (L, B, S, Hkv, D) K and V through the token
-    loop and the layer loop, so XLA's while loops alias them in place: a
-    step reads each live row's keys once, as far as the row is long, and
-    writes B rows per layer; nothing of the cache's, a layer's or a
+    is the whole cache; over rows of two heads of 64 the query goes in as
+    wide as a row, ``_attend_rows``), greedy argmax fed back in-graph.
+    The carry holds the WHOLE stacked (L, B, S, Hkv, D) K and V through
+    the token loop and the layer loop, so XLA's while loops alias them in
+    place: a step reads each live row's keys once, as far as the row is
+    long, and writes B rows per layer; nothing of the cache's, a layer's or a
     prefix's shape is made (120 rows scatter in ~15 us on a v5e;
     PERF.md section 5; ``tests/test_decode_inplace.py`` holds it at the
     real widths).  ``s_active`` bounds the keys of a row that has run
@@ -345,7 +374,7 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active,
                     # discarded below.
                     with jax.named_scope("attention" if kind == "window"
                                          else full_scope):
-                        attn = decode_attention(
+                        attn = _attend_rows(
                             q[:, 0], pk, pv, l, lens, active,
                             s_active=s_active, scale=scale,
                             hkv=hkv)[:, None]
@@ -379,7 +408,7 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active,
                         jnp.where(active, jnp.minimum(lens + 1, seen), 0),
                         part.index_topk)
                     with jax.named_scope("sparse_attention"):
-                        attn = decode_attention(
+                        attn = _attend_rows(
                             q[:, 0], ck, cv, l, lens, active,
                             s_active=s_active, scale=scale, hkv=hkv,
                             keep=keep)[:, None]
@@ -459,13 +488,39 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active,
     return step
 
 
+def _attend_rows(q, pk, pv, l, lens, active, *, hkv, **how):
+    """``decode_attention`` of q (B, Hq, D) over layer ``l`` of a pool.
+    Over rows of two heads of 64 (``LlamaConfig.kv_heads_a_row``; ``hkv``
+    rows a position) the query goes in as wide as a row, as
+    ``llama._paired_rows`` lays a differential pair's: a head of a row's
+    first kv head carries its values on the left half and zeros on the
+    right, of its second the other way round, so ``q . [k_2g | k_2g+1]``
+    is the head's own score exactly, the ``Hq / hkv`` query heads that
+    share a row are one grouped-query group of the kernel's, and of ``P @
+    [v_2g | v_2g+1]`` a head keeps its own half.  Read off the shapes: a
+    pool by position, or rows as wide as the query (whole heads, a
+    differential pair's already wide queries), is attended as it is."""
+    d = q.shape[-1]
+    if pk.ndim == 5 or pk.shape[3] == d:
+        return decode_attention(q, pk, pv, l, lens, active, hkv=hkv, **how)
+    hq = q.shape[1]
+    second = ((np.arange(hq) // (hq // (2 * hkv))) % 2 == 1)[:, None]
+    zeros = jnp.zeros_like(q)
+    wide = jnp.concatenate([jnp.where(second, zeros, q),
+                            jnp.where(second, q, zeros)], axis=-1)
+    out = decode_attention(wide, pk, pv, l, lens, active, hkv=hkv, **how)
+    return jnp.where(second, out[..., d:], out[..., :d])
+
+
 @jax.named_scope("kv_write")
 def _write(pool, l, slots, pos, new):
     """``new`` (B, Hkv, D) as slot ``slots[b]``'s position ``pos[b]`` of
     layer ``l`` of a pool ``(layers, B, positions, Hkv, D)`` or of its
-    rows ``(layers, B, positions * Hkv, D)``; a position out of range
-    writes nothing."""
+    rows ``(layers, B, positions * Hkv, D)`` (two heads of 64 a row: the
+    same values as ``(B, Hkv / 2, 128)``); a position out of range writes
+    nothing."""
     if pool.ndim == 4:
+        new = new.reshape(new.shape[0], -1, pool.shape[3])
         hkv = new.shape[1]
         pos = pos[:, None] * hkv + jnp.arange(hkv, dtype=jnp.int32)[None, :]
         slots = slots[:, None]

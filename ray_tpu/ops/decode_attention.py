@@ -47,11 +47,15 @@ kernel alone at every cell's shapes over several blocks).
 A cache of kv heads that do not fill a sublane tile (4 of them) is handed
 over already AS rows, ``(L, B, S * Hkv, D)``: stored with a dimension of
 4 before the lanes it would be padded to a tile, occupy a multiple of its
-bytes and not be contiguous.  The kernel is the same.  A ring of the last
-``S`` positions (a window layer's cache: position ``p`` in row ``p mod
-S``) is read through it unchanged: its rows carry their RoPE from when
-they were written and softmax does not ask for their order, so a row
-attends its first ``min(length, S)`` ring rows.
+bytes and not be contiguous.  The kernel is the same.  So it is for heads
+of HALF a lane row (64), which a serving pool keeps two a 128-lane row
+(``llama_serve.init_cache``; GQA 32/8 x 64 is then 32 query heads over 4
+rows of 128): the queries come as wide as a row, zeros on the other head's
+side (``llama_serve._attend_rows``), and this module sees rows of 128.  A
+ring of the last ``S`` positions (a window layer's cache: position ``p``
+in row ``p mod S``) is read through it unchanged: its rows carry their
+RoPE from when they were written and softmax does not ask for their order,
+so a row attends its first ``min(length, S)`` ring rows.
 
 Interpret mode runs the same kernel on the CPU for the test suite; what
 decides is ``flash_attention._use_interpret``, looked up at call time (a
@@ -102,6 +106,15 @@ def _tiles(hkv: int, d: int, as_rows: bool = False) -> bool:
     second-minor dimension of 6 to 8, and the rows are then not
     contiguous) unless the cache is stored as rows already."""
     return d % LANES == 0 and (as_rows or hkv % 8 == 0)
+
+
+def path_taken(hkv: int, d: int, as_rows: bool = False) -> str:
+    """What attends a cache of ``hkv`` rows of ``d`` lanes a position on
+    this backend: the Mosaic ``"kernel"`` (interpreted off the chip) or
+    ``"xla"``.  Fixed with a config's shapes and the layout of its pool:
+    ``serve.engine_build`` says it (docs/observability.md)."""
+    return "kernel" if _flash._use_interpret() or _tiles(hkv, d, as_rows) \
+        else "xla"
 
 
 def _kernel(layer_ref, n_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
@@ -229,9 +242,10 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     read as far as the row is long, and masked).  -> (B, Hq, D) in the
     cache's dtype.
 
-    On a TPU a cache Mosaic cannot read as rows (kv heads that do not
-    fill a sublane tile, a head that is not whole lanes) is attended by
-    XLA, as ``llama._cache_attend`` over the layer's prefix."""
+    On a TPU a cache Mosaic cannot read as rows (kv heads by position
+    that do not fill a sublane tile, a head that is not whole lanes and
+    was not paired into rows that are) is attended by XLA, as
+    ``llama._cache_attend`` over the layer's prefix."""
     B, hq, d = q.shape
     as_rows = ck.ndim == 4
     if as_rows:
@@ -242,8 +256,7 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
         raise ValueError(f"Hq={hq} not a multiple of Hkv={hkv}")
     s_active = min(s_active, S)
     n = jnp.where(active, jnp.minimum(lens + 1, s_active), 0)
-    interpret = _flash._use_interpret()
-    if not interpret and not _tiles(hkv, d, as_rows):
+    if path_taken(hkv, d, as_rows) == "xla":
         if as_rows:
             ck, cv = (c.reshape(L, B, S, hkv, d) for c in (ck, cv))
         return _xla_decode_attention(q, ck, cv, layer, n, s_active, scale,
@@ -294,7 +307,7 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, hq_pad, d), cv.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        interpret=_flash._use_interpret(),
     )
     q = jnp.pad(q.astype(ck.dtype), ((0, 0), (0, hq_pad - hq), (0, 0)))
     with jax.named_scope("decode_attention"):

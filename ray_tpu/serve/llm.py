@@ -539,6 +539,9 @@ class LLMServer:
         self._chunk_ema: Optional[float] = None
         self._prefill_ema: Optional[float] = None
 
+        # how this engine's K/V lie and what attends them: fixed here
+        build.args = llama_serve.kv_rows(
+            self.cfg, None if self.paged else self.cache)
         build.__exit__()
         if warmup:
             with _tracing.span("serve.warmup"):

@@ -25,11 +25,20 @@ _SHAPES = {
     "rows_32_4_x128_with_keep": (2, 8, 512, 32, 4, 128, 1, 384),
     "ring_512_of_longer_rows": (2, 8, 512, 40, 10, 128, 1, 16384),
     "paired_rows_with_keep": (1, 8, 200, 40, 10, 128, 0, 160),
+    # heads of 64 kept two a 128-lane row (``_HEAD_64``: cells 9's and 6's
+    # GQA 32/8, one row a position, MHA; the pool handed over as
+    # ``init_cache`` stores it, the queries widened by ``_attend_rows``)
+    "head_64_gqa_32_8": (2, 8, 512, 32, 8, 64, 1, 512),
+    "head_64_gqa_8_2": (1, 8, 2048, 8, 2, 64, 0, 2048),
+    "head_64_mha_4_4": (2, 8, 1024, 4, 4, 64, 1, 768),
+    "head_64_gqa_32_8_with_keep": (1, 8, 512, 32, 8, 64, 0, 384),
 }
 _AS_ROWS = {"paired_rows_40_10_x128", "rows_28_4_x128",
             "rows_32_4_x128_with_keep", "ring_512_of_longer_rows",
             "paired_rows_with_keep"}
-_KEPT = {"rows_32_4_x128_with_keep", "paired_rows_with_keep"}
+_HEAD_64 = {name for name in _SHAPES if name.startswith("head_64")}
+_KEPT = {"rows_32_4_x128_with_keep", "paired_rows_with_keep",
+         "head_64_gqa_32_8_with_keep"}
 _TOL = 2e-2       # bf16: eight bits of mantissa on values of order one
 
 
@@ -83,12 +92,15 @@ def test_kernel_agrees_with_cache_attend(name, rows):
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.models import llama_serve
     from ray_tpu.ops import decode_attention as da
 
     shape = _SHAPES[name]
     L, B, S, hq, hkv, d, layer, s_active = shape
     q, ck, cv = _inputs(shape, seed=len(name))
-    bk = da.block_k(S, hkv, d, ck.dtype.itemsize)
+    # rows a position and their width, as the pool is handed over
+    rows, width = (hkv // 2, 2 * d) if name in _HEAD_64 else (hkv, d)
+    bk = da.block_k(S, rows, width, ck.dtype.itemsize)
     lens = jnp.asarray(_lengths(min(bk, s_active - 2), s_active))
     active = jnp.asarray(
         [True, False, True, True, False, True, True, False]
@@ -102,10 +114,11 @@ def test_kernel_agrees_with_cache_attend(name, rows):
         ck, cv = (jnp.where(past[None, :, :, None, None], jnp.nan, c)
                   for c in (ck, cv))
     as_rows = {}
-    if name in _AS_ROWS:
-        ck, cv = (c.reshape(L, B, S * hkv, d) for c in (ck, cv))
-        as_rows = dict(hkv=hkv)
-    got = jax.jit(da.decode_attention,
+    if name in _AS_ROWS | _HEAD_64:
+        ck, cv = (c.reshape(L, B, S * rows, width) for c in (ck, cv))
+        as_rows = dict(hkv=rows)
+    got = jax.jit(llama_serve._attend_rows if name in _HEAD_64
+                  else da.decode_attention,
                   static_argnames=("s_active", "scale", "hkv"))(
         q, ck, cv, jnp.int32(layer), lens, active, s_active=s_active,
         scale=d ** -0.5, keep=None if keep is None else jnp.asarray(keep),
@@ -151,6 +164,63 @@ def test_xla_path_for_a_cache_mosaic_cannot_tile_agrees_too():
     want = _reference(q, ck, cv, layer, lens, active, s_active)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim,stored,path", [
+    (10, 5, 64, (5, 64), "xla"),      # an odd number of heads of 64
+    (32, 8, 64, (4, 128), "kernel"),  # cells 9 and 6: two heads a row
+    (6, 6, 128, (6, 128), "xla"),     # the default preset: 6 by position
+    (4, 2, 96, (2, 96), "xla"),       # neither 64 nor whole lanes
+])
+def test_which_heads_are_paired_and_which_still_go_to_xla(
+        monkeypatch, heads, kv_heads, head_dim, stored, path):
+    """Read off the config's shapes alone: an even number of heads of 64
+    lies two a row and goes through the kernel on a TPU; what Mosaic still
+    cannot read keeps its layout by position and XLA's attention -- and
+    still decodes, to the tokens the interpreted kernel gives."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama, llama_serve
+    from ray_tpu.models.llama import LlamaConfig
+
+    flash = importlib.import_module("ray_tpu.ops.flash_attention")
+    cfg = LlamaConfig.debug(n_layers=2, n_heads=heads, n_kv_heads=kv_heads,
+                            head_dim=head_dim, max_seq_len=64,
+                            dtype=jnp.float32)
+    params = llama.init_params(jax.random.key(3), cfg, jnp.float32)
+    prompt = np.arange(1, 10, dtype=np.int32)[None]
+
+    def served(on_a_tpu):
+        monkeypatch.setattr(flash, "_use_interpret", lambda: not on_a_tpu)
+        cache = llama_serve.init_cache(cfg, 2, 64)
+        said = llama_serve.kv_rows(cfg, cache)
+        if said["decode_attention"] == "kernel" and on_a_tpu:
+            return said, None           # Mosaic: not on this backend
+        cache, first, _ = llama_serve.build_prefill(cfg)(
+            params, cache, jnp.asarray(prompt), jnp.asarray([9]),
+            jnp.asarray([1]))
+        tok = jnp.zeros(2, jnp.int32).at[1].set(first[0])
+        lens = jnp.zeros(2, jnp.int32).at[1].set(9)
+        zeros, no = jnp.zeros(2, jnp.int32), jnp.zeros(2, bool)
+        cache, out, *_ = llama_serve.build_decode_k(cfg)(
+            params, cache, tok, lens, zeros, zeros, no,
+            jnp.asarray([False, True]), k=6, s_active=64)
+        # the second layer's rows of the decoded positions were computed
+        # from the first layer's attention
+        kv = np.asarray(cache["k"]).reshape(2, 2, 64, kv_heads, head_dim)
+        return said, (np.asarray(out)[:, 1], kv[1, 1, 9:15])
+
+    said, on_chip = served(on_a_tpu=True)
+    assert said == {"kv_row_heads": stored[0], "kv_row_dim": stored[1],
+                    "decode_attention": path}
+    assert (cfg.kv_row_heads, cfg.kv_row_dim) == stored
+    interpreted, (tokens, rows) = served(on_a_tpu=False)
+    assert interpreted == {**said, "decode_attention": "kernel"}
+    assert np.abs(rows).min(axis=(1, 2)).all()      # all six were written
+    if path == "xla":
+        np.testing.assert_array_equal(on_chip[0], tokens)
+        np.testing.assert_allclose(on_chip[1], rows, atol=1e-5, rtol=1e-5)
 
 
 def test_block_follows_from_the_bytes_of_a_position():
@@ -228,6 +298,9 @@ def test_the_benchmarks_cells_take_the_block_their_shapes_say():
         "smallthinker-21b-a3b.serve-long-prompt": (28, 4, 128, 256),
         "keye-vl-2.0-30b-a3b.serve-long-prompt": (32, 4, 128, 256),
         "phi-4-mini-flash-reasoning.serve-long-prompt": (40, 10, 128, 128),
+        # GQA 32/8 x 64, two heads a row: cells 7's and 10's geometry
+        "granite-4.0-h-micro.serve-batch-decode": (32, 4, 128, 256),
+        "lfm2-8b-a1b.serve-batch-decode-wide": (32, 4, 128, 256),
     }.items()
 
 

@@ -389,7 +389,7 @@ def test_decode_k_of_a_hybrid_updates_the_recurrent_state_in_place(
             if dims == state:
                 assert opcode == "custom-call", (inst, opcode, root)
                 kernels.append(inst)
-            elif dims == (4, slots, max_len, 8, 64):
+            elif dims == (4, slots, max_len * 4, 128):   # two heads a row
                 assert (opcode, root) == ("fusion", "scatter"), inst
                 scatters += 1
             else:

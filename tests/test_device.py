@@ -777,6 +777,13 @@ class TestEngineStartSpans:
                 parent["args"]["span_id"]
             assert _inside(child, parent)
         assert build["ts"] + build["dur"] <= warmup["ts"] + 1e3
+        # how the engine's K/V lie and what attends them, fixed at the
+        # build (the toy: 2 heads of 16 by position; off the chip the
+        # kernel is interpreted)
+        assert {key: build["args"].get(key) for key in (
+            "kv_row_heads", "kv_row_dim", "decode_attention")} == {
+            "kv_row_heads": 2, "kv_row_dim": 16,
+            "decode_attention": "kernel"}
         by_id = {e["args"]["span_id"]: e for e in spans
                  if e["name"] in START_SPANS}
         phases = [e for e in spans if e["name"] in XLA_SPANS]

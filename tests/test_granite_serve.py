@@ -66,7 +66,10 @@ def _published(cfg):
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = _cfg()
+    return _model(_cfg())
+
+
+def _model(cfg):
     params = family.init_params(jax.random.key(0), cfg)
     # norms, the conv bias and D away from their initial constants, so
     # that one left out or misplaced shows
@@ -256,6 +259,29 @@ def test_prefill_then_decode_against_the_full_forward_pass(model):
         gap = _worst_gap(cfg, params, prompts, slots, emitted)
         assert gap <= TOL, gap
         assert len({t for e in emitted.values() for t in e}) > 12
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(8, 4), (4, 2)])
+def test_heads_of_64_lie_two_a_row_and_read_as_the_full_forward_pass(
+        heads, kv_heads):
+    """Granite 4.0-H's own heads are 64 wide: the pool keeps two a 128-lane
+    row, as rows, and prefill's insert, the step's write and the widened
+    query give what the reference's full forward pass gives -- GQA with two
+    rows a position, and with one."""
+    cfg, params = _model(_cfg(n_heads=heads, n_kv_heads=kv_heads,
+                              head_dim=64, attention_multiplier=1.0 / 64))
+    cache = jax.eval_shape(lambda: llama_serve.init_cache(cfg, SLOTS,
+                                                          MAX_LEN))
+    assert cache["k"].shape == cache["v"].shape == (
+        2, SLOTS, MAX_LEN * kv_heads // 2, 128)
+    assert llama_serve.kv_rows(cfg, cache) == {
+        "kv_row_heads": kv_heads // 2, "kv_row_dim": 128,
+        "decode_attention": "kernel"}
+    lengths, slots = (1, 7, 20), (2, 0, 3)
+    prompts = _prompts(sum(lengths), lengths)
+    emitted, _ = _serve(cfg, params, prompts, slots, steps=12)
+    gap = _worst_gap(cfg, params, prompts, slots, emitted)
+    assert gap <= TOL, gap
 
 
 def test_reference_deviation_is_what_the_gap_divides_by(model):

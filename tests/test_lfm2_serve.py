@@ -42,6 +42,9 @@ PUBLISHED = ("conv", "conv", "full_attention", "conv", "conv", "conv",
              "conv", "full_attention", "conv", "conv", "full_attention",
              "conv", "conv")
 PATTERNS = {"cut": PUBLISHED[:14], "published": PUBLISHED}
+# the cut at heads of 64 (MHA 2/2 on a stream of 128: the reference's head
+# is hidden_size / heads), whose pool keeps two heads a 128-lane row
+HEAD_64 = dict(hidden_size=128, n_heads=2, n_kv_heads=2, head_dim=64)
 
 
 def _cfg(kinds=PUBLISHED[:14], **kw):
@@ -96,10 +99,10 @@ def _init(cfg, seed=7):
     return jax.jit(init)(jax.random.key(seed), jax.random.key(seed + 1))
 
 
-@pytest.fixture(scope="module", params=sorted(PATTERNS))
+@pytest.fixture(scope="module", params=sorted(PATTERNS) + ["cut_head_64"])
 def model(request):
-    kinds = PATTERNS[request.param]
-    cfg = _cfg(kinds)
+    kinds = PATTERNS.get(request.param, PATTERNS["cut"])
+    cfg = _cfg(kinds, **(HEAD_64 if request.param == "cut_head_64" else {}))
     return cfg, _init(cfg), _published(cfg, kinds)
 
 
@@ -157,7 +160,8 @@ def test_prefill_logits_are_the_references(model):
         assert float(jnp.abs(got[0][g] - want[g, n - 1]).max()) <= TOL
     conv_layers = cfg.layers_of("conv")
     (state,) = got[4]
-    assert state.shape == (conv_layers, cfg.conv_taps - 1, 3, 64)
+    assert state.shape == (conv_layers, cfg.conv_taps - 1, 3,
+                           cfg.hidden_size)
     assert got[1].shape[0] == cfg.layers_of("attention")
 
 
@@ -172,6 +176,40 @@ def test_a_reused_slot_and_a_slot_that_sits_out(cut):
     emitted = lfm2_check.serve_one(cfg, params, before, prompt, 20, 32,
                                    MAX_LEN, k=4, slots=3)
     assert _gap(params, prompt, emitted, published) <= TOL
+
+
+def test_a_row_of_two_heads_of_64_round_trips_bit_for_bit():
+    """``init_cache`` -> a prefill group's insert -> the decode step's
+    write: a pool of heads of 64 is ``(La, B, S * Hkv / 2, 128)``, and what
+    the one insert and the one write leave in it is, read back by position
+    and head, what they leave in ``(La, B, S, Hkv, 64)`` -- the same bytes
+    in the same order."""
+    cfg = _cfg(n_heads=8, n_kv_heads=4, head_dim=64)
+    assert (cfg.kv_row_heads, cfg.kv_row_dim) == (2, 128)
+    rows = llama_serve.init_cache(cfg, SLOTS, MAX_LEN)
+    La = cfg.layers_of("attention")
+    assert rows["k"].shape == rows["v"].shape == (La, SLOTS, MAX_LEN * 2, 128)
+    assert llama_serve.cache_pools(cfg, SLOTS, MAX_LEN)["kv"] == (
+        2 * La * SLOTS * MAX_LEN * 4 * 64 * 4, "float32")
+    by_position = (La, SLOTS, MAX_LEN, 4, 64)
+    rng = np.random.default_rng(60)
+    group = jnp.asarray(rng.normal(size=(La, 3, 16, 4, 64)), jnp.float32)
+    slots = jnp.asarray([2, -1, 0], jnp.int32)       # a padding row
+    fresh = jnp.asarray(rng.normal(size=(SLOTS, 4, 64)), jnp.float32)
+    pos = jnp.asarray([16, 0, MAX_LEN, 5], jnp.int32)    # one out of range
+    pools = {}
+    for name, pool in (("rows", rows["k"]),
+                       ("by_position", jnp.zeros(by_position))):
+        pool = llama_serve._insert_rows(pool, group, slots)
+        pool = llama_serve._write(pool, 1, jnp.arange(SLOTS), pos, fresh)
+        pools[name] = np.asarray(pool).reshape(by_position)
+    np.testing.assert_array_equal(pools["rows"], pools["by_position"])
+    np.testing.assert_array_equal(pools["rows"][:, 2, :16], group[:, 0])
+    np.testing.assert_array_equal(pools["rows"][1, 0, 16], fresh[0])
+    np.testing.assert_array_equal(pools["rows"][1, 3, 5], fresh[3])
+    np.testing.assert_array_equal(pools["rows"][1, 1, 0], fresh[1])
+    assert not pools["rows"][0, 1].any()        # the padding row's slot
+    assert not pools["rows"][:, 2, 16:].any()   # a write past the pool
 
 
 def test_conv_prefill_state_is_the_last_real_inputs(cut):

@@ -35,8 +35,11 @@ CELLS = {
     "3": (120, 16, 8, 128, 512, 24, (192, 0.6, 32, 511), None),
     "4": (40, 16, 8, 128, 1280, 24, (600, 0.5, 64, 1279), None),
     "5": (120, 16, 16, 128, 512, 8, (192, 0.6, 32, 511), None),
+    # GQA 32/8 x 64 kept two heads a 128-lane row: what the kernel sees
+    "6": (80, 32, 4, 128, 512, 4, (192, 0.6, 32, 511), None),
     "7.full": (48, 28, 4, 128, 16384, 2, LONG, None),
     "7.ring": (48, 28, 4, 128, 4096, 6, LONG, None),
+    "9": (240, 32, 4, 128, 512, 3, (192, 0.6, 32, 511), None),
     "10": (16, 32, 4, 128, 16384, 6, LONG, 2048),
     "11.pool": (64, 40, 10, 128, 16384, 1, LONG, None),
     "11.ring": (64, 40, 10, 128, 512, 8, LONG, None),
