@@ -166,7 +166,9 @@ class LlamaConfig:
     # What the router reads: "ffn", the rows the experts multiply (the
     # stream after attention, normed), or "layer", the layer's INPUT,
     # before the attention norm and un-normed (SmallThinker).  And the
-    # experts' gate activation: "silu" or "relu".
+    # experts' activation: "silu" or "relu" on the gate of a gated expert
+    # (``act(x W_gate) * (x W_up)``), or "relu2": an expert of TWO matrices,
+    # ``relu(x W_up)^2 W_down``, no gate (Nemotron-H; the shared expert too).
     moe_router_input: str = "ffn"
     moe_activation: str = "silu"
     # How the router scores: "softmax" over all experts, or "sigmoid" an
@@ -200,8 +202,9 @@ class LlamaConfig:
     qk_head_norm: bool = False
     # ONE PERIOD of the layer stack, a kind per layer: "attention",
     # "mamba" (a Mamba-2 mixer, models/mamba2.py, in place of attention;
-    # every layer keeps its FFN) or "window" (attention over the last
-    # ``window_size`` keys, the query's own among them; served only).
+    # a layer keeps its FFN unless ``block_pattern`` says it has none) or
+    # "window" (attention over the last ``window_size`` keys, the query's
+    # own among them; served only).
     # n_layers is a whole number of periods and the layer scans run a
     # period an iteration.  () is a period of one attention layer: the
     # plain decoder.
@@ -217,6 +220,18 @@ class LlamaConfig:
     # kind it says.
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3
+    # A stack whose blocks hold ONE sub-layer each, ``x + f(norm(x))``, as
+    # a published ``hybrid_override_pattern`` spells them (Nemotron-H): "M"
+    # a Mamba-2 mixer, "*" attention, "E" the expert feed-forward part.  A
+    # mixer and the "E" right after it are one LAYER here (``attn_norm``
+    # the mixer's pre-norm, ``mlp_norm`` the feed-forward part's); a mixer
+    # that no "E" follows is a layer WITHOUT a feed-forward half.
+    # ``layer_types`` is derived from it and n_layers counts those layers
+    # ("MEMEMEM*EME": 11 blocks, 6 layers, the fourth without an FFN).
+    # ``parts`` walks each FFN-less layer as a stack of its own, whose
+    # config says ``no_ffn``; on anything but such a part it is refused.
+    block_pattern: str = ""
+    no_ffn: bool = False
     # Rotary position embedding on q and k (False: NoPE, Granite 4), and
     # the kinds of attending layer that go without it where the others
     # rotate (SmallThinker: the global layers are NoPE, the window
@@ -230,13 +245,16 @@ class LlamaConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
-    # The Mamba-2 layers' sizes (one group of ssm_state dimensions shared
-    # by all heads), the chunk their prefill scans by, and the type their
-    # recurrent state is STORED in by the serving cache (the recurrence's
-    # arithmetic is float32 either way).
+    # The Mamba-2 layers' sizes (ssm_groups groups of ssm_state dimensions:
+    # head j reads the B and C of group j // (ssm_heads / ssm_groups), and
+    # the gated norm runs over a group's ssm_heads * ssm_head_dim /
+    # ssm_groups channels), the chunk their prefill scans by, and the type
+    # their recurrent state is STORED in by the serving cache (the
+    # recurrence's arithmetic is float32 either way).
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
+    ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
     ssm_state_dtype: Any = jnp.float32
@@ -273,6 +291,13 @@ class LlamaConfig:
     # ones (n_shared_experts x the expert width, as ONE SwiGLU; 0: none).
     moe_intermediate_size: int = 0
     moe_shared_size: int = 0
+    # LATENT experts (Nemotron 3's LatentMoE), on with moe_latent_size > 0:
+    # the routed experts read and write rows of that width, ``W_lat_out
+    # sum_e g_e E_e(W_lat_in h)``, the two projections (leaves ``w_lat_in``
+    # hidden -> latent, ``w_lat_out`` latent -> hidden) around the dispatch
+    # and the combine, so that the sorted rows are latent-wide.  The router
+    # and the shared expert read ``h`` at full width.
+    moe_latent_size: int = 0
     # Group-limited routing: the experts are moe_groups groups of
     # consecutive ones, a group scores its best expert, only the
     # moe_top_groups best groups' experts can be chosen (0: no groups).
@@ -358,6 +383,20 @@ class LlamaConfig:
             for kind in self.layer_types))
         object.__setattr__(self, "nope_kinds", tuple(self.nope_kinds))
         object.__setattr__(self, "moe_held", tuple(self.moe_held))
+        if self.block_pattern:
+            kinds, _ = self._blocks_to_layers()
+            if self.layer_pattern or self.layer_types not in ((), kinds):
+                raise ValueError("block_pattern stands in place of "
+                                 "layer_pattern and layer_types")
+            object.__setattr__(self, "layer_types", kinds)
+            if "E" in self.block_pattern and self.moe_experts < 1:
+                raise ValueError("an 'E' block is an expert feed-forward "
+                                 "part: it needs moe_experts")
+        if self.no_ffn and (self.block_pattern or self.layer_types
+                            or self.first_dense_layers or self.moe_experts):
+            raise ValueError("no_ffn is what parts() says of a block_pattern"
+                             "'s mixer-only layer, a plain stack without "
+                             "experts: spell the model as a block_pattern")
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(self.rope_scaling.items())))
@@ -382,8 +421,11 @@ class LlamaConfig:
                 f"n_layers={self.n_layers} is not a whole number of "
                 f"periods of {len(self.period)} layers")
         self._check_latent_and_share()
-        if "mamba" in kinds and self.ssm_heads < 1:
-            raise ValueError("a mamba layer needs ssm_heads")
+        if "mamba" in kinds and (
+                self.ssm_heads < 1 or self.ssm_groups < 1
+                or self.ssm_heads % self.ssm_groups):
+            raise ValueError("a mamba layer needs ssm_heads, a whole number "
+                             "of them an ssm_groups group")
         if "conv" in kinds and self.conv_taps < 2:
             raise ValueError("a conv layer needs conv_taps of 2 or more")
         if "window" in kinds and self.window_size < 1:
@@ -426,8 +468,26 @@ class LlamaConfig:
             raise ValueError(f"nope_kinds: choose from {ATTENDING_KINDS}")
         if self.moe_router_input not in ("ffn", "layer"):
             raise ValueError(f"moe_router_input {self.moe_router_input!r}")
-        if self.moe_activation not in ("silu", "relu"):
+        if self.moe_activation not in ("silu", "relu", "relu2"):
             raise ValueError(f"moe_activation {self.moe_activation!r}")
+
+    def _blocks_to_layers(self):
+        """``block_pattern`` as ``(a kind a layer, the indices of the layers
+        without a feed-forward half)``."""
+        kinds, bare = [], []
+        mixers = {"M": "mamba", "*": "attention"}
+        blocks = self.block_pattern
+        for i, b in enumerate(blocks):
+            if b in mixers:
+                if blocks[i + 1:i + 2] != "E":
+                    bare.append(len(kinds))
+                kinds.append(mixers[b])
+            elif b != "E" or i == 0 or blocks[i - 1] == "E":
+                raise ValueError(
+                    f"block_pattern {blocks!r}: a block is 'M', '*' or an "
+                    f"'E' right after a mixer (a dense '-' block and an "
+                    f"expert block after another are not built)")
+        return tuple(kinds), tuple(bare)
 
     def _check_cross_decoder(self, kinds):
         """Refuse a cross or gmu layer with nothing before it to read, and
@@ -579,20 +639,28 @@ class LlamaConfig:
         # (leading dense layers without a list: a stack of attention layers)
         kinds = self.layer_types or ("attention",) * self.n_layers
         dense = dict(moe_experts=0, moe_shared_size=0, moe_groups=0,
-                     moe_top_groups=0, moe_held=(), moe_router_bias=False)
+                     moe_top_groups=0, moe_held=(), moe_router_bias=False,
+                     moe_latent_size=0)
         out = []
+        # a layer without a feed-forward half is a stack of its own
+        bare = self._blocks_to_layers()[1] if self.block_pattern else ()
         for key, first, stack, fields in (("dense_layers", 0, kinds[:k], dense),
                                           ("layers", k, kinds[k:], {})):
             cuts = () if self.kv_layer is None else tuple(
                 self.kv_layer - first + i for i in (0, 1))
+            cuts += tuple(b - first + i for b in bare for i in (0, 1))
             for i, (start, pattern, periods) in enumerate(
                     _runs(stack, cuts)):
                 if self.diff_attention:
                     fields = {**fields, "layer_offset": first + start}
+                if bare:
+                    alone = first + start in bare
+                    fields = {**(dense if alone else {}), "no_ffn": alone}
                 out.append((dataclasses.replace(
                     self, n_layers=len(pattern) * periods, layer_types=(),
                     layer_pattern=() if pattern == ("attention",)
-                    else pattern, first_dense_layers=0, **fields),
+                    else pattern, first_dense_layers=0, block_pattern="",
+                    **fields),
                     key if i == 0 else f"{key}_{i}", first + start))
         return out
 
@@ -617,6 +685,20 @@ class LlamaConfig:
         order as by position, in rows the decode kernel reads); 1 else."""
         return 2 if self.diff_attention or (
             self.head_dim == 64 and self.n_kv_heads % 2 == 0) else 1
+
+    @property
+    def kv_as_rows(self) -> bool:
+        """Whether a per-slot serving pool keeps K and V as the ROWS the
+        decode kernel reads, ``(La, B, positions * kv_row_heads,
+        kv_row_dim)``, and not by position and head: heads of 64 two a row,
+        and whole-lane heads too few to fill a sublane tile (2 of 128:
+        stored ``(..., S, 2, 128)`` the chip pads the 2 to a tile of 16, 8 x
+        the bytes, and Mosaic cannot read them) beside a state or another
+        pool.  A plain decoder's pool stays by position, which is also what
+        ``forward_with_cache`` and the paged planes' gathered blocks are."""
+        return self.kv_heads_a_row > 1 or (
+            self.head_dim % 128 == 0 and self.n_kv_heads % 8 != 0
+            and not self.one_kv_stack)
 
     @property
     def kv_row_heads(self) -> int:
@@ -826,13 +908,18 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
                  for part, key, _ in config.parts()}
         return {**parts["layers"],
                 **{key: axes["layers"] for key, axes in parts.items()}}
-    if config.moe_experts > 0:
+    if config.no_ffn:
+        ffn_axes = {}
+    elif config.moe_experts > 0:
         ffn_axes = {
             "router": ("layers", None, "expert"),
             "w_gate": ("layers", "expert", "embed", "mlp"),
             "w_up": ("layers", "expert", "embed", "mlp"),
             "w_down": ("layers", "expert", "mlp", "embed"),
         }
+        if config.moe_latent_size:
+            ffn_axes.update(w_lat_in=("layers", "embed", None),
+                            w_lat_out=("layers", None, "embed"))
     else:
         ffn_axes = {
             "w_gate": ("layers", "embed", "mlp"),
@@ -852,6 +939,8 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         },
         "final_norm": (None,),
     }
+    if config.no_ffn:
+        del axes["layers"]["mlp_norm"]
     if config.qk_norm or config.qk_head_norm:
         axes["layers"]["q_norm"] = ("layers", None)
         axes["layers"]["k_norm"] = ("layers", None)
@@ -865,6 +954,9 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
             ws_gate=("layers", "embed", "mlp"),
             ws_up=("layers", "embed", "mlp"),
             ws_down=("layers", "mlp", "embed"))
+    if config.moe_experts > 0 and not expert_config(config).gated:
+        for name in ("w_gate", "ws_gate"):
+            axes["layers"].pop(name, None)
     if config.kv_lora_rank:
         for name in ("wq", "wk", "wv"):
             del axes["layers"][name]
@@ -962,25 +1054,37 @@ def init_params(rng: jax.Array, config: LlamaConfig,
                                    else 970 + i), part, dtype)["layers"]
         return params
     L, La = c.n_layers, c.attending_layers()
-    if c.moe_experts > 0:
+    if c.no_ffn:
+        ffn = {}
+    elif c.moe_experts > 0:
         # The router scores every expert; the matrices are those of the
-        # experts held here (all of them unless ``moe_held``).
+        # experts held here (all of them unless ``moe_held``), as wide as
+        # the rows they read: the stream's, or the latent's.
         E, (_, Eh), F = c.moe_experts, c.held_experts, c.expert_width
+        Din = c.moe_latent_size or c.hidden_size
         ffn = {
             "router": dense(keys[5], (L, c.hidden_size, E), c.hidden_size),
-            "w_gate": dense(keys[6], (L, Eh, c.hidden_size, F),
-                            c.hidden_size),
             "w_up": dense(jax.random.fold_in(keys[6], 1),
-                          (L, Eh, c.hidden_size, F), c.hidden_size),
-            "w_down": dense(keys[7], (L, Eh, F, c.hidden_size), F),
+                          (L, Eh, Din, F), Din),
+            "w_down": dense(keys[7], (L, Eh, F, Din), F),
         }
+        if expert_config(c).gated:
+            ffn["w_gate"] = dense(keys[6], (L, Eh, Din, F), Din)
+        if c.moe_latent_size:
+            kl = jax.random.split(jax.random.fold_in(rng, 76), 2)
+            ffn.update(
+                w_lat_in=dense(kl[0], (L, c.hidden_size, Din),
+                               c.hidden_size),
+                w_lat_out=dense(kl[1], (L, Din, c.hidden_size), Din))
         if c.moe_shared_size:
             Fs, ks = c.moe_shared_size, jax.random.split(
                 jax.random.fold_in(rng, 96), 3)
             ffn.update(
-                ws_gate=dense(ks[0], (L, c.hidden_size, Fs), c.hidden_size),
                 ws_up=dense(ks[1], (L, c.hidden_size, Fs), c.hidden_size),
                 ws_down=dense(ks[2], (L, Fs, c.hidden_size), Fs))
+            if expert_config(c).gated:
+                ffn["ws_gate"] = dense(ks[0], (L, c.hidden_size, Fs),
+                                       c.hidden_size)
     else:
         ffn = {
             "w_gate": dense(keys[5], (L, c.hidden_size, c.intermediate_size),
@@ -1018,6 +1122,8 @@ def init_params(rng: jax.Array, config: LlamaConfig,
         },
         "final_norm": jnp.ones((c.hidden_size,), dtype),
     }
+    if c.no_ffn:
+        del params["layers"]["mlp_norm"]
     if c.qk_norm:
         params["layers"]["q_norm"] = jnp.ones((La, c.q_dim), dtype)
         params["layers"]["k_norm"] = jnp.ones((La, c.kv_dim), dtype)
@@ -1673,12 +1779,13 @@ FLASH_PREFILL_FROM = 2048
 def split_expert_stacks(layers: Dict[str, jax.Array],
                         config: LlamaConfig):
     """(what a layer scan slices per layer, what it closes over): the
-    ``[L, E, ...]`` expert matrices stay whole, for ``attn_out_ffn`` to
-    read at ``layer_index``; everything of a dense model is sliced."""
+    ``[L, E, ...]`` expert matrices stay whole (two of them where an expert
+    has no gate), for ``attn_out_ffn`` to read at ``layer_index``;
+    everything of a dense model is sliced."""
     if config.moe_experts == 0:
         return layers, {}
     return ({k: v for k, v in layers.items() if k not in EXPERT_STACKS},
-            {k: layers[k] for k in EXPERT_STACKS})
+            {k: layers[k] for k in EXPERT_STACKS if k in layers})
 
 
 def gate_attention(x: jax.Array, attn: jax.Array,
@@ -1728,6 +1835,8 @@ def attn_out_ffn(x: jax.Array, attn: jax.Array,
             out = out + layer["bo"].astype(config.dtype)
         x = residual_add(x, out, config, layer["post_attn_norm"]
                          if config.sandwich_norm else None)
+    if config.no_ffn:       # a block of attention alone (``block_pattern``)
+        return x, jnp.zeros((), jnp.float32), None
     return ffn_half(x, layer, config, valid, layer_index, route_x,
                     training)
 
@@ -1782,7 +1891,15 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
 
     mcfg = expert_config(c)
     moe_params = {k: layer[k] for k in ("router",) + EXPERT_STACKS
-                  + (("router_bias",) if c.moe_router_bias else ())}
+                  + (("router_bias",) if c.moe_router_bias else ())
+                  if k in layer}
+    rows_in = h
+    if c.moe_latent_size:
+        # latent experts: the dispatch's rows are the latent's, the router
+        # reads the normed stream at full width
+        with jax.named_scope("latent_proj"):
+            rows_in = matmul(h, layer["w_lat_in"].astype(dt))
+        route_x = h if route_x is None else route_x
     mesh = current_mesh()
     if mesh is not None and mesh.shape.get("expert", 1) > 1:
         # Expert-parallel training: the dense dispatch, whose sharding
@@ -1791,18 +1908,26 @@ def ffn_half(x: jax.Array, layer: Dict[str, jax.Array],
         expert_rows = None
     elif c.moe_dispatch_chunk and h.shape[1] > c.moe_dispatch_chunk:
         ff, aux, expert_rows = _dispatch_in_chunks(
-            h, moe_params, mcfg, valid, layer_index, route_x,
+            rows_in, moe_params, mcfg, valid, layer_index, route_x,
             c.moe_dispatch_chunk, training)
     else:
         ff, aux, expert_rows = moe.moe_ffn_dropless(
-            h, moe_params, mcfg, valid=valid, layer_index=layer_index,
+            rows_in, moe_params, mcfg, valid=valid, layer_index=layer_index,
             route_x=route_x, training=training)
+    if c.moe_latent_size:
+        with jax.named_scope("latent_proj"):
+            ff = matmul(ff, layer["w_lat_out"].astype(dt))
     if c.moe_shared_size:
-        # the shared expert: a plain SwiGLU every token passes, added to
-        # what its routed experts gave
+        # the shared expert: a plain SwiGLU every token passes (two
+        # matrices and a squared ReLU where the experts have no gate), added
+        # to what its routed experts gave
         with jax.named_scope("shared_expert"):
-            shared = jax.nn.silu(matmul(h, layer["ws_gate"].astype(dt))) \
-                * matmul(h, layer["ws_up"].astype(dt))
+            if mcfg.gated:
+                shared = jax.nn.silu(
+                    matmul(h, layer["ws_gate"].astype(dt))) \
+                    * matmul(h, layer["ws_up"].astype(dt))
+            else:
+                shared = moe.relu2(matmul(h, layer["ws_up"].astype(dt)))
             ff = ff + matmul(shared, layer["ws_down"].astype(dt))
     x = residual_add(x, ff, c, post_norm)
     return with_logical_constraint(x, "batch", "seq", None), aux, \
@@ -1814,7 +1939,7 @@ def expert_config(config: LlamaConfig):
     from ray_tpu.models import moe
 
     c = config
-    return moe.MoEConfig(hidden_size=c.hidden_size,
+    return moe.MoEConfig(hidden_size=c.moe_latent_size or c.hidden_size,
                          intermediate_size=c.expert_width,
                          n_experts=c.moe_experts, top_k=c.moe_top_k,
                          capacity_factor=c.moe_capacity_factor,
@@ -2082,6 +2207,8 @@ def layer_block(x, layer, kind: str, config: LlamaConfig, sin, cos,
     out, ys = state_step(mixer, h)
     with jax.named_scope(out_scope):
         x = residual_add(x, out, c)
+    if c.no_ffn:        # a block of the mixer alone (``block_pattern``)
+        return x, jnp.zeros((), jnp.float32), None, ys
     return ffn_half(x, layer, c, **rows_and_place()) + (ys,)
 
 

@@ -6,7 +6,8 @@ plane's from a ``BlockPool``: config, block size, block format): no
 engine, thread or weights are needed to lower one.  By the cache kept:
 
 - a per-slot cache (``init_cache``: K and V ``(La, B, S, Hkv, D)`` over
-  the attention layers -- heads of 64 two a 128-lane row, as rows -- and,
+  the attention layers -- heads of 64 two a 128-lane row, and whole-lane
+  heads too few for a sublane tile beside a state, as rows -- and,
   for a model with Mamba-2 layers, each slot's
   recurrent and conv states beside them, for one with short-convolution
   layers its conv states alone; for a model with window layers
@@ -77,7 +78,9 @@ def _expert_load(expert_rows, held: bool = False):
 def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     """The per-slot cache of a config: K and V ``(La, B, S, Hkv, D)``
     over its attention layers (heads of 64, an even number of them: two a
-    128-lane row, as rows ``(La, B, S * Hkv / 2, 128)``, the same bytes)
+    128-lane row, as rows ``(La, B, S * Hkv / 2, 128)``, the same bytes; 2
+    heads of 128 beside a state as rows ``(La, B, S * 2, 128)``:
+    ``LlamaConfig.kv_as_rows``)
     and, for its Mamba layers, each slot's
     recurrent state ``ssm (Lm, B, N, nh x hd)`` (stored as
     ``cfg.ssm_state_dtype``) and conv window ``conv (Lm, K - 1, B,
@@ -104,10 +107,12 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
             (cfg.n_layers, slots, max_len, cfg.latent_row), cfg.dtype)}
     if cfg.index_topk:
         return _init_indexed_cache(cfg, slots, max_len)
-    if cfg.kv_heads_a_row > 1:
+    if cfg.kv_as_rows:
         # Heads of 64: two a 128-lane row, as rows -- the bytes of ``(La,
         # B, S, Hkv, 64)`` in their order, in the one shape of them that
-        # the decode kernel reads where they lie (``_attend_rows``).
+        # the decode kernel reads where they lie (``_attend_rows``).  And
+        # whole-lane heads too few for a sublane tile (2 of 128), which by
+        # position would be padded to one.
         cache = {name: _row_pool(cfg, cfg.layers_of("attention"), slots,
                                  max_len) for name in ("k", "v")}
     else:
@@ -206,6 +211,24 @@ def kv_rows(cfg: LlamaConfig, cache=None):
         else (cfg.n_kv_heads, cfg.head_dim)
     return {"kv_row_heads": hkv, "kv_row_dim": d,
             "decode_attention": path_taken(hkv, d, as_rows)}
+
+
+def share_and_state(cfg: LlamaConfig):
+    """What ``serve.engine_build`` says beside ``kv_rows`` of a model that
+    keeps a state a slot or holds experts: ``state_bytes_per_slot`` (the
+    recurrent and conv states of all its layers together), ``ssm_groups``
+    of a Mamba-2 model, and ``experts_held`` of the ``experts_routed`` its
+    router scores.  {} for a plain dense decoder."""
+    facts = {}
+    state = state_bytes_per_slot(cfg)
+    if state:
+        facts["state_bytes_per_slot"] = sum(state.values())
+    if cfg.layers_of("mamba"):
+        facts["ssm_groups"] = cfg.ssm_groups
+    if cfg.moe_experts:
+        facts.update(experts_held=cfg.held_experts[1],
+                     experts_routed=cfg.moe_experts)
+    return facts
 
 
 def state_bytes_per_slot(cfg: LlamaConfig):

@@ -1,16 +1,19 @@
 """Mamba-2 mixer (state-space duality, arXiv:2405.21060) for the layers
 of a hybrid model that are not attention (``LlamaConfig.layer_pattern``).
 
-Per layer and token, with ``nh`` heads of ``hd`` channels, ONE group of
-``N`` state dimensions shared by all heads, and a depthwise causal conv
-of ``K`` taps over ``conv_dim = nh * hd + 2 * N`` channels:
+Per layer and token, with ``nh`` heads of ``hd`` channels, ``G`` groups
+of ``N`` state dimensions (``ssm_groups``: head ``j`` reads the B and C of
+group ``j // (nh / G)``; Granite 4 has one group shared by all heads,
+Nemotron-H eight), and a depthwise causal conv of ``K`` taps over
+``conv_dim = nh * hd + 2 * G * N`` channels:
 
     [z | xBC | dt] = W_in h                    (nh*hd | conv_dim | nh)
-    xBC = silu(conv(xBC) + b);  x, B, C = split(xBC)
+    xBC = silu(conv(xBC) + b);  x, B, C = split(xBC)      B, C: (G, N)
     dt  = softplus(dt + dt_bias);  A = -exp(A_log)            per head
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t              (nh, hd, N)
     y_t = S_t C_t + D x_t
-    out = W_out (RMSNorm(y * silu(z)) * w)     over the whole nh*hd
+    out = W_out (RMSNorm(y * silu(z)) * w)     a GROUP's nh*hd/G channels
+                                               at a time (G = 1: the whole)
 
 Two forms of the same recurrence.  ``prefill`` runs it over a prompt in
 chunks of ``ssm_chunk`` positions (the matmul form: inside a chunk a
@@ -50,7 +53,7 @@ import jax.numpy as jnp
 def dims(c) -> Tuple[int, int, int]:
     """(d_inner, conv_dim, width of the in-projection)."""
     d_inner = c.ssm_heads * c.ssm_head_dim
-    conv_dim = d_inner + 2 * c.ssm_state
+    conv_dim = d_inner + 2 * c.ssm_groups * c.ssm_state
     return d_inner, conv_dim, d_inner + conv_dim + c.ssm_heads
 
 
@@ -144,13 +147,17 @@ def _dt_a(dt_raw, layer, live):
 
 @jax.named_scope("ssm_out")
 def _gated_out(y, z, layer, c):
-    """``W_out (RMSNorm(y * silu(z)) * w)``: y float32 (..., nh, hd)."""
+    """``W_out (RMSNorm(y * silu(z)) * w)``, the norm over each group's
+    channels (one group: over all of them): y float32 (..., nh, hd)."""
     from ray_tpu.models.llama import matmul, rms_norm
 
     d_inner = c.ssm_heads * c.ssm_head_dim
+    by_group = y.shape[:-2] + (c.ssm_groups, d_inner // c.ssm_groups)
+    y = rms_norm(
+        y.reshape(by_group) * jax.nn.silu(
+            z.astype(jnp.float32)).reshape(by_group),
+        layer["ssm_norm"].reshape(by_group[-2:]), c.norm_eps)
     y = y.reshape(y.shape[:-2] + (d_inner,))
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), layer["ssm_norm"],
-                 c.norm_eps)
     return matmul(y.astype(c.dtype), layer["ssm_out"].astype(c.dtype))
 
 
@@ -158,38 +165,42 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                 C: jax.Array, chunk: int):
     """The recurrence over P positions from a zero state, in chunks.
 
-    x (G, P, nh, hd) and B, C (G, P, N) in the compute type; dt (G, P,
-    nh) float32, 0 at padded positions; A (nh,) float32, negative.
+    x (G, P, nh, hd) and B, C (G, P, R, N), R groups of state dimensions
+    (head j reads group j // (nh / R)), in the compute type; dt (G, P, nh)
+    float32, 0 at padded positions; A (nh,) float32, negative.
     Returns (y (G, P, nh, hd) float32 without the ``D x`` term, the state
     after the last position (G, nh, hd, N) float32)."""
     G, P, nh, hd = x.shape
-    N = B.shape[-1]
+    R, N = B.shape[-2:]
+    per = nh // R                        # heads a group
     Q = min(chunk, P)
     if P % Q:
         raise ValueError(f"{P} positions are not whole chunks of {Q}")
     nc = P // Q
     f32 = jnp.float32
     xc = x.reshape(G, nc, Q, nh, hd)
-    Bc, Cc = B.reshape(G, nc, Q, N), C.reshape(G, nc, Q, N)
+    Bc, Cc = B.reshape(G, nc, Q, R, N), C.reshape(G, nc, Q, R, N)
     dth = dt.reshape(G, nc, Q, nh).transpose(0, 1, 3, 2)   # (G, nc, nh, Q)
     acum = jnp.cumsum(dth * A[None, None, :, None], axis=-1)
     # Inside a chunk: position i reads j <= i through exp(sum of a over
-    # (j, i]) -- one (Q, Q) matrix a head -- times C_i . B_j, shared by
-    # the heads of the one group.
+    # (j, i]) -- one (Q, Q) matrix a head -- times C_i . B_j, one (Q, Q)
+    # matrix a GROUP, shared by its heads.
     seg = acum[..., :, None] - acum[..., None, :]      # (G, nc, nh, Qi, Qj)
     causal = jnp.tril(jnp.ones((Q, Q), bool))
     decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
-    scores = jnp.einsum("gcin,gcjn->gcij", Cc, Bc,
+    scores = jnp.einsum("gcirn,gcjrn->gcrij", Cc, Bc,
                         preferred_element_type=f32)
-    mix = scores[:, :, None] * decay * dth[..., None, :]
+    mix = jnp.repeat(scores, per, axis=2) * decay * dth[..., None, :]
     y = jnp.einsum("gchij,gcjhd->gcihd", mix.astype(x.dtype), xc,
                    preferred_element_type=f32)
     # What a chunk adds to the state by its end.
     to_end = jnp.exp(acum[..., -1:] - acum) * dth          # (G, nc, nh, Q)
     xw = (xc.astype(f32) * to_end.transpose(0, 1, 3, 2)[..., None]
           ).astype(x.dtype)
-    states = jnp.einsum("gcjhd,gcjn->gchdn", xw, Bc,
-                        preferred_element_type=f32)
+    states = jnp.einsum("gcjrhd,gcjrn->gcrhdn",
+                        xw.reshape(G, nc, Q, R, per, hd), Bc,
+                        preferred_element_type=f32
+                        ).reshape(G, nc, nh, hd, N)
     if nc == 1:
         return y.reshape(G, P, nh, hd), states[:, 0]
     # Between chunks: the state entering chunk c, a scan over the chunks.
@@ -202,8 +213,10 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     final, entering = jax.lax.scan(
         carry_state, jnp.zeros((G, nh, hd, N), f32),
         (chunk_decay.transpose(1, 0, 2), states.transpose(1, 0, 2, 3, 4)))
-    y_in = jnp.einsum("gcin,cghdn->gcihd", Cc.astype(f32), entering,
-                      preferred_element_type=f32)
+    y_in = jnp.einsum("gcirn,cgrhdn->gcirhd", Cc.astype(f32),
+                      entering.reshape(nc, G, R, per, hd, N),
+                      preferred_element_type=f32
+                      ).reshape(G, nc, Q, nh, hd)
     y = y + y_in * jnp.exp(acum).transpose(0, 1, 3, 2)[..., None]
     return y.reshape(G, P, nh, hd), final
 
@@ -217,6 +230,7 @@ def prefill(h: jax.Array, layer, c, lengths: Optional[jax.Array]):
     as of each row's last real position."""
     G, P, _ = h.shape
     nh, hd, N, K = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_conv
+    R = c.ssm_groups
     d_inner = nh * hd
     if lengths is None:
         lengths = jnp.full((G,), P, jnp.int32)
@@ -233,7 +247,8 @@ def prefill(h: jax.Array, layer, c, lengths: Optional[jax.Array]):
             padded, taps[:, :, None], axis=1).transpose(1, 0, 2)
     with jax.named_scope("ssm_scan"):
         x = xbc[..., :d_inner].reshape(G, P, nh, hd)
-        B, C = xbc[..., d_inner:d_inner + N], xbc[..., d_inner + N:]
+        B, C = (xbc[..., at:at + R * N].reshape(G, P, R, N)
+                for at in (d_inner, d_inner + R * N))
         live = (jnp.arange(P, dtype=jnp.int32)[None, :]
                 < lengths[:, None])[..., None]
         dt, A = _dt_a(dt_raw, layer, live)
@@ -259,7 +274,7 @@ def decode(h: jax.Array, layer, c, ssm: jax.Array, conv: jax.Array,
     they are.  Returns (out (B, 1, H), ssm, conv)."""
     from ray_tpu.ops.ssm_state_update import ssm_state_update
 
-    nh, hd, N = c.ssm_heads, c.ssm_head_dim, c.ssm_state
+    nh, hd, N, R = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups
     d_inner = nh * hd
     f32 = jnp.float32
     z, xbc_in, dt_raw = _project(h[:, 0], layer, c)
@@ -276,6 +291,7 @@ def decode(h: jax.Array, layer, c, ssm: jax.Array, conv: jax.Array,
             ssm, m, active,
             jnp.repeat(jnp.exp(dt * A), hd, axis=1),
             (dt[..., None] * x).reshape(-1, d_inner),
-            xbc[:, d_inner:d_inner + N], xbc[:, d_inner + N:])
+            *(xbc[:, at:at + R * N].reshape(-1, R, N)
+              for at in (d_inner, d_inner + R * N)))
         y = y.reshape(-1, nh, hd) + layer["ssm_D"].astype(f32)[:, None] * x
     return _gated_out(y, z, layer, c)[:, None], ssm, conv

@@ -38,6 +38,11 @@ from ray_tpu.parallel.sharding import with_logical_constraint
 PyTree = Any
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """``relu(x)^2``: the activation of an expert of two matrices."""
+    return jnp.square(jax.nn.relu(x))
+
+
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     hidden_size: int
@@ -49,7 +54,9 @@ class MoEConfig:
     # use the softmax's probabilities as they are (OLMoE's
     # ``norm_topk_prob: false``).
     norm_topk: bool = True
-    # The experts' gate activation: "silu" (SwiGLU) or "relu" (ReGLU).
+    # The experts' gate activation: "silu" (SwiGLU) or "relu" (ReGLU); or
+    # "relu2": an expert of TWO matrices, ``relu(x W_up)^2 W_down``, whose
+    # params hold no ``w_gate`` (``moe_ffn_dropless``'s forward alone).
     activation: str = "silu"
     dtype: Any = jnp.bfloat16
     # Group-limited routing (DeepSeek-V2's group_limited_greedy): the
@@ -70,7 +77,15 @@ class MoEConfig:
 
     @property
     def act(self):
-        return {"silu": jax.nn.silu, "relu": jax.nn.relu}[self.activation]
+        return {"silu": jax.nn.silu, "relu": jax.nn.relu,
+                "relu2": relu2}[self.activation]
+
+    @property
+    def gated(self) -> bool:
+        """Whether an expert (and a shared one beside it) has a gate matrix
+        beside ``w_up`` and ``w_down``: all but "relu2".  The one place the
+        gate's absence is read from the activation."""
+        return self.activation != "relu2"
 
 
 def init_moe_params(rng: jax.Array, config: MoEConfig,
@@ -230,11 +245,24 @@ def _sorted_ffn(c: MoEConfig, training: bool, xt, w_gate, w_up, w_down,
             else rows
 
     with jax.named_scope("expert_dispatch"):
-        rows = guarded(xt[order // K])                     # (T*K, D)
+        # Sorted rows in whole sublane tiles: at 22 picks of a few decode
+        # slots (44 rows) the v5e compiler fails on the grouped matmul's
+        # group sizes where a stack's ``L * E`` is no power of two
+        # (INTERNAL, "Bitcast cannot have different shape sizes").  The rows
+        # added lie past every group: no matmul computes them and
+        # ``inverse`` names none.  Every benchmark cell's rows are whole
+        # tiles already.
+        spare = -(T * K) % 8
+        gathered = jnp.pad(order, (0, spare)) if spare else order
+        rows = guarded(xt[gathered // K])         # (T*K [+ spare], D)
     with jax.named_scope("expert_ffn"):
-        act = guarded(
-            c.act(_grouped(rows, w_gate, group_sizes).astype(dt))
-            * _grouped(rows, w_up, group_sizes).astype(dt))
+        if c.gated:
+            act = guarded(
+                c.act(_grouped(rows, w_gate, group_sizes).astype(dt))
+                * _grouped(rows, w_up, group_sizes).astype(dt))
+        else:
+            act = guarded(
+                c.act(_grouped(rows, w_up, group_sizes)).astype(dt))
         # (T*K, D) float32; a share's in ``dt``: three in four of its rows
         # are not computed, and at a 12,288-token prompt's 73,728 rows of
         # 5,120 the float32 result and its un-sorted copy are 2.8 GB
@@ -435,8 +463,9 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     since a custom call cannot read through a dynamic slice.)  The
     router in ``params`` is the layer's own either way.
 
-    ``route_x`` (B, S, D): what the router reads where that is not the
-    rows the experts multiply (a router placed before attention).
+    ``route_x`` (B, S, width of the router): what the router reads where
+    that is not the rows the experts multiply (a router placed before
+    attention; the full-width stream of experts that work in a latent).
 
     ``training``: what a backward pass and the balance update of a
     selection bias need, beside a forward that serving lowers as it always
@@ -456,7 +485,7 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
     xt = x.reshape(T, D).astype(dt)
 
     probs, gate_vals, expert_idx = _route(
-        xt if route_x is None else route_x.reshape(T, D),
+        xt if route_x is None else route_x.reshape(T, -1),
         params["router"], K, c.norm_topk, c.groups, c.top_groups,
         c.routed_scale, c.score, params.get("router_bias"))
     with jax.named_scope("expert_dispatch"):
@@ -479,16 +508,19 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
         expert_rows = _rows_per_group(flat, E)
 
     with jax.named_scope("expert_ffn"):
-        w_gate, w_up, w_down = (params[k].astype(dt)
-                                for k in ("w_gate", "w_up", "w_down"))
+        # (an expert without a gate has no ``w_gate``: None in its place)
+        w_gate, w_up, w_down = (
+            params[k].astype(dt) if c.gated or k != "w_gate" else None
+            for k in ("w_gate", "w_up", "w_down"))
         group_sizes = expert_rows
         if layer_index is not None:
-            L = w_gate.shape[0]
+            L = w_up.shape[0]
             group_sizes = jax.lax.dynamic_update_slice(
                 jnp.zeros((L * E,), jnp.int32), expert_rows,
                 (layer_index * E,))
-            w_gate, w_up, w_down = (w.reshape((L * E,) + w.shape[2:])
-                                    for w in (w_gate, w_up, w_down))
+            w_gate, w_up, w_down = (
+                None if w is None else w.reshape((L * E,) + w.shape[2:])
+                for w in (w_gate, w_up, w_down))
 
     with jax.named_scope("expert_dispatch"):
         if training and c.held:
@@ -497,6 +529,10 @@ def moe_ffn_dropless(x: jax.Array, params: PyTree, config: MoEConfig,
                 chosen = jnp.where(jnp.repeat(valid, K), chosen, c.n_experts)
             choices = _rows_per_group(chosen, c.n_experts)
     stacks = (w_gate, w_up, w_down)
+    if training and not c.gated:
+        raise NotImplementedError(
+            "an expert of two matrices (relu2) is served only: the written-"
+            "out backward of a held share multiplies by a gate")
     if training and compact_rows(T, c) < T * K:
         out = _held_rows_ffn(c, xt, *stacks, gate_vals, order, group_sizes)
     else:

@@ -611,7 +611,7 @@ SCOPES = (
     "flash_attention.dkdv", "decode_attention",
     "kv_write", "attn_out", "ffn",
     "router", "expert_dispatch", "expert_ffn", "shared_expert",
-    "router_balance",
+    "latent_proj", "router_balance",
     "mla_absorb", "mla_expand", "mla_decode_attention",
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_state_update", "ssm_out",
     "conv_proj", "short_conv", "conv_out",

@@ -4,6 +4,10 @@ does not advance neither read nor written.
 
     S <- decay * S + B (x) dtx        y = S C         per advancing slot
 
+(B and C come in ``R`` groups of ``N`` state dimensions: the channels are
+``R`` equal runs of lanes, and run ``g`` is fed by ``B_g`` and read by
+``C_g``.  One group -- Granite 4 -- is every channel under the one B and C.)
+
 The states of all layers and slots are ONE array, ``(Lm, B, N, HD)``:
 the carry of the serving loops (``llama_serve.decode_step``).  Written in
 XLA, a layer's update is two fusions -- one contracts the new state with
@@ -24,15 +28,18 @@ aliased to its result:
   (``_plan``);
 - the state's layout keeps the ``HD = heads x head_dim`` channels on the
   lanes and the ``N`` state dimensions on the sublanes, so the decay and
-  ``dtx`` are rows broadcast down the sublanes, ``B`` is a column made
-  by one tile transpose, and ``y = C S`` is a matmul whose result lies
-  along the lanes as the next op wants it.
+  ``dtx`` are rows broadcast down the sublanes, a group's ``B`` is a
+  column made by one tile transpose, and ``y = C S`` is a matmul whose
+  result lies along the lanes as the next op wants it; the lanes are
+  computed ``_CHUNK`` at a time, a chunk under ITS group's column and row
+  (a group is a whole number of chunks: 8,192 lanes in 8 groups are two
+  chunks of 512 a group).
 
 Arithmetic in float32; the state is rounded to its storage type once,
 and ``y`` contracts the state AS STORED (what the next step will read).
 
-A state Mosaic cannot tile (``N`` or ``HD`` not whole 128-lane tiles: the
-toy presets) is updated by XLA, by shape (``_xla_update``: the same
+A state Mosaic cannot tile (``N`` or a group's ``HD / R`` lanes not whole
+128-lane tiles: the toy presets) is updated by XLA, by shape (``_xla_update``: the same
 arithmetic); interpret mode runs the kernel on the CPU for the test
 suite, decided as ``ops/decode_attention.py`` decides.
 """
@@ -52,10 +59,13 @@ LANES = _flash.LANES
 # Lanes of a state the kernel computes at a time: (N, 512) float32
 # temporaries are 256 KiB each beside the 2 x 2 blocks of 1 MiB.
 _CHUNK = 512
+# Mosaic's scoped VMEM unless a call asks for more: a state of 128 x 8,192
+# float32 is a block of 4 MiB, and the pipeline holds four.
+_SCOPED_VMEM = 16 << 20
 
 
-def _tiles(n: int, hd: int) -> bool:
-    return n % LANES == 0 and hd % LANES == 0
+def _tiles(n: int, hd: int, groups: int = 1) -> bool:
+    return n % LANES == 0 and (hd // groups) % LANES == 0
 
 
 def _plan(active: jax.Array):
@@ -79,10 +89,15 @@ def _kernel(layer_ref, block_ref, mode_ref, s_ref, decay_ref, dtx_ref,
     @pl.when(mode == 1)
     def _update():
         f32 = jnp.float32
-        # B down the sublanes: a lanes-constant tile by one transpose
-        b_col = jnp.broadcast_to(b_ref[0], (n, n)).T[:, :1]       # (N, 1)
-        c_rows = jnp.broadcast_to(c_ref[0], (8, n)).astype(o_ref.dtype)
+        per_group = hd // chunk // b_ref.shape[1]     # chunks a group
         for j in range(hd // chunk):
+            if j % per_group == 0:
+                # the group's B down the sublanes: a lanes-constant tile
+                # by one transpose
+                g = slice(j // per_group, j // per_group + 1)
+                b_col = jnp.broadcast_to(b_ref[0, g], (n, n)).T[:, :1]
+                c_rows = jnp.broadcast_to(c_ref[0, g], (8, n)).astype(
+                    o_ref.dtype)
             at = pl.ds(j * chunk, chunk)
             new = (decay_ref[0, :, at] * s_ref[0, 0, :, at].astype(f32)
                    + b_col * dtx_ref[0, :, at])
@@ -103,10 +118,16 @@ def _kernel(layer_ref, block_ref, mode_ref, s_ref, decay_ref, dtx_ref,
 
 def _xla_update(ssm, layer, active, decay, dtx, b, c):
     s = jax.lax.dynamic_index_in_dim(ssm, layer, 0, keepdims=False)
+    slots, n, hd = s.shape
+    groups = b.shape[1]
+    by_group = (slots, n, groups, hd // groups)
     new = (decay[:, None, :] * s.astype(jnp.float32)
-           + b[:, :, None] * dtx[:, None, :]).astype(ssm.dtype)
+           + (b.transpose(0, 2, 1)[..., None]
+              * dtx.reshape(slots, 1, groups, -1)).reshape(s.shape)
+           ).astype(ssm.dtype)
     new = jnp.where(active[:, None, None], new, s)
-    y = jnp.einsum("bnj,bn->bj", new.astype(jnp.float32), c)
+    y = jnp.einsum("bngj,bgn->bgj", new.astype(jnp.float32).reshape(by_group),
+                   c).reshape(slots, hd)
     return (jax.lax.dynamic_update_index_in_dim(ssm, new, layer, 0),
             jnp.where(active[:, None], y, 0.0))
 
@@ -116,40 +137,46 @@ def ssm_state_update(ssm: jax.Array, layer: jax.Array, active: jax.Array,
                      c: jax.Array):
     """ssm (Lm, B, N, HD) the stacked states; layer () int32; active (B,)
     bool; decay (B, HD) float32, each head's ``exp(dt A)`` over its
-    channels; dtx (B, HD) float32, ``dt * x``; b, c (B, N) float32.
+    channels; dtx (B, HD) float32, ``dt * x``; b, c (B, R, N) float32, a
+    row a group of ``HD / R`` channels.
     Returns (ssm with layer ``layer`` of the active slots advanced, y
     (B, HD) float32 = the new state contracted with c; 0 for a slot that
     is not active)."""
     _lm, slots, n, hd = ssm.shape
+    groups = b.shape[1]
     interpret = _flash._use_interpret()
-    if not interpret and not _tiles(n, hd):
+    if not interpret and not _tiles(n, hd, groups):
         return _xla_update(ssm, layer, active, decay, dtx, b, c)
-    chunk = min(_CHUNK, hd)
+    chunk = min(_CHUNK, hd // groups)
     block, mode = _plan(active)
 
     def row(x):
         return x.astype(jnp.float32)[:, None, :]
 
-    def vector(width):
-        return pl.BlockSpec((1, 1, width), lambda r, *_: (r, 0, 0))
+    def vector(width, rows=1):
+        return pl.BlockSpec((1, rows, width), lambda r, *_: (r, 0, 0))
 
     state = pl.BlockSpec((1, 1, n, hd),
                          lambda r, layer, block, mode: (layer[0], block[r],
                                                         0, 0))
     precision = (jax.lax.Precision.HIGHEST if ssm.dtype == jnp.float32
                  else None)
+    # in and out, double buffered, and the chunk's temporaries
+    vmem = 4 * n * hd * ssm.dtype.itemsize + (4 << 20)
     out, y = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, precision=precision),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(slots,),
-            in_specs=[state, vector(hd), vector(hd), vector(n), vector(n)],
+            in_specs=[state, vector(hd), vector(hd), vector(n, groups),
+                      vector(n, groups)],
             out_specs=[state, vector(hd)]),
         out_shape=[jax.ShapeDtypeStruct(ssm.shape, ssm.dtype),
                    jax.ShapeDtypeStruct((slots, 1, hd), jnp.float32)],
         input_output_aliases={3: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem if vmem > _SCOPED_VMEM else None),
         interpret=interpret, name="ssm_state_update",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), block, mode, ssm,
-      row(decay), row(dtx), row(b), row(c))
+      row(decay), row(dtx), b.astype(jnp.float32), c.astype(jnp.float32))
     return out, y[:, 0]

@@ -540,8 +540,10 @@ class LLMServer:
         self._prefill_ema: Optional[float] = None
 
         # how this engine's K/V lie and what attends them: fixed here
-        build.args = llama_serve.kv_rows(
-            self.cfg, None if self.paged else self.cache)
+        build.args = {
+            **llama_serve.kv_rows(self.cfg,
+                                  None if self.paged else self.cache),
+            **llama_serve.share_and_state(self.cfg)}
         build.__exit__()
         if warmup:
             with _tracing.span("serve.warmup"):

@@ -109,34 +109,39 @@ def _padded(prompts, bucket):
 # ------------------------------------------------------ the scan, by itself
 def _sequential(x, dt, A, B, C):
     """The recurrence as defined, one position at a time (numpy,
-    float64): (y (G, P, nh, hd), the state after each row's LAST position
+    float64), B and C (G, P, R, N) in R groups, head j reading group j //
+    (nh / R): (y (G, P, nh, hd), the state after each row's LAST position
     with dt > 0)."""
     x, dt, A, B, C = (np.asarray(a, np.float64) for a in (x, dt, A, B, C))
     G, P, nh, hd = x.shape
+    B, C = (np.repeat(a, nh // a.shape[2], axis=2) for a in (B, C))
     s = np.zeros((G, nh, hd, B.shape[-1]))
     ys = np.zeros((G, P, nh, hd))
     for t in range(P):
         s = (np.exp(dt[:, t] * A)[..., None, None] * s
              + (dt[:, t, :, None] * x[:, t])[..., None]
-             * B[:, t, None, None, :])
-        ys[:, t] = np.einsum("ghdn,gn->ghd", s, C[:, t])
+             * B[:, t, :, None, :])
+        ys[:, t] = np.einsum("ghdn,ghn->ghd", s, C[:, t])
     return ys, s
 
 
+@pytest.mark.parametrize("groups", [1, 2], ids=["one-group", "two-groups"])
 @pytest.mark.parametrize("lengths", [(1,), (7,), (8,), (9,), (20,),
                                      (20, 3, 8, 17)],
                          ids=["1", "7", "8", "9", "20", "padded-group"])
-def test_chunked_scan_is_the_sequential_recurrence(lengths):
+def test_chunked_scan_is_the_sequential_recurrence(lengths, groups):
     """``ssd_chunked`` in chunks of 8 over right-padded rows (dt = 0 past
     a row's length) gives the recurrence's outputs at every real position
     and, as its final state, the state at EACH ROW'S OWN last real
     position: lengths inside a chunk, on its edge, one past it, over
-    several, and unequal lengths in one group."""
+    several, and unequal lengths in one group; with one group of B and C
+    (Granite 4) and with two, each read by its half of the heads
+    (Nemotron-H has eight)."""
     G, P, nh, hd, N = len(lengths), 24, 4, 8, 16
     keys = jax.random.split(jax.random.key(sum(lengths)), 5)
     x = jax.random.normal(keys[0], (G, P, nh, hd))
-    B = jax.random.normal(keys[1], (G, P, N))
-    C = jax.random.normal(keys[2], (G, P, N))
+    B = jax.random.normal(keys[1], (G, P, groups, N))
+    C = jax.random.normal(keys[2], (G, P, groups, N))
     dt = jax.random.uniform(keys[3], (G, P, nh), minval=0.01, maxval=0.5)
     A = -jax.random.uniform(keys[4], (nh,), minval=0.5, maxval=8.0)
     live = np.arange(P)[None, :] < np.asarray(lengths)[:, None]
@@ -159,15 +164,19 @@ def test_chunked_scan_is_the_sequential_recurrence(lengths):
     (0, 1, 1, 0, 1, 0), (1, 0, 0, 0, 0, 0), (0, 0, 0, 0, 0, 0),
     (1, 1, 1, 1, 1, 1), (0, 0, 0, 0, 0, 1)],
     ids=["mixed", "first-only", "none", "all", "last-only"])
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16", "f32"])
-def test_state_update_kernel_advances_the_active_slots_alone(active, dtype):
+@pytest.mark.parametrize("dtype,groups", [
+    (jnp.bfloat16, 1), (jnp.float32, 1), (jnp.float32, 2)],
+    ids=["bf16", "f32", "f32-two-groups"])
+def test_state_update_kernel_advances_the_active_slots_alone(active, dtype,
+                                                             groups):
     """``ops/ssm_state_update.py`` (interpreted here) against its own XLA
     form, which is the arithmetic written out: the active slots' layer is
     advanced, every other slot and every other layer is BIT FOR BIT what
     it was -- whichever slots are active, none of them, or only one at
     either end (the kernel maps a slot that does not advance to another
-    slot's block: ``_plan``)."""
+    slot's block: ``_plan``).  With two groups of B and C each half of the
+    lanes is fed and read by its own, in the kernel's chunks as in the
+    einsum."""
     from ray_tpu.ops import ssm_state_update as op
 
     layers, slots, n, hd = 3, 6, 16, 64
@@ -175,8 +184,8 @@ def test_state_update_kernel_advances_the_active_slots_alone(active, dtype):
     ssm = jax.random.normal(keys[0], (layers, slots, n, hd)).astype(dtype)
     decay = jax.random.uniform(keys[1], (slots, hd))
     dtx = jax.random.normal(keys[2], (slots, hd))
-    b = jax.random.normal(keys[3], (slots, n))
-    c = jax.random.normal(keys[4], (slots, n)).astype(
+    b = jax.random.normal(keys[3], (slots, groups, n))
+    c = jax.random.normal(keys[4], (slots, groups, n)).astype(
         jnp.bfloat16).astype(jnp.float32)
     on = jnp.asarray(active, bool)
     got, y = op.ssm_state_update(ssm, jnp.int32(1), on, decay, dtx, b, c)

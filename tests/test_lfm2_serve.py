@@ -505,7 +505,10 @@ def test_spans_counters_and_the_conv_pool(engine):
 # Here: ``prefill`` and ``decode_k`` of the windowed toy (cell 7's shape)
 # and of the latent / shared / held toy with a leading dense layer (cell
 # 8's), whose ``parts``, walk, router and state pool this PR reaches into,
-# as the PARENT commit (124731d, PR 39) lowered them.
+# as the PARENT commit (124731d, PR 39) lowered them -- but for both
+# ``decode_k`` texts since PR 61: 4 slots x top-3 are 12 sorted rows, not
+# whole sublane tiles, and ``moe._sorted_ffn`` now gathers 16 (the cells'
+# rows are whole tiles and their programs lower to the text they did).
 _YARN = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
          "mscale": 0.707, "mscale_all_dim": 0.707,
          "original_max_position_embeddings": 16}
@@ -517,7 +520,7 @@ _BEFORE = {
         window_size=8, layer_pattern=("attention", "window", "window",
                                       "window"),
         nope_kinds=("attention",), tie_embeddings=False, max_seq_len=64),
-        "b30e9e97101338d5", "0698a326403b18d6"),
+        "b30e9e97101338d5", "655b9a1e885b515f"),
     "latent_share": (dict(
         vocab_size=256, hidden_size=64, n_layers=3, n_heads=4, n_kv_heads=4,
         head_dim=24, intermediate_size=128, max_seq_len=64,
@@ -528,7 +531,7 @@ _BEFORE = {
         moe_norm_topk=False, moe_intermediate_size=32, moe_shared_size=64,
         moe_groups=4, moe_top_groups=2, moe_routed_scale=16.0,
         moe_held=(0, 8)),
-        "58dc65ea57dddac9", "fd5b57795001f31e"),
+        "58dc65ea57dddac9", "ee14c78dd71c11ca"),
 }
 
 

@@ -401,7 +401,15 @@ def test_spans_counters_and_pools_for_a_windowed_model_and_only_for_one(
 # A later PR that means to change a program records its new text here:
 # PR 33 did for the six ``prefill`` programs (a group's rows written where
 # they lie, ``llama_serve._insert_rows``; the ``decode_k`` texts are
-# PR 31's still) and for the windowed one below.
+# PR 31's still) and for the windowed one below; PR 61 did for the two
+# hybrids' four programs (Mamba-2's B and C carry a group axis through the
+# chunked scan, the state update and the gated norm: one group there, so
+# unit dimensions; compiled for a v5e at granite's widths the decode step
+# differs from its parent's in 27 bitcasts and the prefill in nothing:
+# PERF.md section 6, PR 61) and for ``olmoe_like``'s ``decode_k``, whose 4
+# slots x top-3 are 12 sorted rows and not whole sublane tiles:
+# ``moe._sorted_ffn`` now gathers 16 (every benchmark cell's rows are whole
+# tiles and their programs the text they were).
 _BEFORE = {
     "dense": (LlamaConfig.debug,
               {}, "9cea23cd675cc71a", "451d4209906be1e0"),
@@ -412,12 +420,12 @@ _BEFORE = {
             "a6acd292520feb78", "488ee7a67f842d7e"),
     "olmoe_like": (LlamaConfig.moe_debug,
                    dict(moe_norm_topk=False, qk_norm=True, moe_top_k=3),
-                   "75aefc941011707b", "18c2dd9cf37184c1"),
+                   "75aefc941011707b", "999bb3ff565da99f"),
     "hybrid": (LlamaConfig.hybrid_debug, {},
-               "8714e32043223842", "a0cebe2ffa1301dd"),
+               "1cb35f28ca05d404", "e877b22e6a56c290"),
     "hybrid_f32_stream": (LlamaConfig.hybrid_debug,
                           dict(stream_dtype="float32"),
-                          "a7acc3dfa685fa99", "3f29528740f257e6"),
+                          "177292a141842087", "ee8b3b308e03eecd"),
 }
 
 
