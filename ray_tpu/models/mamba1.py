@@ -23,14 +23,21 @@ layer reads (``llama.layer_block``, kind ``gmu``): ``prefill`` and
 ``decode`` hand it back beside their result.
 
 ``prefill`` runs the recurrence over a prompt as it is written, one
-position after another, ``ssm_chunk`` positions an iteration of the loop
-(XLA's: the state is a loop carry, no ``(P, N, Di)`` tensor is made -- 4 GB
-in float32 at 12,288 positions); a padded position takes ``dt = 0`` and
-neither decays the state nor feeds it, so a row's state is that of ITS OWN
-last real position, and its conv state its last ``K - 1`` real pre-conv
-inputs (zeros before position 0).  ``decode`` advances every slot's state
-by one token in place in the stacked states the serving loops carry; a row
-that is not ``active`` takes ``dt = 0`` and keeps its conv window.
+position after another, in ONE kernel (``ops/mamba1_scan.py``: a block of
+1,024 channels' state stays in vector registers from a row's first position
+to its last and crosses HBM once a row, not once a position; no ``(P, N,
+Di)`` tensor is made -- 4 GB in float32 at 12,288 positions); channels that
+are not whole blocks (the toy presets) and a group of several rows keep
+``selective_scan``, XLA's loop of ``ssm_chunk`` positions an iteration with
+the state a loop carry.  A
+padded position takes ``dt = 0`` and neither decays the state nor feeds it,
+so a row's state is that of ITS OWN last real position, and its conv state
+its last ``K - 1`` real pre-conv inputs (zeros before position 0); the
+memory ``y`` PAST a row's last position is not defined (the kernel leaves
+zeros from the next group of 8 positions on, the loop ``S . C_t``).
+``decode`` advances every slot's state by one token in place in the stacked
+states the serving loops carry; a row that is not ``active`` takes ``dt =
+0`` and keeps its conv window.
 
 The recurrence's arithmetic is float32 whatever type the state is stored
 in (``ssm_state_dtype``), and so is everything between the projections:
@@ -182,6 +189,8 @@ def prefill(h: jax.Array, layer, c, lengths: Optional[jax.Array]):
     every position is real).  Returns (out (G, P, H), (state (G, N, Di) in
     the state's storage type, conv state (K - 1, G, Di)), both as of each
     row's last real position, the memory y (G, P, Di) float32)."""
+    from ray_tpu.ops.mamba1_scan import mamba1_scan
+
     G, P, _ = h.shape
     K = c.ssm_conv
     if lengths is None:
@@ -201,8 +210,9 @@ def prefill(h: jax.Array, layer, c, lengths: Optional[jax.Array]):
     dt, B, C = _dt_b_c(u, layer, c, live)
     with jax.named_scope("mamba1_scan"):
         A = -jnp.exp(layer["ssm_A_log"].astype(jnp.float32))
-        y, state = selective_scan(u, dt, A, B, C, c.ssm_chunk)
-        y = y + layer["ssm_D"].astype(jnp.float32) * u
+        y, state = mamba1_scan(u, dt, A, B, C,
+                               layer["ssm_D"].astype(jnp.float32), lengths,
+                               c.ssm_chunk)
         state = state.astype(c.ssm_state_dtype)
     return _gated_out(y, z, layer, c), (state, conv_state), y
 

@@ -485,6 +485,13 @@ def serve_engine_counters():
             "sent through the chunked delta rule's kernel "
             "(ops/kda_chunk.py); a shape that keeps XLA's form adds none",
             tag_keys=("deployment",)),
+        # A model with Mamba-1 layers only.
+        "mamba1_scan_positions": Counter(
+            "ray_tpu_serve_mamba1_scan_positions_total",
+            "padded prompt positions x Mamba-1 layers the prefill launches "
+            "sent through the selective scan's kernel (ops/mamba1_scan.py); "
+            "a shape that keeps XLA's loop adds none",
+            tag_keys=("deployment",)),
         # A model with experts only (a dense one never touches these).
         "moe_expert_rows": Counter(
             "ray_tpu_serve_moe_expert_rows_total",

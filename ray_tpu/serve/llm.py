@@ -2038,6 +2038,18 @@ class LLMServer:
                     if engages(self.cfg.kda_head_dim, chunk) else 0)
                 m["kda_chunk_positions"].inc(
                     scan["kda_chunk_positions"], tags=self._tags)
+            mamba1_layers = self.cfg.layers_of("mamba1")
+            if mamba1_layers:
+                # the same of ``ops/mamba1_scan.py``: the bucket in whole
+                # blocks of positions x the Mamba-1 layers (a prefill
+                # walks every one: none lies after a K/V layer)
+                from ray_tpu.ops.mamba1_scan import engages, padded_len
+
+                scan["mamba1_scan_positions"] = (
+                    rows * padded_len(bucket) * mamba1_layers
+                    if engages(self.cfg.ssm_inner, rows) else 0)
+                m["mamba1_scan_positions"].inc(
+                    scan["mamba1_scan_positions"], tags=self._tags)
         if self._ring:
             # of the bucket's score square, the share inside a window
             # layer's band (what its attention has to compute)

@@ -488,8 +488,10 @@ def test_spans_counters_and_pools_for_a_hybrid_and_only_for_one(engine):
     assert groups and chunks
     for g in groups:        # buckets 16 and 32 in chunks of 8
         assert g["scan_chunks"] == g["rows_padded"] * g["bucket"] // 8
-        # Mamba layers, no KDA layer: nothing of the delta rule's kernel
+        # Mamba-2 layers, no KDA and no Mamba-1 layer: nothing of the
+        # delta rule's kernel nor of the selective scan's
         assert "kda_chunk_positions" not in g
+        assert "mamba1_scan_positions" not in g
     for c in chunks:
         assert c["state_rows_updated"] == c["active"] * c["k"]
         assert c["state_bytes"] == 2 * c["state_rows_updated"] * sum(

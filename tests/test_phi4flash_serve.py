@@ -263,7 +263,9 @@ def test_the_kernels_compute_the_two_explicit_softmaxes(model, monkeypatch):
 # ------------------------------------------------------------ the engine
 # two slots and groups of one and two rows: three requests put the third
 # in a REUSED slot
-_presets = family.presets({"phi4flash_toy": toy})
+_presets = family.presets({
+    "phi4flash_toy": toy,
+    "phi4flash_wide": lambda **kw: toy(ssm_inner=1024, **kw)})
 engine = family.engines("phi4flash_toy", max_slots=2, max_len=64,
                         prefill_groups=(1, 2))
 
@@ -275,8 +277,8 @@ def test_prefill_then_decode_through_the_cache_is_the_reference(
     each, so the third request is served in a REUSED slot and inherits no
     state, ring row or memory; the flash forward prefills the longer
     bucket.  Every emitted token is the reference's leading one to within
-    float32 rounding.  The spans carry the skipped positions and the shared
-    pool's reads."""
+    float32 rounding.  The spans carry the skipped positions, the shared
+    pool's reads and the positions the scan's kernel took."""
     cfg, params = model
     monkeypatch.setattr(llama, "FLASH_PREFILL_FROM", 16)
     monkeypatch.setattr(flash, "DEFAULT_BLOCK", 16)
@@ -309,6 +311,63 @@ def test_prefill_then_decode_through_the_cache_is_the_reference(
         assert c["state_rows_updated"] == c["k"] * c["active"]
     pools = server.kv_stats()["kv_pools"]
     assert set(pools) >= {"kv_full", "kv_window"}
+    # the toy's 128 channels keep XLA's loop: no position went through
+    # ``ops/mamba1_scan.py`` (the kernel's side: the test below)
+    assert all(g["mamba1_scan_positions"] == 0 for g in groups)
+    assert "pallas_call" not in _mixers_jaxpr(cfg, 20)
+
+
+def _mixers_jaxpr(cfg, bucket, rows=1):
+    """What ``mamba1.prefill`` traces to for ``rows`` rows of ``bucket``
+    positions: the program text that says which form of the scan a prefill
+    of this geometry holds."""
+    part = cfg.parts()[0][0]
+    params = jax.eval_shape(
+        lambda k: llama.init_params(k, cfg, jnp.float32), jax.random.key(0))
+    layer = {k: jax.ShapeDtypeStruct(v.shape[1:], v.dtype)
+             for k, v in params["layers"].items() if k.startswith("ssm_")}
+    return str(jax.make_jaxpr(
+        lambda h, layer, lengths: mamba1.prefill(h, layer, part, lengths))(
+            jax.ShapeDtypeStruct((rows, bucket, cfg.hidden_size),
+                                 jnp.float32),
+            layer, jax.ShapeDtypeStruct((rows,), jnp.int32)))
+
+
+def test_an_engine_whose_channels_are_whole_blocks_serves_through_the_kernel(
+        tokens, traced, engine):
+    """1,024 channels, ONE block of ``ops/mamba1_scan.py``: the same engine's
+    prefill program holds the kernel, its tokens are the reference's, and
+    ``serve.prefill_group`` counts the positions the kernel covered -- the
+    bucket in whole groups of 8 x the three Mamba-1 layers, from a real
+    launch (the span's rule and the program's are one ``engages``).  A group
+    of several rows keeps the loop (PERF.md section 6 (g), PR 62)."""
+    from ray_tpu.ops import mamba1_scan
+
+    cfg = toy(ssm_inner=1024)
+    assert mamba1_scan.engages(cfg.ssm_inner, 1)
+    params = family.init_params(jax.random.key(0), cfg, jnp.float32)
+    # a server of its own: another preset, one slot, one bucket of 20
+    server = engine(model_preset="phi4flash_wide", params=params,
+                    max_slots=1, prefill_groups=(1,), prefill_buckets=(20,),
+                    fresh=True)
+    prompt = tokens[1, :13].tolist()
+    reply, = family.generate(server, [{"prompt": prompt,
+                                       "max_new_tokens": 6}])
+    family.settle(server)
+    server.shutdown()
+    gaps = reference.teacher_forced_gap(
+        params, prompt, reply["tokens"], {**PUBLISHED, "mamba_expand": 16})
+    assert float(np.max(gaps)) < 1e-3
+    text = _mixers_jaxpr(cfg, 20)
+    assert "pallas_call" in text and "mamba1_scan" in text
+    assert not mamba1_scan.engages(cfg.ssm_inner, 2)
+    assert "pallas_call" not in _mixers_jaxpr(cfg, 20, rows=2)
+    groups = family.span_args(traced.export_timeline(),
+                              "serve.prefill_group")
+    assert mamba1_scan.padded_len(20) == 24
+    assert groups and all(
+        g["mamba1_scan_positions"] == g["rows_padded"] * 24 * 3
+        and g["bucket"] == 20 for g in groups)
 
 
 def _walk_distance(cfg, params, tokens):
