@@ -263,28 +263,28 @@ def _sorted_ffn(c: MoEConfig, training: bool, xt, w_gate, w_up, w_down,
         else:
             act = guarded(
                 c.act(_grouped(rows, w_up, group_sizes)).astype(dt))
-        # (T*K, D) float32; a share's in ``dt``: three in four of its rows
-        # are not computed, and at a 12,288-token prompt's 73,728 rows of
-        # 5,120 the float32 result and its un-sorted copy are 2.8 GB
+        # (T*K, D) float32 (the benchmark's test of cell 5 finds its three
+        # kernels as ``f32[``); a share's in ``dt``: 73,728 rows of 5,120,
+        # three in four of them not computed, are 1.5 GB in float32
         out = _grouped(act, w_down, group_sizes,
                        dt if c.held else jnp.float32)
-    # Un-sort (order is a permutation) and sum under the gates.  Rows
-    # past the last group were not computed: select, do not multiply.
+    # Un-sort (order is a permutation) and sum under the gates in float32:
+    # K gathers of (T, D), a token's k-th result from wherever the sort put
+    # it, written in the stream's type.  Un-sorted whole, every row is copied
+    # once more before the sum: as (T, K, D) the chip pads K = 6 to a
+    # sublane tile, as (T, K x D) a row changes its tile.
     with jax.named_scope("expert_dispatch"):
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(T * K, dtype=order.dtype), unique_indices=True)
-        if not c.held:
-            out = out[inverse].reshape(T, K, D)
-            return jnp.sum(out * gate_vals[..., None], axis=1)
-        # A token's K results side by side, (T, K x D): as (T, K, D)
-        # the chip pads K = 6 to a sublane tile.  An assignment
-        # elsewhere was not computed: select.
-        out = out[inverse].reshape(T, K * D)
-        computed = (group_of_row < c.held[1]).reshape(T, K)
-        return sum(
-            jnp.where(computed[:, k, None], out[:, k * D:(k + 1) * D],
-                      0).astype(jnp.float32) * gate_vals[:, k, None]
-            for k in range(K))
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(
+            T * K, dtype=order.dtype), unique_indices=True).reshape(T, K)
+
+        def result(k):
+            mine = out[inverse[:, k]].astype(dt)
+            if c.held:    # an assignment elsewhere was not computed: select
+                here = group_of_row.reshape(T, K)[:, k, None] < c.held[1]
+                mine = jnp.where(here, mine, 0)
+            return mine.astype(jnp.float32) * gate_vals[:, k, None]
+
+        return sum(result(k) for k in range(K))
 
 
 def _block(c: MoEConfig, b, xt, gate_vals, order, group_sizes):

@@ -373,8 +373,8 @@ def test_the_four_shares_of_an_expert_layer_sum_to_the_uncut_layer(
 
 
 @pytest.mark.parametrize("program,ragged_dots,conds,sha", [
-    ("prefill", 3, 0, "15c0464d743f9330"),
-    ("decode_k", 3, 10, "40575bf467613ecb")], ids=["prefill", "decode_k"])
+    ("prefill", 3, 0, "7e7c61cf5af878b4"),
+    ("decode_k", 3, 10, "3643d1604ed59a9b")], ids=["prefill", "decode_k"])
 def test_serving_a_quarter_share_lowers_no_compact_dispatch(
         program, ragged_dots, conds, sha):
     """A share that TRAINING dispatches compactly (4 of 16 experts: twice
@@ -382,7 +382,10 @@ def test_serving_a_quarter_share_lowers_no_compact_dispatch(
     served by the programs the parent commit (47a1d40, PR 57) lowered: as
     many grouped matmuls, no conditional more, the same text by sha256
     (``decode_k``'s since PR 61: its 4 slots x top-3 = 12 sorted rows are
-    gathered as 16, whole sublane tiles; cell 8's 32 x 6 are whole already).
+    gathered as 16, whole sublane tiles; cell 8's 32 x 6 are whole already;
+    both since PR 63: a token's k-th result is gathered straight into the
+    sum, by the one path every configuration takes, where a share un-sorted
+    all its rows and reshaped them to (T, K x D) first).
     The loop over blocks exists where ``training`` asks for it alone."""
     import hashlib
     import re

@@ -28,8 +28,11 @@ CELL = "solar-open2-250b.serve-long-prompt"
 # commit before the kernel (1de62b7), without the Mosaic kernels'
 # serialized bodies: those carry the checkout's own file paths, and the
 # two files they are built from (``ops/decode_attention.py``,
-# ``ops/kda_state_update.py``) are that commit's
-DECODE_BEFORE = "ced9784b7a45767a"
+# ``ops/kda_state_update.py``) are that commit's -- and since PR 63 with the
+# expert layer's one un-sort and sum, which a share takes too: a token's k-th
+# result is gathered straight into the float32 sum (``moe._sorted_ffn``; at
+# c19b209, before it, the text was that commit's still, ced9784b7a45767a)
+DECODE_BEFORE = "4e700bf7e6e82721"
 MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[^\\]*\\22')
 
 

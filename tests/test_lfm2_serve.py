@@ -508,7 +508,11 @@ def test_spans_counters_and_the_conv_pool(engine):
 # as the PARENT commit (124731d, PR 39) lowered them -- but for both
 # ``decode_k`` texts since PR 61: 4 slots x top-3 are 12 sorted rows, not
 # whole sublane tiles, and ``moe._sorted_ffn`` now gathers 16 (the cells'
-# rows are whole tiles and their programs lower to the text they did).
+# rows are whole tiles and their programs lower to the text they did); and
+# for all four texts since PR 63: ``moe._sorted_ffn`` gathers a token's k-th
+# result straight into the float32 sum, held or whole, in the stream's type
+# (the un-sorted copy of every row, which a share reshaped to (T, K x D) and
+# a whole configuration to a float32 (T, K, D), is gone).
 _YARN = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
          "mscale": 0.707, "mscale_all_dim": 0.707,
          "original_max_position_embeddings": 16}
@@ -520,7 +524,7 @@ _BEFORE = {
         window_size=8, layer_pattern=("attention", "window", "window",
                                       "window"),
         nope_kinds=("attention",), tie_embeddings=False, max_seq_len=64),
-        "b30e9e97101338d5", "655b9a1e885b515f"),
+        "05a755b177536528", "38e267d825665734"),
     "latent_share": (dict(
         vocab_size=256, hidden_size=64, n_layers=3, n_heads=4, n_kv_heads=4,
         head_dim=24, intermediate_size=128, max_seq_len=64,
@@ -531,7 +535,7 @@ _BEFORE = {
         moe_norm_topk=False, moe_intermediate_size=32, moe_shared_size=64,
         moe_groups=4, moe_top_groups=2, moe_routed_scale=16.0,
         moe_held=(0, 8)),
-        "58dc65ea57dddac9", "ee14c78dd71c11ca"),
+        "d1b60ebf6084b9a7", "c3e0487b90f1cd7d"),
 }
 
 

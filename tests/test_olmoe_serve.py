@@ -206,6 +206,112 @@ def test_every_token_reaches_its_experts_where_a_capacity_would_drop():
     assert np.abs(want.reshape(32, 32)).max(-1).min() > 0
 
 
+# ------------------------------------------ one un-sort and sum, every top-k
+def _layer_of_experts(top_k, dtype, held=()):
+    """(config, params, x (2, 12, 32)): 16 experts of 32 x 64, all of them
+    or the share ``held``; 24 tokens, so ``T x K`` is 96 / 144 / 192 rows
+    (top-6 and top-4 are what a chip pads to a sublane tile as (T, K, D))."""
+    mcfg = moe.MoEConfig(hidden_size=32, intermediate_size=64, n_experts=16,
+                         top_k=top_k, norm_topk=True, dtype=dtype, held=held)
+    params = moe.init_moe_params(jax.random.key(top_k), mcfg)
+    if held:
+        first, count = held
+        params = {k: v if k == "router" else v[first:first + count]
+                  for k, v in params.items()}
+    return mcfg, params, jax.random.normal(jax.random.key(1), (2, 12, 32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("how", ["every_row", "valid", "layer_index"])
+@pytest.mark.parametrize("top_k", [4, 6, 8])
+def test_whole_expert_layer_agrees_with_the_reference(top_k, how, dtype):
+    """``moe_ffn_dropless`` of a configuration that holds all its experts
+    against the per-token reference in the same type: with every row real,
+    with a ``valid`` mask (the rows that are not real come out zero and
+    take no expert's row) and as layer 1 of a stack of three."""
+    mcfg, params, x = _layer_of_experts(top_k, dtype)
+    x = x.astype(dtype)
+    want = np.asarray(moe.moe_ffn_reference(x, params, mcfg), np.float32)
+    real = 24
+    if how == "every_row":
+        got, _aux, rows = jax.jit(
+            lambda x: moe.moe_ffn_dropless(x, params, mcfg))(x)
+    elif how == "valid":
+        valid = jax.random.bernoulli(jax.random.key(2), 0.6, (2, 12))
+        real = int(valid.sum())
+        assert 0 < real < 24
+        want = np.where(np.asarray(valid)[..., None], want, 0.0)
+        got, _aux, rows = jax.jit(
+            lambda x, valid: moe.moe_ffn_dropless(x, params, mcfg, valid))(
+                x, valid)
+    else:
+        below, above = (moe.init_moe_params(jax.random.key(50 + i), mcfg)
+                        for i in range(2))
+        stack = {k: v if k == "router" else jnp.stack([below[k], v, above[k]])
+                 for k, v in params.items()}
+        got, _aux, rows = jax.jit(
+            lambda x, layer: moe.moe_ffn_dropless(
+                x, stack, mcfg, layer_index=layer))(x, jnp.int32(1))
+    assert got.dtype == dtype and got.shape == x.shape
+    assert rows.shape == (16,) and int(rows.sum()) == real * top_k
+    got = np.asarray(got, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    else:
+        # a result row is rounded to bfloat16 once in either form (on its
+        # way out of the matmul, of the un-sort); the sum under the gates is
+        # float32 in both
+        assert np.abs(got - want).max() <= 2 ** -7 * np.abs(want).max()
+
+
+def _every_equation(jaxpr):
+    """The equations of ``jaxpr``, those inside its equations too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _every_equation(inner)
+
+
+@pytest.mark.parametrize("held", [(), (4, 8)], ids=["whole", "held"])
+@pytest.mark.parametrize("top_k", [4, 6, 8])
+def test_a_serving_expert_layer_keeps_no_unsorted_copy_of_its_rows(
+        top_k, held):
+    """A result row of the grouped matmuls is written once and gathered
+    once, as its token's k-th result and in the stream's type, into the
+    float32 sum, held or whole: the traced program has no un-sorted array
+    of all ``T x K`` rows -- not ``(T, K, D)`` (on the chip ``K`` = 4 or 6
+    there is padded to 8), not ``(T, K x D)`` (every row in another tile
+    than it was written in), not ``(T x K, D)`` a second time -- and what
+    leaves a gather in float32 is cast before anything else reads it.
+    With and without a ``valid`` mask."""
+    mcfg, params, x = _layer_of_experts(top_k, jnp.bfloat16, held)
+    T, D = 24, 32
+    for valid in (None, jnp.ones((2, 12), bool)):
+        eqns = list(_every_equation(jax.make_jaxpr(
+            lambda x: moe.moe_ffn_dropless(x, params, mcfg, valid))(x).jaxpr))
+        made = [(eqn.primitive.name, tuple(eqn.outvars[0].aval.shape),
+                 eqn.outvars[0].aval.dtype.name) for eqn in eqns]
+        shapes = {shape for _op, shape, _dtype in made}
+        assert not {(T, top_k, D), (T, top_k * D), (top_k, T, D)} & shapes
+        # all the rows at once: gathered in, and out of the third matmul
+        # (float32 where the benchmark's cell test holds that: a whole
+        # configuration's) -- nothing else
+        assert [(op, dtype) for op, shape, dtype in made
+                if shape == (T * top_k, D)] == [
+            ("gather", "bfloat16"),
+            ("ragged_dot_general", "bfloat16" if held else "float32")]
+        assert sum(op == "gather" and shape == (T, D)
+                   for op, shape, _dtype in made) == top_k
+        for i, eqn in enumerate(eqns):
+            if made[i] == ("gather", (T, D), "float32"):
+                readers = [e for e in eqns if eqn.outvars[0] in e.invars]
+                assert [e.primitive.name for e in readers] == [
+                    "convert_element_type"]
+                assert readers[0].outvars[0].aval.dtype.name == "bfloat16"
+        assert ("add", (T, D), "float32") in made   # the sum under the gates
+
+
 @pytest.mark.parametrize("top_k", [2, 3])
 def test_skewed_router_agrees_with_the_reference(top_k):
     cfg, params = _model(top_k, zero_router=True)

@@ -409,7 +409,10 @@ def test_spans_counters_and_pools_for_a_windowed_model_and_only_for_one(
 # PERF.md section 6, PR 61) and for ``olmoe_like``'s ``decode_k``, whose 4
 # slots x top-3 are 12 sorted rows and not whole sublane tiles:
 # ``moe._sorted_ffn`` now gathers 16 (every benchmark cell's rows are whole
-# tiles and their programs the text they were).
+# tiles and their programs the text they were); PR 63 did for the two expert
+# configurations' four programs (``moe._sorted_ffn`` has one un-sort-and-sum:
+# a token's k-th result is gathered straight into the float32 sum, in the
+# stream's type; no un-sorted float32 (T, K, D) copy of every row).
 _BEFORE = {
     "dense": (LlamaConfig.debug,
               {}, "9cea23cd675cc71a", "451d4209906be1e0"),
@@ -417,10 +420,10 @@ _BEFORE = {
                          dict(tie_embeddings=False, n_kv_heads=1),
                          "5b383136284f778a", "836058ef106088be"),
     "moe": (LlamaConfig.moe_debug, {},
-            "a6acd292520feb78", "488ee7a67f842d7e"),
+            "fd31f33e37df1817", "d6e027ba0b02138a"),
     "olmoe_like": (LlamaConfig.moe_debug,
                    dict(moe_norm_topk=False, qk_norm=True, moe_top_k=3),
-                   "75aefc941011707b", "999bb3ff565da99f"),
+                   "03c185f81e225cd6", "d155c85388c16566"),
     "hybrid": (LlamaConfig.hybrid_debug, {},
                "1cb35f28ca05d404", "e877b22e6a56c290"),
     "hybrid_f32_stream": (LlamaConfig.hybrid_debug,
@@ -471,8 +474,8 @@ def test_the_configurations_before_build_and_lower_what_they_did(name):
     assert (_sha(prefill), _sha(decode)) == (prefill_sha, decode_sha)
 
 
-@pytest.mark.parametrize("rows,prefill_sha", [(1, "d23f00925e2d73ab"),
-                                              (2, "7036e14381dfafce")])
+@pytest.mark.parametrize("rows,prefill_sha", [(1, "0135664d0f36f3b4"),
+                                              (2, "b9e053996451c05f")])
 def test_the_windowed_prefills_lowered_text_is_recorded(rows, prefill_sha):
     """The insert is one function with one shape rule for both layouts of
     a pool since PR 33: a member is cut out of the group as a slice.  A
@@ -482,6 +485,7 @@ def test_the_windowed_prefills_lowered_text_is_recorded(rows, prefill_sha):
     recorded here differs from that in the insert's reshape, slice and
     select (and the numbering of what follows them), and at cell 7's real
     widths both compile for a v5e to the same optimised HLO, instruction
-    for instruction (AOT, PR 33: PERF.md section 6)."""
+    for instruction (AOT, PR 33: PERF.md section 6).  Since PR 63 the text
+    is the expert layer's one un-sort and sum (``_BEFORE``'s comment)."""
     cfg = _cfg()
     assert _sha(_lowered_prefill(cfg, *_abstract(cfg), rows)) == prefill_sha
