@@ -193,7 +193,13 @@ def run(ctx: runtime.Context) -> Dict[str, Any]:
         "no compilation in the window": marks["window_compiles"] == 0,
     }
     obs.update({
-        "checks": checks, "attempted": len(measured),
+        "checks": checks,
+        # each number ``correct`` compares, beside its limit
+        "compared": {
+            "logit_gap_max": [obs["logit_gap_max"], LOGIT_MARGIN],
+            "requests_incomplete": [len(measured) - len(complete), 0],
+            "window_compiles": [marks["window_compiles"], 0]},
+        "attempted": len(measured),
         "failed": len(measured) - len(complete),
         "setup_s": marks["t_open"] - ctx.t_process,
         "log": log, "measured": measured,
@@ -201,8 +207,6 @@ def run(ctx: runtime.Context) -> Dict[str, Any]:
         "window_compiles": marks["window_compiles"],
         "program_window_compiles": program_close["xla_compiles"]
         - marks["program_open"]["xla_compiles"],
-        "program_setup_compile_s":
-            marks["program_open"]["xla_compile_seconds"],
         "trace": runtime.read_trace(ctx),
         "trace_span": [tracer.t_start, tracer.t_stop],
         "decode_chunk": engine.engine.get("decode_chunk", 16),

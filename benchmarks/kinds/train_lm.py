@@ -105,13 +105,11 @@ def _loop(config: Dict[str, Any]) -> None:
         "batch_sharding": str(token_sharding),
         "state_dtypes": sorted({str(x.dtype)
                                 for x in jax.tree.leaves(state["params"])}),
-        "compile_s_before_first_step": watch.seconds,
     }
 
     # First step: compiles (or fetches), then the checks on live state.
     old_leaves = jax.tree.leaves(state)
     old_shardings = [leaf.sharding for leaf in old_leaves]
-    obs["t_first_step_launch"] = time.perf_counter()
     state, metrics = step(state, batch)
     losses = [float(metrics["loss"])]
     obs["grad_norm_first"] = float(metrics["grad_norm"])
@@ -130,10 +128,12 @@ def _loop(config: Dict[str, Any]) -> None:
     compiles_open = watch.count
     program_open = runtime.program_counters()
     waits["s"] = 0.0
-    setup_compile_s = program_open["xla_compile_seconds"]
     jax.block_until_ready(state)
     t_open = t_mark = time.perf_counter()
-    groups = []
+    # The steps' own ``expert_rows`` (a model with experts: what its grouped
+    # matmuls had to do), kept as they lie on the device and fetched after
+    # the window: the traced group's, else the last step's.
+    groups, expert_rows = [], []
     while True:
         # Group after group with no gap between them, except around the
         # one traced group: starting and stopping the profiler is not
@@ -144,6 +144,8 @@ def _loop(config: Dict[str, Any]) -> None:
             t_mark = time.perf_counter()
         for _ in range(every):
             state, metrics = step(state, next(feed))
+            if traced:
+                expert_rows.append(metrics.get("expert_rows"))
         loss = float(metrics["loss"])          # the user's log line: waits
         t_end = time.perf_counter()
         groups.append({"steps": every, "t_start": t_mark, "t_end": t_end,
@@ -157,12 +159,18 @@ def _loop(config: Dict[str, Any]) -> None:
                 not (tracer.enabled and len(groups) < 2):
             break
     t_close = time.perf_counter()
+    expert_rows = [np.asarray(rows) for rows in
+                   expert_rows or [metrics.get("expert_rows")]
+                   if rows is not None]
     obs.update({
+        # (expert layers, experts the router scores): rows a step, the mean
+        # over the steps kept; None for a model without experts
+        "expert_rows": np.mean(expert_rows, axis=0).tolist()
+        if expert_rows else None,
         "t_open": t_open, "t_close": t_close,
         "groups": groups, "losses_warmup": losses,
         "input_wait_s": waits["s"],
         "window_compiles": watch.count - compiles_open,
-        "program_setup_compile_s": setup_compile_s,
         "program_window_compiles":
             runtime.program_counters()["xla_compiles"]
             - program_open["xla_compiles"],
@@ -251,6 +259,13 @@ def run(ctx: runtime.Context) -> Dict[str, Any]:
         json.dump(obs["groups"], f)
     return {
         "kind": "train_lm", "checks": checks,
+        # each number ``correct`` compares, beside its limit
+        "compared": {
+            "loss_gap": [loss_gap, LOSS_TOL],
+            "grad_norm_gap": [grad_norm_gap, GRAD_NORM_TOL],
+            "grad_leaf_gap_max": [max(obs["grad_leaf_gaps"].values()),
+                                  GRAD_LEAF_TOL],
+            "window_compiles": [obs["window_compiles"], 0]},
         "attempted": steps,
         "failed": 0 if checks["losses finite"] else steps,
         "setup_s": obs["t_open"] - ctx.t_process,
@@ -261,9 +276,7 @@ def run(ctx: runtime.Context) -> Dict[str, Any]:
         "window_compiles": obs["window_compiles"],
         **{k: obs[k] for k in (
             "groups", "t_open", "t_close", "input_wait_s", "memory",
-            "t_first_step_launch", "compile_s_before_first_step",
-            "program_setup_compile_s", "reference_loss",
-            "reference_grad_norm", "grad_leaf_gaps", "reference_s",
-            "batch_sharding",
+            "expert_rows", "reference_loss", "reference_grad_norm",
+            "grad_leaf_gaps", "reference_s", "batch_sharding",
             "grad_norm_first", "program_window_compiles")},
     }

@@ -9,7 +9,12 @@ published keys; ``share`` says which of the routed experts this chip
 holds).  A multiply-add counts as 2 FLOPs.  ``lib/flops.py`` counts every
 layer a dense SwiGLU and every layer's attention the whole causal square;
 here a layer is what its entry of ``layer_types`` and its place before or
-after ``num_dense_layers`` say.
+after ``num_dense_layers`` say.  A configuration's file names this module
+under ``train_counts`` and ``readers.train_mfu`` /
+``readers.flash_attention_roofline`` divide by its counts; what the grouped
+matmuls alone must do in a step is ``lib/moe_flops.py``'s
+(``expert_matmul_train_flops`` / ``_bytes``), every configuration's with
+experts.
 """
 
 from __future__ import annotations
@@ -102,24 +107,3 @@ def flash_train_flops(c: Dict[str, Any], batch: int, seq: int) -> float:
     dV, dP, dQ, dK) = 2.5 x forward.  The kernels' own recompute of the
     scores is part of the flash algorithm and is counted."""
     return 3.5 * batch * attention_pairs(c, seq) * flops_per_pair(c)
-
-
-def expert_matmul_train_flops(c: Dict[str, Any], rows: float) -> float:
-    """The grouped matmuls of one step over the ``rows`` the held experts
-    computed in all expert layers together (what the router sent here: under
-    random weights far from ``held_rows_per_token`` x tokens, PERF.md section
-    6, PR 57): three forward, and for each its two backward products (the
-    rows' and the weights' cotangents) = 3 x forward.  No recomputation."""
-    return 3.0 * 2.0 * rows * expert_params(c)
-
-
-def expert_matmul_train_bytes(c: Dict[str, Any], rows: float,
-                              dtype_bytes: int = 2) -> float:
-    """Their least HBM traffic: the held experts' matrices read once
-    forward and once backward, their float32 gradient written once, and the
-    rows in and out of each pass at the stream's width."""
-    held = (c.get("share") or {}).get("experts_held", c["num_experts"])
-    layers = c["num_hidden_layers"] - c["num_dense_layers"]
-    weights = layers * held * expert_params(c)
-    return float(weights * (2 * dtype_bytes + 4)
-                 + 4 * rows * c["hidden_size"] * dtype_bytes)

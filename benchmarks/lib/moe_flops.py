@@ -15,7 +15,12 @@ about the experts (``expert_width``, ``expert_layers``,
 keys that say them whatever the family -- ``moe_intermediate_size``
 where a configuration has a dense width beside its experts', and what
 the program is given under ``program_fields`` (``first_dense_layers``,
-``moe_experts``, ``moe_held``).
+``moe_experts``, ``moe_held``).  An expert that is not three matrices of
+stream x width, or expert layers that are not all but the leading dense
+ones, the file states under ``expert_shape``: ``{"matrices": 2,
+"row_width": 1024, "layers": 5}`` (any of the three; what is absent is
+counted as above) -- two matrices an expert, a row that enters and leaves
+1,024 wide (a latent), five layers with experts among those held.
 """
 
 from __future__ import annotations
@@ -37,10 +42,27 @@ def expert_width(c: Dict[str, Any]) -> int:
     return c.get("moe_intermediate_size", c["intermediate_size"])
 
 
+def _stated(c: Dict[str, Any], key: str, otherwise):
+    return (c.get("expert_shape") or {}).get(key, otherwise)
+
+
 def expert_layers(c: Dict[str, Any]) -> int:
-    """Layers that have experts: all but the leading dense ones."""
-    return c["num_hidden_layers"] \
-        - c.get("program_fields", {}).get("first_dense_layers", 0)
+    """Layers that have experts: all but the leading dense ones, or what
+    the file states."""
+    return _stated(c, "layers", c["num_hidden_layers"]
+                   - c.get("program_fields", {}).get("first_dense_layers", 0))
+
+
+def expert_matrices(c: Dict[str, Any]) -> int:
+    """Matrices of one expert: gate, up and down, or what the file
+    states (two where an expert has no gate)."""
+    return _stated(c, "matrices", 3)
+
+
+def expert_row_width(c: Dict[str, Any]) -> int:
+    """The width a row enters an expert and leaves it with: the stream's,
+    or what the file states (a latent's)."""
+    return _stated(c, "row_width", c["hidden_size"])
 
 
 def experts_held(c: Dict[str, Any]) -> int:
@@ -52,8 +74,8 @@ def experts_held(c: Dict[str, Any]) -> int:
 
 
 def expert_params(c: Dict[str, Any]) -> int:
-    """The three matrices of ONE expert of one layer."""
-    return 3 * c["hidden_size"] * expert_width(c)
+    """The matrices of ONE expert of one layer."""
+    return expert_matrices(c) * expert_row_width(c) * expert_width(c)
 
 
 def dense_matmul_params_per_layer(c: Dict[str, Any]) -> int:
@@ -86,19 +108,40 @@ def active_params(c: Dict[str, Any], layers: int = None) -> int:
 
 def expert_matmul_bytes(c: Dict[str, Any], experts_touched: float,
                         expert_rows: float, dtype_bytes: int = 2) -> float:
-    """Least HBM traffic of the grouped matmuls alone: the three
-    matrices of each (layer, expert) pair that has a row, once, and each
-    row's activations (in at width h twice, the hidden row of width f
-    out twice and in once, out at width h once)."""
-    h, f = c["hidden_size"], expert_width(c)
+    """Least HBM traffic of the grouped matmuls alone: the matrices of
+    each (layer, expert) pair that has a row, once, and each row's
+    activations, a read or a write of it at each end of every matrix (of
+    three: in at width h twice, the hidden row of width f out twice and
+    in once, out at width h once)."""
+    h, f = expert_row_width(c), expert_width(c)
     return (experts_touched * expert_params(c)
-            + expert_rows * (3 * h + 3 * f)) * dtype_bytes
+            + expert_rows * (expert_matrices(c) * (h + f))) * dtype_bytes
 
 
 def expert_matmul_flops(c: Dict[str, Any], expert_rows: float) -> float:
-    """``expert_rows`` (token, expert) assignments through three
+    """``expert_rows`` (token, expert) assignments through an expert's
     matrices of h x f."""
     return 2.0 * expert_rows * expert_params(c)
+
+
+def expert_matmul_train_flops(c: Dict[str, Any], rows: float) -> float:
+    """The grouped matmuls of one TRAIN step over the ``rows`` the held
+    experts computed in all expert layers together (what the router sent
+    here: under random weights far from an even share, PERF.md section 6,
+    PR 57): the forward's, and for each matrix its two backward products
+    (the rows' and the weights' cotangents) = 3 x forward.  No
+    recomputation."""
+    return 3.0 * expert_matmul_flops(c, rows)
+
+
+def expert_matmul_train_bytes(c: Dict[str, Any], rows: float,
+                              dtype_bytes: int = 2) -> float:
+    """Their least HBM traffic: the held experts' matrices read once
+    forward and once backward, their float32 gradient written once, and
+    the rows in and out of each pass at the width they enter with."""
+    weights = expert_layers(c) * experts_held(c) * expert_params(c)
+    return float(weights * (2 * dtype_bytes + 4)
+                 + 4 * rows * expert_row_width(c) * dtype_bytes)
 
 
 def decode_step_bytes(c: Dict[str, Any], experts_touched: float,

@@ -1,10 +1,13 @@
 """Parameters, operations and bytes the ALGORITHM of a Nemotron-H stack needs
 (blocks of ONE sub-layer each: a Mamba-2 mixer with groups, softmax
 attention, or a LatentMoE feed-forward part), from shapes alone.  The
-yardstick's own arithmetic: nothing here is read from the program, and none
-of ``lib/moe_flops.py``'s or ``lib/ssm_flops.py``'s counts is used: an
-expert there is three full-width matrices and every layer an expert layer,
-and the state-space keys there are Granite's names.
+yardstick's own arithmetic: nothing here is read from the program.  This
+file counts the stack's parameters, its pools and its WHOLE decode step;
+what the grouped matmuls and the state update alone must do is
+``lib/moe_flops.py``'s and ``lib/ssm_flops.py``'s, every configuration's
+with experts or a state-space mixer, at the shapes the file states under
+``expert_shape`` (two matrices an expert, rows 1,024 wide, five expert
+blocks) and ``ssm_shape`` (the mixer's, five Mamba blocks).
 
 A configuration is the dict of ``benchmarks/configs/<name>.json``: the
 published ``config.json`` keys (``hybrid_override_pattern``,
@@ -39,6 +42,8 @@ one shared expert of 5,376 at full width; 11 blocks ``MEMEMEM*EME`` = 5 M +
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Sequence
+
+from . import moe_flops, ssm_flops
 
 _ITEMSIZE = {"bfloat16": 2, "float32": 4}
 
@@ -130,38 +135,6 @@ def slot_bytes(c: Dict[str, Any], max_len: int) -> Dict[str, int]:
             * _ITEMSIZE[c["dtype"]["serve"]]}
 
 
-# ------------------------------------------------------ the two kernels
-def expert_matmul_bytes(c: Dict[str, Any], experts_touched: float,
-                        expert_rows: float, dtype_bytes: int = 2) -> float:
-    """Least HBM traffic of the grouped matmuls alone: the two matrices of
-    each (block, expert) pair that has a row, once, and each row's
-    activations (in at the latent's width, the hidden row of the expert's
-    width out and in, out at the latent's width)."""
-    r, f = c["moe_latent_size"], c["moe_intermediate_size"]
-    return (experts_touched * expert_params(c)
-            + expert_rows * (2 * r + 2 * f)) * dtype_bytes
-
-
-def expert_matmul_flops(c: Dict[str, Any], expert_rows: float) -> float:
-    """``expert_rows`` (token, expert) assignments through two matrices of
-    latent x width."""
-    return 2.0 * expert_rows * expert_params(c)
-
-
-def state_update_bytes(c: Dict[str, Any], rows: float) -> float:
-    """Least HBM traffic of the recurrence's update of ``rows`` (slot,
-    step) pairs: each advanced slot's state once in and once out a Mamba
-    block."""
-    return 2.0 * rows * block_counts(c)["M"] * state_bytes(c)
-
-
-def state_update_flops(c: Dict[str, Any], rows: float) -> float:
-    """Per state element and row: the decay (1), B x dtx and its add (2),
-    C x S and its sum (2)."""
-    nh, hd, n, _g, _d, _conv, _k = mamba_dims(c)
-    return 5.0 * rows * block_counts(c)["M"] * nh * hd * n
-
-
 # ------------------------------------------------------- the whole step
 def attention_flops(c: Dict[str, Any], lengths: Sequence[float]) -> float:
     """QK^T and PV of one step over the attention blocks."""
@@ -186,19 +159,19 @@ def decode_step_bytes(c: Dict[str, Any], lengths: Sequence[float],
 def decode_step_flops(c: Dict[str, Any], lengths: Sequence[float],
                       expert_rows: float) -> float:
     return (2.0 * dense_matmul_params(c) * len(lengths)
-            + expert_matmul_flops(c, expert_rows)
+            + moe_flops.expert_matmul_flops(c, expert_rows)
             + attention_flops(c, lengths)
-            + state_update_flops(c, len(lengths)))
+            + ssm_flops.state_update_flops(c, len(lengths)))
 
 
 def decode_step_least_s(obs) -> Optional[float]:
     """Least seconds of one WHOLE decode step at the rows in flight at the
     middle of the traced span and the experts its steps touched (HBM bytes
     or FLOPs at peak, the larger); None where the run says neither."""
-    from . import nemotron_names, swa_names   # what the run observed
+    from . import moe_names, swa_names   # what the run observed
 
     lengths = swa_names._traced_lengths(obs)
-    load = nemotron_names.expert_load_a_step(obs)
+    load = moe_names.chunk_medians(obs)
     if lengths is None or load is None:
         return None
     rows, touched, _ = load

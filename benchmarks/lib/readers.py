@@ -5,7 +5,6 @@ or None where there is nothing to read (the metric is then left out)."""
 
 from __future__ import annotations
 
-import re
 import statistics
 from typing import List, Optional
 
@@ -18,9 +17,6 @@ FAILED_REQUEST_MS = 120_000.0   # a failed request counts as the worst
 TRAIN_STEP_MODULE = r"^jit_step\b"
 DECODE_MODULE = r"^jit_decode_k\b"
 PREFILL_MODULE = r"^jit_prefill\b"
-# The train step's only Mosaic kernels are flash attention's (forward,
-# dq, dk/dv); the trace does not carry a kernel's own name.
-FLASH_KERNEL_OP = re.escape('custom_call_target="tpu_custom_call"')
 
 
 # ------------------------------------------------------------------ train
@@ -33,12 +29,27 @@ def train_tokens_per_s_per_chip(obs) -> Optional[float]:
     return tokens / seconds / obs["chips"]
 
 
+def train_counts(cell):
+    """The module that counts what a TRAIN step of the configuration
+    needs (``train_flops_per_token``, ``flash_train_flops``,
+    ``flash_train_bytes``): the one of ``benchmarks/lib/`` its file names
+    under ``train_counts``, else ``lib/flops.py`` (a dense decoder, every
+    layer's attention the whole causal square)."""
+    name = cell.config.get("train_counts")
+    if not name:
+        return flops
+    counts = spec.load_module("lib", name, cell.bench_dir)
+    if counts is None:
+        raise spec.SpecError(f"{cell.name}: no benchmarks/lib/{name}.py")
+    return counts
+
+
 def train_mfu(obs) -> Optional[float]:
     rate = train_tokens_per_s_per_chip(obs)
     if rate is None:
         return None
-    per_token = flops.train_flops_per_token(obs["cell"].config,
-                                            obs["seq_len"])
+    per_token = train_counts(obs["cell"]).train_flops_per_token(
+        obs["cell"].config, obs["seq_len"])
     return 100.0 * rate * per_token / obs["peaks"]["bf16_flops_per_s"]
 
 
@@ -70,29 +81,43 @@ def _share_of_steps(obs, seconds: float) -> Optional[float]:
     return 100.0 * seconds / sum(e - s for s, e, _ in runs)
 
 
+def kernel_s_a_step(obs, seconds: Optional[float]) -> Optional[float]:
+    """Seconds a step of kernels that took ``seconds`` in the traced
+    steps: their share of the steps' device time x a whole step's, so a
+    step that the trace's edge cut miscounts neither."""
+    step_ms = train_step_device_ms(obs)
+    if not seconds or step_ms is None:
+        return None
+    return _share_of_steps(obs, seconds) * 1e-2 * step_ms * 1e-3
+
+
 def flash_seconds(obs) -> Optional[float]:
+    """Device seconds of the three flash kernels (forward, dq, dk/dv), by
+    the names ``lib/flash_names.py`` reads: a step with other Mosaic
+    kernels (an expert layer's grouped matmuls) counts these alone."""
+    from . import flash_names
+
     trace = obs.get("trace")
     if not trace:
         return None
-    s = trace.seconds_matching(FLASH_KERNEL_OP)
-    return s or None
+    return sum(flash_names.kernel_seconds(trace, kernel)
+               for kernel in flash_names.KERNEL_OPS) or None
 
 
 def flash_attention_roofline(obs) -> Optional[float]:
     """Least time the chip could take for what the kernels of one step
-    must do on ONE chip (the larger of FLOPs / peak and bytes / peak),
-    over the kernels' measured time per step: their share of the steps'
-    device time x a whole step's, so a step that the trace's edge cut
-    miscounts neither."""
-    s, step_ms = flash_seconds(obs), train_step_device_ms(obs)
-    if s is None or step_ms is None:
+    must do on ONE chip (the larger of FLOPs / peak and bytes / peak, by
+    the counts of the module the configuration's file names:
+    ``train_counts``), over the kernels' measured time per step."""
+    kernel_s = kernel_s_a_step(obs, flash_seconds(obs))
+    if kernel_s is None:
         return None
-    kernel_s = _share_of_steps(obs, s) * 1e-2 * step_ms * 1e-3
     cfg, peaks, chips = obs["cell"].config, obs["peaks"], obs["chips"]
-    need_flops = flops.flash_train_flops(cfg, obs["batch"],
-                                         obs["seq_len"]) / chips
-    need_bytes = flops.flash_train_bytes(cfg, obs["batch"],
-                                         obs["seq_len"]) / chips
+    counts = train_counts(obs["cell"])
+    need_flops = counts.flash_train_flops(cfg, obs["batch"],
+                                          obs["seq_len"]) / chips
+    need_bytes = counts.flash_train_bytes(cfg, obs["batch"],
+                                          obs["seq_len"]) / chips
     least = max(need_flops / peaks["bf16_flops_per_s"],
                 need_bytes / peaks["hbm_bytes_per_s"])
     return 100.0 * least / kernel_s

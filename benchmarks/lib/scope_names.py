@@ -180,19 +180,26 @@ def _write_report(obs) -> None:
         json.dump(report, f, indent=1)
 
 
+def scope_seconds(obs, which: str, *scopes: str) -> Optional[tuple]:
+    """(own device seconds of the ops the program traced under ``scopes``,
+    device seconds of the module's runs); None where none of them ran or
+    the program keeps no map."""
+    got = split(obs, which)
+    if not got or not got.module_s:
+        return None
+    seconds = sum(s for (name, _phase), s in got.by.items()
+                  if name in scopes)
+    return (seconds, got.module_s) if seconds else None
+
+
 def scopes_time_share(*scopes: str, which: str = "decode", applies=None):
-    """Own device seconds of the ops the program traced under ``scopes``
-    / device seconds of the decode (or prefill) programs, in %; None
-    where none of them ran, where the program keeps no map, and where
-    ``applies(obs)`` says that the configuration has no such layer."""
+    """``scope_seconds`` of the decode (or prefill, or train) programs as a
+    share, in %; None also where ``applies(obs)`` says that the
+    configuration has no such layer."""
     def read(obs) -> Optional[float]:
-        got = split(obs, which) if applies is None or applies(obs) \
-            else None
-        if not got or not got.module_s:
-            return None
-        seconds = sum(s for (name, _phase), s in got.by.items()
-                      if name in scopes)
-        return 100.0 * seconds / got.module_s if seconds else None
+        found = scope_seconds(obs, which, *scopes) \
+            if applies is None or applies(obs) else None
+        return None if found is None else 100.0 * found[0] / found[1]
     return read
 
 

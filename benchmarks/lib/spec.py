@@ -45,14 +45,46 @@ the CPU):
 One entry a question, and a cell joins by what its program does.  A served
 configuration joins ``batch.decode_step_roofline`` (one reader,
 ``readers.decode_step_roofline``, over the floor its file names) and brings
-no entry for its step.  One with experts joins the four ``moe_*`` entries:
-they read the program's ``router`` / ``expert_dispatch`` / ``expert_ffn``
-scopes, the ``%ragged-dot-none*`` kernels and the ``serve.chunk`` spans, at
-the width, layers and held range its file states (``lib/moe_names.py``).  One
-whose program is given an ``index_topk`` joins the ``dsa_*`` selection
-entries, one with a ``kv_lora_rank`` the ``mla_*`` ones that read scopes and
-spans.  It brings entries only for kernels and layers of its own (``per_layer``
-holds 112 of the 128 it may).
+no entry for its step.  What lets a cell join the counted entries is said
+in its configuration's file:
+
+    ``moe_*``                    the program's ``router`` / ``expert_dispatch``
+                                 / ``expert_ffn`` / ``shared_expert`` scopes,
+                                 the ``%ragged-dot-none*`` kernels and the
+                                 ``serve.chunk`` spans, at an expert's width
+                                 (``moe_intermediate_size``) and the layers
+                                 and range ``program_fields`` gives the
+                                 program (``first_dense_layers``,
+                                 ``moe_experts``, ``moe_held``); where an
+                                 expert is not three matrices of stream x
+                                 width in all but the leading layers,
+                                 ``expert_shape`` {``matrices``,
+                                 ``row_width``, ``layers``}
+                                 (``lib/moe_flops.py``, ``lib/moe_names.py``)
+    ``ssm_*``                    ``layer_types`` with ``"mamba"`` and the
+                                 ``mamba_*`` keys, or ``ssm_shape`` {``heads``,
+                                 ``head_dim``, ``state``, ``groups``, ``conv``,
+                                 ``layers``}, with ``"ops": "scopes"`` where
+                                 the program traces ``ssm_state_update`` /
+                                 ``ssm_scan`` (``lib/ssm_flops.py``,
+                                 ``lib/ssm_names.py``)
+    ``train_mfu``,               ``train_counts``: the module of ``lib/`` with
+    ``flash_attention_roofline`` ``train_flops_per_token``,
+                                 ``flash_train_flops``, ``flash_train_bytes``
+                                 of ITS train step (none named:
+                                 ``lib/flops.py``, a dense full-causal
+                                 decoder); ``train_expert_*`` /
+                                 ``train_routing_*`` read the step's scopes
+                                 and its ``expert_rows`` metric as ``moe_*``
+                                 do a served step's
+    ``dsa_*`` selection          an ``index_topk`` among ``program_fields``
+    ``mla_*`` scopes and spans   a ``kv_lora_rank``
+
+It brings entries only for kernels and layers of its own, appended to
+``per_layer`` (``benchmarks/tests/test_yardstick.py`` holds the table's one
+limit, 128, and that every file under ``metrics/`` is an entry's reader; no
+other test counts the entries, and no cell's test says what another
+family's names are).
 
 A configuration that is cut says so twice: ``reduced`` in
 ``BENCHMARK.json`` lists the keys, and ``reduced`` in the file has one

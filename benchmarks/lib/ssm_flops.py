@@ -9,6 +9,14 @@ published ``config.json`` keys: ``layer_types``, ``mamba_n_heads``,
 ``dtype.ssm_state`` names the type the recurrent state is stored in).  A
 multiply-add counts as 2 FLOPs.  Every layer has the dense SwiGLU MLP.
 
+The mixer's own shape (``shape``: what the state's bytes and the update's
+operations are counted from) is every configuration's with such layers: a
+file whose family spells the keys otherwise states it under ``ssm_shape``,
+``{"heads": 128, "head_dim": 64, "state": 128, "groups": 8, "conv": 4,
+"layers": 5}`` (``layers``: the Mamba layers held), and with ``"ops":
+"scopes"`` that its program traces the update and the scan under the scopes
+``ssm_state_update`` / ``ssm_scan`` (``lib/ssm_names.py``).
+
 By hand, granite-4.0-h-micro: a Mamba mixer's in-projection is 2,048 x
 (4,096 + 4,352 + 64) = 17,432,576 weights, its out-projection 4,096 x
 2,048 = 8,388,608; a slot's recurrent state is 36 x 64 x 64 x 128 =
@@ -18,9 +26,32 @@ By hand, granite-4.0-h-micro: a Mamba mixer's in-projection is 2,048 x
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 _ITEMSIZE = {"bfloat16": 2, "float32": 4}
+
+
+class Shape(NamedTuple):
+    heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv: int       # taps
+    layers: int     # Mamba layers
+
+
+def shape(c: Dict[str, Any]) -> Optional[Shape]:
+    """The mixer's shape: what the file states under ``ssm_shape``, else
+    Granite's published keys; None for a configuration with no such
+    layer."""
+    stated = c.get("ssm_shape")
+    if stated:
+        return Shape(*(stated[k] for k in Shape._fields))
+    if "mamba" not in c.get("layer_types", ()):
+        return None
+    return Shape(c["mamba_n_heads"], c["mamba_d_head"], c["mamba_d_state"],
+                 c["mamba_n_groups"], c["mamba_d_conv"],
+                 c["layer_types"].count("mamba"))
 
 
 def layer_counts(c: Dict[str, Any]):
@@ -31,9 +62,10 @@ def layer_counts(c: Dict[str, Any]):
 
 def mamba_dims(c: Dict[str, Any]):
     """(d_inner, conv_dim, width of the in-projection [z | xBC | dt])."""
-    d_inner = c["mamba_n_heads"] * c["mamba_d_head"]
-    conv_dim = d_inner + 2 * c["mamba_n_groups"] * c["mamba_d_state"]
-    return d_inner, conv_dim, d_inner + conv_dim + c["mamba_n_heads"]
+    s = shape(c)
+    d_inner = s.heads * s.head_dim
+    conv_dim = d_inner + 2 * s.groups * s.state
+    return d_inner, conv_dim, d_inner + conv_dim + s.heads
 
 
 def mixer_matmul_params(c: Dict[str, Any]) -> int:
@@ -67,8 +99,9 @@ def param_count(c: Dict[str, Any]) -> int:
     embedding (tied: counted once, as the head), the final norm."""
     n_attn, n_mamba = layer_counts(c)
     d_inner, conv_dim, _ = mamba_dims(c)
-    nh, h = c["mamba_n_heads"], c["hidden_size"]
-    small = conv_dim * c["mamba_d_conv"] + conv_dim + 3 * nh + d_inner
+    s, h = shape(c), c["hidden_size"]
+    nh = s.heads
+    small = conv_dim * s.conv + conv_dim + 3 * nh + d_inner
     head = 0 if c["tie_word_embeddings"] else h * c["vocab_size"]
     return (matmul_params(c) + head + (n_attn + n_mamba) * 2 * h
             + n_mamba * small + h)
@@ -78,12 +111,11 @@ def state_bytes_per_slot(c: Dict[str, Any]) -> Dict[str, int]:
     """Bytes ONE slot's states hold over all Mamba layers: ``ssm`` (nh x
     hd x N a layer, in ``dtype.ssm_state``) and ``conv`` (the last
     d_conv - 1 inputs of conv_dim channels, in the serving type)."""
-    _n_attn, n_mamba = layer_counts(c)
+    s = shape(c)
     _d_inner, conv_dim, _ = mamba_dims(c)
-    elements = (c["mamba_n_heads"] * c["mamba_d_head"]
-                * c["mamba_d_state"])
-    return {"ssm": n_mamba * elements * _ITEMSIZE[c["dtype"]["ssm_state"]],
-            "conv": n_mamba * (c["mamba_d_conv"] - 1) * conv_dim
+    elements = s.heads * s.head_dim * s.state
+    return {"ssm": s.layers * elements * _ITEMSIZE[c["dtype"]["ssm_state"]],
+            "conv": s.layers * (s.conv - 1) * conv_dim
             * _ITEMSIZE[c["dtype"]["serve"]]}
 
 
@@ -104,9 +136,8 @@ def state_update_bytes(c: Dict[str, Any], rows: float) -> float:
 def state_update_flops(c: Dict[str, Any], rows: float) -> float:
     """Per state element and row: decay x S, (dt x) x B, their sum, and
     the contraction with C (a multiply and an add): 5."""
-    _n_attn, n_mamba = layer_counts(c)
-    return 5.0 * rows * n_mamba * (c["mamba_n_heads"] * c["mamba_d_head"]
-                                   * c["mamba_d_state"])
+    s = shape(c)
+    return 5.0 * rows * s.layers * (s.heads * s.head_dim * s.state)
 
 
 def decode_step_bytes(c: Dict[str, Any], rows: float,

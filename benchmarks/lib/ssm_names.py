@@ -20,8 +20,17 @@ and operands with their shapes (``lib/moe_names.py`` is the precedent):
   state]`` one (the chunk states) or a ``[..., state, heads x head_dim]``
   one (the rows' final states, scattered into the cache).
 
+Where the configuration's file says that its program traces the two under
+scopes of their own (``ssm_shape`` with ``"ops": "scopes"``,
+``lib/ssm_flops.py``), they are the own seconds of the ops under
+``ssm_state_update`` in the decode programs and under ``ssm_scan`` in the
+prefill programs (``scope_names.split``), and no array's shape is looked at:
+a mixer with groups, or one whose states lie otherwise, joins with that line
+in its file.
+
 A program without such layers matches nothing here, a configuration
-without ``layer_types`` is not looked at, and the readers return None.
+without ``layer_types`` or ``ssm_shape`` is not looked at, and the readers
+return None.
 """
 
 from __future__ import annotations
@@ -30,7 +39,7 @@ import re
 import statistics
 from typing import List, Optional
 
-from . import readers, ssm_flops, trace_reduce
+from . import readers, scope_names, ssm_flops, trace_reduce
 
 
 STATE_UPDATE_KERNEL = r"^%ssm_state_update(\.\w+)* = "
@@ -65,13 +74,11 @@ def _seconds(obs, key: str, module: str, pattern) -> Optional[tuple]:
     head_dim, state)``, seconds of the module), cached on the
     observations; None where there is nothing to read."""
     trace = obs.get("trace")
-    cfg = obs["cell"].config
-    if not trace or not trace.devices or "mamba" not in cfg.get(
-            "layer_types", ()):
+    shape = ssm_flops.shape(obs["cell"].config)
+    if not trace or not trace.devices or shape is None:
         return None
     if key not in obs:
-        op = re.compile(pattern(cfg["mamba_n_heads"], cfg["mamba_d_head"],
-                                cfg["mamba_d_state"]))
+        op = re.compile(pattern(shape.heads, shape.head_dim, shape.state))
         matched = sum(end - start
                       for start, end, name in _leaves_inside(trace, module)
                       if op.search(name))
@@ -80,10 +87,24 @@ def _seconds(obs, key: str, module: str, pattern) -> Optional[tuple]:
     return obs[key]
 
 
+def _by_scopes(obs) -> bool:
+    stated = obs["cell"].config.get("ssm_shape") or {}
+    return stated.get("ops") == "scopes"
+
+
 def _update_seconds(obs):
+    if _by_scopes(obs):
+        return scope_names.scope_seconds(obs, "decode", "ssm_state_update")
     slots = obs["cell"].workload["engine"]["max_slots"]
     return _seconds(obs, "ssm_update_s", readers.DECODE_MODULE,
                     lambda *sizes: state_update_op(slots, *sizes))
+
+
+def _scan_seconds(obs):
+    if _by_scopes(obs):
+        return scope_names.scope_seconds(obs, "prefill", "ssm_scan")
+    return _seconds(obs, "ssm_scan_s", readers.PREFILL_MODULE,
+                    prefill_scan_op)
 
 
 def rows_a_step(obs) -> Optional[float]:
@@ -108,8 +129,7 @@ def state_update_time_share(obs) -> Optional[float]:
 
 
 def prefill_scan_time_share(obs) -> Optional[float]:
-    found = _seconds(obs, "ssm_scan_s", readers.PREFILL_MODULE,
-                     prefill_scan_op)
+    found = _scan_seconds(obs)
     return None if found is None else 100.0 * found[0] / found[1]
 
 

@@ -1,5 +1,6 @@
-"""Least time for the kernels' causal FLOPs / bytes at the chip's peaks /
-their measured time.
+"""Least time for the three flash kernels' FLOPs / bytes of a step at the
+chip's peaks (inside the layers' masks, by the counts of the module the
+configuration's file names: ``train_counts``) / their measured time.
 """
 
 from benchmarks.lib import readers

@@ -1,6 +1,7 @@
 """Device time of the chunked state-space scan's ops (told by their
-arrays: a chunk's ``[heads, Q, Q]`` matrices, the chunk and final states)
-/ device time of the prefill programs.
+arrays: a chunk's ``[heads, Q, Q]`` matrices, the chunk and final states; by
+the scope ``ssm_scan`` where the configuration's file says so) / device time
+of the prefill programs.
 """
 
 from benchmarks.lib import ssm_names

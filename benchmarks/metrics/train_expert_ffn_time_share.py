@@ -1,7 +1,7 @@
-"""Own device time of the ops under scope expert_ffn, every phase / device time
-of the train steps.  Not entered in BENCHMARK.json yet (PERF.md section 7).
+"""Own device time of the ops under scope expert_ffn (the grouped matmuls and
+the activation between them), every phase / device time of the train steps.
 """
 
-from benchmarks.lib import afmoe_names
+from benchmarks.lib import moe_names
 
-read = afmoe_names.expert_ffn_time_share
+read = moe_names.train_expert_ffn_time_share

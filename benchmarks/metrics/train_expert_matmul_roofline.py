@@ -1,10 +1,8 @@
 """Least time of a step's grouped matmuls forward + backward over the rows the
-held experts computed (FLOPs or bytes at peak, no recompute) / the
-%ragged-dot-none* kernels' measured time a step; nothing until the kind hands
-the step's expert_rows over.  Not entered in BENCHMARK.json yet (PERF.md
-section 7).
+held experts computed (the step's own expert_rows metric; FLOPs or bytes at
+peak, no recompute) / the %ragged-dot-none* kernels' measured time a step.
 """
 
-from benchmarks.lib import afmoe_names
+from benchmarks.lib import moe_names
 
-read = afmoe_names.train_expert_matmul_roofline
+read = moe_names.train_expert_matmul_roofline

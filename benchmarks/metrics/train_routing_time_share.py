@@ -1,8 +1,7 @@
 """Own device time of the ops under scopes router and expert_dispatch, every
-phase / device time of the train steps.  Not entered in BENCHMARK.json yet
-(PERF.md section 7).
+phase / device time of the train steps.
 """
 
-from benchmarks.lib import afmoe_names
+from benchmarks.lib import moe_names
 
-read = afmoe_names.routing_time_share
+read = moe_names.train_routing_time_share

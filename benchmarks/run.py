@@ -95,6 +95,9 @@ def measure(argv, allow_platforms=("tpu",), bench_dir=spec.BENCH_DIR,
             "device_ops": [[n, s] for n, s in trace.top_ops()],
             "idle_gaps": [[n, s] for n, s in
                           trace.idle_gaps(names=HOST_ANNOTATIONS)]}
+    # what ``correct`` compared, each number beside its limit: the result
+    # line's last key, and the run's last lines on standard error
+    result["compared"] = obs["compared"]
     _say(event="facts", checks=checks, memory=memory,
          setup_s=obs["setup_s"], compile_events=watch.count,
          compile_s=watch.seconds, cache_hits=watch.cache_hits,
@@ -117,6 +120,9 @@ def main(argv) -> int:
         print(f"benchmarks/run.py: refused: {e}", file=sys.stderr,
               flush=True)
         return 2
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name} {value!r} limit {limit!r}", file=sys.stderr)
+    print(f"correct {result['correct']}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

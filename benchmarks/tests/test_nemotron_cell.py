@@ -1,10 +1,12 @@
 """The Nemotron-H configuration's part of the benchmark:
-``lib/nemotron_flops.py`` against hand-worked numbers and the program's own
-trees; the widest programs the cell's engine warms compiled at the REAL
+``lib/nemotron_flops.py`` (the stack's parameters, pools and whole step) and
+what its file states for ``lib/moe_flops.py`` / ``lib/ssm_flops.py`` (the
+grouped matmuls', the state update's) against hand-worked numbers and the
+program's own trees; the widest programs the cell's engine warms compiled at the REAL
 widths for a v5e that is described, not attached; a CPU rehearsal of a toy
 of the same shape through ``run.measure`` with ``nemotron_h_decoder`` as its
-reference and of ``tools/nemotron_check.py``; and the ``latent_moe_*`` /
-``nemotron_*`` readers' arithmetic on a split that is given.
+reference and of ``tools/nemotron_check.py``; and the arithmetic of the
+``moe_*`` / ``ssm_*`` readers the cell joins, on a split that is given.
 """
 
 import json
@@ -16,8 +18,9 @@ import types
 import pytest
 
 from benchmarks import run as bench_run
-from benchmarks.lib import (nemotron_flops, nemotron_names, program,
-                            program_spans, scope_names, spec)
+from benchmarks.lib import (moe_flops, moe_names, nemotron_flops, program,
+                            program_spans, readers, scope_names, spec,
+                            ssm_flops, ssm_names)
 from benchmarks.tests import test_rehearsal
 # ``topo`` is described inside that file's fixture (never at import);
 # ``compiled_kernels`` keeps these compiles out of the persistent cache.
@@ -27,15 +30,14 @@ from benchmarks.tests.test_aot_real_widths import (  # noqa: F401
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 CONFIG = "nemotron-3-super-120b-a12b"
 CELL = "nemotron-3-super-120b-a12b.serve-reasoning-decode"
-# readers written for this family and not entered in BENCHMARK.json yet
-OWN_READERS = (
-    "latent_moe_expert_matmul_roofline", "latent_moe_expert_load_imbalance",
-    "latent_moe_projection_time_share",
-    "latent_moe_shared_expert_time_share",
-    "nemotron_ssm_state_update_time_share",
-    "nemotron_ssm_state_update_roofline",
-    "nemotron_ssm_prefill_scan_time_share",
-    "nemotron_decode_attention_time_share")
+# the counted entries the cell joins by what its file states
+# (``expert_shape``, ``ssm_shape``): one reader a question for every
+# configuration with experts or a state-space mixer
+JOINED = (
+    "moe_expert_matmul_roofline", "moe_expert_load_imbalance",
+    "moe_expert_ffn_time_share", "moe_routing_time_share",
+    "moe_shared_expert_time_share", "ssm_state_update_time_share",
+    "ssm_state_update_roofline", "ssm_prefill_scan_time_share")
 PUBLISHED_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
                      "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
 
@@ -77,6 +79,20 @@ def test_operations_and_bytes_by_hand():
     assert (mamba, attention, outside, expert) \
         == (109_640_064, 35_655_680, 54_530_560, 5_505_024)
     assert nemotron_flops.expert_params(c) == expert
+    # what the file states for the readers every such configuration joins
+    # is what its published keys say: two matrices an expert in the latent,
+    # the pattern's E and M blocks, the mixer's own sizes
+    assert c["expert_shape"] == {
+        "why": c["expert_shape"]["why"], "matrices": 2,
+        "row_width": c["moe_latent_size"], "layers": 5}
+    assert (moe_flops.expert_params(c), moe_flops.expert_width(c),
+            moe_flops.expert_layers(c), moe_flops.experts_held(c)) \
+        == (expert, 2688, 5, 128)
+    assert ssm_flops.shape(c) == (
+        c["mamba_num_heads"], c["mamba_head_dim"], c["ssm_state_size"],
+        c["n_groups"], c["conv_kernel"], 5) == (128, 64, 128, 8, 4, 5)
+    assert ssm_flops.shape(c) == nemotron_flops.mamba_dims(c)[:4] + (4, 5)
+    assert c["ssm_shape"]["ops"] == "scopes"
     per, small = (nemotron_flops.block_matmul_params(c),
                   nemotron_flops.block_small_params(c))
     assert {k: per[k] + small[k] for k in per} \
@@ -89,17 +105,21 @@ def test_operations_and_bytes_by_hand():
     assert nemotron_flops.slot_bytes(c, 4096) == {
         "kv": 4_194_304, "ssm": 5 * 4_194_304, "conv": 307_200}
     assert nemotron_flops.state_bytes(c) == 128 * 64 * 128 * 4
+    assert ssm_flops.state_bytes_per_slot(c) == {
+        "ssm": 5 * 4_194_304, "conv": 307_200}
     # a step that advances 96 slots: each state once in, once out, 5 blocks
-    assert nemotron_flops.state_update_bytes(c, 96) \
-        == 96 * 2 * 4_194_304 * 5
-    assert nemotron_flops.state_update_bytes(c, 96) / 819e9 \
+    assert ssm_flops.state_update_bytes(c, 96) == 96 * 2 * 4_194_304 * 5
+    assert ssm_flops.state_update_bytes(c, 96) / 819e9 \
         == pytest.approx(4.9e-3, rel=0.01)
+    assert ssm_flops.state_update_flops(c, 96) \
+        == 5 * 96 * 5 * 128 * 64 * 128
     # 96 x 22 / 4 picks a block land on ~630 of its 640 (block, expert)
     # pairs: the grouped matmuls stream 7.0 GB, bytes and not FLOPs
-    assert nemotron_flops.expert_matmul_bytes(c, 630, 2640) \
+    assert moe_flops.expert_matmul_bytes(c, 630, 2640) \
         == (630 * expert + 2640 * (2 * 1024 + 2 * 2688)) * 2
-    assert nemotron_flops.expert_matmul_bytes(c, 630, 2640) / 819e9 \
-        > 50 * nemotron_flops.expert_matmul_flops(c, 2640) / 197e12
+    assert moe_flops.expert_matmul_flops(c, 2640) == 2 * 2640 * expert
+    assert moe_flops.expert_matmul_bytes(c, 630, 2640) / 819e9 \
+        > 50 * moe_flops.expert_matmul_flops(c, 2640) / 197e12
     # the whole step at 96 rows of 1,000 positions: ~16 ms, bytes-bound
     lengths = [1000] * 96
     dense = 2 * nemotron_flops.dense_matmul_params(c)
@@ -108,6 +128,10 @@ def test_operations_and_bytes_by_hand():
         + 2 * 96 * (5 * 4_194_304 + 307_200)
     least = nemotron_flops.decode_step_bytes(c, lengths, 630) / 819e9
     assert 0.014 < least < 0.018
+    assert nemotron_flops.decode_step_flops(c, lengths, 2640) \
+        == 2.0 * nemotron_flops.dense_matmul_params(c) * 96 \
+        + 2 * 2640 * expert + 2 * 2 * 32 * 128 * 96_000 \
+        + 5 * 96 * 5 * 128 * 64 * 128
     assert least > 5 * nemotron_flops.decode_step_flops(c, lengths, 2640) \
         / 197e12
 
@@ -225,6 +249,9 @@ def test_a_decode_step_of_two_slots_compiles(one_chip):
 TINY = {
     "name": "tiny-nemotron", "source": "none (test, single-sub-layer blocks)",
     "reference": "nemotron_h_decoder", "roofline": "nemotron_flops",
+    "expert_shape": {"matrices": 2, "row_width": 32, "layers": 3},
+    "ssm_shape": {"heads": 8, "head_dim": 8, "state": 16, "groups": 2,
+                  "conv": 4, "layers": 3, "ops": "scopes"},
     "model_type": "nemotron_h", "vocab_size": 256, "hidden_size": 64,
     "num_hidden_layers": 7, "hybrid_override_pattern": "MEM*EME",
     "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 8,
@@ -300,11 +327,15 @@ def tree(tmp_path_factory):
 cpu_peaks = test_rehearsal.cpu_peaks
 
 
-def test_the_cells_names_lead_to_files_and_join_the_serve_metrics():
-    from benchmarks.tests.test_yardstick import names_lead_to_files
+def the_cells_entries(root=spec.ROOT):
+    """What THIS cell reports, on the tree at ``root`` (the rehearsal's has
+    a later PR's entries appended: nothing here counts the table or says
+    what another family's names are)."""
+    from benchmarks.tests.test_yardstick import (cell_at, names_lead_to_files,
+                                                 reader_at)
 
-    names_lead_to_files(spec.ROOT)
-    cell = spec.Cell(CELL)
+    names_lead_to_files(root)
+    cell = cell_at(root, CELL)
     assert cell.chips == 1 and cell.workload["kind"] == "serve_llm_even"
     engine = dict(cell.workload["engine"])
     slots = engine.pop("max_slots")
@@ -325,31 +356,25 @@ def test_the_cells_names_lead_to_files_and_join_the_serve_metrics():
     assert entry["reduced"] == [r["key"] for r in cell.config["reduced"]]
     assert len(entry["why"]) <= 200 and len(cell.entry["why"]) <= 200
     names = {m["name"] for m in cell.metric_entries("per_layer")}
-    # The table is held at 117 entries by two of the benchmark's own tests
-    # (test_solar_open2_cell.py, test_trinity_cell.py), which a model_config
-    # PR may not edit: this family's eight readers are files that
-    # ``tools/traced_with.py`` runs beside a traced result, and no entry yet.
-    assert not {n for n in names
-                if n.startswith(("latent_moe_", "nemotron_"))}
-    for reader in OWN_READERS:
-        assert callable(spec.load_module("metrics", reader).read)
+    # the cell brings no entry of its own: its experts, its shared expert
+    # and its state-space mixer are asked the questions every such
+    # configuration is, at the shapes its file states
+    assert set(JOINED) <= names
+    for reader in JOINED:
+        assert callable(reader_at(root, reader).read)
     assert {"batch.slot_wait_p50_ms", "batch.decode_kv_read_share",
             "batch.prefill_unscoped_time_share", "batch.decode_step_roofline",
-            "moe_expert_ffn_time_share", "moe_routing_time_share",
             "batch.prefill_expert_dispatch_time_share",
             "setup_before_engine_s", "setup_warmup_s"} <= names
-    # the counted readers whose expert is three full-width matrices a layer,
-    # and Granite's state-space keys, are NOT joined
-    assert not {"moe_expert_matmul_roofline", "moe_expert_load_imbalance",
-                "ssm_state_update_roofline", "ssm_state_update_time_share",
-                "ssm_prefill_scan_time_share"} & names
     assert not {n for n in names if n.startswith(
         ("swa_", "dsa_", "mla_", "lfm2_", "sambay_", "kda_", "chat."))}
     assert cell.config["roofline"] == "nemotron_flops"
     assert {m["name"] for m in cell.metric_entries("end_to_end")} \
         == {"serve_output_tokens_per_s", "setup_s"}
-    assert len(cell.benchmark["per_layer"]) <= 128
-    assert len(OWN_READERS) == 8
+
+
+def test_the_cells_names_lead_to_files_and_join_the_serve_metrics():
+    the_cells_entries()
 
 
 def test_a_toy_nemotron_model_runs_end_to_end_on_the_cpu(tree, cpu_peaks):
@@ -371,13 +396,14 @@ def test_a_toy_nemotron_model_runs_end_to_end_on_the_cpu(tree, cpu_peaks):
     metrics = result["metrics"]
     assert {"batch.slot_wait_p50_ms", "batch.token_burst_gap_p50_ms",
             "batch.decode_slot_utilization", "batch.decode_kv_read_share",
-            "batch.prefill_padding_share", "window_compiles"} <= set(metrics)
-    assert spec.load_module("metrics", "latent_moe_expert_load_imbalance"
-                            ).read(obs) >= 1.0
+            "batch.prefill_padding_share", "window_compiles",
+            "moe_expert_load_imbalance"} <= set(metrics)
+    # the busiest of the 3 E blocks x 8 held experts against their mean
+    assert metrics["moe_expert_load_imbalance"]["value"] >= 1.0
     # no device trace on a CPU: no share of a device's time is invented
     assert not {name for name in metrics if name.endswith(
         ("_roofline", "_time_share"))}
-    load = nemotron_names.expert_load_a_step(obs)
+    load = moe_names.chunk_medians(obs)
     assert load[1] <= 3 * 8 and load[2] >= 1.0
     chunk = next(c for c in program_spans.collect(obs).chunks
                  if c.get("state_rows_updated"))
@@ -417,8 +443,9 @@ def test_the_readers_arithmetic_on_a_given_split(monkeypatch):
     """A decode and a prefill program's seconds by scope as
     ``scope_names.split`` would hand them, 90 slots advanced a step: the
     shares are the scopes' own seconds over their programs', the update's
-    roofline its 3.77 GB at the HBM peak over its 6 ms a step; a
-    configuration of another family reads nothing."""
+    roofline its 3.77 GB at the HBM peak over its 6 ms a step, the grouped
+    matmuls' their two matrices an expert in the latent over their kernels';
+    a configuration whose file states no shape reads as it did."""
     c = _json("configs", CONFIG)
     splits = {
         "decode": scope_names.Split(
@@ -432,39 +459,70 @@ def test_the_readers_arithmetic_on_a_given_split(monkeypatch):
             3.0, [])}
     monkeypatch.setattr(scope_names, "split",
                         lambda obs, which: splits.get(which))
-    monkeypatch.setattr(nemotron_names.readers, "decode_step_device_ms",
-                        lambda obs: 30.0)
-    monkeypatch.setattr(nemotron_names.ssm_names, "rows_a_step",
-                        lambda obs: 90.0)
+    monkeypatch.setattr(readers, "decode_step_device_ms", lambda obs: 30.0)
+    monkeypatch.setattr(ssm_names, "rows_a_step", lambda obs: 90.0)
     obs = {"cell": types.SimpleNamespace(config=c, bench_dir=spec.BENCH_DIR,
                                          name=CELL),
            "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}}
-    assert nemotron_names.state_update_time_share(obs) == pytest.approx(20.0)
-    assert nemotron_names.projection_time_share(obs) == pytest.approx(4.0)
-    assert nemotron_names.shared_expert_time_share(obs) == pytest.approx(6.0)
-    assert nemotron_names.decode_attention_time_share(obs) \
-        == pytest.approx(3.0)
-    assert nemotron_names.prefill_scan_time_share(obs) \
+    assert ssm_names.state_update_time_share(obs) == pytest.approx(20.0)
+    assert moe_names.shared_expert_time_share(obs) == pytest.approx(6.0)
+    assert moe_names.expert_ffn_time_share(obs) == pytest.approx(40.0)
+    assert ssm_names.prefill_scan_time_share(obs) \
         == pytest.approx(100 * 0.6 / 3.0)
     least = 90 * 2 * 4_194_304 * 5 / 819e9
-    assert nemotron_names.state_update_roofline(obs) \
+    assert ssm_names.state_update_roofline(obs) \
         == pytest.approx(100 * least / (0.20 * 30e-3))
     # bytes, not FLOPs, set the floor: 5 FLOPs an element against 8 bytes
-    assert nemotron_flops.state_update_flops(c, 90) / 197e12 < least
-    # another family's configuration: nothing to read, nothing raised
+    assert ssm_flops.state_update_flops(c, 90) / 197e12 < least
+    # the grouped matmuls: 2,000 rows a step on 600 (block, expert) pairs,
+    # their kernels a quarter of the decode programs' 2 s
+    trace = types.SimpleNamespace(
+        devices=[object()],
+        module_runs=lambda module: [(0.0, 2.0, "jit_decode_k")])
+    monkeypatch.setattr(
+        ssm_names, "_leaves_inside", lambda trace, module: [
+            (0.0, 0.3, "%ragged-dot-none.3 = f32[2112,2688] custom-call("),
+            (0.4, 0.6, "%ragged-dot-none = bf16[2112,1024] custom-call("),
+            (0.7, 0.9, "%fusion.7 = bf16[96,4096] fusion(")])
+    monkeypatch.setattr(moe_names, "chunk_medians",
+                        lambda obs: (2000.0, 600.0, 4.0))
+    floor = (600 * 5_505_024 + 2000 * (2 * 1024 + 2 * 2688)) * 2 / 819e9
+    assert moe_names.expert_matmul_roofline({**obs, "trace": trace}) \
+        == pytest.approx(100 * floor / (0.25 * 30e-3))
+    assert moe_names.load_imbalance(obs) == 4.0
+    # Granite's file states no shape: its update and its scan are told by
+    # the kernel's name and their arrays, not by a scope, and a trace that
+    # holds neither reads nothing
     other = {**obs, "cell": types.SimpleNamespace(
         config=_json("configs", "granite-4.0-h-micro"),
-        bench_dir=spec.BENCH_DIR, name="x")}
-    assert nemotron_names.state_update_time_share(other) is None
-    assert nemotron_names.state_update_roofline(other) is None
-    assert nemotron_names.load_imbalance(other) is None
+        workload={"engine": {"max_slots": 80}}, bench_dir=spec.BENCH_DIR,
+        name="x"), "trace": trace}
+    assert ssm_flops.shape(other["cell"].config) == (64, 64, 128, 1, 4, 36)
+    assert ssm_names.state_update_time_share(other) is None
+    assert ssm_names.state_update_roofline(other) is None
+    assert ssm_names.prefill_scan_time_share(other) is None
+    # a configuration with neither layer: nothing is looked at
+    dense = {**obs, "cell": types.SimpleNamespace(
+        config=_json("configs", "internlm2-1.8b"),
+        workload={"engine": {"max_slots": 120}}, bench_dir=spec.BENCH_DIR,
+        name="y"), "trace": trace}
+    assert ssm_flops.shape(dense["cell"].config) is None
+    assert ssm_names.state_update_time_share(dense) is None
     # a program without the scopes
     splits["decode"] = scope_names.Split({("ffn", "forward"): 0.3}, 1.0, [])
     splits["prefill"] = None
-    assert nemotron_names.state_update_time_share(obs) is None
-    assert nemotron_names.state_update_roofline(obs) is None
-    assert nemotron_names.prefill_scan_time_share(obs) is None
-    assert nemotron_names.projection_time_share(obs) is None
+    assert ssm_names.state_update_time_share(obs) is None
+    assert ssm_names.state_update_roofline(obs) is None
+    assert ssm_names.prefill_scan_time_share(obs) is None
+    assert moe_names.shared_expert_time_share(obs) is None
+    # on the yardstick's fixed observations, what 5e17fcb's
+    # ``latent_moe_expert_load_imbalance`` read: the busiest expert against
+    # the mean over the 5 E blocks x 128 held experts, not 11 layers' worth
+    from benchmarks.tests.test_yardstick import fixed_observations
+
+    monkeypatch.undo()
+    assert moe_names.load_imbalance(fixed_observations(c, CELL)) \
+        == pytest.approx(13.643410852713178, rel=1e-12)
 
 
 def test_the_references_swap_limits_lie_between_their_readings():

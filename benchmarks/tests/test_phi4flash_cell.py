@@ -269,11 +269,14 @@ def tree(tmp_path_factory):
 cpu_peaks = test_rehearsal.cpu_peaks
 
 
-def test_the_cells_names_lead_to_files_and_join_the_serve_metrics():
-    from benchmarks.tests.test_yardstick import names_lead_to_files
+def the_cells_entries(root=spec.ROOT):
+    """What THIS cell reports, on the tree at ``root`` (the rehearsal's has
+    a later PR's entries appended: nothing here counts the table or says
+    what another family's names are)."""
+    from benchmarks.tests.test_yardstick import cell_at, names_lead_to_files
 
-    names_lead_to_files(spec.ROOT)
-    cell = spec.Cell(CELL)
+    names_lead_to_files(root)
+    cell = cell_at(root, CELL)
     assert cell.chips == 1 and cell.workload["kind"] == "serve_llm_even"
     assert cell.workload["engine"]["max_len"] == 16384
     assert cell.workload["engine"]["prefill_buckets"] == [4096, 8192, 12288]
@@ -295,6 +298,10 @@ def test_the_cells_names_lead_to_files_and_join_the_serve_metrics():
     assert "batch.decode_step_roofline" in names
     assert {m["name"] for m in cell.metric_entries("end_to_end")} \
         == {"serve_output_tokens_per_s", "setup_s"}
+
+
+def test_the_cells_names_lead_to_files_and_join_the_serve_metrics():
+    the_cells_entries()
 
 
 def test_a_toy_decoder_hybrid_decoder_runs_end_to_end_on_the_cpu(

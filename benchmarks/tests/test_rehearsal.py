@@ -140,7 +140,7 @@ TINY_FAMILY_JOINS = (
     "moe_expert_load_imbalance", "dsa_indexer_time_share",
     "dsa_select_time_share", "dsa_prefill_selection_time_share",
     "dsa_selected_share", "mla_absorb_time_share",
-    "mla_shared_expert_time_share", "mla_held_rows_share",
+    "moe_shared_expert_time_share", "mla_held_rows_share",
     "serve_output_tokens_per_s")
 NEW_METRIC = '''"""Requests the generator measured (a count, from its log)."""
 
@@ -148,6 +148,23 @@ NEW_METRIC = '''"""Requests the generator measured (a count, from its log)."""
 def read(obs):
     return float(len(obs["measured"])) if "measured" in obs else None
 '''
+# ... and for a layer of its own: a reader file built on
+# ``scope_names.scopes_time_share`` with its entry APPENDED to ``per_layer``
+# -- the step that the table's own tests once refused (three cell tests and
+# ``test_scope_names`` counted its entries: PERF.md section 6, PR 64).
+NEW_SCOPE_METRIC = '''"""Own device time of the ops under scope ``mla_absorb`` / device time of
+the decode programs: a new family's own layer, by the program's scope."""
+
+from benchmarks.lib import scope_names
+
+read = scope_names.scopes_time_share("mla_absorb")
+'''
+# the cell tests whose entry assertions (``the_cells_entries``) the
+# rehearsal runs on its tree
+CELL_TESTS = ("test_smallthinker_cell", "test_deepseek_v2_cell",
+              "test_lfm2_cell", "test_keye_cell", "test_phi4flash_cell",
+              "test_solar_open2_cell", "test_trinity_cell",
+              "test_nemotron_cell")
 
 
 @pytest.fixture(scope="module")
@@ -172,6 +189,7 @@ def tree(tmp_path_factory):
     for name, traffic in TRAFFIC.items():
         drop(f"traffic/{name}.json", json.dumps(traffic))
     drop("metrics/tiny_requests_measured.py", NEW_METRIC)
+    drop("metrics/tiny_absorb_time_share.py", NEW_SCOPE_METRIC)
     drop("configs/tiny-latent-select.json", json.dumps(TINY_FAMILY))
     drop("lib/tiny_latent_select_flops.py", TINY_FAMILY_FLOOR)
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
@@ -209,6 +227,11 @@ def tree(tmp_path_factory):
          "better": "higher", "source": "program_counter",
          "layer": "request path", "moves": "serve_tpot_p50_ms",
          "workloads": ["tiny.tiny-open"]})
+    benchmark["per_layer"].append(
+        {"name": "tiny_absorb_time_share", "unit": "%", "better": "lower",
+         "source": "device_trace", "layer": "serve device programs",
+         "moves": "serve_output_tokens_per_s",
+         "workloads": [TINY_FAMILY_CELL]})
     path = str(root / "BENCHMARK.json")
     with open(path, "w") as f:
         json.dump(benchmark, f)
@@ -242,6 +265,10 @@ def _measure(tree, cell, trace, seconds=2.0):
     assert result["failed"] == 0 < result["attempted"]
     assert result["device"]["platform"] == "cpu"
     json.dumps(result)      # the last line is JSON
+    # what ``correct`` compared comes last, each number beside its limit
+    assert list(result)[-1] == "compared" and result["compared"]
+    assert all(value <= limit for value, limit in
+               result["compared"].values())
     for metric in result["metrics"].values():
         assert isinstance(metric["value"], float) and metric["unit"]
     return result, obs
@@ -403,7 +430,8 @@ def test_a_new_family_joins_by_files_and_list_entries(tree):
     cell = spec.Cell(TINY_FAMILY_CELL, bench, benchmark_json)
     # (and the one entry every cell reports, which has no list)
     assert {e["name"] for e, _ in cell.readers("per_layer")} \
-        == {*TINY_FAMILY_JOINS[:-1], "window_compiles"}
+        == {*TINY_FAMILY_JOINS[:-1], "window_compiles",
+            "tiny_absorb_time_share"}
     obs = _traced_run_of_the_family(cell)
     reads = {entry["name"]: read(obs)
              for entry, read in cell.readers("per_layer")}
@@ -434,9 +462,57 @@ def test_a_new_family_joins_by_files_and_list_entries(tree):
     assert reads["dsa_selected_share"] == pytest.approx(100 * 16 / 40)
     assert reads["mla_absorb_time_share"] == pytest.approx(
         100 * 100 / layer_us)
-    assert reads["mla_shared_expert_time_share"] == pytest.approx(
+    assert reads["moe_shared_expert_time_share"] == pytest.approx(
         100 * 100 / layer_us)
+    # its own entry's reader file, dropped in beside the others
+    assert reads["tiny_absorb_time_share"] \
+        == reads["mla_absorb_time_share"]
     assert reads["mla_held_rows_share"] == pytest.approx(100 * 2 / 8)
+
+
+def test_the_appended_entries_pass_the_table_wide_checks(tree):
+    """The tree holds three configurations, six cells and two ``per_layer``
+    entries more than the repo's: the yardstick's check of every name, the
+    table's one limit and that every reader file has an entry, and the
+    scope readers' check, all pass on it."""
+    from benchmarks.tests.test_scope_names import scope_entries_hold
+    from benchmarks.tests.test_yardstick import (benchmark_at,
+                                                 names_lead_to_files)
+
+    root = os.path.dirname(tree[1])
+    names_lead_to_files(root)
+    assert "tiny_absorb_time_share" in scope_entries_hold(root)
+    assert len(benchmark_at(root)["per_layer"]) \
+        == len(benchmark_at(spec.ROOT)["per_layer"]) + 2
+
+
+@pytest.mark.parametrize("module", CELL_TESTS)
+def test_the_appended_entries_fail_no_cell_tests_entry_assertions(
+        tree, module):
+    """Each cell test's assertions about the entries ITS cell reports hold
+    on the tree that a later PR's configuration, cells and entries were
+    appended to: none counts the table or says what another family's names
+    are (PRs 57 and 61 could enter none of their readers because three
+    did)."""
+    import importlib
+
+    cell_test = importlib.import_module(f"benchmarks.tests.{module}")
+    cell_test.the_cells_entries(os.path.dirname(tree[1]))
+
+
+def test_every_cell_test_with_entry_assertions_is_rehearsed():
+    here = os.path.dirname(os.path.abspath(__file__))
+    with_assertions = set()
+    for name in os.listdir(here):
+        if name.startswith("test_") and name.endswith("_cell.py"):
+            with open(os.path.join(here, name)) as f:
+                text = f.read()
+            if "def the_cells_entries(" in text:
+                with_assertions.add(name[:-3])
+            # no cell test counts the table's entries
+            assert 'len(cell.benchmark["per_layer"])' not in text, name
+            assert 'benchmark["per_layer"]) ==' not in text, name
+    assert with_assertions == set(CELL_TESTS)
 
 
 def test_command_refuses_without_a_chip(tree):
@@ -449,6 +525,22 @@ def test_command_refuses_without_a_chip(tree):
     assert bench_run.main(
         ["--workload", "smollm2-360m.train-1chip", "--seed", "1",
          "--seconds", "1", "--trace", "0"]) == 2
+
+
+def test_the_command_says_what_it_compared_last(monkeypatch, capsys):
+    """``main`` ends standard error with each number ``correct`` compared
+    beside its limit, and standard output with the result line, whose last
+    key holds the same."""
+    result = {"correct": False, "attempted": 3, "failed": 0, "metrics": {},
+              "device": {}, "compared": {"loss_gap": [0.5, 0.002],
+                                         "window_compiles": [0, 0]}}
+    monkeypatch.setattr(bench_run, "measure", lambda argv: (result, {}))
+    assert bench_run.main([]) == 0
+    out, err = capsys.readouterr()
+    assert json.loads(out.splitlines()[-1]) == result
+    assert err.splitlines()[-3:] == [
+        "compared loss_gap 0.5 limit 0.002",
+        "compared window_compiles 0 limit 0", "correct False"]
 
 
 @pytest.mark.parametrize("tied", [False, True])

@@ -1,14 +1,16 @@
-"""Trinity-Mini's part of the benchmark: ``lib/afmoe_flops.py`` against
-hand-worked numbers and the program's own tree; the cell's names lead to its
-files and it joins what its program does (117 of the table's 128 entries);
+"""Trinity-Mini's part of the benchmark: ``lib/afmoe_flops.py`` (the train
+step's counts, which the file names under ``train_counts``) and
+``lib/moe_flops.py`` (the grouped matmuls') against hand-worked numbers and
+the program's own tree; the cell's names lead to its files and it joins
+what its program does (``train_mfu``, ``flash_attention_roofline``, the
+``train_expert_*`` entries);
 the train step of a toy of the same shape compiled for a v5e that is
 described, not attached, with its kernels under the scopes the readers sum
 (the REAL widths' step takes a minute or more to compile:
 ``benchmarks/tools/train_step_aot.py`` does that by hand, PERF.md section
 4); a CPU rehearsal of the toy through ``run.measure`` with
-``afmoe_decoder`` as its reference; and the readers this PR wrote,
-``afmoe_train_mfu`` and the five that are not entered yet, on observations
-that are given.
+``afmoe_decoder`` as its reference; and the readers it reports through, on
+observations that are given.
 """
 
 import json
@@ -20,8 +22,8 @@ import types
 import pytest
 
 from benchmarks import run as bench_run
-from benchmarks.lib import (afmoe_flops, afmoe_names, program, scope_names,
-                            spec)
+from benchmarks.lib import (afmoe_flops, flash_names, moe_flops, moe_names,
+                            program, readers, scope_names, spec)
 from benchmarks.tests import test_rehearsal
 # ``topo`` is described inside that file's fixture (never at import);
 # ``compiled_kernels`` keeps these compiles out of the persistent cache.
@@ -31,9 +33,8 @@ from benchmarks.tests.test_aot_real_widths import (  # noqa: F401
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 CONFIG = "trinity-mini"
 CELL = "trinity-mini.train-8k-1chip"
-NOT_ENTERED = ("swa_train_attention_roofline", "train_expert_matmul_roofline",
-               "train_expert_ffn_time_share", "train_routing_time_share",
-               "train_balance_update_time_share")
+OWN_ENTRIES = ("train_expert_matmul_roofline", "train_expert_ffn_time_share",
+               "train_routing_time_share")
 
 
 # ------------------------------------------------------------------ flops
@@ -56,7 +57,12 @@ def test_operations_by_hand():
     assert afmoe_flops.layer_counts(c) == (1, 4)
     attention = 2048 * 4096 * 3 + 2 * 2048 * 512
     assert afmoe_flops.attention_params(c) == attention == 27_262_976
-    assert afmoe_flops.expert_params(c) == 6_291_456
+    assert afmoe_flops.expert_params(c) == moe_flops.expert_params(c) \
+        == 6_291_456
+    # the experts' facts as every expert cell's readers take them
+    assert (moe_flops.expert_width(c), moe_flops.expert_layers(c),
+            moe_flops.experts_held(c), moe_flops.expert_matrices(c),
+            moe_flops.expert_row_width(c)) == (1024, 4, 16, 3, 2048)
     assert afmoe_flops.held_rows_per_token(c) == 1.0
     matmuls = 5 * attention + 3 * 2048 * 6144 \
         + 4 * (2048 * 128 + 2 * 6_291_456) + 2048 * 25024
@@ -77,14 +83,14 @@ def test_operations_by_hand():
     # the grouped matmuls of a step over the rows the held experts got:
     # at the expected 8,192 a layer FLOPs bound them on a v5e, at the 1,400
     # a layer the first traced run's kernels point to (PERF.md) the bytes
-    assert afmoe_flops.expert_matmul_train_flops(c, 4 * 8192) \
+    assert moe_flops.expert_matmul_train_flops(c, 4 * 8192) \
         == 3 * 2 * 4 * 8192 * 6_291_456
-    assert afmoe_flops.expert_matmul_train_bytes(c, 4 * 8192) \
+    assert moe_flops.expert_matmul_train_bytes(c, 4 * 8192) \
         == 4 * 16 * 6_291_456 * 8 + 4 * 4 * 8192 * 2048 * 2
-    assert afmoe_flops.expert_matmul_train_flops(c, 4 * 8192) / 197e12 \
-        > afmoe_flops.expert_matmul_train_bytes(c, 4 * 8192) / 819e9
-    assert afmoe_flops.expert_matmul_train_flops(c, 4 * 1400) / 197e12 \
-        < afmoe_flops.expert_matmul_train_bytes(c, 4 * 1400) / 819e9
+    assert moe_flops.expert_matmul_train_flops(c, 4 * 8192) / 197e12 \
+        > moe_flops.expert_matmul_train_bytes(c, 4 * 8192) / 819e9
+    assert moe_flops.expert_matmul_train_flops(c, 4 * 1400) / 197e12 \
+        < moe_flops.expert_matmul_train_bytes(c, 4 * 1400) / 819e9
 
 
 def test_the_programs_tree_is_what_the_file_counts():
@@ -111,11 +117,15 @@ def test_the_programs_tree_is_what_the_file_counts():
     assert max(max(x.shape) for x in jax.tree.leaves(shapes)) <= 25024
 
 
-def test_the_cells_names_lead_to_files_and_join_the_train_metrics():
-    from benchmarks.tests.test_yardstick import names_lead_to_files
+def the_cells_entries(root=spec.ROOT):
+    """What THIS cell reports, on the tree at ``root`` (the rehearsal's has
+    a later PR's entries appended: nothing here counts the table or says
+    what another family's names are)."""
+    from benchmarks.tests.test_yardstick import (cell_at, names_lead_to_files,
+                                                 reader_at)
 
-    names_lead_to_files(spec.ROOT)
-    cell = spec.Cell(CELL)
+    names_lead_to_files(root)
+    cell = cell_at(root, CELL)
     assert cell.chips == 1 and cell.workload["kind"] == "train_lm"
     assert cell.workload["trainer"] == {
         "mesh": None, "fused_optimizer": True, "prefetch_batches": 2,
@@ -127,33 +137,40 @@ def test_the_cells_names_lead_to_files_and_join_the_train_metrics():
     assert entry["reduced"] == [r["key"] for r in cell.config["reduced"]]
     assert len(entry["why"]) <= 200 and len(cell.entry["why"]) <= 200
     names = {m["name"] for m in cell.metric_entries("per_layer")}
-    assert {"afmoe_train_mfu", "train_step_device_ms", "flash_dq_time_share",
-            "flash_dkdv_time_share", "flash_fwd_time_share",
-            "train_optimizer_time_share", "train_ffn_time_share",
-            "train.device_idle_share", "train.hbm_peak_in_use_bytes",
-            "train_worker_start_s", "setup_trace_s"} <= names
-    # their readers divide by lib/flops.py's dense, full-causal counts
-    assert not names & {"train_mfu", "flash_attention_roofline"}
-    assert not names & set(NOT_ENTERED)
+    assert {"train_mfu", "flash_attention_roofline", "train_step_device_ms",
+            "flash_dq_time_share", "flash_dkdv_time_share",
+            "flash_fwd_time_share", "train_optimizer_time_share",
+            "train_ffn_time_share", "train.device_idle_share",
+            "train.hbm_peak_in_use_bytes", "train_worker_start_s",
+            "setup_trace_s", *OWN_ENTRIES} <= names
+    # the whole step's share of the peak and the flash kernels' of their
+    # floor are every train cell's entries, over the counts the file names:
+    # a layer inside its mask, experts held, not lib/flops.py's dense
+    # full-causal decoder
+    assert cell.config["train_counts"] == "afmoe_flops"
+    assert readers.train_counts(cell).__name__.endswith("afmoe_flops")
     assert {m["name"] for m in cell.metric_entries("end_to_end")} \
         == {"train_tokens_per_s_per_chip", "setup_s"}
-    mfu = next(m for m in cell.benchmark["per_layer"]
-               if m["name"] == "afmoe_train_mfu")
-    assert mfu == {"name": "afmoe_train_mfu", "unit": "%",
-                   "better": "higher", "source": "host_clock",
-                   "layer": "train step program",
-                   "moves": "train_tokens_per_s_per_chip",
-                   "workloads": [CELL]}
-    assert len(cell.benchmark["per_layer"]) == 117
-    for reader in NOT_ENTERED:      # written, waiting for their entries
-        assert callable(spec.load_module("metrics", reader).read)
+    entries = {m["name"]: m for m in cell.metric_entries("per_layer")}
+    assert entries["train_mfu"]["source"] == "host_clock"
+    for name in OWN_ENTRIES:
+        assert entries[name] == {
+            "name": name, "unit": "%", "source": "device_trace",
+            "better": "higher" if name.endswith("roofline") else "lower",
+            "layer": "expert layer", "moves": "train_tokens_per_s_per_chip",
+            "workloads": entries[name]["workloads"]}
+        assert callable(reader_at(root, name).read)
+
+
+def test_the_cells_names_lead_to_files_and_join_the_train_metrics():
+    the_cells_entries()
 
 
 # ------------------------------------------------ a toy of the same shape
 TINY = {
     "name": "tiny-afmoe", "source": "none (test)",
-    "reference": "afmoe_decoder", "model_type": "afmoe",
-    "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 4,
+    "reference": "afmoe_decoder", "train_counts": "afmoe_flops",
+    "model_type": "afmoe", "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 4,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "intermediate_size": 96, "max_position_embeddings": 256,
     "rope_theta": 10000, "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
@@ -252,16 +269,20 @@ def test_a_toy_of_the_same_shape_trains_end_to_end_on_the_cpu(
     assert set(result["metrics"]) == {"train_tokens_per_s_per_chip",
                                       "setup_s"}
     rate = result["metrics"]["train_tokens_per_s_per_chip"]["value"]
-    mfu = next(read for entry, read in obs["cell"].readers("per_layer")
-               if entry["name"] == "afmoe_train_mfu")
-    assert mfu(obs) == pytest.approx(
+    reads = {entry["name"]: read
+             for entry, read in obs["cell"].readers("per_layer")}
+    assert reads["train_mfu"](obs) == pytest.approx(
         100 * rate * afmoe_flops.train_flops_per_token(TINY, 128) / 197e12)
-    assert not {entry["name"] for entry, _ in
-                obs["cell"].readers("per_layer")} \
-        & {"train_mfu", "flash_attention_roofline"}
-    # the readers that wait for their entries find no trace to read
-    for reader in NOT_ENTERED:
-        assert spec.load_module("metrics", reader, bench).read(obs) is None
+    # the last step's own count of its experts' rows is handed over: 3
+    # expert layers x the 16 experts the router scores, 4 picks a token
+    rows = obs["expert_rows"]
+    assert len(rows) == 3 and {len(layer) for layer in rows} == {16}
+    assert {sum(layer) for layer in rows} == {2 * 128 * 4}
+    assert moe_names.held_rows_a_step(obs) == sum(
+        sum(layer[:4]) for layer in rows) > 0
+    # what needs a device trace finds none to read
+    for name in ("flash_attention_roofline", *OWN_ENTRIES):
+        assert reads[name](obs) is None
 
 
 # ----------------------------------------- the toy's step, for the chip
@@ -315,8 +336,8 @@ def test_the_toys_step_compiles_for_a_v5e_with_its_kernels_in_scope(
 def test_the_readers_arithmetic_on_given_observations(monkeypatch):
     """A traced step of 0.5 s whose flash kernels take 60 ms, grouped
     matmuls 20 ms, and whose scopes are given: each reader is its count of
-    ``afmoe_flops`` at the chip's peaks over those seconds, and nothing
-    where the trace or the scopes hold nothing."""
+    ``afmoe_flops`` / ``moe_flops`` at the chip's peaks over those seconds,
+    and nothing where the trace or the scopes hold nothing."""
     c = _json("configs", CONFIG)
     obs = {"cell": types.SimpleNamespace(config=c, bench_dir=spec.BENCH_DIR,
                                          name=CELL),
@@ -325,37 +346,49 @@ def test_the_readers_arithmetic_on_given_observations(monkeypatch):
            "groups": [{"steps": 10, "t_start": 0.0, "t_end": 5.0}],
            "trace": types.SimpleNamespace(devices=[object()])}
     # 16,384 tokens/s x 3 x 737.95 MFLOP / 197 TFLOP/s
-    assert afmoe_names.train_mfu(obs) == pytest.approx(
+    assert readers.train_mfu(obs) == pytest.approx(
         100 * 16384 * 3 * 737_951_744 / 197e12)
-    assert 18 < afmoe_names.train_mfu(obs) < 19
-    assert afmoe_names.train_mfu({**obs, "groups": []}) is None
+    assert 18 < readers.train_mfu(obs) < 19
+    assert readers.train_mfu({**obs, "groups": []}) is None
+    # a file that names no module is counted as a dense full-causal decoder
+    dense = {**obs, "cell": types.SimpleNamespace(
+        config={k: v for k, v in c.items() if k != "train_counts"},
+        bench_dir=spec.BENCH_DIR, name=CELL)}
+    assert readers.train_counts(dense["cell"]).__name__.endswith(".flops")
+    assert readers.train_mfu(dense) > 1.3 * readers.train_mfu(obs)
+    with pytest.raises(spec.SpecError):
+        readers.train_counts(types.SimpleNamespace(
+            config=dict(c, train_counts="none"), bench_dir=spec.BENCH_DIR,
+            name=CELL))
 
     seconds = {"fwd": 0.02, "dq": 0.03, "dkdv": 0.07}
-    monkeypatch.setattr(afmoe_names.flash_names, "kernel_seconds",
+    monkeypatch.setattr(flash_names, "kernel_seconds",
                         lambda trace, kernel: seconds[kernel])
-    monkeypatch.setattr(afmoe_names.readers, "train_step_device_ms",
-                        lambda obs: 500.0)
+    monkeypatch.setattr(readers, "train_step_device_ms", lambda obs: 500.0)
     # two steps in the trace: a kernel's seconds are half a step's each
-    monkeypatch.setattr(afmoe_names.readers, "_share_of_steps",
+    monkeypatch.setattr(readers, "_share_of_steps",
                         lambda obs, s: 100.0 * s / 1.0)
     least = 3.5 * (4 * 14_681_088 + 33_558_528) * 16_384 / 197e12
-    assert afmoe_names.swa_train_attention_roofline(obs) == pytest.approx(
+    assert readers.flash_attention_roofline(obs) == pytest.approx(
         100 * least / 0.06)
-    assert 40 < afmoe_names.swa_train_attention_roofline(obs) < 50
+    assert 40 < readers.flash_attention_roofline(obs) < 50
+    # half the square in each of five layers would read 1.82 x that
+    assert readers.flash_attention_roofline(dense) == pytest.approx(
+        100 * 3.5 * 5 * (8192 * 8192 // 2) * 16_384 / 197e12 / 0.06)
     monkeypatch.setattr(
-        afmoe_names.ssm_names, "_leaves_inside", lambda trace, module: [
+        moe_names.ssm_names, "_leaves_inside", lambda trace, module: [
             (0.0, 0.03, "%ragged-dot-none.3 = f32[65536,1024] custom-call("),
             (0.1, 0.11, "%ragged-dot-none = bf16[65536,2048] custom-call("),
             (0.2, 0.9, "%fusion.7 = f32[8192,2048] fusion(")])
     # no step metric, no rows: nothing, not the expected rows' 100+%
-    assert afmoe_names.train_expert_matmul_roofline(obs) is None
+    assert moe_names.train_expert_matmul_roofline(obs) is None
     rows = [[500] * 16 + [40] * 112] * 4        # (expert layers, experts)
     obs["expert_rows"] = rows
-    assert afmoe_names.held_rows_a_step(obs) == 4 * 16 * 500
+    assert moe_names.held_rows_a_step(obs) == 4 * 16 * 500
     least = 3 * 2 * 32_000 * 6_291_456 / 197e12
-    assert afmoe_names.train_expert_matmul_roofline(obs) == pytest.approx(
+    assert moe_names.train_expert_matmul_roofline(obs) == pytest.approx(
         100 * least / 0.02)
-    assert afmoe_names.train_expert_matmul_roofline(obs) < 105
+    assert moe_names.train_expert_matmul_roofline(obs) < 105
     splits = {"train": scope_names.Split(
         {("expert_ffn", "forward"): 0.05, ("expert_ffn", "backward"): 0.10,
          ("router", "forward"): 0.01, ("expert_dispatch", "backward"): 0.03,
@@ -363,18 +396,18 @@ def test_the_readers_arithmetic_on_given_observations(monkeypatch):
         1.0, [])}
     monkeypatch.setattr(scope_names, "split",
                         lambda obs, which: splits.get(which))
-    assert afmoe_names.expert_ffn_time_share(obs) == pytest.approx(15.0)
-    assert afmoe_names.routing_time_share(obs) == pytest.approx(4.0)
-    assert afmoe_names.balance_update_time_share(obs) == pytest.approx(0.1)
+    assert moe_names.train_expert_ffn_time_share(obs) == pytest.approx(15.0)
+    assert moe_names.train_routing_time_share(obs) == pytest.approx(4.0)
+    # a served step's entries read the decode programs, not the train step
+    assert moe_names.expert_ffn_time_share(obs) is None
     # a program without the kernels or the scopes (the parent): nothing
     seconds.update(fwd=0.0, dq=0.0, dkdv=0.0)
-    monkeypatch.setattr(afmoe_names.ssm_names, "_leaves_inside",
+    monkeypatch.setattr(moe_names.ssm_names, "_leaves_inside",
                         lambda trace, module: [])
     splits["train"] = scope_names.Split({("ffn", "forward"): 0.2}, 1.0, [])
-    for read in (afmoe_names.swa_train_attention_roofline,
-                 afmoe_names.train_expert_matmul_roofline,
-                 afmoe_names.expert_ffn_time_share,
-                 afmoe_names.routing_time_share,
-                 afmoe_names.balance_update_time_share):
+    for read in (readers.flash_attention_roofline,
+                 moe_names.train_expert_matmul_roofline,
+                 moe_names.train_expert_ffn_time_share,
+                 moe_names.train_routing_time_share):
         assert read(obs) is None
         assert read({**obs, "trace": None}) is None

@@ -380,6 +380,15 @@ def fixed_observations(config, cell_name):
     ("phi-4-mini-flash-reasoning",
      "phi-4-mini-flash-reasoning.serve-long-prompt", "sambay_flops",
      0.01100107968493337),
+    # as 5e17fcb's own floor modules priced them (PR 64: cell 14's grouped
+    # matmuls and state update are since counted by ``moe_flops`` /
+    # ``ssm_flops`` at the shapes its file states, its experts' load by
+    # ``moe_names.chunk_medians``)
+    ("solar-open2-250b", "solar-open2-250b.serve-long-prompt", "kda_flops",
+     0.008027047273679378),
+    ("nemotron-3-super-120b-a12b",
+     "nemotron-3-super-120b-a12b.serve-reasoning-decode", "nemotron_flops",
+     0.005115520792778819),
 ])
 def test_a_configurations_floor_is_its_familys_of_the_parent(
         config, cell, module, parent_s):
@@ -407,6 +416,25 @@ def test_a_configurations_floor_is_its_familys_of_the_parent(
 
 
 # ------------------------------------------------------------------- spec
+def benchmark_at(root):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def cell_at(root, name):
+    """The cell of ``<root>/BENCHMARK.json`` over ``<root>/benchmarks``: a
+    cell test's entry assertions run on the repo's tree and, from the
+    rehearsal, on a tree that a later PR's files and entries were added
+    to."""
+    return spec.Cell(name, os.path.join(root, "benchmarks"),
+                     os.path.join(root, "BENCHMARK.json"))
+
+
+def reader_at(root, name):
+    return spec.load_module("metrics", name.rsplit(".", 1)[-1],
+                            os.path.join(root, "benchmarks"))
+
+
 def names_lead_to_files(root):
     """Every name in ``<root>/BENCHMARK.json`` against the files under
     ``<root>/benchmarks`` (the rehearsal calls this on its throw-away
@@ -436,9 +464,18 @@ def names_lead_to_files(root):
                              cell.readers("end_to_end")}
     for m in bench["per_layer"]:
         assert m["moves"] in end_to_end
-    # one entry a question: the table has room, and no two entries are one
-    # reader of one end-to-end metric in the same cells
+    # one entry a question: the table has room (the ONE count of its
+    # entries that any test holds: a later PR appends its own), no reader
+    # waits beside it without an entry, and no two entries are one reader
+    # of one end-to-end metric in the same cells
     assert len(bench["per_layer"]) <= 128
+    entered = {m["name"].rsplit(".", 1)[-1]
+               for group in ("end_to_end", "per_layer")
+               for m in bench[group]}
+    files = {f[:-3] for f in os.listdir(os.path.join(bench_dir, "metrics"))
+             if f.endswith(".py") and f != "__init__.py"}
+    assert files == entered, (sorted(files - entered),
+                              sorted(entered - files))
     asked = [(m["name"].rsplit(".", 1)[-1], m["moves"],
               tuple(m.get("workloads", ()))) for m in bench["per_layer"]]
     assert len(set(asked)) == len(asked)
