@@ -40,9 +40,9 @@ from ray_tpu.parallel.sharding import (axes_entry, current_rules,
 
 PyTree = Any
 LAYER_KINDS = ("attention", "mamba", "window", "conv", "mamba1", "gmu",
-               "cross", "kda")
+               "cross", "kda", "power")
 # The kinds that keep a state a slot (``state_mixer``): a model has one.
-STATE_KINDS = ("mamba", "mamba1", "conv", "kda")
+STATE_KINDS = ("mamba", "mamba1", "conv", "kda", "power")
 # Leaves that stay float32 whatever type the weights are served in.
 FLOAT32_LEAVES = ("router_bias",)
 # The deviation a router's selection bias is drawn with: of the order of
@@ -349,6 +349,23 @@ class LlamaConfig:
     kda_conv: int = 4
     kda_gate_rank: int = 128
     kda_chunk: int = 64
+    # POWER-RETENTION layers (kind "power", models/power_retention.py;
+    # served only, dense plane): a squared-product linear attention of
+    # n_heads query heads in groups over n_kv_heads key/value heads of
+    # head_dim, q and k normed a head and rotated (``rope``) on the way in;
+    # a key/value head's state is the symmetric square of its keys against
+    # its values, head_dim (head_dim + 1) / 2 rows of head_dim, and a
+    # normaliser of as many (stored as ssm_state_dtype), decayed by one
+    # gate a head and position, log sigmoid of a linear map (no bias: a
+    # checkpoint's gate is its weights alone); the prefill runs the
+    # recurrence power_chunk positions at a time.  power_gate_shift, for
+    # RANDOM weights alone, shifts the gate's pre-activation by a constant
+    # a head, its two ends and evenly between (power_retention.init_params
+    # says what of a trained gate it stands for).  A model of these layers
+    # alone holds no K/V.
+    power_chunk: int = 256
+    power_gate_shift: Optional[Tuple[float, float]] = None
+    power_eps: float = 1e-6
     # An output GATE on every attending layer: what the output projection
     # reads is multiplied by ``sigmoid(W_gate h)``, h the layer's normed
     # input, a value an attention output (leaf ``w_attn_gate``; served
@@ -383,6 +400,9 @@ class LlamaConfig:
             for kind in self.layer_types))
         object.__setattr__(self, "nope_kinds", tuple(self.nope_kinds))
         object.__setattr__(self, "moe_held", tuple(self.moe_held))
+        if self.power_gate_shift is not None:
+            object.__setattr__(self, "power_gate_shift",
+                               tuple(self.power_gate_shift))
         if self.block_pattern:
             kinds, _ = self._blocks_to_layers()
             if self.layer_pattern or self.layer_types not in ((), kinds):
@@ -439,17 +459,28 @@ class LlamaConfig:
             raise ValueError("a kda layer needs kda_heads, kda_gate_rank, "
                              "kda_conv of 2 or more and a kda_chunk of "
                              "whole sublane tiles")
+        if "power" in kinds and (
+                self.head_dim % 2 or self.power_chunk % 8
+                or len(self.power_gate_shift or (0, 0)) != 2
+                or self.n_heads % self.n_kv_heads or self.kv_lora_rank
+                or self.index_topk or self.block_pattern):
+            raise ValueError(
+                "a power layer is built at degree 2 (the symmetric square "
+                "of a key of an even head_dim), with a power_chunk of whole "
+                "sublane tiles, no power_gate_shift or its two ends, whole "
+                "groups of query heads, and neither a latent cache, an "
+                "indexer nor a block_pattern beside it")
         if len(kinds & set(STATE_KINDS)) > 1:
             raise ValueError(
-                "mamba, mamba1, conv and kda layers keep their states under "
-                "the same leaves of the serving cache (ssm, conv): they "
+                "mamba, mamba1, conv, kda and power layers keep their states "
+                "under the same leaves of the serving cache (ssm, conv): they "
                 "do not mix, one kind of state-keeping layer a model")
-        if "window" in kinds and kinds & {"mamba", "conv", "kda"}:
+        if "window" in kinds and kinds & {"mamba", "conv", "kda", "power"}:
             raise ValueError(
                 "window rings beside a recurrent or conv state are built "
                 "and held to a reference for Mamba-1 layers alone (a "
-                "decoder-hybrid-decoder): window and mamba, conv or kda "
-                "layers do not mix")
+                "decoder-hybrid-decoder): window and mamba, conv, kda or "
+                "power layers do not mix")
         self._check_cross_decoder(kinds)
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm is over the whole projection, "
@@ -986,6 +1017,10 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         from ray_tpu.models import kda
 
         axes["layers"].update(kda.param_axes(config))
+    if config.layers_of("power"):
+        from ray_tpu.models import power_retention
+
+        axes["layers"].update(power_retention.param_axes(config))
     if config.attn_gate:
         axes["layers"]["w_attn_gate"] = ("layers", "embed", "heads")
     if config.layers_of("gmu"):
@@ -1170,6 +1205,12 @@ def init_params(rng: jax.Array, config: LlamaConfig,
 
         params["layers"].update(kda.init_params(
             jax.random.fold_in(rng, 78), c, c.layers_of("kda"), dtype,
+            dense))
+    if c.layers_of("power"):
+        from ray_tpu.models import power_retention
+
+        params["layers"].update(power_retention.init_params(
+            jax.random.fold_in(rng, 75), c, c.layers_of("power"), dtype,
             dense))
     if c.attn_gate:
         params["layers"]["w_attn_gate"] = dense(
@@ -2038,6 +2079,8 @@ def _leaf_kind(name: str) -> Optional[str]:
         return "gmu"
     if name.startswith("kda_"):
         return "kda"
+    if name.startswith("power_"):
+        return "power"
     return "mamba" if name.startswith("ssm_") else None
 
 
@@ -2144,6 +2187,12 @@ def state_mixer(kind: str):
         from ray_tpu.models import kda
 
         return kda, "ssm_proj", "ssm_out"
+    if kind == "power":
+        # (its q and k are rotated: the caller's ``state_step`` hands it
+        # the rope table, ``power_retention.ROPES``)
+        from ray_tpu.models import power_retention
+
+        return power_retention, "ssm_proj", "ssm_out"
     from ray_tpu.models import mamba2
 
     return mamba2, "ssm_proj", "ssm_out"
@@ -2248,7 +2297,9 @@ def walk_block(sin, cos, positions, kv_step: Callable, window_step=None,
 
         def state_step(mixer, h):
             nonlocal memory
-            out, ys, *scan_output = mixer.prefill(h, layer, c, lengths)
+            out, ys, *scan_output = mixer.prefill(
+                h, layer, c, lengths,
+                *((sin, cos) if getattr(mixer, "ROPES", False) else ()))
             if scan_output and memory is not None:
                 memory = scan_output[0] if memory_at is None else \
                     jnp.take_along_axis(scan_output[0],
@@ -3104,8 +3155,10 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
     last_logits, ys, expert_rows, states, window = layer_walk(
         params, tokens, config, kv_step, lengths=lengths,
         window_step=window_step)
-    # latent attention keeps ONE leaf: its rows come back as ``ks``
-    ks, vs, *index_keys = (ys, None) if config.kv_lora_rank else ys
+    # latent attention keeps ONE leaf: its rows come back as ``ks``; a
+    # model without an attending layer keeps no rows at all
+    ks, vs, *index_keys = (ys, None) \
+        if config.kv_lora_rank or ys is None else ys
     return (last_logits, ks, vs, expert_rows, states, window,
             index_keys[0] if index_keys else None)
 

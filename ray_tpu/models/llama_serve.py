@@ -86,7 +86,9 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
     ``cfg.ssm_state_dtype``) and conv window ``conv (Lm, K - 1, B,
     conv_dim)``; for its short-convolution layers ``conv (Lc, taps - 1,
     B, D)`` alone; for its KDA layers ``ssm (Lk, B, H, d, d)``, a matrix a
-    head, and ``conv (Lk, K - 1, B, 3 H d)``.  A state is not positional: ``build_prefill`` replaces a
+    head, and ``conv (Lk, K - 1, B, 3 H d)``; for its power-retention layers
+    ``ssm (Lp, B, Hkv, d/2 + 2, d, d)`` alone (``ops/power_state_update.py``
+    has the layout), and NO ``k`` or ``v`` where no layer attends.  A state is not positional: ``build_prefill`` replaces a
     slot's whole state, ``decode_step`` advances it in place."""
     if cfg.layers_of("window") or cfg.kv_layer is not None:
         # (a decoder-hybrid-decoder: the pools its kinds of layer ask for
@@ -115,6 +117,11 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
         # position would be padded to one.
         cache = {name: _row_pool(cfg, cfg.layers_of("attention"), slots,
                                  max_len) for name in ("k", "v")}
+    elif not cfg.attending_layers():
+        # No layer attends: no K/V leaf at all (not one of no rows), and a
+        # slot costs the same whatever ``max_len`` is, which then bounds
+        # the positions a rope table is asked for and nothing else.
+        cache = {}
     else:
         cache = llama.init_kv_cache(cfg, slots, max_len)
     if cfg.layers_of("mamba"):
@@ -130,6 +137,11 @@ def init_cache(cfg: LlamaConfig, slots: int, max_len: int):
         from ray_tpu.models import kda
 
         cache.update(kda.init_state(cfg, cfg.layers_of("kda"), slots))
+    if cfg.layers_of("power"):
+        from ray_tpu.models import power_retention
+
+        cache.update(power_retention.init_state(
+            cfg, cfg.layers_of("power"), slots))
     return cache
 
 
@@ -203,8 +215,8 @@ def kv_rows(cfg: LlamaConfig, cache=None):
     None: by position and head, as the paged planes gather their blocks),
     and ``decode_attention``, what attends them on this backend: the Mosaic
     ``"kernel"`` or ``"xla"``.  {} for a latent cache, which keeps neither
-    K nor V."""
-    if cfg.kv_lora_rank:
+    K nor V, and for a model without an attending layer."""
+    if cfg.kv_lora_rank or not cfg.attending_layers():
         return {}
     as_rows = cache is not None and cache["k"].ndim == 4
     hkv, d = (cfg.kv_row_heads, cfg.kv_row_dim) if as_rows \
@@ -217,7 +229,9 @@ def share_and_state(cfg: LlamaConfig):
     """What ``serve.engine_build`` says beside ``kv_rows`` of a model that
     keeps a state a slot or holds experts: ``state_bytes_per_slot`` (the
     recurrent and conv states of all its layers together), ``ssm_groups``
-    of a Mamba-2 model, and ``experts_held`` of the ``experts_routed`` its
+    of a Mamba-2 model, ``power_state_rows`` (the rows of ``phi`` a
+    key/value head's state has as laid out), ``power_degree`` and
+    ``attending_layers`` of a power-retention model, and ``experts_held`` of the ``experts_routed`` its
     router scores.  {} for a plain dense decoder."""
     facts = {}
     state = state_bytes_per_slot(cfg)
@@ -225,6 +239,12 @@ def share_and_state(cfg: LlamaConfig):
         facts["state_bytes_per_slot"] = sum(state.values())
     if cfg.layers_of("mamba"):
         facts["ssm_groups"] = cfg.ssm_groups
+    if cfg.layers_of("power"):
+        from ray_tpu.models import power_retention
+
+        facts.update(power_state_rows=power_retention.state_rows(cfg),
+                     power_degree=power_retention.DEGREE,
+                     attending_layers=cfg.attending_layers())
     if cfg.moe_experts:
         facts.update(experts_held=cfg.held_experts[1],
                      experts_routed=cfg.moe_experts)
@@ -334,10 +354,13 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active,
         # past the attended prefix.  Their row goes out of range
         # and the scatter drops it.  (Nor may an inactive slot's
         # recurrent state advance: mamba2.decode.)
+        # (a model without an attending layer carries no K/V: ``ck`` is
+        # None, nothing is written by position and ``s_active`` bounds
+        # nothing)
         with jax.named_scope("kv_write"):
             rows = jnp.arange(tok.shape[0], dtype=jnp.int32)
-            pos = jnp.where(active & (lens < s_active), lens,
-                            ck.shape[2])
+            pos = None if ck is None else jnp.where(
+                active & (lens < s_active), lens, ck.shape[2])
             scale = cfg.attn_scale
             at = {"attention": pos}       # out of range past any row
             if n_win:
@@ -439,15 +462,17 @@ def decode_step(cfg: LlamaConfig, params, s_active: int, active,
 
                 def state_step(mixer, h):
                     # A state-keeping layer (Mamba-2, Mamba-1, short
-                    # convolution, KDA): its layer of the stacked states,
-                    # in place.
+                    # convolution, KDA, power retention): its layer of the
+                    # stacked states, in place.
                     nonlocal memory
                     m = llama.layer_index(p, n_of(kind), i)
                     if l0:
                         m = m + cfg.layers_before(l0, kind)
-                    held = ("conv",) if kind == "conv" else ("ssm", "conv")
+                    held = getattr(mixer, "HELD", ("ssm", "conv"))
                     out, *new = mixer.decode(
-                        h, layer, part, *(state[n] for n in held), m, active)
+                        h, layer, part, *(state[n] for n in held), m, active,
+                        *((sin, cos) if getattr(mixer, "ROPES", False)
+                          else ()))
                     if kind == "mamba1":
                         *new, scan_output = new
                         if memory is not None:
@@ -586,7 +611,8 @@ def _carry(cache, tok, lens):
     them."""
     if "latent" in cache:       # the one leaf, where K lies; no V
         return (cache["latent"], None, tok, lens)
-    return (cache["k"], cache["v"], tok, lens,
+    # (a model without an attending layer: neither)
+    return (cache.get("k"), cache.get("v"), tok, lens,
             *(cache[name] for name in _carried_names(cache)))
 
 
@@ -595,8 +621,8 @@ def _uncarry(carry, cache):
     ck, cv, tok, lens, *state = carry
     if "latent" in cache:
         return {"latent": ck}, tok, lens
-    return ({"k": ck, "v": cv, **dict(zip(_carried_names(cache), state))},
-            tok, lens)
+    kv = {} if ck is None else {"k": ck, "v": cv}
+    return {**kv, **dict(zip(_carried_names(cache), state))}, tok, lens
 
 
 # ------------------------------------------------------------- dense plane
@@ -675,7 +701,7 @@ def build_prefill(cfg: LlamaConfig) -> Callable:
                     cache["wk"], _ring_rows(window[0], lengths, ring), slots),
                 "wv": _insert_rows(
                     cache["wv"], _ring_rows(window[1], lengths, ring), slots)}
-        else:
+        elif ks is not None:
             cache = {**cache, "k": _insert_rows(cache["k"], ks, slots),
                      "v": _insert_rows(cache["v"], vs, slots)}
         if states is not None:

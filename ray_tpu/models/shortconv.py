@@ -35,6 +35,10 @@ import jax
 import jax.numpy as jnp
 
 
+# The state leaves of the serving cache this mixer keeps (no recurrent one).
+HELD = ("conv",)
+
+
 def param_axes(c) -> Dict[str, tuple]:
     return {
         "conv_in": ("layers", "embed", "mlp"),

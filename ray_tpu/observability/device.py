@@ -619,6 +619,7 @@ SCOPES = (
     "mamba1_scan", "mamba1_state_update", "gmu", "cross_attention",
     "diff_combine",
     "kda_chunk", "kda_state_update", "kda_gates",
+    "power_gate", "power_chunk", "power_state_update",
     "head", "sample", "head_loss", "optimizer",
 )
 PHASES = ("forward", "backward", "remat")

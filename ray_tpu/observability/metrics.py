@@ -485,6 +485,19 @@ def serve_engine_counters():
             "sent through the chunked delta rule's kernel "
             "(ops/kda_chunk.py); a shape that keeps XLA's form adds none",
             tag_keys=("deployment",)),
+        # A model with power-retention layers only.
+        "power_slots_advanced": Counter(
+            "ray_tpu_serve_power_slots_advanced_total",
+            "(slot, step) pairs whose decode step advanced the slot's "
+            "power-retention states: each is one read and one write of a "
+            "key/value head's symmetric-square state and normaliser a layer",
+            tag_keys=("deployment",)),
+        "power_chunk_positions": Counter(
+            "ray_tpu_serve_power_chunk_positions_total",
+            "padded prompt positions x power-retention layers the prefill "
+            "launches sent through the chunked form's kernel "
+            "(ops/power_chunk.py); a shape that keeps XLA's form adds none",
+            tag_keys=("deployment",)),
         # A model with Mamba-1 layers only.
         "mamba1_scan_positions": Counter(
             "ray_tpu_serve_mamba1_scan_positions_total",

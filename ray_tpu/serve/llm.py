@@ -387,7 +387,8 @@ class LLMServer:
         ) if on]
         stateful = [name for kind, name in (
             ("mamba", "state-space"), ("mamba1", "state-space"),
-            ("conv", "short-convolution"), ("kda", "linear-attention"))
+            ("conv", "short-convolution"), ("kda", "linear-attention"),
+            ("power", "power-retention"))
             if self.cfg.layers_of(kind)]
         if asked and stateful:
             raise ValueError(
@@ -450,7 +451,9 @@ class LLMServer:
             dbs.append(b)
             b *= 2
         dbs.append(max_len)
-        self.decode_buckets = tuple(dbs)
+        # (no attending layer: a step attends no prefix, ONE decode program)
+        self.decode_buckets = tuple(dbs) if self.cfg.attending_layers() \
+            else (max_len,)
         if params is None:
             params = llama.init_params(jax.random.key(seed), self.cfg)
         # One-time cast: per-use .astype(c.dtype) in the forward becomes
@@ -1993,15 +1996,18 @@ class LLMServer:
             total += moved
             self._engine_metrics["state_bytes"].inc(
                 moved, tags=self._state_tags[kind])
-        kda = {}
-        if self.cfg.layers_of("kda"):
-            # the matrix states alone, which ``ops/kda_state_update.py``
-            # moves: what its roofline is counted from
-            kda = {"kda_slots_advanced": rows,
-                   "kda_state_bytes": 2 * rows * self._state_bytes["ssm"]}
-            self._engine_metrics["kda_slots_advanced"].inc(
-                rows, tags=self._tags)
-        return {"state_rows_updated": rows, "state_bytes": total, **kda}
+        matrix = {}
+        for kind in ("kda", "power"):
+            if self.cfg.layers_of(kind):
+                # the matrix states alone, which ``ops/kda_state_update.py``
+                # (``ops/power_state_update.py``) moves: what its roofline
+                # is counted from
+                matrix = {f"{kind}_slots_advanced": rows,
+                          f"{kind}_state_bytes":
+                              2 * rows * self._state_bytes["ssm"]}
+                self._engine_metrics[f"{kind}_slots_advanced"].inc(
+                    rows, tags=self._tags)
+        return {"state_rows_updated": rows, "state_bytes": total, **matrix}
 
     def _record_prefill_group(self, t0: float, t1: float, bucket: int,
                               lens: np.ndarray, real: int,
@@ -2024,8 +2030,24 @@ class LLMServer:
             # chunks of the state-space scan (of the delta rule) the
             # padded group computed, a Mamba (a KDA) layer
             kda_layers = self.cfg.layers_of("kda")
-            chunk = self.cfg.kda_chunk if kda_layers else self.cfg.ssm_chunk
+            power_layers = self.cfg.layers_of("power")
+            chunk = self.cfg.kda_chunk if kda_layers \
+                else self.cfg.power_chunk if power_layers \
+                else self.cfg.ssm_chunk
             scan["scan_chunks"] = rows * -(-bucket // min(bucket, chunk))
+            if power_layers:
+                # positions x power-retention layers that went through
+                # ``ops/power_chunk.py``'s kernel (0 where the shape kept
+                # XLA's form), the bucket in whole blocks of chunks: what
+                # its roofline is counted from
+                from ray_tpu.models.power_retention import padded_len
+                from ray_tpu.ops.power_chunk import engages
+
+                scan["power_chunk_positions"] = (
+                    rows * padded_len(bucket, chunk) * power_layers
+                    if engages(self.cfg.head_dim, chunk) else 0)
+                m["power_chunk_positions"].inc(
+                    scan["power_chunk_positions"], tags=self._tags)
             if kda_layers:
                 # positions x KDA layers that went through
                 # ``ops/kda_chunk.py`` (0 where the shape kept XLA's form):

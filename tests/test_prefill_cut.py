@@ -150,7 +150,7 @@ def test_warm_up_holds_every_shape_the_cut_emits(cell):
                       if r == rungs[0] or r * b <= 2048}
     # (4,096 and up: a row a launch, whatever the ladder; 512 / 1,024 /
     # 2,048: a row a launch and four rows of 512)
-    assert len(warmed) == (3 if buckets[0] > 2048
+    assert len(warmed) == (len(buckets) if buckets[0] > 2048
                            else 4 if buckets == (512, 1024, 2048)
                            else {3: 9, 5: 12}[len(buckets)])
     rng = np.random.default_rng(7)
