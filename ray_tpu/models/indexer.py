@@ -134,14 +134,28 @@ def topk_keep(x: jax.Array, k: int) -> jax.Array:
     return (above | (equal & (pos <= last[..., None]))) & (x > -jnp.inf)
 
 
-def prefill_keep(qi, ki_t, w, k: int) -> Optional[jax.Array]:
+def prefill_keep(qi, ki_t, w, k: int, lengths=None) -> Optional[jax.Array]:
     """Which keys each query of a prompt attends, as int8 (B, P, P): query
     t sees key s iff s <= t and s is among the ``k`` highest ``I_{t,.}``
     over s <= t.  A ``QUERY_TILE`` of queries at a time.  None where the
-    prompt is no longer than ``k``: every query sees every key before it."""
+    prompt is no longer than ``k``: every query sees every key before it.
+
+    A launch of one row of whole tiles takes ``ops/index_select.py``'s
+    kernel (by shape: ``index_select.engages``), which is told the rows'
+    ``lengths`` (B,) and leaves the queries wholly past a row's length
+    unselected, their rows of the mask zeros; every other shape XLA's form
+    below, which selects for every query of the bucket."""
+    from ray_tpu.ops import index_select
+
     B, P, Hi, Di = qi.shape
     if P <= k:
         return None
+    if index_select.engages(B, P, k, Hi, Di, QUERY_TILE):
+        if lengths is None:
+            lengths = jnp.full((B,), P, jnp.int32)
+        with jax.named_scope("index_select"):
+            return index_select.prefill_keep(qi, ki_t, w, lengths, k,
+                                             QUERY_TILE)
     tile = QUERY_TILE if P % QUERY_TILE == 0 else P
     key_pos = jnp.arange(P, dtype=jnp.int32)
 
@@ -160,6 +174,28 @@ def prefill_keep(qi, ki_t, w, k: int) -> Optional[jax.Array]:
     keep = jax.lax.map(one, (tiles(qi), tiles(w),
                              jnp.arange(0, P, tile, dtype=jnp.int32)))
     return jnp.moveaxis(keep, 0, 1).reshape(B, P, P)
+
+
+def prefill_tiles(c, bucket: int, lengths) -> Optional[Tuple[str, int, int]]:
+    """Host arithmetic for the engine's own account of a prefill group of
+    ``len(lengths)`` rows x ``bucket`` positions (``lengths``: a row's real
+    positions, 0 a padding row): ``(the form prefill_keep takes -- "kernel"
+    or "xla" --, the group's query tiles a layer, those of them that start
+    at or past their row's length and that the kernel therefore declines)``;
+    XLA's form declines none.  None where the bucket is no longer than
+    ``index_topk``: nothing is selected."""
+    from ray_tpu.ops import index_select
+
+    if bucket <= c.index_topk:
+        return None
+    rows = len(lengths)
+    if not index_select.engages(rows, bucket, c.index_topk, c.index_heads,
+                                c.index_head_dim, QUERY_TILE):
+        tile = QUERY_TILE if bucket % QUERY_TILE == 0 else bucket
+        return "xla", rows * (bucket // tile), 0
+    tiles = bucket // QUERY_TILE
+    run = sum(min(-(-int(n) // QUERY_TILE), tiles) for n in lengths)
+    return "kernel", rows * tiles, rows * tiles - run
 
 
 @jax.named_scope("index_select")

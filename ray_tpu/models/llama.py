@@ -3145,7 +3145,7 @@ def prefill_with_states(params: PyTree, tokens: jax.Array,
 
         qi, ki, w = index
         ki_t = jnp.swapaxes(ki, 1, 2)
-        keep = indexer.prefill_keep(qi, ki_t, w, config.index_topk)
+        keep = indexer.prefill_keep(qi, ki_t, w, config.index_topk, lengths)
         with jax.named_scope("sparse_attention"):
             return attend(q, k, v, positions, None, keep), (k, v, ki_t)
 

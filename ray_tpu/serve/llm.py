@@ -547,6 +547,16 @@ class LLMServer:
             **llama_serve.kv_rows(self.cfg,
                                   None if self.paged else self.cache),
             **llama_serve.share_and_state(self.cfg)}
+        if self.cfg.index_topk:
+            # which form selects a prefill's keys: the Mosaic kernel where
+            # a warmed (rows, bucket) engages it, XLA's for the rest
+            from ray_tpu.models import indexer
+
+            forms = [indexer.prefill_tiles(self.cfg, b, [b] * g)
+                     for g, b in prefill_shapes(
+                         self.prefill_groups, self.buckets, self.max_slots)]
+            build.args["index_select"] = "kernel" if any(
+                f and f[0] == "kernel" for f in forms) else "xla"
         build.__exit__()
         if warmup:
             with _tracing.span("serve.warmup"):
@@ -2096,6 +2106,16 @@ class LLMServer:
             scan["flash_q_blocks"] = sum(nq for nq, _ in blocks)
             scan["flash_q_blocks_declined"] = sum(
                 nq - run for nq, run in blocks)
+        if self.cfg.index_topk and not warm:
+            # the selection's query tiles, a layer, and those of them that
+            # ``ops/index_select.py`` declines: wholly past their row's
+            # length (none where the group's shape keeps XLA's form)
+            from ray_tpu.models import indexer
+
+            tiles = indexer.prefill_tiles(self.cfg, bucket, lens)
+            if tiles:
+                scan["index_select_tiles"] = tiles[1]
+                scan["index_select_tiles_declined"] = tiles[2]
         self._span("serve.prefill_group", t0, t1, {
             "bucket": bucket, "rows": real, "rows_padded": rows,
             "prompt_tokens": tokens, "token_positions": computed,

@@ -166,8 +166,8 @@ def test_prefill_logits_and_selected_sets_are_the_references(model):
     want = reference.logits(params, row[None], published)[0]
     masks, keep = [], indexer.prefill_keep
 
-    def recording(qi, ki_t, w, k):
-        out = keep(qi, ki_t, w, k)
+    def recording(qi, ki_t, w, k, lengths=None):
+        out = keep(qi, ki_t, w, k, lengths)
         jax.debug.callback(lambda m: masks.append(np.asarray(m[-1])), out,
                            ordered=True)
         return out
@@ -344,21 +344,47 @@ def test_the_flash_forward_takes_a_mask_that_is_data(monkeypatch):
         assert float(jnp.abs(got - want).max()) < 2e-5
 
 
-def test_a_long_prefill_selects_through_the_flash_forward(model, monkeypatch):
+@pytest.mark.parametrize("engaged", [False, True])
+def test_a_long_prefill_selects_through_the_flash_forward(model, monkeypatch,
+                                                          engaged):
     """Past ``FLASH_PREFILL_FROM`` the mask goes to the kernel, and the
-    scores are made a query tile at a time."""
+    scores are made a query tile at a time: by XLA's form at the toy index
+    width, and at one that engages (index heads of 64, a row of two whole
+    tiles of 512 that ends inside the second) by ``ops/index_select.py``,
+    told the row's length."""
+    from ray_tpu.ops import index_select
+
     cfg, params, published = model
     monkeypatch.setattr(llama, "FLASH_PREFILL_FROM", 16)
-    monkeypatch.setattr(indexer, "QUERY_TILE", 8)
     flash = importlib.import_module("ray_tpu.ops.flash_attention")
-    monkeypatch.setattr(flash, "DEFAULT_BLOCK", 16)
-    wide = dataclasses.replace(cfg, head_dim=128)
+    if engaged:
+        bucket, length = 1024, 900
+        wide = dataclasses.replace(cfg, head_dim=128, index_head_dim=64,
+                                   max_seq_len=bucket)
+    else:
+        bucket, length = 32, 29
+        monkeypatch.setattr(indexer, "QUERY_TILE", 8)
+        monkeypatch.setattr(flash, "DEFAULT_BLOCK", 16)
+        wide = dataclasses.replace(cfg, head_dim=128)
+    assert engaged == index_select.engages(
+        1, bucket, wide.index_topk, wide.index_heads, wide.index_head_dim,
+        indexer.QUERY_TILE)
+    calls, kernel = [], index_select.prefill_keep
+
+    def counting(qi, keys_t, w, lengths, k, tile):
+        calls.append((qi.shape[1], k, tile))
+        return kernel(qi, keys_t, w, lengths, k, tile)
+
+    monkeypatch.setattr(index_select, "prefill_keep", counting)
     params = _init(wide)
-    row = np.random.default_rng(8).integers(0, VOCAB, 32).astype(np.int32)
+    row = np.random.default_rng(8).integers(0, VOCAB, bucket).astype(np.int32)
     got = llama.prefill_with_states(
-        params, jnp.asarray(row[None]), jnp.asarray([29], jnp.int32), wide)
+        params, jnp.asarray(row[None]), jnp.asarray([length], jnp.int32),
+        wide)
     want = reference.logits(params, row[None], _published(wide))[0]
-    assert float(jnp.abs(got[0][0] - want[28]).max()) <= TOL
+    assert float(jnp.abs(got[0][0] - want[length - 1]).max()) <= TOL
+    # traced once: the layers are one scan
+    assert calls == [(bucket, TOPK, 512)] * engaged
 
 
 # ------------------------------------------------------- broken programs
@@ -476,7 +502,9 @@ def test_training_and_the_one_stack_cache_refuse_the_config(model):
 
 _presets = family.presets({
     "keye_debug_f32": _cfg,
-    "keye_debug": lambda **kw: _cfg(**{"dtype": jnp.bfloat16, **kw})})
+    "keye_debug": lambda **kw: _cfg(**{"dtype": jnp.bfloat16, **kw}),
+    # index heads of 64: a row of whole tiles engages ops/index_select.py
+    "keye_debug_wide": lambda **kw: _cfg(**{"index_head_dim": 64, **kw})})
 engine = family.engines("keye_debug", max_len=128)
 
 
@@ -486,6 +514,42 @@ def test_planes_that_hold_no_index_keys_refuse_the_config(plane, args):
     and K/V quantization hold K and V rows alone."""
     family.refuses_plane("keye_debug", plane, args, "has an indexer",
                          words=("no index-key pool",), absent=("window",))
+
+
+@pytest.mark.parametrize("preset,form,groups", [
+    # (bucket, a row's lengths) -> (tiles a layer, declined)
+    ("keye_debug_wide", "kernel", [
+        ((1536, [700]), (3, 1)), ((1536, [1536]), (3, 0)),
+        ((1024, [1]), (2, 1)), ((1024, [513]), (2, 0)),
+        # several rows, a bucket of no whole tile: XLA's form, a tile a row
+        ((16, [9, 16, 0, 0]), (4, 0)),
+        ((8, [8]), None)]),                 # no longer than topk
+    ("keye_debug", "xla", [
+        ((1536, [700]), (3, 0)), ((16, [9, 3]), (2, 0))]),
+])
+def test_the_engine_says_which_form_selects_and_counts_its_tiles(
+        engine, preset, form, groups):
+    """``serve.engine_build`` says ``index_select`` (the kernel where a
+    warmed (rows, bucket) engages it) and ``serve.prefill_group`` the
+    selection's query tiles a layer and those the kernel declines, from the
+    lengths the scheduler holds."""
+    timeline.clear()
+    server = engine(model_preset=preset, max_len=2048, fresh=True,
+                    prefill_buckets=(8, 16, 1024, 1536),
+                    prefill_groups=(1, 4))
+    built = family.span_args(timeline.export_timeline(), "serve.engine_build")
+    assert built[-1]["index_select"] == form
+    for (bucket, lens), _ in groups:
+        server._record_prefill_group(0.0, 1.0, bucket, np.asarray(lens),
+                                     sum(n > 0 for n in lens))
+    spans = family.span_args(timeline.export_timeline(),
+                             "serve.prefill_group")
+    assert len(spans) == len(groups)
+    for span, ((bucket, lens), want) in zip(spans, groups):
+        assert span["bucket"] == bucket
+        got = (span.get("index_select_tiles"),
+               span.get("index_select_tiles_declined"))
+        assert got == (want or (None, None)), (bucket, lens)
 
 
 def test_llm_server_serves_the_model_through_generate(model, engine):
@@ -517,7 +581,14 @@ def test_llm_server_serves_the_model_through_generate(model, engine):
     family.settle(server)
     stats = server.kv_stats()
     server.shutdown()
-    chunks = family.span_args(timeline.export_timeline(), "serve.chunk")
+    events = timeline.export_timeline()
+    assert family.span_args(events, "serve.engine_build")[-1][
+        "index_select"] == "xla"
+    groups = family.span_args(events, "serve.prefill_group")
+    assert groups and all(        # a bucket of no whole tile: one a row
+        g["index_select_tiles"] == g["rows_padded"]
+        and g["index_select_tiles_declined"] == 0 for g in groups)
+    chunks = family.span_args(events, "serve.chunk")
     assert chunks
     for c in chunks:
         assert "index_keys_scored" not in c     # one a position present
